@@ -12,17 +12,11 @@ import sys
 from typing import Optional, Union
 
 from deepspeed_tpu import comm as comm
-from deepspeed_tpu import module_inject
 from deepspeed_tpu import ops
 from deepspeed_tpu.accelerator import get_accelerator
 from deepspeed_tpu.comm.comm import init_distributed
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
-from deepspeed_tpu.module_inject import replace_transformer_layer, revert_transformer_layer
-from deepspeed_tpu.ops.transformer import (DeepSpeedTransformerConfig,
-                                           DeepSpeedTransformerLayer)
 from deepspeed_tpu.runtime import DeepSpeedOptimizer, ZeROOptimizer
-from deepspeed_tpu.runtime.config import DeepSpeedConfig, DeepSpeedConfigError
-from deepspeed_tpu.runtime import zero
 from deepspeed_tpu.runtime.activation_checkpointing import checkpointing
 from deepspeed_tpu.runtime.lr_schedules import add_tuning_arguments
 from deepspeed_tpu.utils import groups, logger, log_dist
@@ -33,10 +27,24 @@ dist = comm
 
 
 def __getattr__(name):
-    # engine/pipe/inference classes re-exported LAZILY (reference
-    # deepspeed/__init__.py exports them eagerly; here an eager import would
-    # pull jax-heavy modules into every `import deepspeed_tpu`)
+    # everything that imports jax or flax at module level is re-exported
+    # LAZILY (reference deepspeed/__init__.py exports eagerly): `import
+    # deepspeed_tpu` itself must not import jax, because the launcher
+    # (launcher/runner.py, a submodule of this package) is a parent that
+    # starts the processes which own the chips
     lazy = {
+        "module_inject": ("deepspeed_tpu.module_inject", None),
+        "replace_transformer_layer": ("deepspeed_tpu.module_inject",
+                                      "replace_transformer_layer"),
+        "revert_transformer_layer": ("deepspeed_tpu.module_inject",
+                                     "revert_transformer_layer"),
+        "DeepSpeedTransformerConfig": ("deepspeed_tpu.ops.transformer",
+                                       "DeepSpeedTransformerConfig"),
+        "DeepSpeedTransformerLayer": ("deepspeed_tpu.ops.transformer",
+                                      "DeepSpeedTransformerLayer"),
+        "DeepSpeedConfig": ("deepspeed_tpu.runtime.config", "DeepSpeedConfig"),
+        "DeepSpeedConfigError": ("deepspeed_tpu.runtime.config", "DeepSpeedConfigError"),
+        "zero": ("deepspeed_tpu.runtime.zero", None),
         "DeepSpeedEngine": ("deepspeed_tpu.runtime.engine", "DeepSpeedEngine"),
         "DeepSpeedHybridEngine": ("deepspeed_tpu.runtime.hybrid_engine",
                                   "DeepSpeedHybridEngine"),
@@ -48,7 +56,8 @@ def __getattr__(name):
     if name in lazy:
         import importlib
         mod, attr = lazy[name]
-        return getattr(importlib.import_module(mod), attr)
+        module = importlib.import_module(mod)
+        return module if attr is None else getattr(module, attr)
     raise AttributeError(f"module 'deepspeed_tpu' has no attribute {name!r}")
 
 

@@ -32,12 +32,10 @@ def get_accelerator():
     if accelerator_name is not None:
         _validate_accelerator(accelerator_name)
     else:
-        try:
-            import jax
-            backend = jax.default_backend()
-        except Exception:
-            backend = "cpu"
-        accelerator_name = "tpu" if backend == "tpu" else "cpu"
+        # a backend that cannot be reached raises here: answering "cpu" for a
+        # TPU that failed to start would let a run succeed without the chip
+        import jax
+        accelerator_name = "tpu" if jax.default_backend() == "tpu" else "cpu"
 
     set_accelerator_by_name(accelerator_name)
     return ds_accelerator

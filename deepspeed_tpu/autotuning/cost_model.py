@@ -22,15 +22,15 @@ from typing import Dict, List, Optional
 import numpy as np
 
 
-def device_memory_bytes(default: int = 16 << 30) -> int:
+def device_memory_bytes() -> Optional[int]:
+    """The first device's memory limit as its backend reports it; None where
+    the backend reports none (the CPU does not). A backend that cannot be
+    asked raises — no size is assumed for a device nobody saw."""
     import jax
-    try:
-        stats = jax.devices()[0].memory_stats()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
-    return default
+    stats = jax.devices()[0].memory_stats()
+    if stats and "bytes_limit" in stats:
+        return int(stats["bytes_limit"])
+    return None
 
 
 class AnalyticCostModel:
@@ -64,7 +64,9 @@ class AnalyticCostModel:
         return master + compute + grads + opt + act
 
     def fits(self, cfg: Dict, safety: float = 0.85) -> bool:
-        return self.memory_bytes(cfg) <= self.hbm * safety
+        """False only for a config predicted over a KNOWN limit: with no
+        limit reported there is nothing to prune against."""
+        return self.hbm is None or self.memory_bytes(cfg) <= self.hbm * safety
 
     def throughput_prior(self, cfg: Dict) -> float:
         """Relative samples/sec prior (unitless; ordering is what matters):

@@ -35,11 +35,7 @@ def load_model_factory(spec: str):
     return getattr(importlib.import_module(mod), fn)
 
 
-from deepspeed_tpu.utils.jax_platform import honor_platform_env
-
-
 def run(exp_dir: str) -> int:
-    honor_platform_env()
     with open(os.path.join(exp_dir, "exp.json")) as f:
         exp = json.load(f)
     result_path = os.path.join(exp_dir, "results.json")
@@ -80,7 +76,6 @@ def profile(factory_spec: str, config_path: str) -> int:
     """Build the factory's model once and print its parameter count as one
     JSON line — the tuner's static profile, run out-of-process so a model
     too big for the tuner process can't kill it."""
-    honor_platform_env()
     import numpy as np
     import jax
 

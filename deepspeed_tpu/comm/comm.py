@@ -37,7 +37,6 @@ from deepspeed_tpu.comm.reduce_op import ReduceOp
 from deepspeed_tpu.utils import groups as groups_mod
 from deepspeed_tpu.utils.comms_logging import CommsLogger
 from deepspeed_tpu.utils.logging import logger
-from deepspeed_tpu.utils.jax_compat import shard_map as _compat_shard_map
 
 cdb = None  # current distributed backend (reference: comm.py:41)
 comms_logger = CommsLogger()
@@ -131,13 +130,9 @@ def _enable_cpu_cross_process_collectives():
         return
     if platforms.split(",")[0].strip().lower() != "cpu":
         return
-    try:
-        if getattr(jax.config, "jax_cpu_collectives_implementation", None) != "gloo":
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-            logger.info("CPU gang: cross-process collectives backend = gloo")
-    except Exception as e:  # older jaxlibs without the option: surface, don't die
-        logger.warning(f"could not select gloo CPU collectives ({e}); "
-                       f"multi-process CPU computations may be unavailable")
+    if jax.config.jax_cpu_collectives_implementation != "gloo":
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
+        logger.info("CPU gang: cross-process collectives backend = gloo")
 
 
 def destroy_process_group(group=None):
@@ -232,7 +227,7 @@ def timed_op(func):
 def _shard_map(fn, in_specs, out_specs):
     import jax
     mesh = groups_mod.get_mesh()
-    return _compat_shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
 
 
 def _device_put_grouped(tensor, axes):

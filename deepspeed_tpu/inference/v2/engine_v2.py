@@ -47,7 +47,7 @@ class InferenceEngineV2:
 
         self._model = model
         self._initialize_comm_groups()
-        self._apply_tensor_parallel()
+        self._place_params()
 
         self._batch = RaggedBatchWrapper(engine_config.state_manager,
                                          block_size=engine_config.kv_block_size)
@@ -101,14 +101,19 @@ class InferenceEngineV2:
         elif tp > 1 or ep > 1:
             groups.initialize_mesh(model_parallel_size=tp, expert_parallel_size=ep)
 
-    def _apply_tensor_parallel(self) -> None:
-        """TP>1 (incl. TP+EP, which the reference rejects at engine_v2.py:85):
-        place the param tree with AutoTP-derived shardings; the SPMD partitioner
-        inserts the per-layer all-reduce the reference's ``LinearAllreduce``
-        modules perform (module_inject/layers.py:16). Expert banks stay sharded
-        only on the expert axis — the EP shard_map path owns their layout."""
+    def _place_params(self) -> None:
+        """TP>1 and/or EP>1 (incl. TP+EP, which the reference rejects at
+        engine_v2.py:85): place the param tree with AutoTP-derived shardings.
+        Over ``model`` the SPMD partitioner inserts the per-layer all-reduce the
+        reference's ``LinearAllreduce`` modules perform (module_inject/
+        layers.py:16); over ``expert`` each chip holds only its own experts'
+        banks, which is the layout the EP shard_map consumes — left where the
+        caller made them, the banks would sit whole on one device and be
+        resharded inside every step. A tree that already has these shardings
+        (``init_params(..., mesh=...)``) is not moved."""
         tp = self._config.tensor_parallel.tp_size
-        if tp <= 1:
+        ep = self._config.expert_parallel.replica_num if self._config.expert_parallel.enabled else 1
+        if tp <= 1 and ep <= 1:
             return
         import jax
         from jax.sharding import NamedSharding
@@ -118,7 +123,8 @@ class InferenceEngineV2:
         specs = auto_tp_specs(self._model._params)
         self._model._params = jax.device_put(
             self._model._params, jax.tree.map(lambda s: NamedSharding(mesh, s), specs))
-        logger.info(f"inference-v2: AutoTP placed params over model axis (tp={tp})")
+        logger.info(f"inference-v2: AutoTP placed params (tp={tp} over model, "
+                    f"ep={ep} over expert)")
 
     # ------------------------------------------------------------ properties --
     @property

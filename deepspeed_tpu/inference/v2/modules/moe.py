@@ -34,7 +34,6 @@ from typing import Optional
 import numpy as np
 
 from deepspeed_tpu.utils import groups
-from deepspeed_tpu.utils.jax_compat import shard_map as _compat_shard_map
 
 _SIMULATED_GATING = {"enabled": False, "temperature": 1.0}
 
@@ -242,7 +241,7 @@ class RaggedMoE:
             ret = ret.reshape(E, C, M)                           # global-expert major
             return jnp.einsum("tec,ecm->tm", combine.astype(h_l.dtype), ret)
 
-        shmap = _compat_shard_map(body, mesh=mesh,
+        shmap = jax.shard_map(body, mesh=mesh,
                               in_specs=(P(ax), P(), P(ax), P(ax), P(ax), P()),
                               out_specs=P(ax), check_vma=False)
         out = shmap(h, gate_w, wi, wo, token_valid, seed)

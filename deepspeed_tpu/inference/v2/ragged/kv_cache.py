@@ -27,6 +27,23 @@ def _dtype_size(name):
     return {"bfloat16": 2, "float16": 2, "float32": 4, "int8": 1}[name]
 
 
+def _cache_sharding(kv_heads: int):
+    """Where the cache array lives: on the engine's mesh, KV heads split over
+    the ``model`` axis (tensor parallelism shards attention by head) and
+    replicated over every other axis — under expert parallelism each chip
+    attends over all tokens and only the MoE exchanges them. None (the default
+    device) when no mesh exists, i.e. a single-device engine."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.utils import groups
+    if not groups.mesh_is_initialized():
+        return None
+    mesh = groups.get_mesh()
+    tp = mesh.shape[groups.MODEL_AXIS]
+    heads_axis = groups.MODEL_AXIS if tp > 1 and kv_heads % tp == 0 else None
+    return NamedSharding(mesh, P(None, None, None, heads_axis, None, None))
+
+
 class _LazyAIO:
     """Spill-file I/O for the tiered store that defers to the cache's AIO
     engine — built lazily so a cache that never spills never imports
@@ -61,7 +78,9 @@ class BlockedKVCache:
         self._allocator = BlockedAllocator(num_blocks)
 
         dtype = {"bfloat16": jnp.bfloat16, "float16": jnp.float16, "float32": jnp.float32}[config.cache_dtype]
-        self._cache = jnp.zeros((num_layers, 2, num_blocks, kv_heads, config.block_size, head_dim), dtype)
+        self._sharding = _cache_sharding(kv_heads)
+        self._cache = jnp.zeros((num_layers, 2, num_blocks, kv_heads, config.block_size, head_dim), dtype,
+                                device=self._sharding)
         logger.info(f"BlockedKVCache: {num_blocks} blocks x {config.block_size} tokens "
                     f"({num_blocks * block_bytes / 1e9:.2f} GB)")
 
@@ -95,6 +114,12 @@ class BlockedKVCache:
     @property
     def cache(self):
         return self._cache
+
+    @property
+    def sharding(self):
+        """The cache's ``NamedSharding`` on the engine's mesh; None for a cache
+        on the default device of a mesh-less engine."""
+        return self._sharding
 
     def set_cache(self, cache):
         self._cache = cache

@@ -162,9 +162,9 @@ class RaggedBatchWrapper:
             blocks = self._seq_blocks[i]
             block_table[i, :len(blocks)] = blocks
 
-        # Pack into TWO device arrays (plus host-only counts): under a tunneled
-        # or multi-host dispatch every h2d transfer pays latency, and decode
-        # issues one batch per generated token — 2 transfers/step, not 10.
+        # Pack into TWO device arrays (plus host-only counts): every h2d
+        # transfer pays dispatch latency, and decode issues one batch per
+        # generated token — 2 transfers/step, not 10.
         # transformer_base._unpack_batch restores the named views inside jit.
         tok_meta = np.stack([input_ids, token_seq, token_pos,
                              token_valid.astype(np.int32)])  # [4, T]

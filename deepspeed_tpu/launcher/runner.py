@@ -11,6 +11,17 @@ render a pdsh/ssh/srun command. Spawned processes receive
 ``DSTPU_COORDINATOR/NUM_PROCESSES/PROCESS_ID`` which
 ``deepspeed_tpu.comm.init_distributed`` feeds to ``jax.distributed.initialize``
 (the JAX coordination-service rendezvous replacing torch.distributed's).
+
+This process never imports jax: a chip belongs to one process at a time, and
+a launcher that asked the backend how many chips there are would hold the
+chips its children need. The count comes from ``--num_chips`` or the hostfile.
+
+On ONE host the supported way to use several chips is one process driving all
+of them (single-controller SPMD — ``python chip_smoke.py --chips 4`` does
+exactly that; run such a script directly, without this launcher).
+``dstpu --num_chips N`` starts N processes with rank variables but gives none
+of them a chip of its own, so on a single TPU host it is a CPU / multi-host
+formulation only.
 """
 
 import argparse
@@ -138,8 +149,12 @@ def main(argv=None):
 
     pool = fetch_hostfile(args.hostfile)
     if pool is None:
-        n = args.num_chips if args.num_chips > 0 else _local_chip_count()
-        pool = OrderedDict([("localhost", n)])
+        if args.num_chips <= 0:
+            raise SystemExit(
+                f"dstpu: no hostfile at {args.hostfile} and no --num_chips: say how "
+                "many processes to start (the launcher does not ask the backend — "
+                "a process that counts the chips holds them)")
+        pool = OrderedDict([("localhost", args.num_chips)])
     active = parse_resource_filter(pool, args.include, args.exclude)
     if args.num_nodes > 0:
         active = OrderedDict(list(active.items())[:args.num_nodes])
@@ -174,14 +189,6 @@ def main(argv=None):
     cmd = runner.get_cmd(env, active)
     logger.info(f"dstpu {runner.name}: {' '.join(cmd)}")
     return subprocess.call(cmd, env=env)
-
-
-def _local_chip_count():
-    try:
-        import jax
-        return max(1, len(jax.devices()))
-    except Exception:
-        return 1
 
 
 if __name__ == "__main__":

@@ -9,7 +9,7 @@ equivalent model implementation: flax, bf16 matmuls on the MXU, GQA, RoPE, SwiGL
 """
 
 from dataclasses import dataclass, field
-from functools import partial
+from functools import lru_cache, partial
 from typing import Optional
 
 import flax.linen as nn
@@ -105,8 +105,36 @@ def causal_attention(q, k, v, scale, window: int = 0):
 
 
 def flash_causal_attention(q, k, v, scale):
+    """Pallas flash attention over [B, S, H, D], one kernel instance per device.
+
+    The SPMD partitioner cannot split a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so on a multi-device mesh the call is
+    shard_mapped: sequences over the data-parallel axes, heads over ``seq``
+    (where Ulysses puts them) and ``model``. Attention is independent per
+    (sequence, head), so any such split is exact; an axis that does not divide
+    its dimension is left out and that dimension stays whole on every device."""
+    from jax.sharding import PartitionSpec as P
+
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-    return flash_attention(q, k, v, scale=scale, causal=True)
+    attn = partial(flash_attention, scale=scale, causal=True)
+    mesh = groups.get_mesh() if groups.mesh_is_initialized() else None
+    # inside someone else's shard_map (the pipeline engine) the program is
+    # already per-device
+    if mesh is None or mesh.size == 1 or jax.sharding.get_abstract_mesh().manual_axes:
+        return attn(q, k, v)
+
+    def dividing(axes, *sizes):
+        picked, n = [], 1
+        for ax in axes:
+            if mesh.shape[ax] > 1 and all(s % (n * mesh.shape[ax]) == 0 for s in sizes):
+                picked.append(ax)
+                n *= mesh.shape[ax]
+        return tuple(picked) or None
+
+    spec = P(dividing(groups.DATA_PARALLEL_AXES, q.shape[0]), None,
+             dividing((groups.SEQ_AXIS, groups.MODEL_AXIS), q.shape[2], k.shape[2]), None)
+    return jax.shard_map(attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)(q, k, v)
 
 
 class LlamaAttention(nn.Module):
@@ -209,12 +237,57 @@ def cross_entropy_loss(logits, labels, ignore_index=-100):
     return -jnp.sum(ll * valid) / jnp.maximum(jnp.sum(valid), 1)
 
 
-def init_params(cfg: LlamaConfig, rng=None, batch_size=1, seq_len=None):
-    model = LlamaForCausalLM(cfg)
+@lru_cache(maxsize=32)
+def _jitted_init(model, batch_size, seq_len, param_dtype, mesh, specs_fn):
+
+    def init(rng):
+        # the forward pass flax runs to discover the parameters is dead code
+        # under jit: only the initializers survive into the program
+        ids = jnp.zeros((batch_size, seq_len), jnp.int32)
+        params = model.init(rng, (ids, ids))["params"]
+        if param_dtype is None:
+            return params
+        return jax.tree.map(
+            lambda x: x.astype(param_dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x,
+            params)
+
+    out_shardings = None
+    if mesh is not None:
+        specs = specs_fn(jax.eval_shape(init, jax.random.PRNGKey(0)))
+        out_shardings = jax.tree.map(lambda s: jax.sharding.NamedSharding(mesh, s), specs)
+    return jax.jit(init, out_shardings=out_shardings)
+
+
+def random_params(model, rng, batch_size, seq_len, param_dtype=None, mesh=None,
+                  specs_fn=None):
+    """Random parameters of a loss module over ``(input_ids, labels)`` batches.
+
+    Asked for nothing else, this is flax's eager ``model.init`` (float32 Dense
+    kernels; what the tiny models of the tests use, at no compile).
+
+    Asked for a dtype or a mesh, the parameters are made ON THE DEVICE by one
+    jitted program — the way to build a full-width tree (Llama-2-7B,
+    Mixtral-8x7B), which must never exist as an eagerly initialized float32 copy
+    on the host or on the first device. ``param_dtype`` casts every floating leaf
+    inside the program (serving keeps weights in the compute dtype at rest).
+    ``mesh`` places each leaf as ``specs_fn(abstract_params)`` says, each device
+    generating only its own shard; the values do not depend on the placement
+    (jax's threefry is partitionable), so one seed gives one model on one chip
+    and on four."""
     rng = rng if rng is not None else jax.random.PRNGKey(0)
+    if param_dtype is None and mesh is None:
+        ids = jnp.zeros((batch_size, seq_len), jnp.int32)
+        return model.init(rng, (ids, ids))["params"]
+    return _jitted_init(model, batch_size, seq_len, param_dtype, mesh, specs_fn)(rng)
+
+
+def init_params(cfg: LlamaConfig, rng=None, batch_size=1, seq_len=None, param_dtype=None,
+                mesh=None):
+    """``(model, params)`` with random weights; see :func:`random_params`.
+    With ``mesh``, leaves are placed by :func:`llama_param_specs`."""
+    model = LlamaForCausalLM(cfg)
     S = seq_len or min(cfg.max_position_embeddings, 16)
-    ids = jnp.zeros((batch_size, S), jnp.int32)
-    return model, model.init(rng, (ids, ids))["params"]
+    return model, random_params(model, rng, batch_size, S, param_dtype, mesh, llama_param_specs)
 
 
 def llama_param_specs(params, model_axis=groups.MODEL_AXIS):

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.llama import (LlamaAttention, LlamaConfig, RMSNorm, cross_entropy_loss,
-                                        rotary_embedding)
+                                        random_params, rotary_embedding)
 from deepspeed_tpu.moe.layer import MoE
 from deepspeed_tpu.utils import groups
 
@@ -101,11 +101,16 @@ class MixtralForCausalLM(nn.Module):
         return ce + self.aux_loss_weight * total_aux
 
 
-def init_params(cfg: MixtralConfig, rng=None, batch_size=1, seq_len=16):
+def init_params(cfg: MixtralConfig, rng=None, batch_size=1, seq_len=16, param_dtype=None,
+                mesh=None):
+    """``(model, params)`` with random weights; full-width trees are made on the
+    device by asking for ``param_dtype`` and/or ``mesh``
+    (:func:`deepspeed_tpu.models.llama.random_params`). With ``mesh``, leaves
+    are placed by :func:`mixtral_param_specs`: the stacked expert banks over the
+    ``expert`` axis, attention/embedding/lm_head over ``model``."""
     model = MixtralForCausalLM(cfg)
-    rng = rng if rng is not None else jax.random.PRNGKey(0)
-    ids = jnp.zeros((batch_size, seq_len), jnp.int32)
-    return model, model.init(rng, (ids, ids))["params"]
+    return model, random_params(model, rng, batch_size, seq_len, param_dtype, mesh,
+                                mixtral_param_specs)
 
 
 def mixtral_param_specs(params, model_axis=groups.MODEL_AXIS, expert_axis=groups.EXPERT_AXIS):

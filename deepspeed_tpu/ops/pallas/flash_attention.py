@@ -12,7 +12,7 @@ Design:
 - Backward: hand Pallas kernels (``_flash_bwd_pallas``): a dK/dV kernel owning one
   KV block and streaming q/do rows, and a dQ kernel owning one Q block and
   streaming KV — the FA2 backward, O(S) memory. The blockwise-JAX backward
-  (``_flash_bwd_manual``) stays as the numerical oracle and debug fallback.
+  (``_flash_bwd_manual``) stays as the tests' numerical oracle only.
 - CPU (tests): interpret mode.
 
 Layout: q, k, v are [B, S, H, D] (kv may have fewer heads — GQA is expanded by the
@@ -137,6 +137,7 @@ def _flash_fwd_pallas(q, k, v, scale, causal, block_q=512, block_k=1024, save_ls
         out_shape=out_shape if save_lse else out_shape[0],
         scratch_shapes=scratch,
         interpret=on_cpu,
+        name="flash_attention_fwd",
         **kwargs,
     )(qr, kr, vr)
     if save_lse:
@@ -381,6 +382,7 @@ def _flash_bwd_pallas(q, k, v, out, g, lse, scale, causal, block_q=512, block_k=
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         interpret=on_cpu,
+        name="flash_attention_bwd_dkv",
         **kwargs,
     )(qr, dor, lse, delta, kr, vr)
     dq = pl.pallas_call(
@@ -399,6 +401,7 @@ def _flash_bwd_pallas(q, k, v, out, g, lse, scale, causal, block_q=512, block_k=
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=on_cpu,
+        name="flash_attention_bwd_dq",
         **kwargs,
     )(kr, vr, qr, dor, lse, delta)
 
@@ -407,35 +410,11 @@ def _flash_bwd_pallas(q, k, v, out, g, lse, scale, causal, block_q=512, block_k=
     return back(dq), back(dk), back(dv)
 
 
-# test/debug escape hatch: the blockwise-JAX backward stays as the oracle
+# the tests' oracle switch: the blockwise-JAX backward is what the Pallas
+# backward is compared against. Nothing else selects it — a backward the
+# compiler refuses raises (tests/unit/ops/test_tpu_compile.py asks the chip's
+# compiler at the training shapes, without a chip)
 _FORCE_MANUAL_BWD = False
-_PALLAS_BWD_OK = {}  # (dtype, head_dim, causal) -> bool
-
-
-def _pallas_bwd_available(q, causal) -> bool:
-    """Per-(dtype, head_dim, causal) compile probe of the backward kernels on
-    tiny shapes: Mosaic lowering rejections are shape/dtype-dependent and
-    differ across compiler versions — they must degrade THAT config to the
-    blockwise-JAX oracle, not kill the training step (and must not pin other
-    configs to the slow path)."""
-    D = q.shape[-1]
-    key = (jnp.dtype(q.dtype).name, D, bool(causal))
-    ok = _PALLAS_BWD_OK.get(key)
-    if ok is None:
-        try:
-            S = 256
-            z = jnp.zeros((1, S, 1, D), q.dtype)
-            lse = jnp.zeros((1, S, 1), jnp.float32)
-            jax.jit(functools.partial(_flash_bwd_pallas, scale=1.0, causal=bool(causal))) \
-                .lower(z, z, z, z, z, lse).compile()
-            ok = True
-        except Exception as e:  # pragma: no cover - compiler-version dependent
-            from deepspeed_tpu.utils.logging import logger
-            logger.warning(f"Pallas flash backward unavailable for {key} on this "
-                           f"compiler ({str(e)[:120]}); using the blockwise-JAX backward")
-            ok = False
-        _PALLAS_BWD_OK[key] = ok
-    return ok
 
 
 def _fa_fwd(q, k, v, scale, causal):
@@ -450,7 +429,7 @@ def _fa_bwd(scale, causal, res, g):
     q, k, v, out, lse = res
     kvh = k.shape[2]
     ke, ve = _expand_gqa(q, k, v)
-    if _FORCE_MANUAL_BWD or not _pallas_bwd_available(q, causal):
+    if _FORCE_MANUAL_BWD:
         dq, dke, dve = _flash_bwd_manual(q, ke, ve, out, g, scale, causal)
     else:
         dq, dke, dve = _flash_bwd_pallas(q, ke, ve, out, g, lse, scale, causal)

@@ -168,11 +168,11 @@ def paged_attention_update(q, k_new, v_new, cache, layer_idx, block_table, token
             pl.BlockSpec((1, H, D), lambda t, *_: (t, 0, 0)),
             pl.BlockSpec((1, KVH, D), lambda t, *_: (t, 0, 0)),
             pl.BlockSpec((1, KVH, D), lambda t, *_: (t, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # cache in HBM, aliased in/out
+            pl.BlockSpec(memory_space=pl.ANY),  # cache in HBM, aliased in/out
         ],
         out_specs=[
             pl.BlockSpec((1, H, D), lambda t, *_: (t, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.VMEM((2, CHUNK, KVH, bs, D), cache.dtype),
@@ -190,6 +190,7 @@ def paged_attention_update(q, k_new, v_new, cache, layer_idx, block_table, token
                    jax.ShapeDtypeStruct(cache.shape, cache.dtype)],
         input_output_aliases={7: 1},  # cache operand (after 4 scalar-prefetch args)
         interpret=interpret,
+        name="paged_attention_update",
     )(block_table.astype(jnp.int32), token_seq.astype(jnp.int32),
       token_pos.astype(jnp.int32), token_valid.astype(jnp.int32),
       q, k_new.astype(cache.dtype), v_new.astype(cache.dtype), cache)

@@ -30,7 +30,8 @@ active; with telemetry off the engine's observer slot stays None and the
 dispatch path pays a single attribute load.
 """
 
-from deepspeed_tpu.perf.chip_specs import DEFAULT_CHIP, get_chip_spec
+from deepspeed_tpu.perf.chip_specs import (DEFAULT_CHIP, chip_spec_for_device_kind,
+                                           get_chip_spec)
 
 # engine dispatch kind -> the flagship program whose roofline models it; a
 # `put` whose feeds are all single tokens IS a paged decode step
@@ -76,7 +77,15 @@ class PerfObservedLedger:
                  baseline_dispatches: int = 8):
         self._registry = registry
         self._pricebook = pricebook
-        self._chip = get_chip_spec(chip or DEFAULT_CHIP)
+        # on a TPU the chip is the one the process runs on, looked up by what
+        # the device reports (unknown kind = error); ``chip`` only names the
+        # prediction target for off-TPU runs, whose ratios are baseline-
+        # relative anyway (see the module docstring)
+        import jax
+        if jax.default_backend() == "tpu":
+            self._chip = chip_spec_for_device_kind(jax.devices()[0].device_kind)
+        else:
+            self._chip = get_chip_spec(chip or DEFAULT_CHIP)
         self._drift_factor = float(drift_factor)
         self._drift_consecutive = max(1, int(drift_consecutive))
         self._baseline_dispatches = max(1, int(baseline_dispatches))

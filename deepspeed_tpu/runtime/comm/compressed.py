@@ -20,7 +20,6 @@ from typing import Tuple
 import numpy as np
 
 from deepspeed_tpu.utils import groups
-from deepspeed_tpu.utils.jax_compat import shard_map as _compat_shard_map
 
 
 def _sign_compress(x):
@@ -80,7 +79,7 @@ def compressed_allreduce(tensor, worker_error, server_error, axis_name=None, mes
     if n <= 1:
         return tensor, worker_error, server_error
 
-    fn = _compat_shard_map(
+    fn = jax.shard_map(
         lambda x, we, se: compressed_allreduce_local(x[0], we[0], se[0], axis_name, n),
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P(axis_name)),
@@ -146,7 +145,7 @@ def quantized_reduce_scatter(tensor, axis_name=None, mesh=None, block: int = 512
         raise ValueError(f"reduce-scatter length {tensor.shape[-1]} must be divisible "
                          f"by the axis size {n} (pad the flat gradient first)")
 
-    fn = _compat_shard_map(
+    fn = jax.shard_map(
         lambda x: quantized_reduce_scatter_local(x[0], axis_name, n, block),
         mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name), check_vma=False)
     return fn(tensor)

@@ -24,7 +24,6 @@ from functools import partial
 from deepspeed_tpu.runtime.comm.compressed import quantized_reduce_scatter_local
 from deepspeed_tpu.utils import groups
 from deepspeed_tpu.utils.logging import logger
-from deepspeed_tpu.utils.jax_compat import shard_map as _compat_shard_map
 
 
 def qgz_supported(mesh, stage: int) -> bool:
@@ -79,7 +78,7 @@ def make_qgz_micro_grads(loss_fn, takes_rng, compute_dtype, accum_dtype, mesh,
             jax.tree.map(lambda l: jax.ShapeDtypeStruct(l.shape, jnp.float32), params))
         total = sample.shape[0]
 
-        body = _compat_shard_map(
+        body = jax.shard_map(
             local_body,
             mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), params),

@@ -371,6 +371,12 @@ class DeepSpeedEngine:
                 self.scale_state = static_loss_scale_state(self._config.fp16_config.loss_scale)
         else:
             self.scale_state = static_loss_scale_state(1.0)
+        # on the mesh like every other step input: left uncommitted, the first
+        # step hands it back committed, and the SECOND step — the same program
+        # under a different argument placement — compiles all over again
+        from jax.sharding import NamedSharding, PartitionSpec
+        self.scale_state = jax.device_put(self.scale_state,
+                                          NamedSharding(self.mesh, PartitionSpec()))
         self._overflow_count = jnp.zeros([], jnp.int32)
 
         # 10. lr scheduler (reference _configure_lr_scheduler, engine.py:905)

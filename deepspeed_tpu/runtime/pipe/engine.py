@@ -35,7 +35,6 @@ from deepspeed_tpu.runtime.engine import DeepSpeedEngine
 from deepspeed_tpu.runtime.pipe.module import LayerSpec, PipelineModule
 from deepspeed_tpu.utils import groups
 from deepspeed_tpu.utils.logging import logger
-from deepspeed_tpu.utils.jax_compat import shard_map as _compat_shard_map
 
 PIPE_AXIS = groups.PIPE_AXIS
 
@@ -224,7 +223,7 @@ class PipelineEngine(DeepSpeedEngine):
                 total = jax.lax.psum(jnp.where(stage == P_stages - 1, losses.mean(), 0.0), PIPE_AXIS)
                 return jax.lax.pmean(total, dp_axes)
 
-            return _compat_shard_map(pipelined,
+            return jax.shard_map(pipelined,
                                  mesh=mesh,
                                  in_specs=(param_specs, batch_spec, batch_spec),
                                  out_specs=PS(),

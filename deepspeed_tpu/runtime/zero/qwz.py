@@ -23,7 +23,6 @@ import functools
 import numpy as np
 
 from deepspeed_tpu.utils import groups
-from deepspeed_tpu.utils.jax_compat import shard_map as _compat_shard_map
 
 
 def qwz_supported(stage: int) -> bool:
@@ -133,7 +132,7 @@ def _make_quantized_gather(dim, spec, gathered_spec, gather_axes, mesh, compute_
         s_full = jax.lax.all_gather(s_blk, axis_name, axis=dim, tiled=True)
         return q_full, s_full
 
-    gather_sm = _compat_shard_map(gather_block, mesh=mesh, in_specs=(spec, scale_spec),
+    gather_sm = jax.shard_map(gather_block, mesh=mesh, in_specs=(spec, scale_spec),
                               out_specs=(gathered_spec, scale_gathered),
                               check_vma=False)
 

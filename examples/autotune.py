@@ -46,4 +46,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from deepspeed_tpu.utils.jax_platform import enable_compile_cache
+    enable_compile_cache()
     main()

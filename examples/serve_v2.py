@@ -3,8 +3,9 @@
 Prefill + on-device decode_loop + continuous-batching generate + the
 inference-checkpoint round-trip, on a tiny random llama.
 
-Run:
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 python examples/serve_v2.py
+Run (virtual 8-device CPU mesh; on a TPU host drop both variables):
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        python examples/serve_v2.py
 
 Server mode (``DSTPU_SERVE_MODE=server``): start the persistent serving layer
 — ServingScheduler + ServingServer on an ephemeral port — submit two
@@ -30,11 +31,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.realpath(__file__))))
 import tempfile
-
-if "host_platform_device_count" in os.environ.get("XLA_FLAGS", "") \
-        or os.environ.get("JAX_PLATFORMS", "") == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 
@@ -336,6 +332,8 @@ def main():
 
 
 if __name__ == "__main__":
+    from deepspeed_tpu.utils.jax_platform import enable_compile_cache
+    enable_compile_cache()
     mode = os.environ.get("DSTPU_SERVE_MODE")
     if mode == "server":
         serve_main()

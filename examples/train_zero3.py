@@ -1,19 +1,15 @@
 """Quickstart: ZeRO-3 training with bf16 compute and qwZ weight gathers.
 
 Run (virtual 8-device CPU mesh):
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 python examples/train_zero3.py
-On a TPU host, drop the flag — the real chips form the mesh.
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        python examples/train_zero3.py
+On a TPU host, drop both variables — the real chips form the mesh.
 """
 
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.realpath(__file__))))
-
-if "--cpu" in sys.argv or os.environ.get("JAX_PLATFORMS", "") == "cpu" \
-        or "host_platform_device_count" in os.environ.get("XLA_FLAGS", ""):
-    import jax
-    jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 import jax
@@ -150,6 +146,8 @@ def main():
 
 
 if __name__ == "__main__":
+    from deepspeed_tpu.utils.jax_platform import enable_compile_cache
+    enable_compile_cache()
     if os.environ.get("DSTPU_CKPT_DIR"):
         main_fault_tolerant()
     else:

@@ -159,3 +159,38 @@ def test_simulated_gating(mixtral_setup):
     assert float(p_hot.max()) > float(p_flat.max())
     np.testing.assert_allclose(np.asarray(simulated_expert_probs(0, 4, temperature=1.0)),
                                np.asarray(simulated_expert_probs(0, 4, temperature=1.0)))
+
+
+def test_ep_without_tp_places_banks_and_cache(mixtral_setup):
+    """EP=4 with tp_size=1: every expert bank is split over four devices (a
+    quarter of the bytes each, no device holding a whole bank) and the KV cache
+    sits replicated on the engine's mesh — before and after a forward, so a
+    bucket compiles once and the donated cache never changes layout."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    cfg, params = mixtral_setup
+    seqs = _batch(cfg, (9, 3))
+    groups.initialize_mesh(devices=jax.devices()[:1], force=True)
+    ref = np.asarray(build_engine(params, cfg, _engine_config()).put(list(seqs), list(seqs.values())))
+
+    mesh = groups.initialize_mesh(expert_parallel_size=4, data_parallel_size=1,
+                                  devices=jax.devices()[:4], force=True)
+    # the Pallas kernel forced on: on a mesh it must run shard_mapped (a Mosaic
+    # kernel is not partitionable), one instance per device over the replica
+    engine = build_engine(params, cfg, _engine_config(ep=True, use_paged_kernel=True))
+
+    for li in range(cfg.num_hidden_layers):
+        for name, bank in engine.model._params[f"layers_{li}"]["block_sparse_moe"]["ExpertFFN_0"].items():
+            shards = bank.addressable_shards
+            assert len({s.device for s in shards}) == 4, name
+            assert all(s.data.nbytes * 4 == bank.nbytes for s in shards), name
+    gate = jax.tree.leaves(engine.model._params["layers_0"]["block_sparse_moe"]["gate"])[0]
+    assert gate.sharding.is_fully_replicated and len(gate.addressable_shards) == 4
+
+    want = NamedSharding(mesh, P())
+    cache = engine._state_manager.kv_cache
+    assert cache.cache.sharding.is_equivalent_to(want, cache.cache.ndim)
+    out = np.asarray(engine.put(list(seqs), list(seqs.values())))
+    np.testing.assert_allclose(out, ref, rtol=3e-4, atol=3e-4)
+    assert cache.cache.sharding.is_equivalent_to(want, cache.cache.ndim)
+    assert len(cache.cache.addressable_shards) == 4

@@ -99,6 +99,25 @@ def test_slurm_cmd_construction():
     assert any("$SLURM_NODEID" in c for c in cmd)
 
 
+def test_local_launch_needs_a_count_and_never_imports_jax():
+    """The launcher is the parent of the processes that own the chips: it must
+    not touch (or even import) jax, so with no hostfile and no --num_chips it
+    has nothing to count with and says so."""
+    code = textwrap.dedent("""\
+        import sys
+        from deepspeed_tpu.launcher import runner
+        try:
+            runner.main(["--hostfile", "/nonexistent/hostfile", "train.py"])
+        except SystemExit as e:
+            print("REFUSED:", e)
+        print("jax imported:", "jax" in sys.modules)
+        """)
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+    assert "REFUSED:" in r.stdout and "--num_chips" in r.stdout
+    assert "jax imported: False" in r.stdout
+
+
 TRAIN_SCRIPT = """
 import os
 os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=4"

@@ -1,0 +1,177 @@
+"""What the chip's COMPILER accepts, asked without a chip.
+
+The TPU compiler is installed in the sandbox and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``). Interpret-mode kernel
+tests cannot see what it refuses: a slice off the tiling, too much VMEM, a
+program that does not fit 16 GB, a Mosaic kernel the partitioner is asked to
+split. These compile the main paths' kernels and two whole serving programs at
+the shapes ``chip_smoke.py`` runs. Nothing executes, so nothing here is a result
+or a time — only "the compiler did not refuse".
+"""
+
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
+HBM_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four devices of a described v5e 2x2 host."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"the v5e topology cannot be described here: {e}")
+    return topo.devices
+
+
+@pytest.fixture(autouse=True)
+def compile_for_tpu(monkeypatch):
+    """The code under test asks ``jax.default_backend()`` to choose between the
+    Mosaic kernel and interpret mode; here it is compiled for the TPU. And a
+    compile for a described device must not go through the persistent cache
+    (it can be written but not read back without a chip)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def sizes():
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.pop(0)
+    return chip_smoke.ServeSizes(), chip_smoke.TrainSizes()
+
+
+def _on(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _device_bytes(compiled):
+    m = compiled.memory_analysis()
+    return m.argument_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes \
+        + m.temp_size_in_bytes
+
+
+# serve-phase geometry: Mixtral-8x7B attention, the engine's default 64-token blocks
+H, KVH, D, BS = 32, 8, 128, 64
+
+
+@pytest.mark.parametrize("tokens,max_blocks", [(8, 4), (32, 16)],
+                         ids=["decode-bucket", "largest-kernel-bucket"])
+def test_paged_attention_update_compiles(v5e, sizes, tokens, max_blocks):
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention_update
+    serve, _ = sizes
+    one = SingleDeviceSharding(v5e[0])
+    on = functools.partial(_on, one)
+
+    def step(q, k, v, cache, *meta):
+        return paged_attention_update(q, k, v, cache, 1, *meta)
+
+    compiled = jax.jit(step, donate_argnums=(3, )).lower(
+        on((tokens, H, D), jnp.bfloat16), on((tokens, KVH, D), jnp.bfloat16),
+        on((tokens, KVH, D), jnp.bfloat16),
+        on((serve.layers, 2, serve.kv_blocks, KVH, BS, D), jnp.bfloat16),
+        on((8, max_blocks), jnp.int32), on((tokens, ), jnp.int32), on((tokens, ), jnp.int32),
+        on((tokens, ), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_forward_and_backward_compile(v5e, sizes):
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    _, train = sizes
+    one = SingleDeviceSharding(v5e[0])
+    x = _on(one, (1, train.seq_len, 32, D), jnp.bfloat16)
+    lse = _on(one, (32, train.seq_len, 1), jnp.float32)
+
+    fwd = jax.jit(functools.partial(fa._flash_fwd_pallas, scale=D**-0.5, causal=True,
+                                    save_lse=True)).lower(x, x, x).compile()
+    assert "flash_attention_fwd" in fwd.as_text()
+    bwd = jax.jit(functools.partial(fa._flash_bwd_pallas, scale=D**-0.5, causal=True)) \
+        .lower(x, x, x, x, x, lse).compile().as_text()
+    assert "flash_attention_bwd_dkv" in bwd and "flash_attention_bwd_dq" in bwd
+
+
+def test_flash_under_a_data_parallel_mesh_compiles(v5e, sizes):
+    """The partitioner refuses to split a Mosaic kernel; the model's attention
+    must hand each device its own sequences (shard_map) for ZeRO-3 over
+    ``data=4`` to compile at all."""
+    from deepspeed_tpu.models.llama import flash_causal_attention
+    from deepspeed_tpu.utils import groups
+    _, train = sizes
+    mesh = groups.set_mesh(Mesh(np.array(v5e).reshape(1, 4, 1, 1, 1, 1), groups.MESH_AXES))
+    x = _on(NamedSharding(mesh, P(groups.DATA_AXIS)), (4, train.seq_len, 32, D), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_causal_attention(q, k, v, D**-0.5).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+
+
+@pytest.fixture(scope="module")
+def serve_model(sizes):
+    """The serve phase's model over ``jax.eval_shape``d parameters (6.3 GB of
+    bf16 weights that are never made)."""
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.model_implementations.registry import model_cls_for
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import DSStateManagerConfig
+    from deepspeed_tpu.models import mixtral
+    serve, _ = sizes
+    cfg = mixtral.MixtralConfig(num_hidden_layers=serve.layers)
+    abstract = jax.eval_shape(lambda: mixtral.init_params(cfg, param_dtype=cfg.dtype)[1])
+    engine_config = RaggedInferenceEngineConfig(
+        state_manager=DSStateManagerConfig(max_context=serve.max_context,
+                                           max_ragged_batch_size=serve.token_budget,
+                                           max_ragged_sequence_count=8), kv_block_size=BS)
+    return model_cls_for(cfg)(abstract, cfg, engine_config), abstract
+
+
+def _serve_args(device, sizes, abstract, bucket):
+    serve, _ = sizes
+    one = SingleDeviceSharding(device)
+    tokens, seqs, max_blocks = bucket
+    params = jax.tree.map(lambda leaf: _on(one, leaf.shape, leaf.dtype), abstract)
+    cache = _on(one, (serve.layers, 2, serve.kv_blocks, KVH, BS, D), jnp.bfloat16)
+    batch = {"tok_meta": _on(one, (4, tokens), jnp.int32),
+             "seq_meta": _on(one, (seqs, 4 + max_blocks), jnp.int32)}
+    return one, params, cache, batch
+
+
+@pytest.mark.parametrize("bucket,kernel", [((8, 8, 4), True), ((128, 8, 4), False)],
+                         ids=["decode-bucket", "prefill-bucket"])
+def test_serve_put_program_fits_one_chip(v5e, sizes, serve_model, bucket, kernel):
+    model, abstract = serve_model
+    _, params, cache, batch = _serve_args(v5e[0], sizes, abstract, bucket)
+    compiled = jax.jit(model._forward_impl, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == kernel  # what heuristics.py chose
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_serve_decode_loop_program_fits_one_chip(v5e, sizes, serve_model):
+    serve, _ = sizes
+    model, abstract = serve_model
+    one, params, cache, batch = _serve_args(v5e[0], sizes, abstract, (8, 8, 4))
+    loop = functools.partial(model._decode_loop_impl, n_steps=serve.decode_chunk, sampled=False)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(
+        params, cache, batch, _on(one, (), jnp.float32), _on(one, (2, ), jnp.uint32)).compile()
+    assert "paged_attention_update" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES

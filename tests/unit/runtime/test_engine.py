@@ -152,6 +152,9 @@ def test_bf16_runs_and_converges():
     for b in batches:
         losses.append(float(engine.train_batch(batch=b)))
     assert losses[-1] < losses[0]
+    # every input of the step is placed before the first call: a state leaf
+    # that comes back with another placement makes step 2 compile all over
+    assert engine.lowerable_callables()["train_batch"]._cache_size() == 1
 
 
 def test_fp16_dynamic_loss_scale_skips_on_overflow():

@@ -148,3 +148,20 @@ def test_user_supplied_optimizer_not_mutated_by_zero_marker():
     assert not isinstance(ret_opt, deepspeed_tpu.ZeROOptimizer)
     loss = float(eng.train_batch(batch=random_batches(1, 8, 16)[0]))
     assert np.isfinite(loss)
+
+
+def test_get_accelerator_raises_when_the_backend_cannot_be_reached(monkeypatch):
+    """No quiet "cpu" for a TPU that failed to start: the failure is the answer."""
+    import jax
+
+    from deepspeed_tpu.accelerator import real_accelerator
+
+    def unreachable():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(real_accelerator, "ds_accelerator", None)
+    monkeypatch.delenv("DS_ACCELERATOR", raising=False)
+    monkeypatch.setattr(jax, "default_backend", unreachable)
+    with pytest.raises(RuntimeError, match="Unable to initialize backend"):
+        real_accelerator.get_accelerator()
+    assert real_accelerator.ds_accelerator is None
