@@ -2,7 +2,8 @@
 
 Reference: ``deepspeed/inference/v2/config_v2.py`` (RaggedInferenceEngineConfig:29,
 DeepSpeedTPConfig:12, the fork's DeepSpeedEPConfig:18 with ``replica_num``, and the
-``simulated_gating``/``trace_enabled`` fork flags).
+``simulated_gating`` fork flag; in place of the fork's ``trace_enabled`` flag the
+models carry named scopes and telemetry spans land in a ``jax.profiler`` trace).
 """
 
 from typing import Optional
@@ -59,10 +60,6 @@ class RaggedInferenceEngineConfig(DeepSpeedConfigModel):
 
     simulated_gating: bool = False
     simulated_gating_temperature: float = 1.0
-    trace_enabled: bool = False
-    max_trace_batches: int = 1024
-    """Tracer ring-buffer capacity (batches); beyond it the oldest unconsumed
-    trace is dropped — drain via ``engine.tracer.drain_summaries()``."""
 
     telemetry: TelemetryConfig = TelemetryConfig()
     """Unified telemetry: batch/token/KV gauges, per-phase spans, and the

@@ -24,9 +24,9 @@ from deepspeed_tpu.inference.v2.ragged.ragged_manager import DSStateManager
 from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import RaggedBatchWrapper
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import PlaceholderSequenceDescriptor
 from deepspeed_tpu.inference.v2.scheduling_utils import SchedulingError, SchedulingResult
-from deepspeed_tpu.inference.v2.tracer import Tracer, get_tracer, set_tracer
 from deepspeed_tpu.telemetry import get_span_recorder as _tel_get_spans
 from deepspeed_tpu.telemetry import is_active as _tel_is_active
+from deepspeed_tpu.telemetry import live_span as _tel_live_span
 from deepspeed_tpu.telemetry import now_us as _tel_now_us
 from deepspeed_tpu.utils import groups
 from deepspeed_tpu.utils.logging import logger
@@ -75,14 +75,6 @@ class InferenceEngineV2:
         # the default, and always the case with telemetry off — costs one
         # attribute load per dispatch.
         self.dispatch_observer = None
-
-        if engine_config.trace_enabled:
-            self._tracer = Tracer(max_batches=engine_config.max_trace_batches,
-                                  span_recorder=self._telemetry.spans
-                                  if self._telemetry is not None else None)
-            set_tracer(self._tracer)
-        else:
-            self._tracer = None
 
     # ------------------------------------------------------------------ groups --
     def _initialize_comm_groups(self) -> None:
@@ -140,10 +132,6 @@ class InferenceEngineV2:
         return self._model
 
     @property
-    def tracer(self) -> Optional[Tracer]:
-        return self._tracer
-
-    @property
     def telemetry_session(self):
         return self._telemetry
 
@@ -159,73 +147,97 @@ class InferenceEngineV2:
 
     def close(self) -> None:
         """Tear the engine down (idempotent): stop an attached serving
-        scheduler, deregister this engine's tracer from the module-global slot
-        (so tracer state cannot leak into the next engine in this process),
-        and stop the telemetry endpoint / flush sinks."""
+        scheduler and stop the telemetry endpoint / flush sinks."""
         if self._serving_scheduler is not None:
             self._serving_scheduler.stop(drain=False)
             self._serving_scheduler = None
-        if self._tracer is not None and get_tracer() is self._tracer:
-            set_tracer(None)
         if self._telemetry is not None:
             self._telemetry.close()
             self._telemetry = None
 
     # ----------------------------------------------------------------- put() --
-    def put(self, batch_uids: Iterable[int], batch_tokens: Iterable, do_checks: bool = True):
-        """Run one ragged forward over ``batch_uids``/``batch_tokens``; returns
-        logits ``[len(batch_uids), vocab]`` — each sequence's final token only."""
-        batch_uids = list(batch_uids)
-        batch_tokens = [np.atleast_1d(np.asarray(t)) for t in batch_tokens]
+    # Spans (cat ``inference``; live only under a telemetry session, and then
+    # also ``dstpu.inference.*`` annotations in a jax.profiler trace):
+    # ``prepare`` is the host work from entry to just before the jitted call;
+    # ``put`` / ``decode_loop`` / ``verify`` / ``verify_tree`` time the DISPATCH
+    # of that call (plus, where the method itself fetches, the fetch) — not the
+    # device: JAX returns before the device finishes, and the caller's
+    # ``np.asarray`` is where the wait shows.
+    def _prepare_forward(self, spans, batch_uids, feeds, do_checks, n_tokens, trees=None):
+        """The host side of one ragged forward, under the ``prepare`` span:
+        admission check, restore of offloaded sequences, KV allocation and the
+        ragged batch. ``feeds``: each sequence's token array."""
+        args = None
+        if spans is not None:
+            free_before = self._state_manager.free_blocks
+            args = {"sequences": len(batch_uids), "tokens": n_tokens}
+        with _tel_live_span(spans, "prepare", "inference", args):
+            if do_checks:
+                # BEFORE restoring: can_schedule counts offloaded sequences'
+                # restore cost, so admission failure is a SchedulingError here,
+                # never a raw allocator error mid-restore
+                schedule_check = self.can_schedule(batch_uids, [t.size for t in feeds])
+                if schedule_check != SchedulingResult.Success:
+                    raise SchedulingError(schedule_check)
+            self._restore_offloaded(batch_uids)
 
-        if do_checks:
-            # BEFORE restoring: can_schedule counts offloaded sequences'
-            # restore cost, so admission failure is a SchedulingError here,
-            # never a raw allocator error mid-restore
-            schedule_check = self.can_schedule(batch_uids, [t.size for t in batch_tokens])
-            if schedule_check != SchedulingResult.Success:
-                raise SchedulingError(schedule_check)
-        self._restore_offloaded(batch_uids)
+            self._batch.clear()
+            for i, (uid, tokens) in enumerate(zip(batch_uids, feeds)):
+                seq_desc = self._state_manager.get_or_create_sequence(uid)
+                self._model.maybe_allocate_kv(seq_desc, tokens.size)
+                seq_desc.pre_forward(tokens.size)
+                if trees is None:
+                    self._batch.insert_sequence(seq_desc, tokens, do_checks=do_checks)
+                else:
+                    self._batch.insert_sequence(seq_desc, tokens, do_checks=do_checks,
+                                                tree=(trees[i].parents, trees[i].depths))
 
-        self._batch.clear()
-        if self._tracer:
-            self._tracer.init_batch(is_empty_run=False, num_layers=self._model.num_layers)
-        for uid, tokens in zip(batch_uids, batch_tokens):
-            seq_desc = self._state_manager.get_or_create_sequence(uid)
-            self._model.maybe_allocate_kv(seq_desc, tokens.size)
-            seq_desc.pre_forward(tokens.size)
-            self._batch.insert_sequence(seq_desc, tokens, do_checks=do_checks)
-            if self._tracer:
-                self._tracer.add_sequence(seq_desc)
+            self._batch.finalize()
+            self._model.prepare_batch(self._batch)
+            if args is not None:
+                args["allocated_blocks"] = free_before - self._state_manager.free_blocks
 
-        self._batch.finalize()
-        self._model.prepare_batch(self._batch)
-        spans = self._resolve_spans()
-        observer = self.dispatch_observer
-        if spans is not None or observer is not None:
-            _t0 = _tel_now_us()
-        logits = self._model.forward(self._batch)
-        if observer is not None:
-            observer("put", len(batch_uids),
-                     int(sum(t.size for t in batch_tokens)),
-                     (_tel_now_us() - _t0) / 1e6)
-        assert logits.shape[0] == self._batch.current_sequences
+    def _telemetry_sinks(self):
+        """``(spans, observer, metrics)``: all None with telemetry off and no
+        scheduler cost plane attached."""
+        return self._resolve_spans(), self.dispatch_observer, self._resolve_tel_metrics()
 
+    @staticmethod
+    def _dispatch_args(spans, batch_uids, **counts):
+        """A dispatch span's ``args`` (None while telemetry is off). The uids
+        link the batch span to the per-request serving traces: each uid's
+        request track carries the same uid in its args."""
+        if spans is None:
+            return None
+        return dict(counts, sequences=len(batch_uids), uids=[int(u) for u in batch_uids])
+
+    def _post_forward(self, batch_uids) -> None:
         for uid in batch_uids:
             seq_desc = self._state_manager.get_sequence(uid)
             seq_desc.post_forward()
             self._model.maybe_free_kv(seq_desc)
-        metrics = self._resolve_tel_metrics()
-        if spans is not None or metrics is not None:
-            n_tokens = int(sum(t.size for t in batch_tokens))
-        if spans is not None:
-            # uids link this batch span to the per-request serving traces
-            # (each uid's request track carries the same uid in its args)
-            spans.record("put", cat="inference", ts_us=_t0,
-                         dur_us=_tel_now_us() - _t0,
-                         args={"sequences": len(batch_uids),
-                               "tokens": n_tokens,
-                               "uids": [int(u) for u in batch_uids]})
+
+    def put(self, batch_uids: Iterable[int], batch_tokens: Iterable, do_checks: bool = True):
+        """Run one ragged forward over ``batch_uids``/``batch_tokens``; returns
+        logits ``[len(batch_uids), vocab]`` — each sequence's final token only.
+        The logits are a device array still being computed: the ``put`` span
+        is the dispatch, the caller's fetch is the wait."""
+        batch_uids = list(batch_uids)
+        batch_tokens = [np.atleast_1d(np.asarray(t)) for t in batch_tokens]
+        spans, observer, metrics = self._telemetry_sinks()
+        live = spans is not None or observer is not None or metrics is not None
+        n_tokens = int(sum(t.size for t in batch_tokens)) if live else 0
+
+        self._prepare_forward(spans, batch_uids, batch_tokens, do_checks, n_tokens)
+        args = self._dispatch_args(spans, batch_uids, tokens=n_tokens)
+        with _tel_live_span(spans, "put", "inference", args):
+            if observer is not None:
+                _t0 = _tel_now_us()
+            logits = self._model.forward(self._batch)
+            if observer is not None:
+                observer("put", len(batch_uids), n_tokens, (_tel_now_us() - _t0) / 1e6)
+            assert logits.shape[0] == self._batch.current_sequences
+            self._post_forward(batch_uids)
         if metrics is not None:
             self._write_telemetry(metrics, batch_tokens=n_tokens)
         return logits
@@ -321,53 +333,56 @@ class InferenceEngineV2:
             # host boundary, not [1+k, vocab] float32 logits
             return self.verify(batch_uids, batch_tokens, do_checks=do_checks,
                                greedy=True)
-        if do_checks:
-            # each SCAN STEP's ragged batch holds one token per sequence, so
-            # the token budget is checked against n_seqs — but the KV-block
-            # budget must cover all n_steps appended tokens per sequence
-            if len(batch_uids) > self._config.state_manager.max_ragged_sequence_count:
-                raise SchedulingError(SchedulingResult.BatchSequenceLimitExceeded)
-            if len(batch_uids) > self._config.state_manager.max_ragged_batch_size:
-                raise SchedulingError(SchedulingResult.BatchTokenLimitExceeded)
-            free_blocks = self._state_manager.free_blocks
-            for uid in batch_uids:
-                seq_desc = self._state_manager.get_sequence(uid)
-                if seq_desc is None:
-                    seq_desc = PlaceholderSequenceDescriptor()
-                restore = self._restore_cost(uid, seq_desc)
-                sched_len, sched_blocks = self._model.get_kv_requirements(
-                    seq_desc, n_steps, free_blocks - restore)
-                if sched_len != n_steps:
-                    raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
-                free_blocks -= sched_blocks + restore
-        self._restore_offloaded(batch_uids)
-
-        self._batch.clear()
-        for uid, tokens in zip(batch_uids, batch_tokens):
-            seq_desc = self._state_manager.get_or_create_sequence(uid)
-            # pre-allocate KV blocks for the WHOLE generation: the device loop
-            # cannot allocate mid-scan, and the block table is static inside it
-            self._model.maybe_allocate_kv(seq_desc, n_steps)
-            seq_desc.pre_forward(tokens.size)
-            self._batch.insert_sequence(seq_desc, tokens, do_checks=do_checks)
-
-        self._batch.finalize()
-        spans = self._resolve_spans()
-        observer = self.dispatch_observer
-        if spans is not None or observer is not None:
-            _t0 = _tel_now_us()
-        tokens = self._model.decode_loop(self._batch, n_steps, temperature=temperature,
-                                         rng=rng)  # [n_steps, S_bucket]
-        if observer is not None:
-            observer("decode_loop", len(batch_uids),
-                     len(batch_uids) * n_steps, (_tel_now_us() - _t0) / 1e6)
+        spans, observer, metrics = self._telemetry_sinks()
+        prep = None
         if spans is not None:
-            spans.record("decode_loop", cat="inference", ts_us=_t0,
-                         dur_us=_tel_now_us() - _t0,
-                         args={"sequences": len(batch_uids),
-                               "steps": n_steps,
-                               "uids": [int(u) for u in batch_uids]})
-        metrics = self._resolve_tel_metrics()
+            free_before = self._state_manager.free_blocks
+            prep = {"sequences": len(batch_uids), "tokens": len(batch_uids) * n_steps}
+        with _tel_live_span(spans, "prepare", "inference", prep):
+            if do_checks:
+                # each SCAN STEP's ragged batch holds one token per sequence, so
+                # the token budget is checked against n_seqs — but the KV-block
+                # budget must cover all n_steps appended tokens per sequence
+                if len(batch_uids) > self._config.state_manager.max_ragged_sequence_count:
+                    raise SchedulingError(SchedulingResult.BatchSequenceLimitExceeded)
+                if len(batch_uids) > self._config.state_manager.max_ragged_batch_size:
+                    raise SchedulingError(SchedulingResult.BatchTokenLimitExceeded)
+                free_blocks = self._state_manager.free_blocks
+                for uid in batch_uids:
+                    seq_desc = self._state_manager.get_sequence(uid)
+                    if seq_desc is None:
+                        seq_desc = PlaceholderSequenceDescriptor()
+                    restore = self._restore_cost(uid, seq_desc)
+                    sched_len, sched_blocks = self._model.get_kv_requirements(
+                        seq_desc, n_steps, free_blocks - restore)
+                    if sched_len != n_steps:
+                        raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
+                    free_blocks -= sched_blocks + restore
+            self._restore_offloaded(batch_uids)
+
+            self._batch.clear()
+            for uid, tokens in zip(batch_uids, batch_tokens):
+                seq_desc = self._state_manager.get_or_create_sequence(uid)
+                # pre-allocate KV blocks for the WHOLE generation: the device
+                # loop cannot allocate mid-scan, and the block table is static
+                # inside it
+                self._model.maybe_allocate_kv(seq_desc, n_steps)
+                seq_desc.pre_forward(tokens.size)
+                self._batch.insert_sequence(seq_desc, tokens, do_checks=do_checks)
+
+            self._batch.finalize()
+            if prep is not None:
+                prep["allocated_blocks"] = free_before - self._state_manager.free_blocks
+
+        args = self._dispatch_args(spans, batch_uids, steps=n_steps)
+        with _tel_live_span(spans, "decode_loop", "inference", args):
+            if observer is not None:
+                _t0 = _tel_now_us()
+            tokens = self._model.decode_loop(self._batch, n_steps, temperature=temperature,
+                                             rng=rng)  # [n_steps, S_bucket]
+            if observer is not None:
+                observer("decode_loop", len(batch_uids),
+                         len(batch_uids) * n_steps, (_tel_now_us() - _t0) / 1e6)
         if metrics is not None:
             self._write_telemetry(metrics, batch_tokens=len(batch_uids) * n_steps)
         for uid in batch_uids:
@@ -401,48 +416,18 @@ class InferenceEngineV2:
         write-then-truncate mechanism chunk-decode over-run relies on."""
         batch_uids = list(batch_uids)
         batch_tokens = [np.atleast_1d(np.asarray(t)) for t in batch_tokens]
-        if do_checks:
-            schedule_check = self.can_schedule(batch_uids, [t.size for t in batch_tokens])
-            if schedule_check != SchedulingResult.Success:
-                raise SchedulingError(schedule_check)
-        self._restore_offloaded(batch_uids)
-
-        self._batch.clear()
-        if self._tracer:
-            self._tracer.init_batch(is_empty_run=False, num_layers=self._model.num_layers)
-        for uid, tokens in zip(batch_uids, batch_tokens):
-            seq_desc = self._state_manager.get_or_create_sequence(uid)
-            self._model.maybe_allocate_kv(seq_desc, tokens.size)
-            seq_desc.pre_forward(tokens.size)
-            self._batch.insert_sequence(seq_desc, tokens, do_checks=do_checks)
-            if self._tracer:
-                self._tracer.add_sequence(seq_desc)
-
-        self._batch.finalize()
-        self._model.prepare_batch(self._batch)
-        spans = self._resolve_spans()
-        observer = self.dispatch_observer
-        if spans is not None or observer is not None:
-            _t0 = _tel_now_us()
-        # [T, vocab] logits, or [T] argmax ids when greedy
-        rows = np.asarray(self._model.forward_verify(self._batch, greedy=greedy))
-        if observer is not None:
-            observer("verify", len(batch_uids),
-                     int(sum(t.size for t in batch_tokens)),
-                     (_tel_now_us() - _t0) / 1e6)
-
-        for uid in batch_uids:
-            seq_desc = self._state_manager.get_sequence(uid)
-            seq_desc.post_forward()
-            self._model.maybe_free_kv(seq_desc)
+        spans, observer, metrics = self._telemetry_sinks()
         n_tokens = int(sum(t.size for t in batch_tokens))
-        if spans is not None:
-            spans.record("verify", cat="inference", ts_us=_t0,
-                         dur_us=_tel_now_us() - _t0,
-                         args={"sequences": len(batch_uids),
-                               "tokens": n_tokens,
-                               "uids": [int(u) for u in batch_uids]})
-        metrics = self._resolve_tel_metrics()
+        self._prepare_forward(spans, batch_uids, batch_tokens, do_checks, n_tokens)
+        args = self._dispatch_args(spans, batch_uids, tokens=n_tokens)
+        with _tel_live_span(spans, "verify", "inference", args):
+            if observer is not None:
+                _t0 = _tel_now_us()
+            # [T, vocab] logits, or [T] argmax ids when greedy
+            rows = np.asarray(self._model.forward_verify(self._batch, greedy=greedy))
+            if observer is not None:
+                observer("verify", len(batch_uids), n_tokens, (_tel_now_us() - _t0) / 1e6)
+            self._post_forward(batch_uids)
         if metrics is not None:
             self._write_telemetry(metrics, batch_tokens=n_tokens)
         # insertion order is batch order: each sequence's positions are one
@@ -473,49 +458,20 @@ class InferenceEngineV2:
         :meth:`compact_accepted`."""
         batch_uids = list(batch_uids)
         trees = list(trees)
-        if do_checks:
-            schedule_check = self.can_schedule(batch_uids, [t.size for t in trees])
-            if schedule_check != SchedulingResult.Success:
-                raise SchedulingError(schedule_check)
-        self._restore_offloaded(batch_uids)
-
-        self._batch.clear()
-        if self._tracer:
-            self._tracer.init_batch(is_empty_run=False, num_layers=self._model.num_layers)
-        for uid, tree in zip(batch_uids, trees):
-            seq_desc = self._state_manager.get_or_create_sequence(uid)
-            self._model.maybe_allocate_kv(seq_desc, tree.size)
-            seq_desc.pre_forward(tree.size)
-            self._batch.insert_sequence(seq_desc, tree.tokens, do_checks=do_checks,
-                                        tree=(tree.parents, tree.depths))
-            if self._tracer:
-                self._tracer.add_sequence(seq_desc)
-
-        self._batch.finalize()
-        self._model.prepare_batch(self._batch)
-        spans = self._resolve_spans()
-        observer = self.dispatch_observer
-        if spans is not None or observer is not None:
-            _t0 = _tel_now_us()
-        rows, hidden = self._model.forward_verify_tree(self._batch, greedy=greedy)
-        rows, hidden = np.asarray(rows), np.asarray(hidden)
-        if observer is not None:
-            observer("verify_tree", len(batch_uids),
-                     int(sum(t.size for t in trees)),
-                     (_tel_now_us() - _t0) / 1e6)
-
-        for uid in batch_uids:
-            seq_desc = self._state_manager.get_sequence(uid)
-            seq_desc.post_forward()
-            self._model.maybe_free_kv(seq_desc)
+        spans, observer, metrics = self._telemetry_sinks()
         n_tokens = int(sum(t.size for t in trees))
-        if spans is not None:
-            spans.record("verify_tree", cat="inference", ts_us=_t0,
-                         dur_us=_tel_now_us() - _t0,
-                         args={"sequences": len(batch_uids),
-                               "tokens": n_tokens,
-                               "uids": [int(u) for u in batch_uids]})
-        metrics = self._resolve_tel_metrics()
+        self._prepare_forward(spans, batch_uids, [t.tokens for t in trees], do_checks,
+                              n_tokens, trees=trees)
+        args = self._dispatch_args(spans, batch_uids, tokens=n_tokens)
+        with _tel_live_span(spans, "verify_tree", "inference", args):
+            if observer is not None:
+                _t0 = _tel_now_us()
+            rows, hidden = self._model.forward_verify_tree(self._batch, greedy=greedy)
+            rows, hidden = np.asarray(rows), np.asarray(hidden)
+            if observer is not None:
+                observer("verify_tree", len(batch_uids), n_tokens,
+                         (_tel_now_us() - _t0) / 1e6)
+            self._post_forward(batch_uids)
         if metrics is not None:
             self._write_telemetry(metrics, batch_tokens=n_tokens)
         out, offset = [], 0
@@ -715,8 +671,6 @@ class InferenceEngineV2:
     def empty_run(self) -> None:
         """Participate in EP collectives with zero live tokens (fork
         engine_v2.py:308) — keeps idle replicas in lock-step with busy ones."""
-        if self._tracer:
-            self._tracer.init_batch(is_empty_run=True, num_layers=self._model.num_layers)
         metrics = self._resolve_tel_metrics()
         if metrics is not None:
             metrics["empty_runs"].inc()

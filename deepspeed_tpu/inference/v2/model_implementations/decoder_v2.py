@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from deepspeed_tpu.inference.v2.model_implementations.llama_v2 import _root, rotary_embedding
 from deepspeed_tpu.inference.v2.model_implementations.transformer_base import \
     DSTransformerModelBase
-from deepspeed_tpu.inference.v2.tracer import record
 from deepspeed_tpu.models.decoder import DecoderConfig, _act
 
 
@@ -90,11 +89,13 @@ class DecoderV2Model(DSTransformerModelBase):
         return self._config.vocab_size
 
     # --------------------------------------------------------------- phases --
+    @jax.named_scope("embed")
     def embed(self, params, ids):
         r = _root(params)
         x = r["embed_tokens"]["embedding"][ids].astype(self._config.dtype)
         return x
 
+    @jax.named_scope("embed")
     def _add_positions(self, params, x, batch):
         cfg = self._config
         if cfg.pos_embed != "learned":
@@ -103,6 +104,7 @@ class DecoderV2Model(DSTransformerModelBase):
         pos = batch["token_pos"] + cfg.learned_pos_offset
         return x + wpe[pos].astype(x.dtype)
 
+    @jax.named_scope("unembed")
     def unembed(self, params, x):
         r = _root(params)
         x = _ln(x, r["final_layer_norm"], self._config.layer_norm_eps)
@@ -138,20 +140,19 @@ class DecoderV2Model(DSTransformerModelBase):
         lp = _root(params)[f"layers_{li}"]
         if li == 0:
             x = self._add_positions(params, x, batch)
+        # the norms and residual adds take the scope of the phase they feed
         if cfg.parallel_residual:
+            with jax.named_scope("attn"):
+                h = _ln(x, lp["input_layernorm"], cfg.layer_norm_eps)
+                attn_out, cache = self._attn(params, li, h, cache, attn_fn, batch)
+            with jax.named_scope("mlp"):
+                hm = _ln(x, lp["post_attention_layernorm"], cfg.layer_norm_eps) \
+                    if cfg.parallel_mlp_norm else h
+                return x + attn_out + self._mlp(params, li, hm), cache
+        with jax.named_scope("attn"):
             h = _ln(x, lp["input_layernorm"], cfg.layer_norm_eps)
-            hm = _ln(x, lp["post_attention_layernorm"], cfg.layer_norm_eps) \
-                if cfg.parallel_mlp_norm else h
             attn_out, cache = self._attn(params, li, h, cache, attn_fn, batch)
-            return x + attn_out + self._mlp(params, li, hm), cache
-        h = _ln(x, lp["input_layernorm"], cfg.layer_norm_eps)
-        attn_out, cache = self._attn(params, li, h, cache, attn_fn, batch)
-        x = x + attn_out
-        h = _ln(x, lp["post_attention_layernorm"], cfg.layer_norm_eps)
-        return x + self._mlp(params, li, h), cache
-
-    def layer_forward_traced(self, params, li, x, cache, attn_fn, batch):
-        with record("layer"):
-            x, cache = self.layer_forward(params, li, x, cache, attn_fn, batch)
-            x.block_until_ready()
-        return x, cache
+            x = x + attn_out
+        with jax.named_scope("mlp"):
+            h = _ln(x, lp["post_attention_layernorm"], cfg.layer_norm_eps)
+            return x + self._mlp(params, li, h), cache

@@ -9,6 +9,10 @@ verbatim (``{"model": {embed_tokens, layers_i{self_attn,mlp,*layernorm}, norm},
 lm_head}``) so inference logits are testable bit-for-bit against the training
 forward — the reference needs a LayerContainer mapping step instead
 (``layer_container_base.py:164``); a functional pytree makes it a no-op.
+
+Every phase runs under a ``jax.named_scope`` (``embed``, ``attn``, ``mlp``,
+``unembed``; no layer index, so a kind of operation is one row whatever its
+layer): metadata only, carried into the device trace with each operation.
 """
 
 from typing import Optional
@@ -17,7 +21,6 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.v2.model_implementations.transformer_base import DSTransformerModelBase
-from deepspeed_tpu.inference.v2.tracer import record
 from deepspeed_tpu.models.llama import LlamaConfig, rotary_embedding
 
 
@@ -70,15 +73,18 @@ class LlamaV2Model(DSTransformerModelBase):
         return self._config.vocab_size
 
     # --------------------------------------------------------------- phases --
+    @jax.named_scope("embed")
     def embed(self, params, ids):
         emb = _root(params)["embed_tokens"]["embedding"]
         return emb[ids].astype(self._config.dtype)
 
+    @jax.named_scope("unembed")
     def unembed(self, params, x):
         r = _root(params)
         x = _rms(x, r["norm"]["weight"], self._config.rms_norm_eps)
         return x @ r["lm_head"]["kernel"].astype(x.dtype)
 
+    @jax.named_scope("attn")
     def _attn_phase(self, params, li, x, cache, attn_fn, batch):
         cfg = self._config
         lp = _root(params)[f"layers_{li}"]
@@ -102,6 +108,7 @@ class LlamaV2Model(DSTransformerModelBase):
         out = out.reshape(x.shape[0], H * D)
         return x + out @ ap["o_proj"]["kernel"].astype(h.dtype), cache
 
+    @jax.named_scope("mlp")
     def _ffn_phase(self, params, li, x):
         cfg = self._config
         lp = _root(params)[f"layers_{li}"]
@@ -114,15 +121,6 @@ class LlamaV2Model(DSTransformerModelBase):
     def layer_forward(self, params, li, x, cache, attn_fn, batch):
         x, cache = self._attn_phase(params, li, x, cache, attn_fn, batch)
         return self._ffn_phase(params, li, x), cache
-
-    def layer_forward_traced(self, params, li, x, cache, attn_fn, batch):
-        with record("attn"):
-            x, cache = self._attn_phase(params, li, x, cache, attn_fn, batch)
-            x.block_until_ready()
-        with record("ffn"):
-            x = self._ffn_phase(params, li, x)
-            x.block_until_ready()
-        return x, cache
 
     @property
     def attention_window(self):

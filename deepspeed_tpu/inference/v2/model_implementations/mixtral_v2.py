@@ -13,7 +13,6 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.inference.v2.model_implementations.llama_v2 import LlamaV2Model, _rms, _root
 from deepspeed_tpu.inference.v2.modules.moe import RaggedMoE
-from deepspeed_tpu.inference.v2.tracer import record
 from deepspeed_tpu.models.mixtral import MixtralConfig
 
 
@@ -38,6 +37,7 @@ class MixtralV2Model(LlamaV2Model):
         mp = _root(params)[f"layers_{li}"]["block_sparse_moe"]
         return mp["gate"], mp["ExpertFFN_0"]["wi"], mp["ExpertFFN_0"]["wo"]
 
+    @jax.named_scope("moe")
     def _ffn_phase(self, params, li, x, batch=None):
         cfg = self._moe_config
         lp = _root(params)[f"layers_{li}"]
@@ -56,12 +56,3 @@ class MixtralV2Model(LlamaV2Model):
     def layer_forward(self, params, li, x, cache, attn_fn, batch):
         x, cache = self._attn_phase(params, li, x, cache, attn_fn, batch)
         return self._ffn_phase(params, li, x, batch=batch), cache
-
-    def layer_forward_traced(self, params, li, x, cache, attn_fn, batch):
-        with record("attn"):
-            x, cache = self._attn_phase(params, li, x, cache, attn_fn, batch)
-            x.block_until_ready()
-        with record("moe_ffn"):
-            x = self._ffn_phase(params, li, x, batch=batch)
-            x.block_until_ready()
-        return x, cache

@@ -21,7 +21,6 @@ import numpy as np
 
 from deepspeed_tpu.inference.v2.ragged.manager_configs import KVCacheConfig
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import DSSequenceDescriptor
-from deepspeed_tpu.inference.v2.tracer import get_tracer, record
 from deepspeed_tpu.telemetry import compile_watch
 
 
@@ -159,13 +158,9 @@ class DSTransformerModelBase:
                   batch["seq_meta"].shape[1] - 4)
         fn = self._get_compiled(bucket)
         cache = self._state_manager.kv_cache.cache
-        tracer = get_tracer()
         n = int(batch["n_seqs"])
         dev = {"tok_meta": batch["tok_meta"], "seq_meta": batch["seq_meta"]}
-        if tracer is not None:
-            logits, new_cache = self._traced_forward(dev, cache, n)
-        else:
-            logits, new_cache = fn(self._params, cache, dev)
+        logits, new_cache = fn(self._params, cache, dev)
         self._state_manager.kv_cache.set_cache(new_cache)
         return logits[:n] if n else logits[:0]
 
@@ -177,10 +172,6 @@ class DSTransformerModelBase:
                                      block_size=self._engine_config.kv_block_size)
         batch = wrapper.finalize()  # zero live sequences/tokens
         dev = {"tok_meta": batch["tok_meta"], "seq_meta": batch["seq_meta"]}
-        tracer = get_tracer()
-        if tracer is not None:
-            self._traced_forward(dev, self._state_manager.kv_cache.cache, 0)
-            return
         fn = self._get_compiled((batch["tok_meta"].shape[1], batch["seq_meta"].shape[0],
                                  batch["seq_meta"].shape[1] - 4))
         _, new_cache = fn(self._params, self._state_manager.kv_cache.cache, dev)
@@ -527,38 +518,6 @@ class DSTransformerModelBase:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), hidden, cache
         return logits, hidden, cache
 
-    def _traced_forward(self, batch, cache, n):
-        """Phase-timed execution for the tracer: embed / per-layer phases /
-        unembed run as separate device computations so host timers see real
-        boundaries (slower than the fused program — tracing mode trades speed
-        for observability; the reference pays CUDA-event overhead instead)."""
-        import jax
-        import jax.numpy as jnp
-        from deepspeed_tpu.inference.v2.quantization import dequantize_tree
-
-        # one cached jit; with quantization on, tracing mode holds a full-
-        # precision weight copy for the duration of the phase-split forward
-        # (observability mode trades memory+speed for timers, as documented)
-        if not hasattr(self, "_dequant_fn"):
-            self._dequant_fn = jax.jit(dequantize_tree)
-        params = self._dequant_fn(self._params)
-        batch_j = self._unpack_batch({k: jnp.asarray(v) for k, v in batch.items()})
-        with record("embed"):
-            x = jax.jit(self.embed)(params, batch_j["input_ids"])
-            x.block_until_ready()
-        attn = partial(self._paged_attention, batch=batch_j)
-        for li in range(self.num_layers):
-            x, cache = self.layer_forward_traced(params, li, x, cache, attn, batch_j)
-        with record("unembed"):
-            logits = jax.jit(self.unembed)(params, x[batch_j["last_tok"]])
-            logits = logits.astype(jnp.float32)
-            logits.block_until_ready()
-        self._state_manager.kv_cache.set_cache(cache)
-        return logits[:n], cache
-
-    def layer_forward_traced(self, params, li, x, cache, attn_fn, batch):
-        raise NotImplementedError("tracing requires a model with phase-split layers")
-
     # -------------------------------------------------------- paged attention --
     @property
     def attention_window(self) -> int:
@@ -581,19 +540,14 @@ class DSTransformerModelBase:
         q: [T, H, D]; k_new/v_new: [T, KVH, D];
         cache: [L, 2, num_blocks, KVH, bs, D]."""
         import jax
-        import jax.numpy as jnp
-
-        T = q.shape[0]
-        S, MB = batch["block_table"].shape
-        bs = cache.shape[4]
-        H, D = self.num_heads, self.head_dim
-        KVH = self.num_kv_heads
 
         token_seq = batch["token_seq"]
         token_pos = batch["token_pos"]
         token_valid = batch["token_valid"]
 
-        if self._use_paged_kernel(T):
+        # scopes (under the caller's ``attn``): ``paged_kernel`` / ``kv_write``
+        # + ``gather`` name the arm a device operation belongs to in the trace
+        if self._use_paged_kernel(q.shape[0]):
             # fused KV-insert + blocked attention; the cache is aliased through
             # the kernel (an XLA-side scatter would copy it at the boundary)
             from jax.sharding import PartitionSpec as P
@@ -606,27 +560,55 @@ class DSTransformerModelBase:
             args = (q, k_new, v_new, cache, batch["block_table"], token_seq, token_pos,
                     token_valid)
             placed = None if self._state_manager is None else self._state_manager.kv_cache.sharding
-            if placed is None or placed.mesh.size == 1:
-                return kernel(*args)
-            # the SPMD partitioner cannot split a Mosaic kernel: on a mesh each
-            # device runs it over what the cache's placement gives it — its KV
-            # heads under tensor parallelism, everything under expert parallelism
-            heads = P(None, placed.spec[3], None)
-            return jax.shard_map(kernel, mesh=placed.mesh,
-                                 in_specs=(heads, heads, heads, placed.spec, P(), P(), P(), P()),
-                                 out_specs=(heads, placed.spec), check_vma=False)(*args)
+            with jax.named_scope("paged_kernel"):
+                if placed is None or placed.mesh.size == 1:
+                    return kernel(*args)
+                # the SPMD partitioner cannot split a Mosaic kernel: on a mesh
+                # each device runs it over what the cache's placement gives it —
+                # its KV heads under tensor parallelism, everything under expert
+                # parallelism
+                heads = P(None, placed.spec[3], None)
+                return jax.shard_map(kernel, mesh=placed.mesh,
+                                     in_specs=(heads, heads, heads, placed.spec,
+                                               P(), P(), P(), P()),
+                                     out_specs=(heads, placed.spec), check_vma=False)(*args)
 
-        # --- scatter new kv ---------------------------------------------------
-        NB = cache.shape[2]
-        blk_idx = token_pos // bs
-        blk_ids = batch["block_table"][token_seq, jnp.minimum(blk_idx, MB - 1)]
-        # padding tokens and unallocated (-1) table slots route to NB — a
-        # POSITIVE out-of-bounds index: scatter mode="drop" discards those
-        # writes, whereas -1 would WRAP to block NB-1 and corrupt it
-        blk_ids = jnp.where(token_valid & (blk_ids >= 0), blk_ids, NB)
-        offs = token_pos % bs
-        cache = cache.at[li, 0, blk_ids, :, offs].set(k_new.astype(cache.dtype), mode="drop")
-        cache = cache.at[li, 1, blk_ids, :, offs].set(v_new.astype(cache.dtype), mode="drop")
+        cache = self._kv_write(cache, li, k_new, v_new, token_pos, batch)
+        with jax.named_scope("gather"):
+            return self._gather_attention(q, cache, li, batch), cache
+
+    @staticmethod
+    def _kv_write(cache, li, k_new, v_new, slot_pos, batch):
+        """Scatter the new K/V into layer ``li``'s blocks at ``slot_pos``."""
+        import jax
+        import jax.numpy as jnp
+
+        with jax.named_scope("kv_write"):
+            MB = batch["block_table"].shape[1]
+            NB, bs = cache.shape[2], cache.shape[4]
+            blk_idx = slot_pos // bs
+            blk_ids = batch["block_table"][batch["token_seq"], jnp.minimum(blk_idx, MB - 1)]
+            # padding tokens and unallocated (-1) table slots route to NB — a
+            # POSITIVE out-of-bounds index: scatter mode="drop" discards those
+            # writes, whereas -1 would WRAP to block NB-1 and corrupt it
+            blk_ids = jnp.where(batch["token_valid"] & (blk_ids >= 0), blk_ids, NB)
+            offs = slot_pos % bs
+            cache = cache.at[li, 0, blk_ids, :, offs].set(k_new.astype(cache.dtype), mode="drop")
+            return cache.at[li, 1, blk_ids, :, offs].set(v_new.astype(cache.dtype), mode="drop")
+
+    def _gather_attention(self, q, cache, li, batch):
+        """The XLA arm: gather each sequence's history from the block table and
+        attend densely. q: [T, H, D]; returns [T, H, D]."""
+        import jax
+        import jax.numpy as jnp
+
+        S, MB = batch["block_table"].shape
+        bs = cache.shape[4]
+        H, D = self.num_heads, self.head_dim
+        KVH = self.num_kv_heads
+        token_seq = batch["token_seq"]
+        token_pos = batch["token_pos"]
+        token_valid = batch["token_valid"]
 
         # --- gather per-sequence history (XLA fallback) ----------------------
         table = jnp.maximum(batch["block_table"], 0)  # [S, MB]
@@ -664,8 +646,7 @@ class DSTransformerModelBase:
 
         # --- back to token-major ---------------------------------------------
         out = out_dense[token_seq, jnp.minimum(local_q, Qm - 1)]  # [T, H, D]
-        out = jnp.where(token_valid[:, None, None], out, 0.0)
-        return out, cache
+        return jnp.where(token_valid[:, None, None], out, 0.0)
 
     def _tree_paged_attention(self, q, k_new, v_new, cache, li, *, batch,
                               slot_pos, parents, depths):
@@ -702,13 +683,7 @@ class DSTransformerModelBase:
         token_valid = batch["token_valid"]
 
         # --- scatter new kv at slot positions --------------------------------
-        NB = cache.shape[2]
-        blk_idx = slot_pos // bs
-        blk_ids = batch["block_table"][token_seq, jnp.minimum(blk_idx, MB - 1)]
-        blk_ids = jnp.where(token_valid & (blk_ids >= 0), blk_ids, NB)
-        offs = slot_pos % bs
-        cache = cache.at[li, 0, blk_ids, :, offs].set(k_new.astype(cache.dtype), mode="drop")
-        cache = cache.at[li, 1, blk_ids, :, offs].set(v_new.astype(cache.dtype), mode="drop")
+        cache = self._kv_write(cache, li, k_new, v_new, slot_pos, batch)
 
         # --- gather per-sequence history -------------------------------------
         table = jnp.maximum(batch["block_table"], 0)  # [S, MB]

@@ -27,6 +27,13 @@ dependency), sharpened/flattened by ``softmax(log(p)/temperature)``. The draw is
 seeded per (layer, batch, replica): callers thread a data-dependent ``gate_seed``
 (the model passes the sum of live token positions, so successive decode steps
 route differently) and the EP body folds in the replica index.
+
+Named scopes (``jax.named_scope``, metadata only): ``route`` (router
+probabilities + capacity packing), ``dispatch`` (tokens into the expert-major
+buffer), ``experts`` (the grouped GEMMs), ``combine`` (back to token-major with
+the routing weights) and, under expert parallelism, ``a2a`` (the two
+all-to-alls). They are relative: the caller's ``moe`` scope (mixtral_v2's FFN
+phase) makes them ``moe/route`` ... in the device trace.
 """
 
 from typing import Optional
@@ -178,23 +185,28 @@ class RaggedMoE:
         """Single-replica path: all tokens local, no explicit collectives. When a
         degenerate EP mesh is passed (experts not divisible), the expert buffers
         are still constraint-sharded so GSPMD partitions the grouped GEMM."""
+        import jax
         import jax.numpy as jnp
         from deepspeed_tpu.sequence.layer import _constrain
 
         T, M = h.shape
         E = self.num_experts
         C = max(4, int(np.ceil(T * self.top_k / E * self.capacity_factor)))
-        probs = self._router_probs(h, gate_w, gate_seed=gate_seed)  # [T, E]
-        if token_valid is not None:
-            probs = probs * token_valid[:, None]
-        combine, dispatch = self._pack(probs, token_valid, C, h.dtype)
-        buf = jnp.einsum("tec,tm->ecm", dispatch, h)  # [E, C, M]
-        if mesh is not None:
-            buf = _constrain(buf, (self.expert_axis, None, None), mesh)
-        out = self._expert_ffn(buf, wi, wo, activation)
-        if mesh is not None:
-            out = _constrain(out, (self.expert_axis, None, None), mesh)
-        return jnp.einsum("tec,ecm->tm", combine.astype(h.dtype), out)
+        with jax.named_scope("route"):
+            probs = self._router_probs(h, gate_w, gate_seed=gate_seed)  # [T, E]
+            if token_valid is not None:
+                probs = probs * token_valid[:, None]
+            combine, dispatch = self._pack(probs, token_valid, C, h.dtype)
+        with jax.named_scope("dispatch"):
+            buf = jnp.einsum("tec,tm->ecm", dispatch, h)  # [E, C, M]
+            if mesh is not None:
+                buf = _constrain(buf, (self.expert_axis, None, None), mesh)
+        with jax.named_scope("experts"):
+            out = self._expert_ffn(buf, wi, wo, activation)
+            if mesh is not None:
+                out = _constrain(out, (self.expert_axis, None, None), mesh)
+        with jax.named_scope("combine"):
+            return jnp.einsum("tec,ecm->tm", combine.astype(h.dtype), out)
 
     def _ep_forward(self, h, gate_w, wi, wo, token_valid, activation, mesh, ep, gate_seed):
         """Disaggregated EP: each replica owns T/ep tokens and its E/ep experts.
@@ -227,19 +239,25 @@ class RaggedMoE:
         seed = jnp.asarray(0 if gate_seed is None else gate_seed, jnp.int32)
 
         def body(h_l, gate_w, wi_l, wo_l, tv_l, seed_l):
-            replica = jax.lax.axis_index(ax)
-            probs = self._router_probs(h_l, gate_w, gate_seed=seed_l, replica=replica)
-            probs = probs * tv_l[:, None]
-            combine, dispatch = self._pack(probs, tv_l, C, h_l.dtype)
-            buf = jnp.einsum("tec,tm->ecm", dispatch, h_l)       # [E, C, M]
-            buf = buf.reshape(ep, El, C, M)                      # dest-replica major
-            buf = jax.lax.all_to_all(buf, ax, 0, 0, tiled=True)  # a2a #1: dispatch
-            merged = buf.transpose(1, 0, 2, 3).reshape(El, ep * C, M)
-            out = self._expert_ffn(merged, wi_l, wo_l, activation)
-            out = out.reshape(El, ep, C, M).transpose(1, 0, 2, 3)
-            ret = jax.lax.all_to_all(out, ax, 0, 0, tiled=True)  # a2a #2: return
-            ret = ret.reshape(E, C, M)                           # global-expert major
-            return jnp.einsum("tec,ecm->tm", combine.astype(h_l.dtype), ret)
+            with jax.named_scope("route"):
+                replica = jax.lax.axis_index(ax)
+                probs = self._router_probs(h_l, gate_w, gate_seed=seed_l, replica=replica)
+                probs = probs * tv_l[:, None]
+                combine, dispatch = self._pack(probs, tv_l, C, h_l.dtype)
+            with jax.named_scope("dispatch"):
+                buf = jnp.einsum("tec,tm->ecm", dispatch, h_l)       # [E, C, M]
+                buf = buf.reshape(ep, El, C, M)                      # dest-replica major
+            with jax.named_scope("a2a"):
+                buf = jax.lax.all_to_all(buf, ax, 0, 0, tiled=True)  # a2a #1: dispatch
+            with jax.named_scope("experts"):
+                merged = buf.transpose(1, 0, 2, 3).reshape(El, ep * C, M)
+                out = self._expert_ffn(merged, wi_l, wo_l, activation)
+                out = out.reshape(El, ep, C, M).transpose(1, 0, 2, 3)
+            with jax.named_scope("a2a"):
+                ret = jax.lax.all_to_all(out, ax, 0, 0, tiled=True)  # a2a #2: return
+            with jax.named_scope("combine"):
+                ret = ret.reshape(E, C, M)                           # global-expert major
+                return jnp.einsum("tec,ecm->tm", combine.astype(h_l.dtype), ret)
 
         shmap = jax.shard_map(body, mesh=mesh,
                               in_specs=(P(ax), P(), P(ax), P(ax), P(ax), P()),

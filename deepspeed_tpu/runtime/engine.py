@@ -802,6 +802,10 @@ class DeepSpeedEngine:
         gas = self._apply_gas_divisor if self._apply_gas_divisor is not None \
             else float(self.gradient_accumulation_steps())
 
+        # everything from the accumulated gradients to the new parameters runs
+        # under the ``optimizer`` scope: its share of the device's busy time is
+        # read from the trace by that name
+        @jax.named_scope("optimizer")
         def fn(params, opt_state, acc_grads, scale_state, lr):
             inv = (1.0 / (scale_state.cur_scale * gas))
             grads = jax.tree.map(lambda g: g.astype(jnp.float32) * inv, acc_grads)

@@ -31,6 +31,7 @@ import itertools
 import threading
 import time
 from collections import deque
+from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -43,7 +44,7 @@ from deepspeed_tpu.serving.overload import (BrownoutController, FairSharePolicy,
                                             RateEstimator, priority_rank,
                                             validate_priority)
 from deepspeed_tpu.serving.request import Request, RequestState
-from deepspeed_tpu.telemetry import new_span_id, new_trace_id, now_us
+from deepspeed_tpu.telemetry import NULL_SPAN, live_span, new_span_id, new_trace_id, now_us
 from deepspeed_tpu.telemetry.flight_recorder import SERVING_SCHEDULER_CHANNEL
 from deepspeed_tpu.utils.logging import logger
 
@@ -94,6 +95,41 @@ class AdmissionRejected(RuntimeError):
         self.retry_after_s = retry_after_s
 
 
+# consecutive idle polls one ``no_work`` span covers at most: an idle server
+# writes ~50 spans a second into the ring, not one per millisecond poll, and a
+# profiler slice that starts inside a long idle stretch still sees the next one
+_NO_WORK_SPAN_POLLS = 20
+
+
+class _IdleSpan:
+    """The scheduler loop's ``no_work`` span (cat ``sched``): open from the
+    first poll that found nothing to run until work arrives (or
+    ``_NO_WORK_SPAN_POLLS`` polls passed), covering the heartbeats and
+    ``time.sleep(scheduler_tick_s)`` between. Nothing while telemetry is off."""
+
+    def __init__(self):
+        self._ctx = None
+        self.polls = 0
+
+    @property
+    def open(self) -> bool:
+        return self._ctx is not None
+
+    def poll(self, spans) -> None:
+        if self._ctx is None:
+            if spans is None:
+                return
+            self._ctx = spans.span("no_work", "sched")
+            self._ctx.__enter__()
+            self.polls = 0
+        self.polls += 1
+
+    def close(self) -> None:
+        if self._ctx is not None:
+            ctx, self._ctx = self._ctx, None
+            ctx.__exit__(None, None, None)
+
+
 class ServingScheduler:
     """Owns the request lifecycle end-to-end over one :class:`InferenceEngineV2`.
 
@@ -123,6 +159,13 @@ class ServingScheduler:
         # active, but drain and load accounting must still see it
         self._admitting: Optional[Request] = None
         self._uids = itertools.count()
+        # tick-phase spans (cat ``sched``; see step()): the live recorder of the
+        # tick in progress, its ``tick`` / ``emit`` args, and the tick's number.
+        # All None while telemetry is off.
+        self._tick_spans = None
+        self._tick = None
+        self._emit = None
+        self._tick_seq = 0
         self._counters = {k: 0 for k in
                           ("submitted", "rejected", "completed", "cancelled",
                            "timed_out", "failed", "evictions", "batches", "heartbeats",
@@ -846,21 +889,59 @@ class ServingScheduler:
     # ------------------------------------------------------------------ tick --
     def step(self) -> bool:
         """One scheduling iteration; returns True iff a batch executed.
-        Runs on the scheduler thread — or inline when ``start=False``."""
-        self._drain_control()
-        now = time.monotonic()
-        for req in list(self._active.values()):
-            # the deadline check doubles as the decode feed-stop: a request
-            # past its deadline is finalized HERE, before batch building, so
-            # it never receives another decode step
-            if req.cancel_requested:
-                self._finalize(req, RequestState.CANCELLED)
-            elif req.deadline is not None and now > req.deadline:
-                self._finalize(req, RequestState.TIMED_OUT)
-        if self._config.overload.enabled:
-            self._overload_tick(now)
-        self._admit(now)
-        plan = self._build_batch()
+        Runs on the scheduler thread — or inline when ``start=False``.
+
+        With telemetry on, a tick that has work is one ``tick`` span (cat
+        ``sched``) holding its phases in order: ``admit``, ``build_batch``,
+        then in :meth:`_execute` the engine's ``prepare`` and dispatch spans
+        (cat ``inference``), ``fetch`` and ``emit``. Each is also a
+        ``dstpu.sched.*`` annotation on this thread's line of a jax.profiler
+        trace. An idle poll (nothing queued, nothing active) records nothing;
+        :meth:`_run` covers it with ``no_work``."""
+        spans = self._spans
+        if spans is not None and not self._has_work():
+            spans = None
+        self._tick_spans = spans
+        tick = None
+        if spans is not None:
+            self._tick_seq += 1
+            tick = {"tick": self._tick_seq}
+        self._tick = tick
+        try:
+            with live_span(spans, "tick", "sched", tick):
+                ran = self._step_phases(spans)
+                if tick is not None and "kind" not in tick:
+                    tick.update(seqs=0, tokens=0, kind="none")  # had work, ran no batch
+                return ran
+        finally:
+            self._tick_spans = self._tick = None
+
+    def _step_phases(self, spans) -> bool:
+        # args are filled in when known: what is there at entry rides on the
+        # profiler annotation, and a placeholder would read as a value there
+        args = None if spans is None else {}
+        with live_span(spans, "admit", "sched", args):
+            self._drain_control()
+            now = time.monotonic()
+            for req in list(self._active.values()):
+                # the deadline check doubles as the decode feed-stop: a request
+                # past its deadline is finalized HERE, before batch building, so
+                # it never receives another decode step
+                if req.cancel_requested:
+                    self._finalize(req, RequestState.CANCELLED)
+                elif req.deadline is not None and now > req.deadline:
+                    self._finalize(req, RequestState.TIMED_OUT)
+            if self._config.overload.enabled:
+                self._overload_tick(now)
+            admitted = self._admit(now)
+            if args is not None:
+                args["admitted"] = admitted
+        args = None if spans is None else {}
+        with live_span(spans, "build_batch", "sched", args):
+            evicted = self._evicted_total()
+            plan = self._build_batch()
+            if args is not None:
+                args["evicted"] = self._evicted_total() - evicted
         if not plan:
             if not self._active:
                 self._starved_ticks = 0  # idle, not starved
@@ -879,8 +960,14 @@ class ServingScheduler:
         self._counters["batches"] += 1
         return True
 
-    def _admit(self, now: float) -> None:
+    def _evicted_total(self) -> int:
+        c = self._counters
+        return c["evictions"] + c["prefix_evictions"] + c["tier_demotions"]
+
+    def _admit(self, now: float) -> int:
+        """Move queued requests into the active set; returns how many."""
         max_active = self._engine._config.state_manager.max_tracked_sequences
+        admitted = 0
         while True:
             # the queue condition guards ONLY the pop: engine work below (a
             # resume import scatters hundreds of MB of KV and may evict) must
@@ -946,6 +1033,7 @@ class ServingScheduler:
                                else RequestState.PREFILL)
                 with self._not_full:
                     self._active[req.uid] = req
+                admitted += 1
             finally:
                 self._admitting = None
             spans = self._spans  # bind once: the property re-resolves
@@ -960,6 +1048,7 @@ class ServingScheduler:
                 queue_depth = len(self._queue)
             self._metrics.queue_depth.set(queue_depth)
             self._metrics.in_flight.set(len(self._active))
+        return admitted
 
     def _import_resume(self, req: Request) -> Optional[str]:
         """Import a handed-off sequence under the request's uid (scheduler
@@ -1511,7 +1600,7 @@ class ServingScheduler:
         accepted = 0
         k = int(feed.size) - 1
         for j in range(int(feed.size)):
-            tok = self._sample(req, rows[j])
+            tok = self._draw(req, rows[j])
             emitted.append(tok)
             if req.eos_token_id is not None and tok == req.eos_token_id:
                 break
@@ -1721,13 +1810,15 @@ class ServingScheduler:
         # close + re-anchor each member's KV block-second segment at its
         # pre-dispatch occupancy (the final segment closes at finalize)
         self._touch_kv_plan(plan)
-        spans = self._spans
+        spans = self._tick_spans
+        tick = self._tick
         if spans is not None:
             # capture each request's phase before the processing loop mutates
             # state (PREFILL flips to DECODE on the final chunk)
             _t0 = now_us()
             _phases = [("prefill" if req.state is RequestState.PREFILL else "decode",
                         int(toks.size)) for req, toks in plan]
+            tick.update(seqs=len(plan), tokens=sum(n for _, n in _phases), kind="put")
 
         def _record_phase_spans(counts=None):
             if spans is None:
@@ -1736,19 +1827,23 @@ class ServingScheduler:
             for i, ((phase, ntok), (req, _)) in enumerate(zip(_phases, plan)):
                 spans.record(phase, cat="serving", ts_us=_t0, dur_us=end - _t0,
                              trace_id=req.trace_id, parent_id=req.root_span_id,
-                             args={"uid": req.uid,
+                             args={"uid": req.uid, "tick": tick["tick"],
                                    "tokens": ntok if counts is None else counts[i]})
 
         # tree-verify (learned/auto drafters): any decode entry carrying a
         # TokenTree — root-only trees included — routes the tick through ONE
         # engine.verify_tree dispatch
         if any(req._spec_tree is not None for req, _ in plan):
+            if tick is not None:
+                tick["kind"] = "verify_tree"
             self._execute_verify_tree(plan, _record_phase_spans)
             return
         # speculative verify: any decode feed wider than one token (next
         # input + draft tokens) routes the tick through the verify path
         if any(req.state is RequestState.DECODE and toks.size > 1
                for req, toks in plan):
+            if tick is not None:
+                tick["kind"] = "verify"
             self._execute_verify(plan, _record_phase_spans)
             return
 
@@ -1770,50 +1865,102 @@ class ServingScheduler:
                                      and chunk_safe(req) for req, _ in plan))
         if decode_only:
             try:
-                rows = np.asarray(engine.decode_loop(uids, tokens, K))
+                # decode_loop returns host tokens: the wait is inside its span
+                rows = self._fetch(engine.decode_loop(uids, tokens, K))
             except SchedulingError:
                 rows = None  # KV too tight for K steps — single-step fallback
             if rows is not None:
-                # record before pushing: the final token finalizes the request
-                # and closes the root span, which children must nest inside —
-                # with the kept-token counts driving BOTH the span args and
-                # the push loop, so trace and stream cannot disagree
-                counts = [self._kept_tokens(req, row)
-                          for (req, _), row in zip(plan, rows)]
-                self._rate.observe(sum(counts))
-                # billed work is what the device ran: K decode steps per
-                # member, kept or not (the discarded over-run still computed)
-                self._charge_members([(req, "decode", K) for req, _ in plan])
-                _record_phase_spans(counts=counts)
-                for (req, _), row, kept in zip(plan, rows, counts):
-                    req.decode_steps += 1
-                    # eos/cap discard the over-generated tail
-                    self._push_burst(req, row[:kept])
+                if tick is not None:
+                    tick["kind"] = "decode_loop"
+                with self._emit_phase(spans):
+                    # record before pushing: the final token finalizes the
+                    # request and closes the root span, which children must
+                    # nest inside — with the kept-token counts driving BOTH the
+                    # span args and the push loop, so trace and stream cannot
+                    # disagree
+                    counts = [self._kept_tokens(req, row)
+                              for (req, _), row in zip(plan, rows)]
+                    self._rate.observe(sum(counts))
+                    # billed work is what the device ran: K decode steps per
+                    # member, kept or not (the discarded over-run still computed)
+                    self._charge_members([(req, "decode", K) for req, _ in plan])
+                    _record_phase_spans(counts=counts)
+                    for (req, _), row, kept in zip(plan, rows, counts):
+                        req.decode_steps += 1
+                        # eos/cap discard the over-generated tail
+                        self._push_burst(req, row[:kept])
                 return
 
         try:
-            logits = np.asarray(engine.put(uids, tokens))
+            logits = self._fetch(engine.put(uids, tokens))
         except Exception as e:  # pragma: no cover - defensive: the scheduler
             # thread must survive an engine fault; the batch's requests fail
             logger.exception("serving: engine.put failed; failing the batch")
             for req, _ in plan:
                 self._finalize(req, RequestState.FAILED, error=f"engine error: {e}")
             return
-        self._rate.observe(sum(int(t.size) for t in tokens))
-        # attribute BEFORE the processing loop flips any PREFILL to DECODE
-        self._charge_members(
-            [(req, "prefill" if req.state is RequestState.PREFILL else "decode",
-              int(toks.size)) for req, toks in plan])
-        _record_phase_spans()
-        for i, (req, toks) in enumerate(plan):
-            if req.state is RequestState.PREFILL:
-                self._advance_prefill(req, toks, logits[i])
-            else:
-                req.decode_steps += 1
-                nxt = self._sample(req, logits[i])
-                self._push_token(req, nxt)
-                if not req.finished:
-                    req._next = nxt
+        with self._emit_phase(spans):
+            self._rate.observe(sum(int(t.size) for t in tokens))
+            # attribute BEFORE the processing loop flips any PREFILL to DECODE
+            self._charge_members(
+                [(req, "prefill" if req.state is RequestState.PREFILL else "decode",
+                  int(toks.size)) for req, toks in plan])
+            _record_phase_spans()
+            for i, (req, toks) in enumerate(plan):
+                if req.state is RequestState.PREFILL:
+                    self._advance_prefill(req, toks, logits[i])
+                else:
+                    req.decode_steps += 1
+                    nxt = self._draw(req, logits[i])
+                    self._push_token(req, nxt)
+                    if not req.finished:
+                        req._next = nxt
+
+    def _fetch(self, result) -> np.ndarray:
+        """The blocking transfer of an engine call's result to the host, apart
+        from the call itself (span ``fetch``: the wait for the device is here,
+        the call's own span is the dispatch)."""
+        spans = self._tick_spans
+        if spans is None:
+            return np.asarray(result)
+        args = {}
+        with spans.span("fetch", "sched", args):
+            out = np.asarray(result)
+            args["bytes"] = int(out.nbytes)
+        return out
+
+    def _emit_phase(self, spans):
+        """Everything a tick does after the fetch (span ``emit``): billing, the
+        per-request phase spans, sampling, pushing tokens, finalizing.
+        ``args``: ``sample_us`` (time inside this tick's :meth:`_sample`
+        calls), ``pushed`` (tokens streamed), ``finished`` (requests)."""
+        if spans is None:
+            return NULL_SPAN
+        return self._emit_phase_live(spans)
+
+    @contextmanager
+    def _emit_phase_live(self, spans):
+        emit = self._emit = {"sample_us": 0.0, "pushed": 0, "finished": 0}
+        args = {}
+        try:
+            with spans.span("emit", "sched", args):
+                try:
+                    yield
+                finally:
+                    args.update(emit, sample_us=int(round(emit["sample_us"])))
+        finally:
+            self._emit = None
+
+    def _draw(self, req: Request, row: np.ndarray) -> int:
+        """:meth:`_sample`; under a live ``emit`` span its time is summed into
+        ``sample_us``."""
+        emit = self._emit
+        if emit is None:
+            return self._sample(req, row)
+        t0 = time.perf_counter()
+        tok = self._sample(req, row)
+        emit["sample_us"] += (time.perf_counter() - t0) * 1e6
+        return tok
 
     def _advance_prefill(self, req: Request, toks: np.ndarray, last_row) -> None:
         """Account one executed prefill chunk; on the final chunk: flip to
@@ -1830,7 +1977,7 @@ class ServingScheduler:
             seq = self._engine._state_manager.get_sequence(req.uid)
             if seq is not None:
                 self._publish(req, seq, req.prompt, seq.seen_tokens)
-        nxt = self._sample(req, last_row)
+        nxt = self._draw(req, last_row)
         self._push_token(req, nxt)
         if not req.finished:
             req._next = nxt
@@ -1878,7 +2025,7 @@ class ServingScheduler:
             # prefill put overwrites the observer slots
             verify_s = self._last_dispatch_s
             verify_amnesty_s = self._last_dispatch_amnesty_s
-            prefill_logits = (np.asarray(engine.put(
+            prefill_logits = (self._fetch(engine.put(
                 [req.uid for req, _ in prefill_plan],
                 [toks for _, toks in prefill_plan])) if prefill_plan else None)
         except Exception as e:  # pragma: no cover - defensive: same contract
@@ -1887,59 +2034,60 @@ class ServingScheduler:
             for req, _ in plan:
                 self._finalize(req, RequestState.FAILED, error=f"engine error: {e}")
             return
-        # the estimator measures engine-token throughput: verify feeds cost
-        # their full width (accepted or not), like any other fed token
-        self._rate.observe(sum(int(t.size) for _, t in plan))
-        self._charge_members([(req, "verify", int(t.size))
-                              for req, t in decode_plan],
-                             seconds=verify_s, amnesty=verify_amnesty_s)
-        if prefill_plan:
-            self._charge_members([(req, "prefill", int(t.size))
-                                  for req, t in prefill_plan])
-        alpha = self._config.speculative.accept_alpha
-        # sample/accept BEFORE any push: span token counts must be final when
-        # the root span closes, and each request's private stream makes the
-        # per-request draw order independent of processing order
-        accepts = {id(req): self._spec_accept(req, toks, rows)
-                   for (req, toks), rows in zip(decode_plan, per_seq)}
-        record_spans(counts=[len(accepts[id(req)][0]) if id(req) in accepts
-                             else int(toks.size) for req, toks in plan])
-        for (req, toks), rows in zip(decode_plan, per_seq):
-            emitted, accepted = accepts[id(req)]
-            k = int(toks.size) - 1
-            rejected = int(toks.size) - len(emitted)
-            # rollback BEFORE pushing: a push may finalize, and the handoff
-            # export / trie publish there must see the truncated seen_tokens
-            # (= full history - 1, the same invariant every other path keeps)
-            engine.rollback(req.uid, rejected)
-            req.decode_steps += 1
-            if k:
-                # a k=0 feed riding a verify batch proposed nothing — no
-                # acceptance evidence, no EWMA movement
-                req.spec_drafted += k
-                req.spec_accepted += accepted
-                if self._ledger is not None and req.cost is not None:
-                    self._ledger.charge_spec(req.cost, k, accepted)
-                self._counters["spec_steps"] += 1
-                self._counters["spec_drafted"] += k
-                self._counters["spec_rollback"] += rejected
-                self._counters["spec_accepted"] += accepted
-                rate = accepted / k
-                req._spec_ewma = (rate if req._spec_ewma is None
-                                  else alpha * rate + (1 - alpha) * req._spec_ewma)
-                self._spec_accept_ewma = (rate if self._spec_accept_ewma is None
-                                          else alpha * rate
-                                          + (1 - alpha) * self._spec_accept_ewma)
-                if self._metrics:
-                    self._metrics.spec_verify_steps.inc()
-                    self._metrics.spec_drafted.inc(k)
-                    self._metrics.spec_accepted.inc(accepted)
-                    self._metrics.spec_rollback.inc(rejected)
-                    self._metrics.spec_accept_rate.set(self._spec_accept_ewma or 0.0)
-                    self._metrics.spec_tokens_per_step.observe(len(emitted))
-            self._push_burst(req, emitted)
-        for i, (req, toks) in enumerate(prefill_plan):
-            self._advance_prefill(req, toks, prefill_logits[i])
+        with self._emit_phase(self._tick_spans):
+            # the estimator measures engine-token throughput: verify feeds cost
+            # their full width (accepted or not), like any other fed token
+            self._rate.observe(sum(int(t.size) for _, t in plan))
+            self._charge_members([(req, "verify", int(t.size))
+                                  for req, t in decode_plan],
+                                 seconds=verify_s, amnesty=verify_amnesty_s)
+            if prefill_plan:
+                self._charge_members([(req, "prefill", int(t.size))
+                                      for req, t in prefill_plan])
+            alpha = self._config.speculative.accept_alpha
+            # sample/accept BEFORE any push: span token counts must be final when
+            # the root span closes, and each request's private stream makes the
+            # per-request draw order independent of processing order
+            accepts = {id(req): self._spec_accept(req, toks, rows)
+                       for (req, toks), rows in zip(decode_plan, per_seq)}
+            record_spans(counts=[len(accepts[id(req)][0]) if id(req) in accepts
+                                 else int(toks.size) for req, toks in plan])
+            for (req, toks), rows in zip(decode_plan, per_seq):
+                emitted, accepted = accepts[id(req)]
+                k = int(toks.size) - 1
+                rejected = int(toks.size) - len(emitted)
+                # rollback BEFORE pushing: a push may finalize, and the handoff
+                # export / trie publish there must see the truncated seen_tokens
+                # (= full history - 1, the same invariant every other path keeps)
+                engine.rollback(req.uid, rejected)
+                req.decode_steps += 1
+                if k:
+                    # a k=0 feed riding a verify batch proposed nothing — no
+                    # acceptance evidence, no EWMA movement
+                    req.spec_drafted += k
+                    req.spec_accepted += accepted
+                    if self._ledger is not None and req.cost is not None:
+                        self._ledger.charge_spec(req.cost, k, accepted)
+                    self._counters["spec_steps"] += 1
+                    self._counters["spec_drafted"] += k
+                    self._counters["spec_rollback"] += rejected
+                    self._counters["spec_accepted"] += accepted
+                    rate = accepted / k
+                    req._spec_ewma = (rate if req._spec_ewma is None
+                                      else alpha * rate + (1 - alpha) * req._spec_ewma)
+                    self._spec_accept_ewma = (rate if self._spec_accept_ewma is None
+                                              else alpha * rate
+                                              + (1 - alpha) * self._spec_accept_ewma)
+                    if self._metrics:
+                        self._metrics.spec_verify_steps.inc()
+                        self._metrics.spec_drafted.inc(k)
+                        self._metrics.spec_accepted.inc(accepted)
+                        self._metrics.spec_rollback.inc(rejected)
+                        self._metrics.spec_accept_rate.set(self._spec_accept_ewma or 0.0)
+                        self._metrics.spec_tokens_per_step.observe(len(emitted))
+                self._push_burst(req, emitted)
+            for i, (req, toks) in enumerate(prefill_plan):
+                self._advance_prefill(req, toks, prefill_logits[i])
 
     def _spec_accept_tree(self, req: Request, tree, rows, ids):
         """The acceptance rule over one verified token tree. Walk from the
@@ -1959,7 +2107,7 @@ class ServingScheduler:
         node = 0
         while True:
             tok = (int(ids[node]) if rows is None
-                   else self._sample(req, rows[node]))
+                   else self._draw(req, rows[node]))
             emitted.append(tok)
             if req.eos_token_id is not None and tok == req.eos_token_id:
                 break
@@ -2008,7 +2156,7 @@ class ServingScheduler:
             # prefill put overwrites the observer slots
             verify_s = self._last_dispatch_s
             verify_amnesty_s = self._last_dispatch_amnesty_s
-            prefill_logits = (np.asarray(engine.put(
+            prefill_logits = (self._fetch(engine.put(
                 [req.uid for req, _ in prefill_plan],
                 [toks for _, toks in prefill_plan])) if prefill_plan else None)
         except Exception as e:  # pragma: no cover - defensive: same contract
@@ -2017,87 +2165,88 @@ class ServingScheduler:
             for req, _ in plan:
                 self._finalize(req, RequestState.FAILED, error=f"engine error: {e}")
             return
-        # verify feeds cost their full width (accepted or not), like any fed
-        # token — tree nodes included
-        self._rate.observe(sum(int(t.size) for _, t in plan))
-        self._charge_members([(req, "tree_verify", int(t.size))
-                              for req, t in decode_plan],
-                             seconds=verify_s, amnesty=verify_amnesty_s)
-        if prefill_plan:
-            self._charge_members([(req, "prefill", int(t.size))
-                                  for req, t in prefill_plan])
-        alpha = self._config.speculative.accept_alpha
-        # sample/accept BEFORE any push: span token counts must be final when
-        # the root span closes, and each request's private stream makes the
-        # per-request draw order independent of processing order
-        accepts = {id(req): self._spec_accept_tree(req, tree,
-                                                   res["rows"], res["ids"])
-                   for (req, _), tree, res in zip(decode_plan, trees, per_seq)}
-        record_spans(counts=[len(accepts[id(req)][0]) if id(req) in accepts
-                             else int(toks.size) for req, toks in plan])
-        for (req, toks), tree, res in zip(decode_plan, trees, per_seq):
-            emitted, path, last_node = accepts[id(req)]
-            k = tree.size - 1  # draft nodes proposed (the root is the input)
-            accepted = len(path)
-            # compact BEFORE pushing (a push may finalize, and the handoff
-            # export / trie publish there must see the truncated seen_tokens):
-            # accepted-path KV moves contiguously behind the committed
-            # history, the rejected remainder truncates off — the same
-            # full-history-minus-1 invariant every other path leaves behind
-            rejected = engine.compact_accepted(req.uid, tree.size, path)
-            req.decode_steps += 1
-            # the hidden state behind the next decode input is the deepest
-            # CONSUMED node's residual; _spec_hidden_pos stamps the history
-            # length it is valid at (stale after any gap: handoff, brownout)
-            hidden = res.get("hidden")
-            if hidden is not None:
-                req._spec_hidden = np.asarray(hidden[last_node], np.float32)
-                req._spec_hidden_pos = (int(req.prompt.size) + len(req.tokens)
-                                        + len(emitted))
-            self._counters["spec_tree_nodes"] += tree.size
-            compacted = any(p != j + 1 for j, p in enumerate(path))
-            if compacted:
-                self._counters["spec_tree_compactions"] += 1
-            if self._metrics:
-                self._metrics.spec_tree_nodes.inc(tree.size)
+        with self._emit_phase(self._tick_spans):
+            # verify feeds cost their full width (accepted or not), like any fed
+            # token — tree nodes included
+            self._rate.observe(sum(int(t.size) for _, t in plan))
+            self._charge_members([(req, "tree_verify", int(t.size))
+                                  for req, t in decode_plan],
+                                 seconds=verify_s, amnesty=verify_amnesty_s)
+            if prefill_plan:
+                self._charge_members([(req, "prefill", int(t.size))
+                                      for req, t in prefill_plan])
+            alpha = self._config.speculative.accept_alpha
+            # sample/accept BEFORE any push: span token counts must be final when
+            # the root span closes, and each request's private stream makes the
+            # per-request draw order independent of processing order
+            accepts = {id(req): self._spec_accept_tree(req, tree,
+                                                       res["rows"], res["ids"])
+                       for (req, _), tree, res in zip(decode_plan, trees, per_seq)}
+            record_spans(counts=[len(accepts[id(req)][0]) if id(req) in accepts
+                                 else int(toks.size) for req, toks in plan])
+            for (req, toks), tree, res in zip(decode_plan, trees, per_seq):
+                emitted, path, last_node = accepts[id(req)]
+                k = tree.size - 1  # draft nodes proposed (the root is the input)
+                accepted = len(path)
+                # compact BEFORE pushing (a push may finalize, and the handoff
+                # export / trie publish there must see the truncated seen_tokens):
+                # accepted-path KV moves contiguously behind the committed
+                # history, the rejected remainder truncates off — the same
+                # full-history-minus-1 invariant every other path leaves behind
+                rejected = engine.compact_accepted(req.uid, tree.size, path)
+                req.decode_steps += 1
+                # the hidden state behind the next decode input is the deepest
+                # CONSUMED node's residual; _spec_hidden_pos stamps the history
+                # length it is valid at (stale after any gap: handoff, brownout)
+                hidden = res.get("hidden")
+                if hidden is not None:
+                    req._spec_hidden = np.asarray(hidden[last_node], np.float32)
+                    req._spec_hidden_pos = (int(req.prompt.size) + len(req.tokens)
+                                            + len(emitted))
+                self._counters["spec_tree_nodes"] += tree.size
+                compacted = any(p != j + 1 for j, p in enumerate(path))
                 if compacted:
-                    self._metrics.spec_tree_compactions.inc()
-            if k:
-                # a root-only bootstrap proposed nothing — no acceptance
-                # evidence, no EWMA movement (linear-path rule, tree-shaped)
-                drafter = req._spec_last_drafter or self._drafter_mode
-                short = "learned" if drafter == "learned" else "lookup"
-                # the arbitration/adaptation signal is DEPTH productivity:
-                # accepted serial depth over proposed depth — comparable
-                # across a branching tree and a linear chain at the same k
-                rate = accepted / max(int(tree.max_depth), 1)
-                req.spec_drafted += k
-                req.spec_accepted += accepted
-                if self._ledger is not None and req.cost is not None:
-                    self._ledger.charge_spec(req.cost, k, accepted)
-                self._counters["spec_steps"] += 1
-                self._counters["spec_drafted"] += k
-                self._counters["spec_rollback"] += rejected
-                self._counters["spec_accepted"] += accepted
-                self._counters[f"spec_drafted_{short}"] += k
-                self._counters[f"spec_accepted_{short}"] += accepted
-                req._spec_ewma = (rate if req._spec_ewma is None
-                                  else alpha * rate + (1 - alpha) * req._spec_ewma)
-                self._arb_update(req, drafter, rate)
-                self._spec_accept_ewma = (rate if self._spec_accept_ewma is None
-                                          else alpha * rate
-                                          + (1 - alpha) * self._spec_accept_ewma)
+                    self._counters["spec_tree_compactions"] += 1
                 if self._metrics:
-                    self._metrics.spec_verify_steps.inc()
-                    self._metrics.spec_drafted.inc(k)
-                    self._metrics.spec_accepted.inc(accepted)
-                    self._metrics.spec_rollback.inc(rejected)
-                    self._metrics.spec_accept_rate.set(self._spec_accept_ewma or 0.0)
-                    self._metrics.spec_tokens_per_step.observe(len(emitted))
-                    self._metrics.spec_tree_accept_depth.observe(accepted)
-            self._push_burst(req, emitted)
-        for i, (req, toks) in enumerate(prefill_plan):
-            self._advance_prefill(req, toks, prefill_logits[i])
+                    self._metrics.spec_tree_nodes.inc(tree.size)
+                    if compacted:
+                        self._metrics.spec_tree_compactions.inc()
+                if k:
+                    # a root-only bootstrap proposed nothing — no acceptance
+                    # evidence, no EWMA movement (linear-path rule, tree-shaped)
+                    drafter = req._spec_last_drafter or self._drafter_mode
+                    short = "learned" if drafter == "learned" else "lookup"
+                    # the arbitration/adaptation signal is DEPTH productivity:
+                    # accepted serial depth over proposed depth — comparable
+                    # across a branching tree and a linear chain at the same k
+                    rate = accepted / max(int(tree.max_depth), 1)
+                    req.spec_drafted += k
+                    req.spec_accepted += accepted
+                    if self._ledger is not None and req.cost is not None:
+                        self._ledger.charge_spec(req.cost, k, accepted)
+                    self._counters["spec_steps"] += 1
+                    self._counters["spec_drafted"] += k
+                    self._counters["spec_rollback"] += rejected
+                    self._counters["spec_accepted"] += accepted
+                    self._counters[f"spec_drafted_{short}"] += k
+                    self._counters[f"spec_accepted_{short}"] += accepted
+                    req._spec_ewma = (rate if req._spec_ewma is None
+                                      else alpha * rate + (1 - alpha) * req._spec_ewma)
+                    self._arb_update(req, drafter, rate)
+                    self._spec_accept_ewma = (rate if self._spec_accept_ewma is None
+                                              else alpha * rate
+                                              + (1 - alpha) * self._spec_accept_ewma)
+                    if self._metrics:
+                        self._metrics.spec_verify_steps.inc()
+                        self._metrics.spec_drafted.inc(k)
+                        self._metrics.spec_accepted.inc(accepted)
+                        self._metrics.spec_rollback.inc(rejected)
+                        self._metrics.spec_accept_rate.set(self._spec_accept_ewma or 0.0)
+                        self._metrics.spec_tokens_per_step.observe(len(emitted))
+                        self._metrics.spec_tree_accept_depth.observe(accepted)
+                self._push_burst(req, emitted)
+            for i, (req, toks) in enumerate(prefill_plan):
+                self._advance_prefill(req, toks, prefill_logits[i])
 
     @staticmethod
     def _kept_tokens(req: Request, row) -> int:
@@ -2136,6 +2285,8 @@ class ServingScheduler:
             self._metrics.itl.observe(now - req._last_token_s)
         req._last_token_s = now
         req.stream.put(tok)
+        if self._emit is not None:
+            self._emit["pushed"] += 1
         if req.eos_token_id is not None and tok == req.eos_token_id:
             req.finish_reason = "eos"
             self._finalize(req, RequestState.DONE)
@@ -2265,6 +2416,8 @@ class ServingScheduler:
                 self._engine.flush(req.uid)  # returns KV blocks (incl. offloaded)
         req._set_state(state)
         self._counters[self._FINAL_COUNTER[state]] += 1
+        if self._emit is not None:
+            self._emit["finished"] += 1
         if self._ledger is not None and req.cost is not None:
             # close the open KV segment and fold the bill into the tenant
             # rollup — conservation holds once every request finalizes
@@ -2295,23 +2448,30 @@ class ServingScheduler:
     # ------------------------------------------------------------------ loop --
     def _run(self) -> None:
         self._ready.set()  # readiness gate: the loop is ticking
-        while not self._shutdown:
-            if self._kill_reason is not None:
-                self._die()  # in-flight disposition on the engine-owning thread
-                return
-            flight = telemetry.get_flight_recorder()
-            if flight is not self._flight:
-                self._attach_flight(flight)
-            if flight is not None:
-                flight.heartbeat(self._flight_channel)
-            try:
-                progressed = self.step()
-            except Exception:  # pragma: no cover - must never kill the thread
-                logger.exception("serving scheduler: step() raised")
-                progressed = False
-            if not progressed:
-                self._maybe_heartbeat()
-                time.sleep(self._config.scheduler_tick_s)
+        idle = _IdleSpan()
+        try:
+            while not self._shutdown:
+                if self._kill_reason is not None:
+                    self._die()  # in-flight disposition on the engine-owning thread
+                    return
+                flight = telemetry.get_flight_recorder()
+                if flight is not self._flight:
+                    self._attach_flight(flight)
+                if flight is not None:
+                    flight.heartbeat(self._flight_channel)
+                if idle.open and (self._has_work() or idle.polls >= _NO_WORK_SPAN_POLLS):
+                    idle.close()
+                try:
+                    progressed = self.step()
+                except Exception:  # pragma: no cover - must never kill the thread
+                    logger.exception("serving scheduler: step() raised")
+                    progressed = False
+                if not progressed:
+                    idle.poll(self._spans)
+                    self._maybe_heartbeat()
+                    time.sleep(self._config.scheduler_tick_s)
+        finally:
+            idle.close()
 
     def _maybe_heartbeat(self) -> None:
         enabled = self._config.heartbeat_enabled

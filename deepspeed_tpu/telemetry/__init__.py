@@ -32,9 +32,9 @@ from deepspeed_tpu.telemetry.slo import SLOEngine
 from deepspeed_tpu.telemetry.timeseries import TimeSeriesStore
 from deepspeed_tpu.telemetry.registry import (Counter, Gauge, Histogram, MetricsRegistry,
                                               parse_prometheus_text)
-from deepspeed_tpu.telemetry.spans import (Span, SpanRecorder, TracingTimers,
-                                           current_trace, new_span_id, new_trace_id,
-                                           now_us, trace_context)
+from deepspeed_tpu.telemetry.spans import (NULL_SPAN, Span, SpanRecorder, TracingTimers,
+                                           current_trace, live_span, new_span_id,
+                                           new_trace_id, now_us, trace_context)
 from deepspeed_tpu.utils.logging import logger
 
 __all__ = [
@@ -47,7 +47,7 @@ __all__ = [
     "get_timeseries", "get_slo_engine",
     "is_active", "record_comm_op", "wrap_timers", "start_http_server", "scrape_metrics",
     "parse_prometheus_text", "state", "now_us", "new_trace_id", "new_span_id",
-    "trace_context", "current_trace", "compile_watch",
+    "trace_context", "current_trace", "compile_watch", "live_span", "NULL_SPAN",
 ]
 
 # comm-op latencies live well under the default buckets' top decades; bytes

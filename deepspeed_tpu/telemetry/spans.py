@@ -3,9 +3,18 @@
 The recorder is the single sink behind every existing timing call site:
 ``SynchronizedWallClockTimer`` (fwd/bwd/step — wrapped via
 :class:`TracingTimers`), the comms ``timed_op`` wrapper (one span per
-collective) and the inference ``Tracer.record`` phases. Spans are complete
+collective), the serving scheduler's tick phases (cat ``sched``) and
+per-request lifecycle (cat ``serving``) and the inference engine's
+``prepare`` / dispatch spans (cat ``inference``). Spans are complete
 ``"ph": "X"`` events, so the export loads directly in ``chrome://tracing`` /
 Perfetto.
+
+Two sinks, one call site: a LIVE span (:meth:`SpanRecorder.span`, the context
+manager) is also a ``jax.profiler.TraceAnnotation`` named
+``dstpu.<cat>.<name>``, so while a ``jax.profiler`` session runs it lands on
+the calling thread's line of ``/host:CPU``, on the profiler's clock, beside
+the device's ``XLA Ops``. :meth:`SpanRecorder.record` writes after the fact
+and reaches the ring only.
 
 Distributed tracing (Dapper-style): spans optionally carry
 ``trace_id``/``span_id``/``parent_id``. The serving layer assigns one trace id
@@ -26,7 +35,7 @@ import threading
 import time
 import uuid
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from contextvars import ContextVar
 from dataclasses import dataclass, field
 from typing import Optional
@@ -36,6 +45,34 @@ def now_us():
     """Monotonic microsecond timestamp shared by every span source (mixing
     clocks would break trace-viewer ordering)."""
     return int(time.perf_counter() * 1e6)
+
+
+# What a call site enters while telemetry is off: one shared, reusable,
+# re-entrant no-op, so the off path constructs nothing.
+NULL_SPAN = nullcontext()
+
+ANNOTATION_PREFIX = "dstpu."
+
+
+def live_span(spans, name, cat="default", args=None):
+    """``spans.span(...)``, or :data:`NULL_SPAN` while telemetry is off
+    (``spans`` is None): the call sites' one ``None`` check per phase."""
+    if spans is None:
+        return NULL_SPAN
+    return spans.span(name, cat, args)
+
+
+def _annotation(name, cat, args):
+    """The ``jax.profiler.TraceAnnotation`` twin of a live span. The scalar
+    ``args`` known at entry ride along as the event's stats (``tick``,
+    ``steps``, ...); what a call site fills in later reaches the ring only.
+    A TraceMe check when no profiler session runs."""
+    from jax.profiler import TraceAnnotation
+    if args:
+        return TraceAnnotation(f"{ANNOTATION_PREFIX}{cat}.{name}",
+                               **{k: v for k, v in args.items()
+                                  if isinstance(v, (int, float, str))})
+    return TraceAnnotation(f"{ANNOTATION_PREFIX}{cat}.{name}")
 
 
 # --------------------------------------------------------------- trace ids --
@@ -134,27 +171,31 @@ class SpanRecorder:
     @contextmanager
     def span(self, name, cat="default", args=None, trace_id=None, parent_id=None):
         """Timed span; inside a trace the block's children parent to it (the
-        span id is allocated up-front and made ambient for the duration)."""
-        t0 = now_us()
+        span id is allocated up-front and made ambient for the duration).
+        Also a ``jax.profiler.TraceAnnotation`` ``dstpu.<cat>.<name>`` for its
+        duration. ``args`` may be filled in by the block: the ring keeps the
+        dict as it is at exit."""
         ctx = _TRACE_CTX.get()
         if trace_id is None and ctx is not None:
             trace_id = ctx[0]
             if parent_id is None:
                 parent_id = ctx[1]
-        if trace_id is None:
+        with _annotation(name, cat, args):
+            t0 = now_us()
+            if trace_id is None:
+                try:
+                    yield
+                finally:
+                    self.record(name, cat, ts_us=t0, dur_us=now_us() - t0, args=args)
+                return
+            span_id = new_span_id()
+            token = _TRACE_CTX.set((trace_id, span_id))
             try:
                 yield
             finally:
-                self.record(name, cat, ts_us=t0, dur_us=now_us() - t0, args=args)
-            return
-        span_id = new_span_id()
-        token = _TRACE_CTX.set((trace_id, span_id))
-        try:
-            yield
-        finally:
-            _TRACE_CTX.reset(token)
-            self.record(name, cat, ts_us=t0, dur_us=now_us() - t0, args=args,
-                        trace_id=trace_id, span_id=span_id, parent_id=parent_id)
+                _TRACE_CTX.reset(token)
+                self.record(name, cat, ts_us=t0, dur_us=now_us() - t0, args=args,
+                            trace_id=trace_id, span_id=span_id, parent_id=parent_id)
 
     def clear(self):
         with self._lock:

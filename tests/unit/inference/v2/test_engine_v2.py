@@ -124,26 +124,6 @@ def test_flush_recycles_blocks(llama_setup):
     assert engine._state_manager.get_sequence(7) is None
 
 
-def test_tracer_records_per_layer(llama_setup):
-    cfg, _, params = llama_setup
-    ec = _engine_config()
-    ec.trace_enabled = True
-    engine = build_engine(params, cfg, ec)
-    engine.put([0], [np.arange(12) % cfg.vocab_size])
-    engine.empty_run()
-    summaries = list(engine.tracer.batch_summaries())
-    assert len(summaries) == 2
-    real, empty = summaries
-    assert not real.is_empty_run and empty.is_empty_run
-    assert real.num_layers == cfg.num_hidden_layers
-    assert real.seen_tokens == [0] and real.in_flight_tokens == [12]
-    # per-layer phase timings recorded for attn+ffn
-    times = np.asarray(real.record_exec_times)
-    assert times.shape[0] == cfg.num_hidden_layers
-    assert (times[:, real.record_names.index("attn")] > 0).all()
-    assert real.embed > 0 and real.unembed > 0
-
-
 def test_serialize_roundtrip(llama_setup, tmp_path):
     """serialize → build_engine_from_ds_checkpoint is a REAL round-trip
     (reference engine_factory.py:29): the rebuilt engine serves identical

@@ -1,0 +1,128 @@
+"""The serving programs' named scopes: present in the lowered ``put`` programs
+(``op_name`` metadata, which the TPU's trace carries as each operation's
+``tf_op``), and metadata only — the logits are bitwise what the same code
+gives with every scope turned off."""
+
+import contextlib
+import importlib.util
+import re
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+from deepspeed_tpu.inference.v2.engine_factory import build_engine
+from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
+from deepspeed_tpu.inference.v2.ragged.manager_configs import (AllocationMode,
+                                                               DSStateManagerConfig, MemoryConfig)
+from deepspeed_tpu.models import mixtral
+from deepspeed_tpu.models.llama import LlamaConfig, LlamaModel
+from deepspeed_tpu.utils import groups
+
+V2 = "deepspeed_tpu.inference.v2."
+
+
+def _engine_config(**kw):
+    mgr = DSStateManagerConfig(memory_config=MemoryConfig(mode=AllocationMode.ALLOCATE, size=32),
+                               max_context=128)
+    return RaggedInferenceEngineConfig(state_manager=mgr, kv_block_size=16, **kw)
+
+
+@pytest.fixture(scope="module")
+def models():
+    groups.destroy_mesh()
+    llama_cfg = LlamaConfig.tiny(dtype=jnp.float32)
+    llama = {"model": LlamaModel(llama_cfg).init(jax.random.PRNGKey(0),
+                                                 jnp.zeros((1, 8), jnp.int32))["params"]}
+    mixtral_cfg = mixtral.MixtralConfig.tiny(dtype=jnp.float32)
+    return {"llama": (llama_cfg, llama),
+            "mixtral": (mixtral_cfg, mixtral.init_params(mixtral_cfg, rng=jax.random.PRNGKey(0))[1])}
+
+
+@pytest.fixture(scope="module")
+def lowered(models):
+    """The lowered text, with debug info, of each family's ``put`` program on
+    each attention arm, and Mixtral's ``decode_loop``."""
+    out = {}
+    for family, (cfg, params) in models.items():
+        for arm, kernel in (("gather", False), ("kernel", True)):
+            engine = build_engine(params, cfg, _engine_config(use_paged_kernel=kernel))
+            out[family, arm] = engine.lower_forward().as_text(debug_info=True)
+            if family == "mixtral" and arm == "gather":
+                out[family, "loop"] = engine.lower_decode_loop(2).as_text(debug_info=True)
+            engine.close()
+    return out
+
+
+@pytest.mark.parametrize("family,arm,scope", [
+    ("llama", "gather", "embed"), ("llama", "gather", "attn"), ("llama", "gather", "attn/kv_write"),
+    ("llama", "gather", "attn/gather"), ("llama", "kernel", "attn/paged_kernel"),
+    ("llama", "gather", "mlp"), ("llama", "gather", "unembed"),
+    ("mixtral", "gather", "attn"), ("mixtral", "gather", "moe/route"),
+    ("mixtral", "gather", "moe/dispatch"), ("mixtral", "gather", "moe/experts"),
+    ("mixtral", "gather", "moe/combine"), ("mixtral", "gather", "unembed"),
+    ("mixtral", "loop", "moe/experts"), ("mixtral", "loop", "attn/gather"),
+])
+def test_put_program_carries_the_scope(lowered, family, arm, scope):
+    # "jit(_forward_impl)/moe/route/top_k"; inside a scan's body the path is
+    # relative ("moe/route/top_k") and the call site supplies the rest
+    assert re.search(rf'[/"]{scope}/', lowered[family, arm]), \
+        f"no operation of the {family} {arm} program is under {scope}"
+
+
+def test_scopes_name_no_layer_and_dense_models_have_no_moe(lowered):
+    assert "/moe/" not in lowered["llama", "gather"] and "/mlp/" not in lowered["mixtral", "gather"]
+    assert "/attn/gather/" not in lowered["llama", "kernel"]
+    assert "layers_0/" not in lowered["mixtral", "gather"]  # one row a kind, whatever the layer
+
+
+class _NoScope(contextlib.ContextDecorator):
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def _unscoped_model_classes(monkeypatch):
+    """The model modules executed once more, privately, with
+    ``jax.named_scope`` a no-op: the same code without its scopes."""
+    monkeypatch.setattr(jax, "named_scope", lambda name: _NoScope())
+    private = {}
+    for name in ("modules.moe", "model_implementations.transformer_base",
+                 "model_implementations.llama_v2", "model_implementations.mixtral_v2"):
+        spec = importlib.util.find_spec(V2 + name)
+        module = importlib.util.module_from_spec(spec)
+        monkeypatch.setitem(sys.modules, V2 + name, module)
+        spec.loader.exec_module(module)
+        private[name.rsplit(".", 1)[1]] = module
+    return {"llama": private["llama_v2"].LlamaV2Model, "mixtral": private["mixtral_v2"].MixtralV2Model}
+
+
+@pytest.mark.parametrize("family", ["llama", "mixtral"])
+def test_logits_are_bitwise_what_they_are_without_scopes(models, family, monkeypatch):
+    cfg, params = models[family]
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32) for n in (9, 5)]
+    step = [np.array([7], np.int32), np.array([11], np.int32)]
+
+    def run(engine):
+        first = np.asarray(engine.put([0, 1], prompts))
+        second = np.asarray(engine.put([0, 1], step))
+        looped = np.asarray(engine.decode_loop([0, 1], step, 3))
+        engine.close()
+        return first, second, looped
+
+    scoped = run(build_engine(params, cfg, _engine_config()))
+    model_cls = _unscoped_model_classes(monkeypatch)[family]
+    config = _engine_config()
+    plain_engine = InferenceEngineV2(model_cls(params, cfg, config), config)
+    assert "/attn/" not in plain_engine.lower_forward().as_text(debug_info=True)
+    plain = run(plain_engine)
+    for a, b in zip(scoped, plain):
+        np.testing.assert_array_equal(a, b)

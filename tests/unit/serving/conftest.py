@@ -36,7 +36,7 @@ def llama_setup():
 @pytest.fixture
 def make_engine(llama_setup):
     """Engine factory with a small, test-controllable KV pool; every engine
-    built through it is closed at teardown (scheduler detach + tracer clear)."""
+    built through it is closed at teardown (scheduler detach)."""
     cfg, _, params = llama_setup
     engines = []
 

@@ -227,3 +227,149 @@ def test_flight_dump_during_active_workload(make_engine, llama_setup, tmp_path):
     with open(path) as f:
         assert not any(k.startswith("serving_scheduler")
                        for k in json.load(f)["state"])
+
+
+# ------------------------------------------------- scheduler-thread tick phases --
+def _sched_spans():
+    return [s for s in telemetry.get_span_recorder().export_since(0)["spans"]]
+
+
+def _serve_inline(make_engine, submit, **serving):
+    """Serve through a manually stepped scheduler with telemetry on; returns
+    the recorded spans."""
+    telemetry.configure(telemetry.TelemetryConfig(enabled=True))
+    sched = ServingScheduler(make_engine(), ServingConfig(**serving), start=False)
+    reqs = submit(sched)
+    for _ in range(200):
+        sched.step()
+        if all(r.finished for r in reqs):
+            break
+    assert all(r.state is RequestState.DONE for r in reqs)
+    spans = _sched_spans()
+    sched.stop(drain=False)
+    return spans
+
+
+def _inside(child, parent):
+    return (parent["ts_us"] <= child["ts_us"]
+            and child["ts_us"] + child["dur_us"] <= parent["ts_us"] + parent["dur_us"])
+
+
+def test_tick_spans_hold_their_phases_in_order(make_engine):
+    spans = _serve_inline(
+        make_engine,
+        lambda s: [s.submit([1, 2, 3, 4, 5], max_new_tokens=4, temperature=0.7, seed=1)])
+    ticks = [s for s in spans if s["cat"] == "sched" and s["name"] == "tick"]
+    assert len(ticks) == 4  # the prefill (first token) + three decode steps
+    assert [t["args"]["tick"] for t in ticks] == sorted(t["args"]["tick"] for t in ticks)
+    for tick in ticks:
+        assert tick["args"]["kind"] == "put" and tick["args"]["seqs"] == 1
+        children = sorted((s for s in spans if s is not tick and _inside(s, tick)
+                           and s["cat"] in ("sched", "inference")), key=lambda s: s["ts_us"])
+        assert [(c["cat"], c["name"]) for c in children] == [
+            ("sched", "admit"), ("sched", "build_batch"), ("inference", "prepare"),
+            ("inference", "put"), ("sched", "fetch"), ("sched", "emit")]
+        for a, b in zip(children, children[1:]):
+            assert a["ts_us"] + a["dur_us"] <= b["ts_us"], "phases do not overlap"
+        emit = children[-1]
+        assert 0 < emit["args"]["sample_us"] <= emit["dur_us"]
+        assert emit["args"]["pushed"] == 1
+        assert children[2]["args"]["sequences"] == 1
+        assert children[4]["args"]["bytes"] > 0
+    assert ticks[0]["args"]["tokens"] == 5 and ticks[1]["args"]["tokens"] == 1
+    assert sum(s["args"]["finished"] for s in spans if s["name"] == "emit") == 1
+    first_admit = min((s for s in spans if s["name"] == "admit"), key=lambda s: s["ts_us"])
+    assert first_admit["args"]["admitted"] == 1
+
+
+def test_request_phase_spans_carry_the_tick_that_ran_them(make_engine):
+    spans = _serve_inline(
+        make_engine, lambda s: [s.submit([1, 2, 3], max_new_tokens=3),
+                                s.submit([4, 5, 6, 7], max_new_tokens=2)])
+    ticks = {s["args"]["tick"]: s for s in spans if s["cat"] == "sched" and s["name"] == "tick"}
+    phases = [s for s in spans if s["cat"] == "serving" and s["name"] in ("prefill", "decode")]
+    assert phases
+    for s in phases:
+        tick = ticks[s["args"]["tick"]]
+        assert tick["ts_us"] <= s["ts_us"] < tick["ts_us"] + tick["dur_us"]
+        assert {"uid", "tokens", "tick"} <= set(s["args"])  # what benchmark/spans.py reads
+
+
+def test_decode_loop_tick_is_named_for_its_dispatch(make_engine):
+    spans = _serve_inline(make_engine,
+                          lambda s: [s.submit([1, 2, 3], max_new_tokens=9)], decode_chunk=4)
+    kinds = [s["args"]["kind"] for s in spans if s["cat"] == "sched" and s["name"] == "tick"]
+    assert kinds[0] == "put" and "decode_loop" in kinds
+    loop = next(s for s in spans if s["cat"] == "inference" and s["name"] == "decode_loop")
+    assert loop["args"]["steps"] == 4
+    prepare = max((s for s in spans if s["name"] == "prepare" and s["ts_us"] <= loop["ts_us"]),
+                  key=lambda s: s["ts_us"])
+    assert prepare["ts_us"] + prepare["dur_us"] <= loop["ts_us"]
+    assert prepare["args"]["tokens"] == 4 and "allocated_blocks" in prepare["args"]
+
+
+def test_idle_polls_record_nothing_but_no_work(make_engine):
+    """A drained, running scheduler: no ``tick`` / ``admit`` / ``build_batch``
+    per idle poll, and one ``no_work`` span for many polls."""
+    import time
+
+    from deepspeed_tpu.serving.scheduler import _NO_WORK_SPAN_POLLS
+    telemetry.configure(telemetry.TelemetryConfig(enabled=True))
+    sched = ServingScheduler(make_engine(), ServingConfig(scheduler_tick_s=0.001))
+    time.sleep(0.15)
+    req = sched.submit([1, 2, 3], max_new_tokens=2)
+    assert req.stream.get(timeout=60) is not None
+    sched.stop()
+    spans = _sched_spans()
+    idle = [s for s in spans if s["cat"] == "sched" and s["name"] == "no_work"]
+    ticks = [s for s in spans if s["cat"] == "sched" and s["name"] == "tick"]
+    assert idle and len(ticks) == 2
+    before = [s for s in idle if s["ts_us"] + s["dur_us"] <= ticks[0]["ts_us"]]
+    # ~150 polls of 1 ms: a handful of spans, not one a poll
+    assert 1 <= len(before) <= 150 // _NO_WORK_SPAN_POLLS + 2
+    assert max(s["dur_us"] for s in before) >= 5_000
+    for s in idle:  # never over a tick
+        assert all(s["ts_us"] + s["dur_us"] <= t["ts_us"] or t["ts_us"] + t["dur_us"] <= s["ts_us"]
+                   for t in ticks)
+    assert len([s for s in spans if s["name"] == "admit"]) == len(ticks)
+
+
+def test_profiler_trace_shows_the_scheduler_threads_phases(make_engine, tmp_path):
+    """ISSUE acceptance: a ``jax.profiler`` trace of a serving run with
+    telemetry on carries ``dstpu.sched.*`` and ``dstpu.inference.*`` events on
+    the scheduler thread's line, nested, on the trace's own clock."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    telemetry.configure(telemetry.TelemetryConfig(enabled=True))
+    sched = ServingScheduler(make_engine(), ServingConfig())
+    warm = sched.submit([1, 2, 3], max_new_tokens=2)
+    while not warm.finished:
+        assert warm.stream.get(timeout=60) is not None or True
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        req = sched.submit([1, 2, 3, 4], max_new_tokens=3)
+        while req.stream.get(timeout=60) is not None:
+            pass
+    finally:
+        jax.profiler.stop_trace()
+        sched.stop()
+    (path, ) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    by_line = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("dstpu."):
+                    by_line.setdefault(line.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns, e.name, dict(e.stats)))
+    (events, ) = [v for v in by_line.values() if any(n == "dstpu.sched.tick" for _, _, n, _ in v)]
+    names = {n for _, _, n, _ in events}
+    assert {"dstpu.sched.tick", "dstpu.sched.admit", "dstpu.sched.build_batch",
+            "dstpu.sched.fetch", "dstpu.sched.emit", "dstpu.inference.prepare",
+            "dstpu.inference.put"} <= names
+    ticks = [e for e in events if e[2] == "dstpu.sched.tick"]
+    assert all("tick" in stats for _, _, _, stats in ticks)
+    for s, e, n, _ in events:
+        if n not in ("dstpu.sched.tick", "dstpu.sched.no_work"):
+            assert any(ts <= s and e <= te for ts, te, _, _ in ticks), n

@@ -3,6 +3,8 @@
 import json
 import time
 
+import pytest
+
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.telemetry import SpanRecorder, TelemetryConfig, TracingTimers
 from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer
@@ -97,3 +99,123 @@ def test_tracing_timers_wrap_wall_clock_timers():
     # the inner timer still accumulates (the engine's log() path keeps working)
     assert timers("fwd").elapsed(reset=False) > 0
     assert "fwd" in timers.get_timers()
+
+
+# ------------------------------------------- live spans in the profiler's trace --
+class _RecordingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: keeps what was
+    constructed, entered and exited."""
+    log = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.kwargs = name, kwargs
+        _RecordingAnnotation.log.append(("init", name, kwargs))
+
+    def __enter__(self):
+        _RecordingAnnotation.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        _RecordingAnnotation.log.append(("exit", self.name))
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax
+    _RecordingAnnotation.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _RecordingAnnotation)
+    return _RecordingAnnotation.log
+
+
+def test_live_span_is_also_a_trace_annotation(annotations):
+    rec = SpanRecorder()
+    args = {"tick": 7, "kind": "put", "uids": [1, 2]}
+    with rec.span("tick", cat="sched", args=args):
+        assert annotations[-1] == ("enter", "dstpu.sched.tick")
+        args["seqs"] = 2  # filled in by the block: the ring keeps it, the annotation is gone
+    assert annotations == [("init", "dstpu.sched.tick", {"tick": 7, "kind": "put"}),
+                           ("enter", "dstpu.sched.tick"), ("exit", "dstpu.sched.tick")]
+    (span, ) = rec.tail(1)
+    assert span["name"] == "tick" and span["cat"] == "sched"
+    assert span["args"] == {"tick": 7, "kind": "put", "uids": [1, 2], "seqs": 2}
+
+
+def test_nested_live_spans_nest_their_annotations(annotations):
+    rec = SpanRecorder()
+    with rec.span("tick", cat="sched"):
+        with rec.span("emit", cat="sched"):
+            pass
+    assert [a[:2] for a in annotations if a[0] != "init"] == [
+        ("enter", "dstpu.sched.tick"), ("enter", "dstpu.sched.emit"),
+        ("exit", "dstpu.sched.emit"), ("exit", "dstpu.sched.tick")]
+    inner, outer = rec.tail(2)
+    assert (inner["name"], outer["name"]) == ("emit", "tick")
+    assert outer["ts_us"] <= inner["ts_us"]
+    assert inner["ts_us"] + inner["dur_us"] <= outer["ts_us"] + outer["dur_us"]
+
+
+def test_record_writes_the_ring_only(annotations):
+    rec = SpanRecorder()
+    rec.record("queued", cat="serving", ts_us=1, dur_us=2)
+    assert annotations == [] and len(rec) == 1
+
+
+def test_live_span_with_telemetry_off_is_the_shared_null_context(annotations):
+    from deepspeed_tpu.telemetry import NULL_SPAN, live_span
+    assert live_span(None, "tick", "sched", None) is NULL_SPAN
+    with live_span(None, "tick", "sched"):
+        with live_span(None, "emit", "sched"):  # re-entrant
+            pass
+    assert annotations == []
+    rec = SpanRecorder()
+    with live_span(rec, "tick", "sched"):
+        pass
+    assert len(rec) == 1 and annotations[0][1] == "dstpu.sched.tick"
+
+
+def test_scheduler_tick_and_engine_put_touch_nothing_with_telemetry_off(annotations, monkeypatch):
+    """The off path: one ``None`` check per phase. No recorder call, no
+    registry call, no annotation constructed, by a scheduler tick or by an
+    engine ``put`` / ``decode_loop``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.engine_factory import build_engine
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import (AllocationMode,
+                                                                   DSStateManagerConfig,
+                                                                   MemoryConfig)
+    from deepspeed_tpu.models.llama import LlamaConfig, LlamaModel
+    from deepspeed_tpu.serving import ServingConfig, ServingScheduler
+
+    calls = []
+    for method in ("span", "record"):
+        monkeypatch.setattr(SpanRecorder, method,
+                            lambda self, *a, _m=method, **k: calls.append(_m))
+    cfg = LlamaConfig.tiny(dtype=jnp.float32)
+    params = {"model": LlamaModel(cfg).init(jax.random.PRNGKey(0),
+                                            jnp.zeros((1, 8), jnp.int32))["params"]}
+    mgr = DSStateManagerConfig(memory_config=MemoryConfig(mode=AllocationMode.ALLOCATE, size=32),
+                               max_context=128)
+    engine = build_engine(params, cfg,
+                          RaggedInferenceEngineConfig(state_manager=mgr, kv_block_size=16))
+    try:
+        np.asarray(engine.put([0], [np.arange(5, dtype=np.int32)]))
+        engine.decode_loop([0], [np.array([3], np.int32)], 2)
+        engine.flush(0)
+        sched = ServingScheduler(engine, ServingConfig(decode_chunk=2), start=False)
+        greedy = sched.submit([1, 2, 3], max_new_tokens=4)
+        sampled = sched.submit([1, 2, 3], max_new_tokens=3, temperature=0.8, seed=3)
+        for _ in range(50):
+            sched.step()
+            if greedy.finished and sampled.finished:
+                break
+        assert greedy.finished and sampled.finished
+        assert not sched.step()  # an idle poll
+        sched.stop(drain=False)
+    finally:
+        engine.close()
+    assert calls == [] and annotations == []
+    assert telemetry.get_registry().api_calls == 0
