@@ -55,7 +55,8 @@ class RaggedInferenceEngineConfig(DeepSpeedConfigModel):
 
     kv_block_size: int = 64
     # Pallas blocked-attention kernel (reference blocked_flash role):
-    # True/False force it; None = auto (TPU decode buckets)
+    # True/False force it; None = auto (on a TPU, every bucket of a model
+    # without a sliding window: modules/heuristics.py)
     use_paged_kernel: Optional[bool] = None
 
     simulated_gating: bool = False

@@ -230,6 +230,11 @@ class InferenceEngineV2:
 
         self._prepare_forward(spans, batch_uids, batch_tokens, do_checks, n_tokens)
         args = self._dispatch_args(spans, batch_uids, tokens=n_tokens)
+        if args is not None:
+            # the arm the bucket's program takes (modules/heuristics.py):
+            # paged_tiled / paged_token / xla_gather
+            args["attention"] = self._model.attention_arm(
+                self._batch.device_batch["tok_meta"].shape[1])
         with _tel_live_span(spans, "put", "inference", args):
             if observer is not None:
                 _t0 = _tel_now_us()

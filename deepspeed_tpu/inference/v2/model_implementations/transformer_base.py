@@ -525,40 +525,48 @@ class DSTransformerModelBase:
         it via its model config)."""
         return 0
 
-    def _use_paged_kernel(self, T: int) -> bool:
-        """Attention-implementation choice; delegates to the heuristics layer
+    def attention_arm(self, T: int) -> str:
+        """The attention arm a ``T``-token bucket takes (``paged_token`` /
+        ``paged_tiled`` / ``xla_gather``); delegates to the heuristics layer
         (reference modules/heuristics.py:36-165)."""
         from deepspeed_tpu.inference.v2.modules.heuristics import attention_implementation
-        return attention_implementation(self, self._engine_config, T) == "pallas_paged"
+        return attention_implementation(self, self._engine_config, T)
 
     def _paged_attention(self, q, k_new, v_new, cache, li, *, batch):
-        """Scatter new K/V into the paged cache, then attend each query token to
-        its sequence's full history (gather per-sequence K/V from the block
-        table — the XLA lowering of the reference's blocked flash kernel; a
-        Pallas kernel consuming the same layout can swap in here).
+        """Insert the new K/V into the paged cache and attend each query token
+        to its sequence's full history: the Pallas kernel walking the block
+        table (grid over tokens for a decode bucket, over query tiles for a
+        larger one), or the XLA arm (scatter, then gather per-sequence K/V).
 
         q: [T, H, D]; k_new/v_new: [T, KVH, D];
         cache: [L, 2, num_blocks, KVH, bs, D]."""
         import jax
 
-        token_seq = batch["token_seq"]
         token_pos = batch["token_pos"]
-        token_valid = batch["token_valid"]
 
         # scopes (under the caller's ``attn``): ``paged_kernel`` / ``kv_write``
         # + ``gather`` name the arm a device operation belongs to in the trace
-        if self._use_paged_kernel(q.shape[0]):
+        arm = self.attention_arm(q.shape[0])
+        if arm != "xla_gather":
             # fused KV-insert + blocked attention; the cache is aliased through
             # the kernel (an XLA-side scatter would copy it at the boundary)
             from jax.sharding import PartitionSpec as P
 
-            from deepspeed_tpu.ops.pallas.paged_attention import paged_attention_update
+            from deepspeed_tpu.ops.pallas import paged_attention
+
+            if arm == "paged_tiled":
+                update = paged_attention.paged_attention_prefill
+                meta = (batch["block_table"], batch["seq_seen"], batch["seq_ntok"],
+                        batch["last_tok"])
+            else:
+                update = paged_attention.paged_attention_update
+                meta = (batch["block_table"], batch["token_seq"], token_pos,
+                        batch["token_valid"])
 
             def kernel(q, k_new, v_new, cache, *meta):
-                return paged_attention_update(q, k_new, v_new, cache, li, *meta)
+                return update(q, k_new, v_new, cache, li, *meta)
 
-            args = (q, k_new, v_new, cache, batch["block_table"], token_seq, token_pos,
-                    token_valid)
+            args = (q, k_new, v_new, cache) + meta
             placed = None if self._state_manager is None else self._state_manager.kv_cache.sharding
             with jax.named_scope("paged_kernel"):
                 if placed is None or placed.mesh.size == 1:

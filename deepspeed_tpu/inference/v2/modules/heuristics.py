@@ -11,17 +11,25 @@ from deepspeed_tpu.utils.logging import logger
 
 
 def attention_implementation(model, engine_config, bucket_tokens: int) -> str:
-    """Pick the attention implementation for a (model, bucket) pair.
+    """Pick the attention arm for a (model, bucket) pair.
 
-    Returns "pallas_paged" (ops/pallas/paged_attention.py — the reference's
-    blocked_flash role) or "xla_gather" (dense per-batch gather). Policy:
+    Returns ``"paged_token"`` or ``"paged_tiled"`` — the Pallas kernel of
+    ops/pallas/paged_attention.py (the reference's blocked_flash role) on its
+    per-token grid (buckets of at most ``TOKEN_GRID_MAX`` = 32 tokens: decode
+    steps and ``decode_loop``) or its query-tile grid (every larger bucket:
+    prefill and mixed steps) — or ``"xla_gather"`` (scatter + dense
+    per-sequence gather: whole-pool layout copies every step on a TPU, PERF.md
+    §6 PR 24; what window models and the CPU run). Policy:
 
-    - an explicit ``use_paged_kernel`` config wins;
-    - the kernel needs a TPU backend, a decode-dominated bucket (its grid is
-      sequential per token — long prefills amortize better through one dense
-      gather), full-causal masking (the sliding-window walk is not implemented
-      in-kernel), and VMEM room for its double-buffered K/V chunks.
+    - a sliding-window model takes the gather arm: the window is only masked
+      there;
+    - an explicit ``use_paged_kernel`` config wins: kernel (grid by bucket) or
+      gather;
+    - otherwise the kernel needs a TPU backend and VMEM room for its
+      double-buffered K/V chunks and, on the tile grid, the tile's state.
     """
+    from deepspeed_tpu.ops.pallas.paged_attention import (CHUNK, TOKEN_GRID_MAX,
+                                                          tile_grid_vmem_bytes)
     flag = getattr(engine_config, "use_paged_kernel", None)
     if getattr(model, "attention_window", 0):
         # sliding window is only masked on the dense path — correctness beats
@@ -30,18 +38,20 @@ def attention_implementation(model, engine_config, bucket_tokens: int) -> str:
             logger.warning("use_paged_kernel=True ignored: the Pallas kernel has no "
                            "sliding-window mask; using the XLA gather path")
         return "xla_gather"
+    kernel = "paged_token" if bucket_tokens <= TOKEN_GRID_MAX else "paged_tiled"
     if flag is not None:
-        return "pallas_paged" if flag else "xla_gather"
+        return kernel if flag else "xla_gather"
     import jax
     if jax.default_backend() != "tpu":
         return "xla_gather"
-    if bucket_tokens > 32:
-        return "xla_gather"  # prefill-heavy bucket
-    from deepspeed_tpu.ops.pallas.paged_attention import CHUNK
     bs = engine_config.kv_block_size
     scratch_bytes = 2 * 2 * CHUNK * model.num_kv_heads * bs * model.head_dim * 2
-    if scratch_bytes > 8 * 1024 * 1024:  # leave headroom in ~16MB VMEM
-        logger.warning(f"paged kernel K/V scratch {scratch_bytes >> 20}MB exceeds VMEM "
-                       f"budget (kv_block_size={bs}); using the XLA gather path")
+    held = scratch_bytes if kernel == "paged_token" else tile_grid_vmem_bytes(
+        model.num_heads, model.num_kv_heads, model.head_dim, bs)
+    # of the ~16MB a kernel may use: half for the chunks, three quarters in all
+    if scratch_bytes > 8 * 1024 * 1024 or held > 12 * 1024 * 1024:
+        logger.warning(f"paged kernel K/V scratch {scratch_bytes >> 20}MB ({held >> 20}MB held "
+                       f"in all) exceeds VMEM budget (kv_block_size={bs}); using the XLA "
+                       f"gather path")
         return "xla_gather"
-    return "pallas_paged"
+    return kernel

@@ -3,24 +3,40 @@
 Reference role: ``deepspeed/inference/v2/kernels/ragged_ops/blocked_flash/
 blocked_flash.cpp:101`` + ``blocked_kv_rotary.cu:385`` (the KV insert) —
 attention that walks each sequence's block table instead of densifying
-history, so decode cost scales with *live* tokens, not the padded table
-width (VERDICT r2 weak #4).
+history, so cost scales with *live* tokens, not the padded table width or the
+pool's size (VERDICT r2 weak #4).
 
-TPU design, one fused kernel per layer:
+TPU design, one fused kernel per layer, its grid chosen from the bucket:
 
 - the paged cache is ALIASED in/out of the kernel (``input_output_aliases``)
-  and updated in place — an XLA-side scatter would force the multi-GB cache
-  to round-trip HBM at every pallas boundary (measured 74 ms/step for a 2 GB
-  cache vs 0.2 ms with in-kernel insert);
-- grid over the (bucket-padded) token dim, sequentially executed: program t
-  first DMAs its own new K/V tile into its sequence's block (so later tokens
-  of the same prefill read it), then walks the block table in CHUNKS of 8
-  blocks — 16 outstanding async DMAs double-buffered against the previous
-  chunk's online-softmax update;
-- a chunk's 8 ``[KVH, bs, D]`` tiles form a 128-lane ``[KVH, rep, 8*bs]``
-  logits tile — one VPU-native softmax step per chunk. Padding tokens have
-  zero blocks and skip everything; HBM traffic per token is its sequence's
-  live KV bytes, never the bucket ceiling.
+  and updated in place — an XLA-side scatter and block-table gather make the
+  compiler relayout the whole donated pool at the program's entry and exit and
+  copy one layer's K or V plane per gather (PERF.md §6, PR 24: 41 % of a
+  256-token step's device time on a 1.6 GB pool);
+- the block table is walked in CHUNKS of 8 blocks — 16 outstanding async DMAs
+  double-buffered against the previous chunk's online-softmax update. Padding
+  has zero blocks and skips everything; HBM traffic is the live KV bytes,
+  never the bucket ceiling.
+
+``paged_attention_update`` — decode buckets (≤ 32 tokens): grid over TOKENS,
+sequentially executed. Program t first DMAs its own new K/V row into its
+sequence's block (one full-block read-modify-write), then walks the table; a
+chunk's 8 ``[KVH, bs, D]`` tiles form a 128-lane ``[KVH, rep, 8*bs]`` logits
+tile — one VPU-native softmax step per chunk.
+
+``paged_attention_prefill`` — prefill and mixed buckets (> 32 tokens): grid
+over QUERY TILES of ``TQ`` consecutive tokens of the ragged batch, and inside a
+tile one pass per SEQUENCE that has tokens in it (a sequence's tokens are
+contiguous and in position order: ``ragged_wrapper.insert_sequence``; which
+rows are whose is computed in the kernel from ``seq_seen`` / ``seq_ntok`` /
+``last_tok``, the scalar prefetch). A pass inserts its rows into their blocks
+in place (at most ``TQ / bs + 1`` block read-modify-writes, the rows moved to
+their offsets by a 0/1 selection matmul), then walks the sequence's table
+ONCE: per KV head a ``[rep·TQ, 8·bs]`` logits tile on the MXU, operands in the
+wider of the queries' and the pool's dtype, float32 accumulation, causal mask
+from positions. A decode row
+riding along is a pass with one live query row; a tile with no tokens writes
+zeros and touches nothing.
 """
 
 import functools
@@ -32,6 +48,23 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 CHUNK = 8  # KV blocks fetched per loop iteration
+TQ = 64  # query rows of a tile (tiled mode); every bucket over 32 tokens is a multiple
+TOKEN_GRID_MAX = 32  # largest bucket the per-token grid takes
+
+
+def _stage_blocks(bs):
+    """Blocks that TQ consecutive positions can touch."""
+    return 1 + -(-(TQ - 1) // bs)
+
+
+def tile_grid_vmem_bytes(H, KVH, D, bs, itemsize=2):
+    """VMEM a query-tiled call holds: the double-buffered K/V chunks, the
+    insert's staging blocks, the grouped queries, the softmax state (a per-row
+    scalar pads to 128 lanes) and the pipelined q / out / new-K/V blocks."""
+    block = KVH * bs * D * itemsize
+    tile = H * TQ * D
+    return ((2 * 2 * CHUNK + 2 * _stage_blocks(bs)) * block + tile * itemsize + tile * 4
+            + 2 * H * TQ * 128 * 4 + 2 * 2 * (tile + KVH * TQ * D) * itemsize)
 
 
 def _kernel(li, S, MB, bs, rep, scale,
@@ -195,3 +228,240 @@ def paged_attention_update(q, k_new, v_new, cache, layer_idx, block_table, token
       token_pos.astype(jnp.int32), token_valid.astype(jnp.int32),
       q, k_new.astype(cache.dtype), v_new.astype(cache.dtype), cache)
     return out, new_cache
+
+
+def _tiled_kernel(S, MB, bs, rep, scale, precision,
+                  # scalar prefetch
+                  layer_ref, table_ref, seen_ref, ntok_ref, last_ref,
+                  # inputs
+                  q_ref, kn_ref, vn_ref, cache_ref,
+                  # outputs
+                  out_ref, cache_out_ref,
+                  # scratch
+                  q_s, m_s, l_s, acc_s, k_buf, v_buf, kv_stage, sems, wsem):
+    # Loops over heads and blocks are ``fori_loop``s, not Python loops: one
+    # kernel is traced and lowered for every bucket's program at every start
+    # of the server, and an unrolled body costs that 8 x over.
+    t0 = pl.program_id(0) * TQ
+    li = layer_ref[0]
+    KVH, D = k_buf.shape[1], k_buf.shape[3]
+    dtype = k_buf.dtype  # the pool's
+    op_dtype = q_s.dtype  # the matmuls' operands: the wider of the queries' and the pool's
+
+    def each(n, fn):
+        jax.lax.fori_loop(0, n, lambda i, carry: fn(i), None)
+
+    def head_rows(h):
+        """Head h's TQ rows of its KV group's ``[rep*TQ, ·]`` slab, and its lanes
+        of a token-major ``[TQ, H*D]`` block."""
+        return (h // rep, pl.ds(pl.multiple_of((h % rep) * TQ, TQ), TQ)), \
+            pl.ds(pl.multiple_of(h * D, D), D)
+
+    def group_queries(h):
+        # row r*TQ + t of q_s[g] is token t under head g*rep + r
+        slab, lanes = head_rows(h)
+        q_s[slab] = q_ref[:, lanes]
+
+    each(KVH * rep, group_queries)
+    m_s[...] = jnp.full_like(m_s, NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+
+    row_tok = t0 + jnp.bitwise_and(
+        jax.lax.broadcasted_iota(jnp.int32, (rep * TQ, 1), 0), TQ - 1)
+    tile_tok = t0 + jax.lax.broadcasted_iota(jnp.int32, (1, TQ), 1)
+
+    def one_sequence(s, lo, hi, shift):
+        """Tokens lo..hi of the batch (inside this tile) belong to sequence
+        ``s``; token t sits at position t + shift."""
+        p_lo, p_hi = lo + shift, hi + shift
+        b0 = p_lo // bs
+        b1 = jnp.minimum(p_hi // bs, MB - 1)
+
+        def block_id(b):
+            return jnp.maximum(table_ref[s, jnp.minimum(b, MB - 1)], 0)
+
+        # ---- insert the rows into their blocks, in place ---------------------
+        def stage_copy(j, kv, write):
+            block, stage = cache_out_ref.at[li, kv, block_id(b0 + j)], kv_stage.at[j, kv]
+            src, dst = (stage, block) if write else (block, stage)
+            return pltpu.make_async_copy(src, dst, wsem.at[j, kv])
+
+        n_touched = b1 - b0 + 1  # <= kv_stage.shape[0]
+        tok_pos = jnp.where((tile_tok >= lo) & (tile_tok <= hi), tile_tok + shift, -1)
+
+        def fetch(j):
+            stage_copy(j, 0, False).start()
+            stage_copy(j, 1, False).start()
+
+        def merge(j):
+            stage_copy(j, 0, False).wait()
+            stage_copy(j, 1, False).wait()
+            slot_pos = (b0 + j) * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, TQ), 0)
+            # 0/1 selection: block row <- the tile row at that position (a dynamic
+            # sublane shift Mosaic cannot prove aligned, done exactly on the MXU)
+            sel = (slot_pos == tok_pos).astype(dtype)
+            written = (slot_pos[:, :1] >= p_lo) & (slot_pos[:, :1] <= p_hi)
+
+            def head(g):
+                lanes = pl.ds(pl.multiple_of(g * D, D), D)
+                for kv, new_ref in enumerate((kn_ref, vn_ref)):
+                    rows = jnp.dot(sel, new_ref[:, lanes], preferred_element_type=jnp.float32,
+                                   precision=precision).astype(dtype)
+                    kv_stage[j, kv, g] = jnp.where(written, rows, kv_stage[j, kv, g])
+
+            each(KVH, head)
+            stage_copy(j, 0, True).start()
+            stage_copy(j, 1, True).start()
+
+        def landed(j):
+            stage_copy(j, 0, True).wait()
+            stage_copy(j, 1, True).wait()
+
+        each(n_touched, fetch)
+        each(n_touched, merge)
+        each(n_touched, landed)
+
+        # ---- walk the block table once, double-buffered chunks --------------
+        nchunks = pl.cdiv(b1 + 1, CHUNK)
+        # a row of another sequence (or of padding) sees no key
+        q_pos = jnp.where((row_tok >= lo) & (row_tok <= hi), row_tok + shift, -1)
+
+        def chunk_copies(c, slot, j):
+            bid = block_id(c * CHUNK + j)
+            rows = pl.ds(pl.multiple_of(j * bs, bs), bs)
+            return (pltpu.make_async_copy(cache_out_ref.at[li, 0, bid], k_buf.at[slot, :, rows],
+                                          sems.at[0, slot, j]),
+                    pltpu.make_async_copy(cache_out_ref.at[li, 1, bid], v_buf.at[slot, :, rows],
+                                          sems.at[1, slot, j]))
+
+        def start_chunk(c, slot):
+            def start(j):
+                for cp in chunk_copies(c, slot, j):
+                    cp.start()
+            each(CHUNK, start)
+
+        def wait_chunk(c, slot):
+            def wait(j):
+                for cp in chunk_copies(c, slot, j):
+                    cp.wait()
+            each(CHUNK, wait)
+
+        start_chunk(0, 0)
+
+        def chunk(c):
+            slot = jax.lax.rem(c, 2)
+
+            @pl.when(c + 1 < nchunks)
+            def _():
+                start_chunk(c + 1, 1 - slot)
+
+            wait_chunk(c, slot)
+            kv_pos = c * (CHUNK * bs) + jax.lax.broadcasted_iota(
+                jnp.int32, (1, CHUNK * bs), 1)
+            mask = kv_pos <= q_pos  # [rep*TQ, CHUNK*bs]
+
+            def head(g):
+                logits = jax.lax.dot_general(
+                    q_s[g], k_buf[slot, g].astype(op_dtype), (((1, ), (1, )), ((), ())),
+                    preferred_element_type=jnp.float32, precision=precision) * scale
+                # masked logits sit BELOW the running max's floor, so their exp
+                # is 0 even for a row that has seen no key yet
+                logits = jnp.where(mask, logits, 2 * NEG_INF)
+                m_prev = m_s[g]
+                m_new = jnp.maximum(m_prev, logits.max(axis=1, keepdims=True))
+                p = jnp.exp(logits - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_s[g] = l_s[g] * alpha + p.sum(axis=1, keepdims=True)
+                acc_s[g] = acc_s[g] * alpha + jnp.dot(
+                    p.astype(op_dtype), v_buf[slot, g].astype(op_dtype),
+                    preferred_element_type=jnp.float32, precision=precision)
+                m_s[g] = m_new
+
+            each(KVH, head)
+
+        each(nchunks, chunk)
+
+    def sequence(s):
+        n, last = ntok_ref[s], last_ref[s]
+        first = last - n + 1
+        lo, hi = jnp.maximum(first, t0), jnp.minimum(last, t0 + TQ - 1)
+
+        @pl.when((n > 0) & (lo <= hi))
+        def _():
+            one_sequence(s, lo, hi, seen_ref[s] - first)
+
+    each(S, sequence)
+
+    def write_out(h):
+        slab, lanes = head_rows(h)
+        out_ref[:, lanes] = (acc_s[slab] / jnp.maximum(l_s[slab], 1e-20)).astype(out_ref.dtype)
+
+    each(KVH * rep, write_out)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", ), donate_argnums=(3, ))
+def paged_attention_prefill(q, k_new, v_new, cache, layer_idx, block_table, seq_seen,
+                            seq_ntok, last_tok, interpret=None):
+    """Fused KV-insert + blocked attention for one layer, query-tiled: for the
+    buckets of more than ``TOKEN_GRID_MAX`` tokens (a multiple of ``TQ``).
+
+    q: [T, H, D]; k_new/v_new: [T, KVH, D]; cache: [L, 2, NB, KVH, bs, D]
+    (donated; updated in place); block_table: [S, MB]; seq_seen / seq_ntok /
+    last_tok: [S] — sequence s holds tokens ``last_tok - seq_ntok + 1 ..
+    last_tok`` of the batch at positions ``seq_seen ..``; a slot with
+    ``seq_ntok <= 0`` is empty. ``layer_idx`` is an operand, not a constant:
+    a model's layers share ONE kernel in the compiled program. Returns
+    (attn_out [T, H, D], cache); rows of no sequence are zero."""
+    T, H, D = q.shape
+    L, _, NB, KVH, bs, Dc = cache.shape
+    assert D == Dc and H % KVH == 0 and T % TQ == 0
+    S, MB = block_table.shape
+    rep = H // KVH
+    scale = 1.0 / (D**0.5)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    op_dtype = jnp.promote_types(q.dtype, cache.dtype)
+    # float32 operands (tests, a float32 pool) must not pass through bf16 on the MXU
+    precision = jax.lax.Precision.HIGHEST if op_dtype == jnp.float32 else None
+    n_stage = _stage_blocks(bs)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(T // TQ, ),
+        in_specs=[
+            pl.BlockSpec((TQ, H * D), lambda i, *_: (i, 0)),
+            pl.BlockSpec((TQ, KVH * D), lambda i, *_: (i, 0)),
+            pl.BlockSpec((TQ, KVH * D), lambda i, *_: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # cache in HBM, aliased in/out
+        ],
+        out_specs=[
+            pl.BlockSpec((TQ, H * D), lambda i, *_: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((KVH, rep * TQ, D), op_dtype),           # head-grouped queries
+            pltpu.VMEM((KVH, rep * TQ, 1), jnp.float32),        # running max
+            pltpu.VMEM((KVH, rep * TQ, 1), jnp.float32),        # running sum
+            pltpu.VMEM((KVH, rep * TQ, D), jnp.float32),        # accumulator
+            pltpu.VMEM((2, KVH, CHUNK * bs, D), cache.dtype),
+            pltpu.VMEM((2, KVH, CHUNK * bs, D), cache.dtype),
+            pltpu.VMEM((n_stage, 2, KVH, bs, D), cache.dtype),
+            pltpu.SemaphoreType.DMA((2, 2, CHUNK)),
+            pltpu.SemaphoreType.DMA((n_stage, 2)),
+        ],
+    )
+    kernel = functools.partial(_tiled_kernel, S, MB, bs, rep, scale, precision)
+    out, new_cache = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((T, H * D), q.dtype),
+                   jax.ShapeDtypeStruct(cache.shape, cache.dtype)],
+        input_output_aliases={8: 1},  # cache operand (after 5 scalar-prefetch args)
+        interpret=interpret,
+        name="paged_attention_prefill",
+    )(jnp.asarray(layer_idx, jnp.int32).reshape(1), block_table.astype(jnp.int32),
+      seq_seen.astype(jnp.int32), seq_ntok.astype(jnp.int32), last_tok.astype(jnp.int32),
+      q.astype(op_dtype).reshape(T, H * D), k_new.astype(cache.dtype).reshape(T, KVH * D),
+      v_new.astype(cache.dtype).reshape(T, KVH * D), cache)
+    return out.reshape(T, H, D), new_cache

@@ -54,6 +54,10 @@ def lowered(models):
             out[family, arm] = engine.lower_forward().as_text(debug_info=True)
             if family == "mixtral" and arm == "gather":
                 out[family, "loop"] = engine.lower_decode_loop(2).as_text(debug_info=True)
+            if family == "llama" and arm == "kernel":
+                # a prefill bucket (64 tokens, 8 sequences, 4 blocks): the query-tiled grid
+                out[family, "kernel-prefill"] = engine.lower_forward((64, 8, 4)).as_text(
+                    debug_info=True)
             engine.close()
     return out
 
@@ -61,6 +65,7 @@ def lowered(models):
 @pytest.mark.parametrize("family,arm,scope", [
     ("llama", "gather", "embed"), ("llama", "gather", "attn"), ("llama", "gather", "attn/kv_write"),
     ("llama", "gather", "attn/gather"), ("llama", "kernel", "attn/paged_kernel"),
+    ("llama", "kernel-prefill", "attn/paged_kernel"),
     ("llama", "gather", "mlp"), ("llama", "gather", "unembed"),
     ("mixtral", "gather", "attn"), ("mixtral", "gather", "moe/route"),
     ("mixtral", "gather", "moe/dispatch"), ("mixtral", "gather", "moe/experts"),
@@ -77,6 +82,11 @@ def test_put_program_carries_the_scope(lowered, family, arm, scope):
 def test_scopes_name_no_layer_and_dense_models_have_no_moe(lowered):
     assert "/moe/" not in lowered["llama", "gather"] and "/mlp/" not in lowered["mixtral", "gather"]
     assert "/attn/gather/" not in lowered["llama", "kernel"]
+    # a prefill bucket on the kernel arm: the tiled kernel, no scatter, no gather
+    prefill = lowered["llama", "kernel-prefill"]
+    # (interpret mode inlines the kernel: its body's locations name the function)
+    assert "_tiled_kernel" in prefill and "_tiled_kernel" not in lowered["llama", "kernel"]
+    assert "/attn/gather/" not in prefill and "/attn/kv_write/" not in prefill
     assert "layers_0/" not in lowered["mixtral", "gather"]  # one row a kind, whatever the layer
 
 

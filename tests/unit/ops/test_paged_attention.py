@@ -8,7 +8,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.ops.pallas.paged_attention import paged_attention_update
+from deepspeed_tpu.ops.pallas.paged_attention import (paged_attention_prefill,
+                                                      paged_attention_update)
 
 
 def _dense_reference(q, cache, li, table, token_seq, token_pos, token_valid):
@@ -87,6 +88,71 @@ def test_paged_attention_matches_dense(kvh):
     np.testing.assert_allclose(np.asarray(cache2), exp_cache, rtol=0, atol=0)
 
 
+# tiled mode: (seen, new tokens) per sequence, in batch order; bucket tokens
+TILED_BATCHES = {
+    # one sequence's chunk starting mid-block (seen > 0) and crossing block
+    # boundaries (16-token blocks) and the tile boundary at token 64
+    "chunk-crossing-blocks": ([(37, 70)], 128),
+    # a 2-tile chunk with decode rows riding along, before and after it
+    "chunk-plus-decode-rows": ([(3, 1), (20, 100), (33, 1), (0, 1)], 128),
+    # a whole tile (tokens 64..127) of padding
+    "padding-tile": ([(0, 50)], 128),
+    "first-prefill-one-tile": ([(0, 64)], 64),
+}
+
+
+@pytest.mark.parametrize("kvh", [4, 2])  # MHA and GQA
+@pytest.mark.parametrize("batch", list(TILED_BATCHES))
+def test_paged_attention_prefill_matches_dense(kvh, batch):
+    """The query-tiled grid against the dense reference, and the pool's blocks:
+    every inserted row lands, every other element is bit-identical."""
+    seqs, T = TILED_BATCHES[batch]
+    rng = np.random.default_rng(0)
+    L, NB, bs, D, H = 2, 40, 16, 128, 4
+    S, MB = 8, 8
+    cache0 = rng.normal(size=(L, 2, NB, kvh, bs, D)).astype(np.float32)
+    # distinct blocks per sequence; the pool's LAST block belongs to nobody
+    free = list(rng.permutation(NB - 1))
+    table = np.full((S, MB), -1, np.int32)
+    token_seq = np.full(T, S - 1, np.int32)
+    token_pos = np.zeros(T, np.int32)
+    token_valid = np.zeros(T, np.int32)
+    seq_seen, seq_ntok, last_tok = (np.zeros(S, np.int32) for _ in range(3))
+    cursor = 0
+    for s, (seen, n) in enumerate(seqs):
+        for b in range(-(-(seen + n) // bs)):
+            table[s, b] = free.pop()
+        token_seq[cursor:cursor + n] = s
+        token_pos[cursor:cursor + n] = np.arange(seen, seen + n)
+        token_valid[cursor:cursor + n] = 1
+        cursor += n
+        seq_seen[s], seq_ntok[s], last_tok[s] = seen, n, cursor - 1
+    q = rng.normal(size=(T, H, D)).astype(np.float32)
+    k_new = rng.normal(size=(T, kvh, D)).astype(np.float32)
+    v_new = rng.normal(size=(T, kvh, D)).astype(np.float32)
+
+    exp_cache = cache0.copy()
+    for t in range(cursor):
+        bid = table[token_seq[t], token_pos[t] // bs]
+        exp_cache[:, 0, bid, :, token_pos[t] % bs] = k_new[t]
+        exp_cache[:, 1, bid, :, token_pos[t] % bs] = v_new[t]
+
+    cache = jnp.asarray(cache0)
+    for li in range(L):
+        got, cache = paged_attention_prefill(q, k_new, v_new, cache, li, table, seq_seen,
+                                             seq_ntok, last_tok)
+        want = _dense_reference(q, exp_cache, li, table, token_seq, token_pos, token_valid)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+        assert not np.any(np.asarray(got)[cursor:])  # padding rows are zero
+    np.testing.assert_array_equal(np.asarray(cache), exp_cache)
+
+    # no live sequence: no output, no cache mutation
+    out2, cache2 = paged_attention_prefill(q, k_new, v_new, jnp.asarray(exp_cache), 0, table,
+                                           seq_seen, np.zeros(S, np.int32), last_tok)
+    assert not np.any(np.asarray(out2))
+    np.testing.assert_array_equal(np.asarray(cache2), exp_cache)
+
+
 def test_padding_tokens_never_corrupt_last_block():
     """Regression (code-review r3): -1 scatter indices WRAP in jax; padding
     tokens must route to a positive OOB sentinel or they overwrite block NB-1
@@ -114,9 +180,12 @@ def test_padding_tokens_never_corrupt_last_block():
     np.testing.assert_array_equal(last_block_after, last_block_before)
 
 
-def test_engine_kernel_vs_dense_path():
+@pytest.mark.parametrize("prompt_tokens", [21, 90], ids=["token-grid", "tiled-prefill"])
+def test_engine_kernel_vs_dense_path(prompt_tokens):
     """Full engine equivalence: forcing the Pallas kernel must reproduce the
-    XLA gather path's logits through prefill + decode."""
+    XLA gather path's logits through prefill + decode. 21 tokens are a bucket
+    of 32 (the per-token grid); 90 are a bucket of 128 (the query-tiled grid,
+    two tiles crossing five 16-token blocks), then decode rows on the token grid."""
     from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
     from deepspeed_tpu.inference.v2.engine_factory import build_engine
     from deepspeed_tpu.inference.v2.ragged.manager_configs import (AllocationMode,
@@ -136,7 +205,7 @@ def test_engine_kernel_vs_dense_path():
                                            use_paged_kernel=kernel)
 
     rng = np.random.default_rng(1)
-    prompt = rng.integers(0, cfg.vocab_size, 21)
+    prompt = rng.integers(0, cfg.vocab_size, prompt_tokens)
 
     outs = {}
     for kernel in (False, True):
@@ -148,6 +217,37 @@ def test_engine_kernel_vs_dense_path():
         outs[kernel] = logits
     for a, b in zip(outs[False], outs[True]):
         np.testing.assert_allclose(a, b, rtol=3e-5, atol=3e-5)
+
+
+def test_engine_mixed_put_kernel_vs_gather_path():
+    """One ``put`` carrying a decode row and another sequence's second chunk
+    (``seq_seen`` > 0, 70 tokens: a bucket of 128 on the query-tiled grid):
+    the kernel arm's logits are the gather arm's."""
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.engine_factory import build_engine
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import (AllocationMode,
+                                                                   DSStateManagerConfig,
+                                                                   MemoryConfig)
+    from deepspeed_tpu.models.llama import LlamaConfig, init_params
+    from deepspeed_tpu.utils import groups
+
+    groups.initialize_mesh(force=True)
+    cfg = LlamaConfig.tiny(dtype=jnp.float32)
+    _, params = init_params(cfg)
+    rng = np.random.default_rng(5)
+    first, chunk_a, chunk_b = (rng.integers(0, cfg.vocab_size, n) for n in (19, 40, 70))
+
+    outs = {}
+    for kernel in (False, True):
+        mgr = DSStateManagerConfig(memory_config=MemoryConfig(mode=AllocationMode.ALLOCATE,
+                                                              size=64), max_context=512)
+        eng = build_engine(params, cfg, RaggedInferenceEngineConfig(
+            state_manager=mgr, kv_block_size=16, use_paged_kernel=kernel))
+        assert eng.model.attention_arm(128) == ("paged_tiled" if kernel else "xla_gather")
+        nxt = int(np.argmax(np.asarray(eng.put([0], [first]))[0]))
+        eng.put([1], [chunk_a])
+        outs[kernel] = np.asarray(eng.put([0, 1], [np.asarray([nxt]), chunk_b]))
+    np.testing.assert_allclose(outs[False], outs[True], rtol=3e-5, atol=3e-5)
 
 
 def test_decode_loop_kernel_vs_gather_path():

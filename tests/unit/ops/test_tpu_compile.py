@@ -75,24 +75,34 @@ def _device_bytes(compiled):
 H, KVH, D, BS = 32, 8, 128, 64
 
 
-@pytest.mark.parametrize("tokens,max_blocks", [(8, 4), (32, 16)],
-                         ids=["decode-bucket", "largest-kernel-bucket"])
+@pytest.mark.parametrize("tokens,max_blocks", [(8, 4), (32, 16), (64, 16), (256, 16)],
+                         ids=["decode-bucket", "largest-token-grid-bucket",
+                              "smallest-tiled-bucket", "rag-chunk-bucket"])
 def test_paged_attention_update_compiles(v5e, sizes, tokens, max_blocks):
-    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention_update
+    """Both grids of the paged kernel at the benchmark configuration's head
+    shapes: per token up to 32 tokens, query-tiled above."""
+    from deepspeed_tpu.ops.pallas import paged_attention
     serve, _ = sizes
     one = SingleDeviceSharding(v5e[0])
     on = functools.partial(_on, one)
+    tiled = tokens > paged_attention.TOKEN_GRID_MAX
+    seqs = 16 if tiled else 8
 
     def step(q, k, v, cache, *meta):
-        return paged_attention_update(q, k, v, cache, 1, *meta)
+        update = paged_attention.paged_attention_prefill if tiled \
+            else paged_attention.paged_attention_update
+        return update(q, k, v, cache, 1, *meta)
 
+    meta = (on((seqs, ), jnp.int32), ) * 3 if tiled else \
+        (on((tokens, ), jnp.int32), on((tokens, ), jnp.int32), on((tokens, ), jnp.bool_))
     compiled = jax.jit(step, donate_argnums=(3, )).lower(
         on((tokens, H, D), jnp.bfloat16), on((tokens, KVH, D), jnp.bfloat16),
         on((tokens, KVH, D), jnp.bfloat16),
         on((serve.layers, 2, serve.kv_blocks, KVH, BS, D), jnp.bfloat16),
-        on((8, max_blocks), jnp.int32), on((tokens, ), jnp.int32), on((tokens, ), jnp.int32),
-        on((tokens, ), jnp.bool_)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        on((seqs, max_blocks), jnp.int32), *meta).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("paged_attention_prefill" if tiled else "paged_attention_update") in text
 
 
 def test_flash_forward_and_backward_compile(v5e, sizes):
@@ -156,14 +166,53 @@ def _serve_args(device, sizes, abstract, bucket):
     return one, params, cache, batch
 
 
-@pytest.mark.parametrize("bucket,kernel", [((8, 8, 4), True), ((128, 8, 4), False)],
+def _pool_sized_results(text, pool_shape):
+    """``copy`` / ``slice`` / ``bitcast-slice fusion`` instructions of a compiled
+    program whose result has the KV pool's shape or one layer's K or V plane's:
+    what the XLA gather arm costs a step (PERF.md §6, PR 24), and the kernel
+    arm, which aliases the pool through, must not."""
+    import re
+    plane = tuple(pool_shape[2:])
+    shapes = {",".join(map(str, shape)) for shape in (pool_shape, plane, (1, 1) + plane)}
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.-]+) = \w+\[([\d,]*)\][^ ]* (\w[\w-]*)\(", line)
+        if m and m.group(2) in shapes and (
+                m.group(3) in ("copy", "slice", "dynamic-slice")
+                or (m.group(3) == "fusion" and re.match(r"(copy|slice)", m.group(1)))):
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("bucket,kernel", [((8, 8, 4), "paged_attention_update"),
+                                           ((128, 8, 4), "paged_attention_prefill")],
                          ids=["decode-bucket", "prefill-bucket"])
 def test_serve_put_program_fits_one_chip(v5e, sizes, serve_model, bucket, kernel):
+    serve, _ = sizes
     model, abstract = serve_model
     _, params, cache, batch = _serve_args(v5e[0], sizes, abstract, bucket)
     compiled = jax.jit(model._forward_impl, donate_argnums=(1, )).lower(params, cache, batch).compile()
-    assert ("tpu_custom_call" in compiled.as_text()) == kernel  # what heuristics.py chose
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and kernel in text  # what heuristics.py chose
     assert _device_bytes(compiled) < HBM_BYTES
+    # the pool is aliased through every layer's kernel: the compiler neither
+    # copies it nor cuts a plane out of it
+    assert not _pool_sized_results(text, cache.shape)
+
+
+def test_gather_arm_program_copies_the_pool(v5e, sizes, serve_model):
+    """The same prefill bucket on the XLA gather arm, as a check OF the check
+    above: the compiler answers its scatter and gather with pool-sized copies
+    (what every step over 32 tokens paid before PR 24)."""
+    import copy
+    model, abstract = serve_model
+    gather = copy.copy(model)
+    gather._engine_config = model._engine_config.model_copy(update={"use_paged_kernel": False})
+    _, params, cache, batch = _serve_args(v5e[0], sizes, abstract, (128, 8, 4))
+    text = jax.jit(gather._forward_impl, donate_argnums=(1, )).lower(
+        params, cache, batch).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert _pool_sized_results(text, cache.shape)
 
 
 def test_serve_decode_loop_program_fits_one_chip(v5e, sizes, serve_model):

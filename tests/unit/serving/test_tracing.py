@@ -282,6 +282,32 @@ def test_tick_spans_hold_their_phases_in_order(make_engine):
     assert first_admit["args"]["admitted"] == 1
 
 
+@pytest.mark.parametrize("use_paged_kernel,prompt_tokens,arm", [
+    (None, 5, "xla_gather"), (True, 5, "paged_token"), (True, 40, "paged_tiled")])
+def test_put_span_names_the_attention_arm_its_bucket_took(llama_setup, use_paged_kernel,
+                                                          prompt_tokens, arm):
+    """``inference.put``'s ``attention`` arg: what modules/heuristics.py chose
+    for the bucket the batch was padded to (40 tokens are a bucket of 64: over
+    the per-token grid's 32)."""
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.engine_factory import build_engine
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import (AllocationMode,
+                                                                   DSStateManagerConfig,
+                                                                   MemoryConfig)
+    cfg, _, params = llama_setup
+    telemetry.configure(telemetry.TelemetryConfig(enabled=True))
+    mgr = DSStateManagerConfig(memory_config=MemoryConfig(mode=AllocationMode.ALLOCATE, size=16),
+                               max_context=128)
+    engine = build_engine(params, cfg, RaggedInferenceEngineConfig(
+        state_manager=mgr, kv_block_size=16, use_paged_kernel=use_paged_kernel))
+    try:
+        engine.put([0], [np.arange(prompt_tokens) % cfg.vocab_size])
+        put = [s for s in _sched_spans() if s["cat"] == "inference" and s["name"] == "put"]
+        assert [s["args"]["attention"] for s in put] == [arm]
+    finally:
+        engine.close()
+
+
 def test_request_phase_spans_carry_the_tick_that_ran_them(make_engine):
     spans = _serve_inline(
         make_engine, lambda s: [s.submit([1, 2, 3], max_new_tokens=3),
