@@ -51,6 +51,10 @@ class ServeSizes:
     short_prompt: int = 40
     long_prompt: int = 200     # > token_budget, and prompt + new tokens stay inside 4 blocks
     compare_steps: int = 8
+    # the sliding-window check: Mistral-7B widths with the window cut to 512 so
+    # that a prompt of window + two feeds + 40 stays inside max_context
+    window: int = 512
+    window_prompt: int = 808
 
 
 @dataclass(frozen=True)
@@ -161,12 +165,16 @@ def check_logits_close(what, ref, other, rel_tol=LOGIT_REL_TOL):
           f"{int(same.sum())}/{same.size} positions, {int((~decided).sum())} inside the margin")
 
 
-def greedy_chain(engine, uid, prompt, steps, feed=None):
-    """Prefill ``prompt`` and decode ``steps`` tokens one ``put`` at a time.
-    Returns (tokens fed back, logits [1 + steps, vocab]). With ``feed`` the next
-    token is taken from it (teacher forcing: the same inputs on two engines, so
-    every position compares), otherwise it is this engine's own argmax."""
-    rows = [np.asarray(engine.put([uid], [np.asarray(prompt)]))[0]]
+def greedy_chain(engine, uid, prompt, steps, feed=None, budget=None):
+    """Prefill ``prompt`` (in chunks of ``budget`` tokens, if given) and decode
+    ``steps`` tokens one ``put`` at a time. Returns (tokens fed back, logits
+    [1 + steps, vocab]: the last prefill chunk's row first). With ``feed`` the
+    next token is taken from it (teacher forcing: the same inputs on two engines,
+    so every position compares), otherwise it is this engine's own argmax."""
+    prompt = np.asarray(prompt)
+    for at in range(0, prompt.size, budget or prompt.size):
+        row = np.asarray(engine.put([uid], [prompt[at:at + (budget or prompt.size)]]))[0]
+    rows = [row]
     tokens = []
     for j in range(steps):
         nxt = int(feed[j]) if feed is not None else int(rows[-1].argmax())
@@ -225,10 +233,11 @@ def _check_done(what, doc, n_tokens):
                              f"{doc and {k: doc[k] for k in ('state', 'n_tokens', 'error')}}")
 
 
-def serve_phase(meter, seed, sizes=ServeSizes(), config=None):
+def serve_phase(meter, seed, sizes=ServeSizes(), config=None, window_config=None):
     """Mixtral widths through engine → scheduler → HTTP server; kernel path
-    against gather path. ``config`` replaces the published-width config (the
-    CPU rehearsal passes a tiny one)."""
+    against gather path; then :func:`window_check`. ``config`` and
+    ``window_config`` replace the published-width configs (the CPU rehearsal
+    passes tiny ones)."""
     import jax
 
     from deepspeed_tpu.inference.v2.engine_factory import build_engine
@@ -354,7 +363,58 @@ def serve_phase(meter, seed, sizes=ServeSizes(), config=None):
             break
     engine.close()
     reference.close()
+    del engine, reference, scheduler, server, params
+    gc.collect()
+    window_check(meter, seed, sizes, config=window_config)
     _report_peak("serve")
+
+
+def window_check(meter, seed, sizes=ServeSizes(), config=None):
+    """A sliding-window model past its window: the kernel (tile grid for the
+    prompt's chunks, token grid for the decode steps, the pool releasing blocks
+    as the window passes them) against the gather arm, same weights and inputs.
+    Mistral-7B-v0.1 widths, one layer; the window is ``sizes.window``, not the
+    published 4096, so that the check stays inside ``sizes.max_context``."""
+    import jax
+
+    from deepspeed_tpu.inference.v2.engine_factory import build_engine
+    from deepspeed_tpu.models import llama
+
+    cfg = config or llama.LlamaConfig(
+        vocab_size=32000, hidden_size=4096, intermediate_size=14336, num_hidden_layers=1,
+        num_attention_heads=32, num_key_value_heads=8, rope_theta=10000.0,
+        max_position_embeddings=32768, model_type="mistral", sliding_window=sizes.window,
+        remat=False)
+    window, n = cfg.sliding_window, sizes.window_prompt
+    print(f"  window model: Mistral widths hidden={cfg.hidden_size} heads="
+          f"{cfg.num_attention_heads}/{cfg.num_key_value_heads}, {cfg.num_hidden_layers} layer, "
+          f"window {window}; a {n}-token prompt in chunks of {sizes.token_budget}", flush=True)
+    if n <= window + sizes.token_budget:
+        raise AssertionError("the window check's prompt does not pass the window")
+    with meter.step("window model weights"):
+        _, params = llama.init_params(cfg, rng=jax.random.PRNGKey(seed), param_dtype=cfg.dtype)
+        jax.block_until_ready(params)
+    (prompt, ) = _prompts(seed + 1, cfg.vocab_size, (n, ))
+    engine = build_engine(params, cfg, _engine_config(sizes))
+    reference = build_engine(params, cfg, _engine_config(sizes, use_paged_kernel=False))
+    arms = {engine.model.attention_arm(t) for t in (8, sizes.token_budget)}
+    if jax.default_backend() == "tpu" and arms != {"paged_token", "paged_tiled"}:
+        raise AssertionError(f"on a TPU a window model must take the kernel, took {arms}")
+    free = engine.free_blocks
+    with meter.step("window model: kernel path vs gather path past the window"):
+        k_tokens, k_logits = greedy_chain(engine, 200, prompt, sizes.compare_steps,
+                                          budget=sizes.token_budget)
+        _, g_logits = greedy_chain(reference, 200, prompt, sizes.compare_steps, feed=k_tokens,
+                                   budget=sizes.token_budget)
+    check_logits_close("window model, kernel vs gather", k_logits, g_logits)
+    # the chain flushed its sequence: the pool is whole, and the window gave blocks back on the way
+    if engine.released_blocks <= 0 or engine.free_blocks != free:
+        raise AssertionError(f"rolling release: {engine.released_blocks} blocks released, "
+                             f"{free - engine.free_blocks} still held after the flush")
+    print(f"  window model: {engine.released_blocks} blocks released as the window passed "
+          f"(its context spans {-(-(n + sizes.compare_steps) // 64)})")
+    engine.close()
+    reference.close()
 
 
 # ------------------------------------------------------------------- train --

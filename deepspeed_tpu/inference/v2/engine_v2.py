@@ -76,6 +76,12 @@ class InferenceEngineV2:
         # attribute load per dispatch.
         self.dispatch_observer = None
 
+        # rolling release (sliding-window models): blocks given back to the
+        # pool as the window passed them, ever and as of the last ``prepare``
+        # span (which reports the difference as ``released_blocks``)
+        self._released_blocks = 0
+        self._released_at_prepare = 0
+
     # ------------------------------------------------------------------ groups --
     def _initialize_comm_groups(self) -> None:
         """Reference engine_v2.py:108 creates TP (and fork: EP-replica) process
@@ -124,6 +130,12 @@ class InferenceEngineV2:
         return self._state_manager.free_blocks
 
     @property
+    def released_blocks(self) -> int:
+        """KV blocks given back to the pool by the rolling release of a
+        sliding-window model since the engine was built."""
+        return self._released_blocks
+
+    @property
     def n_kv_cache_groups(self) -> int:
         return 1
 
@@ -170,7 +182,8 @@ class InferenceEngineV2:
         args = None
         if spans is not None:
             free_before = self._state_manager.free_blocks
-            args = {"sequences": len(batch_uids), "tokens": n_tokens}
+            args = {"sequences": len(batch_uids), "tokens": n_tokens,
+                    "released_blocks": self._released_since_prepare()}
         with _tel_live_span(spans, "prepare", "inference", args):
             if do_checks:
                 # BEFORE restoring: can_schedule counts offloaded sequences'
@@ -197,6 +210,13 @@ class InferenceEngineV2:
             if args is not None:
                 args["allocated_blocks"] = free_before - self._state_manager.free_blocks
 
+    def _released_since_prepare(self) -> int:
+        """Blocks the rolling release gave back since the last ``prepare``
+        span (after the step before this one): its ``released_blocks``."""
+        n = self._released_blocks - self._released_at_prepare
+        self._released_at_prepare = self._released_blocks
+        return n
+
     def _telemetry_sinks(self):
         """``(spans, observer, metrics)``: all None with telemetry off and no
         scheduler cost plane attached."""
@@ -211,11 +231,15 @@ class InferenceEngineV2:
             return None
         return dict(counts, sequences=len(batch_uids), uids=[int(u) for u in batch_uids])
 
-    def _post_forward(self, batch_uids) -> None:
+    def _post_forward(self, batch_uids, release: bool = True) -> None:
+        """Commit the fed tokens and, unless the caller may still roll some
+        back (the verify steps: a released block cannot come back), let the
+        model release what the window has passed."""
         for uid in batch_uids:
             seq_desc = self._state_manager.get_sequence(uid)
             seq_desc.post_forward()
-            self._model.maybe_free_kv(seq_desc)
+            if release:
+                self._released_blocks += self._model.maybe_free_kv(seq_desc)
 
     def put(self, batch_uids: Iterable[int], batch_tokens: Iterable, do_checks: bool = True):
         """Run one ragged forward over ``batch_uids``/``batch_tokens``; returns
@@ -258,6 +282,9 @@ class InferenceEngineV2:
             "tracked": reg.gauge("inference_tracked_sequences", "Sequences tracked"),
             "empty_runs": reg.counter("inference_empty_runs_total",
                                       "EP lock-step forwards with zero tokens"),
+            "released": reg.gauge("inference_kv_released_blocks",
+                                  "KV blocks a sliding window's rolling release has "
+                                  "given back to the pool"),
         }
 
     def _resolve_tel_metrics(self) -> Optional[dict]:
@@ -289,6 +316,7 @@ class InferenceEngineV2:
         metrics["tokens"].inc(batch_tokens)
         metrics["in_flight"].set(batch_tokens)
         metrics["free_blocks"].set(self._state_manager.free_blocks)
+        metrics["released"].set(self._released_blocks)
         metrics["tracked"].set(self._state_manager.n_tracked_sequences)
 
     # ------------------------------------------------------------ decode_loop --
@@ -342,7 +370,8 @@ class InferenceEngineV2:
         prep = None
         if spans is not None:
             free_before = self._state_manager.free_blocks
-            prep = {"sequences": len(batch_uids), "tokens": len(batch_uids) * n_steps}
+            prep = {"sequences": len(batch_uids), "tokens": len(batch_uids) * n_steps,
+                    "released_blocks": self._released_since_prepare()}
         with _tel_live_span(spans, "prepare", "inference", prep):
             if do_checks:
                 # each SCAN STEP's ragged batch holds one token per sequence, so
@@ -396,7 +425,7 @@ class InferenceEngineV2:
             if n_steps > 1:                   # the n_steps-1 tokens the loop inserted
                 seq_desc.pre_forward(n_steps - 1)
                 seq_desc.post_forward()
-            self._model.maybe_free_kv(seq_desc)
+            self._released_blocks += self._model.maybe_free_kv(seq_desc)
         return tokens[:, :len(batch_uids)].T
 
     # ------------------------------------------------------ speculative verify --
@@ -432,7 +461,7 @@ class InferenceEngineV2:
             rows = np.asarray(self._model.forward_verify(self._batch, greedy=greedy))
             if observer is not None:
                 observer("verify", len(batch_uids), n_tokens, (_tel_now_us() - _t0) / 1e6)
-            self._post_forward(batch_uids)
+            self._post_forward(batch_uids, release=False)
         if metrics is not None:
             self._write_telemetry(metrics, batch_tokens=n_tokens)
         # insertion order is batch order: each sequence's positions are one
@@ -476,7 +505,7 @@ class InferenceEngineV2:
             if observer is not None:
                 observer("verify_tree", len(batch_uids), n_tokens,
                          (_tel_now_us() - _t0) / 1e6)
-            self._post_forward(batch_uids)
+            self._post_forward(batch_uids, release=False)
         if metrics is not None:
             self._write_telemetry(metrics, batch_tokens=n_tokens)
         out, offset = [], 0
@@ -527,6 +556,13 @@ class InferenceEngineV2:
         seq_desc = self._state_manager.get_sequence(uid)
         if seq_desc is None:
             raise ValueError(f"rollback: unknown uid {uid}")
+        window = self._model.attention_window
+        if window > 0 and seq_desc.released_blocks:
+            first_seen = max(seq_desc.seen_tokens - n_tokens - window + 1, 0)
+            if first_seen // self._state_manager.kv_block_size < seq_desc.released_blocks:
+                raise ValueError(
+                    f"rollback({n_tokens}): uid {uid} would need keys from position "
+                    f"{first_seen}, in a block its attention window already released")
         seq_desc.rollback(n_tokens)
 
     # ------------------------------------------------------------- scheduling --
@@ -546,7 +582,7 @@ class InferenceEngineV2:
         """Device blocks a touch of ``uid`` must re-allocate first: an
         offloaded sequence's stale descriptor still reports its (freed)
         blocks as resident."""
-        return seq_desc.cur_allocated_blocks if self._state_manager.is_offloaded(uid) else 0
+        return seq_desc.live_blocks if self._state_manager.is_offloaded(uid) else 0
 
     def can_schedule(self, uids: Iterable[int], lengths: Iterable[int]) -> SchedulingResult:
         uids, lengths = list(uids), list(lengths)

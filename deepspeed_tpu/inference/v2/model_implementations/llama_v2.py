@@ -130,8 +130,11 @@ class LlamaV2Model(DSTransformerModelBase):
 
 class MistralV2Model(LlamaV2Model):
     """Reference: inference/v2/model_implementations/mistral — llama
-    architecture + sliding-window attention (the window rides the shared
-    ``attention_window`` masking in the paged attention)."""
+    architecture + sliding-window attention. The window is the config's
+    ``sliding_window`` (``attention_window``); every attention arm masks it, the
+    Pallas kernel also walks only the window's blocks, and the KV pool takes
+    back each block the window has passed (``maybe_free_kv``), so contexts up to
+    ``max_context`` are served at the window's cost and memory."""
 
 
 class Qwen2V2Model(LlamaV2Model):

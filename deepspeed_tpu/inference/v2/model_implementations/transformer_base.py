@@ -107,7 +107,11 @@ class DSTransformerModelBase:
                             max_new_blocks: int) -> Tuple[int, int]:
         """How many of ``max_new_tokens`` can run given ``max_new_blocks`` free
         blocks, and how many blocks that takes (reference
-        inference_transformer_base.py get_kv_requirements)."""
+        inference_transformer_base.py get_kv_requirements). The blocks are the
+        NEW ones past the table's end: what a sliding-window sequence released
+        behind it (:meth:`maybe_free_kv`) is already back in the pool the
+        caller counts, so a long context is admitted by what it holds, not by
+        its length; ``max_context`` still bounds its positions."""
         bs = self._state_manager.kv_block_size
         # the per-sequence table cap (max_context) bounds schedulable tokens
         # too: admission must reject here, not crash in extend_kv_cache after
@@ -141,8 +145,27 @@ class DSTransformerModelBase:
         if n_blocks > 0:
             seq_desc.extend_kv_cache(self._state_manager.allocate_blocks(n_blocks))
 
-    def maybe_free_kv(self, seq_desc: DSSequenceDescriptor) -> None:
-        """Hook for cache shrinking; paged blocks are retained until flush."""
+    def maybe_free_kv(self, seq_desc: DSSequenceDescriptor) -> int:
+        """After a step: a sliding-window model gives back every block ALL of
+        whose positions are more than ``attention_window`` behind the
+        sequence's next query (rolling release); returns how many. A
+        full-causal model keeps its blocks until flush. The step just
+        dispatched may still be reading them: whoever is handed them next
+        writes in a LATER program, and the pool is threaded through the
+        programs in order."""
+        return self._state_manager.release_passed_blocks(seq_desc, self.attention_window)
+
+    def max_live_blocks(self, n_tokens: int) -> int:
+        """The most KV blocks a sequence of ``n_tokens`` holds at once: all of
+        them, or under a sliding window the window's, one step's feed and the
+        two blocks the window's ends straddle."""
+        bs = self._engine_config.kv_block_size
+        whole = -(-int(n_tokens) // bs)
+        window = self.attention_window
+        if window <= 0:
+            return whole
+        feed = self._engine_config.state_manager.max_ragged_batch_size
+        return min(whole, (window + feed - 1) // bs + 2)
 
     # ---------------------------------------------------------------- forward --
     def prepare_batch(self, ragged_batch) -> None:
@@ -534,9 +557,12 @@ class DSTransformerModelBase:
 
     def _paged_attention(self, q, k_new, v_new, cache, li, *, batch):
         """Insert the new K/V into the paged cache and attend each query token
-        to its sequence's full history: the Pallas kernel walking the block
-        table (grid over tokens for a decode bucket, over query tiles for a
-        larger one), or the XLA arm (scatter, then gather per-sequence K/V).
+        to its sequence's history (the last ``attention_window`` keys of it for
+        a sliding-window model): the Pallas kernel walking the block table
+        (grid over tokens for a decode bucket, over query tiles for a larger
+        one), or the XLA arm (scatter, then gather per-sequence K/V). Table
+        entries of blocks the window has passed may be released (-1): the
+        kernel never walks them, the XLA arm masks what it gathers for them.
 
         q: [T, H, D]; k_new/v_new: [T, KVH, D];
         cache: [L, 2, num_blocks, KVH, bs, D]."""
@@ -563,8 +589,10 @@ class DSTransformerModelBase:
                 meta = (batch["block_table"], batch["token_seq"], token_pos,
                         batch["token_valid"])
 
+            window = self.attention_window
+
             def kernel(q, k_new, v_new, cache, *meta):
-                return update(q, k_new, v_new, cache, li, *meta)
+                return update(q, k_new, v_new, cache, li, *meta, window=window)
 
             args = (q, k_new, v_new, cache) + meta
             placed = None if self._state_manager is None else self._state_manager.kv_cache.sharding
@@ -792,7 +820,7 @@ class DSTransformerModelBase:
         if src.size == 0:
             return
         bs = self._state_manager.kv_block_size
-        blocks = seq_desc.kv_blocks
+        blocks = seq_desc.kv_blocks  # the slots are the feed's own: never released
         NB = self._state_manager.kv_cache.cache.shape[2]
         P = _pow2_pad(src.size, 2)
         src_blk = np.zeros(P, np.int32)

@@ -19,10 +19,11 @@ def attention_implementation(model, engine_config, bucket_tokens: int) -> str:
     steps and ``decode_loop``) or its query-tile grid (every larger bucket:
     prefill and mixed steps) — or ``"xla_gather"`` (scatter + dense
     per-sequence gather: whole-pool layout copies every step on a TPU, PERF.md
-    §6 PR 24; what window models and the CPU run). Policy:
+    §6 PR 24; what the CPU runs, and the check the kernel is tested against).
+    A sliding-window model (``attention_window`` > 0) is chosen for like any
+    other: every arm masks the window, and the kernel also starts its block
+    walk at the window's first block. Policy:
 
-    - a sliding-window model takes the gather arm: the window is only masked
-      there;
     - an explicit ``use_paged_kernel`` config wins: kernel (grid by bucket) or
       gather;
     - otherwise the kernel needs a TPU backend and VMEM room for its
@@ -31,13 +32,6 @@ def attention_implementation(model, engine_config, bucket_tokens: int) -> str:
     from deepspeed_tpu.ops.pallas.paged_attention import (CHUNK, TOKEN_GRID_MAX,
                                                           tile_grid_vmem_bytes)
     flag = getattr(engine_config, "use_paged_kernel", None)
-    if getattr(model, "attention_window", 0):
-        # sliding window is only masked on the dense path — correctness beats
-        # an explicit kernel request
-        if flag:
-            logger.warning("use_paged_kernel=True ignored: the Pallas kernel has no "
-                           "sliding-window mask; using the XLA gather path")
-        return "xla_gather"
     kernel = "paged_token" if bucket_tokens <= TOKEN_GRID_MAX else "paged_tiled"
     if flag is not None:
         return kernel if flag else "xla_gather"

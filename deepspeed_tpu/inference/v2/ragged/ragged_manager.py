@@ -78,8 +78,22 @@ class DSStateManager:
         handle = self._offloaded.pop(uid, None)
         if handle is not None:
             self._kv_cache.drop_offloaded(handle)
-        elif seq.cur_allocated_blocks > 0:
-            self._kv_cache.free(seq.kv_blocks)
+        elif seq.live_blocks > 0:
+            self._kv_cache.free(seq.live_kv_blocks)
+
+    def release_passed_blocks(self, seq: DSSequenceDescriptor, window: int) -> int:
+        """Rolling release under a sliding ``window``: give back to the
+        allocator every block ALL of whose positions are more than ``window``
+        behind the sequence's next query (position ``seen_tokens``, which sees
+        keys ``seen_tokens - window + 1 ..``). Returns the number released.
+        An offloaded sequence is left alone: its table is not live."""
+        if window <= 0 or seq.tracking_id in self._offloaded:
+            return 0
+        passed = max(seq.seen_tokens - window + 1, 0) // self._kv_config.block_size
+        freed = seq.release_leading(passed)
+        if freed:
+            self._kv_cache.free(freed)
+        return len(freed)
 
     # ----------------------------------------------------------- kv offload --
     def is_offloaded(self, uid: int) -> bool:
@@ -105,9 +119,9 @@ class DSStateManager:
             return
         if seq.in_flight_tokens:
             raise RuntimeError(f"offload_sequence: uid {uid} has in-flight tokens")
-        if seq.cur_allocated_blocks == 0:
+        if seq.live_blocks == 0:
             return
-        self._offloaded[uid] = self._kv_cache.offload(seq.kv_blocks)
+        self._offloaded[uid] = self._kv_cache.offload(seq.live_kv_blocks)
         seq.kv_tier = self.sequence_tier(uid)
 
     def demote_sequence(self, uid: int, wait: bool = False) -> bool:
@@ -155,6 +169,12 @@ class DSStateManager:
             raise ValueError(f"export_sequence: unknown uid {uid}")
         if seq.in_flight_tokens:
             raise RuntimeError(f"export_sequence: uid {uid} has in-flight tokens")
+        if seq.released_blocks:
+            raise ValueError(
+                f"export_sequence: uid {uid} has passed its attention window and "
+                f"released {seq.released_blocks} KV blocks; a handoff or park frame "
+                f"carries a whole block table and cannot hold it — recompute the "
+                f"sequence on the recipient instead")
         if uid in self._offloaded:
             self.restore_sequence(uid)
         kv = (self._kv_cache.gather_blocks(seq.kv_blocks)

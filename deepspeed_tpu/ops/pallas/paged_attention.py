@@ -37,6 +37,14 @@ wider of the queries' and the pool's dtype, float32 accumulation, causal mask
 from positions. A decode row
 riding along is a pass with one live query row; a tile with no tokens writes
 zeros and touches nothing.
+
+Sliding window (``window`` > 0, static; 0 = full causal and the program it
+always was): both grids mask ``kv_pos > q_pos - window`` AND start their walk
+at the first block that holds a visible key — for a tile pass, visible to its
+FIRST query — so a sequence's K/V traffic is bounded by ``window`` + the pass's
+queries (+ the last chunk's padding), not by its context. Table entries before
+that block are never read: the pool releases those blocks as the window passes
+them (``transformer_base.maybe_free_kv``) and leaves -1 there.
 """
 
 import functools
@@ -67,7 +75,13 @@ def tile_grid_vmem_bytes(H, KVH, D, bs, itemsize=2):
             + 2 * H * TQ * 128 * 4 + 2 * 2 * (tile + KVH * TQ * D) * itemsize)
 
 
-def _kernel(li, S, MB, bs, rep, scale,
+def _first_visible_block(pos, window, bs):
+    """The block that holds the oldest key a query at ``pos`` sees under a
+    sliding ``window`` (> 0): keys ``pos - window + 1 .. pos``."""
+    return jnp.maximum(pos - window + 1, 0) // bs
+
+
+def _kernel(li, S, MB, bs, rep, scale, window,
             # scalar prefetch
             table_ref, seq_ref, pos_ref, valid_ref,
             # inputs
@@ -81,6 +95,11 @@ def _kernel(li, S, MB, bs, rep, scale,
     pos = pos_ref[t]
     valid = valid_ref[t] > 0
     nblocks = jnp.where(valid, jnp.minimum(pos // bs + 1, MB), 0)
+    if window:
+        # the walk starts at the first block with a visible key: blocks wholly
+        # behind the window are never fetched (the pool may have released them)
+        b_first = _first_visible_block(pos, window, bs)
+        nblocks = jnp.maximum(nblocks - b_first, 0)
     nchunks = pl.cdiv(nblocks, CHUNK)
 
     KVH, _, D = k_buf.shape[2:]
@@ -120,7 +139,10 @@ def _kernel(li, S, MB, bs, rep, scale,
     def chunk_copies(c, slot):
         copies = []
         for j in range(CHUNK):
-            b = jnp.minimum(c * CHUNK + j, MB - 1)
+            b = c * CHUNK + j
+            if window:
+                b = b + b_first
+            b = jnp.minimum(b, MB - 1)
             bid = jnp.maximum(table_ref[seq, b], 0)
             copies.append(pltpu.make_async_copy(cache_out_ref.at[li, 0, bid],
                                                 k_buf.at[slot, j], sems.at[0, slot, j]))
@@ -157,7 +179,11 @@ def _kernel(li, S, MB, bs, rep, scale,
 
         kv_pos = c * (CHUNK * bs) + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, CHUNK * bs), 2)
+        if window:
+            kv_pos = kv_pos + b_first * bs
         mask = kv_pos <= pos
+        if window:
+            mask &= kv_pos > pos - window
         logits = jnp.where(mask, logits, NEG_INF)
 
         m_new = jnp.maximum(m, logits.max(axis=-1))
@@ -178,13 +204,16 @@ def _kernel(li, S, MB, bs, rep, scale,
     out_ref[0] = out.reshape(1, KVH * rep, D).astype(out_ref.dtype)[0]
 
 
-@functools.partial(jax.jit, static_argnames=("layer_idx", "interpret"), donate_argnums=(3, ))
+@functools.partial(jax.jit, static_argnames=("layer_idx", "interpret", "window"),
+                   donate_argnums=(3, ))
 def paged_attention_update(q, k_new, v_new, cache, layer_idx, block_table, token_seq,
-                           token_pos, token_valid, interpret=None):
+                           token_pos, token_valid, interpret=None, window=0):
     """Fused KV-insert + blocked attention for one layer.
 
     q: [T, H, D]; k_new/v_new: [T, KVH, D]; cache: [L, 2, NB, KVH, bs, D]
-    (donated; updated in place). Returns (attn_out [T, H, D], cache)."""
+    (donated; updated in place). ``window`` > 0: a token at position p sees
+    keys ``p - window + 1 .. p`` only, and table entries of blocks wholly
+    before them are never read. Returns (attn_out [T, H, D], cache)."""
     T, H, D = q.shape
     L, _, NB, KVH, bs, Dc = cache.shape
     assert D == Dc and H % KVH == 0
@@ -215,7 +244,7 @@ def paged_attention_update(q, k_new, v_new, cache, layer_idx, block_table, token
             pltpu.SemaphoreType.DMA((2, )),
         ],
     )
-    kernel = functools.partial(_kernel, layer_idx, S, MB, bs, rep, scale)
+    kernel = functools.partial(_kernel, layer_idx, S, MB, bs, rep, scale, int(window))
     out, new_cache = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -230,7 +259,7 @@ def paged_attention_update(q, k_new, v_new, cache, layer_idx, block_table, token
     return out, new_cache
 
 
-def _tiled_kernel(S, MB, bs, rep, scale, precision,
+def _tiled_kernel(S, MB, bs, rep, scale, precision, window,
                   # scalar prefetch
                   layer_ref, table_ref, seen_ref, ntok_ref, last_ref,
                   # inputs
@@ -323,12 +352,19 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision,
         each(n_touched, landed)
 
         # ---- walk the block table once, double-buffered chunks --------------
-        nchunks = pl.cdiv(b1 + 1, CHUNK)
+        if window:
+            # from the first block that holds a key the pass's FIRST query
+            # sees: blocks wholly behind the window are never fetched (the
+            # pool may have released them)
+            b_first = _first_visible_block(p_lo, window, bs)
+            nchunks = pl.cdiv(b1 + 1 - b_first, CHUNK)
+        else:
+            nchunks = pl.cdiv(b1 + 1, CHUNK)
         # a row of another sequence (or of padding) sees no key
         q_pos = jnp.where((row_tok >= lo) & (row_tok <= hi), row_tok + shift, -1)
 
         def chunk_copies(c, slot, j):
-            bid = block_id(c * CHUNK + j)
+            bid = block_id(c * CHUNK + j + b_first if window else c * CHUNK + j)
             rows = pl.ds(pl.multiple_of(j * bs, bs), bs)
             return (pltpu.make_async_copy(cache_out_ref.at[li, 0, bid], k_buf.at[slot, :, rows],
                                           sems.at[0, slot, j]),
@@ -359,7 +395,11 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision,
             wait_chunk(c, slot)
             kv_pos = c * (CHUNK * bs) + jax.lax.broadcasted_iota(
                 jnp.int32, (1, CHUNK * bs), 1)
+            if window:
+                kv_pos = kv_pos + b_first * bs
             mask = kv_pos <= q_pos  # [rep*TQ, CHUNK*bs]
+            if window:
+                mask &= kv_pos > q_pos - window
 
             def head(g):
                 logits = jax.lax.dot_general(
@@ -400,9 +440,9 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision,
     each(KVH * rep, write_out)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", ), donate_argnums=(3, ))
+@functools.partial(jax.jit, static_argnames=("interpret", "window"), donate_argnums=(3, ))
 def paged_attention_prefill(q, k_new, v_new, cache, layer_idx, block_table, seq_seen,
-                            seq_ntok, last_tok, interpret=None):
+                            seq_ntok, last_tok, interpret=None, window=0):
     """Fused KV-insert + blocked attention for one layer, query-tiled: for the
     buckets of more than ``TOKEN_GRID_MAX`` tokens (a multiple of ``TQ``).
 
@@ -411,8 +451,10 @@ def paged_attention_prefill(q, k_new, v_new, cache, layer_idx, block_table, seq_
     last_tok: [S] — sequence s holds tokens ``last_tok - seq_ntok + 1 ..
     last_tok`` of the batch at positions ``seq_seen ..``; a slot with
     ``seq_ntok <= 0`` is empty. ``layer_idx`` is an operand, not a constant:
-    a model's layers share ONE kernel in the compiled program. Returns
-    (attn_out [T, H, D], cache); rows of no sequence are zero."""
+    a model's layers share ONE kernel in the compiled program. ``window`` as
+    in :func:`paged_attention_update`; a pass walks from the first block its
+    first query sees. Returns (attn_out [T, H, D], cache); rows of no sequence
+    are zero."""
     T, H, D = q.shape
     L, _, NB, KVH, bs, Dc = cache.shape
     assert D == Dc and H % KVH == 0 and T % TQ == 0
@@ -451,7 +493,7 @@ def paged_attention_prefill(q, k_new, v_new, cache, layer_idx, block_table, seq_
             pltpu.SemaphoreType.DMA((n_stage, 2)),
         ],
     )
-    kernel = functools.partial(_tiled_kernel, S, MB, bs, rep, scale, precision)
+    kernel = functools.partial(_tiled_kernel, S, MB, bs, rep, scale, precision, int(window))
     out, new_cache = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

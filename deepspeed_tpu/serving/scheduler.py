@@ -266,6 +266,16 @@ class ServingScheduler:
         # mutation happens on the scheduler thread — the same thread that owns
         # every other engine touch.
         self._prefix_cache = None
+        # a sliding-window model releases KV blocks as its window passes them
+        # (transformer_base.maybe_free_kv); what needs a sequence's whole block
+        # table refuses here, or at submission, instead of serving wrong keys
+        self._window = int(getattr(getattr(engine, "model", None), "attention_window", 0) or 0)
+        if self._window and (self._config.prefix_cache.enabled or self._config.kv_tiers.enabled):
+            raise ValueError(
+                f"prefix_cache / kv_tiers cannot serve a sliding-window model "
+                f"(attention_window={self._window}): the trie shares and the tier ladder "
+                f"moves whole block tables, and this model's sequences release the blocks "
+                f"their window has passed. Turn both off for this model.")
         if self._config.prefix_cache.enabled:
             from deepspeed_tpu.inference.v2.ragged.prefix_cache import PrefixCache
             self._prefix_cache = PrefixCache(
@@ -401,7 +411,7 @@ class ServingScheduler:
             if req.cost is None:
                 continue
             seq = sm.get_sequence(req.uid)
-            blocks = seq.cur_allocated_blocks if seq is not None else 0
+            blocks = seq.live_blocks if seq is not None else 0
             tier = (sm.sequence_tier(req.uid) or "device") if blocks else "device"
             self._ledger.touch_kv(req.cost, blocks, tier, now_s)
 
@@ -577,6 +587,11 @@ class ServingScheduler:
 
     def _enqueue(self, req: Request, trace_id: Optional[str],
                  parent_span_id: Optional[int], handoff: bool) -> Request:
+        if self._window and (handoff or req.park_requested or req._resume_header is not None):
+            raise ValueError(
+                f"handoff, park and resume frames carry a sequence's whole KV block table; "
+                f"a sliding-window model (attention_window={self._window}) releases the "
+                f"blocks its window has passed. Send the prompt for recompute instead.")
         req.handoff_requested = bool(handoff)
         if self._ledger is not None:
             # every admitted request carries a RequestCost from birth (the
@@ -1636,8 +1651,9 @@ class ServingScheduler:
             return (f"prompt of {req.prompt.size} tokens exceeds max_context="
                     f"{sm.max_context} (room for at least one generated token "
                     f"is required)")
-        block_size = self._engine._state_manager.kv_block_size
-        min_blocks = -(-(req.prompt.size + 1) // block_size)
+        # what the sequence holds at once: a sliding-window model releases
+        # blocks as it goes, so a prompt longer than the pool can still fit
+        min_blocks = self._engine.model.max_live_blocks(req.prompt.size + 1)
         if min_blocks > self._capacity_blocks:
             return (f"prompt needs {min_blocks} KV blocks; the pool holds "
                     f"{self._capacity_blocks}")
@@ -1788,7 +1804,7 @@ class ServingScheduler:
             if req.uid in exclude_uids or engine.is_offloaded(req.uid):
                 continue
             seq = engine._state_manager.get_sequence(req.uid)
-            if seq is not None and seq.cur_allocated_blocks > 0:
+            if seq is not None and seq.live_blocks > 0:
                 candidates.append(req)
         if not candidates:
             return False
@@ -2770,7 +2786,7 @@ class ServingScheduler:
                 cached_tokens=req.cached_tokens,
                 deferred_ticks=req._deferred,
                 deadline_in_s=(req.deadline - now) if req.deadline is not None else None,
-                kv_blocks=seq.cur_allocated_blocks if seq is not None else 0,
+                kv_blocks=seq.live_blocks if seq is not None else 0,
                 offloaded=engine.is_offloaded(req.uid),
             )
             rows.append(row)

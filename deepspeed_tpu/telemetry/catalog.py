@@ -51,6 +51,8 @@ METRIC_FAMILIES = {
     "inference_tokens_total": "tokens scheduled into batches",
     "inference_in_flight_tokens": "tokens in the last ragged batch",
     "inference_kv_free_blocks": "free KV-cache blocks",
+    "inference_kv_released_blocks": "KV blocks a sliding window's rolling release has given back "
+                                    "to the pool since the engine was built",
     "inference_tracked_sequences": "sequences tracked",
     "inference_empty_runs_total": "EP lock-step forwards with zero tokens",
     # serving layer (serving/metrics.py)

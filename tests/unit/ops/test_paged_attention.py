@@ -12,9 +12,10 @@ from deepspeed_tpu.ops.pallas.paged_attention import (paged_attention_prefill,
                                                       paged_attention_update)
 
 
-def _dense_reference(q, cache, li, table, token_seq, token_pos, token_valid):
+def _dense_reference(q, cache, li, table, token_seq, token_pos, token_valid, window=0):
     """Per-token dense attention over the block-table history (cache already
-    contains every token's K/V, including the queries' own)."""
+    contains every token's K/V, including the queries' own); with ``window``
+    over the last ``window`` keys only, and no table entry before them read."""
     T, H, D = q.shape
     L, _, NB, KVH, bs, _ = cache.shape
     S, MB = table.shape
@@ -24,13 +25,14 @@ def _dense_reference(q, cache, li, table, token_seq, token_pos, token_valid):
         if not token_valid[t]:
             continue
         s, pos = int(token_seq[t]), int(token_pos[t])
-        n = pos + 1
+        first = max(pos - window + 1, 0) if window else 0
+        n = pos + 1 - first
         k = np.zeros((n, KVH, D), np.float32)
         v = np.zeros((n, KVH, D), np.float32)
-        for p in range(n):
+        for p in range(first, pos + 1):
             bid = int(table[s, p // bs])
-            k[p] = np.asarray(cache[li, 0, bid, :, p % bs], np.float32)
-            v[p] = np.asarray(cache[li, 1, bid, :, p % bs], np.float32)
+            k[p - first] = np.asarray(cache[li, 0, bid, :, p % bs], np.float32)
+            v[p - first] = np.asarray(cache[li, 1, bid, :, p % bs], np.float32)
         for h in range(H):
             kv = h // rep
             logits = (np.asarray(q[t, h], np.float32) @ k[:, kv].T) / np.sqrt(D)
@@ -151,6 +153,162 @@ def test_paged_attention_prefill_matches_dense(kvh, batch):
                                            seq_seen, np.zeros(S, np.int32), last_tok)
     assert not np.any(np.asarray(out2))
     np.testing.assert_array_equal(np.asarray(cache2), exp_cache)
+
+
+# ---- sliding window: both grids, window 16 over 4-token blocks ---------------
+WINDOW, WBS = 16, 4
+
+
+def _release_passed(table, cache, seq_next_pos, window, bs):
+    """What the pool's rolling release leaves a later step: the table entries
+    of blocks wholly behind ``next_pos - window + 1`` are holes (-1), and the
+    blocks they named hold another owner's data — NaN here, so that one
+    dereference of a released block poisons the output."""
+    table, cache = table.copy(), cache.copy()
+    for s, pos in seq_next_pos.items():
+        for b in range(max(pos - window + 1, 0) // bs):
+            cache[:, :, table[s, b]] = np.nan
+            table[s, b] = -1
+    return table, cache
+
+
+@pytest.mark.parametrize("kvh", [4, 2])  # MHA and GQA
+def test_token_grid_window_matches_dense_masked(kvh):
+    """Decode rows at 3-4 x the window, one exactly at the window's edge (it
+    still sees position 0), one inside it, one padding row; the blocks the
+    window has passed are released and overwritten."""
+    rng = np.random.default_rng(0)
+    L, NB, bs, D, H = 2, 80, WBS, 128, 4
+    S, MB = 8, 16
+    positions = [50, 63, WINDOW - 1, WINDOW, 5, 0]
+    T = 8
+    cache0 = rng.normal(size=(L, 2, NB, kvh, bs, D)).astype(np.float32)
+    cache0[:, :, 0] = 0.0  # block 0 is nobody's: where a hole's -1 clamps to
+    free = list(rng.permutation(np.arange(1, NB)))
+    table = np.full((S, MB), -1, np.int32)
+    token_seq = np.full(T, S - 1, np.int32)
+    token_pos = np.zeros(T, np.int32)
+    token_valid = np.zeros(T, np.int32)
+    for s, pos in enumerate(positions):
+        for b in range(pos // bs + 1):
+            table[s, b] = free.pop()
+        token_seq[s], token_pos[s], token_valid[s] = s, pos, 1
+    q = rng.normal(size=(T, H, D)).astype(np.float32)
+    k_new = rng.normal(size=(T, kvh, D)).astype(np.float32)
+    v_new = rng.normal(size=(T, kvh, D)).astype(np.float32)
+    exp_cache = cache0.copy()
+    for t, pos in enumerate(positions):
+        exp_cache[:, 0, table[t, pos // bs], :, pos % bs] = k_new[t]
+        exp_cache[:, 1, table[t, pos // bs], :, pos % bs] = v_new[t]
+
+    holes, poisoned = _release_passed(table, cache0, dict(enumerate(positions)), WINDOW, bs)
+    assert (holes[0, :8] == -1).all() and holes[2, 0] >= 0 and holes[3, 0] >= 0
+    cache = jnp.asarray(poisoned)
+    for li in range(L):
+        got, cache = paged_attention_update(q, k_new, v_new, cache, li, holes, token_seq,
+                                            token_pos, token_valid, window=WINDOW)
+        want = _dense_reference(q, exp_cache, li, table, token_seq, token_pos, token_valid,
+                                window=WINDOW)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+    # the window changes the answer (the test would pass a kernel without one otherwise)
+    full = _dense_reference(q, exp_cache, 0, table, token_seq, token_pos, token_valid)
+    assert np.abs(full[0] - want[0]).max() > 1e-3
+
+
+WINDOW_TILED_BATCHES = {
+    # a first chunk that straddles the window's edge: queries 0..15 see all
+    # they have, queries 16..69 lose keys; the tile's first and last query
+    # see different first blocks
+    "chunk-straddling-the-edge": ([(0, 70)], 128),
+    # a later chunk far past the window (3-4 x), starting mid-block, over the
+    # tile boundary, with decode rows riding along past and inside the window
+    "late-chunk-plus-decode-rows": ([(61, 1), (45, 100), (7, 1), (WINDOW - 1, 1)], 128),
+    "one-tile-at-the-edge": ([(WINDOW - 3, 64)], 64),
+}
+
+
+@pytest.mark.parametrize("kvh", [4, 2])  # MHA and GQA
+@pytest.mark.parametrize("batch", list(WINDOW_TILED_BATCHES))
+def test_tile_grid_window_matches_dense_masked(kvh, batch):
+    seqs, T = WINDOW_TILED_BATCHES[batch]
+    rng = np.random.default_rng(0)
+    L, NB, bs, D, H = 2, 120, WBS, 128, 4
+    S, MB = 8, 64
+    cache0 = rng.normal(size=(L, 2, NB, kvh, bs, D)).astype(np.float32)
+    cache0[:, :, 0] = 0.0
+    free = list(rng.permutation(np.arange(1, NB)))
+    table = np.full((S, MB), -1, np.int32)
+    token_seq = np.full(T, S - 1, np.int32)
+    token_pos = np.zeros(T, np.int32)
+    token_valid = np.zeros(T, np.int32)
+    seq_seen, seq_ntok, last_tok = (np.zeros(S, np.int32) for _ in range(3))
+    cursor = 0
+    for s, (seen, n) in enumerate(seqs):
+        for b in range(-(-(seen + n) // bs)):
+            table[s, b] = free.pop()
+        token_seq[cursor:cursor + n] = s
+        token_pos[cursor:cursor + n] = np.arange(seen, seen + n)
+        token_valid[cursor:cursor + n] = 1
+        cursor += n
+        seq_seen[s], seq_ntok[s], last_tok[s] = seen, n, cursor - 1
+    q = rng.normal(size=(T, H, D)).astype(np.float32)
+    k_new = rng.normal(size=(T, kvh, D)).astype(np.float32)
+    v_new = rng.normal(size=(T, kvh, D)).astype(np.float32)
+    exp_cache = cache0.copy()
+    for t in range(cursor):
+        bid = table[token_seq[t], token_pos[t] // bs]
+        exp_cache[:, 0, bid, :, token_pos[t] % bs] = k_new[t]
+        exp_cache[:, 1, bid, :, token_pos[t] % bs] = v_new[t]
+
+    # released before this step: what is behind the window of each sequence's
+    # FIRST query of the step
+    holes, poisoned = _release_passed(table, cache0, {s: seen for s, (seen, _) in enumerate(seqs)},
+                                      WINDOW, bs)
+    cache = jnp.asarray(poisoned)
+    for li in range(L):
+        got, cache = paged_attention_prefill(q, k_new, v_new, cache, li, holes, seq_seen,
+                                             seq_ntok, last_tok, window=WINDOW)
+        want = _dense_reference(q, exp_cache, li, table, token_seq, token_pos, token_valid,
+                                window=WINDOW)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+        assert not np.any(np.asarray(got)[cursor:])
+    live = ~np.isnan(poisoned)
+    np.testing.assert_array_equal(np.asarray(cache)[live], exp_cache[live])
+
+
+# sha256 of str(jax.make_jaxpr(...)) (addresses blanked) of both grids at the
+# shapes below, taken from the commit BEFORE the kernel had a window argument
+# (d15f72e, jax 0.9.0): with window == 0 the traced program is that one.
+_PRE_WINDOW_JAXPR = {
+    "update": "eac6774739b3692404a729d6558959bb2d91e2e90a4447e8f1ae91da79a697f7",
+    "prefill": "ab0af99cf22339658ef1e5723906e91a18e334a90a28f839deefe5955e66ed0e",
+}
+
+
+@pytest.mark.parametrize("grid", list(_PRE_WINDOW_JAXPR))
+def test_window_zero_traces_the_program_it_always_did(grid):
+    import hashlib
+    import re
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the recorded jaxpr text is jax 0.9.0's")
+    L, NB, KVH, bs, D, H, S, MB = 2, 12, 2, 16, 128, 4, 8, 4
+    cache = jnp.zeros((L, 2, NB, KVH, bs, D), jnp.float32)
+    table = jnp.zeros((S, MB), jnp.int32)
+    if grid == "update":
+        fn, T, meta = paged_attention_update, 8, [jnp.zeros((8, ), jnp.int32)] * 3
+    else:
+        fn, T, meta = paged_attention_prefill, 64, [jnp.zeros((S, ), jnp.int32)] * 3
+    q = jnp.zeros((T, H, D), jnp.float32)
+    kn = jnp.zeros((T, KVH, D), jnp.float32)
+
+    def text(**kw):
+        jaxpr = jax.make_jaxpr(lambda *a: fn(*a[:4], 1, *a[4:], interpret=False, **kw))(
+            q, kn, kn, cache, table, *meta)
+        return re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+
+    assert hashlib.sha256(text(window=0).encode()).hexdigest() == _PRE_WINDOW_JAXPR[grid]
+    assert text(window=0) == text()
+    assert text(window=16) != text()
 
 
 def test_padding_tokens_never_corrupt_last_block():

@@ -224,3 +224,92 @@ def test_serve_decode_loop_program_fits_one_chip(v5e, sizes, serve_model):
         params, cache, batch, _on(one, (), jnp.float32), _on(one, (2, ), jnp.uint32)).compile()
     assert "paged_attention_update" in compiled.as_text()
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+# ---- a sliding-window model at the benchmark cell's shapes (PR 26) -----------
+WINDOW, WINDOW_MAX_BLOCKS, WINDOW_POOL_BLOCKS, WINDOW_LAYERS = 4096, 128, 7104, 5
+
+
+@pytest.mark.parametrize("tokens", [8, 256], ids=["token-grid", "tile-grid"])
+def test_paged_attention_with_a_window_compiles(v5e, tokens):
+    """Both grids with ``window`` 4096 over a block table of 8192 tokens."""
+    from deepspeed_tpu.ops.pallas import paged_attention
+    on = functools.partial(_on, SingleDeviceSharding(v5e[0]))
+    tiled = tokens > paged_attention.TOKEN_GRID_MAX
+
+    def step(q, k, v, cache, *meta):
+        update = paged_attention.paged_attention_prefill if tiled \
+            else paged_attention.paged_attention_update
+        return update(q, k, v, cache, 1, *meta, window=WINDOW)
+
+    meta = (on((8, ), jnp.int32), ) * 3 if tiled else \
+        (on((tokens, ), jnp.int32), on((tokens, ), jnp.int32), on((tokens, ), jnp.bool_))
+    compiled = jax.jit(step, donate_argnums=(3, )).lower(
+        on((tokens, H, D), jnp.bfloat16), on((tokens, KVH, D), jnp.bfloat16),
+        on((tokens, KVH, D), jnp.bfloat16),
+        on((2, 2, 256, KVH, BS, D), jnp.bfloat16),
+        on((8, WINDOW_MAX_BLOCKS), jnp.int32), *meta).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("paged_attention_prefill" if tiled else "paged_attention_update") in text
+
+
+@pytest.fixture(scope="module")
+def window_model():
+    """``mistral-7b-serve-1chip``: Mistral-7B-v0.1 widths, 5 layers, window
+    4096, contexts to 8192, over ``jax.eval_shape``d parameters."""
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.model_implementations.registry import model_cls_for
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import DSStateManagerConfig
+    from deepspeed_tpu.models import llama
+    cfg = llama.LlamaConfig(vocab_size=32000, hidden_size=4096, intermediate_size=14336,
+                            num_hidden_layers=WINDOW_LAYERS, num_attention_heads=32,
+                            num_key_value_heads=8, rope_theta=10000.0,
+                            max_position_embeddings=32768, model_type="mistral",
+                            sliding_window=WINDOW, remat=False)
+    abstract = jax.eval_shape(lambda: llama.init_params(cfg, param_dtype=cfg.dtype)[1])
+    engine_config = RaggedInferenceEngineConfig(
+        state_manager=DSStateManagerConfig(max_context=WINDOW_MAX_BLOCKS * BS,
+                                           max_ragged_batch_size=256,
+                                           max_ragged_sequence_count=8), kv_block_size=BS)
+    model = model_cls_for(cfg)(abstract, cfg, engine_config)
+    assert model.attention_window == WINDOW
+    return model, abstract
+
+
+def _window_args(device, abstract, bucket):
+    one = SingleDeviceSharding(device)
+    tokens, seqs, max_blocks = bucket
+    params = jax.tree.map(lambda leaf: _on(one, leaf.shape, leaf.dtype), abstract)
+    cache = _on(one, (WINDOW_LAYERS, 2, WINDOW_POOL_BLOCKS, KVH, BS, D), jnp.bfloat16)
+    batch = {"tok_meta": _on(one, (4, tokens), jnp.int32),
+             "seq_meta": _on(one, (seqs, 4 + max_blocks), jnp.int32)}
+    return one, params, cache, batch
+
+
+@pytest.mark.parametrize("bucket,kernel", [((8, 8, 128), "paged_attention_update"),
+                                           ((256, 8, 128), "paged_attention_prefill")],
+                         ids=["decode-bucket", "chunk-bucket"])
+def test_window_model_put_program_fits_one_chip(v5e, window_model, bucket, kernel):
+    """A window model takes the kernel like any other, and its 8.7 GiB pool is
+    aliased through: one pool-sized copy would not fit beside it."""
+    model, abstract = window_model
+    assert model.attention_arm(bucket[0]) in ("paged_token", "paged_tiled")
+    _, params, cache, batch = _window_args(v5e[0], abstract, bucket)
+    compiled = jax.jit(model._forward_impl, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and kernel in text
+    assert _device_bytes(compiled) < 0.8 * HBM_BYTES
+    assert not _pool_sized_results(text, cache.shape)
+
+
+def test_window_model_decode_loop_program_fits_one_chip(v5e, window_model):
+    model, abstract = window_model
+    one, params, cache, batch = _window_args(v5e[0], abstract, (8, 8, 128))
+    loop = functools.partial(model._decode_loop_impl, n_steps=8, sampled=False)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(
+        params, cache, batch, _on(one, (), jnp.float32), _on(one, (2, ), jnp.uint32)).compile()
+    text = compiled.as_text()
+    assert "paged_attention_update" in text
+    assert _device_bytes(compiled) < 0.8 * HBM_BYTES
+    assert not _pool_sized_results(text, cache.shape)

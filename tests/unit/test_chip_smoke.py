@@ -42,7 +42,7 @@ def test_phases_run_tiny_on_the_virtual_mesh(chip_smoke):
     meter = chip_smoke.CompileMeter()
     serve = chip_smoke.ServeSizes(kv_blocks=32, max_context=256, token_budget=64, new_tokens=6,
                                   decode_chunk=2, short_prompt=20, long_prompt=90,
-                                  compare_steps=2)
+                                  compare_steps=2, window=64, window_prompt=210)
     train = chip_smoke.TrainSizes(seq_len=128, steps=2,
                                   zero_optimization=(("stage", 3),
                                                      ("stage3_param_persistence_threshold", 0)))
@@ -51,7 +51,9 @@ def test_phases_run_tiny_on_the_virtual_mesh(chip_smoke):
                              use_flash_attention=True)
     four = jax.devices()[:4]
 
-    chip_smoke.serve_phase(meter, 0, serve, config=mixtral)
+    windowed = LlamaConfig.tiny(num_hidden_layers=1, max_position_embeddings=256,
+                                model_type="mistral", sliding_window=serve.window)
+    chip_smoke.serve_phase(meter, 0, serve, config=mixtral, window_config=windowed)
     chip_smoke.train_phase(meter, 0, train, config=llama)
     chip_smoke.ep_serve_phase(meter, 0, serve, config=mixtral, devices=four)
     chip_smoke.zero3_phase(meter, 0, train, config=llama, devices=four)
