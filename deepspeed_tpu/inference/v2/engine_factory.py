@@ -111,8 +111,9 @@ def generate(engine: InferenceEngineV2,
     over-generates up to K-1 discarded tokens before its KV blocks recycle —
     the standard chunked-serving tradeoff of host-RTT against speculative
     compute. The fast path is greedy-only: with ``temperature > 0`` each
-    request samples from its own host numpy stream (seeded ``seed + index``)
-    through the step-by-step path, so concurrent requests stay independently
+    request (seeded ``seed + index``) takes the step-by-step path, its tokens
+    drawn on the device from its own positional stream
+    (``inference/v2/sampling.py``), so concurrent requests stay independently
     reproducible; greedy output is identical either way.
     """
     from deepspeed_tpu.serving.config import ServingConfig

@@ -246,6 +246,29 @@ class InferenceEngineV2:
         logits ``[len(batch_uids), vocab]`` — each sequence's final token only.
         The logits are a device array still being computed: the ``put`` span
         is the dispatch, the caller's fetch is the wait."""
+        return self._put(batch_uids, batch_tokens, do_checks, None)
+
+    def put_draw(self, batch_uids: Iterable[int], batch_tokens: Iterable,
+                 temperature, seed, draw_index, do_checks: bool = True):
+        """:meth:`put`, with each sequence's next token drawn on the device
+        (:mod:`~deepspeed_tpu.inference.v2.sampling`): the same forward
+        program, the draw dispatched behind it, and device int32 ids
+        ``[S_bucket]`` back — entry i is ``batch_uids[i]``'s, the rest is
+        padding — in place of ``[n, vocab]`` float32 logits. ``temperature``
+        (0 = greedy), ``seed`` and ``draw_index`` (tokens the request has
+        emitted over its whole life) hold one entry a sequence; a sequence's
+        token depends on its own three and its logits, never on the batch.
+        :meth:`warm_draw` builds the draw's programs ahead of the first
+        call."""
+        return self._put(batch_uids, batch_tokens, do_checks, (temperature, seed, draw_index))
+
+    def warm_draw(self) -> None:
+        """Compile :meth:`put_draw`'s draw for every sequence bucket this
+        engine can produce (a ``ServingScheduler`` calls it when it is
+        constructed: set-up, never a first request's stall)."""
+        self._model.warm_draw()
+
+    def _put(self, batch_uids, batch_tokens, do_checks, draw):
         batch_uids = list(batch_uids)
         batch_tokens = [np.atleast_1d(np.asarray(t)) for t in batch_tokens]
         spans, observer, metrics = self._telemetry_sinks()
@@ -262,14 +285,17 @@ class InferenceEngineV2:
         with _tel_live_span(spans, "put", "inference", args):
             if observer is not None:
                 _t0 = _tel_now_us()
-            logits = self._model.forward(self._batch)
+            if draw is None:
+                out = self._model.forward(self._batch)
+                assert out.shape[0] == self._batch.current_sequences
+            else:
+                out = self._model.forward_draw(self._batch, *draw)
             if observer is not None:
                 observer("put", len(batch_uids), n_tokens, (_tel_now_us() - _t0) / 1e6)
-            assert logits.shape[0] == self._batch.current_sequences
             self._post_forward(batch_uids)
         if metrics is not None:
             self._write_telemetry(metrics, batch_tokens=n_tokens)
-        return logits
+        return out
 
     @staticmethod
     def _build_tel_metrics(reg) -> dict:
@@ -340,8 +366,10 @@ class InferenceEngineV2:
         caller accepts the longest prefix where ``out[i][j] == feed_i[j+1]``
         and rolls back the rejected tail via :meth:`rollback`. All-single-token
         feeds keep the old on-device scan path unchanged — the k=0 fast case.
-        Sampled verification consumes :meth:`verify` logits host-side instead
-        (per-request seeded streams cannot share a device PRNG).
+        Sampled verification consumes :meth:`verify` logits instead and draws
+        each emitted token at its request's own ``(seed, draw_index)``
+        (:mod:`~deepspeed_tpu.inference.v2.sampling`); this loop's ``rng`` is
+        one key for the whole batch, folded per step.
 
         EOS is not monitored on device: the loop always runs ``n_steps``; the
         caller trims at the first EOS (the fixed-shape scan is what makes the

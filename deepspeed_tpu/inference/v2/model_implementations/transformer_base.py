@@ -19,7 +19,9 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from deepspeed_tpu.inference.v2 import sampling
 from deepspeed_tpu.inference.v2.ragged.manager_configs import KVCacheConfig
+from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import sequence_buckets
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import DSSequenceDescriptor
 from deepspeed_tpu.telemetry import compile_watch
 
@@ -174,18 +176,51 @@ class DSTransformerModelBase:
     def forward(self, ragged_batch):
         """Run the ragged forward; returns logits [n_seqs, vocab] (one row per
         sequence — its final token), and updates the paged KV cache in place."""
-        import jax
+        logits, n = self._forward_padded(ragged_batch)
+        return logits[:n] if n else logits[:0]
 
+    def forward_draw(self, ragged_batch, temperature, seed, draw_index):
+        """:meth:`forward`, then one token drawn per sequence ON THE DEVICE
+        (:mod:`~deepspeed_tpu.inference.v2.sampling`): the bucket's forward
+        program — the one :meth:`forward` runs — and, dispatched right behind
+        it, the draw over its padded logits. Returns the device int32
+        ``[S_bucket]`` ids; rows past the live sequences are padding. The
+        per-sequence ``temperature`` / ``seed`` / ``draw_index`` are host
+        vectors, one entry a live sequence."""
+        logits, _ = self._forward_padded(ragged_batch)
+        return sampling.draw(logits, temperature, seed, draw_index)
+
+    def _forward_padded(self, ragged_batch):
+        """The bucket's program over ``ragged_batch``: its padded
+        ``[S_bucket, vocab]`` logits and the live sequence count."""
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
         bucket = (batch["tok_meta"].shape[1], batch["seq_meta"].shape[0],
                   batch["seq_meta"].shape[1] - 4)
         fn = self._get_compiled(bucket)
         cache = self._state_manager.kv_cache.cache
-        n = int(batch["n_seqs"])
         dev = {"tok_meta": batch["tok_meta"], "seq_meta": batch["seq_meta"]}
         logits, new_cache = fn(self._params, cache, dev)
         self._state_manager.kv_cache.set_cache(new_cache)
-        return logits[:n] if n else logits[:0]
+        return logits, int(batch["n_seqs"])
+
+    def warm_draw(self) -> None:
+        """Compile the draw for every sequence bucket this engine's
+        configuration can produce, placed as the forward's logits are: on the
+        KV pool's mesh, replicated, or on the default device of a mesh-less
+        engine. (Under tensor parallelism the compiler may leave the logits
+        split over the vocabulary; that draw is then built at its first
+        step.)"""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+        pool = self._state_manager.kv_cache.sharding
+        placed = (NamedSharding(pool.mesh, PartitionSpec()) if pool is not None
+                  else SingleDeviceSharding(jax.devices()[0]))
+        sm = self._engine_config.state_manager
+        # a batch holds a token and a KV block for each of its sequences
+        most = min(sm.max_ragged_sequence_count, sm.max_ragged_batch_size,
+                   self._state_manager.kv_cache.num_blocks)
+        for rows in sequence_buckets(most):
+            sampling.compiled(rows, self.vocab_size, placed)
 
     def empty_run(self) -> None:
         """Participate in collectives with zero live tokens (fork engine_v2.py:308).

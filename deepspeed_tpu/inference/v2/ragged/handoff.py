@@ -19,7 +19,9 @@ Header fields::
     seen_tokens  committed token count (KV coverage)
     tokens       full token-id history (prompt + generated so far)
     extra        caller state (serving stashes generation state here:
-                 next_token, sampler rng_state, generated count)
+                 next_token and the generated count, which is where a
+                 sampled stream continues; an older payload's rng_state
+                 is ignored)
     kv           {"shape": [...], "dtype": "bfloat16"} or null (no blocks)
     kv_crc32     CRC-32 of the raw KV bytes (present whenever kv is) —
                  verified at unpack, so a payload corrupted in transit is

@@ -37,6 +37,12 @@ def _pad_to(n: int, mult: int) -> int:
     return max(mult, (n + mult - 1) // mult * mult)
 
 
+def sequence_buckets(most: int):
+    """The padded sequence counts (a bucket's S) that batches of 1 to
+    ``most`` sequences land in."""
+    return sorted({_pad_to(n, 8) for n in range(1, most + 1)})
+
+
 def _pow2_pad(n: int, minimum: int = 4) -> int:
     """Power-of-two bucket: the block-table width grows every block with plain
     granularity padding, which would recompile the decode program every few

@@ -1,0 +1,110 @@
+"""The token draw, on the device.
+
+One function serves every path that turns logits into a token: the ragged
+forward's padded ``[S_bucket, vocab]`` logits (``engine.put_draw``, what the
+serving scheduler's ``put`` path calls) and the rows a speculative verify
+step holds. Only int32 ids cross to the host.
+
+Each row carries its own ``temperature`` (0 = greedy), ``seed`` and
+``draw_index`` (how many tokens its request has emitted over its whole life),
+and its key is ``fold_in(key(seed), draw_index)``: a request's stream is a
+function of ``(seed, position)`` alone, so a row's token does not depend on
+its batch-mates, on the sequence bucket, on which execute path a tick took or
+on which replica holds the request. Temperature is data, not a compile-time
+flag: greedy and sampled rows share one program.
+
+The program is compiled ahead of time (``compiled``) — per row count, vocab
+and the logits' sharding — and kept for the process: a ``jax.jit`` cache
+would key on whether its argument is committed, and a draw that compiles on
+the first request is a stall inside somebody's time to first token.
+"""
+
+import threading
+
+import numpy as np
+
+from deepspeed_tpu.telemetry import compile_watch
+
+_EXECUTABLES = {}
+_LOCK = threading.Lock()
+
+
+def draw_tokens(logits, temperature, seed, draw_index):
+    """``[R, vocab]`` float32 logits and per-row ``temperature`` (float32),
+    ``seed`` (uint32), ``draw_index`` (int32) → ``[R]`` int32 ids. A row with
+    ``temperature > 0`` draws from ``softmax(logits / temperature)``
+    (Gumbel-max, in the logits' float32); any other row is ``argmax(logits)``,
+    first index on a tie."""
+    import jax
+    import jax.numpy as jnp
+
+    def row(l, t, s, i):
+        # threefry named: a seeded stream must not change with the process's
+        # default PRNG implementation
+        key = jax.random.fold_in(jax.random.key(s, impl="threefry2x32"), i)
+        noisy = l / t + jax.random.gumbel(key, l.shape, l.dtype)
+        return jnp.argmax(jnp.where(t > 0, noisy, l)).astype(jnp.int32)
+
+    with jax.named_scope("draw"):  # its device time, by name, in a trace
+        return jax.vmap(row)(logits, temperature, seed, draw_index)
+
+
+def compiled(rows: int, vocab: int, sharding):
+    """The draw's executable for ``[rows, vocab]`` logits placed as
+    ``sharding``; built on the first call, then shared by every engine of the
+    process."""
+    key = (rows, vocab, sharding)
+    exe = _EXECUTABLES.get(key)
+    if exe is not None:
+        return exe
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    # the per-row vectors arrive as host arrays: replicated wherever the
+    # logits live
+    vec = (NamedSharding(sharding.mesh, PartitionSpec())
+           if isinstance(sharding, NamedSharding) else sharding)
+
+    def build():
+        return jax.jit(draw_tokens).lower(
+            jax.ShapeDtypeStruct((rows, vocab), jnp.float32, sharding=sharding),
+            jax.ShapeDtypeStruct((rows, ), jnp.float32, sharding=vec),
+            jax.ShapeDtypeStruct((rows, ), jnp.uint32, sharding=vec),
+            jax.ShapeDtypeStruct((rows, ), jnp.int32, sharding=vec)).compile()
+
+    with _LOCK:
+        exe = _EXECUTABLES.get(key)
+        if exe is None:
+            cw = compile_watch.get()
+            exe = (build if cw is None else cw.wrap("inference_draw", key[:2], build))()
+            _EXECUTABLES[key] = exe
+    return exe
+
+
+def _padded(values, rows: int, dtype) -> np.ndarray:
+    out = np.zeros(rows, dtype)
+    out[:len(values)] = values
+    return out
+
+
+def draw(logits, temperature, seed, draw_index):
+    """Draw one token per row of the device array ``logits`` ``[R, vocab]``;
+    the per-row vectors may be shorter than R (the live rows: the rest is
+    padding, drawn greedily and ignored). Returns the device ``[R]`` int32
+    ids, still being computed."""
+    rows, vocab = logits.shape
+    return compiled(rows, vocab, logits.sharding)(
+        logits, _padded(temperature, rows, np.float32), _padded(seed, rows, np.uint32),
+        _padded(draw_index, rows, np.int32))
+
+
+def draw_host_rows(rows: np.ndarray, temperature, seed, draw_index) -> np.ndarray:
+    """:func:`draw` for logits rows held on the HOST (a speculative verify
+    step's): the same program, fed from the host, fetched at once. Rows are
+    padded to a multiple of 8 so a verify feed's width is not a program."""
+    import jax.numpy as jnp
+    n = rows.shape[0]
+    padded = np.zeros((-(-n // 8) * 8, rows.shape[1]), np.float32)
+    padded[:n] = rows
+    return np.asarray(draw(jnp.asarray(padded), temperature, seed, draw_index))[:n]

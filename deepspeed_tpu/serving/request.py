@@ -99,8 +99,11 @@ class Request:
     ``deadline_s`` is a *relative* budget from submission; the scheduler
     enforces the absolute ``deadline`` (monotonic clock) at every tick and
     mid-decode. ``max_new_tokens``/``eos_token_id``/``temperature``/``seed``
-    are per-request sampling parameters (the seed feeds a private numpy
-    stream so concurrent requests sample independently).
+    are per-request sampling parameters. The request's stream is private and
+    positional: token g is drawn on the device with the key
+    ``fold_in(key(seed), g)`` (``inference/v2/sampling.py``; the seed's low
+    32 bits), so the same ``(prompt, seed, temperature)`` gives the same
+    tokens in any batch, on any replica, resumed from a handoff or not.
     """
 
     def __init__(self,
@@ -206,7 +209,9 @@ class Request:
         self._deferred = 0            # consecutive ticks skipped under pressure
         self._last_touch_s = self.arrival_s  # eviction coldness ordering
         self._last_token_s: Optional[float] = None  # ITL measurement
-        self._rng: Optional[np.random.Generator] = None
+        # tokens a donor generated before a handoff brought the request here:
+        # its draws continue at _draw_base + len(tokens)
+        self._draw_base = 0
         self._spec_ewma: Optional[float] = None  # acceptance EWMA (None = cold)
         # drafting history buffer (prompt + generated), grown incrementally by
         # the scheduler so per-step drafting copies O(new tokens), not O(all)

@@ -37,6 +37,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from deepspeed_tpu import telemetry
+from deepspeed_tpu.inference.v2 import sampling
 from deepspeed_tpu.inference.v2.scheduling_utils import SchedulingError, SchedulingResult
 from deepspeed_tpu.serving.config import ServingConfig
 from deepspeed_tpu.serving.metrics import ServingMetrics
@@ -180,7 +181,8 @@ class ServingScheduler:
                            "peer_fetch_hits", "peer_fetch_rejects",
                            "peer_fetch_blocks", "steals",
                            "tier_demotions", "brownout_demotions",
-                           "parks", "rehydrates", "fair_share_shed")}
+                           "parks", "rehydrates", "fair_share_shed",
+                           "device_draws", "host_draws")}
         self._stopping = False   # no new submits
         self._shutdown = False   # thread exit
         self._stopped = False
@@ -328,6 +330,10 @@ class ServingScheduler:
         from deepspeed_tpu.serving import kv_tiers as _kv_tiers_mod
         self._kv_tiers = _kv_tiers_mod.maybe_create(
             engine, self._config.kv_tiers, metrics=self._metrics)
+
+        # every token is drawn on the device (inference/v2/sampling.py): its
+        # programs are built here, as set-up, never under a first request
+        engine.warm_draw()
 
         engine._serving_scheduler = self
         # armed last: flight_state() must never observe a half-built
@@ -499,9 +505,11 @@ class ServingScheduler:
         thread — the engine is not thread-safe) and the request enters DECODE
         directly; its ``prompt`` is the full token history so context,
         deadline and stats accounting match a locally-prefilled request.
-        Generation state (next input token, sampler RNG state) rides in the
-        payload's ``extra`` block, so greedy AND sampled continuations are
-        token-identical to the single-engine run. ``request.tokens`` holds
+        Generation state (next input token, tokens generated so far) rides in
+        the payload's ``extra`` block; a sampled stream is a function of
+        ``(seed, tokens generated)`` alone, so greedy AND sampled continuations
+        are token-identical to the single-engine run at the same ``seed`` (a
+        ``rng_state`` in an older payload is ignored). ``request.tokens`` holds
         only the tokens generated HERE; the caller merges with the prefill
         leg's.
 
@@ -511,9 +519,8 @@ class ServingScheduler:
         be a strict prefix. The parked KV imports as-is and the request
         enters PREFILL for the un-parked suffix only — the cached turns
         schedule zero prefill chunks. The new turn samples on its own
-        ``seed`` (the parked ``rng_state`` is NOT adopted), so the result is
-        bitwise-identical to an uninterrupted request over the same full
-        prompt at the same seed."""
+        ``seed`` from draw 0, so the result is bitwise-identical to an
+        uninterrupted request over the same full prompt at the same seed."""
         from deepspeed_tpu.inference.v2.ragged.handoff import unpack
         if not isinstance(payload, (bytes, bytearray)):
             # materialize views; a bytearray from the streaming body decoder
@@ -558,12 +565,9 @@ class ServingScheduler:
             req.kv_tier_source = (extra.get("tier") or {}).get("source")
             return self._enqueue(req, trace_id, parent_span_id, handoff)
         req._next = int(extra["next_token"])
-        rng_state = extra.get("rng_state")
-        if rng_state is not None:
-            # exact sampler continuation: the donor's PCG64 state, not a
-            # reseed — sampled handoffs stay token-identical
-            req._rng = np.random.default_rng()
-            req._rng.bit_generator.state = rng_state
+        # the stream continues at the donor's position: draw g of a request
+        # is keyed by (seed, g) wherever it is drawn
+        req._draw_base = int(extra.get("generated") or 0)
         req.decode_steps = int(extra.get("decode_steps") or 0)
         spec = extra.get("spec")
         if spec:
@@ -1614,8 +1618,11 @@ class ServingScheduler:
         emitted: List[int] = []
         accepted = 0
         k = int(feed.size) - 1
+        # row j's token is the request's draw len(tokens) + j whatever came
+        # before it: one call draws them all, the walk keeps what it reaches
+        drawn = self._draw_rows(req, rows, np.arange(len(rows)))
         for j in range(int(feed.size)):
-            tok = self._draw(req, rows[j])
+            tok = int(drawn[j])
             emitted.append(tok)
             if req.eos_token_id is not None and tok == req.eos_token_id:
                 break
@@ -1626,6 +1633,7 @@ class ServingScheduler:
             if int(feed[j + 1]) != tok:
                 break  # rejection: the target model disagrees with the draft
             accepted += 1
+        self._count_draws("host_draws", len(emitted))
         return emitted, accepted
 
     def _permanently_infeasible(self, req: Request) -> Optional[str]:
@@ -1869,9 +1877,12 @@ class ServingScheduler:
         max_context = self._engine._config.state_manager.max_context
 
         def chunk_safe(req):
-            # greedy only (a sampled batch must keep each request on its own
-            # private seeded stream, which a shared device PRNG cannot honor)
-            # and never past max_context: the device loop always runs K steps,
+            # greedy only. A sampled request's stream is keyed by (seed, draw
+            # index) and could be drawn inside the loop as well; it stays out
+            # as a matter of scheduling: a chunk is K steps in which no arrival
+            # is admitted, and sampled traffic is the interactive, open-loop
+            # kind judged on its time to first token (ROADMAP S2b).
+            # And never past max_context: the device loop always runs K steps,
             # and tokens beyond the context window must not reach the client
             seq = engine._state_manager.get_sequence(req.uid)
             return (req.temperature <= 0.0
@@ -1908,10 +1919,10 @@ class ServingScheduler:
                 return
 
         try:
-            logits = self._fetch(engine.put(uids, tokens))
+            ids = self._put_draw(plan)
         except Exception as e:  # pragma: no cover - defensive: the scheduler
             # thread must survive an engine fault; the batch's requests fail
-            logger.exception("serving: engine.put failed; failing the batch")
+            logger.exception("serving: engine.put_draw failed; failing the batch")
             for req, _ in plan:
                 self._finalize(req, RequestState.FAILED, error=f"engine error: {e}")
             return
@@ -1924,13 +1935,19 @@ class ServingScheduler:
             _record_phase_spans()
             for i, (req, toks) in enumerate(plan):
                 if req.state is RequestState.PREFILL:
-                    self._advance_prefill(req, toks, logits[i])
+                    self._advance_prefill(req, toks, int(ids[i]))
                 else:
                     req.decode_steps += 1
-                    nxt = self._draw(req, logits[i])
-                    self._push_token(req, nxt)
-                    if not req.finished:
-                        req._next = nxt
+                    self._push_drawn(req, int(ids[i]))
+
+    def _put_draw(self, plan) -> np.ndarray:
+        """``plan`` through ``engine.put_draw``, fetched: the sequence bucket's
+        int32 ids, entry i the token drawn for ``plan[i]``'s request (a
+        mid-prompt chunk's is meaningless). The put path's step, and how the
+        prefill chunks that share a verify tick run."""
+        reqs = [req for req, _ in plan]
+        return self._fetch(self._engine.put_draw(
+            [req.uid for req in reqs], [toks for _, toks in plan], *self._draw_inputs(reqs)))
 
     def _fetch(self, result) -> np.ndarray:
         """The blocking transfer of an engine call's result to the host, apart
@@ -1947,16 +1964,20 @@ class ServingScheduler:
 
     def _emit_phase(self, spans):
         """Everything a tick does after the fetch (span ``emit``): billing, the
-        per-request phase spans, sampling, pushing tokens, finalizing.
-        ``args``: ``sample_us`` (time inside this tick's :meth:`_sample`
-        calls), ``pushed`` (tokens streamed), ``finished`` (requests)."""
+        per-request phase spans, pushing tokens, finalizing. ``args``:
+        ``device_draws`` (tokens whose ids this tick took from the device),
+        ``host_draws`` and ``sample_us`` (tokens a speculative step drew from
+        rows it holds on the host, and the time inside those
+        :meth:`_draw_rows` calls; both 0 on the ``put`` path), ``pushed``
+        (tokens streamed), ``finished`` (requests)."""
         if spans is None:
             return NULL_SPAN
         return self._emit_phase_live(spans)
 
     @contextmanager
     def _emit_phase_live(self, spans):
-        emit = self._emit = {"sample_us": 0.0, "pushed": 0, "finished": 0}
+        emit = self._emit = {"sample_us": 0.0, "device_draws": 0, "host_draws": 0,
+                             "pushed": 0, "finished": 0}
         args = {}
         try:
             with spans.span("emit", "sched", args):
@@ -1967,22 +1988,47 @@ class ServingScheduler:
         finally:
             self._emit = None
 
-    def _draw(self, req: Request, row: np.ndarray) -> int:
-        """:meth:`_sample`; under a live ``emit`` span its time is summed into
-        ``sample_us``."""
-        emit = self._emit
-        if emit is None:
-            return self._sample(req, row)
-        t0 = time.perf_counter()
-        tok = self._sample(req, row)
-        emit["sample_us"] += (time.perf_counter() - t0) * 1e6
-        return tok
+    @staticmethod
+    def _draw_inputs(reqs, offsets=None):
+        """What the draw (inference/v2/sampling.py) takes for ``reqs``, one
+        entry each: ``temperature``, ``seed`` (its low 32 bits) and
+        ``draw_index`` — the tokens the request has emitted over its whole
+        life, a donor's included, plus ``offsets``."""
+        index = np.array([req._draw_base + len(req.tokens) for req in reqs], np.int32)
+        return (np.array([req.temperature for req in reqs], np.float32),
+                np.array([req.seed & 0xFFFFFFFF for req in reqs], np.uint32),
+                index if offsets is None else index + np.asarray(offsets, np.int32))
 
-    def _advance_prefill(self, req: Request, toks: np.ndarray, last_row) -> None:
+    def _draw_rows(self, req: Request, rows: np.ndarray, offsets) -> np.ndarray:
+        """``req``'s tokens from logits ``rows`` a speculative step holds on
+        the host, row j at draw ``len(req.tokens) + offsets[j]``: the put
+        path's draw, so a request has one stream whatever a tick does. Under
+        a live ``emit`` span its time is summed into ``sample_us``."""
+        t0 = time.perf_counter()
+        drawn = sampling.draw_host_rows(rows, *self._draw_inputs([req] * len(rows), offsets))
+        if self._emit is not None:
+            self._emit["sample_us"] += (time.perf_counter() - t0) * 1e6
+        return drawn
+
+    def _count_draws(self, kind: str, n: int) -> None:
+        """``device_draws``: tokens whose ids came off the device;
+        ``host_draws``: tokens drawn from rows fetched to the host first."""
+        self._counters[kind] += n
+        if self._emit is not None:
+            self._emit[kind] += n
+
+    def _push_drawn(self, req: Request, tok: int) -> None:
+        """Stream a token the device drew; it is the next decode input."""
+        self._count_draws("device_draws", 1)
+        self._push_token(req, tok)
+        if not req.finished:
+            req._next = tok
+
+    def _advance_prefill(self, req: Request, toks: np.ndarray, drawn: int) -> None:
         """Account one executed prefill chunk; on the final chunk: flip to
         DECODE, publish the prompt's blocks (peers sharing the prefix are
         likely already queued behind it — the burst shape), and emit the
-        first token from the chunk's final-position logits. Shared by the
+        first token: ``drawn``, from the chunk's final-position logits. Shared by the
         put and verify execute paths so prefill behavior cannot depend on
         whether a draft rode the same batch."""
         req._fed += toks.size
@@ -1993,10 +2039,7 @@ class ServingScheduler:
             seq = self._engine._state_manager.get_sequence(req.uid)
             if seq is not None:
                 self._publish(req, seq, req.prompt, seq.seen_tokens)
-        nxt = self._draw(req, last_row)
-        self._push_token(req, nxt)
-        if not req.finished:
-            req._next = nxt
+        self._push_drawn(req, drawn)
 
     def _push_burst(self, req: Request, toks) -> None:
         """Stream a multi-token burst (a decode chunk's kept tokens, a verify
@@ -2041,9 +2084,7 @@ class ServingScheduler:
             # prefill put overwrites the observer slots
             verify_s = self._last_dispatch_s
             verify_amnesty_s = self._last_dispatch_amnesty_s
-            prefill_logits = (self._fetch(engine.put(
-                [req.uid for req, _ in prefill_plan],
-                [toks for _, toks in prefill_plan])) if prefill_plan else None)
+            prefill_ids = self._put_draw(prefill_plan) if prefill_plan else None
         except Exception as e:  # pragma: no cover - defensive: same contract
             # as the put path — the scheduler thread must survive
             logger.exception("serving: engine verify tick failed; failing the batch")
@@ -2062,8 +2103,8 @@ class ServingScheduler:
                                       for req, t in prefill_plan])
             alpha = self._config.speculative.accept_alpha
             # sample/accept BEFORE any push: span token counts must be final when
-            # the root span closes, and each request's private stream makes the
-            # per-request draw order independent of processing order
+            # the root span closes, and each request's positional stream makes
+            # its draws independent of processing order
             accepts = {id(req): self._spec_accept(req, toks, rows)
                        for (req, toks), rows in zip(decode_plan, per_seq)}
             record_spans(counts=[len(accepts[id(req)][0]) if id(req) in accepts
@@ -2103,7 +2144,7 @@ class ServingScheduler:
                         self._metrics.spec_tokens_per_step.observe(len(emitted))
                 self._push_burst(req, emitted)
             for i, (req, toks) in enumerate(prefill_plan):
-                self._advance_prefill(req, toks, prefill_logits[i])
+                self._advance_prefill(req, toks, int(prefill_ids[i]))
 
     def _spec_accept_tree(self, req: Request, tree, rows, ids):
         """The acceptance rule over one verified token tree. Walk from the
@@ -2121,9 +2162,12 @@ class ServingScheduler:
         emitted: List[int] = []
         path: List[int] = []
         node = 0
+        if rows is not None:
+            # a node's token is the request's draw len(tokens) + depth(node),
+            # whichever branch the walk arrives by: one call draws every node
+            ids = self._draw_rows(req, rows, tree.depths)
         while True:
-            tok = (int(ids[node]) if rows is None
-                   else self._draw(req, rows[node]))
+            tok = int(ids[node])
             emitted.append(tok)
             if req.eos_token_id is not None and tok == req.eos_token_id:
                 break
@@ -2134,6 +2178,7 @@ class ServingScheduler:
                 break  # rejection: the target disagrees with every branch
             path.append(child)
             node = child
+        self._count_draws("device_draws" if rows is None else "host_draws", len(emitted))
         return emitted, path, node
 
     def _execute_verify_tree(self, plan: List[Tuple[Request, np.ndarray]],
@@ -2162,8 +2207,8 @@ class ServingScheduler:
                 tree = TokenTree.chain(toks)
             trees.append(tree)
         # the device-argmax program only when EVERY decode entry is greedy: a
-        # sampled request needs the full rows for its private stream (greedy
-        # peers argmax the same f32 rows host-side — the identical result)
+        # sampled request needs the full rows for its draw (greedy peers then
+        # take the same draw at temperature 0: argmax of the same f32 rows)
         greedy = all(req.temperature <= 0.0 for req, _ in decode_plan)
         try:
             per_seq = engine.verify_tree([req.uid for req, _ in decode_plan],
@@ -2172,9 +2217,7 @@ class ServingScheduler:
             # prefill put overwrites the observer slots
             verify_s = self._last_dispatch_s
             verify_amnesty_s = self._last_dispatch_amnesty_s
-            prefill_logits = (self._fetch(engine.put(
-                [req.uid for req, _ in prefill_plan],
-                [toks for _, toks in prefill_plan])) if prefill_plan else None)
+            prefill_ids = self._put_draw(prefill_plan) if prefill_plan else None
         except Exception as e:  # pragma: no cover - defensive: same contract
             # as the put path — the scheduler thread must survive
             logger.exception("serving: tree-verify tick failed; failing the batch")
@@ -2193,8 +2236,8 @@ class ServingScheduler:
                                       for req, t in prefill_plan])
             alpha = self._config.speculative.accept_alpha
             # sample/accept BEFORE any push: span token counts must be final when
-            # the root span closes, and each request's private stream makes the
-            # per-request draw order independent of processing order
+            # the root span closes, and each request's positional stream makes
+            # its draws independent of processing order
             accepts = {id(req): self._spec_accept_tree(req, tree,
                                                        res["rows"], res["ids"])
                        for (req, _), tree, res in zip(decode_plan, trees, per_seq)}
@@ -2262,7 +2305,7 @@ class ServingScheduler:
                         self._metrics.spec_tree_accept_depth.observe(accepted)
                 self._push_burst(req, emitted)
             for i, (req, toks) in enumerate(prefill_plan):
-                self._advance_prefill(req, toks, prefill_logits[i])
+                self._advance_prefill(req, toks, int(prefill_ids[i]))
 
     @staticmethod
     def _kept_tokens(req: Request, row) -> int:
@@ -2277,18 +2320,6 @@ class ServingScheduler:
                     or len(req.tokens) + n >= req.max_new_tokens):
                 break
         return n
-
-    @staticmethod
-    def _sample(req: Request, row: np.ndarray) -> int:
-        if req.temperature <= 0.0:
-            return int(np.argmax(row))
-        if req._rng is None:
-            req._rng = np.random.default_rng(req.seed)
-        z = row.astype(np.float64) / req.temperature
-        z -= z.max()
-        p = np.exp(z)
-        p /= p.sum()
-        return int(req._rng.choice(row.shape[0], p=p))
 
     def _push_token(self, req: Request, tok: int, record_itl: bool = True) -> None:
         now = time.monotonic()
@@ -2316,17 +2347,16 @@ class ServingScheduler:
 
     def _export_handoff(self, req: Request) -> bytes:
         """Portable continuation payload for a DONE handoff-requested request:
-        full token history, KV blocks, next decode input and the sampler's
-        exact RNG state — everything :meth:`submit_resume` on a decode-role
-        peer needs to continue token-identically. Runs on the scheduler
-        thread, before the sequence's KV is flushed."""
-        extra = {"generated": len(req.tokens)}
+        full token history, KV blocks, next decode input and how many tokens
+        the request has generated over its whole life (the position its
+        sampled stream continues from) — everything :meth:`submit_resume` on
+        a decode-role peer needs to continue token-identically. Runs on the
+        scheduler thread, before the sequence's KV is flushed."""
+        extra = {"generated": req._draw_base + len(req.tokens)}
         if req.finish_reason == "length" and req.tokens:
             # an eos/context finish is not continuable; length means the donor
             # stopped at ITS cap with the last kept token as the next input
             extra["next_token"] = int(req.tokens[-1])
-        if req._rng is not None:
-            extra["rng_state"] = req._rng.bit_generator.state
         # the dispatch count rides every handoff (tokens-per-step accounting
         # must survive the migration whether or not the donor ever drafted)
         extra["decode_steps"] = req.decode_steps
@@ -2361,20 +2391,18 @@ class ServingScheduler:
         handoff export plus a versioned ``tier`` record (which tier the KV
         was resident on at finish — what the rehydrate response reports).
         Unlike a handoff, an eos finish IS parkable: the next turn continues
-        from the full history via a rehydrate prompt, not from ``next_token``.
-        The parked ``rng_state`` is informational — a rehydrate samples on
-        its own seed so the returning turn matches a cold run bitwise."""
+        from the full history via a rehydrate prompt, not from ``next_token``,
+        and samples on its own seed from draw 0, so the returning turn matches
+        a cold run bitwise."""
         from deepspeed_tpu.inference.v2.ragged.handoff import (PARK_VERSION,
                                                                TIER_FIELD_VERSION)
         sm = self._engine._state_manager
         source = sm.sequence_tier(req.uid)  # capture BEFORE export restores
-        extra = {"generated": len(req.tokens),
+        extra = {"generated": req._draw_base + len(req.tokens),
                  "decode_steps": req.decode_steps,
                  "tier": {"v": TIER_FIELD_VERSION, "source": source}}
         if req.finish_reason == "length" and req.tokens:
             extra["next_token"] = int(req.tokens[-1])
-        if req._rng is not None:
-            extra["rng_state"] = req._rng.bit_generator.state
         tokens = [int(t) for t in req.prompt.tolist()] + [int(t) for t in req.tokens]
         return self._engine.export_sequence(req.uid, tokens=tokens, extra=extra,
                                             seen_tokens=len(tokens) - 1,

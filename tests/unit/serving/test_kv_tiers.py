@@ -70,13 +70,13 @@ def test_park_rehydrate_bitwise_across_replicas(make_engine, tmp_path,
     sched_b = ServingScheduler(eng_b, _tiered_config(tmp_path / "b"),
                                start=False)
     fed_b = []
-    real_put = eng_b.put
+    real_put = eng_b.put_draw  # the scheduler's put path
 
     def counting_put(uids, tokens, *a, **kw):
         fed_b.extend(int(np.asarray(t).size) for t in tokens)
         return real_put(uids, tokens, *a, **kw)
 
-    eng_b.put = counting_put
+    eng_b.put_draw = counting_put
     req2 = sched_b.submit_resume(req1.park_payload, prompt=p2,
                                  max_new_tokens=6, temperature=temperature,
                                  seed=9)

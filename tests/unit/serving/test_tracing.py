@@ -272,10 +272,12 @@ def test_tick_spans_hold_their_phases_in_order(make_engine):
         for a, b in zip(children, children[1:]):
             assert a["ts_us"] + a["dur_us"] <= b["ts_us"], "phases do not overlap"
         emit = children[-1]
-        assert 0 < emit["args"]["sample_us"] <= emit["dur_us"]
+        # the token was drawn on the device: no host-side draw, and the fetch
+        # brought the bucket's 8 int32 ids, not a row of logits
+        assert emit["args"]["sample_us"] == 0 and emit["args"]["device_draws"] == 1
         assert emit["args"]["pushed"] == 1
         assert children[2]["args"]["sequences"] == 1
-        assert children[4]["args"]["bytes"] > 0
+        assert children[4]["args"]["bytes"] == 4 * 8
     assert ticks[0]["args"]["tokens"] == 5 and ticks[1]["args"]["tokens"] == 1
     assert sum(s["args"]["finished"] for s in spans if s["name"] == "emit") == 1
     first_admit = min((s for s in spans if s["name"] == "admit"), key=lambda s: s["ts_us"])
