@@ -71,7 +71,7 @@ class InferenceEngineV2:
         # cost-attribution hook (telemetry/ledger.py + perf/observed.py): a
         # scheduler with an active telemetry session installs a callable
         # ``(kind, n_seqs, n_tokens, wall_seconds)`` invoked around every
-        # jitted dispatch (put / decode_loop / verify / verify_tree). None —
+        # jitted dispatch (put / decode_loop / verify_tree). None —
         # the default, and always the case with telemetry off — costs one
         # attribute load per dispatch.
         self.dispatch_observer = None
@@ -171,7 +171,7 @@ class InferenceEngineV2:
     # Spans (cat ``inference``; live only under a telemetry session, and then
     # also ``dstpu.inference.*`` annotations in a jax.profiler trace):
     # ``prepare`` is the host work from entry to just before the jitted call;
-    # ``put`` / ``decode_loop`` / ``verify`` / ``verify_tree`` time the DISPATCH
+    # ``put`` / ``decode_loop`` / ``verify_tree`` time the DISPATCH
     # of that call (plus, where the method itself fetches, the fetch) — not the
     # device: JAX returns before the device finishes, and the caller's
     # ``np.asarray`` is where the wait shows.
@@ -355,21 +355,11 @@ class InferenceEngineV2:
         generated tokens ``[n_seqs, n_steps]``. ``temperature`` 0 = greedy;
         > 0 samples categorically with the (per-step folded) ``rng``.
 
-        **Multi-token verify feed** (speculative decoding): an entry may carry
-        its next-input token followed by k draft tokens. Any entry wider than
-        one token switches the call into verify mode — ``n_steps`` must be 1,
-        greedy only — where ONE ragged forward scores every fed position and
-        the return value is a list of per-sequence int32 arrays: element i
-        holds, for each of sequence i's ``1+k_i`` positions, the target
-        model's greedy next token after consuming the feed up to and including
-        that position (``out[i][j] == argmax`` after ``feed_i[:j+1]``). The
-        caller accepts the longest prefix where ``out[i][j] == feed_i[j+1]``
-        and rolls back the rejected tail via :meth:`rollback`. All-single-token
-        feeds keep the old on-device scan path unchanged — the k=0 fast case.
-        Sampled verification consumes :meth:`verify` logits instead and draws
-        each emitted token at its request's own ``(seed, draw_index)``
-        (:mod:`~deepspeed_tpu.inference.v2.sampling`); this loop's ``rng`` is
-        one key for the whole batch, folded per step.
+        Each entry is ONE token: a feed of several (a next-input token plus
+        drafts) is a speculative verify step, :meth:`verify_tree`'s. A
+        sampled request is drawn at its own ``(seed, draw_index)`` by
+        :meth:`put_draw`; this loop's ``rng`` is one key for the whole batch,
+        folded per step.
 
         EOS is not monitored on device: the loop always runs ``n_steps``; the
         caller trims at the first EOS (the fixed-shape scan is what makes the
@@ -377,23 +367,11 @@ class InferenceEngineV2:
         """
         batch_uids = list(batch_uids)
         batch_tokens = [np.atleast_1d(np.asarray(t)) for t in batch_tokens]
-        if any(t.size < 1 for t in batch_tokens):
-            raise ValueError("decode_loop needs at least one next-input token per sequence")
+        if any(t.size != 1 for t in batch_tokens):
+            raise ValueError("decode_loop takes exactly one next-input token per "
+                             "sequence (a multi-token feed is verify_tree's)")
         if n_steps < 1:
             raise ValueError("n_steps must be >= 1")
-        if any(t.size != 1 for t in batch_tokens):
-            if n_steps != 1:
-                raise ValueError("a multi-token verify feed runs exactly one step "
-                                 "(n_steps=1); the on-device scan takes single-token "
-                                 "entries only")
-            if temperature > 0:
-                raise ValueError("the multi-token verify feed is greedy; sampled "
-                                 "verification consumes engine.verify() logits "
-                                 "host-side")
-            # device-side argmax: only [1+k] int32 ids per sequence cross the
-            # host boundary, not [1+k, vocab] float32 logits
-            return self.verify(batch_uids, batch_tokens, do_checks=do_checks,
-                               greedy=True)
         spans, observer, metrics = self._telemetry_sinks()
         prep = None
         if spans is not None:
@@ -457,62 +435,25 @@ class InferenceEngineV2:
         return tokens[:, :len(batch_uids)].T
 
     # ------------------------------------------------------ speculative verify --
-    def verify(self, batch_uids: Iterable[int], batch_tokens: Iterable,
-               do_checks: bool = True, greedy: bool = False) -> List[np.ndarray]:
-        """Speculative-decoding verify step: feed each sequence its next-input
-        token plus draft tokens (``batch_tokens[i]`` holds ``1+k_i`` ids)
-        through ONE ragged forward — the chunked-prefill multi-token feed path
-        — and return per-position logits: a list of float32 arrays, element i
-        shaped ``[1+k_i, vocab]`` where row j scores the token AFTER
-        ``batch_tokens[i][:j+1]``.
-
-        ``greedy=True`` returns per-position ARGMAX ids instead (int32 arrays
-        shaped ``[1+k_i]``): the argmax runs on device, so the host transfer
-        is ``T`` ids rather than a ``[T, vocab]`` float32 materialization —
-        the greedy verify path (decode_loop's multi-token branch) never pays
-        the full-logit transfer.
-
-        Every fed position's KV is written and committed (``seen_tokens``
-        advances by ``1+k_i``); the caller decides the accepted prefix and
-        truncates the rejected tail with :meth:`rollback` — the same
-        write-then-truncate mechanism chunk-decode over-run relies on."""
-        batch_uids = list(batch_uids)
-        batch_tokens = [np.atleast_1d(np.asarray(t)) for t in batch_tokens]
-        spans, observer, metrics = self._telemetry_sinks()
-        n_tokens = int(sum(t.size for t in batch_tokens))
-        self._prepare_forward(spans, batch_uids, batch_tokens, do_checks, n_tokens)
-        args = self._dispatch_args(spans, batch_uids, tokens=n_tokens)
-        with _tel_live_span(spans, "verify", "inference", args):
-            if observer is not None:
-                _t0 = _tel_now_us()
-            # [T, vocab] logits, or [T] argmax ids when greedy
-            rows = np.asarray(self._model.forward_verify(self._batch, greedy=greedy))
-            if observer is not None:
-                observer("verify", len(batch_uids), n_tokens, (_tel_now_us() - _t0) / 1e6)
-            self._post_forward(batch_uids, release=False)
-        if metrics is not None:
-            self._write_telemetry(metrics, batch_tokens=n_tokens)
-        # insertion order is batch order: each sequence's positions are one
-        # contiguous token-major run
-        out, offset = [], 0
-        for tokens in batch_tokens:
-            out.append(rows[offset:offset + tokens.size])
-            offset += tokens.size
-        return out
-
     def verify_tree(self, batch_uids: Iterable[int], trees: Iterable,
-                    greedy: bool = False, do_checks: bool = True) -> List[dict]:
-        """Token-tree verify: feed each sequence a draft TREE
+                    greedy: bool = False, do_checks: bool = True,
+                    hidden: bool = True) -> List[dict]:
+        """The speculative verify step: feed each sequence a draft TREE
         (:class:`~deepspeed_tpu.inference.v2.spec.tree.TokenTree`, root =
-        next-input token) through ONE ragged forward under the tree-attention
-        mask — multiple candidate branches priced for the cost of one
-        dispatch. Returns one dict per sequence:
+        next-input token; a linear ``1+k`` feed is ``TokenTree.chain``)
+        through ONE ragged forward that scores every node. The batch's shape
+        picks the program: when every tree ``is_chain`` the feed is a plain
+        causal one (the attention arm ``put`` takes at that bucket, the Pallas
+        kernel on a TPU); one branching tree puts the whole batch under the
+        ancestor mask, several candidate branches priced by one dispatch.
+        Returns one dict per sequence:
 
         - ``rows``:   float32 ``[n_nodes, vocab]`` logits (None when greedy) —
           row j scores the token AFTER node j's root path;
         - ``ids``:    int32 ``[n_nodes]`` device-argmax ids (greedy only);
         - ``hidden``: float32 ``[n_nodes, hidden]`` final residual states —
-          the learned draft head's input for the next draft step.
+          the learned draft head's input for the next draft step (None, and
+          never fetched, with ``hidden=False``).
 
         Every node's KV is written at slot ``seen + node_index`` and committed
         (``seen_tokens`` advances by ``n_nodes``); the caller walks the tree
@@ -523,25 +464,29 @@ class InferenceEngineV2:
         spans, observer, metrics = self._telemetry_sinks()
         n_tokens = int(sum(t.size for t in trees))
         self._prepare_forward(spans, batch_uids, [t.tokens for t in trees], do_checks,
-                              n_tokens, trees=trees)
+                              n_tokens,
+                              trees=None if all(t.is_chain for t in trees) else trees)
         args = self._dispatch_args(spans, batch_uids, tokens=n_tokens)
         with _tel_live_span(spans, "verify_tree", "inference", args):
             if observer is not None:
                 _t0 = _tel_now_us()
-            rows, hidden = self._model.forward_verify_tree(self._batch, greedy=greedy)
-            rows, hidden = np.asarray(rows), np.asarray(hidden)
+            rows, states = self._model.forward_verify(self._batch, greedy=greedy)
+            rows = np.asarray(rows)
+            states = np.asarray(states) if hidden else None
             if observer is not None:
                 observer("verify_tree", len(batch_uids), n_tokens,
                          (_tel_now_us() - _t0) / 1e6)
             self._post_forward(batch_uids, release=False)
         if metrics is not None:
             self._write_telemetry(metrics, batch_tokens=n_tokens)
+        # insertion order is batch order: each sequence's nodes are one
+        # contiguous token-major run
         out, offset = [], 0
         for tree in trees:
             n = tree.size
             out.append({"rows": None if greedy else rows[offset:offset + n],
                         "ids": rows[offset:offset + n] if greedy else None,
-                        "hidden": hidden[offset:offset + n]})
+                        "hidden": states[offset:offset + n] if hidden else None})
             offset += n
         return out
 
@@ -569,8 +514,7 @@ class InferenceEngineV2:
                                    [seen0 + s for s, _ in copies],
                                    [seen0 + d for _, d in copies])
         rejected = n_fed - 1 - len(path)
-        if rejected > 0:
-            seq_desc.rollback(rejected)
+        self.rollback(uid, rejected)  # with its sliding-window check
         return rejected
 
     def rollback(self, uid: int, n_tokens: int) -> None:
@@ -709,7 +653,7 @@ class InferenceEngineV2:
         """The engine's jitted device programs as raw ``jax.jit`` callables
         (``.lower()``-able): ``forward`` keyed by ``(T, S, MB)`` pad bucket,
         ``decode_loop`` keyed by ``(bucket, n_steps, sampled)`` and ``verify``
-        keyed by ``("verify", bucket)``. This is the official hook for
+        keyed by ``("verify", bucket, tree, greedy)``. This is the official hook for
         HLO-level analysis (the deepspeed_tpu/perf/ gates); the jit-cache
         entries themselves may be compile-watch wrappers shared with
         telemetry and cannot lower."""
@@ -725,16 +669,12 @@ class InferenceEngineV2:
         return self._model.lower_decode_loop(n_steps, bucket=bucket,
                                              temperature=temperature)
 
-    def lower_verify_step(self, bucket=None):
-        """``jax.stages.Lowered`` of the speculative verify program (one
-        ragged forward unembedding every fed position). Never executes."""
-        return self._model.lower_verify_step(bucket)
-
-    def lower_tree_verify(self, bucket=None, greedy: bool = False):
-        """``jax.stages.Lowered`` of the token-tree verify program (one
-        ragged forward under the tree-attention mask, unembedding every node
-        and returning the draft head's hidden states). Never executes."""
-        return self._model.lower_tree_verify(bucket, greedy=greedy)
+    def lower_verify(self, bucket=None, tree: bool = False, greedy: bool = False):
+        """``jax.stages.Lowered`` of the speculative verify step (one ragged
+        forward unembedding every fed position and returning the draft head's
+        hidden states): the causal program, or with ``tree`` the one under
+        the ancestor mask. Never executes."""
+        return self._model.lower_verify(bucket, tree=tree, greedy=greedy)
 
     # -------------------------------------------------------------- empty_run --
     def empty_run(self) -> None:

@@ -253,25 +253,21 @@ class DSTransformerModelBase:
     def _lowerable_kind(key) -> str:
         """Program-kind classification of a ``_compiled``/``_lowerable`` jit
         cache key: ``(T, S, MB)`` int tuples are forward programs,
-        ``(bucket, n_steps, sampled)`` are decode loops, and every 2-tuple
-        with a string head is named after that head (``verify``,
-        ``verify_greedy``, ``tree_verify``, ``tree_verify_greedy``,
-        ``compact``)."""
-        if isinstance(key, tuple) and len(key) == 3 and isinstance(key[0], tuple):
-            return "decode_loop"
-        if isinstance(key, tuple) and len(key) == 2 and isinstance(key[0], str):
+        ``(bucket, n_steps, sampled)`` are decode loops, and a tuple with a
+        string head is named after that head (``verify``, ``compact``)."""
+        if isinstance(key[0], str):
             return key[0]
-        return "forward"
+        return "decode_loop" if isinstance(key[0], tuple) else "forward"
 
     def lowerable_callables(self):
         """Raw ``jax.jit`` callables (they support ``.lower()``) grouped by
         program kind and keyed exactly like ``_compiled``: forward programs by
         ``(T, S, MB)`` bucket, decode programs by ``(bucket, n_steps,
-        sampled)``, the speculative verify family by ``("verify"|
-        "verify_greedy"|"tree_verify"|"tree_verify_greedy", bucket)`` and the
-        accepted-path KV re-pack by ``("compact", n_pairs)``. The official
-        hook for HLO-level analysis (deepspeed_tpu/perf/) — the entries in
-        ``_compiled`` may be compile-watch wrappers, which cannot lower."""
+        sampled)``, the speculative verify step by ``("verify", bucket,
+        tree, greedy)`` and the accepted-path KV re-pack by ``("compact",
+        n_pairs)``. The official hook for HLO-level analysis
+        (deepspeed_tpu/perf/) — the entries in ``_compiled`` may be
+        compile-watch wrappers, which cannot lower."""
         out = {"forward": {}, "decode_loop": {}, "verify": {}}
         for k, v in self._lowerable.items():
             out.setdefault(self._lowerable_kind(k), {})[k] = v
@@ -321,33 +317,19 @@ class DSTransformerModelBase:
         return fn.lower(self._params, self._state_manager.kv_cache.cache, dev,
                         jnp.float32(temperature), jax.random.PRNGKey(0))
 
-    def lower_verify_step(self, bucket=None):
-        """Lower the speculative verify program at ``bucket`` (default
-        smallest) — the same ``_verify_impl`` jit :meth:`forward_verify`
-        runs. Never executes."""
-        import jax
+    def lower_verify(self, bucket=None, tree: bool = False, greedy: bool = False):
+        """Lower the speculative verify step at ``bucket`` (default smallest)
+        — the ``_verify_impl`` jit :meth:`forward_verify` runs: the causal
+        program, or with ``tree`` the ancestor-mask one (its synthetic
+        ``tree_meta`` is a chain; lowering consumes avals only, and the mask
+        program is the same for every tree shape at a bucket). Never
+        executes."""
         dev = self._synthetic_batch(bucket)
-        key = ("verify", (dev["tok_meta"].shape[1], dev["seq_meta"].shape[0],
-                          dev["seq_meta"].shape[1] - 4))
-        fn = self._lowerable.get(key) or jax.jit(self._verify_impl,
-                                                 donate_argnums=(1, ))
-        return fn.lower(self._params, self._state_manager.kv_cache.cache, dev)
-
-    def lower_tree_verify(self, bucket=None, greedy: bool = False):
-        """Lower the token-tree verify program at ``bucket`` (default
-        smallest) — the same ``_tree_verify_impl`` jit
-        :meth:`forward_verify_tree` runs. The synthetic ``tree_meta`` is a
-        chain (lowering consumes avals only; the mask program is identical
-        for every tree shape at a bucket). Never executes."""
-        import jax
-        dev = self._synthetic_batch(bucket)
-        T = dev["tok_meta"].shape[1]
-        dev["tree_meta"] = np.stack([np.arange(-1, T - 1, dtype=np.int32),
-                                     np.arange(T, dtype=np.int32)])
-        key = ("tree_verify_greedy" if greedy else "tree_verify",
-               (T, dev["seq_meta"].shape[0], dev["seq_meta"].shape[1] - 4))
-        fn = self._lowerable.get(key) or jax.jit(
-            partial(self._tree_verify_impl, greedy=greedy), donate_argnums=(1, ))
+        if tree:
+            T = dev["tok_meta"].shape[1]
+            dev["tree_meta"] = np.stack([np.arange(-1, T - 1, dtype=np.int32),
+                                         np.arange(T, dtype=np.int32)])
+        fn = self._verify_jit(self._verify_key(dev, greedy))
         return fn.lower(self._params, self._state_manager.kv_cache.cache, dev)
 
     # ------------------------------------------------------------ decode loop --
@@ -455,119 +437,77 @@ class DSTransformerModelBase:
         return logits.astype(jnp.float32), cache
 
     # ----------------------------------------------------- speculative verify --
-    def forward_verify(self, ragged_batch, greedy: bool = False):
-        """The speculative-decoding verify forward: identical layer compute to
-        :meth:`forward`, but EVERY token position is unembedded — returns
-        logits ``[T_bucket, vocab]`` (row t scores the token AFTER batch
-        position t), so one ragged pass prices a next-input token plus its k
-        draft tokens per sequence. The KV cache is updated in place for every
-        fed position, including drafts that turn out wrong — the caller rolls
-        those back by truncating ``seen_tokens`` (the KV is overwritten when
-        the correct tokens are fed at the same positions).
+    @staticmethod
+    def _verify_key(dev, greedy):
+        """``("verify", bucket, tree, greedy)``: the two facts that pick the
+        verify program at a bucket are named in its key."""
+        bucket = (dev["tok_meta"].shape[1], dev["seq_meta"].shape[0],
+                  dev["seq_meta"].shape[1] - 4)
+        return ("verify", bucket, "tree_meta" in dev, bool(greedy))
 
-        ``greedy=True`` runs the device-argmax variant instead: the ``[T,
-        vocab]`` float32 logits stay on device and only ``[T]`` int32 token
-        ids cross to the host — the greedy verify path's host transfer drops
-        from ``T * vocab * 4`` bytes to ``T * 4`` (memoed in the
-        ``spec_verify_step`` perf budget)."""
+    def _verify_jit(self, key):
+        """The raw jit of the verify program ``key`` names: the engine's own
+        entry when that program has run, else a fresh one."""
         import jax
+        return self._lowerable.get(key) or jax.jit(
+            partial(self._verify_impl, greedy=key[3]), donate_argnums=(1, ))
+
+    def forward_verify(self, ragged_batch, greedy: bool = False):
+        """The speculative verify step: the layer compute of :meth:`forward`
+        with EVERY fed position unembedded, so one ragged pass prices each
+        sequence's next-input token plus its drafts. Which program runs is read
+        off the batch: one that carries ``tree_meta`` (the ragged wrapper packs
+        it when a sequence was inserted with ``tree=``) takes the ancestor
+        mask — a node attends to the committed prefix plus its own root path,
+        so sibling branches sharing the batch cannot see each other — and one
+        without it is a plain causal feed through :meth:`_paged_attention`,
+        the arm :meth:`forward` would take at that bucket.
+
+        Returns device arrays ``(rows_or_ids, hidden)``: float32 logits ``[T,
+        vocab]`` (row t scores the token AFTER batch position t's path) or,
+        with ``greedy``, their int32 argmax ``[T]`` (``T * 4`` bytes to fetch
+        instead of ``T * vocab * 4``), and the final residual ``[T, hidden]``
+        float32 a learned draft head reads. KV is written for every fed
+        position, wrong drafts included (a tree node at slot ``seen +
+        node_index``); the caller keeps the accepted path and truncates the
+        rest (``engine_v2.compact_accepted``)."""
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
-        bucket = (batch["tok_meta"].shape[1], batch["seq_meta"].shape[0],
-                  batch["seq_meta"].shape[1] - 4)
-        key = ("verify_greedy" if greedy else "verify", bucket)
+        dev = {k: batch[k] for k in ("tok_meta", "seq_meta", "tree_meta") if k in batch}
+        key = self._verify_key(dev, greedy)
         if key not in self._compiled:
-            fn = jax.jit(self._verify_greedy_impl if greedy else self._verify_impl,
-                         donate_argnums=(1, ))
-            self._lowerable[key] = fn
+            fn = self._lowerable[key] = self._verify_jit(key)
             cw = compile_watch.get()
             if cw is not None:
                 fn = cw.wrap("inference_verify", key, fn)
             self._compiled[key] = fn
-        cache = self._state_manager.kv_cache.cache
-        dev = {"tok_meta": batch["tok_meta"], "seq_meta": batch["seq_meta"]}
-        out, new_cache = self._compiled[key](self._params, cache, dev)
-        self._state_manager.kv_cache.set_cache(new_cache)
-        return out
-
-    def _verify_impl(self, params, cache, batch):
-        """Same program body as :meth:`_forward_impl` minus the last-token
-        gather: the verify step needs logits at all 1+k fed positions."""
-        import jax.numpy as jnp
-        from deepspeed_tpu.inference.v2.quantization import dequantize_tree
-
-        params = dequantize_tree(params)
-        batch = self._unpack_batch(batch)
-        x = self.embed(params, batch["input_ids"])
-        attn = partial(self._paged_attention, batch=batch)
-        for li in range(self.num_layers):
-            x, cache = self.layer_forward(params, li, x, cache, attn, batch)
-        logits = self.unembed(params, x)  # ALL positions, token-major
-        return logits.astype(jnp.float32), cache
-
-    def _verify_greedy_impl(self, params, cache, batch):
-        """Greedy verify: argmax on device, so only ``[T]`` int32 ids transfer
-        to the host instead of the full ``[T, vocab]`` float32 logits."""
-        import jax.numpy as jnp
-        logits, cache = self._verify_impl(params, cache, batch)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
-
-    # ------------------------------------------------------ tree verification --
-    def forward_verify_tree(self, ragged_batch, greedy: bool = False):
-        """Token-tree verify (spec/tree.py): one ragged forward scores every
-        node of each sequence's draft TREE under a tree-attention mask — a
-        node attends to the committed prefix plus its own ancestor path only,
-        so sibling branches cannot see each other even though they share the
-        batch. Requires the batch to carry ``tree_meta`` (the ragged wrapper
-        packs it when a tree is inserted).
-
-        Returns ``(rows_or_ids, hidden)``: per-node float32 logits ``[T,
-        vocab]`` (or, with ``greedy=True``, device-argmax int32 ids ``[T]``)
-        plus the final residual hidden state ``[T, hidden]`` float32 — the
-        learned draft head's input for the NEXT draft step. KV is written at
-        slot positions ``seen + node_index``; the caller re-packs the accepted
-        path with ``engine_v2.compact_accepted``."""
-        import jax
-        batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
-        if "tree_meta" not in batch:
-            raise ValueError("forward_verify_tree needs a batch with tree_meta "
-                             "(insert sequences with tree=(parents, depths))")
-        bucket = (batch["tok_meta"].shape[1], batch["seq_meta"].shape[0],
-                  batch["seq_meta"].shape[1] - 4)
-        key = ("tree_verify_greedy" if greedy else "tree_verify", bucket)
-        if key not in self._compiled:
-            fn = jax.jit(partial(self._tree_verify_impl, greedy=greedy),
-                         donate_argnums=(1, ))
-            self._lowerable[key] = fn
-            cw = compile_watch.get()
-            if cw is not None:
-                fn = cw.wrap("inference_tree_verify", key, fn)
-            self._compiled[key] = fn
-        cache = self._state_manager.kv_cache.cache
-        dev = {"tok_meta": batch["tok_meta"], "seq_meta": batch["seq_meta"],
-               "tree_meta": batch["tree_meta"]}
-        out, hidden, new_cache = self._compiled[key](self._params, cache, dev)
+        out, hidden, new_cache = self._compiled[key](
+            self._params, self._state_manager.kv_cache.cache, dev)
         self._state_manager.kv_cache.set_cache(new_cache)
         return out, hidden
 
-    def _tree_verify_impl(self, params, cache, batch, *, greedy=False):
-        """Verify-program body for token trees. ``token_pos`` as packed by the
-        wrapper is the KV SLOT position (``seen + node_index``); the model
-        sees the LOGICAL position ``seen + depth`` (rotary embeddings must
-        encode tree depth, not slot), while the attention closure keeps the
-        slot positions for the cache scatter."""
+    def _verify_impl(self, params, cache, batch, *, greedy):
+        """:meth:`_forward_impl` minus the last-token gather. With
+        ``tree_meta`` the packed ``token_pos`` is the KV SLOT (``seen +
+        node_index``) and the model sees the LOGICAL position ``seen + depth``
+        (rotary embeddings encode tree depth, not slot); the attention closure
+        keeps the slots for the cache scatter."""
         import jax.numpy as jnp
         from deepspeed_tpu.inference.v2.quantization import dequantize_tree
 
         params = dequantize_tree(params)
-        tree_meta = jnp.asarray(batch["tree_meta"])
-        parents, depths = tree_meta[0], tree_meta[1]
+        tree_meta = batch.get("tree_meta")
         batch = self._unpack_batch(batch)
-        slot_pos = batch["token_pos"]
-        batch = dict(batch,
-                     token_pos=batch["seq_seen"][batch["token_seq"]] + depths)
+        if tree_meta is None:
+            attn = partial(self._paged_attention, batch=batch)
+        else:
+            tree_meta = jnp.asarray(tree_meta)
+            parents, depths = tree_meta[0], tree_meta[1]
+            slot_pos = batch["token_pos"]
+            batch = dict(batch,
+                         token_pos=batch["seq_seen"][batch["token_seq"]] + depths)
+            attn = partial(self._tree_paged_attention, batch=batch,
+                           slot_pos=slot_pos, parents=parents, depths=depths)
         x = self.embed(params, batch["input_ids"])
-        attn = partial(self._tree_paged_attention, batch=batch,
-                       slot_pos=slot_pos, parents=parents, depths=depths)
         for li in range(self.num_layers):
             x, cache = self.layer_forward(params, li, x, cache, attn, batch)
         hidden = x.astype(jnp.float32)  # pre-final-norm residual, token-major
@@ -728,16 +668,14 @@ class DSTransformerModelBase:
         ``batch["token_pos"]`` already carries the LOGICAL (depth-based)
         positions the rotary embedding consumed.
 
-        Bitwise-identity construction: every query node attends a PER-QUERY
-        virtual KV view in which its depth-d ancestor occupies kv index
-        ``seen + d`` — exactly the slot a linear feed of that root path would
-        write. The masked logits, softmax reduction and value contraction
-        then see identical operands at identical indices as the linear verify
-        of the same path, so any accepted branch scores bit-identically to
-        spec-off decode (floating-point reduction order is layout-sensitive;
-        a mask alone cannot give token-identical speculation). The view is a
-        gather of the shared history — ``Qm`` is a handful of draft nodes, so
-        the duplication is bounded by the tree budget.
+        Every query node attends a PER-QUERY virtual KV view in which its
+        depth-d ancestor occupies kv index ``seen + d`` — the slot a causal
+        feed of that root path would write — so the masked logits, softmax
+        and value contraction see the operands the causal program sees for
+        that path, at the same indices (the two programs agree to float32
+        rounding, not bitwise: XLA orders each program's sums itself). The
+        view is a gather of the shared history — ``Qm`` is a handful of
+        draft nodes, so the duplication is bounded by the tree budget.
 
         Always the XLA fallback path: the Pallas paged kernel assumes a
         contiguous causal feed and cannot express the ancestor view."""

@@ -92,7 +92,7 @@ class RaggedBatchWrapper:
         arrays aligned with ``tokens`` — a speculative token tree (see
         spec/tree.py). Token i then occupies KV SLOT ``seen + i`` (sibling
         branches get distinct cache slots) while its ``token_pos`` stays the
-        slot position; the tree-verify program derives the LOGICAL (RoPE)
+        slot position; the verify program derives the LOGICAL (RoPE)
         position ``seen + depths[i]`` from the packed tree metadata."""
         tokens = np.atleast_1d(np.asarray(tokens)).astype(np.int32)
         if do_checks:

@@ -6,13 +6,16 @@ and the scheduler accepts under the spec-off sampling rule — >1 token per
 decode dispatch, exact spec-off equivalence always. Two drafter families:
 
 - :class:`PromptLookupDrafter` — model-free n-gram lookup (drafter.py); a
-  LINEAR draft verified by ``engine_v2.verify``; wins on repetitive text,
+  LINEAR draft, fed as a chain :class:`TokenTree`; wins on repetitive text,
   degrades to k=0 elsewhere;
 - :class:`LearnedDrafter` over a :class:`MedusaDraftHead` (learned.py) —
   tiny trained heads reading the target's hidden state; proposes a
-  :class:`TokenTree` (tree.py) of candidate branches verified in one ragged
-  forward by ``engine_v2.verify_tree`` under the tree-attention mask; wins
-  on arbitrary text after self-distillation (distill.py).
+  :class:`TokenTree` (tree.py) of candidate branches; wins on arbitrary text
+  after self-distillation (distill.py).
+
+Both are verified by ``engine_v2.verify_tree``, which reads the program off
+the trees' shape: chains take the causal feed ``put`` takes, a branching
+tree the tree-attention mask.
 """
 
 from deepspeed_tpu.inference.v2.spec.drafter import PromptLookupDrafter

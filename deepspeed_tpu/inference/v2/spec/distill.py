@@ -5,7 +5,7 @@ the TARGET model on the target model's OWN outputs. The corpus is generated
 in-process through the engine's generate path (the hybrid engine exposes
 this over the live training weights — see
 ``DeepSpeedHybridEngine.distill_draft_head``), the hidden states come from
-teacher-forced chain feeds through the tree-verify program (which returns
+teacher-forced chain feeds through the engine's verify step (which returns
 the pre-unembed residuals for free), and the optimizer is a hand-written
 numpy Adam so training runs anywhere the serving host runs.
 
@@ -44,8 +44,9 @@ def collect_hidden(engine, sequences: Sequence[Sequence[int]],
                    chunk: int = 32) -> List[np.ndarray]:
     """Teacher-forced hidden states ``[len(seq), hidden]`` per sequence: each
     sequence replays as chain trees through ``verify_tree`` on a scratch uid
-    (one ragged dispatch per chunk — the same program the serving tree-verify
-    path runs, so train-time and serve-time hidden states match bitwise)."""
+    (one ragged dispatch per chunk — the verify step serving runs: the causal
+    program for these chains; a branching batch's differs by float32
+    rounding)."""
     out = []
     for i, seq in enumerate(sequences):
         uid = _DISTILL_UID + i

@@ -8,7 +8,7 @@ scheduler then walks the tree with the exact spec-off sampling rule and
 accepts the deepest matching path, so speculative output stays bitwise
 token-identical to non-speculative output at the same seed.
 
-Packing format (what the ragged wrapper / tree-verify program consume):
+Packing format (what the ragged wrapper / the verify step consume):
 
 - ``tokens[i]``  — node i's token id; node 0 is the ROOT: the sequence's
   next-input token (already sampled, not yet committed), never a draft;
@@ -21,7 +21,9 @@ Packing format (what the ragged wrapper / tree-verify program consume):
   accepted path is re-packed to contiguous slots afterwards
   (``engine_v2.compact_accepted``).
 
-A linear 1+k verify feed is the degenerate chain tree (``parents[i] == i-1``).
+A linear 1+k verify feed is the degenerate chain tree (``parents[i] == i-1``):
+a batch of chains is packed WITHOUT this metadata and runs the causal verify
+program (``engine_v2.verify_tree`` reads :attr:`TokenTree.is_chain`).
 """
 
 from typing import Dict, List, Optional

@@ -4,9 +4,11 @@ Tier-1 runs on the CPU, where no time or utilization means anything, so the
 regression fence it can hold is *structural*: every flagship computation (ZeRO-3
 ``train_batch``, flash fwd+bwd, the paged ``decode_loop`` step, the int4
 decode matmul, the prefix-cache suffix prefill) is lowered under
-``JAX_PLATFORMS=cpu``, and facts XLA itself reports — FLOPs, bytes moved,
-live-buffer peak, collective payloads, fusion counts, dot dtypes — are
-ratcheted against checked-in budget files in tier-1.
+``JAX_PLATFORMS=cpu``, and facts about the program as written — FLOPs,
+argument and output bytes, collective payloads, dot counts and dtypes — are
+ratcheted against checked-in budget files in tier-1. What the compiler makes
+of it (bytes moved, live-buffer peak, fusion and op counts) is recorded and
+printed, never judged: it moves with the toolchain, not with a commit.
 
 Layers:
 

@@ -1,10 +1,11 @@
 """Budget ratchet files: the checked-in fence a program's HLO stats must stay
 inside.
 
-A budget is a JSON snapshot of a program's :class:`HloStats` plus per-metric
-tolerances. Checks are ONE-SIDED: a metric may improve freely (fewer bytes,
-fewer collectives, lower peak) but may not exceed ``value * (1 + tol)`` —
-that is the ratchet. Two exact-by-default families ride along:
+A budget is a JSON snapshot of a program's :class:`HloStats` (less the
+``PRINTED_ONLY`` compiler metrics) plus per-metric tolerances. Checks are
+ONE-SIDED: a metric may improve freely (fewer flops, fewer collectives,
+smaller outputs) but may not exceed ``value * (1 + tol)`` — that is the
+ratchet. Two exact-by-default families ride along:
 
 - the dtype audit (``f32_dot_count``/``dot_count``): an accidental f32 upcast
   on a bf16 path is a new f32 dot, tolerance 0;
@@ -29,27 +30,29 @@ from deepspeed_tpu.perf.hlo_stats import HloStats
 SCHEMA_VERSION = 1
 
 # metric -> (one-sided) relative tolerance. Counts are exact; byte/flop
-# totals get slack for minor XLA scheduling drift between rebuilds.
+# totals get slack. Every judged metric is a fact about the program AS
+# WRITTEN: the shapes it takes and returns, the matmuls and collectives jax
+# lowers it to.
 DEFAULT_TOLERANCES: Dict[str, float] = {
     "flops": 0.05,
-    "bytes_accessed": 0.10,
-    "peak_bytes": 0.10,
     "argument_bytes": 0.05,
     "output_bytes": 0.10,
     "collective_bytes_total": 0.05,
-    "fusion_count": 0.25,
-    "entry_instruction_count": 0.25,
-    "stablehlo_op_count": 0.10,
     "dot_count": 0.0,
     "f32_dot_count": 0.0,
     "collective_bytes": 0.05,   # per-collective entries
     "collective_count": 0.0,
 }
 
-_SCALAR_METRICS = ("flops", "bytes_accessed", "peak_bytes", "argument_bytes",
-                   "output_bytes", "collective_bytes_total", "fusion_count",
-                   "entry_instruction_count", "stablehlo_op_count", "dot_count",
-                   "f32_dot_count")
+_SCALAR_METRICS = ("flops", "argument_bytes", "output_bytes",
+                   "collective_bytes_total", "dot_count", "f32_dot_count")
+
+# What XLA's cost model, buffer assignment and fusion pass, and jax's
+# lowering, make of the program: these move with the toolchain, with no
+# commit of this repo to blame, so a budget neither holds nor judges them
+# (HloStats records them and ``dstpu_perfgate inspect`` prints them).
+PRINTED_ONLY = ("bytes_accessed", "peak_bytes", "fusion_count",
+                "entry_instruction_count", "stablehlo_op_count")
 
 
 @dataclass
@@ -111,7 +114,8 @@ def budget_path(budgets_dir: str, program: str) -> str:
 def budget_from_stats(stats: HloStats, program: Optional[str] = None,
                       tolerances: Optional[Dict[str, float]] = None,
                       note: str = "", roofline: Optional[dict] = None) -> Budget:
-    return Budget(program=program or stats.name, stats=stats.to_dict(),
+    kept = {k: v for k, v in stats.to_dict().items() if k not in PRINTED_ONLY}
+    return Budget(program=program or stats.name, stats=kept,
                   tolerances=dict(tolerances or {}), platform=stats.platform,
                   created=time.strftime("%Y-%m-%d %H:%M:%S UTC", time.gmtime()),
                   note=note, roofline=roofline)

@@ -7,7 +7,7 @@ wall times — a serving-path perf regression that stays inside the budget
 ratchets is invisible.  :class:`PerfObservedLedger` closes that loop:
 
 - the serving scheduler installs an engine ``dispatch_observer``; every jitted
-  call (``put`` / ``decode_loop`` / ``verify`` / ``verify_tree``) reports its
+  call (``put`` / ``decode_loop`` / ``verify_tree``) reports its
   (kind, sequences, tokens, wall seconds);
 - each dispatch maps to the flagship program that models it and a padded
   token bucket, lands in a ``perf_observed_dispatch_seconds{program,bucket}``
@@ -37,7 +37,6 @@ from deepspeed_tpu.perf.chip_specs import (DEFAULT_CHIP, chip_spec_for_device_ki
 # `put` whose feeds are all single tokens IS a paged decode step
 _KIND_PROGRAM = {
     "decode_loop": "paged_decode_step",
-    "verify": "spec_verify_step",
     "verify_tree": "spec_tree_verify",
 }
 

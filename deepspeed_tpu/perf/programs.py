@@ -43,17 +43,17 @@ class BuiltProgram:
 
 
 def _flops_per_token(cfg, n_params, S):
-    """bench.py's PaLM-appendix convention: 6*(N - N_embed) dense fwd+bwd +
+    """The PaLM-appendix convention: 6*(N - N_embed) dense fwd+bwd +
     12*L*S*H attention per token."""
     return 6.0 * (n_params - cfg.vocab_size * cfg.hidden_size) \
         + 12.0 * cfg.num_hidden_layers * S * cfg.hidden_size
 
 
-def build_train_engine(remat: bool = True, dtype=None):
+def build_train_engine(dtype=None):
     """Tiny ZeRO-3 training engine on the full 8-way data mesh, params
     force-sharded (persistence threshold 0) so the gathered/reduced
     collectives exist to be budgeted. Shared with the gate-sensitivity tests
-    (the drop-remat / f32-upcast regressions are built here too)."""
+    (the f32-upcast regression is built here too)."""
     import jax.numpy as jnp
 
     import deepspeed_tpu
@@ -61,7 +61,7 @@ def build_train_engine(remat: bool = True, dtype=None):
     from deepspeed_tpu.utils import groups
 
     groups.initialize_mesh(force=True)
-    cfg = llama.LlamaConfig.tiny(remat=remat, remat_policy="dots" if remat else "nothing",
+    cfg = llama.LlamaConfig.tiny(remat=True, remat_policy="dots",
                                  dtype=dtype if dtype is not None else jnp.bfloat16)
     model, params = llama.init_params(cfg, batch_size=TRAIN_B, seq_len=TRAIN_S)
     engine, _, _, _ = deepspeed_tpu.initialize(
@@ -162,14 +162,15 @@ def _build_paged_decode_step() -> BuiltProgram:
 
 
 def _build_spec_verify_step() -> BuiltProgram:
-    """The speculative-decoding verify program: one ragged forward scoring a
-    next-input token plus SPEC_DRAFT_K drafts per sequence (every position
-    unembedded). Built at the smallest pad bucket — the same bucket a
-    single-token decode forward runs in, which IS the speculative claim: 1+k
-    verified positions for the dispatch cost of one step."""
+    """The speculative verify step over chains (the causal program): one
+    ragged forward scoring a next-input token plus SPEC_DRAFT_K drafts per
+    sequence (every position unembedded). Built at the smallest pad bucket —
+    the same bucket a single-token decode forward runs in, which IS the
+    speculative claim: 1+k verified positions for the dispatch cost of one
+    step."""
     engine, _ = build_v2_engine()
     return BuiltProgram(
-        name="spec_verify_step", lowered=engine.lower_verify_step(),
+        name="spec_verify_step", lowered=engine.lower_verify(),
         meta={"draft_tokens": SPEC_DRAFT_K, "feed_width": 1 + SPEC_DRAFT_K,
               "kv_block_size": KV_BLOCK,
               "note": "all-position unembed over the smallest decode bucket"},
@@ -177,25 +178,25 @@ def _build_spec_verify_step() -> BuiltProgram:
 
 
 def _build_spec_tree_verify() -> BuiltProgram:
-    """The token-tree verify program: one ragged forward scoring a whole
-    draft TREE (root + branching candidates) under the tree-attention mask
-    with the per-query virtual-KV gather, in its device-argmax greedy
+    """The same verify step over a branching batch: one ragged forward
+    scoring a whole draft TREE (root + branching candidates) under the
+    tree-attention mask with the per-query virtual-KV gather, in its greedy
     variant — per-node ids cross the host boundary, not a ``[T, vocab]``
     f32 logits block. Built at the smallest pad bucket; the comparisons ARE
     the tree-speculation claim: verifying up to SPEC_TREE_NODES tree nodes
     costs a budgeted multiple of ONE single-token forward at the same
     bucket — nowhere near node-count sequential steps — and stays in the
-    linear verify program's weight class despite the mask and gather."""
+    causal verify program's weight class despite the mask and gather."""
     engine, _ = build_v2_engine()
     return BuiltProgram(
         name="spec_tree_verify",
-        lowered=engine.lower_tree_verify(greedy=True),
+        lowered=engine.lower_verify(tree=True, greedy=True),
         meta={"tree_nodes": SPEC_TREE_NODES, "kv_block_size": KV_BLOCK,
               "greedy": True,
               "note": "tree-attention mask + per-query virtual KV at the "
                       "smallest decode bucket; greedy returns per-node ids"},
         comparisons={"single_token_forward": engine.lower_forward(),
-                     "linear_verify": engine.lower_verify_step()})
+                     "linear_verify": engine.lower_verify()})
 
 
 def _build_int4_decode_matmul() -> BuiltProgram:

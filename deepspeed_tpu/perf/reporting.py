@@ -84,8 +84,9 @@ def render_budgets_dir(budgets_dir: str) -> str:
             b = json.load(f)
         s = b.get("stats", {})
         lines.append(f"{name:<26} flops={_fmt_flops(s.get('flops', 0))} "
-                     f"bytes={_fmt_bytes(s.get('bytes_accessed', 0))} "
-                     f"peak={_fmt_bytes(s.get('peak_bytes', 0))} "
+                     f"args={_fmt_bytes(s.get('argument_bytes', 0))} "
+                     f"out={_fmt_bytes(s.get('output_bytes', 0))} "
+                     f"dots={s.get('dot_count', 0)} "
                      f"colls={len(s.get('collectives', {}))} "
                      f"created={b.get('created', '?')}")
         rl = b.get("roofline") or {}

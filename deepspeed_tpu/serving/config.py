@@ -52,13 +52,15 @@ class SpeculativeConfig(DeepSpeedConfigModel):
     default.
 
     Two drafter families, selected by ``drafter``: ``prompt_lookup`` mines
-    n-gram repeats (linear ``1+k`` feeds through ``engine.verify``; wins on
-    repetitive text, k adapts to 0 elsewhere) and ``learned`` reads the
-    target's hidden state through trained Medusa-style heads and proposes a
-    token TREE verified under the tree-attention mask
-    (``engine.verify_tree``; wins on arbitrary text after self-distillation
-    — ``bin/dstpu_spec_train``). ``auto`` arbitrates per request on measured
-    per-drafter acceptance EWMAs, probing the loser periodically."""
+    n-gram repeats (linear ``1+k`` feeds; wins on repetitive text, k adapts
+    to 0 elsewhere) and ``learned`` reads the target's hidden state through
+    trained Medusa-style heads and proposes a branching token TREE (wins on
+    arbitrary text after self-distillation — ``bin/dstpu_spec_train``).
+    ``auto`` arbitrates per request on measured per-drafter acceptance
+    EWMAs, probing the loser periodically. Every draft is a ``TokenTree``
+    verified by ``engine.verify_tree``, which picks its program from the
+    trees' shape (chains: the causal feed ``put`` takes; a branching tree:
+    the tree-attention mask), never from ``drafter``."""
 
     def __init__(self, strict=False, **data):
         # the base model drops "auto"-valued kwargs so defaults apply (the
@@ -74,10 +76,8 @@ class SpeculativeConfig(DeepSpeedConfigModel):
     the decode path."""
 
     drafter: Literal["prompt_lookup", "learned", "auto"] = "prompt_lookup"
-    """Drafter selection. ``prompt_lookup`` keeps the linear verify path;
-    ``learned``/``auto`` route speculative decode through token-tree verify
-    (a prompt-lookup draft then rides as a chain tree — bitwise the linear
-    program's output)."""
+    """Who drafts: ``prompt_lookup`` (chains), ``learned`` (loads the draft
+    heads; branching trees) or ``auto`` (both, raced per request)."""
 
     max_draft_tokens: int = Field(4, ge=1)
     """Upper bound on draft tokens per sequence per step (k). The effective k
@@ -95,9 +95,9 @@ class SpeculativeConfig(DeepSpeedConfigModel):
     growing the draft tree (best-first by joint log-probability)."""
 
     tree_node_budget: int = Field(8, ge=2)
-    """Cap on nodes per draft tree (root included). Tree nodes are fed
-    tokens: they compete under the ragged token budget and
-    ``draft_token_budget`` exactly like linear draft tokens."""
+    """Cap on nodes per draft tree (root included), a prompt-lookup chain
+    too. Tree nodes are fed tokens: they compete under the ragged token
+    budget and ``draft_token_budget``."""
 
     draft_head_path: Optional[str] = None
     """Trained draft-head ``.npz`` (``bin/dstpu_spec_train`` output) for the

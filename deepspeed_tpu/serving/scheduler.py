@@ -39,6 +39,7 @@ import numpy as np
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.v2 import sampling
 from deepspeed_tpu.inference.v2.scheduling_utils import SchedulingError, SchedulingResult
+from deepspeed_tpu.inference.v2.spec.tree import TokenTree
 from deepspeed_tpu.serving.config import ServingConfig
 from deepspeed_tpu.serving.metrics import ServingMetrics
 from deepspeed_tpu.serving.overload import (BrownoutController, FairSharePolicy,
@@ -285,10 +286,11 @@ class ServingScheduler:
                 max_blocks=self._config.prefix_cache.max_blocks,
                 min_prefix_blocks=self._config.prefix_cache.min_prefix_blocks)
 
-        # speculative decoding (inference/v2/spec/): a model-free drafter
-        # proposes k continuation tokens per decode step at batch-build time;
-        # the engine verifies 1+k positions in one ragged forward and the
-        # execute path accepts the longest matching prefix. Trie-backed when
+        # speculative decoding (inference/v2/spec/): a drafter proposes a
+        # TokenTree per decode step at batch-build time (a model-free
+        # prompt-lookup draft is a chain); the engine verifies every node in
+        # one ragged forward (engine.verify_tree) and the execute path
+        # accepts the deepest matching path. Trie-backed when
         # the prefix cache runs (the trie holds exactly the token histories a
         # prompt-lookup drafter wants to mine), self-lookup otherwise.
         self._drafter = None
@@ -306,8 +308,8 @@ class ServingScheduler:
             self._drafter_mode = scfg.drafter
             if scfg.drafter != "prompt_lookup":
                 # learned / auto: Medusa-style heads read the target's hidden
-                # state and propose token TREES verified by engine.verify_tree;
-                # "auto" races them against prompt-lookup per request on
+                # state and propose branching trees; "auto" races them
+                # against prompt-lookup per request on
                 # measured acceptance EWMAs. Untrained fresh heads are safe —
                 # acceptance adapts their k to 0 until dstpu_spec_train runs.
                 from deepspeed_tpu.inference.v2.spec import (LearnedDrafter,
@@ -1568,15 +1570,13 @@ class ServingScheduler:
             gauge.set(self._spec_drafter_ewmas[name])
 
     def _draft_tree_for(self, req: Request, k: int, room: int):
-        """A :class:`TokenTree` feed for the learned/auto modes (always
-        non-None: every decode entry in tree mode feeds a tree, so one
-        ``verify_tree`` dispatch carries the whole tick). ``k`` caps draft
-        DEPTH, ``room`` caps draft NODES (root excluded) under the ragged
-        token budget. A prompt-lookup draft rides as a chain tree — bitwise
-        the linear verify program's output — and a learned draft without a
-        valid hidden state bootstraps with a root-only tree whose verify
-        returns the hidden state the next step drafts from."""
-        from deepspeed_tpu.inference.v2.spec import TokenTree
+        """``req``'s :class:`TokenTree` feed, from whichever drafter
+        :meth:`_pick_drafter` names (never None: with nothing drafted it is
+        the root alone). ``k`` caps draft DEPTH, ``room`` caps draft NODES
+        (root excluded) under the ragged token budget and
+        ``tree_node_budget``. A prompt-lookup draft is a chain; a learned
+        draft without a valid hidden state bootstraps with a root-only tree
+        whose verify returns the hidden state the next step drafts from."""
         scfg = self._config.speculative
         name = self._pick_drafter(req)
         if name != req._spec_last_drafter:
@@ -1604,37 +1604,6 @@ class ServingScheduler:
             self._arb_update(req, name, 0.0)  # nothing fit the node budget
             return TokenTree.chain(root)
         return tree
-
-    def _spec_accept(self, req: Request, feed: np.ndarray, rows: np.ndarray):
-        """The acceptance rule over one verify feed. ``rows[j]`` scores the
-        token after ``feed[:j+1]``; the emitted sequence is EXACTLY what
-        non-speculative decoding would produce: each emitted token is sampled
-        (or argmaxed) from the target distribution with the request's own
-        stream — one draw per emitted token, same draw order as spec-off — and
-        a draft survives only when it equals that token (rejection sampling
-        with a point-mass draft distribution). Returns ``(emitted,
-        accepted_drafts)``; emission stops at eos / the generation cap,
-        mirroring :meth:`_push_token`'s rules."""
-        emitted: List[int] = []
-        accepted = 0
-        k = int(feed.size) - 1
-        # row j's token is the request's draw len(tokens) + j whatever came
-        # before it: one call draws them all, the walk keeps what it reaches
-        drawn = self._draw_rows(req, rows, np.arange(len(rows)))
-        for j in range(int(feed.size)):
-            tok = int(drawn[j])
-            emitted.append(tok)
-            if req.eos_token_id is not None and tok == req.eos_token_id:
-                break
-            if len(req.tokens) + len(emitted) >= req.max_new_tokens:
-                break
-            if j >= k:
-                break  # the bonus token: no more drafts to validate
-            if int(feed[j + 1]) != tok:
-                break  # rejection: the target model disagrees with the draft
-            accepted += 1
-        self._count_draws("host_draws", len(emitted))
-        return emitted, accepted
 
     def _permanently_infeasible(self, req: Request) -> Optional[str]:
         """A reason this request can NEVER be scheduled, or None. Failing at
@@ -1715,7 +1684,6 @@ class ServingScheduler:
                 req.finish_reason = "context"
                 self._finalize(req, RequestState.DONE)
                 continue
-            feed = None
             tree = None
             req._spec_tree = None
             if draft_budget > 0:
@@ -1726,35 +1694,25 @@ class ServingScheduler:
                            req.max_new_tokens - len(req.tokens) - 1)
                 if seq is not None:
                     room = min(room, sm_cfg.max_context - seq.seen_tokens - 1)
-                k = min(self._spec_k(req), room)
-                if self._drafter_mode != "prompt_lookup":
-                    # learned/auto: every decode entry feeds a TokenTree so
-                    # ONE verify_tree dispatch carries the tick (a root-only
-                    # tree when nothing drafts — its verify still returns the
-                    # hidden state the learned drafter reads next step)
-                    tree = self._draft_tree_for(req, k, room)
-                    feed = tree.tokens
-                elif k > 0:
-                    draft = self._draft_for(req, k)
-                    if draft.size:
-                        feed = np.concatenate(
-                            [np.asarray([req._next], np.int32), draft])
-            if feed is not None and \
-                    admission(req.uid, int(feed.size)) == SchedulingResult.Success:
+                tree = self._draft_tree_for(req, min(self._spec_k(req), room), room)
+                if tree.size == 1 and self._learned is None:
+                    # nothing drafted and no head to read a hidden state: a
+                    # plain decode row (a tick of these is put / decode_loop)
+                    tree = None
+            if tree is not None and \
+                    admission(req.uid, tree.size) == SchedulingResult.Success:
                 # drafts are speculative: they never trigger eviction — a feed
                 # the pool can't take falls back to the k=0 single token below
                 req._deferred = 0
                 req._spec_tree = tree
-                admit(req, feed)
-                draft_budget -= int(feed.size) - 1
+                admit(req, tree.tokens)
+                draft_budget -= tree.size - 1
             elif admit_under_pressure(req, 1):
                 req._deferred = 0
-                if tree is not None:
-                    # tree mode under pressure: a root-only tree keeps the
-                    # tick on one verify_tree dispatch (same 1-token cost)
-                    from deepspeed_tpu.inference.v2.spec import TokenTree
-                    req._spec_tree = TokenTree.chain(
-                        np.asarray([req._next], np.int32))
+                if tree is not None and self._learned is not None:
+                    # under pressure the root alone still rides the verify
+                    # step: the learned drafter reads its hidden state next
+                    req._spec_tree = TokenTree.chain([req._next])
                 admit(req, [req._next])
             else:
                 req._deferred += 1  # KV held by in-flight work; retry next tick
@@ -1854,21 +1812,13 @@ class ServingScheduler:
                              args={"uid": req.uid, "tick": tick["tick"],
                                    "tokens": ntok if counts is None else counts[i]})
 
-        # tree-verify (learned/auto drafters): any decode entry carrying a
-        # TokenTree — root-only trees included — routes the tick through ONE
-        # engine.verify_tree dispatch
+        # speculative verify: any decode entry carrying a TokenTree (a draft,
+        # or the root alone for a learned head's hidden state) routes the
+        # tick through ONE engine.verify_tree dispatch
         if any(req._spec_tree is not None for req, _ in plan):
             if tick is not None:
                 tick["kind"] = "verify_tree"
             self._execute_verify_tree(plan, _record_phase_spans)
-            return
-        # speculative verify: any decode feed wider than one token (next
-        # input + draft tokens) routes the tick through the verify path
-        if any(req.state is RequestState.DECODE and toks.size > 1
-               for req, toks in plan):
-            if tick is not None:
-                tick["kind"] = "verify"
-            self._execute_verify(plan, _record_phase_spans)
             return
 
         K = self._config.decode_chunk
@@ -2061,91 +2011,6 @@ class ServingScheduler:
             for _ in range(pushed):
                 self._metrics.itl.observe(gap)
 
-    def _execute_verify(self, plan: List[Tuple[Request, np.ndarray]],
-                        record_spans) -> None:
-        """Execute a tick containing speculative verify feeds. The decode
-        entries (each a next-input token plus k drafts) run through ONE
-        ``engine.verify`` dispatch; prefill chunks sharing the tick run
-        through their normal ``engine.put`` — a prefill bucket must not pay
-        the verify program's all-position unembed (and a [T, vocab] logits
-        transfer at prefill widths) for a peer's draft. Each decode entry
-        accepts its longest matching draft prefix, rolls the rejected tail
-        back (write-then-truncate on ``seen_tokens``) and streams the
-        emitted tokens."""
-        engine = self._engine
-        decode_plan = [(req, toks) for req, toks in plan
-                       if req.state is not RequestState.PREFILL]
-        prefill_plan = [(req, toks) for req, toks in plan
-                        if req.state is RequestState.PREFILL]
-        try:
-            per_seq = engine.verify([req.uid for req, _ in decode_plan],
-                                    [toks for _, toks in decode_plan])
-            # stash the verify dispatch's observed wall time before the
-            # prefill put overwrites the observer slots
-            verify_s = self._last_dispatch_s
-            verify_amnesty_s = self._last_dispatch_amnesty_s
-            prefill_ids = self._put_draw(prefill_plan) if prefill_plan else None
-        except Exception as e:  # pragma: no cover - defensive: same contract
-            # as the put path — the scheduler thread must survive
-            logger.exception("serving: engine verify tick failed; failing the batch")
-            for req, _ in plan:
-                self._finalize(req, RequestState.FAILED, error=f"engine error: {e}")
-            return
-        with self._emit_phase(self._tick_spans):
-            # the estimator measures engine-token throughput: verify feeds cost
-            # their full width (accepted or not), like any other fed token
-            self._rate.observe(sum(int(t.size) for _, t in plan))
-            self._charge_members([(req, "verify", int(t.size))
-                                  for req, t in decode_plan],
-                                 seconds=verify_s, amnesty=verify_amnesty_s)
-            if prefill_plan:
-                self._charge_members([(req, "prefill", int(t.size))
-                                      for req, t in prefill_plan])
-            alpha = self._config.speculative.accept_alpha
-            # sample/accept BEFORE any push: span token counts must be final when
-            # the root span closes, and each request's positional stream makes
-            # its draws independent of processing order
-            accepts = {id(req): self._spec_accept(req, toks, rows)
-                       for (req, toks), rows in zip(decode_plan, per_seq)}
-            record_spans(counts=[len(accepts[id(req)][0]) if id(req) in accepts
-                                 else int(toks.size) for req, toks in plan])
-            for (req, toks), rows in zip(decode_plan, per_seq):
-                emitted, accepted = accepts[id(req)]
-                k = int(toks.size) - 1
-                rejected = int(toks.size) - len(emitted)
-                # rollback BEFORE pushing: a push may finalize, and the handoff
-                # export / trie publish there must see the truncated seen_tokens
-                # (= full history - 1, the same invariant every other path keeps)
-                engine.rollback(req.uid, rejected)
-                req.decode_steps += 1
-                if k:
-                    # a k=0 feed riding a verify batch proposed nothing — no
-                    # acceptance evidence, no EWMA movement
-                    req.spec_drafted += k
-                    req.spec_accepted += accepted
-                    if self._ledger is not None and req.cost is not None:
-                        self._ledger.charge_spec(req.cost, k, accepted)
-                    self._counters["spec_steps"] += 1
-                    self._counters["spec_drafted"] += k
-                    self._counters["spec_rollback"] += rejected
-                    self._counters["spec_accepted"] += accepted
-                    rate = accepted / k
-                    req._spec_ewma = (rate if req._spec_ewma is None
-                                      else alpha * rate + (1 - alpha) * req._spec_ewma)
-                    self._spec_accept_ewma = (rate if self._spec_accept_ewma is None
-                                              else alpha * rate
-                                              + (1 - alpha) * self._spec_accept_ewma)
-                    if self._metrics:
-                        self._metrics.spec_verify_steps.inc()
-                        self._metrics.spec_drafted.inc(k)
-                        self._metrics.spec_accepted.inc(accepted)
-                        self._metrics.spec_rollback.inc(rejected)
-                        self._metrics.spec_accept_rate.set(self._spec_accept_ewma or 0.0)
-                        self._metrics.spec_tokens_per_step.observe(len(emitted))
-                self._push_burst(req, emitted)
-            for i, (req, toks) in enumerate(prefill_plan):
-                self._advance_prefill(req, toks, int(prefill_ids[i]))
-
     def _spec_accept_tree(self, req: Request, tree, rows, ids):
         """The acceptance rule over one verified token tree. Walk from the
         root: each emitted token is sampled (or argmaxed) from the target
@@ -2183,11 +2048,13 @@ class ServingScheduler:
 
     def _execute_verify_tree(self, plan: List[Tuple[Request, np.ndarray]],
                              record_spans) -> None:
-        """Execute a tick whose decode entries carry TokenTree feeds (the
-        learned/auto drafter modes). Every tree — branching, chain, or
-        root-only — verifies in ONE ``engine.verify_tree`` dispatch; prefill
-        chunks sharing the tick keep their normal ``engine.put`` (same split
-        as :meth:`_execute_verify`, same reason). Each entry accepts its
+        """Execute a tick in which a decode entry carries a TokenTree feed.
+        Every decode entry — branching tree, chain, root-only, or a plain
+        row riding as the chain of its one token — verifies in ONE
+        ``engine.verify_tree`` dispatch; prefill chunks sharing the tick run
+        through their normal ``engine.put_draw`` (a prefill bucket must not
+        pay the verify program's all-position unembed, and a [T, vocab]
+        fetch at prefill widths, for a peer's draft). Each entry accepts its
         deepest matching path under the spec-off sampling rule, compacts the
         accepted path's KV left behind the committed history (tree-aware
         write-then-truncate) and streams the emitted run; the deepest
@@ -2198,29 +2065,27 @@ class ServingScheduler:
                        if req.state is not RequestState.PREFILL]
         prefill_plan = [(req, toks) for req, toks in plan
                         if req.state is RequestState.PREFILL]
-        trees = []
-        for req, toks in decode_plan:
-            tree = req._spec_tree
+        trees = [TokenTree.chain(toks) if req._spec_tree is None else req._spec_tree
+                 for req, toks in decode_plan]
+        for req, _ in decode_plan:
             req._spec_tree = None
-            if tree is None:  # defensive: a plain feed rides as a chain
-                from deepspeed_tpu.inference.v2.spec import TokenTree
-                tree = TokenTree.chain(toks)
-            trees.append(tree)
         # the device-argmax program only when EVERY decode entry is greedy: a
         # sampled request needs the full rows for its draw (greedy peers then
         # take the same draw at temperature 0: argmax of the same f32 rows)
         greedy = all(req.temperature <= 0.0 for req, _ in decode_plan)
         try:
+            # the hidden states are fetched only for a head that reads them
             per_seq = engine.verify_tree([req.uid for req, _ in decode_plan],
-                                         trees, greedy=greedy)
-            # stash the tree-verify dispatch's observed wall time before the
+                                         trees, greedy=greedy,
+                                         hidden=self._learned is not None)
+            # stash the verify dispatch's observed wall time before the
             # prefill put overwrites the observer slots
             verify_s = self._last_dispatch_s
             verify_amnesty_s = self._last_dispatch_amnesty_s
             prefill_ids = self._put_draw(prefill_plan) if prefill_plan else None
         except Exception as e:  # pragma: no cover - defensive: same contract
             # as the put path — the scheduler thread must survive
-            logger.exception("serving: tree-verify tick failed; failing the batch")
+            logger.exception("serving: verify tick failed; failing the batch")
             for req, _ in plan:
                 self._finalize(req, RequestState.FAILED, error=f"engine error: {e}")
             return
@@ -2257,7 +2122,7 @@ class ServingScheduler:
                 # the hidden state behind the next decode input is the deepest
                 # CONSUMED node's residual; _spec_hidden_pos stamps the history
                 # length it is valid at (stale after any gap: handoff, brownout)
-                hidden = res.get("hidden")
+                hidden = res["hidden"]
                 if hidden is not None:
                     req._spec_hidden = np.asarray(hidden[last_node], np.float32)
                     req._spec_hidden_pos = (int(req.prompt.size) + len(req.tokens)
@@ -2271,8 +2136,8 @@ class ServingScheduler:
                     if compacted:
                         self._metrics.spec_tree_compactions.inc()
                 if k:
-                    # a root-only bootstrap proposed nothing — no acceptance
-                    # evidence, no EWMA movement (linear-path rule, tree-shaped)
+                    # a root-only feed proposed nothing — no acceptance
+                    # evidence, no EWMA movement
                     drafter = req._spec_last_drafter or self._drafter_mode
                     short = "learned" if drafter == "learned" else "lookup"
                     # the arbitration/adaptation signal is DEPTH productivity:

@@ -30,7 +30,7 @@ DEFAULT_TENANT = "default"
 OTHER_TENANT = "<other>"
 
 # phases the scheduler bills (the engine dispatch kinds, scheduler-side view)
-PHASES = ("prefill", "decode", "verify", "tree_verify")
+PHASES = ("prefill", "decode", "tree_verify")
 
 # fallbacks when no model config is reachable: arbitrary but fixed, so pricing
 # stays deterministic across runs of the same build

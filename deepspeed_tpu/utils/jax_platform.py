@@ -17,7 +17,7 @@ COMPILE_CACHE_DIR = os.path.join(
 
 def enable_compile_cache() -> str:
     """Turn the persistent compilation cache on for this process and return its
-    directory. For process entry points (``chip_smoke.py``, ``bench.py``,
+    directory. For process entry points (``chip_smoke.py``,
     ``bin/dstpu_replica``, ``bin/dstpu_bench``, ``examples/*.py``) — never
     called at library import, so tests compile what they test.
 

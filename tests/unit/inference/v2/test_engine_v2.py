@@ -243,9 +243,9 @@ def test_decode_loop_validation(llama_setup):
     cfg, model, params = llama_setup
     engine = build_engine(params, cfg, _engine_config())
     engine.put([0], [np.arange(5) % cfg.vocab_size])
-    # a multi-token entry is the speculative verify feed: one step, greedy —
-    # the on-device scan still takes single-token entries only
-    with pytest.raises(ValueError, match="one step"):
+    # a multi-token entry is a speculative verify feed (verify_tree's): the
+    # on-device scan takes single-token entries only
+    with pytest.raises(ValueError, match="exactly one next-input token"):
         engine.decode_loop([0], [np.array([1, 2])], 4)
     with pytest.raises(ValueError, match="n_steps"):
         engine.decode_loop([0], [np.array([1])], 0)

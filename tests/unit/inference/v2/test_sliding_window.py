@@ -17,6 +17,7 @@ from deepspeed_tpu.inference.v2.engine_factory import build_engine
 from deepspeed_tpu.inference.v2.model_implementations.llama_v2 import MistralV2Model
 from deepspeed_tpu.inference.v2.ragged.manager_configs import (AllocationMode, DSStateManagerConfig,
                                                                MemoryConfig)
+from deepspeed_tpu.inference.v2.spec import TokenTree
 from deepspeed_tpu.models import llama
 from deepspeed_tpu.utils import groups
 
@@ -252,9 +253,12 @@ def test_a_verify_step_releases_nothing_until_its_rollback_is_settled(model):
     engine.put([0], [ids[:40]])
     seq = engine._state_manager.get_sequence(0)
     released = seq.released_blocks
-    engine.verify([0], [ids[40:48]], greedy=True)
+    engine.verify_tree([0], [TokenTree.chain(ids[40:48])], greedy=True)
     assert seq.seen_tokens == 48 and seq.released_blocks == released
-    engine.rollback(0, 5)
+    # truncating through compact_accepted keeps rollback's guard
+    with pytest.raises(ValueError, match="already released"):
+        engine.compact_accepted(0, 30, [])
+    assert engine.compact_accepted(0, 8, [1, 2]) == 5
     got = np.asarray(engine.put([0], [ids[43:44]]))[0]
     np.testing.assert_allclose(got, _reference_rows(model, ids[:44], [43])[0], atol=ATOL, rtol=0)
     assert seq.released_blocks == (44 - WINDOW + 1) // BLOCK

@@ -10,7 +10,7 @@ gates) lives in tests/unit/serving/test_speculative.py.
 import numpy as np
 import pytest
 
-from deepspeed_tpu.inference.v2.spec import PromptLookupDrafter
+from deepspeed_tpu.inference.v2.spec import PromptLookupDrafter, TokenTree
 
 
 # ----------------------------------------------------------------- drafter --
@@ -173,7 +173,7 @@ def test_verify_fully_accepted_feed_matches_sequential_decode(spec_engine_setup)
         drafts = ref[len(out):len(out) + k]
         feed = np.asarray([out[-1]] + drafts, np.int32)
         seen0 = seq.seen_tokens
-        rows = engine.verify([0], [feed])[0]
+        rows = engine.verify_tree([0], [TokenTree.chain(feed)])[0]["rows"]
         assert rows.shape == (feed.size, cfg.vocab_size)
         emitted = [int(np.argmax(rows[j])) for j in range(feed.size)]
         # oracle drafts: every position verifies, k+1 tokens emitted
@@ -194,7 +194,7 @@ def test_verify_rejection_rolls_back_and_continues_exactly(spec_engine_setup):
     assert t1 == ref[0]
     # garbage drafts: only the next-input position survives
     bad = np.asarray([t1, (ref[1] + 1) % cfg.vocab_size, 7, 9], np.int32)
-    rows = engine.verify([0], [bad])[0]
+    rows = engine.verify_tree([0], [TokenTree.chain(bad)])[0]["rows"]
     emitted = int(np.argmax(rows[0]))
     engine.rollback(0, bad.size - 1)  # truncate the 3 rejected positions
     seq = engine._state_manager.get_sequence(0)
@@ -214,8 +214,8 @@ def test_verify_batches_multiple_sequences_with_ragged_widths(spec_engine_setup)
     p1 = rng.integers(0, cfg.vocab_size, 12)
     logits = np.asarray(engine.put([0, 1], [p0, p1]))
     n0, n1 = (int(np.argmax(logits[0])), int(np.argmax(logits[1])))
-    rows = engine.verify([0, 1], [np.asarray([n0, 1, 2], np.int32),
-                                  np.asarray([n1], np.int32)])
+    rows = [out["rows"] for out in engine.verify_tree(
+        [0, 1], [TokenTree.chain([n0, 1, 2]), TokenTree.chain([n1])])]
     assert rows[0].shape == (3, cfg.vocab_size)
     assert rows[1].shape == (1, cfg.vocab_size)
     s0 = engine._state_manager.get_sequence(0)
@@ -225,28 +225,17 @@ def test_verify_batches_multiple_sequences_with_ragged_widths(spec_engine_setup)
 
 
 def test_decode_loop_multi_token_feed_contract(spec_engine_setup):
-    """The generalized decode_loop: multi-token entries run the greedy verify
-    feed (list of per-position argmax arrays); single-token entries keep the
-    scan path; misuse raises."""
+    """decode_loop takes ONE next-input token per sequence: a feed of several
+    is a verify step (``verify_tree``), and says so."""
     cfg, make = spec_engine_setup
-    prompt = np.random.default_rng(0).integers(0, cfg.vocab_size, 24)
-    ref = _greedy_reference(make(), prompt, 4)
-
     engine = make()
-    logits = engine.put([0], [prompt])
-    t1 = int(np.argmax(np.asarray(logits)[0]))
-    out = engine.decode_loop([0], [np.asarray([t1] + ref[1:3], np.int32)], 1)
-    assert isinstance(out, list) and out[0].shape == (3,)
-    assert out[0].tolist() == ref[1:4]  # oracle drafts: the greedy continuation
-    engine.rollback(0, 0)
-
-    with pytest.raises(ValueError, match="one step"):
-        engine.decode_loop([0], [np.asarray([1, 2], np.int32)], 2)
-    with pytest.raises(ValueError, match="greedy"):
-        engine.decode_loop([0], [np.asarray([1, 2], np.int32)], 1,
-                           temperature=0.5, rng=np.zeros(2))
-    with pytest.raises(ValueError, match="at least one"):
-        engine.decode_loop([0], [np.asarray([], np.int32)], 1)
+    engine.put([0], [np.random.default_rng(0).integers(0, cfg.vocab_size, 24)])
+    seq = engine._state_manager.get_sequence(0)
+    seen = seq.seen_tokens
+    for feed, steps in (([1, 2], 1), ([1, 2], 2), ([], 1)):
+        with pytest.raises(ValueError, match="exactly one next-input token"):
+            engine.decode_loop([0], [np.asarray(feed, np.int32)], steps)
+    assert seq.seen_tokens == seen  # refused before anything was fed
     engine.flush(0)
 
 

@@ -226,6 +226,25 @@ def test_serve_decode_loop_program_fits_one_chip(v5e, sizes, serve_model):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+@pytest.mark.parametrize("tree", [False, True], ids=["chains", "branching"])
+def test_the_verify_step_keeps_the_kernel_for_chains(v5e, sizes, serve_model, tree):
+    """Why the one verify step has two programs (PR 28): a batch of chains
+    carries no ``tree_meta`` and attends through the paged kernel, the pool
+    aliased through, exactly as ``put`` at that bucket; the ancestor mask has
+    no kernel arm, and the compiler answers its scatter and gather with
+    pool-sized copies. Sending chains through it would cost them the kernel."""
+    model, abstract = serve_model
+    one, params, cache, batch = _serve_args(v5e[0], sizes, abstract, (32, 8, 4))
+    if tree:
+        batch["tree_meta"] = _on(one, (2, 32), jnp.int32)
+    verify = functools.partial(model._verify_impl, greedy=True)
+    compiled = jax.jit(verify, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text and "paged_attention_update" in text) == (not tree)
+    assert bool(_pool_sized_results(text, cache.shape)) == tree
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
 # ---- a sliding-window model at the benchmark cell's shapes (PR 26) -----------
 WINDOW, WINDOW_MAX_BLOCKS, WINDOW_POOL_BLOCKS, WINDOW_LAYERS = 4096, 128, 7104, 5
 

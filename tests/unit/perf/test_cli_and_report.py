@@ -1,4 +1,4 @@
-"""bin/dstpu_perfgate + dstpu_report --perf + bench.py --microbench plumbing."""
+"""bin/dstpu_perfgate + dstpu_report --perf plumbing."""
 
 import json
 import os
@@ -89,33 +89,3 @@ def test_dstpu_report_perf_checked_in_budgets():
 def test_dstpu_report_perf_bad_path():
     r = _run("dstpu_report", "--perf", "/nonexistent/thing")
     assert r.returncode == 2
-
-
-# ------------------------------------------------------------ bench plumbing --
-def test_bench_refuses_to_measure_off_the_chip():
-    """No TPU, no number: a non-zero exit, the platform named, and nothing on
-    stdout that could be read as a result under a device metric's name."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py"), "--microbench"],
-                       capture_output=True, text=True, timeout=240, env=env)
-    assert r.returncode != 0
-    assert "'cpu'" in r.stderr and "tpu" in r.stderr
-    assert r.stdout.strip() == ""
-
-
-def test_bench_microbench_kernel_bodies_run_tiny():
-    """The kernel legs themselves execute (interpret mode, shrunk shapes) —
-    the TPU run uses the same code with the default shapes."""
-    import jax.numpy as jnp
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    r = bench._microbench_int4_unpack(jnp, K=64, N=64, N1=1, N2=3)
-    assert set(r) >= {"bf16", "int4", "int4_speedup"}
-    assert r["int4"]["matmul_us"] > 0
-    r = bench._microbench_paged_decode(jnp, T=2, H=2, KVH=2, D=16, bs=4, S=2, MB=4,
-                                       N1=1, N2=2)
-    assert r["kernel_step_ms"] > 0
-    assert r["context"] == 16

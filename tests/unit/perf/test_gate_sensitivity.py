@@ -3,13 +3,12 @@
 Each test INJECTS one regression class into a program and asserts the
 budget check trips the matching assertion:
 
-1. drop remat            -> live-buffer peak exceeds the budget;
-2. force an f32 upcast   -> the dtype audit's exact f32-dot count trips;
-3. de-fuse a matmul      -> fusion / entry-kernel counts trip;
-4. double a collective payload -> the per-collective byte budget trips.
+1. force an f32 upcast   -> the dtype audit's exact f32-dot count trips;
+2. double a collective payload -> the per-collective byte budget trips;
+3. widen the draft tree  -> the tree-verify flops budget trips.
 
-1–2 regress the REAL flagship ZeRO-3 program against its checked-in budget;
-3–4 use a minimal synthetic program with an in-test baseline so the injected
+1 and 3 regress a REAL flagship program against its checked-in budget; 2
+uses a minimal synthetic program with an in-test baseline so the injected
 delta is exactly one structural change."""
 
 import jax
@@ -25,16 +24,10 @@ from deepspeed_tpu.perf.programs import build_train_engine, train_batch_example
 pytestmark = pytest.mark.perfgate
 
 
-def _train_stats(remat=True, dtype=None):
-    engine, cfg = build_train_engine(remat=remat, dtype=dtype)
+def _train_stats(dtype=None):
+    engine, cfg = build_train_engine(dtype=dtype)
     lowered = engine.lower_train_batch(batch=train_batch_example(cfg))
     return stats_from_lowered(lowered, name="zero3_train_batch")
-
-
-def test_dropping_remat_trips_peak_bytes_budget():
-    stats = _train_stats(remat=False)
-    tripped = [v.metric for v in gate.check_program("zero3_train_batch", stats)]
-    assert "peak_bytes" in tripped, f"tripped only: {tripped}"
 
 
 def test_f32_upcast_trips_dtype_audit():
@@ -44,29 +37,6 @@ def test_f32_upcast_trips_dtype_audit():
     assert "f32_dot_count" in tripped, f"tripped only: {tripped}"
     f32v = next(v for v in violations if v.metric == "f32_dot_count")
     assert f32v.budget == 0 and f32v.measured > 0
-
-
-def test_defusing_a_matmul_trips_kernel_count_budget():
-    x = jnp.ones((128, 128), jnp.bfloat16)
-    w = jnp.ones((128, 128), jnp.bfloat16)
-
-    def fused(x, w):
-        return jnp.sin((x @ w).astype(jnp.float32) * 2.0 + 1.0).sum()
-
-    def defused(x, w):
-        y = (x @ w).astype(jnp.float32)
-        y = jax.lax.optimization_barrier(y)  # the injected fusion break
-        y = jax.lax.optimization_barrier(y * 2.0)
-        return jnp.sin(jax.lax.optimization_barrier(y + 1.0)).sum()
-
-    budget = budget_from_stats(stats_from_callable(fused, x, w, name="mm_fused"))
-    bad = stats_from_callable(defused, x, w, name="mm_fused")
-    tripped = [v.metric for v in check_stats(bad, budget)]
-    # the CPU backend optimizes through the barriers, so the catch is the
-    # jax-level program-size ratchet (backends that keep the split would
-    # additionally trip the fusion/entry-kernel counters)
-    assert {"stablehlo_op_count", "entry_instruction_count",
-            "fusion_count"} & set(tripped), f"tripped only: {tripped}"
 
 
 def test_doubling_collective_payload_trips_byte_budget(mesh8):
@@ -96,8 +66,8 @@ def test_widening_the_draft_tree_trips_tree_verify_flops_budget():
     from deepspeed_tpu.perf.programs import build_v2_engine
 
     engine, _ = build_v2_engine()
-    wide = stats_from_lowered(engine.lower_tree_verify(bucket=(16, 8, 4),
-                                                       greedy=True),
+    wide = stats_from_lowered(engine.lower_verify(bucket=(16, 8, 4), tree=True,
+                                                  greedy=True),
                               name="spec_tree_verify")
     tripped = [v.metric for v in gate.check_program("spec_tree_verify", wide)]
     assert "flops" in tripped, f"tripped only: {tripped}"

@@ -58,7 +58,6 @@ def test_chip_table_lookup_and_default():
     assert get_chip_spec("v5e").peak_bf16_flops == pytest.approx(197e12)
     with pytest.raises(KeyError):
         get_chip_spec("v99")
-    # v5e numbers feed bench.py's MFU convention — keep them consistent
     assert set(CHIP_SPECS) >= {"v5e", "v5p", "v4", "v6e"}
 
 

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.v2 import sampling
 from deepspeed_tpu.inference.v2.ragged import handoff
+from deepspeed_tpu.inference.v2.spec import TokenTree
 from deepspeed_tpu.serving import RequestState, ServingConfig, ServingScheduler
 from deepspeed_tpu.serving.config import PrefixCacheConfig, SpeculativeConfig
 from deepspeed_tpu.serving.request import Request
@@ -101,11 +102,12 @@ def test_a_rows_token_is_its_own_whatever_the_batch_bucket_or_path(make_engine, 
     req = Request([1, 2, 3], max_new_tokens=32, temperature=T, seed=seed)
     req.tokens = [9] * index
     rows = np.stack([row, rng.normal(size=cfg.vocab_size).astype(np.float32)])
-    emitted, _ = sched._spec_accept(req, np.array([9, (token + 1) % cfg.vocab_size]), rows)
-    assert emitted == [token]  # row 0 drawn, the draft after it rejected
+    feed = TokenTree.chain([9, (token + 1) % cfg.vocab_size])
+    emitted, path, _ = sched._spec_accept_tree(req, feed, rows, None)
+    assert emitted == [token] and path == []  # row 0 drawn, the draft after it rejected
     # a donor's tokens count: 2 generated before a handoff + 3 here
     req.tokens, req._draw_base = [9] * 3, 2
-    assert sched._spec_accept(req, np.array([9]), rows[:1])[0] == [token]
+    assert sched._spec_accept_tree(req, TokenTree.chain([9]), rows[:1], None)[0] == [token]
     assert sched.stats()["counters"]["host_draws"] == 2
     sched.stop(drain=False)
 

@@ -202,6 +202,59 @@ def test_learned_drafter_token_identical_sampled(make_perm_engine, perm_setup,
         on.stop(drain=False)
 
 
+# ------------------------------------------------- one verify step, by shape --
+def test_the_verify_program_follows_the_trees_shape(make_perm_engine, perm_setup,
+                                                    distilled):
+    """Who drafts does not pick the program; the batch's shape does. With a
+    learned head, the bootstrap tick's root-only tree is a chain and takes the
+    causal program (``put``'s attention arm); only a branching draft reaches
+    the ancestor-mask one. With ``prompt_lookup``, a tick without a draft is a
+    plain ``put`` (no verify program exists), a tick with one the causal
+    program again."""
+    _, _, order, _ = perm_setup
+    path, _ = distilled
+    walk = _cycle_prompt(order, n=12)
+
+    def verify_programs(engine):
+        return {(tree, greedy)
+                for _, _, tree, greedy in engine.lowerable_callables()["verify"]}
+
+    def serve(config, prompt, at_first_decode=None):
+        engine = make_perm_engine()
+        sched = ServingScheduler(engine, config, start=False)
+        try:
+            req = sched.submit(prompt, max_new_tokens=10)
+            if at_first_decode is not None:
+                _run_until(sched, lambda: req.decode_steps >= 1)
+                assert verify_programs(engine) == at_first_decode
+            _run_until(sched, lambda: req.finished)
+        finally:
+            sched.stop(drain=False)
+        return req, verify_programs(engine), sched.stats()["counters"]
+
+    off, none, _ = serve(ServingConfig(), walk[:8])
+    assert none == set()
+
+    learned, programs, counters = serve(_learned_config(path), walk[:8],
+                                        at_first_decode={(False, True)})
+    assert programs == {(False, True), (True, True)}
+    assert learned.result() == off.result() and counters["spec_drafted_learned"] > 0
+
+    lookup_cfg = ServingConfig(speculative=SpeculativeConfig(
+        enabled=True, drafter="prompt_lookup", max_draft_tokens=3))
+    # every token of the walk is new: nothing to look up, nothing but put
+    blind, programs, counters = serve(lookup_cfg, walk[:8])
+    assert programs == set() and counters["spec_tree_nodes"] == 0
+    assert blind.result() == off.result()
+    # the walk, then its start again: the history holds what comes next
+    again, programs, counters = serve(lookup_cfg, walk + walk[:4])
+    assert programs == {(False, True)}
+    assert again.result() == [int(t) for t in order[104:114]]
+    assert again.spec_accepted > 0 and again.decode_steps < 9
+    assert counters["spec_tree_nodes"] > counters["spec_drafted_lookup"] > 0
+    assert counters["spec_tree_compactions"] == 0  # a chain's path needs no copy
+
+
 # --------------------------------------------------- acceptance-floor gate --
 def test_learned_acceptance_strictly_beats_prompt_lookup(make_perm_engine,
                                                          perm_setup, distilled):

@@ -159,7 +159,6 @@ def test_bucket_is_next_power_of_two():
 def test_program_mapping():
     pf = PerfObservedLedger.program_for
     assert pf("decode_loop", 4, 4) == "paged_decode_step"
-    assert pf("verify", 2, 10) == "spec_verify_step"
     assert pf("verify_tree", 1, 16) == "spec_tree_verify"
     assert pf("put", 2, 50) == "prefix_suffix_prefill"
     assert pf("put", 4, 4) == "paged_decode_step"  # all-single-token feeds
