@@ -249,7 +249,7 @@ class InferenceEngineV2:
         return self._put(batch_uids, batch_tokens, do_checks, None)
 
     def put_draw(self, batch_uids: Iterable[int], batch_tokens: Iterable,
-                 temperature, seed, draw_index, do_checks: bool = True):
+                 temperature, seed, draw_index, do_checks: bool = True, prev=None):
         """:meth:`put`, with each sequence's next token drawn on the device
         (:mod:`~deepspeed_tpu.inference.v2.sampling`): the same forward
         program, the draw dispatched behind it, and device int32 ids
@@ -258,17 +258,31 @@ class InferenceEngineV2:
         (0 = greedy), ``seed`` and ``draw_index`` (tokens the request has
         emitted over its whole life) hold one entry a sequence; a sequence's
         token depends on its own three and its logits, never on the batch.
-        :meth:`warm_draw` builds the draw's programs ahead of the first
-        call."""
-        return self._put(batch_uids, batch_tokens, do_checks, (temperature, seed, draw_index))
+
+        The ids are returned before anything is fetched, and a later call can
+        take them as they are: ``prev`` = ``(ids, index)``, the ids an
+        earlier ``put_draw`` returned and, for each sequence of THIS batch,
+        the entry of them that is its first input token (-1: the token in
+        ``batch_tokens`` stands). The merge is one tiny device program in
+        front of the bucket's forward program, which is the one
+        :meth:`put` runs, under the same cache key; so a step whose decode
+        rows continue the step before can be dispatched while that one still
+        runs, and the device goes from one into the other. All host
+        bookkeeping (KV allocation, ``seen_tokens``, the rolling release) is
+        done when the call returns, as for any step.
+        :meth:`warm_draw` builds the draw's and the merge's programs ahead of
+        the first call."""
+        return self._put(batch_uids, batch_tokens, do_checks,
+                         (temperature, seed, draw_index), prev)
 
     def warm_draw(self) -> None:
         """Compile :meth:`put_draw`'s draw for every sequence bucket this
-        engine can produce (a ``ServingScheduler`` calls it when it is
-        constructed: set-up, never a first request's stall)."""
+        engine can produce, and its ``prev`` merge for every token bucket
+        beside (a ``ServingScheduler`` calls it when it is constructed:
+        set-up, never a first request's stall)."""
         self._model.warm_draw()
 
-    def _put(self, batch_uids, batch_tokens, do_checks, draw):
+    def _put(self, batch_uids, batch_tokens, do_checks, draw, prev=None):
         batch_uids = list(batch_uids)
         batch_tokens = [np.atleast_1d(np.asarray(t)) for t in batch_tokens]
         spans, observer, metrics = self._telemetry_sinks()
@@ -276,12 +290,21 @@ class InferenceEngineV2:
         n_tokens = int(sum(t.size for t in batch_tokens)) if live else 0
 
         self._prepare_forward(spans, batch_uids, batch_tokens, do_checks, n_tokens)
+        n_padded = self._batch.device_batch["tok_meta"].shape[1]
         args = self._dispatch_args(spans, batch_uids, tokens=n_tokens)
         if args is not None:
             # the arm the bucket's program takes (modules/heuristics.py):
             # paged_tiled / paged_token / xla_gather
-            args["attention"] = self._model.attention_arm(
-                self._batch.device_batch["tok_meta"].shape[1])
+            args["attention"] = self._model.attention_arm(n_padded)
+        if prev is not None:
+            # per sequence -> per token slot: a sequence's first token
+            ids, index = prev
+            src = np.full(n_padded, -1, np.int32)
+            first = np.cumsum([0] + [t.size for t in batch_tokens[:-1]])
+            src[first] = index
+            prev = (ids, src)
+            if args is not None:
+                args["chained"] = int((src >= 0).sum())
         with _tel_live_span(spans, "put", "inference", args):
             if observer is not None:
                 _t0 = _tel_now_us()
@@ -289,7 +312,7 @@ class InferenceEngineV2:
                 out = self._model.forward(self._batch)
                 assert out.shape[0] == self._batch.current_sequences
             else:
-                out = self._model.forward_draw(self._batch, *draw)
+                out = self._model.forward_draw(self._batch, *draw, prev=prev)
             if observer is not None:
                 observer("put", len(batch_uids), n_tokens, (_tel_now_us() - _t0) / 1e6)
             self._post_forward(batch_uids)

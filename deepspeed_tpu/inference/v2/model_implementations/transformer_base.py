@@ -21,7 +21,7 @@ import numpy as np
 
 from deepspeed_tpu.inference.v2 import sampling
 from deepspeed_tpu.inference.v2.ragged.manager_configs import KVCacheConfig
-from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import sequence_buckets
+from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import sequence_buckets, token_buckets
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import DSSequenceDescriptor
 from deepspeed_tpu.telemetry import compile_watch
 
@@ -179,18 +179,21 @@ class DSTransformerModelBase:
         logits, n = self._forward_padded(ragged_batch)
         return logits[:n] if n else logits[:0]
 
-    def forward_draw(self, ragged_batch, temperature, seed, draw_index):
+    def forward_draw(self, ragged_batch, temperature, seed, draw_index, prev=None):
         """:meth:`forward`, then one token drawn per sequence ON THE DEVICE
         (:mod:`~deepspeed_tpu.inference.v2.sampling`): the bucket's forward
         program — the one :meth:`forward` runs — and, dispatched right behind
         it, the draw over its padded logits. Returns the device int32
         ``[S_bucket]`` ids; rows past the live sequences are padding. The
         per-sequence ``temperature`` / ``seed`` / ``draw_index`` are host
-        vectors, one entry a live sequence."""
-        logits, _ = self._forward_padded(ragged_batch)
+        vectors, one entry a live sequence. ``prev`` = ``(ids, src)`` feeds
+        token slot t from ``ids[src[t]]`` — the ids an earlier call returned,
+        fetched or not — wherever ``src[t] >= 0`` (``sampling.chain``, one
+        tiny program in front of the same forward)."""
+        logits, _ = self._forward_padded(ragged_batch, prev)
         return sampling.draw(logits, temperature, seed, draw_index)
 
-    def _forward_padded(self, ragged_batch):
+    def _forward_padded(self, ragged_batch, prev=None):
         """The bucket's program over ``ragged_batch``: its padded
         ``[S_bucket, vocab]`` logits and the live sequence count."""
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
@@ -198,7 +201,8 @@ class DSTransformerModelBase:
                   batch["seq_meta"].shape[1] - 4)
         fn = self._get_compiled(bucket)
         cache = self._state_manager.kv_cache.cache
-        dev = {"tok_meta": batch["tok_meta"], "seq_meta": batch["seq_meta"]}
+        tok_meta = batch["tok_meta"] if prev is None else sampling.chain(batch["tok_meta"], *prev)
+        dev = {"tok_meta": tok_meta, "seq_meta": batch["seq_meta"]}
         logits, new_cache = fn(self._params, cache, dev)
         self._state_manager.kv_cache.set_cache(new_cache)
         return logits, int(batch["n_seqs"])
@@ -209,7 +213,9 @@ class DSTransformerModelBase:
         KV pool's mesh, replicated, or on the default device of a mesh-less
         engine. (Under tensor parallelism the compiler may leave the logits
         split over the vocabulary; that draw is then built at its first
-        step.)"""
+        step.) And the program that feeds a step from the ids of the one
+        before (``sampling.chain``), for every pair of a token bucket and a
+        sequence bucket: a few operations each."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
         pool = self._state_manager.kv_cache.sharding
@@ -221,6 +227,8 @@ class DSTransformerModelBase:
                    self._state_manager.kv_cache.num_blocks)
         for rows in sequence_buckets(most):
             sampling.compiled(rows, self.vocab_size, placed)
+            for tokens in token_buckets(sm.max_ragged_batch_size):
+                sampling.compiled_chain(tokens, rows)
 
     def empty_run(self) -> None:
         """Participate in collectives with zero live tokens (fork engine_v2.py:308).

@@ -43,6 +43,12 @@ def sequence_buckets(most: int):
     return sorted({_pad_to(n, 8) for n in range(1, most + 1)})
 
 
+def token_buckets(most: int):
+    """The padded token counts (a bucket's T) that batches of 1 to ``most``
+    tokens land in."""
+    return sorted({to_padded(n) for n in range(1, most + 1)})
+
+
 def _pow2_pad(n: int, minimum: int = 4) -> int:
     """Power-of-two bucket: the block-table width grows every block with plain
     granularity padding, which would recompile the decode program every few

@@ -13,6 +13,11 @@ its batch-mates, on the sequence bucket, on which execute path a tick took or
 on which replica holds the request. Temperature is data, not a compile-time
 flag: greedy and sampled rows share one program.
 
+A step's ids can feed the next step without leaving the device
+(``chain``): the serving scheduler dispatches a step behind the one in flight
+when no arrival could have joined it, and its decode rows take their input
+ids from the ids being drawn.
+
 The program is compiled ahead of time (``compiled``) — per row count, vocab
 and the logits' sharding — and kept for the process: a ``jax.jit`` cache
 would key on whether its argument is committed, and a draw that compiles on
@@ -80,6 +85,62 @@ def compiled(rows: int, vocab: int, sharding):
             exe = (build if cw is None else cw.wrap("inference_draw", key[:2], build))()
             _EXECUTABLES[key] = exe
     return exe
+
+
+def chain_ids(tok_meta, ids, src):
+    """A ragged batch's packed ``[4, T]`` token metadata with the input ids of
+    its row 0 taken, where ``src[t] >= 0``, from ``ids[src[t]]`` — the
+    ``[R]`` ids the step before drew, still on the device; a slot with
+    ``src[t] < 0`` keeps the id the host wrote."""
+    import jax
+    import jax.numpy as jnp
+    with jax.named_scope("chain"):
+        row = jnp.where(src >= 0, ids[jnp.maximum(src, 0)], tok_meta[0])
+        return tok_meta.at[0].set(row)
+
+
+def compiled_chain(tokens: int, rows: int):
+    """:func:`chain_ids`' executable for a ``[4, tokens]`` batch fed from
+    ``[rows]`` ids. Lowered with no sharding named, so its result is as free
+    to place as the host array it stands in for: the forward program that
+    takes it next sees the argument it was compiled for and is not built a
+    second time."""
+    key = ("chain", tokens, rows)
+    exe = _EXECUTABLES.get(key)
+    if exe is not None:
+        return exe
+    import jax
+    import jax.numpy as jnp
+
+    def build():
+        return jax.jit(chain_ids).lower(
+            jax.ShapeDtypeStruct((4, tokens), jnp.int32),
+            jax.ShapeDtypeStruct((rows, ), jnp.int32),
+            jax.ShapeDtypeStruct((tokens, ), jnp.int32)).compile()
+
+    with _LOCK:
+        exe = _EXECUTABLES.get(key)
+        if exe is None:
+            cw = compile_watch.get()
+            exe = (build if cw is None else cw.wrap("inference_chain", key[1:], build))()
+            _EXECUTABLES[key] = exe
+    return exe
+
+
+def chain(tok_meta: np.ndarray, ids, src: np.ndarray):
+    """``tok_meta`` (host) with the slots ``src`` names fed from the device
+    ids ``ids`` of the step before (:func:`chain_ids`), as a device array:
+    nothing is fetched, so the step that takes it can be dispatched while the
+    one that draws ``ids`` still runs. Ids that do not lie whole on the
+    default device (split over a mesh, or on another chip) are fetched and
+    merged from the host instead: the same batch, one step later."""
+    import jax
+    default = jax.local_devices()[0]
+    if ids.sharding.device_set != {default}:
+        local = [s.data for s in ids.addressable_shards
+                 if s.device == default] if ids.is_fully_replicated else []
+        ids = local[0] if local else np.asarray(ids)
+    return compiled_chain(tok_meta.shape[1], ids.shape[0])(tok_meta, ids, src)
 
 
 def _padded(values, rows: int, dtype) -> np.ndarray:

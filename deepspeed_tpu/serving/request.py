@@ -206,6 +206,9 @@ class Request:
         # scheduler-private bookkeeping (touched on the scheduler thread only)
         self._fed = 0                 # prompt tokens already put() into the engine
         self._next: Optional[int] = None  # next decode input token
+        # tokens drawn for this request by steps dispatched and not yet
+        # fetched: counted at dispatch, streamed (or discarded) at emit
+        self._pending = 0
         self._deferred = 0            # consecutive ticks skipped under pressure
         self._last_touch_s = self.arrival_s  # eviction coldness ordering
         self._last_token_s: Optional[float] = None  # ITL measurement

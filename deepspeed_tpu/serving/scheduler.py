@@ -24,7 +24,11 @@ Batch composition per tick (``step()``):
 5. decode-only batches with ``decode_chunk > 1`` run through the on-device
    ``engine.decode_loop`` (one dispatch per K tokens);
 6. idle ticks heartbeat ``engine.empty_run()`` so idle EP replicas stay in
-   collective lock-step with busy ones.
+   collective lock-step with busy ones;
+7. a ``put`` step whose plan is closed to arrivals (it uses the whole token
+   budget or the whole sequence cap) is left on the device unfetched, and the
+   next tick dispatches its step behind it before fetching it, if that plan
+   is closed too (``step()``).
 """
 
 import itertools
@@ -67,6 +71,24 @@ KILLED_ERROR_PREFIX = "replica killed"
 
 
 _DRAFTER_PINS = ("prompt_lookup", "learned", "auto")
+
+# why a step was fetched before the step after it was dispatched
+# (``drained_steps_<reason>`` in stats()["counters"], ``drain`` on a tick span)
+_DRAIN_REASONS = ("open", "decode_loop", "verify", "pressure", "control", "stop")
+
+
+class _PutStep:
+    """A ``put`` step dispatched and not yet fetched: its plan, the device
+    ids drawn for it, and per plan entry what the id is to its request —
+    ``"first"`` (the chunk that completed the prompt), ``"decode"``, or None
+    (a mid-prompt chunk: meaningless)."""
+
+    __slots__ = ("plan", "ids", "rows", "row_of", "phases", "t0_us", "tick")
+
+    def __init__(self, plan, ids, rows, phases, t0_us, tick):
+        self.plan, self.ids, self.rows = plan, ids, rows
+        self.row_of = {req.uid: i for i, (req, _) in enumerate(plan)}
+        self.phases, self.t0_us, self.tick = phases, t0_us, tick
 
 
 def _validate_drafter_pin(drafter) -> Optional[str]:
@@ -183,7 +205,16 @@ class ServingScheduler:
                            "peer_fetch_blocks", "steals",
                            "tier_demotions", "brownout_demotions",
                            "parks", "rehydrates", "fair_share_shed",
-                           "device_draws", "host_draws")}
+                           "device_draws", "host_draws",
+                           "put_steps", "pipelined_steps", "overrun_rows")
+                          + tuple(f"drained_steps_{r}" for r in _DRAIN_REASONS)}
+        # the put step on the device that no tick has fetched yet (step()),
+        # why the newest fetched step was fetched before its successor was
+        # dispatched, and whether the batch being built behind a step in
+        # flight met something that needs it fetched first
+        self._inflight: Optional[_PutStep] = None
+        self._sync_reason = "open"
+        self._behind_block: Optional[str] = None
         self._stopping = False   # no new submits
         self._shutdown = False   # thread exit
         self._stopped = False
@@ -909,16 +940,41 @@ class ServingScheduler:
 
     # ------------------------------------------------------------------ tick --
     def step(self) -> bool:
-        """One scheduling iteration; returns True iff a batch executed.
-        Runs on the scheduler thread — or inline when ``start=False``.
+        """One scheduling iteration; returns True iff a batch executed or a
+        step in flight was fetched. Runs on the scheduler thread — or inline
+        when ``start=False``.
+
+        A tick dispatches ONE engine step. The tick of a ``put`` step whose
+        plan leaves room for an arrival — fewer tokens than the budget and
+        fewer sequences than the cap — and of every ``decode_loop`` chunk and
+        verify step, runs its phases in this order: ``admit``,
+        ``build_batch``, then in :meth:`_execute` the engine's ``prepare`` and
+        dispatch, ``fetch``, ``emit``. A ``put`` step whose plan is CLOSED to
+        arrivals stays on the device when its tick ends; the next tick builds
+        its plan from what is counted (who finishes by length, whose prompt is
+        fed) and, if that plan is closed too, dispatches it BEHIND the step in
+        flight — its decode rows take their input ids from the ids being
+        drawn, on the device — and only then fetches and emits the step before
+        it: ``admit``, ``build_batch``, ``prepare`` + dispatch (step i+1),
+        ``fetch`` (step i), ``emit`` (step i). The device goes from one step
+        into the next while the host emits. An arrival loses nothing: it had
+        no room in that plan whenever it was built. A tick that finds the
+        step in flight in the way — the plan it would put behind is open or a
+        ``decode_loop`` chunk, drafts need token values, the build would have
+        to evict, a control call, ``stop`` — fetches and emits it first
+        (``drained_steps_<reason>``) and then runs as the first kind; a
+        request's tokens are the same either way (its draws are keyed by a
+        counted position).
 
         With telemetry on, a tick that has work is one ``tick`` span (cat
-        ``sched``) holding its phases in order: ``admit``, ``build_batch``,
-        then in :meth:`_execute` the engine's ``prepare`` and dispatch spans
-        (cat ``inference``), ``fetch`` and ``emit``. Each is also a
-        ``dstpu.sched.*`` annotation on this thread's line of a jax.profiler
-        trace. An idle poll (nothing queued, nothing active) records nothing;
-        :meth:`_run` covers it with ``no_work``."""
+        ``sched``) holding those phases as spans (the engine's are cat
+        ``inference``); its args name the step it dispatched: ``seqs``,
+        ``tokens``, ``kind``, ``pipelined`` (1: dispatched before the step
+        before it was fetched) and, when 0, ``drain`` (why that step had been
+        fetched first). Each is also a ``dstpu.sched.*`` annotation on this
+        thread's line of a jax.profiler trace. An idle poll (nothing queued,
+        nothing active) records nothing; :meth:`_run` covers it with
+        ``no_work``."""
         spans = self._spans
         if spans is not None and not self._has_work():
             spans = None
@@ -938,11 +994,51 @@ class ServingScheduler:
             self._tick_spans = self._tick = None
 
     def _step_phases(self, spans) -> bool:
+        fetched = False
+        if self._inflight is not None:
+            # a closed step is on the device, unfetched: its successor goes
+            # behind it unless something needs its values, or an idle engine
+            reason = "control" if self._control else "stop" if self._stopping else None
+            if reason is None:
+                self._behind_block = None
+                self._admit_phase(spans, control=False)
+                plan = self._build_phase(spans)
+                reason = self._behind_block or self._drain_reason(plan)
+            if reason is None:
+                self._run_plan(plan)
+                return True
+            fetched = self._sync(reason)
+        self._admit_phase(spans)
+        plan = self._build_phase(spans)
+        if not plan:
+            if not self._active:
+                self._starved_ticks = 0  # idle, not starved
+            else:
+                self._starved_ticks += 1
+                if self._starved_ticks >= _STARVATION_FAIL_TICKS:
+                    for req in list(self._active.values()):
+                        self._finalize(req, RequestState.FAILED,
+                                       error=f"starved: unschedulable for "
+                                             f"{self._starved_ticks} ticks "
+                                             f"({self._engine.free_blocks} free KV blocks)")
+                    self._starved_ticks = 0  # a fresh grace period for later work
+            return fetched
+        self._run_plan(plan)
+        return True
+
+    def _run_plan(self, plan) -> None:
+        self._starved_ticks = 0
+        self._execute(plan)
+        self._counters["batches"] += 1
+
+    def _admit_phase(self, spans, control: bool = True) -> None:
         # args are filled in when known: what is there at entry rides on the
         # profiler annotation, and a placeholder would read as a value there
         args = None if spans is None else {}
         with live_span(spans, "admit", "sched", args):
-            self._drain_control()
+            if control:
+                # control calls read sequence state: never beside a step in flight
+                self._drain_control()
             now = time.monotonic()
             for req in list(self._active.values()):
                 # the deadline check doubles as the decode feed-stop: a request
@@ -957,29 +1053,32 @@ class ServingScheduler:
             admitted = self._admit(now)
             if args is not None:
                 args["admitted"] = admitted
+
+    def _build_phase(self, spans):
         args = None if spans is None else {}
         with live_span(spans, "build_batch", "sched", args):
             evicted = self._evicted_total()
             plan = self._build_batch()
             if args is not None:
                 args["evicted"] = self._evicted_total() - evicted
+        return plan
+
+    def _closed(self, plan) -> bool:
+        """No arrival could have joined ``plan``: it uses the whole token
+        budget or the whole sequence cap, so :meth:`_build_batch` had no room
+        for a newcomer whenever it ran."""
+        sm = self._engine._config.state_manager
+        return (len(plan) >= sm.max_ragged_sequence_count
+                or sum(int(toks.size) for _, toks in plan) >= sm.max_ragged_batch_size)
+
+    def _drain_reason(self, plan) -> Optional[str]:
+        """Why ``plan`` must wait for the step in flight to be fetched; None
+        when it can be dispatched behind it."""
         if not plan:
-            if not self._active:
-                self._starved_ticks = 0  # idle, not starved
-            else:
-                self._starved_ticks += 1
-                if self._starved_ticks >= _STARVATION_FAIL_TICKS:
-                    for req in list(self._active.values()):
-                        self._finalize(req, RequestState.FAILED,
-                                       error=f"starved: unschedulable for "
-                                             f"{self._starved_ticks} ticks "
-                                             f"({self._engine.free_blocks} free KV blocks)")
-                    self._starved_ticks = 0  # a fresh grace period for later work
-            return False
-        self._starved_ticks = 0
-        self._execute(plan)
-        self._counters["batches"] += 1
-        return True
+            return "open"
+        if self._chunk_steps(plan):
+            return "decode_loop"  # its chunk returns host tokens: synchronous
+        return None if self._closed(plan) else "open"
 
     def _evicted_total(self) -> int:
         c = self._counters
@@ -1129,6 +1228,7 @@ class ServingScheduler:
         if self._stopped or self._killed:
             raise SchedulerStopped("scheduler is stopped")
         if self._thread is None:
+            self._sync("control")  # fn reads sequence state: nothing in flight
             return fn()
         box = {"done": threading.Event(), "result": None, "error": None}
         self._control.append((fn, box))
@@ -1638,6 +1738,14 @@ class ServingScheduler:
 
     # -------------------------------------------------------- batch building --
     def _build_batch(self) -> List[Tuple[Request, np.ndarray]]:
+        """The next step's plan. It reads counts only — what is fed, who
+        decodes, how many tokens each request has or has in flight — so it can
+        run while a step is on the device unfetched: a request whose token in
+        flight is its last (by length or context) is left out, a decode row
+        whose input is in flight carries a placeholder the engine overwrites
+        from the device ids (:meth:`_dispatch_put`), and what needs more than
+        counts (a draft, an eviction) sets ``_behind_block`` for the tick to
+        fetch that step first and build again."""
         engine = self._engine
         sm_cfg = engine._config.state_manager
         budget = sm_cfg.max_ragged_batch_size
@@ -1678,14 +1786,21 @@ class ServingScheduler:
                 [r for r in list(self._active.values()) if r.state is RequestState.DECODE]):
             if len(lens) + 1 > sm_cfg.max_ragged_sequence_count or sum(lens) + 1 > budget:
                 break
+            if req._pending and len(req.tokens) + req._pending >= req.max_new_tokens:
+                continue  # the token in flight is its last: nothing to feed
             seq = engine._state_manager.get_sequence(req.uid)
             if seq is not None and seq.seen_tokens + 1 > sm_cfg.max_context:
+                if req._pending:
+                    continue  # cut below, once its last token has been emitted
                 # context window exhausted: a clean length-cut, not an error
                 req.finish_reason = "context"
                 self._finalize(req, RequestState.DONE)
                 continue
             tree = None
             req._spec_tree = None
+            if draft_budget > 0 and self._inflight is not None:
+                self._behind_block = "verify"  # a draft continues token VALUES
+                return []
             if draft_budget > 0:
                 # draft tokens compete with prefill chunks under the same
                 # ragged token budget; never draft past the generation cap or
@@ -1713,7 +1828,7 @@ class ServingScheduler:
                     # under pressure the root alone still rides the verify
                     # step: the learned drafter reads its hidden state next
                     req._spec_tree = TokenTree.chain([req._next])
-                admit(req, [req._next])
+                admit(req, [0 if req._pending else req._next])
             else:
                 req._deferred += 1  # KV held by in-flight work; retry next tick
 
@@ -1748,7 +1863,14 @@ class ServingScheduler:
 
         With the tier ladder on, *demotion* runs ahead of the eviction
         ladder: a demoted trie node keeps its KV (host tier, promotes back on
-        the next hit) where an evicted leaf recomputes from scratch."""
+        the next hit) where an evicted leaf recomputes from scratch.
+
+        Never beside a step in flight (its sequences must not be offloaded
+        under it): the caller goes without, and the tick fetches that step
+        and builds again (``drained_steps_pressure``)."""
+        if self._inflight is not None:
+            self._behind_block = "pressure"
+            return False
         if self._kv_tiers is not None and self._prefix_cache is not None:
             freed = self._prefix_cache.demote(1)
             if freed:
@@ -1783,6 +1905,16 @@ class ServingScheduler:
 
     # --------------------------------------------------------------- execute --
     def _execute(self, plan: List[Tuple[Request, np.ndarray]]) -> None:
+        """Dispatch ``plan`` as one engine step. A verify step and a
+        ``decode_loop`` chunk are fetched and emitted here, at once (no step
+        is in flight beside them: :meth:`_drain_reason`). A ``put`` step is
+        dispatched (:meth:`_dispatch_put`: everything that needs only counts
+        happens there); then the step before it, if it is still in flight, is
+        fetched and emitted UNDER it (:meth:`_complete`: everything that needs
+        token values); and it stays in flight itself for the next tick to do
+        the same iff its plan is closed to arrivals (:meth:`_closed`) —
+        otherwise it is fetched and emitted now, and the next tick builds its
+        plan after every arrival this step's run time brought."""
         engine = self._engine
         uids = [req.uid for req, _ in plan]
         tokens = [t for _, t in plan]
@@ -1794,61 +1926,36 @@ class ServingScheduler:
         self._touch_kv_plan(plan)
         spans = self._tick_spans
         tick = self._tick
-        if spans is not None:
-            # capture each request's phase before the processing loop mutates
-            # state (PREFILL flips to DECODE on the final chunk)
-            _t0 = now_us()
-            _phases = [("prefill" if req.state is RequestState.PREFILL else "decode",
-                        int(toks.size)) for req, toks in plan]
-            tick.update(seqs=len(plan), tokens=sum(n for _, n in _phases), kind="put")
+        # each request's phase, before dispatch flips a PREFILL whose final
+        # chunk this is to DECODE
+        t0 = now_us()
+        phases = [("prefill" if req.state is RequestState.PREFILL else "decode",
+                   int(toks.size)) for req, toks in plan]
+        tick_no = None
+        if tick is not None:
+            tick_no = tick["tick"]
+            tick.update(seqs=len(plan), tokens=sum(n for _, n in phases), kind="put")
 
-        def _record_phase_spans(counts=None):
-            if spans is None:
-                return
-            end = now_us()
-            for i, ((phase, ntok), (req, _)) in enumerate(zip(_phases, plan)):
-                spans.record(phase, cat="serving", ts_us=_t0, dur_us=end - _t0,
-                             trace_id=req.trace_id, parent_id=req.root_span_id,
-                             args={"uid": req.uid, "tick": tick["tick"],
-                                   "tokens": ntok if counts is None else counts[i]})
+        def record_phase_spans(counts=None):
+            self._record_phases(spans, plan, phases, t0, now_us(), tick_no, counts)
 
         # speculative verify: any decode entry carrying a TokenTree (a draft,
         # or the root alone for a learned head's hidden state) routes the
         # tick through ONE engine.verify_tree dispatch
         if any(req._spec_tree is not None for req, _ in plan):
-            if tick is not None:
-                tick["kind"] = "verify_tree"
-            self._execute_verify_tree(plan, _record_phase_spans)
+            self._synchronous_tick("verify_tree", "verify")
+            self._execute_verify_tree(plan, record_phase_spans)
             return
 
-        K = self._config.decode_chunk
-        if K > 1 and self._config.overload.enabled and self._brownout.stage >= 2:
-            K = 1  # brownout stage >= 2: speculative extras disabled
-        max_context = self._engine._config.state_manager.max_context
-
-        def chunk_safe(req):
-            # greedy only. A sampled request's stream is keyed by (seed, draw
-            # index) and could be drawn inside the loop as well; it stays out
-            # as a matter of scheduling: a chunk is K steps in which no arrival
-            # is admitted, and sampled traffic is the interactive, open-loop
-            # kind judged on its time to first token (ROADMAP S2b).
-            # And never past max_context: the device loop always runs K steps,
-            # and tokens beyond the context window must not reach the client
-            seq = engine._state_manager.get_sequence(req.uid)
-            return (req.temperature <= 0.0
-                    and (seq is None or seq.seen_tokens + K <= max_context))
-
-        decode_only = (K > 1 and all(req.state is RequestState.DECODE
-                                     and chunk_safe(req) for req, _ in plan))
-        if decode_only:
+        K = self._chunk_steps(plan)
+        if K:
             try:
                 # decode_loop returns host tokens: the wait is inside its span
                 rows = self._fetch(engine.decode_loop(uids, tokens, K))
             except SchedulingError:
                 rows = None  # KV too tight for K steps — single-step fallback
             if rows is not None:
-                if tick is not None:
-                    tick["kind"] = "decode_loop"
+                self._synchronous_tick("decode_loop", "decode_loop")
                 with self._emit_phase(spans):
                     # record before pushing: the final token finalizes the
                     # request and closes the root span, which children must
@@ -1861,43 +1968,185 @@ class ServingScheduler:
                     # billed work is what the device ran: K decode steps per
                     # member, kept or not (the discarded over-run still computed)
                     self._charge_members([(req, "decode", K) for req, _ in plan])
-                    _record_phase_spans(counts=counts)
+                    record_phase_spans(counts=counts)
                     for (req, _), row, kept in zip(plan, rows, counts):
                         req.decode_steps += 1
                         # eos/cap discard the over-generated tail
                         self._push_burst(req, row[:kept])
                 return
 
+        step = self._dispatch_put(plan, phases, t0, tick_no)
+        if step is None:
+            return
+        self._counters["put_steps"] += 1
+        prev, self._inflight = self._inflight, step
+        if tick is not None:
+            tick["pipelined"] = int(prev is not None)
+            if prev is None:
+                tick["drain"] = self._sync_reason
+        if prev is not None:
+            # the device could first have begun this step when it finished the
+            # one before: its phase spans start there (they do not overlap)
+            step.t0_us = self._complete(prev, None)
+        if not self._closed(plan):
+            self._sync("open")
+        elif self._stopping:
+            self._sync("stop")
+
+    def _chunk_steps(self, plan) -> int:
+        """K if ``plan`` runs as one ``engine.decode_loop`` chunk of K steps
+        (every member decodes, greedily, with K positions of context left),
+        else 0: a ``put`` step."""
+        K = self._config.decode_chunk
+        if K > 1 and self._config.overload.enabled and self._brownout.stage >= 2:
+            K = 1  # brownout stage >= 2: speculative extras disabled
+        if K <= 1:
+            return 0
+        sm = self._engine._state_manager
+        max_context = self._engine._config.state_manager.max_context
+
+        def chunk_safe(req):
+            # greedy only. A sampled request's stream is keyed by (seed, draw
+            # index) and could be drawn inside the loop as well; it stays out
+            # as a matter of scheduling: a chunk is K steps in which no arrival
+            # is admitted, and sampled traffic is the interactive, open-loop
+            # kind judged on its time to first token (ROADMAP S2b).
+            # And never past max_context: the device loop always runs K steps,
+            # and tokens beyond the context window must not reach the client
+            seq = sm.get_sequence(req.uid)
+            return (req.temperature <= 0.0
+                    and (seq is None or seq.seen_tokens + K <= max_context))
+
+        return K if all(req.state is RequestState.DECODE and chunk_safe(req)
+                        for req, _ in plan) else 0
+
+    def _synchronous_tick(self, kind: str, reason: str) -> None:
+        """A tick whose step is fetched inside its own engine call (a
+        ``decode_loop`` chunk, a verify step): nothing goes behind it."""
+        self._count_fetched(reason)
+        if self._tick is not None:
+            self._tick.update(kind=kind, pipelined=0, drain=reason)
+
+    def _count_fetched(self, reason: Optional[str]) -> None:
+        """A step is fetched: behind its successor (None), or before any was
+        dispatched and why."""
+        if reason is None:
+            self._counters["pipelined_steps"] += 1
+        else:
+            self._counters[f"drained_steps_{reason}"] += 1
+            self._sync_reason = reason
+
+    def _record_phases(self, spans, plan, phases, t0_us, end_us, tick_no, counts=None) -> None:
+        """One ``prefill`` / ``decode`` span (cat ``serving``) for each member
+        of a step, all from ``t0_us`` to ``end_us``: the step's wall time as
+        its requests saw it. ``tick`` is the tick that DISPATCHED the step."""
+        if spans is None:
+            return
+        for i, ((phase, ntok), (req, _)) in enumerate(zip(phases, plan)):
+            spans.record(phase, cat="serving", ts_us=t0_us, dur_us=end_us - t0_us,
+                         trace_id=req.trace_id, parent_id=req.root_span_id,
+                         args={"uid": req.uid, "tick": tick_no,
+                               "tokens": ntok if counts is None else counts[i]})
+
+    def _dispatch_put(self, plan, phases, t0_us=0, tick_no=None) -> Optional[_PutStep]:
+        """``plan`` through ``engine.put_draw``, NOT fetched, and everything a
+        step changes that can be counted without its token values: ``_fed``,
+        the PREFILL→DECODE flip of a request whose last chunk this is,
+        ``decode_steps``, the tokens each request now has in flight
+        (``_pending``: its draw index and its finish by length count them),
+        billing. The engine did its own share inside the call (KV allocation,
+        ``seen_tokens``, the rolling release). A decode row whose input token
+        is still in flight — its request is in the step before, unfetched —
+        takes it from that step's device ids. None if the engine raised (the
+        plan's requests have then failed)."""
+        reqs = [req for req, _ in plan]
+        prev = self._inflight
+        feed = None if prev is None else (
+            prev.ids, [prev.row_of[req.uid] if req._pending else -1 for req in reqs])
         try:
-            ids = self._put_draw(plan)
+            ids = self._engine.put_draw([req.uid for req in reqs], [toks for _, toks in plan],
+                                        *self._draw_inputs(reqs), prev=feed)
         except Exception as e:  # pragma: no cover - defensive: the scheduler
             # thread must survive an engine fault; the batch's requests fail
             logger.exception("serving: engine.put_draw failed; failing the batch")
-            for req, _ in plan:
+            for req in reqs:
                 self._finalize(req, RequestState.FAILED, error=f"engine error: {e}")
-            return
-        with self._emit_phase(spans):
-            self._rate.observe(sum(int(t.size) for t in tokens))
-            # attribute BEFORE the processing loop flips any PREFILL to DECODE
-            self._charge_members(
-                [(req, "prefill" if req.state is RequestState.PREFILL else "decode",
-                  int(toks.size)) for req, toks in plan])
-            _record_phase_spans()
-            for i, (req, toks) in enumerate(plan):
-                if req.state is RequestState.PREFILL:
-                    self._advance_prefill(req, toks, int(ids[i]))
-                else:
-                    req.decode_steps += 1
-                    self._push_drawn(req, int(ids[i]))
+            return None
+        self._charge_members([(req, phase, n) for req, (phase, n) in zip(reqs, phases)])
+        rows = []
+        for req, toks in plan:
+            row = "decode"
+            if req.state is RequestState.PREFILL:
+                req._fed += toks.size
+                row = None  # mid-prompt logits are meaningless
+                if req._fed >= req.prompt.size:
+                    req._set_state(RequestState.DECODE)
+                    row = "first"
+            else:
+                req.decode_steps += 1
+            if row is not None:
+                req._pending += 1
+            rows.append(row)
+        return _PutStep(plan, ids, rows, phases, t0_us, tick_no)
 
-    def _put_draw(self, plan) -> np.ndarray:
-        """``plan`` through ``engine.put_draw``, fetched: the sequence bucket's
-        int32 ids, entry i the token drawn for ``plan[i]``'s request (a
-        mid-prompt chunk's is meaningless). The put path's step, and how the
-        prefill chunks that share a verify tick run."""
-        reqs = [req for req, _ in plan]
-        return self._fetch(self._engine.put_draw(
-            [req.uid for req in reqs], [toks for _, toks in plan], *self._draw_inputs(reqs)))
+    def _complete(self, step: _PutStep, reason: Optional[str]) -> int:
+        """Fetch ``step``'s ids and emit them: everything that needs token
+        VALUES (:meth:`_emit_rows`), and the step's phase spans. ``reason``
+        is why this happens before the step after it is dispatched
+        (``drained_steps_<reason>``), or None when that step is on the device
+        already (``pipelined_steps``). Returns when the fetch returned, on
+        the span clock."""
+        self._count_fetched(reason)
+        spans = self._tick_spans
+        try:
+            ids = self._fetch(step.ids)
+        except Exception as e:  # pragma: no cover - defensive, as for the dispatch
+            logger.exception("serving: fetching a step's ids failed; failing the batch")
+            for req, _ in step.plan:
+                self._finalize(req, RequestState.FAILED, error=f"engine error: {e}")
+            return now_us()
+        fetched_us = now_us()
+        with self._emit_phase(spans):
+            self._rate.observe(sum(n for _, n in step.phases))
+            self._record_phases(spans, step.plan, step.phases, step.t0_us, fetched_us,
+                                step.tick)
+            self._emit_rows(step, ids)
+        return fetched_us
+
+    def _sync(self, reason: str) -> bool:
+        """Fetch and emit the step in flight, if there is one, before
+        anything that needs an idle engine or the values it drew."""
+        step, self._inflight = self._inflight, None
+        if step is None:
+            return False
+        self._complete(step, reason)
+        return True
+
+    def _emit_rows(self, step: _PutStep, ids: np.ndarray) -> None:
+        """Stream what ``step`` drew: entry i of ``ids`` is ``plan[i]``'s
+        token. On a prompt's first token its blocks are published (peers
+        sharing the prefix are likely already queued behind it — the burst
+        shape). A request that ended while the row was in flight — eos, cancel
+        and deadline are the ends the host cannot count one step early — has
+        its id discarded, never streamed (``overrun_rows``); its KV went with
+        the flush at :meth:`_finalize`, and program order keeps a later owner
+        of those blocks safe. Shared by the put and verify execute paths so
+        prefill behavior cannot depend on whether a draft rode the same
+        batch."""
+        for i, ((req, _), row) in enumerate(zip(step.plan, step.rows)):
+            if row is None:
+                continue
+            if req.finished:
+                self._counters["overrun_rows"] += 1
+                continue
+            req._pending -= 1
+            if row == "first" and self._prefix_cache is not None:
+                seq = self._engine._state_manager.get_sequence(req.uid)
+                if seq is not None:
+                    # a step behind this one may have committed a position more
+                    self._publish(req, seq, req.prompt,
+                                  min(seq.seen_tokens, int(req.prompt.size)))
+            self._push_drawn(req, int(ids[i]))
 
     def _fetch(self, result) -> np.ndarray:
         """The blocking transfer of an engine call's result to the host, apart
@@ -1943,8 +2192,9 @@ class ServingScheduler:
         """What the draw (inference/v2/sampling.py) takes for ``reqs``, one
         entry each: ``temperature``, ``seed`` (its low 32 bits) and
         ``draw_index`` — the tokens the request has emitted over its whole
-        life, a donor's included, plus ``offsets``."""
-        index = np.array([req._draw_base + len(req.tokens) for req in reqs], np.int32)
+        life, a donor's included, and those in flight, plus ``offsets``."""
+        index = np.array([req._draw_base + len(req.tokens) + req._pending for req in reqs],
+                         np.int32)
         return (np.array([req.temperature for req in reqs], np.float32),
                 np.array([req.seed & 0xFFFFFFFF for req in reqs], np.uint32),
                 index if offsets is None else index + np.asarray(offsets, np.int32))
@@ -1973,23 +2223,6 @@ class ServingScheduler:
         self._push_token(req, tok)
         if not req.finished:
             req._next = tok
-
-    def _advance_prefill(self, req: Request, toks: np.ndarray, drawn: int) -> None:
-        """Account one executed prefill chunk; on the final chunk: flip to
-        DECODE, publish the prompt's blocks (peers sharing the prefix are
-        likely already queued behind it — the burst shape), and emit the
-        first token: ``drawn``, from the chunk's final-position logits. Shared by the
-        put and verify execute paths so prefill behavior cannot depend on
-        whether a draft rode the same batch."""
-        req._fed += toks.size
-        if req._fed < req.prompt.size:
-            return  # mid-prefill logits are meaningless
-        req._set_state(RequestState.DECODE)
-        if self._prefix_cache is not None:
-            seq = self._engine._state_manager.get_sequence(req.uid)
-            if seq is not None:
-                self._publish(req, seq, req.prompt, seq.seen_tokens)
-        self._push_drawn(req, drawn)
 
     def _push_burst(self, req: Request, toks) -> None:
         """Stream a multi-token burst (a decode chunk's kept tokens, a verify
@@ -2082,7 +2315,12 @@ class ServingScheduler:
             # prefill put overwrites the observer slots
             verify_s = self._last_dispatch_s
             verify_amnesty_s = self._last_dispatch_amnesty_s
-            prefill_ids = self._put_draw(prefill_plan) if prefill_plan else None
+            # the prefill chunks' step: dispatched (and billed) as any put
+            # step, fetched at once
+            prefill = self._dispatch_put(
+                prefill_plan,
+                [("prefill", int(t.size)) for _, t in prefill_plan]) if prefill_plan else None
+            prefill_ids = self._fetch(prefill.ids) if prefill is not None else None
         except Exception as e:  # pragma: no cover - defensive: same contract
             # as the put path — the scheduler thread must survive
             logger.exception("serving: verify tick failed; failing the batch")
@@ -2096,9 +2334,6 @@ class ServingScheduler:
             self._charge_members([(req, "tree_verify", int(t.size))
                                   for req, t in decode_plan],
                                  seconds=verify_s, amnesty=verify_amnesty_s)
-            if prefill_plan:
-                self._charge_members([(req, "prefill", int(t.size))
-                                      for req, t in prefill_plan])
             alpha = self._config.speculative.accept_alpha
             # sample/accept BEFORE any push: span token counts must be final when
             # the root span closes, and each request's positional stream makes
@@ -2169,8 +2404,8 @@ class ServingScheduler:
                         self._metrics.spec_tokens_per_step.observe(len(emitted))
                         self._metrics.spec_tree_accept_depth.observe(accepted)
                 self._push_burst(req, emitted)
-            for i, (req, toks) in enumerate(prefill_plan):
-                self._advance_prefill(req, toks, int(prefill_ids[i]))
+            if prefill is not None:
+                self._emit_rows(prefill, prefill_ids)
 
     @staticmethod
     def _kept_tokens(req: Request, row) -> int:
@@ -2427,6 +2662,10 @@ class ServingScheduler:
         """The kill disposition, on the engine-owning thread: fail everything
         terminal, free KV, detach, mark dead."""
         error = f"{KILLED_ERROR_PREFIX}: {self._kill_reason or 'killed'}"
+        if self._inflight is not None:
+            # dropped, not fetched: a kill must not wait on the device
+            self._inflight = None
+            self._counters["drained_steps_control"] += 1
         for req in list(self._active.values()):
             self._finalize(req, RequestState.FAILED, error=error)
         while self._queue:
@@ -2479,7 +2718,9 @@ class ServingScheduler:
                 if not self.step():
                     time.sleep(self._config.scheduler_tick_s)
         # cancel whatever drain didn't finish (scheduler thread is dead, so
-        # touching the engine from here is safe)
+        # touching the engine from here is safe); a step still on the device
+        # streams what it drew first
+        self._sync("stop")
         self._fail_control()
         for req in list(self._active.values()):
             self._finalize(req, RequestState.CANCELLED)

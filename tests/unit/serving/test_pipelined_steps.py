@@ -1,0 +1,440 @@
+"""The one-deep pipeline of the scheduler's ``put`` path: a step whose plan is
+closed to arrivals is dispatched before the step before it is fetched, its
+decode rows fed from that step's device ids; anything that needs token values
+or an idle engine fetches the step in flight first. Whatever a tick does, a
+request's tokens are the same.
+"""
+
+import threading
+import time
+
+import numpy as np
+import pytest
+
+import jax.monitoring
+
+from deepspeed_tpu import telemetry
+from deepspeed_tpu.serving import (RequestState, ServingConfig, ServingScheduler,
+                                   SpeculativeConfig)
+
+MAX_STEPS = 600
+# four prompts over a 16-token budget: every step that feeds a prompt is full
+WORK = [(40, 6), (23, 9), (70, 4), (9, 12)]
+CLOSED = dict(max_ragged_batch_size=16, max_ragged_sequence_count=8)
+OPEN = dict(max_ragged_batch_size=512, max_ragged_sequence_count=16)
+
+
+def _run_until(sched, pred, max_steps=MAX_STEPS):
+    for _ in range(max_steps):
+        if pred():
+            return
+        sched.step()
+    raise AssertionError(f"predicate not reached in {max_steps} steps")
+
+
+def _prompts(cfg, work=WORK, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, cfg.vocab_size, n).tolist() for n, _ in work]
+
+
+def _submit_all(sched, cfg, temperature=0.0, work=WORK, **kw):
+    return [sched.submit(p, max_new_tokens=m, temperature=temperature, seed=7 + i, **kw)
+            for i, (p, (_, m)) in enumerate(zip(_prompts(cfg, work), work))]
+
+
+def _serve(make_engine, cfg, temperature=0.0, serving=None, work=WORK, **mgr):
+    engine = make_engine(**mgr)
+    start = engine.free_blocks
+    sched = ServingScheduler(engine, serving or ServingConfig(), start=False)
+    reqs = _submit_all(sched, cfg, temperature, work)
+    _run_until(sched, lambda: all(r.finished for r in reqs))
+    counters = sched.stats()["counters"]
+    sched.stop(drain=False)
+    assert engine.free_blocks == start
+    return [list(r.tokens) for r in reqs], counters
+
+
+def _counted(counters):
+    return counters["pipelined_steps"] + sum(
+        v for k, v in counters.items() if k.startswith("drained_steps_"))
+
+
+@pytest.fixture(scope="module")
+def reference(llama_setup):
+    """The streams of WORK through a scheduler whose plans all stay open."""
+    return {}
+
+
+def _reference(reference, make_engine, cfg, temperature):
+    if temperature not in reference:
+        tokens, counters = _serve(make_engine, cfg, temperature, **OPEN)
+        assert counters["pipelined_steps"] == 0
+        assert counters["drained_steps_open"] == counters["put_steps"] == counters["batches"]
+        reference[temperature] = tokens
+    return reference[temperature]
+
+
+# ------------------------------------------------------------ same streams --
+@pytest.mark.parametrize("temperature", [0.0, 0.8])
+def test_closed_plans_pipeline_and_the_streams_are_the_open_schedulers(
+        make_engine, llama_setup, reference, temperature):
+    cfg, _, _ = llama_setup
+    tokens, counters = _serve(make_engine, cfg, temperature, **CLOSED)
+    assert tokens == _reference(reference, make_engine, cfg, temperature)
+    assert [len(t) for t in tokens] == [m for _, m in WORK]
+    assert counters["pipelined_steps"] >= 5
+    assert counters["overrun_rows"] == 0
+    # every step is counted once: fetched behind its successor, or why not
+    assert _counted(counters) == counters["batches"] == counters["put_steps"]
+
+
+def test_the_sequence_cap_closes_a_plan_as_the_token_budget_does(make_engine, llama_setup):
+    """Two sampled requests under a cap of two sequences: every decode step
+    holds both, no third could join, and the steps go behind one another."""
+    cfg, _, _ = llama_setup
+    work = [(5, 10), (7, 10)]
+    got, counters = _serve(make_engine, cfg, 0.8, work=work, max_ragged_batch_size=64,
+                           max_ragged_sequence_count=2)
+    want, _ = _serve(make_engine, cfg, 0.8, work=work, **OPEN)
+    assert got == want
+    assert counters["pipelined_steps"] >= 8
+
+
+# ----------------------------------------------------- the recording engine --
+class _Ids:
+    """A step's device ids; fetching them is logged."""
+
+    def __init__(self, ids, n, log):
+        self.ids, self.n, self.log = ids, n, log
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("fetch", self.n))
+        return np.asarray(self.ids)
+
+
+class _RecordingEngine:
+    """The engine, with every ``put_draw`` and every fetch of its ids logged
+    in the order the scheduler made them."""
+
+    def __init__(self, engine):
+        self.__dict__["_engine"] = engine
+        self.__dict__["log"] = []
+        self.__dict__["chained"] = {}
+
+    def __getattr__(self, name):
+        return getattr(self._engine, name)
+
+    def __setattr__(self, name, value):
+        setattr(self._engine, name, value)
+
+    def put_draw(self, uids, tokens, *draw, **kw):
+        n = sum(1 for e in self.log if e[0] == "put_draw") + 1
+        if kw.get("prev") is not None:
+            ids, index = kw["prev"]
+            self.chained[n] = (ids.n, dict(zip(uids, index)))
+            kw["prev"] = (ids.ids, index)
+        self.log.append(("put_draw", n))
+        return _Ids(self._engine.put_draw(uids, tokens, *draw, **kw), n, self.log)
+
+
+def test_a_pipelined_tick_dispatches_the_next_step_before_it_fetches_the_last(
+        make_engine, llama_setup, reference):
+    cfg, _, _ = llama_setup
+    engine = _RecordingEngine(make_engine(**CLOSED))
+    sched = ServingScheduler(engine, ServingConfig(), start=False)
+    reqs = _submit_all(sched, cfg)
+    _run_until(sched, lambda: all(r.finished for r in reqs))
+    sched.stop(drain=False)
+    assert [list(r.tokens) for r in reqs] == _reference(reference, make_engine, cfg, 0.0)
+    log = engine.log
+    at = {e: i for i, e in enumerate(log)}
+    assert sorted(n for kind, n in log if kind == "fetch") == \
+        sorted(n for kind, n in log if kind == "put_draw")  # each step fetched once
+    behind = [n for n in engine.chained if at[("put_draw", n)] < at[("fetch", n - 1)]]
+    assert len(behind) == sched.stats()["counters"]["pipelined_steps"] >= 5
+    for n in behind:
+        # ... and fetched right after: the host emits step n-1 under step n
+        assert log[at[("put_draw", n)] + 1] == ("fetch", n - 1)
+    assert all(fed == n - 1 for n, (fed, _) in engine.chained.items())
+
+
+def test_a_prompts_last_chunk_in_flight_feeds_its_first_decode_row_from_the_device(
+        make_engine, llama_setup):
+    """One request, prompt of two full chunks, under a budget of 16: the step
+    after the final chunk is the request's first decode row; it is dispatched
+    with the first token still on the device."""
+    cfg, _, _ = llama_setup
+    engine = _RecordingEngine(make_engine(max_ragged_batch_size=16, max_ragged_sequence_count=1))
+    sched = ServingScheduler(engine, ServingConfig(), start=False)
+    prompt = _prompts(cfg, [(32, 0)])[0]
+    req = sched.submit(prompt, max_new_tokens=5, temperature=0.8, seed=3)
+    sched.step()   # chunk 1: closed (16 tokens), left in flight
+    sched.step()   # chunk 2 behind it; chunk 1 fetched
+    assert req.state is RequestState.DECODE and req.tokens == [] and req._pending == 1
+    sched.step()   # the decode row, behind chunk 2: its input is chunk 2's id 0
+    assert engine.chained[3] == (2, {req.uid: 0})
+    assert engine.chained[2] == (1, {req.uid: -1})   # a chunk takes the host's ids
+    assert len(req.tokens) == 1 and req._pending == 1
+    _run_until(sched, lambda: req.finished)
+    sched.stop(drain=False)
+
+    plain = ServingScheduler(make_engine(**OPEN), ServingConfig(), start=False)
+    ref = plain.submit(prompt, max_new_tokens=5, temperature=0.8, seed=3)
+    _run_until(plain, lambda: ref.finished)
+    plain.stop(drain=False)
+    assert list(req.tokens) == list(ref.tokens) and len(req.tokens) == 5
+
+
+# ------------------------------------------------ ends counted one step early --
+@pytest.mark.parametrize("end", ["length", "context"])
+def test_a_request_whose_token_in_flight_is_its_last_is_not_in_the_next_plan(
+        make_engine, llama_setup, end):
+    cfg, _, _ = llama_setup
+    mgr = dict(max_ragged_batch_size=64, max_ragged_sequence_count=2)
+    if end == "context":
+        mgr["max_context"] = 16   # prompts of 9 and 11: cut at 16 positions
+    engine = _RecordingEngine(make_engine(**mgr))
+    start = engine.free_blocks
+    sched = ServingScheduler(engine, ServingConfig(), start=False)
+    work = [(9, 6), (11, 30 if end == "context" else 9)]
+    reqs = _submit_all(sched, cfg, 0.8, work)
+    _run_until(sched, lambda: all(r.finished for r in reqs))
+    counters = sched.stats()["counters"]
+    sched.stop(drain=False)
+    assert engine.free_blocks == start
+    assert counters["pipelined_steps"] >= 3 and counters["overrun_rows"] == 0
+    assert counters["device_draws"] == sum(len(r.tokens) for r in reqs)  # no surplus row ran
+    if end == "length":
+        assert [len(r.tokens) for r in reqs] == [6, 9]
+        assert all(r.finish_reason == "length" for r in reqs)
+    else:
+        # positions 0..15 hold the prompt and all but the last generated token
+        assert [len(r.tokens) for r in reqs] == [6, 16 - 11 + 1]
+        assert reqs[1].finish_reason == "context"
+    ref = ServingScheduler(make_engine(**dict(OPEN, **{k: v for k, v in mgr.items()
+                                                        if k == "max_context"})),
+                           ServingConfig(), start=False)
+    want = _submit_all(ref, cfg, 0.8, work)
+    _run_until(ref, lambda: all(r.finished for r in want))
+    ref.stop(drain=False)
+    assert [list(r.tokens) for r in reqs] == [list(r.tokens) for r in want]
+
+
+# ------------------------------------------- ends the host cannot count ahead --
+@pytest.mark.parametrize("end", ["eos", "cancel", "deadline"])
+def test_a_row_in_flight_for_a_request_that_ended_is_discarded_never_streamed(
+        make_engine, llama_setup, end):
+    cfg, _, _ = llama_setup
+    mgr = dict(max_ragged_batch_size=64, max_ragged_sequence_count=2)
+    work = [(9, 12), (11, 12)]
+    full, _ = _serve(make_engine, cfg, 0.8, work=work, **mgr)
+
+    engine = make_engine(**mgr)
+    start = engine.free_blocks
+    sched = ServingScheduler(engine, ServingConfig(), start=False)
+    kw = {}
+    cut = 5
+    if end == "eos":
+        # the first token of request 0's stream that did not occur before it
+        cut = next(i for i, t in enumerate(full[0]) if i >= 3 and t not in full[0][:i])
+        kw["eos_token_id"] = full[0][cut]
+    prompts = _prompts(cfg, work)
+    a = sched.submit(prompts[0], max_new_tokens=12, temperature=0.8, seed=7, **kw)
+    b = sched.submit(prompts[1], max_new_tokens=12, temperature=0.8, seed=8)
+    if end == "eos":
+        _run_until(sched, lambda: a.finished)
+        assert a.finish_reason == "eos" and list(a.tokens) == full[0][:cut + 1]
+    else:
+        _run_until(sched, lambda: len(a.tokens) == cut and sched._inflight is not None
+                   and a.uid in sched._inflight.row_of)
+        if end == "cancel":
+            a.cancel()
+        else:
+            a.deadline = time.monotonic() - 1.0
+        _run_until(sched, lambda: a.finished)
+        assert a.state is (RequestState.CANCELLED if end == "cancel" else RequestState.TIMED_OUT)
+        assert list(a.tokens) == full[0][:cut]   # the token in flight was never streamed
+    streamed = len(a.tokens)
+    _run_until(sched, lambda: b.finished)
+    assert len(a.tokens) == streamed and list(a.stream) == list(a.tokens)
+    assert list(b.tokens) == full[1]              # its batch-mate never noticed
+    counters = sched.stats()["counters"]
+    assert counters["overrun_rows"] >= 1
+    sched.stop(drain=False)
+    assert engine.free_blocks == start
+
+
+# ------------------------------------------------------------ drain reasons --
+def _drain_case(reason):
+    """A configuration and workload under which a step in flight meets
+    ``reason``."""
+    if reason == "decode_loop":
+        # greedy, chunks of 4: after the (closed) prompt chunks every plan is
+        # decode-only and takes decode_loop
+        return dict(serving=ServingConfig(decode_chunk=4), mgr=CLOSED, temperature=0.0)
+    if reason == "verify":
+        return dict(serving=ServingConfig(speculative=SpeculativeConfig(
+            enabled=True, max_draft_tokens=3)), mgr=CLOSED, temperature=0.0)
+    if reason == "pressure":
+        # 8 blocks of 16 under three 60-token prompts, two sequences a step:
+        # a plan behind a step in flight would have to evict
+        return dict(serving=ServingConfig(), temperature=0.0,
+                    mgr=dict(num_blocks=8, max_context=128, max_ragged_batch_size=16,
+                             max_ragged_sequence_count=2),
+                    work=[(60, 8), (60, 8), (60, 8)])
+    return dict(serving=ServingConfig(), mgr=CLOSED, temperature=0.8)
+
+
+@pytest.mark.parametrize("reason", ["open", "decode_loop", "verify", "pressure", "control",
+                                    "stop"])
+def test_each_drain_reason_fires_where_it_should_and_the_stream_is_unchanged(
+        make_engine, llama_setup, reason):
+    cfg, _, _ = llama_setup
+    case = _drain_case(reason)
+    work = case.get("work", WORK)
+    open_mgr = dict(OPEN, **{k: v for k, v in case["mgr"].items()
+                             if k in ("max_context", )})
+    want, _ = _serve(make_engine, cfg, case["temperature"], work=work, **open_mgr)
+
+    engine = make_engine(**case["mgr"])
+    start = engine.free_blocks
+    sched = ServingScheduler(engine, case["serving"], start=False)
+    reqs = _submit_all(sched, cfg, case["temperature"], work)
+    ran = []
+    if reason == "control":
+        _run_until(sched, lambda: sched._inflight is not None)
+        box = {"done": threading.Event(), "result": None, "error": None}
+        # as a handler thread queues it (serving/scheduler.py:_call_on_loop)
+        sched._control.append((lambda: ran.append(sched._inflight), box))
+        sched.step()
+        assert box["done"].is_set() and ran == [None]   # it ran beside an idle engine
+        # a manually stepped scheduler runs a control call inline: same rule
+        _run_until(sched, lambda: sched._inflight is not None)
+        assert sched._call_on_loop(lambda: sched._inflight) is None
+    if reason == "stop":
+        _run_until(sched, lambda: sched._inflight is not None
+                   and sched.stats()["counters"]["pipelined_steps"] >= 1)
+        sched.stop(drain=True, timeout=60.0)
+    else:
+        _run_until(sched, lambda: all(r.finished for r in reqs))
+    counters = sched.stats()["counters"]
+    sched.stop(drain=False)
+    assert engine.free_blocks == start
+    assert [list(r.tokens) for r in reqs] == want
+    assert counters["pipelined_steps"] >= 1, counters
+    assert counters[f"drained_steps_{reason}"] >= (2 if reason == "control" else 1), counters
+    assert counters["overrun_rows"] == 0
+    assert _counted(counters) == counters["batches"]
+    if reason == "pressure":
+        assert counters["evictions"] >= 1
+
+
+def test_kill_drops_the_step_in_flight_without_waiting_for_the_device(make_engine, llama_setup):
+    cfg, _, _ = llama_setup
+    engine = make_engine(**CLOSED)
+    start = engine.free_blocks
+    sched = ServingScheduler(engine, ServingConfig(), start=False)
+    reqs = _submit_all(sched, cfg)
+    _run_until(sched, lambda: sched._inflight is not None)
+    sched.kill("test")
+    assert sched._inflight is None and all(r.state is RequestState.FAILED for r in reqs)
+    assert sched.stats()["counters"]["drained_steps_control"] == 1
+    assert engine.free_blocks == start
+
+
+# ----------------------------------------------------- programs and compiles --
+def test_pipelining_builds_no_program_after_the_scheduler_is_constructed(
+        make_engine, llama_setup):
+    """The merge in front of a chained step is compiled when the scheduler is
+    constructed, and the forward a chained step runs is the one ``engine.put``
+    runs, under its cache key: a second pass over warmed buckets compiles
+    nothing, pipelined or not."""
+    cfg, _, _ = llama_setup
+    engine = make_engine(**CLOSED)
+    first = ServingScheduler(engine, ServingConfig(), start=False)
+    reqs = _submit_all(first, cfg, 0.8)
+    _run_until(first, lambda: all(r.finished for r in reqs))
+    first.stop(drain=False)
+    keys = set(engine.model._compiled)
+    assert keys and all(len(k) == 3 and all(isinstance(n, int) for n in k) for k in keys)
+
+    sched = ServingScheduler(engine, ServingConfig(), start=False)
+    compiled = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, *_a, **_k: compiled.append(name) if "backend_compile" in name else None)
+    again = _submit_all(sched, cfg, 0.8)
+    _run_until(sched, lambda: all(r.finished for r in again))
+    counters = sched.stats()["counters"]
+    sched.stop(drain=False)
+    assert counters["pipelined_steps"] >= 5
+    assert [list(r.tokens) for r in again] == [list(r.tokens) for r in reqs]
+    assert set(engine.model._compiled) == keys
+    assert compiled == []
+    # engine.put lands in the same programs: a full 16-token chunk, one sequence
+    engine.put([10_001], [np.zeros(16, np.int32)])
+    engine.flush(10_001)
+    assert set(engine.model._compiled) == keys
+
+
+# ------------------------------------------------------------------- tracing --
+def test_tick_spans_say_whether_their_step_went_behind_the_last_and_why_not(
+        make_engine, llama_setup):
+    cfg, _, _ = llama_setup
+    telemetry.configure(telemetry.TelemetryConfig(enabled=True))
+    sched = ServingScheduler(make_engine(**CLOSED), ServingConfig(), start=False)
+    reqs = _submit_all(sched, cfg)
+    _run_until(sched, lambda: all(r.finished for r in reqs))
+    counters = sched.stats()["counters"]
+    sched.stop(drain=False)
+    spans = telemetry.get_span_recorder().export_since(0)["spans"]
+    ticks = [s for s in spans if s["cat"] == "sched" and s["name"] == "tick"
+             and s["args"]["kind"] == "put"]
+    assert len(ticks) == counters["put_steps"]
+    assert sum(t["args"]["pipelined"] for t in ticks) == counters["pipelined_steps"] >= 5
+    for t in ticks:
+        assert ("drain" in t["args"]) == (t["args"]["pipelined"] == 0)
+        inside = sorted((s for s in spans if s is not t and s["cat"] in ("sched", "inference")
+                         and t["ts_us"] <= s["ts_us"] < t["ts_us"] + t["dur_us"]),
+                        key=lambda s: s["ts_us"])
+        names = [s["name"] for s in inside]
+        if t["args"]["pipelined"]:
+            # admit, build_batch, prepare + put (step i+1), fetch, emit (step i)
+            assert names[:6] == ["admit", "build_batch", "prepare", "put", "fetch", "emit"]
+            put = inside[3]
+            assert put["args"]["chained"] >= 0
+    assert {t["args"]["drain"] for t in ticks if not t["args"]["pipelined"]} <= {"open"}
+    # a step's phase spans do not overlap the step's before it: a pipelined
+    # step's start where the step before it was fetched
+    steps = sorted({(s["ts_us"], s["ts_us"] + s["dur_us"]) for s in spans
+                    if s["cat"] == "serving" and s["name"] in ("prefill", "decode")})
+    assert len(steps) == counters["put_steps"]
+    assert all(b[0] >= a[1] for a, b in zip(steps, steps[1:]))
+    assert sum(b[0] == a[1] for a, b in zip(steps, steps[1:])) == counters["pipelined_steps"]
+
+
+# ------------------------------------------------------------- the merge --
+@pytest.mark.parametrize("placed", ["one_device", "replicated_on_a_mesh", "split_over_a_mesh"])
+def test_chain_feeds_the_named_slots_from_the_ids_wherever_they_lie(placed):
+    """``sampling.chain``: slot t takes ``ids[src[t]]`` where ``src[t] >= 0``
+    and keeps the host's id elsewhere; rows 1..3 of the batch are untouched.
+    Ids replicated over a mesh are read from the default device's replica, ids
+    split over it from the host: the same batch either way."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from deepspeed_tpu.inference.v2 import sampling
+
+    tok_meta = np.arange(64, dtype=np.int32).reshape(4, 16)
+    ids = np.arange(100, 108, dtype=np.int32)
+    src = np.full(16, -1, np.int32)
+    src[[0, 3, 15]] = [7, 0, 2]
+    if placed == "one_device":
+        on_device = jax.device_put(ids, jax.devices()[0])
+    else:
+        mesh = Mesh(np.array(jax.devices()[:2]), ("x", ))
+        spec = PartitionSpec() if placed == "replicated_on_a_mesh" else PartitionSpec("x")
+        on_device = jax.device_put(ids, NamedSharding(mesh, spec))
+    out = np.asarray(sampling.chain(tok_meta, on_device, src))
+    want = tok_meta.copy()
+    want[0, [0, 3, 15]] = [107, 100, 102]
+    assert out.tolist() == want.tolist()

@@ -264,6 +264,9 @@ def test_tick_spans_hold_their_phases_in_order(make_engine):
     assert [t["args"]["tick"] for t in ticks] == sorted(t["args"]["tick"] for t in ticks)
     for tick in ticks:
         assert tick["args"]["kind"] == "put" and tick["args"]["seqs"] == 1
+        # an open plan (one sequence, a few tokens): fetched in its own tick,
+        # as was the step before it, and for that reason
+        assert tick["args"]["pipelined"] == 0 and tick["args"]["drain"] == "open"
         children = sorted((s for s in spans if s is not tick and _inside(s, tick)
                            and s["cat"] in ("sched", "inference")), key=lambda s: s["ts_us"])
         assert [(c["cat"], c["name"]) for c in children] == [
@@ -328,6 +331,9 @@ def test_decode_loop_tick_is_named_for_its_dispatch(make_engine):
                           lambda s: [s.submit([1, 2, 3], max_new_tokens=9)], decode_chunk=4)
     kinds = [s["args"]["kind"] for s in spans if s["cat"] == "sched" and s["name"] == "tick"]
     assert kinds[0] == "put" and "decode_loop" in kinds
+    # a chunk is fetched inside its own engine call: nothing goes behind it
+    assert all(s["args"]["pipelined"] == 0 and s["args"]["drain"] == "decode_loop"
+               for s in spans if s["name"] == "tick" and s["args"]["kind"] == "decode_loop")
     loop = next(s for s in spans if s["cat"] == "inference" and s["name"] == "decode_loop")
     assert loop["args"]["steps"] == 4
     prepare = max((s for s in spans if s["name"] == "prepare" and s["ts_us"] <= loop["ts_us"]),
