@@ -49,10 +49,11 @@ class InferenceEngineV2:
         self._initialize_comm_groups()
         self._place_params()
 
+        kv_config = model.kv_cache_config()
         self._batch = RaggedBatchWrapper(engine_config.state_manager,
-                                         block_size=engine_config.kv_block_size)
-        self._state_manager = DSStateManager(engine_config.state_manager,
-                                             model.kv_cache_config())
+                                         block_size=engine_config.kv_block_size,
+                                         num_groups=kv_config.num_allocation_groups)
+        self._state_manager = DSStateManager(engine_config.state_manager, kv_config)
         self._model.set_state_manager(self._state_manager)
 
         # unified telemetry (telemetry/): batch/token/KV gauges + spans +
@@ -137,7 +138,9 @@ class InferenceEngineV2:
 
     @property
     def n_kv_cache_groups(self) -> int:
-        return 1
+        """KV layer groups: block tables a sequence keeps (window and full
+        layers side by side take one each; ``ragged/kv_cache.py``)."""
+        return self._state_manager.num_groups
 
     @property
     def model(self):
@@ -182,8 +185,7 @@ class InferenceEngineV2:
         args = None
         if spans is not None:
             free_before = self._state_manager.free_blocks
-            args = {"sequences": len(batch_uids), "tokens": n_tokens,
-                    "released_blocks": self._released_since_prepare()}
+            args = self._prepare_args(len(batch_uids), n_tokens)
         with _tel_live_span(spans, "prepare", "inference", args):
             if do_checks:
                 # BEFORE restoring: can_schedule counts offloaded sequences'
@@ -209,6 +211,29 @@ class InferenceEngineV2:
             self._model.prepare_batch(self._batch)
             if args is not None:
                 args["allocated_blocks"] = free_before - self._state_manager.free_blocks
+
+    def _prepare_args(self, n_sequences: int, n_tokens: int) -> dict:
+        """A ``prepare`` span's args: the batch, what the rolling release gave
+        back since the last one, and the blocks every tracked sequence holds
+        as this step begins, in full-causal and in sliding-window layer
+        groups."""
+        held = self._live_blocks_by_kind()
+        return {"sequences": n_sequences, "tokens": n_tokens,
+                "released_blocks": self._released_since_prepare(),
+                "live_blocks_full": held["full"], "live_blocks_window": held["window"]}
+
+    def _live_blocks_by_kind(self) -> dict:
+        """Pool blocks held by tracked, resident sequences: ``full`` in layer
+        groups that keep every key, ``window`` in groups under a sliding
+        window."""
+        held = {"full": 0, "window": 0}
+        windows = self._model.group_windows
+        for uid, seq in self._state_manager.tracked_sequences.items():
+            if self._state_manager.is_offloaded(uid):
+                continue
+            for group, window in enumerate(windows):
+                held["window" if window > 0 else "full"] += seq.live_blocks_in(group)
+        return held
 
     def _released_since_prepare(self) -> int:
         """Blocks the rolling release gave back since the last ``prepare``
@@ -296,6 +321,7 @@ class InferenceEngineV2:
             # the arm the bucket's program takes (modules/heuristics.py):
             # paged_tiled / paged_token / xla_gather
             args["attention"] = self._model.attention_arm(n_padded)
+            args.update(self._model.dispatch_counts(n_padded, n_tokens))
         if prev is not None:
             # per sequence -> per token slot: a sequence's first token
             ids, index = prev
@@ -334,6 +360,10 @@ class InferenceEngineV2:
             "released": reg.gauge("inference_kv_released_blocks",
                                   "KV blocks a sliding window's rolling release has "
                                   "given back to the pool"),
+            **{f"live_{kind}": reg.gauge("inference_kv_group_live_blocks",
+                                         "KV blocks tracked sequences hold, by the kind of "
+                                         "layer group holding them", labels={"kind": kind})
+               for kind in ("full", "window")},
         }
 
     def _resolve_tel_metrics(self) -> Optional[dict]:
@@ -366,6 +396,8 @@ class InferenceEngineV2:
         metrics["in_flight"].set(batch_tokens)
         metrics["free_blocks"].set(self._state_manager.free_blocks)
         metrics["released"].set(self._released_blocks)
+        for kind, n in self._live_blocks_by_kind().items():
+            metrics[f"live_{kind}"].set(n)
         metrics["tracked"].set(self._state_manager.n_tracked_sequences)
 
     # ------------------------------------------------------------ decode_loop --
@@ -399,8 +431,7 @@ class InferenceEngineV2:
         prep = None
         if spans is not None:
             free_before = self._state_manager.free_blocks
-            prep = {"sequences": len(batch_uids), "tokens": len(batch_uids) * n_steps,
-                    "released_blocks": self._released_since_prepare()}
+            prep = self._prepare_args(len(batch_uids), len(batch_uids) * n_steps)
         with _tel_live_span(spans, "prepare", "inference", prep):
             if do_checks:
                 # each SCAN STEP's ragged batch holds one token per sequence, so
@@ -551,10 +582,12 @@ class InferenceEngineV2:
         seq_desc = self._state_manager.get_sequence(uid)
         if seq_desc is None:
             raise ValueError(f"rollback: unknown uid {uid}")
-        window = self._model.attention_window
-        if window > 0 and seq_desc.released_blocks:
+        for group, window in enumerate(self._model.group_windows):
+            released = seq_desc.released_in(group)
+            if window <= 0 or not released:
+                continue
             first_seen = max(seq_desc.seen_tokens - n_tokens - window + 1, 0)
-            if first_seen // self._state_manager.kv_block_size < seq_desc.released_blocks:
+            if first_seen // self._state_manager.kv_block_size < released:
                 raise ValueError(
                     f"rollback({n_tokens}): uid {uid} would need keys from position "
                     f"{first_seen}, in a block its attention window already released")

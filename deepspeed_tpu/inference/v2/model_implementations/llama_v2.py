@@ -36,21 +36,31 @@ def _root(params):
     return params["model"] if "model" in params else params
 
 
-def _rotary_at(x, pos, cos_tab, sin_tab):
-    """x: [T, H, D] with per-token absolute positions [T]."""
-    cos = cos_tab[pos][:, None, :]  # [T, 1, D/2]
-    sin = sin_tab[pos][:, None, :]
+def _rotate_half(x, cos, sin):
+    """x: [T, H, D]; cos, sin: [T, 1, D/2]; rotates the pairs (x[i], x[i + D/2])."""
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+def _rotary_at(x, pos, cos_tab, sin_tab):
+    """x: [T, H, D] with per-token absolute positions [T]."""
+    return _rotate_half(x, cos_tab[pos][:, None, :], sin_tab[pos][:, None, :])
 
 
 class LlamaV2Model(DSTransformerModelBase):
 
     def __init__(self, params, config: LlamaConfig, engine_config, state_manager=None):
         super().__init__(params, config, engine_config, state_manager)
-        D = config.hidden_size // config.num_attention_heads
-        self._cos, self._sin = rotary_embedding(engine_config.state_manager.max_context, D,
-                                                config.rope_theta, jnp.float32)
+        self._rope = self._build_rope(engine_config.state_manager.max_context)
+
+    def _build_rope(self, max_context):
+        """The rotary tables, built once to ``max_context``: here one ``(cos,
+        sin)`` pair for every layer."""
+        return rotary_embedding(max_context, self.head_dim, self._config.rope_theta, jnp.float32)
+
+    def _rotate(self, li, x, pos):
+        """Layer ``li``'s rotary embedding of x ``[T, H, D]`` at positions ``pos``."""
+        return _rotary_at(x, pos, *self._rope)
 
     @property
     def num_layers(self):
@@ -66,7 +76,10 @@ class LlamaV2Model(DSTransformerModelBase):
 
     @property
     def head_dim(self):
-        return self._config.hidden_size // self._config.num_attention_heads
+        """The config's own ``head_dim`` where it has one (heads x head_dim
+        need not be ``hidden_size``), else ``hidden_size / heads``."""
+        return (getattr(self._config, "head_dim", None)
+                or self._config.hidden_size // self._config.num_attention_heads)
 
     @property
     def vocab_size(self):
@@ -102,8 +115,8 @@ class LlamaV2Model(DSTransformerModelBase):
         k = lin(ap["k_proj"], KVH)
         v = lin(ap["v_proj"], KVH)
         pos = batch["token_pos"]
-        q = _rotary_at(q, pos, self._cos, self._sin)
-        k = _rotary_at(k, pos, self._cos, self._sin)
+        q = self._rotate(li, q, pos)
+        k = self._rotate(li, k, pos)
         out, cache = attn_fn(q, k, v, cache, li)
         out = out.reshape(x.shape[0], H * D)
         return x + out @ ap["o_proj"]["kernel"].astype(h.dtype), cache

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from deepspeed_tpu.inference.v2.model_implementations.llama_v2 import LlamaV2Model, _rms, _root
 from deepspeed_tpu.inference.v2.modules.moe import RaggedMoE
 from deepspeed_tpu.models.mixtral import MixtralConfig
+from deepspeed_tpu.utils import groups
 
 
 class MixtralV2Model(LlamaV2Model):
@@ -21,13 +22,29 @@ class MixtralV2Model(LlamaV2Model):
     def __init__(self, params, config: MixtralConfig, engine_config, state_manager=None):
         super().__init__(params, config.as_llama(), engine_config, state_manager)
         self._moe_config = config
+        self._moes = self._build_moes(engine_config, config.num_hidden_layers,
+                                      config.num_local_experts, config.num_experts_per_tok)
+
+    @staticmethod
+    def _build_moes(engine_config, num_layers, num_experts, top_k, norm_topk_prob=True):
+        """One ``RaggedMoE`` a layer (Mixtral renormalises over its chosen
+        two); the capacity factor is the engine's ``expert_parallel`` one."""
         ep_cfg = getattr(engine_config, "expert_parallel", None)
-        self._moes = [
-            RaggedMoE(num_experts=config.num_local_experts,
-                      top_k=config.num_experts_per_tok,
+        return [
+            RaggedMoE(num_experts=num_experts, top_k=top_k,
                       capacity_factor=(ep_cfg.capacity_factor if ep_cfg is not None else 2.0),
-                      layer_id=li) for li in range(config.num_hidden_layers)
+                      layer_id=li, norm_topk_prob=norm_topk_prob) for li in range(num_layers)
         ]
+
+    def dispatch_counts(self, n_padded, n_tokens):
+        """``moe_rows``: rows the expert GEMMs compute this step, summed over
+        the layers (the capacity path computes every expert's every slot);
+        ``moe_assignments``: live tokens x top-k x layers, what had to be."""
+        ep = 1
+        if groups.mesh_is_initialized():
+            ep = int(groups.get_mesh().shape.get(self._moes[0].expert_axis, 1))
+        return {"moe_rows": sum(m.expert_rows(n_padded, ep) for m in self._moes),
+                "moe_assignments": n_tokens * sum(m.top_k for m in self._moes)}
 
     @property
     def num_layers(self):

@@ -38,17 +38,23 @@ def supported_model_types():
 def _register_builtin():
     from deepspeed_tpu.models.decoder import DecoderConfig
     from deepspeed_tpu.models.llama import LlamaConfig
+    from deepspeed_tpu.models.mellum import MellumConfig
     from deepspeed_tpu.models.mixtral import MixtralConfig
     from deepspeed_tpu.inference.v2.model_implementations.decoder_v2 import DecoderV2Model
     from deepspeed_tpu.inference.v2.model_implementations.llama_v2 import (LlamaV2Model,
                                                                            MistralV2Model,
                                                                            Qwen2V2Model)
+    from deepspeed_tpu.inference.v2.model_implementations.mellum_v2 import MellumV2Model
     from deepspeed_tpu.inference.v2.model_implementations.mixtral_v2 import MixtralV2Model
 
     register_policy("llama", LlamaConfig, LlamaV2Model)
     register_policy("mistral", LlamaConfig, MistralV2Model)
     register_policy("qwen2", LlamaConfig, Qwen2V2Model)
     register_policy("mixtral", MixtralConfig, MixtralV2Model)
+    # serving only: window and full layers side by side (KV layer groups), top-k
+    # of many experts; dense MLP layers and other RoPE types are refused by the
+    # config's constructor
+    register_policy("mellum", MellumConfig, MellumV2Model)
     register_policy("opt", DecoderConfig, DecoderV2Model)
     register_policy("falcon", DecoderConfig, DecoderV2Model)
     register_policy("phi", DecoderConfig, DecoderV2Model)

@@ -49,6 +49,7 @@ class DSTransformerModelBase:
         self._state_manager = None
         self._compiled = {}
         self._lowerable = {}  # same keys, UNwrapped jit fns (perf-gate hook)
+        self._group_windows = None
         if state_manager is not None:
             self.set_state_manager(state_manager)
 
@@ -93,6 +94,7 @@ class DSTransformerModelBase:
         if cache_dtype not in ("bfloat16", "float16", "float32"):
             cache_dtype = "bfloat16"
         return KVCacheConfig(block_size=self._engine_config.kv_block_size,
+                             num_allocation_groups=self.kv_groups,
                              cache_shape=(self.num_layers, self.num_kv_heads, self.head_dim),
                              cache_dtype=cache_dtype,
                              max_blocks_per_allocation_group=(sm.max_context + self._engine_config.kv_block_size - 1)
@@ -115,18 +117,20 @@ class DSTransformerModelBase:
         caller counts, so a long context is admitted by what it holds, not by
         its length; ``max_context`` still bounds its positions."""
         bs = self._state_manager.kv_block_size
+        # a block of positions takes one block id in every layer group
+        groups = self.kv_groups
         # the per-sequence table cap (max_context) bounds schedulable tokens
         # too: admission must reject here, not crash in extend_kv_cache after
         # blocks were already pulled from the pool
         seq_cap = seq_desc.max_blocks - seq_desc.cur_allocated_blocks
-        max_new_blocks = min(max_new_blocks, seq_cap)
+        max_new_entries = min(max_new_blocks // groups, seq_cap)
         total = seq_desc.seen_tokens + max_new_tokens
-        blocks_needed = (total + bs - 1) // bs - seq_desc.cur_allocated_blocks
-        if blocks_needed <= max_new_blocks:
-            return max_new_tokens, max(0, blocks_needed)
+        entries_needed = (total + bs - 1) // bs - seq_desc.cur_allocated_blocks
+        if entries_needed <= max_new_entries:
+            return max_new_tokens, max(0, entries_needed) * groups
         # clip tokens to what the block budget allows
-        capacity = (seq_desc.cur_allocated_blocks + max_new_blocks) * bs - seq_desc.seen_tokens
-        return max(0, capacity), max_new_blocks
+        capacity = (seq_desc.cur_allocated_blocks + max_new_entries) * bs - seq_desc.seen_tokens
+        return max(0, capacity), max_new_entries * groups
 
     def get_remaining_block_capacity(self, seq_desc: DSSequenceDescriptor) -> int:
         bs = self._state_manager.kv_block_size
@@ -148,22 +152,28 @@ class DSTransformerModelBase:
             seq_desc.extend_kv_cache(self._state_manager.allocate_blocks(n_blocks))
 
     def maybe_free_kv(self, seq_desc: DSSequenceDescriptor) -> int:
-        """After a step: a sliding-window model gives back every block ALL of
-        whose positions are more than ``attention_window`` behind the
-        sequence's next query (rolling release); returns how many. A
-        full-causal model keeps its blocks until flush. The step just
+        """After a step: each layer group with a sliding window gives back
+        every block ALL of whose positions are more than that window behind
+        the sequence's next query (rolling release); returns how many. A
+        full-causal group keeps its blocks until flush. The step just
         dispatched may still be reading them: whoever is handed them next
         writes in a LATER program, and the pool is threaded through the
         programs in order."""
-        return self._state_manager.release_passed_blocks(seq_desc, self.attention_window)
+        return sum(self._state_manager.release_passed_blocks(seq_desc, window, group)
+                   for group, window in enumerate(self.group_windows) if window > 0)
 
     def max_live_blocks(self, n_tokens: int) -> int:
-        """The most KV blocks a sequence of ``n_tokens`` holds at once: all of
-        them, or under a sliding window the window's, one step's feed and the
-        two blocks the window's ends straddle."""
+        """The most KV blocks a sequence of ``n_tokens`` holds at once, over
+        its layer groups: in a full-causal group all of them, in a group under
+        a sliding window the window's, one step's feed and the two blocks the
+        window's ends straddle."""
+        return sum(self.max_live_blocks_in(group, n_tokens)
+                   for group in range(self.kv_groups))
+
+    def max_live_blocks_in(self, group: int, n_tokens: int) -> int:
         bs = self._engine_config.kv_block_size
         whole = -(-int(n_tokens) // bs)
-        window = self.attention_window
+        window = self.group_windows[group]
         if window <= 0:
             return whole
         feed = self._engine_config.state_manager.max_ragged_batch_size
@@ -197,8 +207,7 @@ class DSTransformerModelBase:
         """The bucket's program over ``ragged_batch``: its padded
         ``[S_bucket, vocab]`` logits and the live sequence count."""
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
-        bucket = (batch["tok_meta"].shape[1], batch["seq_meta"].shape[0],
-                  batch["seq_meta"].shape[1] - 4)
+        bucket = self._bucket_of(batch)
         fn = self._get_compiled(bucket)
         cache = self._state_manager.kv_cache.cache
         tok_meta = batch["tok_meta"] if prev is None else sampling.chain(batch["tok_meta"], *prev)
@@ -235,11 +244,11 @@ class DSTransformerModelBase:
         Uses the smallest bucket with every validity mask false."""
         from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import RaggedBatchWrapper
         wrapper = RaggedBatchWrapper(self._engine_config.state_manager,
-                                     block_size=self._engine_config.kv_block_size)
+                                     block_size=self._engine_config.kv_block_size,
+                                     num_groups=self.kv_groups)
         batch = wrapper.finalize()  # zero live sequences/tokens
         dev = {"tok_meta": batch["tok_meta"], "seq_meta": batch["seq_meta"]}
-        fn = self._get_compiled((batch["tok_meta"].shape[1], batch["seq_meta"].shape[0],
-                                 batch["seq_meta"].shape[1] - 4))
+        fn = self._get_compiled(self._bucket_of(batch))
         _, new_cache = fn(self._params, self._state_manager.kv_cache.cache, dev)
         self._state_manager.kv_cache.set_cache(new_cache)
 
@@ -295,7 +304,7 @@ class DSTransformerModelBase:
             bucket = (to_padded(1), _pad_to(1, 8), _pow2_pad(1, 4))
         T, S, MB = bucket
         return {"tok_meta": np.zeros((4, T), np.int32),
-                "seq_meta": np.full((S, 4 + MB), -1, np.int32)}
+                "seq_meta": np.full((S, 4 + self.kv_groups * MB), -1, np.int32)}
 
     def lower_forward(self, bucket=None):
         """Lower the ragged forward at ``bucket`` (``(T, S, MB)``; default
@@ -304,8 +313,7 @@ class DSTransformerModelBase:
         ``_forward_impl`` jit :meth:`forward` runs for that bucket."""
         import jax
         dev = self._synthetic_batch(bucket)
-        key = (dev["tok_meta"].shape[1], dev["seq_meta"].shape[0],
-               dev["seq_meta"].shape[1] - 4)
+        key = self._bucket_of(dev)
         # reuse the engine's own jit entry when the bucket has run already
         fn = self._lowerable.get(key) or jax.jit(self._forward_impl, donate_argnums=(1, ))
         return fn.lower(self._params, self._state_manager.kv_cache.cache, dev)
@@ -316,8 +324,7 @@ class DSTransformerModelBase:
         import jax
         import jax.numpy as jnp
         dev = self._synthetic_batch(bucket)
-        key = ((dev["tok_meta"].shape[1], dev["seq_meta"].shape[0],
-                dev["seq_meta"].shape[1] - 4), int(n_steps), temperature > 0)
+        key = (self._bucket_of(dev), int(n_steps), temperature > 0)
         fn = self._lowerable.get(key) or jax.jit(
             partial(self._decode_loop_impl, n_steps=int(n_steps),
                     sampled=temperature > 0),
@@ -363,8 +370,7 @@ class DSTransformerModelBase:
         """
         import jax
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
-        bucket = (batch["tok_meta"].shape[1], batch["seq_meta"].shape[0],
-                  batch["seq_meta"].shape[1] - 4)
+        bucket = self._bucket_of(batch)
         temperature = float(temperature)
         key = (bucket, int(n_steps), temperature > 0)
         if key not in self._compiled:
@@ -419,15 +425,25 @@ class DSTransformerModelBase:
             step, (cache, tok_meta, seq_meta, rng), None, length=n_steps)
         return tokens, cache
 
-    @staticmethod
-    def _unpack_batch(batch):
-        """Packed [4,T]/[S,4+MB] metadata → the named per-field views (built
-        inside jit: free slices, no extra transfers)."""
+    def _bucket_of(self, batch):
+        """``(T, S, MB)`` of a packed batch: the jit cache key."""
+        seq_meta = batch["seq_meta"]
+        return (batch["tok_meta"].shape[1], seq_meta.shape[0],
+                (seq_meta.shape[1] - 4) // self.kv_groups)
+
+    def _unpack_batch(self, batch):
+        """Packed [4,T]/[S,4+G*MB] metadata → the named per-field views (built
+        inside jit: free slices, no extra transfers). ``block_table`` is
+        ``[S, MB]``, or ``[S, G, MB]`` for a model with G > 1 KV layer groups
+        (:meth:`_kv_view` hands a layer its own)."""
         tok, seq = batch["tok_meta"], batch["seq_meta"]
-        return dict(input_ids=tok[0], token_seq=tok[1], token_pos=tok[2],
-                    token_valid=tok[3].astype(bool), seq_seen=seq[:, 0],
-                    seq_ntok=seq[:, 1], last_tok=seq[:, 2],
-                    seq_valid=seq[:, 3].astype(bool), block_table=seq[:, 4:])
+        out = dict(input_ids=tok[0], token_seq=tok[1], token_pos=tok[2],
+                   token_valid=tok[3].astype(bool), seq_seen=seq[:, 0],
+                   seq_ntok=seq[:, 1], last_tok=seq[:, 2],
+                   seq_valid=seq[:, 3].astype(bool), block_table=seq[:, 4:])
+        if self.kv_groups > 1:
+            out["block_table"] = out["block_table"].reshape(seq.shape[0], self.kv_groups, -1)
+        return out
 
     def _forward_impl(self, params, cache, batch):
         import jax.numpy as jnp
@@ -445,13 +461,10 @@ class DSTransformerModelBase:
         return logits.astype(jnp.float32), cache
 
     # ----------------------------------------------------- speculative verify --
-    @staticmethod
-    def _verify_key(dev, greedy):
+    def _verify_key(self, dev, greedy):
         """``("verify", bucket, tree, greedy)``: the two facts that pick the
         verify program at a bucket are named in its key."""
-        bucket = (dev["tok_meta"].shape[1], dev["seq_meta"].shape[0],
-                  dev["seq_meta"].shape[1] - 4)
-        return ("verify", bucket, "tree_meta" in dev, bool(greedy))
+        return ("verify", self._bucket_of(dev), "tree_meta" in dev, bool(greedy))
 
     def _verify_jit(self, key):
         """The raw jit of the verify program ``key`` names: the engine's own
@@ -527,9 +540,46 @@ class DSTransformerModelBase:
     # -------------------------------------------------------- paged attention --
     @property
     def attention_window(self) -> int:
-        """Sliding attention window in tokens; 0 = full causal (mistral sets
-        it via its model config)."""
+        """The one sliding attention window of a model whose layers all see
+        alike, in tokens; 0 = full causal (mistral sets it via its model
+        config). What a LAYER sees is :meth:`attention_window_of`."""
         return 0
+
+    def attention_window_of(self, li: int) -> int:
+        """Layer ``li``'s sliding window in tokens; 0 = every earlier key."""
+        return self.attention_window
+
+    @property
+    def group_windows(self) -> Tuple[int, ...]:
+        """The window of each KV layer group. Layers are grouped by position
+        in the shortest period of the per-layer windows: layer ``li`` is in
+        group ``li % len(group_windows)`` (``ragged/kv_cache.py``). One window
+        for every layer — none included — is one group."""
+        if self._group_windows is None:
+            windows = [int(self.attention_window_of(li)) for li in range(self.num_layers)]
+            period = next(p for p in range(1, len(windows) + 1)
+                          if len(windows) % p == 0
+                          and all(w == windows[i % p] for i, w in enumerate(windows)))
+            self._group_windows = tuple(windows[:period])
+        return self._group_windows
+
+    @property
+    def kv_groups(self) -> int:
+        return len(self.group_windows)
+
+    def _kv_view(self, batch, li):
+        """Layer ``li``'s block table ``[S, MB]`` and its layer index in the
+        cache array."""
+        groups = self.kv_groups
+        if groups == 1:
+            return batch["block_table"], li
+        return batch["block_table"][:, li % groups], li // groups
+
+    def dispatch_counts(self, n_padded: int, n_tokens: int) -> dict:
+        """Work counters of one ``put`` step over an ``n_padded``-token bucket
+        holding ``n_tokens`` live tokens, for the step's span (sparse models:
+        expert rows computed and assignments routed)."""
+        return {}
 
     def attention_arm(self, T: int) -> str:
         """The attention arm a ``T``-token bucket takes (``paged_token`` /
@@ -548,10 +598,13 @@ class DSTransformerModelBase:
         kernel never walks them, the XLA arm masks what it gathers for them.
 
         q: [T, H, D]; k_new/v_new: [T, KVH, D];
-        cache: [L, 2, num_blocks, KVH, bs, D]."""
+        cache: [L / groups, 2, num_blocks, KVH, bs, D]. Window, block table
+        and cache layer are layer ``li``'s own (:meth:`_kv_view`)."""
         import jax
 
         token_pos = batch["token_pos"]
+        window = self.attention_window_of(li)
+        table, li = self._kv_view(batch, li)
 
         # scopes (under the caller's ``attn``): ``paged_kernel`` / ``kv_write``
         # + ``gather`` name the arm a device operation belongs to in the trace
@@ -565,14 +618,10 @@ class DSTransformerModelBase:
 
             if arm == "paged_tiled":
                 update = paged_attention.paged_attention_prefill
-                meta = (batch["block_table"], batch["seq_seen"], batch["seq_ntok"],
-                        batch["last_tok"])
+                meta = (table, batch["seq_seen"], batch["seq_ntok"], batch["last_tok"])
             else:
                 update = paged_attention.paged_attention_update
-                meta = (batch["block_table"], batch["token_seq"], token_pos,
-                        batch["token_valid"])
-
-            window = self.attention_window
+                meta = (table, batch["token_seq"], token_pos, batch["token_valid"])
 
             def kernel(q, k_new, v_new, cache, *meta):
                 return update(q, k_new, v_new, cache, li, *meta, window=window)
@@ -592,21 +641,22 @@ class DSTransformerModelBase:
                                                P(), P(), P(), P()),
                                      out_specs=(heads, placed.spec), check_vma=False)(*args)
 
-        cache = self._kv_write(cache, li, k_new, v_new, token_pos, batch)
+        cache = self._kv_write(cache, li, k_new, v_new, token_pos, batch, table)
         with jax.named_scope("gather"):
-            return self._gather_attention(q, cache, li, batch), cache
+            return self._gather_attention(q, cache, li, batch, table, window), cache
 
     @staticmethod
-    def _kv_write(cache, li, k_new, v_new, slot_pos, batch):
-        """Scatter the new K/V into layer ``li``'s blocks at ``slot_pos``."""
+    def _kv_write(cache, li, k_new, v_new, slot_pos, batch, table):
+        """Scatter the new K/V into cache layer ``li``'s blocks at ``slot_pos``
+        (``table``: that layer's block table)."""
         import jax
         import jax.numpy as jnp
 
         with jax.named_scope("kv_write"):
-            MB = batch["block_table"].shape[1]
+            MB = table.shape[1]
             NB, bs = cache.shape[2], cache.shape[4]
             blk_idx = slot_pos // bs
-            blk_ids = batch["block_table"][batch["token_seq"], jnp.minimum(blk_idx, MB - 1)]
+            blk_ids = table[batch["token_seq"], jnp.minimum(blk_idx, MB - 1)]
             # padding tokens and unallocated (-1) table slots route to NB — a
             # POSITIVE out-of-bounds index: scatter mode="drop" discards those
             # writes, whereas -1 would WRAP to block NB-1 and corrupt it
@@ -615,13 +665,14 @@ class DSTransformerModelBase:
             cache = cache.at[li, 0, blk_ids, :, offs].set(k_new.astype(cache.dtype), mode="drop")
             return cache.at[li, 1, blk_ids, :, offs].set(v_new.astype(cache.dtype), mode="drop")
 
-    def _gather_attention(self, q, cache, li, batch):
-        """The XLA arm: gather each sequence's history from the block table and
-        attend densely. q: [T, H, D]; returns [T, H, D]."""
+    def _gather_attention(self, q, cache, li, batch, table, window):
+        """The XLA arm: gather each sequence's history from the layer's block
+        ``table`` and attend densely under its ``window``. q: [T, H, D];
+        returns [T, H, D]."""
         import jax
         import jax.numpy as jnp
 
-        S, MB = batch["block_table"].shape
+        S, MB = table.shape
         bs = cache.shape[4]
         H, D = self.num_heads, self.head_dim
         KVH = self.num_kv_heads
@@ -630,7 +681,7 @@ class DSTransformerModelBase:
         token_valid = batch["token_valid"]
 
         # --- gather per-sequence history (XLA fallback) ----------------------
-        table = jnp.maximum(batch["block_table"], 0)  # [S, MB]
+        table = jnp.maximum(table, 0)  # [S, MB]
         k_hist = cache[li, 0][table]  # [S, MB, KVH, bs, D]
         v_hist = cache[li, 1][table]
         KV = MB * bs
@@ -657,8 +708,8 @@ class DSTransformerModelBase:
         valid_kv = kv_pos <= q_pos                                # causal incl. self
         seq_len = (batch["seq_seen"] + batch["seq_ntok"])[:, None, None, None]
         valid_kv &= kv_pos < seq_len
-        if self.attention_window > 0:  # mistral sliding window
-            valid_kv &= kv_pos > q_pos - self.attention_window
+        if window > 0:  # the layer's sliding window
+            valid_kv &= kv_pos > q_pos - window
         logits = jnp.where(valid_kv, logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
         out_dense = jnp.einsum("shqk,skhd->sqhd", probs, v_hist)
@@ -691,7 +742,9 @@ class DSTransformerModelBase:
         import jax.numpy as jnp
 
         T = q.shape[0]
-        S, MB = batch["block_table"].shape
+        window = self.attention_window_of(li)
+        table, li = self._kv_view(batch, li)
+        S, MB = table.shape
         bs = cache.shape[4]
         H, D = self.num_heads, self.head_dim
         KVH = self.num_kv_heads
@@ -700,10 +753,10 @@ class DSTransformerModelBase:
         token_valid = batch["token_valid"]
 
         # --- scatter new kv at slot positions --------------------------------
-        cache = self._kv_write(cache, li, k_new, v_new, slot_pos, batch)
+        cache = self._kv_write(cache, li, k_new, v_new, slot_pos, batch, table)
 
         # --- gather per-sequence history -------------------------------------
-        table = jnp.maximum(batch["block_table"], 0)  # [S, MB]
+        table = jnp.maximum(table, 0)  # [S, MB]
         k_hist = cache[li, 0][table]
         v_hist = cache[li, 1][table]
         KV = MB * bs
@@ -769,9 +822,9 @@ class DSTransformerModelBase:
         # so the sliding window applies to the raw kv index either way
         valid_kv = (kvr[None, None, :] < seen_v[:, None, None]) | \
             (in_feed[:, None, :] & (node >= 0))                      # [S, Qm, KV]
-        if self.attention_window > 0:
+        if window > 0:
             q_log = seen_v[:, None] + depth_dense                    # [S, Qm]
-            valid_kv &= kvr[None, None, :] > q_log[:, :, None] - self.attention_window
+            valid_kv &= kvr[None, None, :] > q_log[:, :, None] - window
         logits = jnp.where(valid_kv[:, None, :, :], logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
         out_dense = jnp.einsum("shik,sikhd->sihd", probs, v_q)
@@ -801,17 +854,21 @@ class DSTransformerModelBase:
         if src.size == 0:
             return
         bs = self._state_manager.kv_block_size
-        blocks = seq_desc.kv_blocks  # the slots are the feed's own: never released
+        # the slots are the feed's own: never released. A block id holds one
+        # layer group's layers, so each pair is copied once a group, through
+        # that group's table
+        tables = seq_desc.block_tables
+        n = src.size * tables.shape[0]
         NB = self._state_manager.kv_cache.cache.shape[2]
-        P = _pow2_pad(src.size, 2)
+        P = _pow2_pad(n, 2)
         src_blk = np.zeros(P, np.int32)
         src_off = np.zeros(P, np.int32)
         dst_blk = np.full(P, NB, np.int32)  # pad -> positive OOB -> drop
         dst_off = np.zeros(P, np.int32)
-        src_blk[:src.size] = blocks[src // bs]
-        src_off[:src.size] = src % bs
-        dst_blk[:dst.size] = blocks[dst // bs]
-        dst_off[:dst.size] = dst % bs
+        src_blk[:n] = tables[:, src // bs].reshape(-1)
+        src_off[:n] = np.tile(src % bs, tables.shape[0])
+        dst_blk[:n] = tables[:, dst // bs].reshape(-1)
+        dst_off[:n] = np.tile(dst % bs, tables.shape[0])
 
         key = ("compact", P)
         if key not in self._compiled:

@@ -74,13 +74,32 @@ class RaggedMoE:
     """Functional top-k MoE over flat tokens [T, M] with disaggregated EP."""
 
     def __init__(self, num_experts: int, top_k: int = 2, capacity_factor: float = 2.0,
-                 expert_axis: str = groups.EXPERT_AXIS, layer_id: int = 0):
-        assert top_k in (1, 2), "ragged MoE supports top-1/top-2"
+                 expert_axis: str = groups.EXPERT_AXIS, layer_id: int = 0,
+                 norm_topk_prob: bool = True):
+        """``norm_topk_prob``: renormalise the ``top_k`` chosen probabilities to
+        sum to 1, as the model states it (Mixtral does; a top-1 router that
+        weights by the raw probability passes False)."""
+        if not 1 <= top_k <= num_experts:
+            raise ValueError(f"ragged MoE needs 1 <= top_k <= num_experts, got top_k={top_k} "
+                             f"of {num_experts}")
         self.num_experts = num_experts
         self.top_k = top_k
+        self.norm_topk_prob = bool(norm_topk_prob)
         self.capacity_factor = capacity_factor
         self.expert_axis = expert_axis
         self.layer_id = layer_id
+
+    def capacity(self, tokens: int) -> int:
+        """Slots an expert has for a batch of ``tokens``. With ``capacity_factor
+        = num_experts / top_k`` it is ``tokens``: no assignment can be dropped
+        (a token picks an expert at most once)."""
+        return max(4, int(np.ceil(tokens * self.top_k / self.num_experts * self.capacity_factor)))
+
+    def expert_rows(self, tokens: int, ep: int = 1) -> int:
+        """Rows the expert GEMMs compute for a ``tokens``-token bucket, live or
+        padding, over all experts (and all ``ep`` replicas): what the capacity
+        path pays, whatever was routed."""
+        return self.num_experts * ep * self.capacity(-(-tokens // ep))
 
     # ------------------------------------------------------------------ gating --
     def _router_probs(self, h, gate_w, gate_seed=None, replica=None):
@@ -119,11 +138,21 @@ class RaggedMoE:
         combine = jnp.zeros((T, E, C), jnp.float32)
         dispatch = jnp.zeros((T, E, C), dtype)
         topk_p, topk_e = jax.lax.top_k(probs, self.top_k)  # [T, k]
-        if self.top_k == 2:
+        if self.norm_topk_prob:
             denom = jnp.maximum(topk_p.sum(-1, keepdims=True), 1e-9)
-            topk_p = topk_p / denom  # Mixtral renormalizes over the chosen 2
+            topk_p = topk_p / denom  # renormalized over the chosen k (Mixtral's 2)
+        fill = self._fill_level_by_level if self.top_k <= 2 else self._fill_in_one_pass
+        return fill(topk_p, topk_e, token_valid, C, combine, dispatch)
+
+    def _fill_level_by_level(self, topk_p, topk_e, token_valid, C, combine, dispatch):
+        """One pass and one scatter a choice level: the top-1 / top-2 program
+        (what the Mixtral cells trace)."""
+        import jax
+        import jax.numpy as jnp
+
+        T, E = topk_e.shape[0], self.num_experts
         base = jnp.zeros((E, ), jnp.int32)
-        for j in range(self.top_k):
+        for j in range(topk_e.shape[1]):
             e_j = topk_e[:, j]  # [T]
             if token_valid is not None:
                 # invalid tokens must not consume capacity slots: route them OOB
@@ -137,8 +166,39 @@ class RaggedMoE:
             combine = combine.at[t_idx, e_j, slot_c].add(
                 jnp.where(ok, topk_p[:, j], 0.0), mode="drop")
             dispatch = dispatch.at[t_idx, e_j, slot_c].add(
-                jnp.where(ok, 1.0, 0.0).astype(dtype), mode="drop")
+                jnp.where(ok, 1.0, 0.0).astype(dispatch.dtype), mode="drop")
             base = base + onehot.sum(axis=0)
+        return combine, dispatch
+
+    def _fill_in_one_pass(self, topk_p, topk_e, token_valid, C, combine, dispatch):
+        """The same slots for every choice level in ONE pass: the T x k
+        assignments laid out level-major (all first choices in token order,
+        then all second choices, ...) are in the order the level-by-level loop
+        fills slots, so one cumulative count over that list gives each
+        assignment the slot the loop gives it (tier-1 holds the two against
+        each other). One scatter of T x k updates in place of k scatters of T:
+        a top-8 layer's program is about half the unrolled loop's to compile
+        (2.4 s against 4.4 s a 256-token bucket of 8 layers, compiled for a
+        v5e here: PERF.md section 6, PR 30), which a cell with 49 programs to
+        warm inside its run limit needs."""
+        import jax
+        import jax.numpy as jnp
+
+        T, k = topk_e.shape
+        E = self.num_experts
+        e_flat = topk_e.T.reshape(k * T)
+        p_flat = topk_p.T.reshape(k * T)
+        t_flat = jnp.tile(jnp.arange(T), k)
+        if token_valid is not None:
+            # invalid tokens must not consume capacity slots: route them OOB
+            e_flat = jnp.where(jnp.tile(token_valid, k), e_flat, E)
+        onehot = jax.nn.one_hot(e_flat, E, dtype=jnp.int32)  # [k T, E]; OOB -> all-zero
+        slot = (jnp.cumsum(onehot, axis=0) * onehot).max(axis=1) - 1  # -1 for OOB tokens
+        ok = (slot < C) & (slot >= 0)
+        slot_c = jnp.where(ok, slot, C)  # OOB slot -> dropped by scatter
+        combine = combine.at[t_flat, e_flat, slot_c].add(jnp.where(ok, p_flat, 0.0), mode="drop")
+        dispatch = dispatch.at[t_flat, e_flat, slot_c].add(
+            jnp.where(ok, 1.0, 0.0).astype(dispatch.dtype), mode="drop")
         return combine, dispatch
 
     def _expert_ffn(self, buf, wi, wo, activation):
@@ -191,7 +251,7 @@ class RaggedMoE:
 
         T, M = h.shape
         E = self.num_experts
-        C = max(4, int(np.ceil(T * self.top_k / E * self.capacity_factor)))
+        C = self.capacity(T)
         with jax.named_scope("route"):
             probs = self._router_probs(h, gate_w, gate_seed=gate_seed)  # [T, E]
             if token_valid is not None:
@@ -235,7 +295,7 @@ class RaggedMoE:
             h = jnp.pad(h, ((0, Tp - T), (0, 0)))
             token_valid = jnp.pad(token_valid, (0, Tp - T))
         Tl = Tp // ep
-        C = max(4, int(np.ceil(Tl * self.top_k / E * self.capacity_factor)))
+        C = self.capacity(Tl)
         seed = jnp.asarray(0 if gate_seed is None else gate_seed, jnp.int32)
 
         def body(h_l, gate_w, wi_l, wo_l, tv_l, seed_l):

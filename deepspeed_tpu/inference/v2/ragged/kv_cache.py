@@ -3,13 +3,23 @@
 Reference: ``deepspeed/inference/v2/ragged/kv_cache.py`` (BlockedKVCache:40 —
 reserve/free block ids, device cache tensors, offload/restore hooks).
 
-TPU layout: one cache array per allocation group of shape
-``[num_layers, 2, num_blocks, kv_heads, block_size, head_dim]`` — a (layer, k|v,
-block) triple is one contiguous ``[kv_heads, block_size, head_dim]`` tile, which is
-exactly one DMA for the Pallas paged-attention kernel
+TPU layout: ONE cache array of shape
+``[layers_per_group, 2, num_blocks, kv_heads, block_size, head_dim]`` — a (layer,
+k|v, block) triple is one contiguous ``[kv_heads, block_size, head_dim]`` tile,
+which is exactly one DMA for the Pallas paged-attention kernel
 (``ops/pallas/paged_attention.py``) and a clean dynamic-slice for the XLA gather
 fallback. The trailing ``[block_size, head_dim]`` = (16, 128) matches the TPU tile
 so per-block copies are layout-native.
+
+Layer groups (``KVCacheConfig.num_allocation_groups`` = G): layers that keep
+different spans of a sequence (a window layer its last ``window`` tokens, a full
+layer all of them) cannot share a block table. Layer ``li`` belongs to group
+``li % G`` and lives at cache layer ``li // G``, so ``layers_per_group =
+num_layers / G`` and a block id holds that many layers of ONE group: a sequence
+takes a block id per group for each block of positions, and gives a window
+group's back alone. One pool, one allocator, one ``free_blocks``. A model whose
+layers all see alike is one group of ``num_layers`` layers: the array it always
+had.
 """
 
 import os
@@ -68,6 +78,11 @@ class BlockedKVCache:
 
         self._config = config
         num_layers, kv_heads, head_dim = config.cache_shape
+        if num_layers % config.num_allocation_groups:
+            raise ValueError(f"{num_layers} layers do not split into "
+                             f"{config.num_allocation_groups} KV layer groups of equal depth")
+        num_layers //= config.num_allocation_groups  # the layers one block id holds
+        self._layers_per_group = num_layers
         block_bytes = (config.block_size * 2 * num_layers * kv_heads * head_dim *
                        _dtype_size(config.cache_dtype))
         if memory_config.mode == AllocationMode.RESERVE:
@@ -183,7 +198,8 @@ class BlockedKVCache:
         fleet KV-handoff importer. A failed allocation or write consumes
         nothing."""
         data = np.asarray(data)
-        num_layers, kv_heads, head_dim = self._config.cache_shape
+        _, kv_heads, head_dim = self._config.cache_shape
+        num_layers = self._layers_per_group
         expect = (num_layers, 2, kv_heads, self._config.block_size, head_dim)
         got = data.shape[:2] + data.shape[3:] if data.ndim == 6 else None
         if got != expect:

@@ -19,6 +19,9 @@ class AllocationMode(Enum):
 
 class KVCacheConfig(DeepSpeedConfigModel):
     block_size: int = 128
+    # KV layer groups: layer li reads block table li % groups at cache layer
+    # li // groups, so a block id holds num_layers / groups layers of ONE group
+    # (kv_cache.py). One group is one table for every layer.
     num_allocation_groups: int = Field(1, gt=0)
     cache_shape: Tuple[int, int, int] = (0, 0, 0)  # (num_layers, num_heads, head_size)
     cache_dtype: str = "bfloat16"

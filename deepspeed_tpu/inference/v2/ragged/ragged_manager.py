@@ -41,7 +41,8 @@ class DSStateManager:
         if self.n_tracked_sequences >= self._config.max_tracked_sequences:
             raise RuntimeError(f"max_tracked_sequences={self._config.max_tracked_sequences} reached")
         max_blocks = (self._config.max_context + self._kv_config.block_size - 1) // self._kv_config.block_size
-        seq = DSSequenceDescriptor(uid, max_blocks_per_seq=max_blocks)
+        seq = DSSequenceDescriptor(uid, max_blocks_per_seq=max_blocks,
+                                   num_groups=self._kv_config.num_allocation_groups)
         self._seqs[uid] = seq
         return seq
 
@@ -52,6 +53,7 @@ class DSStateManager:
         one reference per block on this sequence's behalf, which
         ``flush_sequence`` returns). The next forward continues at position
         ``seen_tokens`` exactly like a restored or imported sequence."""
+        self._one_table_only("create_cached_sequence (a prefix-cache hit)")
         blocks = np.atleast_1d(np.asarray(blocks)).astype(np.int64)
         seen_tokens = int(seen_tokens)
         if seen_tokens < 0 or seen_tokens > blocks.size * self._kv_config.block_size:
@@ -69,6 +71,14 @@ class DSStateManager:
             raise
         return seq
 
+    def _one_table_only(self, what: str) -> None:
+        """Shared prefixes and handoff frames carry ONE block table a sequence."""
+        if self.num_groups != 1:
+            raise ValueError(f"{what}: this model keeps {self.num_groups} block tables a "
+                             f"sequence (KV layer groups, some with a sliding window); a "
+                             f"shared or exported table cannot stand for them — recompute "
+                             f"the sequence instead")
+
     def flush_sequence(self, uid: int) -> None:
         """Release all state for a sequence (reference ragged_manager.py:110)."""
         seq = self._seqs.pop(uid, None)
@@ -81,16 +91,18 @@ class DSStateManager:
         elif seq.live_blocks > 0:
             self._kv_cache.free(seq.live_kv_blocks)
 
-    def release_passed_blocks(self, seq: DSSequenceDescriptor, window: int) -> int:
-        """Rolling release under a sliding ``window``: give back to the
-        allocator every block ALL of whose positions are more than ``window``
-        behind the sequence's next query (position ``seen_tokens``, which sees
-        keys ``seen_tokens - window + 1 ..``). Returns the number released.
+    def release_passed_blocks(self, seq: DSSequenceDescriptor, window: int,
+                              group: int = 0) -> int:
+        """Rolling release under a sliding ``window``, in the table of layer
+        ``group`` (whose layers have that window): give back to the allocator
+        every block ALL of whose positions are more than ``window`` behind the
+        sequence's next query (position ``seen_tokens``, which sees keys
+        ``seen_tokens - window + 1 ..``). Returns the number released.
         An offloaded sequence is left alone: its table is not live."""
         if window <= 0 or seq.tracking_id in self._offloaded:
             return 0
         passed = max(seq.seen_tokens - window + 1, 0) // self._kv_config.block_size
-        freed = seq.release_leading(passed)
+        freed = seq.release_leading(passed, group)
         if freed:
             self._kv_cache.free(freed)
         return len(freed)
@@ -187,6 +199,7 @@ class DSStateManager:
         count restored. Raises without consuming anything when the uid is
         already tracked, the payload's geometry doesn't fit this cache, or
         the device pool can't hold it (evict and retry)."""
+        self._one_table_only("import_sequence")
         uid = int(snapshot["uid"] if uid is None else uid)
         if uid in self._seqs:
             raise ValueError(f"import_sequence: uid {uid} already tracked")
@@ -227,6 +240,12 @@ class DSStateManager:
     @property
     def free_blocks(self) -> int:
         return self._kv_cache.free_blocks
+
+    @property
+    def num_groups(self) -> int:
+        """KV layer groups: block tables a sequence, block ids a block of
+        positions (kv_cache.py)."""
+        return self._kv_config.num_allocation_groups
 
     def allocate_blocks(self, n_blocks: int):
         return self._kv_cache.reserve(n_blocks)

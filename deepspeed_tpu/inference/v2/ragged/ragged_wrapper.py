@@ -62,9 +62,13 @@ def _pow2_pad(n: int, minimum: int = 4) -> int:
 class RaggedBatchWrapper:
     """Host-side composition of one ragged forward batch."""
 
-    def __init__(self, config: DSStateManagerConfig, block_size: int = 128) -> None:
+    def __init__(self, config: DSStateManagerConfig, block_size: int = 128,
+                 num_groups: int = 1) -> None:
+        """``num_groups``: block tables a sequence (KV layer groups,
+        ``ragged/kv_cache.py``); the batch carries them side by side."""
         self._config = config
         self._block_size = block_size
+        self._num_groups = num_groups
         self.clear()
 
     def clear(self) -> None:
@@ -123,7 +127,7 @@ class RaggedBatchWrapper:
         self._seq_descs.append(seq_desc)
         self._seq_seen.append(seen)
         self._seq_ntok.append(int(tokens.size))
-        self._seq_blocks.append(seq_desc.kv_blocks)
+        self._seq_blocks.append(seq_desc.block_tables)
         self._token_ids.extend(int(t) for t in tokens)
         self._token_seq.extend([seq_idx] * tokens.size)
         self._token_pos.extend(range(seen, seen + tokens.size))
@@ -139,8 +143,9 @@ class RaggedBatchWrapper:
         """Pad to the bucket and build the device-ready numpy struct."""
         T = to_padded(max(1, self.current_tokens))
         S = _pad_to(max(1, self.current_sequences), 8)
-        mb = max((len(b) for b in self._seq_blocks), default=1)
+        mb = max((b.shape[1] for b in self._seq_blocks), default=1)
         MB = _pow2_pad(mb, 4)
+        G = self._num_groups
         cw = compile_watch.get()
         if cw is not None:
             # (T, S, MB) IS the jit cache key downstream — the watch counts
@@ -162,8 +167,9 @@ class RaggedBatchWrapper:
         seq_ntok = np.zeros(S, np.int32)
         last_tok = np.zeros(S, np.int32)
         seq_valid = np.zeros(S, bool)
-        # padded/invalid slots point one past the last block -> scatters drop
-        block_table = np.full((S, MB), -1, np.int32)
+        # padded/invalid slots point one past the last block -> scatters drop;
+        # group g's table is columns [g * MB, (g + 1) * MB)
+        block_table = np.full((S, G, MB), -1, np.int32)
         cursor = 0
         for i in range(n_seq):
             seq_seen[i] = self._seq_seen[i]
@@ -172,7 +178,7 @@ class RaggedBatchWrapper:
             last_tok[i] = cursor - 1
             seq_valid[i] = True
             blocks = self._seq_blocks[i]
-            block_table[i, :len(blocks)] = blocks
+            block_table[i, :, :blocks.shape[1]] = blocks
 
         # Pack into TWO device arrays (plus host-only counts): every h2d
         # transfer pays dispatch latency, and decode issues one batch per
@@ -182,8 +188,8 @@ class RaggedBatchWrapper:
                              token_valid.astype(np.int32)])  # [4, T]
         seq_meta = np.concatenate([
             np.stack([seq_seen, seq_ntok, last_tok, seq_valid.astype(np.int32)], axis=1),
-            block_table
-        ], axis=1)  # [S, 4 + MB]
+            block_table.reshape(S, G * MB)
+        ], axis=1)  # [S, 4 + G * MB]
         self._device_batch = dict(
             tok_meta=tok_meta,
             seq_meta=seq_meta,

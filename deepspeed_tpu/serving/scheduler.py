@@ -303,11 +303,12 @@ class ServingScheduler:
         # a sliding-window model releases KV blocks as its window passes them
         # (transformer_base.maybe_free_kv); what needs a sequence's whole block
         # table refuses here, or at submission, instead of serving wrong keys
-        self._window = int(getattr(getattr(engine, "model", None), "attention_window", 0) or 0)
+        # (some layer has a window: the others keep every block, that one does not)
+        self._window = max(getattr(getattr(engine, "model", None), "group_windows", (0, )))
         if self._window and (self._config.prefix_cache.enabled or self._config.kv_tiers.enabled):
             raise ValueError(
-                f"prefix_cache / kv_tiers cannot serve a sliding-window model "
-                f"(attention_window={self._window}): the trie shares and the tier ladder "
+                f"prefix_cache / kv_tiers cannot serve a sliding-window model (a layer's "
+                f"attention window is {self._window}): the trie shares and the tier ladder "
                 f"moves whole block tables, and this model's sequences release the blocks "
                 f"their window has passed. Turn both off for this model.")
         if self._config.prefix_cache.enabled:
@@ -627,8 +628,8 @@ class ServingScheduler:
         if self._window and (handoff or req.park_requested or req._resume_header is not None):
             raise ValueError(
                 f"handoff, park and resume frames carry a sequence's whole KV block table; "
-                f"a sliding-window model (attention_window={self._window}) releases the "
-                f"blocks its window has passed. Send the prompt for recompute instead.")
+                f"a sliding-window model (a layer's attention window is {self._window}) releases "
+                f"the blocks its window has passed. Send the prompt for recompute instead.")
         req.handoff_requested = bool(handoff)
         if self._ledger is not None:
             # every admitted request carries a RequestCost from birth (the

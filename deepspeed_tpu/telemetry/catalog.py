@@ -53,6 +53,8 @@ METRIC_FAMILIES = {
     "inference_kv_free_blocks": "free KV-cache blocks",
     "inference_kv_released_blocks": "KV blocks a sliding window's rolling release has given back "
                                     "to the pool since the engine was built",
+    "inference_kv_group_live_blocks": "KV blocks tracked sequences hold, by the kind of layer "
+                                      "group holding them (kind=full|window)",
     "inference_tracked_sequences": "sequences tracked",
     "inference_empty_runs_total": "EP lock-step forwards with zero tokens",
     # serving layer (serving/metrics.py)

@@ -332,3 +332,68 @@ def test_window_model_decode_loop_program_fits_one_chip(v5e, window_model):
     assert "paged_attention_update" in text
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert not _pool_sized_results(text, cache.shape)
+
+
+# ---- window and full layers side by side, top-8 of 64 (PR 30) ----------------
+MELLUM_LAYERS, MELLUM_POOL_BLOCKS, MELLUM_MAX_BLOCKS = 8, 17408, 256
+
+
+@pytest.fixture(scope="module")
+def mellum_model():
+    """``mellum2-12b-a2.5b-serve-1chip``: Mellum-2's published widths, two
+    periods of its layer pattern, contexts to 16384, over ``jax.eval_shape``d
+    parameters."""
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.model_implementations.registry import model_cls_for
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import DSStateManagerConfig
+    from deepspeed_tpu.models import mellum
+    cfg = mellum.MellumConfig(num_hidden_layers=MELLUM_LAYERS)
+    abstract = jax.eval_shape(lambda: mellum.init_params(cfg, param_dtype=cfg.dtype)[1])
+    engine_config = RaggedInferenceEngineConfig(
+        state_manager=DSStateManagerConfig(max_context=MELLUM_MAX_BLOCKS * BS,
+                                           max_ragged_batch_size=256,
+                                           max_ragged_sequence_count=8), kv_block_size=BS,
+        expert_parallel={"capacity_factor": 8.0})
+    model = model_cls_for(cfg)(abstract, cfg, engine_config)
+    assert model.group_windows == (1024, 1024, 1024, 0) and model.head_dim == 128
+    return model, abstract
+
+
+def _mellum_args(device, model, abstract, bucket):
+    one = SingleDeviceSharding(device)
+    tokens, seqs, max_blocks = bucket
+    params = jax.tree.map(lambda leaf: _on(one, leaf.shape, leaf.dtype), abstract)
+    cache = _on(one, (MELLUM_LAYERS // model.kv_groups, 2, MELLUM_POOL_BLOCKS, 4, BS, 128),
+                jnp.bfloat16)
+    batch = {"tok_meta": _on(one, (4, tokens), jnp.int32),
+             "seq_meta": _on(one, (seqs, 4 + model.kv_groups * max_blocks), jnp.int32)}
+    return one, params, cache, batch
+
+
+@pytest.mark.parametrize("bucket,kernel", [((8, 8, 256), "paged_attention_update"),
+                                           ((256, 8, 256), "paged_attention_prefill")],
+                         ids=["decode-bucket", "chunk-bucket"])
+def test_mellum_put_program_fits_one_chip(v5e, mellum_model, bucket, kernel):
+    """Two kernel variants a program (window 1024 and none), each layer on its
+    group's table and cache layer; 7.1 GiB of weights beside a 4.25 GiB pool
+    that is aliased through."""
+    model, abstract = mellum_model
+    assert model.attention_arm(bucket[0]) in ("paged_token", "paged_tiled")
+    _, params, cache, batch = _mellum_args(v5e[0], model, abstract, bucket)
+    compiled = jax.jit(model._forward_impl, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and kernel in text
+    assert _device_bytes(compiled) < 0.8 * HBM_BYTES
+    assert not _pool_sized_results(text, cache.shape)
+
+
+def test_mellum_decode_loop_program_fits_one_chip(v5e, mellum_model):
+    model, abstract = mellum_model
+    one, params, cache, batch = _mellum_args(v5e[0], model, abstract, (8, 8, 256))
+    loop = functools.partial(model._decode_loop_impl, n_steps=8, sampled=False)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(
+        params, cache, batch, _on(one, (), jnp.float32), _on(one, (2, ), jnp.uint32)).compile()
+    text = compiled.as_text()
+    assert "paged_attention_update" in text
+    assert _device_bytes(compiled) < 0.8 * HBM_BYTES
+    assert not _pool_sized_results(text, cache.shape)
