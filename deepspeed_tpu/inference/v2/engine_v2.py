@@ -82,6 +82,9 @@ class InferenceEngineV2:
         # span (which reports the difference as ``released_blocks``)
         self._released_blocks = 0
         self._released_at_prepare = 0
+        # how the newest ``put`` step's program routed its tokens to experts
+        # (``grouped`` / ``capacity``; None for a dense model, or before any)
+        self.last_moe_path = None
 
     # ------------------------------------------------------------------ groups --
     def _initialize_comm_groups(self) -> None:
@@ -316,10 +319,14 @@ class InferenceEngineV2:
 
         self._prepare_forward(spans, batch_uids, batch_tokens, do_checks, n_tokens)
         n_padded = self._batch.device_batch["tok_meta"].shape[1]
+        # how the bucket's program routes its tokens to experts (grouped /
+        # capacity; None for a dense model): the scheduler counts steps by it
+        self.last_moe_path = self._model.moe_path(n_padded)
         args = self._dispatch_args(spans, batch_uids, tokens=n_tokens)
         if args is not None:
             # the arm the bucket's program takes (modules/heuristics.py):
-            # paged_tiled / paged_token / xla_gather
+            # paged_tiled / paged_token / xla_gather; a sparse model's
+            # moe_path, moe_rows and moe_assignments
             args["attention"] = self._model.attention_arm(n_padded)
             args.update(self._model.dispatch_counts(n_padded, n_tokens))
         if prev is not None:

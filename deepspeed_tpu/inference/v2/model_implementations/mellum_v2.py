@@ -24,6 +24,7 @@ class MellumV2Model(MixtralV2Model):
         self._moes = self._build_moes(engine_config, config.num_hidden_layers,
                                       config.num_experts, config.num_experts_per_tok,
                                       norm_topk_prob=config.norm_topk_prob)
+        self._expert_width = config.moe_intermediate_size
 
     def _build_rope(self, max_context):
         """No table: a layer type's ``rope_parameters`` entry. The angles are

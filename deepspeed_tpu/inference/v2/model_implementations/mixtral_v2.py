@@ -24,6 +24,7 @@ class MixtralV2Model(LlamaV2Model):
         self._moe_config = config
         self._moes = self._build_moes(engine_config, config.num_hidden_layers,
                                       config.num_local_experts, config.num_experts_per_tok)
+        self._expert_width = config.intermediate_size
 
     @staticmethod
     def _build_moes(engine_config, num_layers, num_experts, top_k, norm_topk_prob=True):
@@ -36,14 +37,26 @@ class MixtralV2Model(LlamaV2Model):
                       layer_id=li, norm_topk_prob=norm_topk_prob) for li in range(num_layers)
         ]
 
+    def _expert_parallel(self):
+        if not groups.mesh_is_initialized():
+            return 1
+        return int(groups.get_mesh().shape.get(self._moes[0].expert_axis, 1))
+
+    def moe_path(self, n_padded):
+        """``grouped`` / ``capacity``: how an ``n_padded``-token bucket's
+        program routes (``modules/heuristics.py``; one answer for every layer,
+        the layers being alike)."""
+        return self._moes[0].path(n_padded, self._expert_width, self._expert_parallel())
+
     def dispatch_counts(self, n_padded, n_tokens):
-        """``moe_rows``: rows the expert GEMMs compute this step, summed over
-        the layers (the capacity path computes every expert's every slot);
-        ``moe_assignments``: live tokens x top-k x layers, what had to be."""
-        ep = 1
-        if groups.mesh_is_initialized():
-            ep = int(groups.get_mesh().shape.get(self._moes[0].expert_axis, 1))
-        return {"moe_rows": sum(m.expert_rows(n_padded, ep) for m in self._moes),
+        """``moe_rows``: rows the expert GEMMs compute this step on the path
+        the bucket takes (``moe_path``), summed over the layers: every
+        expert's every slot on the capacity path, a row a padded assignment on
+        the grouped one; ``moe_assignments``: live tokens x top-k x layers,
+        what had to be."""
+        ep, path = self._expert_parallel(), self.moe_path(n_padded)
+        return {"moe_path": path,
+                "moe_rows": sum(m.expert_rows(n_padded, ep, path) for m in self._moes),
                 "moe_assignments": n_tokens * sum(m.top_k for m in self._moes)}
 
     @property

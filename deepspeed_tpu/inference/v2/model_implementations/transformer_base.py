@@ -578,8 +578,13 @@ class DSTransformerModelBase:
     def dispatch_counts(self, n_padded: int, n_tokens: int) -> dict:
         """Work counters of one ``put`` step over an ``n_padded``-token bucket
         holding ``n_tokens`` live tokens, for the step's span (sparse models:
-        expert rows computed and assignments routed)."""
+        the path to the experts, expert rows computed and assignments routed)."""
         return {}
+
+    def moe_path(self, n_padded: int):
+        """How an ``n_padded``-token bucket's program reaches its experts
+        (``grouped`` / ``capacity``); None for a model without experts."""
+        return None
 
     def attention_arm(self, T: int) -> str:
         """The attention arm a ``T``-token bucket takes (``paged_token`` /

@@ -4,7 +4,9 @@ Reference: ``deepspeed/inference/v2/modules/heuristics.py:36-165``
 (``instantiate_attn/linear/moe/...`` — pick a concrete kernel implementation
 from the registry given the model+engine config). The TPU build has two real
 attention implementations to arbitrate between; everything else is one
-XLA-fused implementation, so the heuristic surface is exactly this choice.
+XLA-fused implementation, so the heuristic surface is that choice and, for a
+sparse model on one replica, how the tokens reach their experts
+(``moe_implementation``).
 """
 
 from deepspeed_tpu.utils.logging import logger
@@ -49,3 +51,36 @@ def attention_implementation(model, engine_config, bucket_tokens: int) -> str:
                        f"gather path")
         return "xla_gather"
     return kernel
+
+
+# The grouped path is taken where the one-hot masks of the capacity path cost a
+# real share of the experts. Per buffer row the two mask einsums
+# (``tec,tm->ecm`` and ``tec,ecm->tm``) are 4*T*M flops and the expert GEMMs
+# 6*M*F, so the masks are 2T / 3F of the experts: 1/20 is where the traces
+# began to see them (``moe_route_busy_pct`` 0.3-2.9 on Mixtral's F = 14336 at
+# T <= 256, where the share is at most 1.2 %; 30 + 10 unscoped on Mellum's
+# F = 896 at T = 256, where it is 19 %: PERF.md section 6, PR 32).
+MOE_MASK_SHARE_MIN = 1 / 20
+# ... and where the masks are arrays worth the name: under 2^20 elements
+# ([tokens, experts, capacity]; a float32 and an activation-dtype one) their
+# fill is microseconds, less than a sort and two gathers of the rows.
+MOE_MASK_ELEMENTS_MIN = 1 << 20
+
+
+def moe_implementation(tokens: int, num_experts: int, capacity: int, intermediate: int,
+                       expert_parallel: int = 1) -> str:
+    """How a ``tokens``-token bucket reaches its experts: ``"grouped"`` (rows
+    sorted by expert, one grouped matmul a projection, dropless whatever the
+    skew) or ``"capacity"`` (``[tokens, experts, capacity]`` one-hot masks into
+    static per-expert buffers). A pure function of static shapes: ``capacity``
+    is what the capacity path would give an expert for this bucket,
+    ``intermediate`` the experts' width F, ``expert_parallel`` the size of the
+    mesh's expert axis (its two all-to-alls need the static per-destination
+    buffers, so anything over 1 answers ``capacity``)."""
+    if expert_parallel > 1:
+        return "capacity"
+    mask_share = 2 * tokens / (3 * intermediate)
+    mask_elements = tokens * num_experts * capacity
+    if mask_share >= MOE_MASK_SHARE_MIN and mask_elements >= MOE_MASK_ELEMENTS_MIN:
+        return "grouped"
+    return "capacity"

@@ -28,12 +28,23 @@ seeded per (layer, batch, replica): callers thread a data-dependent ``gate_seed`
 (the model passes the sum of live token positions, so successive decode steps
 route differently) and the EP body folds in the replica index.
 
-Named scopes (``jax.named_scope``, metadata only): ``route`` (router
-probabilities + capacity packing), ``dispatch`` (tokens into the expert-major
-buffer), ``experts`` (the grouped GEMMs), ``combine`` (back to token-major with
-the routing weights) and, under expert parallelism, ``a2a`` (the two
-all-to-alls). They are relative: the caller's ``moe`` scope (mixtral_v2's FFN
-phase) makes them ``moe/route`` ... in the device trace.
+On ONE replica a bucket reaches its experts by one of two paths, chosen from
+static shapes by ``heuristics.moe_implementation``: the capacity path
+(``_dense_forward``: the same ``[tokens, experts, capacity]`` one-hot masks as
+under EP, into static per-expert buffers) or, where those masks would cost a
+real share of the experts (many narrow experts, a full chunk of tokens), the
+grouped path (``_grouped_forward``: the assignments sorted by expert, one gather
+of their rows, one grouped matmul a projection —
+``ops/pallas/grouped_matmul.py`` — and the rows summed back to their tokens;
+dropless whatever the skew, no capacity).
+
+Named scopes (``jax.named_scope``, metadata only), the same four on both paths:
+``route`` (router probabilities + capacity packing, or the sort),
+``dispatch`` (tokens into the expert-major buffer), ``experts`` (the grouped
+GEMMs), ``combine`` (back to token-major with the routing weights) and, under
+expert parallelism, ``a2a`` (the two all-to-alls). They are relative: the
+caller's ``moe`` scope (mixtral_v2's FFN phase) makes them ``moe/route`` ... in
+the device trace.
 """
 
 from typing import Optional
@@ -95,10 +106,21 @@ class RaggedMoE:
         (a token picks an expert at most once)."""
         return max(4, int(np.ceil(tokens * self.top_k / self.num_experts * self.capacity_factor)))
 
-    def expert_rows(self, tokens: int, ep: int = 1) -> int:
+    def path(self, tokens: int, intermediate: int, ep: int = 1) -> str:
+        """``grouped`` or ``capacity``: the path a ``tokens``-token bucket takes
+        through experts ``intermediate`` wide (``modules/heuristics.py``)."""
+        from deepspeed_tpu.inference.v2.modules.heuristics import moe_implementation
+        return moe_implementation(tokens, self.num_experts, self.capacity(tokens), intermediate,
+                                  ep)
+
+    def expert_rows(self, tokens: int, ep: int = 1, path: str = "capacity") -> int:
         """Rows the expert GEMMs compute for a ``tokens``-token bucket, live or
-        padding, over all experts (and all ``ep`` replicas): what the capacity
-        path pays, whatever was routed."""
+        padding: on the capacity path every slot of every expert (over all
+        ``ep`` replicas), whatever was routed; on the grouped path a row an
+        assignment of the padded bucket."""
+        if path == "grouped":
+            from deepspeed_tpu.ops.pallas.grouped_matmul import padded_rows
+            return padded_rows(tokens * self.top_k)
         return self.num_experts * ep * self.capacity(-(-tokens // ep))
 
     # ------------------------------------------------------------------ gating --
@@ -237,8 +259,66 @@ class RaggedMoE:
             logger.warning(f"RaggedMoE: {self.num_experts} experts not divisible by EP "
                            f"degree {ep}; falling back to GSPMD expert-sharded compute "
                            f"(no token disaggregation)")
+        if self.path(h.shape[0], wo.shape[-2], ep) == "grouped":
+            return self._grouped_forward(h, gate_w, wi, wo, token_valid, activation, gate_seed)
         return self._dense_forward(h, gate_w, wi, wo, token_valid, activation, gate_seed,
                                    mesh if ep > 1 else None)
+
+    def _grouped_forward(self, h, gate_w, wi, wo, token_valid, activation, gate_seed):
+        """Single-replica path that routes by SORTING: the T x k assignments
+        ordered by expert, one gather of their rows, one grouped matmul a
+        projection (each expert's rows against its own bank), the routing
+        weights applied in float32 and the rows summed back to their tokens.
+        Every assignment has a row whatever the skew: dropless by construction,
+        ``capacity_factor`` has no part in it. An invalid token's assignments
+        sort behind every expert's and belong to no group."""
+        import jax
+        import jax.numpy as jnp
+        from deepspeed_tpu.ops.pallas.grouped_matmul import padded_rows
+
+        T, M = h.shape
+        E, k = self.num_experts, self.top_k
+        rows = padded_rows(T * k)
+        with jax.named_scope("route"):
+            probs = self._router_probs(h, gate_w, gate_seed=gate_seed)  # [T, E] float32
+            topk_p, topk_e = jax.lax.top_k(probs, k)  # [T, k]
+            if self.norm_topk_prob:
+                topk_p = topk_p / jnp.maximum(topk_p.sum(-1, keepdims=True), 1e-9)
+            e_flat = topk_e.reshape(T * k)  # token-major: assignment a is token a // k
+            if token_valid is not None:
+                e_flat = jnp.where(jnp.repeat(token_valid, k), e_flat, E)
+            e_flat = jnp.pad(e_flat, (0, rows - T * k), constant_values=E)
+            slots = jnp.arange(rows, dtype=jnp.int32)
+            # stable: an expert's rows stay in token order
+            e_sorted, order = jax.lax.sort((e_flat, slots), num_keys=1, is_stable=True)
+            group_sizes = (e_flat[:, None] == jnp.arange(E)[None, :]).sum(0, dtype=jnp.int32)
+            # where each assignment's row went: the inverse permutation
+            _, back = jax.lax.sort((order, slots), num_keys=1)
+        with jax.named_scope("dispatch"):
+            buf = h[jnp.minimum(order // k, T - 1)]  # [rows, M]
+        with jax.named_scope("experts"):
+            out = self._grouped_ffn(buf, wi, wo, group_sizes, activation)  # [rows, M] float32
+        with jax.named_scope("combine"):
+            # rows behind the last group (an invalid token's, the padding) are
+            # no expert's: whatever the kernel left there
+            out = jnp.where((e_sorted < E)[:, None], out, 0.0)
+            out = out[back[:T * k]].reshape(T, k, M) * topk_p[:, :, None]
+            return out.sum(axis=1).astype(h.dtype)
+
+    def _grouped_ffn(self, buf, wi, wo, group_sizes, activation):
+        """The experts over expert-sorted rows [rows, M]: operands in the rows'
+        dtype, float32 accumulation, a float32 result for the combine."""
+        import jax.numpy as jnp
+        from deepspeed_tpu.ops.pallas.grouped_matmul import group_visits, grouped_matmul
+        visits = group_visits(group_sizes, buf.shape[0])  # one schedule, both projections
+        hpre = grouped_matmul(buf, wi.astype(buf.dtype), group_sizes, buf.dtype, visits=visits)
+        if wi.shape[-1] == 2 * wo.shape[-2]:  # fused (gate|up) SwiGLU bank
+            from deepspeed_tpu.moe.layer import gated_expert_act
+            hmid = gated_expert_act(hpre, activation)
+        else:
+            hmid = activation(hpre)
+        return grouped_matmul(hmid, wo.astype(buf.dtype), group_sizes, jnp.float32,
+                              visits=visits)
 
     def _dense_forward(self, h, gate_w, wi, wo, token_valid, activation, gate_seed,
                        mesh=None):

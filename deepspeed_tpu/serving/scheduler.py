@@ -206,7 +206,8 @@ class ServingScheduler:
                            "tier_demotions", "brownout_demotions",
                            "parks", "rehydrates", "fair_share_shed",
                            "device_draws", "host_draws",
-                           "put_steps", "pipelined_steps", "overrun_rows")
+                           "put_steps", "pipelined_steps", "overrun_rows",
+                           "moe_grouped_steps", "moe_capacity_steps")
                           + tuple(f"drained_steps_{r}" for r in _DRAIN_REASONS)}
         # the put step on the device that no tick has fetched yet (step()),
         # why the newest fetched step was fetched before its successor was
@@ -1980,6 +1981,10 @@ class ServingScheduler:
         if step is None:
             return
         self._counters["put_steps"] += 1
+        moe_path = getattr(self._engine, "last_moe_path", None)
+        if moe_path is not None:
+            # a sparse model's step: by the path its bucket routes on
+            self._counters[f"moe_{moe_path}_steps"] += 1
         prev, self._inflight = self._inflight, step
         if tick is not None:
             tick["pipelined"] = int(prev is not None)

@@ -59,11 +59,27 @@ def model():
     return cfg, params
 
 
-def _engine(model, kernel=False, blocks=160, budget=FEED, block=BLOCK, capacity_factor=4.0):
+MANY_EXPERTS = dict(num_hidden_layers=4, num_experts=64, num_experts_per_tok=8,
+                    moe_intermediate_size=16)
+
+
+@pytest.fixture(scope="module")
+def many_experts():
+    """One period (3 window layers + 1 full) with Mellum's routing, 8 of 64,
+    and experts 16 wide: its 128-token bucket crosses ``heuristics.
+    moe_implementation``'s rule (the masks would be 128 x 64 x 128 = 2^20
+    elements) and routes by sorting; every smaller bucket takes the masks."""
+    cfg = mellum.MellumConfig(dtype=jnp.float32, **dict(SIZES, **MANY_EXPERTS))
+    _, params = mellum.init_params(cfg, jax.random.PRNGKey(5))
+    return cfg, params
+
+
+def _engine(model, kernel=False, blocks=160, budget=FEED, block=BLOCK, capacity_factor=4.0,
+            max_context=128):
     groups.initialize_mesh(force=True)
     cfg, params = model
     mgr = DSStateManagerConfig(memory_config=MemoryConfig(mode=AllocationMode.ALLOCATE, size=blocks),
-                               max_context=128, max_ragged_batch_size=budget,
+                               max_context=max_context, max_ragged_batch_size=budget,
                                max_ragged_sequence_count=8)
     engine = build_engine(params, cfg, RaggedInferenceEngineConfig(
         state_manager=mgr, kv_block_size=block, use_paged_kernel=kernel,
@@ -323,8 +339,31 @@ def test_ragged_moe_refuses_a_top_k_it_cannot_route():
 def test_the_put_span_counts_expert_rows_and_assignments(model):
     engine = _engine(model)
     # 20 live tokens in a 32-token bucket: 8 layers x 16 experts x 32 slots
-    assert engine.model.dispatch_counts(32, 20) == {"moe_rows": 8 * 16 * 32,
+    assert engine.model.dispatch_counts(32, 20) == {"moe_path": "capacity",
+                                                    "moe_rows": 8 * 16 * 32,
                                                     "moe_assignments": 20 * 4 * 8}
+
+
+def test_the_put_span_counts_the_rows_of_the_path_the_bucket_takes(many_experts):
+    """64 experts top-8: the 128-token bucket routes by sorting (a row a padded
+    assignment), the 64-token one through the masks (every slot of every
+    expert); the span of a step says which (``moe_path``)."""
+    from deepspeed_tpu import telemetry
+    engine = _engine(many_experts, budget=128, capacity_factor=8.0, max_context=256)
+    counts = engine.model.dispatch_counts
+    assert counts(128, 100) == {"moe_path": "grouped", "moe_rows": 4 * 128 * 8,
+                                "moe_assignments": 100 * 8 * 4}
+    assert counts(64, 50) == {"moe_path": "capacity", "moe_rows": 4 * 64 * 64,
+                              "moe_assignments": 50 * 8 * 4}
+    session = telemetry.configure(telemetry.TelemetryConfig(enabled=True))
+    try:
+        engine.put([0], [_ids(40, 128)])
+        engine.put([0], [_ids(41, 5)])
+        spans = [s for s in telemetry.get_span_recorder().tail(64) if s["name"] == "put"]
+    finally:
+        session.close()
+    assert [(s["args"]["moe_path"], s["args"]["moe_rows"]) for s in spans] == [
+        ("grouped", 4 * 1024), ("capacity", 4 * 64 * 8)]
 
 
 # (c) ----------------------------------------------------------- layer groups ---
@@ -462,6 +501,59 @@ def test_tree_verify_repacks_the_accepted_path_in_every_group(model):
 
 
 # ------------------------------------------------------ through the scheduler ---
+def _greedy_streams(scheduler, prompts, n):
+    """Submit ``prompts`` for ``n`` greedy tokens each; the streamed tokens."""
+    handles = [scheduler.submit(p, max_new_tokens=n, temperature=0.0) for p in prompts]
+    outs = []
+    for h in handles:
+        toks = []
+        while True:
+            tok = h.stream.get(timeout=120)
+            if tok is None:
+                break
+            toks.append(int(tok))
+        assert h.state.name == "DONE", h.error
+        outs.append(toks)
+    return outs
+
+
+def test_grouped_chunks_and_capacity_decodes_give_the_references_logits(many_experts):
+    """``build_engine`` -> ``ServingScheduler`` with a 128-token budget. The
+    engine alone first: a 201-token prompt fed as a grouped 128-token chunk, a
+    72-token chunk on the capacity path and a decode step gives the float32
+    reference's logits at each end. Then the scheduler: prompts prefill in
+    grouped chunks and decode in capacity buckets, greedy tokens against the
+    reference, and the counters show both paths engaged."""
+    from deepspeed_tpu.serving import ServingConfig, ServingScheduler
+    engine = _engine(many_experts, blocks=400, budget=128, capacity_factor=8.0, max_context=256)
+    assert [engine.model.moe_path(t) for t in (8, 64, 128)] == ["capacity", "capacity", "grouped"]
+    # the engine alone: a grouped chunk, a capacity chunk, a decode step
+    ids = _ids(50, 201)
+    ends = _feed(engine, 9, ids, [128, 72, 1])
+    want = _reference_rows(many_experts, ids, [127, 199, 200], **MANY_EXPERTS)
+    for got, row in zip(ends, want):
+        np.testing.assert_allclose(got, row, atol=ATOL, rtol=0)
+    engine.flush(9)
+    # decode_chunk 1: every decode step is a ``put`` step the counters see
+    scheduler = ServingScheduler(engine, ServingConfig(decode_chunk=1))
+    try:
+        prompts = [_ids(51, 200), _ids(52, 140), _ids(53, 30)]
+        outs = _greedy_streams(scheduler, prompts, 6)
+        counters = scheduler.stats()["counters"]
+    finally:
+        scheduler.stop(drain=False)
+    for prompt, toks in zip(prompts, outs):
+        assert len(toks) == 6
+        full = np.concatenate([prompt, np.asarray(toks[:-1], np.int32)])
+        want = _reference_rows(many_experts, full, np.arange(prompt.size - 1, full.size),
+                               **MANY_EXPERTS)
+        top2 = np.sort(want, axis=-1)[:, -2:]
+        decided = (top2[:, 1] - top2[:, 0]) > 10 * ATOL
+        assert (np.asarray(toks)[decided] == want.argmax(-1)[decided]).all()
+    assert counters["moe_grouped_steps"] >= 2 and counters["moe_capacity_steps"] >= 5
+    assert counters["moe_grouped_steps"] + counters["moe_capacity_steps"] == counters["put_steps"]
+
+
 def test_the_serving_scheduler_serves_it_past_the_window(model):
     """``build_engine`` -> ``ServingScheduler``: the path every serving cell
     takes. Greedy tokens against the reference's argmax, prompts on both sides
@@ -472,17 +564,7 @@ def test_the_serving_scheduler_serves_it_past_the_window(model):
     scheduler = ServingScheduler(engine, ServingConfig(decode_chunk=4))
     try:
         prompts = [_ids(20, 70), _ids(21, 9), _ids(22, 40)]
-        handles = [scheduler.submit(p, max_new_tokens=6, temperature=0.0) for p in prompts]
-        outs = []
-        for h in handles:
-            toks = []
-            while True:
-                tok = h.stream.get(timeout=120)
-                if tok is None:
-                    break
-                toks.append(int(tok))
-            assert h.state.name == "DONE", h.error
-            outs.append(toks)
+        outs = _greedy_streams(scheduler, prompts, 6)
     finally:
         scheduler.stop(drain=False)
     for prompt, toks in zip(prompts, outs):

@@ -385,6 +385,13 @@ def test_mellum_put_program_fits_one_chip(v5e, mellum_model, bucket, kernel):
     assert "tpu_custom_call" in text and kernel in text
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert not _pool_sized_results(text, cache.shape)
+    # the full chunk routes by sorting (PR 32): the grouped-matmul kernel with
+    # the layer's bank an operand and no [tokens, experts, capacity] mask; the
+    # decode bucket keeps the masks
+    grouped = model.moe_path(bucket[0]) == "grouped"
+    assert grouped == (bucket[0] == 256)
+    assert ("grouped_matmul" in text) == grouped
+    assert (f"[{bucket[0]},64,{bucket[0]}]" in text) != grouped
 
 
 def test_mellum_decode_loop_program_fits_one_chip(v5e, mellum_model):
