@@ -42,6 +42,15 @@ def _rotate_half(x, cos, sin):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
+def _swiglu(h, mp):
+    """The dense SwiGLU feed-forward of ``h`` [T, M] over one ``{gate_proj,
+    up_proj, down_proj}`` subtree: a Llama layer's ``mlp``, and any model's
+    always-on (dense or shared) expert."""
+    gate = h @ mp["gate_proj"]["kernel"].astype(h.dtype)
+    up = h @ mp["up_proj"]["kernel"].astype(h.dtype)
+    return (jax.nn.silu(gate) * up) @ mp["down_proj"]["kernel"].astype(h.dtype)
+
+
 def _rotary_at(x, pos, cos_tab, sin_tab):
     """x: [T, H, D] with per-token absolute positions [T]."""
     return _rotate_half(x, cos_tab[pos][:, None, :], sin_tab[pos][:, None, :])
@@ -114,22 +123,33 @@ class LlamaV2Model(DSTransformerModelBase):
         q = lin(ap["q_proj"], H)
         k = lin(ap["k_proj"], KVH)
         v = lin(ap["v_proj"], KVH)
+        if "q_norm" in ap:  # an RMS norm over each head of q and of k
+            with jax.named_scope("qk_norm"):
+                q = _rms(q, ap["q_norm"]["weight"], cfg.rms_norm_eps)
+                k = _rms(k, ap["k_norm"]["weight"], cfg.rms_norm_eps)
         pos = batch["token_pos"]
         q = self._rotate(li, q, pos)
         k = self._rotate(li, k, pos)
         out, cache = attn_fn(q, k, v, cache, li)
         out = out.reshape(x.shape[0], H * D)
-        return x + out @ ap["o_proj"]["kernel"].astype(h.dtype), cache
+        if "gate_proj" in ap:  # an output gate on the heads' output, from the layer's input
+            with jax.named_scope("gate"):
+                out = out * jax.nn.sigmoid(h @ ap["gate_proj"]["kernel"].astype(h.dtype))
+        return x + self._attn_out(lp, out @ ap["o_proj"]["kernel"].astype(h.dtype)), cache
+
+    def _attn_out(self, lp, y):
+        """The attention branch's output ``y`` as it is added to the residual
+        stream: as it is (these models' ``post_attention_layernorm`` is the
+        feed-forward's pre-norm); a model that norms each branch coming out as
+        well as going in applies that norm here."""
+        return y
 
     @jax.named_scope("mlp")
     def _ffn_phase(self, params, li, x):
         cfg = self._config
         lp = _root(params)[f"layers_{li}"]
         h = _rms(x, lp["post_attention_layernorm"]["weight"], cfg.rms_norm_eps)
-        mp = lp["mlp"]
-        gate = h @ mp["gate_proj"]["kernel"].astype(h.dtype)
-        up = h @ mp["up_proj"]["kernel"].astype(h.dtype)
-        return x + (jax.nn.silu(gate) * up) @ mp["down_proj"]["kernel"].astype(h.dtype)
+        return x + _swiglu(h, lp["mlp"])
 
     def layer_forward(self, params, li, x, cache, attn_fn, batch):
         x, cache = self._attn_phase(params, li, x, cache, attn_fn, batch)

@@ -27,14 +27,15 @@ class MixtralV2Model(LlamaV2Model):
         self._expert_width = config.intermediate_size
 
     @staticmethod
-    def _build_moes(engine_config, num_layers, num_experts, top_k, norm_topk_prob=True):
-        """One ``RaggedMoE`` a layer (Mixtral renormalises over its chosen
-        two); the capacity factor is the engine's ``expert_parallel`` one."""
+    def _build_moes(engine_config, num_layers, num_experts, top_k, **router):
+        """One ``RaggedMoE`` a layer; the capacity factor is the engine's
+        ``expert_parallel`` one, ``router`` what the model says of its routing
+        beyond the default (softmax, renormalised over the chosen, unscaled)."""
         ep_cfg = getattr(engine_config, "expert_parallel", None)
         return [
             RaggedMoE(num_experts=num_experts, top_k=top_k,
                       capacity_factor=(ep_cfg.capacity_factor if ep_cfg is not None else 2.0),
-                      layer_id=li, norm_topk_prob=norm_topk_prob) for li in range(num_layers)
+                      layer_id=li, **router) for li in range(num_layers)
         ]
 
     def _expert_parallel(self):
@@ -73,15 +74,22 @@ class MixtralV2Model(LlamaV2Model):
         lp = _root(params)[f"layers_{li}"]
         h = _rms(x, lp["post_attention_layernorm"]["weight"], cfg.rms_norm_eps)
         gate_w, wi, wo = self._moe_params(params, li)
-        token_valid = None if batch is None else batch["token_valid"]
-        # Data-dependent gating seed: live token positions differ every decode
-        # step, so simulated-gating routing varies across forwards (the fork's
-        # load-testing intent) without threading a host counter through jit.
-        gate_seed = None if batch is None else jnp.sum(
-            jnp.where(batch["token_valid"], batch["token_pos"], 0)).astype(jnp.int32)
-        out = self._moes[li](h, gate_w, wi, wo, token_valid=token_valid,
-                             activation=jax.nn.silu, gate_seed=gate_seed)
+        out = self._moes[li](h, gate_w, wi, wo, activation=jax.nn.silu,
+                             **self._gating_inputs(batch))
         return x + out.astype(x.dtype)
+
+    @staticmethod
+    def _gating_inputs(batch):
+        """``token_valid`` and ``gate_seed`` of a step's batch, as ``RaggedMoE``
+        takes them. The seed is data-dependent: live token positions differ
+        every decode step, so simulated-gating routing varies across forwards
+        (the fork's load-testing intent) without threading a host counter
+        through jit."""
+        if batch is None:
+            return {"token_valid": None, "gate_seed": None}
+        return {"token_valid": batch["token_valid"],
+                "gate_seed": jnp.sum(jnp.where(batch["token_valid"], batch["token_pos"],
+                                               0)).astype(jnp.int32)}
 
     def layer_forward(self, params, li, x, cache, attn_fn, batch):
         x, cache = self._attn_phase(params, li, x, cache, attn_fn, batch)

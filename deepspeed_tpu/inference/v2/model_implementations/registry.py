@@ -36,10 +36,12 @@ def supported_model_types():
 
 
 def _register_builtin():
+    from deepspeed_tpu.models.afmoe import AfmoeConfig
     from deepspeed_tpu.models.decoder import DecoderConfig
     from deepspeed_tpu.models.llama import LlamaConfig
     from deepspeed_tpu.models.mellum import MellumConfig
     from deepspeed_tpu.models.mixtral import MixtralConfig
+    from deepspeed_tpu.inference.v2.model_implementations.afmoe_v2 import AfmoeV2Model
     from deepspeed_tpu.inference.v2.model_implementations.decoder_v2 import DecoderV2Model
     from deepspeed_tpu.inference.v2.model_implementations.llama_v2 import (LlamaV2Model,
                                                                            MistralV2Model,
@@ -55,6 +57,10 @@ def _register_builtin():
     # of many experts; dense MLP layers and other RoPE types are refused by the
     # config's constructor
     register_policy("mellum", MellumConfig, MellumV2Model)
+    # serving only: sigmoid-scored experts beside a shared one, leading dense
+    # layers, gated attention with q/k norm, rotary on the window layers alone;
+    # grouped top-k over expert groups is refused by the config's constructor
+    register_policy("afmoe", AfmoeConfig, AfmoeV2Model)
     register_policy("opt", DecoderConfig, DecoderV2Model)
     register_policy("falcon", DecoderConfig, DecoderV2Model)
     register_policy("phi", DecoderConfig, DecoderV2Model)

@@ -38,6 +38,12 @@ of their rows, one grouped matmul a projection —
 ``ops/pallas/grouped_matmul.py`` — and the rows summed back to their tokens;
 dropless whatever the skew, no capacity).
 
+What the router does is the model's to say and one step for every path
+(``_router_probs``, ``_choose``): scores by softmax over the experts or by a
+sigmoid of each (``score_func``), the ``top_k`` largest picked, of the scores or
+of score + a per-expert ``select_bias`` that picks and does not weigh, the
+chosen scores renormalised (``norm_topk_prob``) and scaled (``route_scale``).
+
 Named scopes (``jax.named_scope``, metadata only), the same four on both paths:
 ``route`` (router probabilities + capacity packing, or the sort),
 ``dispatch`` (tokens into the expert-major buffer), ``experts`` (the grouped
@@ -86,16 +92,24 @@ class RaggedMoE:
 
     def __init__(self, num_experts: int, top_k: int = 2, capacity_factor: float = 2.0,
                  expert_axis: str = groups.EXPERT_AXIS, layer_id: int = 0,
-                 norm_topk_prob: bool = True):
+                 norm_topk_prob: bool = True, score_func: str = "softmax",
+                 route_scale: float = 1.0):
         """``norm_topk_prob``: renormalise the ``top_k`` chosen probabilities to
         sum to 1, as the model states it (Mixtral does; a top-1 router that
-        weights by the raw probability passes False)."""
+        weights by the raw probability passes False). ``score_func``: how a
+        router logit becomes an expert's score, ``softmax`` over the experts or
+        ``sigmoid`` of each alone, in float32 either way. ``route_scale``
+        multiplies the routing weights after the renormalisation."""
+        if score_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"ragged MoE scores by softmax or sigmoid, not {score_func!r}")
         if not 1 <= top_k <= num_experts:
             raise ValueError(f"ragged MoE needs 1 <= top_k <= num_experts, got top_k={top_k} "
                              f"of {num_experts}")
         self.num_experts = num_experts
         self.top_k = top_k
         self.norm_topk_prob = bool(norm_topk_prob)
+        self.score_func = score_func
+        self.route_scale = float(route_scale)
         self.capacity_factor = capacity_factor
         self.expert_axis = expert_axis
         self.layer_id = layer_id
@@ -142,10 +156,32 @@ class RaggedMoE:
             logits = jnp.log(probs)[None, :] - jnp.log(-jnp.log(jnp.maximum(u, 1e-9)))
             return jax.nn.softmax(logits, axis=-1)
         logits = h.astype(jnp.float32) @ gate_w.astype(jnp.float32)
+        if self.score_func == "sigmoid":
+            return jax.nn.sigmoid(logits)
         return jax.nn.softmax(logits, axis=-1)
 
+    def _choose(self, probs, select_bias=None):
+        """The ``top_k`` experts of each token and their routing weights
+        ``[T, k]``: what all three arms share. With a ``select_bias`` [E] the
+        experts are the largest of score + bias and the weights their SCORES
+        (the bias picks, it does not weigh); then the renormalisation over the
+        chosen and the route scale, as the model states them."""
+        import jax
+        import jax.numpy as jnp
+        if select_bias is None:
+            topk_p, topk_e = jax.lax.top_k(probs, self.top_k)  # [T, k]
+        else:
+            _, topk_e = jax.lax.top_k(probs + select_bias.astype(probs.dtype), self.top_k)
+            topk_p = jnp.take_along_axis(probs, topk_e, axis=-1)
+        if self.norm_topk_prob:
+            denom = jnp.maximum(topk_p.sum(-1, keepdims=True), 1e-9)
+            topk_p = topk_p / denom  # renormalized over the chosen k (Mixtral's 2)
+        if self.route_scale != 1.0:
+            topk_p = topk_p * self.route_scale
+        return topk_p, topk_e
+
     # ------------------------------------------------------- capacity packing --
-    def _pack(self, probs, token_valid, C, dtype):
+    def _pack(self, probs, token_valid, C, dtype, select_bias=None):
         """Top-k assignment with capacity packing (reference moe_scatter).
 
         Returns combine [T, E, C] (f32 routing weights) and dispatch [T, E, C]
@@ -159,10 +195,7 @@ class RaggedMoE:
         T, E = probs.shape
         combine = jnp.zeros((T, E, C), jnp.float32)
         dispatch = jnp.zeros((T, E, C), dtype)
-        topk_p, topk_e = jax.lax.top_k(probs, self.top_k)  # [T, k]
-        if self.norm_topk_prob:
-            denom = jnp.maximum(topk_p.sum(-1, keepdims=True), 1e-9)
-            topk_p = topk_p / denom  # renormalized over the chosen k (Mixtral's 2)
+        topk_p, topk_e = self._choose(probs, select_bias)
         fill = self._fill_level_by_level if self.top_k <= 2 else self._fill_in_one_pass
         return fill(topk_p, topk_e, token_valid, C, combine, dispatch)
 
@@ -237,10 +270,12 @@ class RaggedMoE:
 
     # ----------------------------------------------------------------- forward --
     def __call__(self, h, gate_w, wi, wo, token_valid=None, activation=None, mesh=None,
-                 gate_seed=None):
+                 gate_seed=None, select_bias=None):
         """h: [T, M]; gate_w: [M, E]; wi: [E, M, F]; wo: [E, F, M] (the training
-        ExpertFFN bank layout — EP-shards on the leading dim). Dispatches to the
-        disaggregated shard_map path when the mesh has an expert axis > 1."""
+        ExpertFFN bank layout — EP-shards on the leading dim); ``select_bias``:
+        float32 [E] added to the scores to PICK the experts (:meth:`_choose`),
+        or None. Dispatches to the disaggregated shard_map path when the mesh
+        has an expert axis > 1."""
         import jax
 
         if activation is None:
@@ -253,18 +288,20 @@ class RaggedMoE:
         ep = int(mesh.shape.get(self.expert_axis, 1)) if mesh is not None else 1
         if ep > 1 and self.num_experts % ep == 0:
             return self._ep_forward(h, gate_w, wi, wo, token_valid, activation, mesh,
-                                    ep, gate_seed)
+                                    ep, gate_seed, select_bias)
         if ep > 1:
             from deepspeed_tpu.utils.logging import logger
             logger.warning(f"RaggedMoE: {self.num_experts} experts not divisible by EP "
                            f"degree {ep}; falling back to GSPMD expert-sharded compute "
                            f"(no token disaggregation)")
         if self.path(h.shape[0], wo.shape[-2], ep) == "grouped":
-            return self._grouped_forward(h, gate_w, wi, wo, token_valid, activation, gate_seed)
+            return self._grouped_forward(h, gate_w, wi, wo, token_valid, activation, gate_seed,
+                                         select_bias)
         return self._dense_forward(h, gate_w, wi, wo, token_valid, activation, gate_seed,
-                                   mesh if ep > 1 else None)
+                                   mesh if ep > 1 else None, select_bias)
 
-    def _grouped_forward(self, h, gate_w, wi, wo, token_valid, activation, gate_seed):
+    def _grouped_forward(self, h, gate_w, wi, wo, token_valid, activation, gate_seed,
+                         select_bias=None):
         """Single-replica path that routes by SORTING: the T x k assignments
         ordered by expert, one gather of their rows, one grouped matmul a
         projection (each expert's rows against its own bank), the routing
@@ -281,9 +318,7 @@ class RaggedMoE:
         rows = padded_rows(T * k)
         with jax.named_scope("route"):
             probs = self._router_probs(h, gate_w, gate_seed=gate_seed)  # [T, E] float32
-            topk_p, topk_e = jax.lax.top_k(probs, k)  # [T, k]
-            if self.norm_topk_prob:
-                topk_p = topk_p / jnp.maximum(topk_p.sum(-1, keepdims=True), 1e-9)
+            topk_p, topk_e = self._choose(probs, select_bias)  # [T, k]
             e_flat = topk_e.reshape(T * k)  # token-major: assignment a is token a // k
             if token_valid is not None:
                 e_flat = jnp.where(jnp.repeat(token_valid, k), e_flat, E)
@@ -321,7 +356,7 @@ class RaggedMoE:
                               visits=visits)
 
     def _dense_forward(self, h, gate_w, wi, wo, token_valid, activation, gate_seed,
-                       mesh=None):
+                       mesh=None, select_bias=None):
         """Single-replica path: all tokens local, no explicit collectives. When a
         degenerate EP mesh is passed (experts not divisible), the expert buffers
         are still constraint-sharded so GSPMD partitions the grouped GEMM."""
@@ -336,7 +371,7 @@ class RaggedMoE:
             probs = self._router_probs(h, gate_w, gate_seed=gate_seed)  # [T, E]
             if token_valid is not None:
                 probs = probs * token_valid[:, None]
-            combine, dispatch = self._pack(probs, token_valid, C, h.dtype)
+            combine, dispatch = self._pack(probs, token_valid, C, h.dtype, select_bias)
         with jax.named_scope("dispatch"):
             buf = jnp.einsum("tec,tm->ecm", dispatch, h)  # [E, C, M]
             if mesh is not None:
@@ -348,7 +383,8 @@ class RaggedMoE:
         with jax.named_scope("combine"):
             return jnp.einsum("tec,ecm->tm", combine.astype(h.dtype), out)
 
-    def _ep_forward(self, h, gate_w, wi, wo, token_valid, activation, mesh, ep, gate_seed):
+    def _ep_forward(self, h, gate_w, wi, wo, token_valid, activation, mesh, ep, gate_seed,
+                    select_bias=None):
         """Disaggregated EP: each replica owns T/ep tokens and its E/ep experts.
 
         The fork's data flow (cutlass_multi_gemm_ep.py):
@@ -378,12 +414,12 @@ class RaggedMoE:
         C = self.capacity(Tl)
         seed = jnp.asarray(0 if gate_seed is None else gate_seed, jnp.int32)
 
-        def body(h_l, gate_w, wi_l, wo_l, tv_l, seed_l):
+        def body(h_l, gate_w, wi_l, wo_l, tv_l, seed_l, *bias):
             with jax.named_scope("route"):
                 replica = jax.lax.axis_index(ax)
                 probs = self._router_probs(h_l, gate_w, gate_seed=seed_l, replica=replica)
                 probs = probs * tv_l[:, None]
-                combine, dispatch = self._pack(probs, tv_l, C, h_l.dtype)
+                combine, dispatch = self._pack(probs, tv_l, C, h_l.dtype, *bias)
             with jax.named_scope("dispatch"):
                 buf = jnp.einsum("tec,tm->ecm", dispatch, h_l)       # [E, C, M]
                 buf = buf.reshape(ep, El, C, M)                      # dest-replica major
@@ -399,8 +435,9 @@ class RaggedMoE:
                 ret = ret.reshape(E, C, M)                           # global-expert major
                 return jnp.einsum("tec,ecm->tm", combine.astype(h_l.dtype), ret)
 
+        bias = () if select_bias is None else (select_bias, )  # replicated, as the gate
         shmap = jax.shard_map(body, mesh=mesh,
-                              in_specs=(P(ax), P(), P(ax), P(ax), P(ax), P()),
+                              in_specs=(P(ax), P(), P(ax), P(ax), P(ax), P()) + (P(), ) * len(bias),
                               out_specs=P(ax), check_vma=False)
-        out = shmap(h, gate_w, wi, wo, token_valid, seed)
+        out = shmap(h, gate_w, wi, wo, token_valid, seed, *bias)
         return out[:T]

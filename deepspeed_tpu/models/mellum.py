@@ -87,8 +87,9 @@ class MellumConfig:
             raise ValueError(f"layer_types {unknown}: only {FULL!r} and {SLIDING!r} are served")
         if set(mlp_types) != {"sparse"}:
             raise NotImplementedError(
-                f"mlp_layer_types {sorted(set(mlp_types))}: only 'sparse' layers are "
-                f"implemented (a 'dense' layer would need the dense SwiGLU this tree lacks)")
+                f"mlp_layer_types {sorted(set(mlp_types))}: a Mellum stack is 'sparse' layers "
+                f"only (its parameter tree has no dense feed-forward; a model with dense layers "
+                f"in front of its expert layers is models/afmoe.py's)")
         if self.tie_word_embeddings or self.attention_bias:
             raise NotImplementedError("tied embeddings / attention biases are not implemented")
         if SLIDING in layer_types and self.sliding_window <= 0:

@@ -404,3 +404,73 @@ def test_mellum_decode_loop_program_fits_one_chip(v5e, mellum_model):
     assert "paged_attention_update" in text
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert not _pool_sized_results(text, cache.shape)
+
+
+# ---- sigmoid top-8 of 128 beside a shared expert, five layer groups (PR 34) ----
+TRINITY_LAYERS, TRINITY_POOL_BLOCKS, TRINITY_MAX_BLOCKS = 5, 26624, 64
+
+
+@pytest.fixture(scope="module")
+def trinity_model():
+    """``trinity-mini-serve-1chip``: Trinity-Mini's published widths, one dense
+    layer and four expert layers in the pattern s, s, s, f, s, contexts to
+    4096, over ``jax.eval_shape``d parameters."""
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.model_implementations.registry import model_cls_for
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import DSStateManagerConfig
+    from deepspeed_tpu.models import afmoe
+    cfg = afmoe.AfmoeConfig(num_hidden_layers=TRINITY_LAYERS, num_dense_layers=1,
+                            layer_types=afmoe.AfmoeConfig().layer_types[:TRINITY_LAYERS])
+    abstract = jax.eval_shape(lambda: afmoe.init_params(cfg, param_dtype=cfg.dtype)[1])
+    engine_config = RaggedInferenceEngineConfig(
+        state_manager=DSStateManagerConfig(max_context=TRINITY_MAX_BLOCKS * BS,
+                                           max_ragged_batch_size=256,
+                                           max_ragged_sequence_count=8), kv_block_size=BS,
+        expert_parallel={"capacity_factor": 16.0})
+    model = model_cls_for(cfg)(abstract, cfg, engine_config)
+    assert model.group_windows == (2048, 2048, 2048, 0, 2048) and model.head_dim == 128
+    return model, abstract
+
+
+def _trinity_args(device, model, abstract, bucket):
+    one = SingleDeviceSharding(device)
+    tokens, seqs, max_blocks = bucket
+    params = jax.tree.map(lambda leaf: _on(one, leaf.shape, leaf.dtype), abstract)
+    cache = _on(one, (1, 2, TRINITY_POOL_BLOCKS, 4, BS, 128), jnp.bfloat16)
+    batch = {"tok_meta": _on(one, (4, tokens), jnp.int32),
+             "seq_meta": _on(one, (seqs, 4 + model.kv_groups * max_blocks), jnp.int32)}
+    return one, params, cache, batch
+
+
+@pytest.mark.parametrize("bucket,kernel", [((8, 8, 64), "paged_attention_update"),
+                                           ((256, 8, 64), "paged_attention_prefill")],
+                         ids=["decode-bucket", "chunk-bucket"])
+def test_trinity_put_program_fits_one_chip(v5e, trinity_model, bucket, kernel):
+    """7.9 GiB of weights beside a 3.25 GiB pool that is aliased through, five
+    block tables a sequence; the full chunk routes by sorting over 128 groups
+    (a point the grouped-matmul kernel had not been compiled at), the decode
+    bucket keeps the ``[8, 128, 8]`` masks."""
+    model, abstract = trinity_model
+    assert model.attention_arm(bucket[0]) in ("paged_token", "paged_tiled")
+    _, params, cache, batch = _trinity_args(v5e[0], model, abstract, bucket)
+    compiled = jax.jit(model._forward_impl, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and kernel in text
+    assert _device_bytes(compiled) < 0.8 * HBM_BYTES
+    assert not _pool_sized_results(text, cache.shape)
+    grouped = model.moe_path(bucket[0]) == "grouped"
+    assert grouped == (bucket[0] == 256)
+    assert ("grouped_matmul" in text) == grouped
+    assert (f"[{bucket[0]},128,{bucket[0]}]" in text) != grouped
+
+
+def test_trinity_decode_loop_program_fits_one_chip(v5e, trinity_model):
+    model, abstract = trinity_model
+    one, params, cache, batch = _trinity_args(v5e[0], model, abstract, (8, 8, 64))
+    loop = functools.partial(model._decode_loop_impl, n_steps=8, sampled=False)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(
+        params, cache, batch, _on(one, (), jnp.float32), _on(one, (2, ), jnp.uint32)).compile()
+    text = compiled.as_text()
+    assert "paged_attention_update" in text
+    assert _device_bytes(compiled) < 0.8 * HBM_BYTES
+    assert not _pool_sized_results(text, cache.shape)
