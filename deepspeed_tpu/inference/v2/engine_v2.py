@@ -82,8 +82,9 @@ class InferenceEngineV2:
         # span (which reports the difference as ``released_blocks``)
         self._released_blocks = 0
         self._released_at_prepare = 0
-        # how the newest ``put`` step's program routed its tokens to experts
-        # (``grouped`` / ``capacity``; None for a dense model, or before any)
+        # how the newest ``put`` step's or ``decode_loop`` chunk's program
+        # routed its tokens to experts (``grouped`` / ``capacity``; None for a
+        # dense model, or before any)
         self.last_moe_path = None
 
     # ------------------------------------------------------------------ groups --
@@ -475,7 +476,13 @@ class InferenceEngineV2:
             if prep is not None:
                 prep["allocated_blocks"] = free_before - self._state_manager.free_blocks
 
+        n_padded = self._batch.device_batch["tok_meta"].shape[1]
+        self.last_moe_path = self._model.moe_path(n_padded)
         args = self._dispatch_args(spans, batch_uids, steps=n_steps)
+        if args is not None:
+            # a sparse model's moe_path, and the chunk's moe_rows and
+            # moe_assignments: every step of it routes this bucket
+            args.update(self._model.dispatch_counts(n_padded, len(batch_uids), n_steps))
         with _tel_live_span(spans, "decode_loop", "inference", args):
             if observer is not None:
                 _t0 = _tel_now_us()

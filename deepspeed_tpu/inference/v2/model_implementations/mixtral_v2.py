@@ -49,16 +49,16 @@ class MixtralV2Model(LlamaV2Model):
         the layers being alike)."""
         return self._moes[0].path(n_padded, self._expert_width, self._expert_parallel())
 
-    def dispatch_counts(self, n_padded, n_tokens):
+    def dispatch_counts(self, n_padded, n_tokens, steps=1):
         """``moe_rows``: rows the expert GEMMs compute this step on the path
         the bucket takes (``moe_path``), summed over the layers: every
         expert's every slot on the capacity path, a row a padded assignment on
         the grouped one; ``moe_assignments``: live tokens x top-k x layers,
-        what had to be."""
+        what had to be. Both over the ``steps`` of a ``decode_loop`` chunk."""
         ep, path = self._expert_parallel(), self.moe_path(n_padded)
         return {"moe_path": path,
-                "moe_rows": sum(m.expert_rows(n_padded, ep, path) for m in self._moes),
-                "moe_assignments": n_tokens * sum(m.top_k for m in self._moes)}
+                "moe_rows": steps * sum(m.expert_rows(n_padded, ep, path) for m in self._moes),
+                "moe_assignments": steps * n_tokens * sum(m.top_k for m in self._moes)}
 
     @property
     def num_layers(self):

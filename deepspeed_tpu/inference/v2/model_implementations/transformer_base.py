@@ -575,10 +575,11 @@ class DSTransformerModelBase:
             return batch["block_table"], li
         return batch["block_table"][:, li % groups], li // groups
 
-    def dispatch_counts(self, n_padded: int, n_tokens: int) -> dict:
-        """Work counters of one ``put`` step over an ``n_padded``-token bucket
-        holding ``n_tokens`` live tokens, for the step's span (sparse models:
-        the path to the experts, expert rows computed and assignments routed)."""
+    def dispatch_counts(self, n_padded: int, n_tokens: int, steps: int = 1) -> dict:
+        """Work counters of one ``put`` step, or of the ``steps`` of one
+        ``decode_loop`` chunk, over an ``n_padded``-token bucket holding
+        ``n_tokens`` live tokens, for the dispatch's span (sparse models: the
+        path to the experts, expert rows computed and assignments routed)."""
         return {}
 
     def moe_path(self, n_padded: int):

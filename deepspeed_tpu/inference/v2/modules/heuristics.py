@@ -65,20 +65,34 @@ MOE_MASK_SHARE_MIN = 1 / 20
 # ([tokens, experts, capacity]; a float32 and an activation-dtype one) their
 # fill is microseconds, less than a sort and two gathers of the rows.
 MOE_MASK_ELEMENTS_MIN = 1 << 20
+# The other thing the capacity path pays for is the BANKS: its einsums multiply
+# every expert's matrices whatever was routed. A bucket whose assignments
+# (tokens x top-k) number at most half the experts cannot touch more than half
+# of the banks, whatever the router does, and the grouped kernel reads only the
+# banks that have rows: a decode step's 8 rows at top-8 of 128 touch ~52 banks
+# a layer, 0.94 ms of both projections where the einsums over all 128 take
+# 2.15 (a TPU v5e; PERF.md section 6, PR 35). The bound is the guaranteed one,
+# not the expected share under some router: Mellum's 8 rows at top-8 of 64
+# (~42 banks expected, 64 possible) stay on the capacity path.
+MOE_BANKS_TOUCHED_MAX = 1 / 2
 
 
-def moe_implementation(tokens: int, num_experts: int, capacity: int, intermediate: int,
-                       expert_parallel: int = 1) -> str:
+def moe_implementation(tokens: int, num_experts: int, top_k: int, capacity: int,
+                       intermediate: int, expert_parallel: int = 1) -> str:
     """How a ``tokens``-token bucket reaches its experts: ``"grouped"`` (rows
-    sorted by expert, one grouped matmul a projection, dropless whatever the
-    skew) or ``"capacity"`` (``[tokens, experts, capacity]`` one-hot masks into
-    static per-expert buffers). A pure function of static shapes: ``capacity``
-    is what the capacity path would give an expert for this bucket,
-    ``intermediate`` the experts' width F, ``expert_parallel`` the size of the
-    mesh's expert axis (its two all-to-alls need the static per-destination
-    buffers, so anything over 1 answers ``capacity``)."""
+    sorted by expert, one grouped matmul a projection that reads only the banks
+    that have rows, dropless whatever the skew) or ``"capacity"``
+    (``[tokens, experts, capacity]`` one-hot masks into static per-expert
+    buffers, every bank multiplied). A pure function of static shapes:
+    ``top_k`` experts a token, ``capacity`` what the capacity path would give
+    an expert for this bucket, ``intermediate`` the experts' width F,
+    ``expert_parallel`` the size of the mesh's expert axis (its two
+    all-to-alls need the static per-destination buffers, so anything over 1
+    answers ``capacity``)."""
     if expert_parallel > 1:
         return "capacity"
+    if tokens * top_k <= num_experts * MOE_BANKS_TOUCHED_MAX:
+        return "grouped"
     mask_share = 2 * tokens / (3 * intermediate)
     mask_elements = tokens * num_experts * capacity
     if mask_share >= MOE_MASK_SHARE_MIN and mask_elements >= MOE_MASK_ELEMENTS_MIN:

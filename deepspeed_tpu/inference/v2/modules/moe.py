@@ -31,12 +31,16 @@ route differently) and the EP body folds in the replica index.
 On ONE replica a bucket reaches its experts by one of two paths, chosen from
 static shapes by ``heuristics.moe_implementation``: the capacity path
 (``_dense_forward``: the same ``[tokens, experts, capacity]`` one-hot masks as
-under EP, into static per-expert buffers) or, where those masks would cost a
-real share of the experts (many narrow experts, a full chunk of tokens), the
-grouped path (``_grouped_forward``: the assignments sorted by expert, one gather
-of their rows, one grouped matmul a projection —
+under EP, into static per-expert buffers, every expert's bank multiplied) or
+the grouped path (``_grouped_forward``: the assignments sorted by expert, one
+gather of their rows, one grouped matmul a projection —
 ``ops/pallas/grouped_matmul.py`` — and the rows summed back to their tokens;
-dropless whatever the skew, no capacity).
+dropless whatever the skew, no capacity, and no bank read that has no row).
+The grouped path is taken where the masks would cost a real share of the
+experts (many narrow experts, a full chunk of tokens), and where the bucket's
+assignments cannot touch more than half of the banks (a decode step's 8 rows
+at top-8 of 128, also inside ``decode_loop``'s scan: the step then streams the
+~52 banks it routed to and not all 128).
 
 What the router does is the model's to say and one step for every path
 (``_router_probs``, ``_choose``): scores by softmax over the experts or by a
@@ -124,8 +128,8 @@ class RaggedMoE:
         """``grouped`` or ``capacity``: the path a ``tokens``-token bucket takes
         through experts ``intermediate`` wide (``modules/heuristics.py``)."""
         from deepspeed_tpu.inference.v2.modules.heuristics import moe_implementation
-        return moe_implementation(tokens, self.num_experts, self.capacity(tokens), intermediate,
-                                  ep)
+        return moe_implementation(tokens, self.num_experts, self.top_k, self.capacity(tokens),
+                                  intermediate, ep)
 
     def expert_rows(self, tokens: int, ep: int = 1, path: str = "capacity") -> int:
         """Rows the expert GEMMs compute for a ``tokens``-token bucket, live or

@@ -14,8 +14,10 @@ rows, and the visits are laid out group-major from ``group_sizes``
 The grid is (column block, visit) with the visit's group and row tile read by
 scalar prefetch: the whole contraction is one block (``tk = K``), so a group's
 ``[K, tn]`` block of the bank stays in VMEM across its row tiles and a row
-tile stays across the groups that share it — every byte of the bank is read
-once. A visit multiplies the whole row tile and stores only its group's rows.
+tile stays across the groups that share it — every byte of a group's bank is
+read once, and a group without rows is no visit: its bank is not read at all
+(the visit dimension of the grid is the dynamic count of visits).
+A visit multiplies the whole row tile and stores only its group's rows.
 The bank is an operand: one kernel a projection serves every layer of a
 program.
 
@@ -25,6 +27,18 @@ Measured at Mellum-2's shapes (2,048 rows over 64 groups, banks
 1.36 at 512 and 768, 1.49 at 256, 10.4 with the contraction cut into 128s, and
 5.15 for ``jax.lax.ragged_dot`` as XLA's TPU backend lowers it; the padded
 einsums of the capacity path take 1.55.
+
+Measured at a decode step's shape (PR 35; Trinity-Mini's 8 rows x top-8 = 64
+assignments in ONE 128-row tile over 128 groups, banks ``[128, 2048, 2048]``
+and ``[128, 1024, 2048]`` bf16, column blocks of 1024 = bank tiles of 4 and
+2 MiB; PERF.md section 6, PR 35): both projections 0.94 ms a layer with 53
+groups touched (a random router's), 1.13 with 64, 2.21 with a row in each of
+the 128 and 0.04 with all rows in one: 710-730 GB/s of the TOUCHED banks'
+bytes whatever their number, where the capacity path's einsums over all 128
+banks take 2.15. A row tile of 64 or 32 for the same rows reads the same
+(0.94, 0.94): a visit's matmul hides behind its bank tile's DMA, so the tile
+stays 128 for every bucket. Inside ``decode_loop``'s ``lax.scan`` the kernel
+runs as it does outside.
 
 Everywhere else (the CPU that the tests run on) it is ``jax.lax.ragged_dot``.
 """

@@ -207,7 +207,8 @@ class ServingScheduler:
                            "parks", "rehydrates", "fair_share_shed",
                            "device_draws", "host_draws",
                            "put_steps", "pipelined_steps", "overrun_rows",
-                           "moe_grouped_steps", "moe_capacity_steps")
+                           "moe_grouped_steps", "moe_capacity_steps",
+                           "moe_grouped_chunks", "moe_capacity_chunks")
                           + tuple(f"drained_steps_{r}" for r in _DRAIN_REASONS)}
         # the put step on the device that no tick has fetched yet (step()),
         # why the newest fetched step was fetched before its successor was
@@ -1957,6 +1958,7 @@ class ServingScheduler:
             except SchedulingError:
                 rows = None  # KV too tight for K steps — single-step fallback
             if rows is not None:
+                self._count_moe_path("chunks")
                 self._synchronous_tick("decode_loop", "decode_loop")
                 with self._emit_phase(spans):
                     # record before pushing: the final token finalizes the
@@ -1981,10 +1983,7 @@ class ServingScheduler:
         if step is None:
             return
         self._counters["put_steps"] += 1
-        moe_path = getattr(self._engine, "last_moe_path", None)
-        if moe_path is not None:
-            # a sparse model's step: by the path its bucket routes on
-            self._counters[f"moe_{moe_path}_steps"] += 1
+        self._count_moe_path("steps")
         prev, self._inflight = self._inflight, step
         if tick is not None:
             tick["pipelined"] = int(prev is not None)
@@ -1998,6 +1997,14 @@ class ServingScheduler:
             self._sync("open")
         elif self._stopping:
             self._sync("stop")
+
+    def _count_moe_path(self, unit: str) -> None:
+        """A sparse model's ``put`` step (``unit`` ``steps``) or ``decode_loop``
+        chunk (``chunks``) just dispatched: counted by the path its bucket
+        routes on (``moe_grouped_<unit>`` / ``moe_capacity_<unit>``)."""
+        moe_path = getattr(self._engine, "last_moe_path", None)
+        if moe_path is not None:
+            self._counters[f"moe_{moe_path}_{unit}"] += 1
 
     def _chunk_steps(self, plan) -> int:
         """K if ``plan`` runs as one ``engine.decode_loop`` chunk of K steps

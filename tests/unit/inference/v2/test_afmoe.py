@@ -61,6 +61,14 @@ def model():
     return _model()
 
 
+# top-4 of 64: a decode bucket's 8 rows x 4 = 32 assignments can touch at most
+# half of the banks, so it routes by sorting (PR 35); a 32-token bucket cannot
+# say so and keeps the masks. ``SIZES``' top-4 of 16 keeps them in every bucket.
+@pytest.fixture(scope="module")
+def wide_model():
+    return _model(num_experts=64)
+
+
 def _engine(model, kernel=False, blocks=400, budget=FEED, block=BLOCK, capacity_factor=4.0,
             max_context=128):
     groups.initialize_mesh(force=True)
@@ -187,6 +195,51 @@ def test_decode_loop_crosses_the_window_while_decoding_and_the_window_groups_rel
     assert full[10:23].tolist() == want[:13].argmax(-1).tolist()
     last = np.asarray(engine.put([0], [full[22:23]]))[0]
     np.testing.assert_allclose(last, want[13], atol=ATOL, rtol=0)
+
+
+def _prefill_then_loops(engine, prompts, loops, steps):
+    """Each prompt in one ``put``, then ``loops`` chunks of ``steps`` greedy
+    tokens for all of them together; the logits of every prompt's last row and
+    each sequence's prompt + generated tokens."""
+    first = [np.asarray(engine.put([u], [p]))[0] for u, p in enumerate(prompts)]
+    uids = list(range(len(prompts)))
+    fed = [int(f.argmax()) for f in first]
+    full = [p.tolist() for p in prompts]
+    for _ in range(loops):
+        out = np.asarray(engine.decode_loop(uids, [np.array([t], np.int32) for t in fed], steps))
+        for u in uids:
+            full[u] += [fed[u]] + out[u, :-1].tolist()
+            fed[u] = int(out[u, -1])
+    last = np.asarray(engine.put(uids, [np.array([t], np.int32) for t in fed]))
+    return first, [np.asarray(f + [t], np.int32) for f, t in zip(full, fed)], last
+
+
+@pytest.mark.parametrize("live", [8, 5], ids=["8 live rows", "5 live rows of 8"])
+def test_a_decode_loop_on_the_grouped_path_matches_the_reference_and_the_capacity_path(
+        wide_model, live, monkeypatch):
+    """The 8-row decode bucket of a top-4-of-64 model routes by sorting inside
+    ``decode_loop``'s scan (and in the 8-token ``put`` behind it): greedy tokens
+    are the float32 reference's, the logits within ``ATOL`` of it, and equal
+    to the capacity path's at the dropless factor."""
+    from deepspeed_tpu.inference.v2.modules import heuristics
+    prompts = [_ids(40 + u, 5 + 2 * u) for u in range(live)]
+
+    def run():
+        engine = _engine(wide_model, capacity_factor=64 / 4)  # dropless on the masks too
+        return engine, _prefill_then_loops(engine, prompts, loops=2, steps=4)
+
+    engine, (first, full, last) = run()
+    assert engine.model.moe_path(8) == "grouped" and engine.model.moe_path(32) == "capacity"
+    for u, (prompt, ids) in enumerate(zip(prompts, full)):
+        want = _reference_rows(wide_model, ids, range(prompt.size - 1, ids.size))
+        np.testing.assert_allclose(first[u], want[0], atol=ATOL, rtol=0)
+        assert ids[prompt.size:].tolist() == want[:-1].argmax(-1).tolist()
+        np.testing.assert_allclose(last[u], want[-1], atol=ATOL, rtol=0)
+    monkeypatch.setattr(heuristics, "MOE_BANKS_TOUCHED_MAX", 0)  # the rule before PR 35
+    masks, (_, full_masks, last_masks) = run()
+    assert masks.model.moe_path(8) == "capacity"
+    assert [f.tolist() for f in full_masks] == [f.tolist() for f in full]
+    np.testing.assert_allclose(last, last_masks, atol=1e-5, rtol=0)
 
 
 def test_sequences_on_both_sides_of_the_window_share_a_batch(model):
@@ -535,8 +588,12 @@ def test_the_initialiser_is_seeded_and_scales_what_writes_into_the_stream(model)
 
 
 # (d) ------------------------------------------------- spans, counters, serving ---
-def test_put_and_decode_loop_spans_carry_the_routed_work_and_nothing_of_the_models_shape(model):
+@pytest.mark.parametrize("which, loop_path", [("model", "capacity"), ("wide_model", "grouped")],
+                         ids=["top-4 of 16", "top-4 of 64"])
+def test_put_and_decode_loop_spans_carry_the_routed_work_and_nothing_of_the_models_shape(
+        request, which, loop_path):
     from deepspeed_tpu import telemetry
+    model = request.getfixturevalue(which)
     session = telemetry.configure({"enabled": True, "compile_watch": False})
     try:
         engine = _engine(model)
@@ -549,6 +606,12 @@ def test_put_and_decode_loop_spans_carry_the_routed_work_and_nothing_of_the_mode
         # live tokens x top-k x expert layers (the dense layer routes nothing)
         assert put["args"]["moe_assignments"] == 20 * 4 * 4 and put["args"]["moe_path"] == "capacity"
         assert put["args"]["tokens"] == 20 and loop["args"]["steps"] == 4
+        # a chunk: its bucket's path, and the work of its steps together
+        assert loop["args"]["moe_path"] == loop_path == engine.last_moe_path
+        assert loop["args"]["moe_assignments"] == 1 * 4 * 4 * 4  # rows x top-k x layers x steps
+        moe = engine.model._moes[0]
+        assert loop["args"]["moe_rows"] == 4 * 4 * (
+            128 if loop_path == "grouped" else moe.num_experts * moe.capacity(8))
         # a constant of the configuration times the tokens is no span arg
         for span in (put, loop):
             assert not {"shared_rows", "dense_layers"} & set(span["args"])
@@ -556,8 +619,14 @@ def test_put_and_decode_loop_spans_carry_the_routed_work_and_nothing_of_the_mode
         telemetry.shutdown()
 
 
-def test_the_serving_scheduler_serves_it_past_the_window(model):
+@pytest.mark.parametrize("which, chunk_path, other", [("model", "capacity", "grouped"),
+                                                      ("wide_model", "grouped", "capacity")],
+                         ids=["top-4 of 16", "top-4 of 64"])
+def test_the_serving_scheduler_serves_it_past_the_window(request, which, chunk_path, other):
+    """... and counts its ``decode_loop`` chunks by the path their bucket
+    routes on, beside the ``put`` steps."""
     from deepspeed_tpu.serving import ServingConfig, ServingScheduler
+    model = request.getfixturevalue(which)
     engine = _engine(model)
     scheduler = ServingScheduler(engine, ServingConfig(decode_chunk=4))
     try:
@@ -579,6 +648,10 @@ def test_the_serving_scheduler_serves_it_past_the_window(model):
         want = _reference_rows(model, full, range(prompt.size - 1, full.size - 1))
         assert toks == want.argmax(-1).tolist()
     assert counters["put_steps"] > 0 and counters["moe_capacity_steps"] > 0
+    assert counters["moe_grouped_steps"] + counters["moe_capacity_steps"] == counters["put_steps"]
+    # (``drained_steps_decode_loop`` also counts a put step fetched for a chunk to follow)
+    assert 2 <= counters[f"moe_{chunk_path}_chunks"] <= counters["drained_steps_decode_loop"]
+    assert counters[f"moe_{other}_chunks"] == 0
 
 
 def test_what_needs_one_whole_block_table_refuses(model):

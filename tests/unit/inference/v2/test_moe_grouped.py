@@ -1,9 +1,10 @@
 """``RaggedMoE``'s grouped path (PR 32): on one replica, where
 ``heuristics.moe_implementation`` says so, the assignments are sorted by expert
 and the experts are one grouped matmul a projection: no ``[tokens, experts,
-capacity]`` mask, no capacity, nothing dropped. Held here, in float32 on the
-CPU, to a dense reference and to the capacity path; the rule is held as a table
-over the buckets the benchmark's configurations warm, and at Mixtral's
+capacity]`` mask, no capacity, nothing dropped, and no bank read that has no
+row (PR 35: a decode step's 8 rows at top-8 of 128). Held here, in float32 on
+the CPU, to a dense reference and to the capacity path; the rule is held as a
+table over the buckets the benchmark's configurations warm, and at Mixtral's
 PUBLISHED shapes ``RaggedMoE.__call__`` must trace what the capacity path
 traces (every program the Mixtral cells run is then the parent's)."""
 
@@ -168,6 +169,7 @@ def test_the_sorted_buffer_pads_to_whole_row_tiles():
 # ------------------------------------------------------------------ the rule ---
 MIXTRAL = dict(E=8, k=2, F=14336, M=4096, factor=4.0)   # mixtral-8x7b-serve-1chip
 MELLUM = dict(E=64, k=8, F=896, M=2304, factor=8.0)     # mellum2-12b-a2.5b-serve-1chip
+TRINITY = dict(E=128, k=8, F=1024, M=2048, factor=16.0)  # trinity-mini-serve-1chip
 
 
 def _path(sizes, tokens, ep=1):
@@ -186,6 +188,15 @@ def test_mellums_full_chunks_take_the_grouped_path(tokens, want):
     assert _path(MELLUM, tokens) == want
 
 
+@pytest.mark.parametrize("tokens,want", [(8, "grouped"), (16, "capacity"), (32, "capacity"),
+                                         (64, "capacity"), (128, "grouped"), (256, "grouped")])
+def test_trinitys_decode_bucket_and_its_full_chunks_take_the_grouped_path(tokens, want):
+    """8 rows x top-8 = 64 assignments cannot touch more than half of the 128
+    banks; 16 rows can touch them all; the full chunks are PR 32's clause."""
+    assert tokens in token_buckets(256)
+    assert _path(TRINITY, tokens) == want
+
+
 def test_the_buckets_are_the_ones_the_table_is_about():
     assert token_buckets(256)[-2:] == [128, 256] and 64 in token_buckets(256)
 
@@ -196,17 +207,32 @@ def test_an_expert_mesh_axis_takes_the_capacity_path(sizes, ep):
     assert _path(sizes, 256, ep) == "capacity"
 
 
-def test_the_rule_is_two_thresholds_on_static_shapes():
-    """The masks' share of the experts' flops (2T / 3F) and the masks' size."""
-    E, C = 64, 256
+@pytest.mark.parametrize("ep", [2, 4, 8])
+def test_an_expert_mesh_axis_keeps_trinitys_decode_bucket_on_the_capacity_path(ep):
+    assert _path(TRINITY, 8) == "grouped" and _path(TRINITY, 8, ep) == "capacity"
+
+
+def test_the_rule_is_three_clauses_on_static_shapes():
+    """One replica; the masks' share of the experts' flops (2T / 3F) with the
+    masks' size; and the banks a bucket's assignments can touch at all."""
+    E, k, C = 64, 8, 256
     big = heuristics.MOE_MASK_ELEMENTS_MIN
-    assert moe_implementation(256, E, C, 896) == "grouped"
+    assert moe_implementation(256, E, k, C, 896) == "grouped"
     # experts so wide that the masks are under a twentieth of them
-    assert moe_implementation(256, E, C, 2 * 256 * 20 // 3 + 1) == "capacity"
-    assert moe_implementation(256, E, C, 2 * 256 * 20 // 3) == "grouped"
+    assert moe_implementation(256, E, k, C, 2 * 256 * 20 // 3 + 1) == "capacity"
+    assert moe_implementation(256, E, k, C, 2 * 256 * 20 // 3) == "grouped"
     # masks too small to be worth a sort
-    assert moe_implementation(128, 4, 128, 128) == "capacity"
+    assert moe_implementation(128, 4, 2, 128, 128) == "capacity"
     assert 128 * 4 * 128 < big <= 128 * 64 * 128
+    # the banks: both sides of tokens x top_k = experts / 2, whatever the width
+    for F in (128, 1024, 14336):
+        assert moe_implementation(8, 128, 8, 8, F) == "grouped"      # 64 = 128 / 2
+        assert moe_implementation(8, 126, 8, 8, F) == "capacity"     # 64 > 63
+        assert moe_implementation(9, 128, 8, 9, F) == "capacity"     # 72 > 64
+        assert moe_implementation(16, 128, 4, 8, F) == "grouped"     # 64 again, by another way
+        assert moe_implementation(8, 128, 8, 8, F, expert_parallel=2) == "capacity"
+    assert moe_implementation(8, 64, 8, 8, 896) == "capacity"        # Mellum's decode bucket
+    assert moe_implementation(8, 8, 2, 8, 14336) == "capacity"       # Mixtral's
 
 
 # -------------------------------------------- Mixtral's programs stay as they are ---
@@ -274,6 +300,65 @@ def test_the_visits_cover_every_groups_rows_once_group_major(sizes):
     assert groups.shape == tiles.shape == (R // 128 + G - 1, )
     assert list(zip(groups[:n].tolist(), tiles[:n].tolist())) == want
     assert (tiles >= 0).all() and (tiles < R // 128).all() and (groups < G).all()
+
+
+def _decode_step_sizes(kind, G=128, assignments=64):
+    """Group sizes of a decode step's (at most) 64 assignments over 128 groups."""
+    rng = np.random.default_rng(len(kind))
+    sizes = np.zeros(G, np.int32)
+    # live rows, each the top-8 of a random order: 8 touch ~52 distinct groups;
+    # with 3 the rest of the row tile is nobody's
+    live = {"routed": 8, "few-live": 3}.get(kind)
+    if live:
+        np.add.at(sizes, np.concatenate([rng.permutation(G)[:8] for _ in range(live)]), 1)
+    elif kind == "one-group":
+        sizes[G // 3] = assignments
+    else:  # "distinct"
+        sizes[rng.permutation(G)[:assignments]] = 1
+    return sizes
+
+
+DECODE_KINDS = ["routed", "one-group", "distinct", "few-live"]
+
+
+@pytest.mark.parametrize("kind", DECODE_KINDS)
+def test_a_decode_steps_schedule_visits_each_group_that_has_rows_once(kind):
+    """64 assignments pad to ONE row tile: a visit a non-empty group, in group
+    order, and the grid's dynamic extent is the number of distinct groups, so
+    an untouched group's bank is no visit's operand."""
+    sizes = _decode_step_sizes(kind)
+    R = padded_rows(64)
+    assert R == 128
+    _, groups, tiles, n = (np.asarray(a) for a in group_visits(jnp.asarray(sizes), R))
+    touched = np.flatnonzero(sizes)
+    assert int(n) == touched.size <= 64
+    assert {"one-group": 1, "distinct": 64}.get(kind, int(n)) == int(n)
+    np.testing.assert_array_equal(groups[:n], touched)
+    assert not tiles.any() and groups.shape == (1 + 128 - 1, )
+
+
+@pytest.mark.parametrize("kind", DECODE_KINDS)
+def test_the_kernel_at_a_decode_steps_shape_is_the_xla_arm_and_reads_no_untouched_bank(kind):
+    """Interpret mode against ``jax.lax.ragged_dot`` for 64 assignments over
+    128 groups, most of them empty; then every UNTOUCHED bank filled with NaN:
+    the kernel's output on the covered rows stays what it was (an empty
+    group's bank is never multiplied in: the capacity path's einsums would
+    spread the NaN over every row)."""
+    sizes = _decode_step_sizes(kind)
+    rng = np.random.default_rng(7)
+    R, K, N, G = 128, 128, 256, sizes.size
+    rows = jnp.asarray(rng.normal(size=(R, K)), jnp.float32)
+    bank = jnp.asarray(rng.normal(size=(G, K, N)) / np.sqrt(K), jnp.float32)
+    group_sizes = jnp.asarray(sizes)
+    covered = int(sizes.sum())
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(grouped_matmul(rows, bank, group_sizes, jnp.float32, interpret=True))
+        want = np.asarray(grouped_matmul(rows, bank, group_sizes, jnp.float32))
+        poisoned = jnp.where((group_sizes > 0)[:, None, None], bank, jnp.nan)
+        alone = np.asarray(grouped_matmul(rows, poisoned, group_sizes, jnp.float32, interpret=True))
+    np.testing.assert_allclose(got[:covered], want[:covered], atol=1e-5, rtol=0)
+    assert np.isfinite(alone[:covered]).all()
+    np.testing.assert_array_equal(alone[:covered], got[:covered])
 
 
 @pytest.mark.parametrize("sizes", [(37, 0, 91, 128), (256, 0, 0, 0), (10, 20, 30, 40)],

@@ -443,13 +443,15 @@ def _trinity_args(device, model, abstract, bucket):
 
 
 @pytest.mark.parametrize("bucket,kernel", [((8, 8, 64), "paged_attention_update"),
+                                           ((32, 8, 64), "paged_attention_update"),
                                            ((256, 8, 64), "paged_attention_prefill")],
-                         ids=["decode-bucket", "chunk-bucket"])
+                         ids=["decode-bucket", "tail-bucket", "chunk-bucket"])
 def test_trinity_put_program_fits_one_chip(v5e, trinity_model, bucket, kernel):
     """7.9 GiB of weights beside a 3.25 GiB pool that is aliased through, five
     block tables a sequence; the full chunk routes by sorting over 128 groups
-    (a point the grouped-matmul kernel had not been compiled at), the decode
-    bucket keeps the ``[8, 128, 8]`` masks."""
+    and so does the decode bucket (64 assignments in one row tile, at most 64
+    of the 128 banks visited: PR 35), the 32-token tail of a prompt keeps the
+    ``[32, 128, 32]`` masks."""
     model, abstract = trinity_model
     assert model.attention_arm(bucket[0]) in ("paged_token", "paged_tiled")
     _, params, cache, batch = _trinity_args(v5e[0], model, abstract, bucket)
@@ -459,18 +461,20 @@ def test_trinity_put_program_fits_one_chip(v5e, trinity_model, bucket, kernel):
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert not _pool_sized_results(text, cache.shape)
     grouped = model.moe_path(bucket[0]) == "grouped"
-    assert grouped == (bucket[0] == 256)
+    assert grouped == (bucket[0] != 32)
     assert ("grouped_matmul" in text) == grouped
     assert (f"[{bucket[0]},128,{bucket[0]}]" in text) != grouped
 
 
 def test_trinity_decode_loop_program_fits_one_chip(v5e, trinity_model):
+    """The grouped kernel with its dynamic visit count inside the loop's scan."""
     model, abstract = trinity_model
     one, params, cache, batch = _trinity_args(v5e[0], model, abstract, (8, 8, 64))
     loop = functools.partial(model._decode_loop_impl, n_steps=8, sampled=False)
     compiled = jax.jit(loop, donate_argnums=(1, )).lower(
         params, cache, batch, _on(one, (), jnp.float32), _on(one, (2, ), jnp.uint32)).compile()
     text = compiled.as_text()
-    assert "paged_attention_update" in text
+    assert "paged_attention_update" in text and "grouped_matmul" in text
+    assert "[8,128,8]" not in text  # no mask of the capacity path
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert not _pool_sized_results(text, cache.shape)
