@@ -86,6 +86,13 @@ class InferenceEngineV2:
         # routed its tokens to experts (``grouped`` / ``capacity``; None for a
         # dense model, or before any)
         self.last_moe_path = None
+        # under a telemetry session, what the fetch of the newest ``put`` step
+        # reports of its experts where its bucket routes by sorting:
+        # ``moe_path``, ``moe_assignments`` and ``moe_banks``, the last the
+        # step's own count, int32 [expert layers], a device array whose copy
+        # to the host is under way; else None, and nothing more than the
+        # step's result is ever fetched
+        self.last_moe_fetch = None
 
     # ------------------------------------------------------------------ groups --
     def _initialize_comm_groups(self) -> None:
@@ -181,7 +188,9 @@ class InferenceEngineV2:
     # ``put`` / ``decode_loop`` / ``verify_tree`` time the DISPATCH
     # of that call (plus, where the method itself fetches, the fetch) — not the
     # device: JAX returns before the device finishes, and the caller's
-    # ``np.asarray`` is where the wait shows.
+    # ``np.asarray`` is where the wait shows. A ``decode_loop`` span says which
+    # part is which: ``launch_us`` (entry until the jitted call has returned)
+    # and ``fetch_us`` (the blocking transfer of the tokens).
     def _prepare_forward(self, spans, batch_uids, feeds, do_checks, n_tokens, trees=None):
         """The host side of one ragged forward, under the ``prepare`` span:
         admission check, restore of offloaded sequences, KV allocation and the
@@ -323,11 +332,13 @@ class InferenceEngineV2:
         # how the bucket's program routes its tokens to experts (grouped /
         # capacity; None for a dense model): the scheduler counts steps by it
         self.last_moe_path = self._model.moe_path(n_padded)
+        self.last_moe_fetch = None
         args = self._dispatch_args(spans, batch_uids, tokens=n_tokens)
         if args is not None:
             # the arm the bucket's program takes (modules/heuristics.py):
             # paged_tiled / paged_token / xla_gather; a sparse model's
-            # moe_path, moe_rows and moe_assignments
+            # moe_path, moe_rows and moe_assignments (and, on the capacity
+            # path, moe_banks: every bank)
             args["attention"] = self._model.attention_arm(n_padded)
             args.update(self._model.dispatch_counts(n_padded, n_tokens))
         if prev is not None:
@@ -349,6 +360,15 @@ class InferenceEngineV2:
                 out = self._model.forward_draw(self._batch, *draw, prev=prev)
             if observer is not None:
                 observer("put", len(batch_uids), n_tokens, (_tel_now_us() - _t0) / 1e6)
+            if args is not None and self._model.last_moe_banks is not None:
+                # a grouped step's count of banks touched is the device's to
+                # say: its transfer started now, behind the step, and handed
+                # over unread for the span of the step's fetch
+                banks = self._model.last_moe_banks
+                banks.copy_to_host_async()
+                self.last_moe_fetch = {"moe_path": args["moe_path"],
+                                       "moe_assignments": args["moe_assignments"],
+                                       "moe_banks": banks}
             self._post_forward(batch_uids)
         if metrics is not None:
             self._write_telemetry(metrics, batch_tokens=n_tokens)
@@ -484,10 +504,23 @@ class InferenceEngineV2:
             # moe_assignments: every step of it routes this bucket
             args.update(self._model.dispatch_counts(n_padded, len(batch_uids), n_steps))
         with _tel_live_span(spans, "decode_loop", "inference", args):
-            if observer is not None:
+            if observer is not None or spans is not None:
                 _t0 = _tel_now_us()
-            tokens = self._model.decode_loop(self._batch, n_steps, temperature=temperature,
-                                             rng=rng)  # [n_steps, S_bucket]
+            tokens, banks = self._model.decode_loop(self._batch, n_steps,
+                                                    temperature=temperature, rng=rng)
+            if spans is not None:
+                launched = _tel_now_us()
+                if banks is not None:
+                    banks.copy_to_host_async()  # rides behind the tokens, not after them
+            tokens = np.asarray(tokens)  # [n_steps, S_bucket]: the wait for the device
+            if spans is not None:
+                # the call's two parts (the ring keeps args as they are at exit)
+                args["launch_us"] = launched - _t0
+                args["fetch_us"] = _tel_now_us() - launched
+                if banks is not None:
+                    # the banks the chunk's routing touched, over its steps and
+                    # expert layers: 4 bytes a layer-step, on the host by now
+                    args["moe_banks"] = int(np.asarray(banks).sum())
             if observer is not None:
                 observer("decode_loop", len(batch_uids),
                          len(batch_uids) * n_steps, (_tel_now_us() - _t0) / 1e6)

@@ -54,11 +54,19 @@ class MixtralV2Model(LlamaV2Model):
         the bucket takes (``moe_path``), summed over the layers: every
         expert's every slot on the capacity path, a row a padded assignment on
         the grouped one; ``moe_assignments``: live tokens x top-k x layers,
-        what had to be. Both over the ``steps`` of a ``decode_loop`` chunk."""
+        what had to be. Both over the ``steps`` of a ``decode_loop`` chunk. On
+        the capacity path also ``moe_banks``, the expert banks the GEMMs read:
+        every expert of every expert layer, every step. On the grouped path
+        that count is the routing's, out of the device with the step's result
+        (``RaggedMoE.__call__``'s ``banks_out``), and whoever fetches the
+        result adds it."""
         ep, path = self._expert_parallel(), self.moe_path(n_padded)
-        return {"moe_path": path,
-                "moe_rows": steps * sum(m.expert_rows(n_padded, ep, path) for m in self._moes),
-                "moe_assignments": steps * n_tokens * sum(m.top_k for m in self._moes)}
+        counts = {"moe_path": path,
+                  "moe_rows": steps * sum(m.expert_rows(n_padded, ep, path) for m in self._moes),
+                  "moe_assignments": steps * n_tokens * sum(m.top_k for m in self._moes)}
+        if path == "capacity":
+            counts["moe_banks"] = steps * sum(m.num_experts for m in self._moes)
+        return counts
 
     @property
     def num_layers(self):
@@ -80,16 +88,19 @@ class MixtralV2Model(LlamaV2Model):
 
     @staticmethod
     def _gating_inputs(batch):
-        """``token_valid`` and ``gate_seed`` of a step's batch, as ``RaggedMoE``
-        takes them. The seed is data-dependent: live token positions differ
-        every decode step, so simulated-gating routing varies across forwards
-        (the fork's load-testing intent) without threading a host counter
-        through jit."""
+        """``token_valid``, ``gate_seed`` and ``banks_out`` of a step's batch, as
+        ``RaggedMoE`` takes them. The seed is data-dependent: live token
+        positions differ every decode step, so simulated-gating routing varies
+        across forwards (the fork's load-testing intent) without threading a
+        host counter through jit. ``banks_out`` is the program's list of the
+        banks each grouped expert layer touched (``_forward_impl`` returns it
+        stacked; a verify step keeps none)."""
         if batch is None:
             return {"token_valid": None, "gate_seed": None}
         return {"token_valid": batch["token_valid"],
                 "gate_seed": jnp.sum(jnp.where(batch["token_valid"], batch["token_pos"],
-                                               0)).astype(jnp.int32)}
+                                               0)).astype(jnp.int32),
+                "banks_out": batch.get("moe_banks")}
 
     def layer_forward(self, params, li, x, cache, attn_fn, batch):
         x, cache = self._attn_phase(params, li, x, cache, attn_fn, batch)

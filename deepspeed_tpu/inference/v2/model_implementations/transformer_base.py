@@ -49,6 +49,9 @@ class DSTransformerModelBase:
         self._state_manager = None
         self._compiled = {}
         self._lowerable = {}  # same keys, UNwrapped jit fns (perf-gate hook)
+        # the newest forward program's count of expert banks touched, int32
+        # [expert layers] ON THE DEVICE (a bucket on the grouped path), else None
+        self.last_moe_banks = None
         self._group_windows = None
         if state_manager is not None:
             self.set_state_manager(state_manager)
@@ -212,8 +215,9 @@ class DSTransformerModelBase:
         cache = self._state_manager.kv_cache.cache
         tok_meta = batch["tok_meta"] if prev is None else sampling.chain(batch["tok_meta"], *prev)
         dev = {"tok_meta": tok_meta, "seq_meta": batch["seq_meta"]}
-        logits, new_cache = fn(self._params, cache, dev)
+        logits, new_cache, *banks = fn(self._params, cache, dev)
         self._state_manager.kv_cache.set_cache(new_cache)
+        self.last_moe_banks = banks[0] if banks else None  # left on the device
         return logits, int(batch["n_seqs"])
 
     def warm_draw(self) -> None:
@@ -249,7 +253,7 @@ class DSTransformerModelBase:
         batch = wrapper.finalize()  # zero live sequences/tokens
         dev = {"tok_meta": batch["tok_meta"], "seq_meta": batch["seq_meta"]}
         fn = self._get_compiled(self._bucket_of(batch))
-        _, new_cache = fn(self._params, self._state_manager.kv_cache.cache, dev)
+        _, new_cache, *_ = fn(self._params, self._state_manager.kv_cache.cache, dev)
         self._state_manager.kv_cache.set_cache(new_cache)
 
     def _get_compiled(self, bucket):
@@ -363,10 +367,13 @@ class DSTransformerModelBase:
         ``n_steps`` tokens must be pre-allocated (engine_v2.decode_loop does
         this).
 
-        Returns generated tokens ``[n_steps, S_bucket]`` (host numpy); column i
-        is sequence-slot i, rows are steps. The cache is updated in place with
-        the n_steps inserted tokens (the last generated token is not yet
-        inserted, matching the host-loop semantics).
+        Returns ``(tokens, banks)`` as the program left them ON THE DEVICE,
+        still being computed (the caller's fetch is the wait): generated tokens
+        ``[n_steps, S_bucket]``, column i sequence-slot i, rows steps; and the
+        expert banks each step touched in each expert layer, int32 ``[n_steps,
+        expert layers]``, where the bucket routes by sorting, else None. The
+        cache is updated in place with the n_steps inserted tokens (the last
+        generated token is not yet inserted, matching the host-loop semantics).
         """
         import jax
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
@@ -388,11 +395,11 @@ class DSTransformerModelBase:
             raise ValueError("decode_loop(temperature>0) requires an rng key — a fixed "
                              "default would return identical 'samples' every call")
         rng = rng if rng is not None else jax.random.PRNGKey(0)
-        tokens, new_cache = self._compiled[key](
+        tokens, new_cache, *banks = self._compiled[key](
             self._params, cache, {"tok_meta": batch["tok_meta"], "seq_meta": batch["seq_meta"]},
             jax.numpy.float32(temperature), rng)
         self._state_manager.kv_cache.set_cache(new_cache)
-        return np.asarray(tokens)
+        return tokens, (banks[0] if banks else None)
 
     def _decode_loop_impl(self, params, cache, batch, temperature, rng, *, n_steps,
                           sampled=False):
@@ -404,8 +411,9 @@ class DSTransformerModelBase:
 
         def step(carry, _):
             cache, tok_meta, seq_meta, r = carry
-            logits, cache = self._forward_impl(params, cache,
-                                               {"tok_meta": tok_meta, "seq_meta": seq_meta})
+            # banks: the forward's count of expert banks touched, where it has one
+            logits, cache, *banks = self._forward_impl(
+                params, cache, {"tok_meta": tok_meta, "seq_meta": seq_meta})
             if sampled:
                 r, sub = jax.random.split(r)
                 next_ids = jax.random.categorical(
@@ -418,12 +426,13 @@ class DSTransformerModelBase:
             tok_meta = tok_meta.at[0].set(new_ids).at[2].add(tv.astype(tok_meta.dtype))
             sv = (seq_meta[:, 3] > 0).astype(seq_meta.dtype)
             seq_meta = seq_meta.at[:, 0].add(sv)
-            return (cache, tok_meta, seq_meta, r), next_ids
+            return (cache, tok_meta, seq_meta, r), (next_ids, *banks)
 
-        # static per-compile sampling flag rides on the jit-cache key
-        (cache, _, _, _), tokens = jax.lax.scan(
+        # static per-compile sampling flag rides on the jit-cache key; the scan
+        # stacks the steps' tokens and, beside them, their bank counts
+        (cache, _, _, _), (tokens, *banks) = jax.lax.scan(
             step, (cache, tok_meta, seq_meta, rng), None, length=n_steps)
-        return tokens, cache
+        return (tokens, cache, *banks)
 
     def _bucket_of(self, batch):
         """``(T, S, MB)`` of a packed batch: the jit cache key."""
@@ -451,14 +460,18 @@ class DSTransformerModelBase:
 
         params = dequantize_tree(params)  # no-op without quantized leaves
         batch = self._unpack_batch(batch)
+        # an expert layer that routes by sorting appends the banks it touched
+        # (``RaggedMoE``'s ``banks_out``); where any did, the program returns
+        # them, int32 [expert layers], as one more output
+        banks = batch["moe_banks"] = []
         x = self.embed(params, batch["input_ids"])
         attn = partial(self._paged_attention, batch=batch)
         for li in range(self.num_layers):
             x, cache = self.layer_forward(params, li, x, cache, attn, batch)
         # unembed ONLY each sequence's last token (reference logits_gather)
         x_last = x[batch["last_tok"]]
-        logits = self.unembed(params, x_last)
-        return logits.astype(jnp.float32), cache
+        logits = self.unembed(params, x_last).astype(jnp.float32)
+        return (logits, cache, jnp.stack(banks)) if banks else (logits, cache)
 
     # ----------------------------------------------------- speculative verify --
     def _verify_key(self, dev, greedy):

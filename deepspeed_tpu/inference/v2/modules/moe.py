@@ -35,7 +35,9 @@ under EP, into static per-expert buffers, every expert's bank multiplied) or
 the grouped path (``_grouped_forward``: the assignments sorted by expert, one
 gather of their rows, one grouped matmul a projection —
 ``ops/pallas/grouped_matmul.py`` — and the rows summed back to their tokens;
-dropless whatever the skew, no capacity, and no bank read that has no row).
+dropless whatever the skew, no capacity, and no bank read that has no row; how
+many banks had a row is data on the device, and a caller that passes
+``banks_out`` is handed the count).
 The grouped path is taken where the masks would cost a real share of the
 experts (many narrow experts, a full chunk of tokens), and where the bucket's
 assignments cannot touch more than half of the banks (a decode step's 8 rows
@@ -274,12 +276,15 @@ class RaggedMoE:
 
     # ----------------------------------------------------------------- forward --
     def __call__(self, h, gate_w, wi, wo, token_valid=None, activation=None, mesh=None,
-                 gate_seed=None, select_bias=None):
+                 gate_seed=None, select_bias=None, banks_out=None):
         """h: [T, M]; gate_w: [M, E]; wi: [E, M, F]; wo: [E, F, M] (the training
         ExpertFFN bank layout — EP-shards on the leading dim); ``select_bias``:
         float32 [E] added to the scores to PICK the experts (:meth:`_choose`),
         or None. Dispatches to the disaggregated shard_map path when the mesh
-        has an expert axis > 1."""
+        has an expert axis > 1. ``banks_out``: a list; a bucket on the grouped
+        path appends the int32 scalar of expert banks this call's routing
+        touched (the banks its GEMMs read), the other paths append nothing
+        (they multiply every bank, whatever was routed)."""
         import jax
 
         if activation is None:
@@ -300,19 +305,22 @@ class RaggedMoE:
                            f"(no token disaggregation)")
         if self.path(h.shape[0], wo.shape[-2], ep) == "grouped":
             return self._grouped_forward(h, gate_w, wi, wo, token_valid, activation, gate_seed,
-                                         select_bias)
+                                         select_bias, banks_out)
         return self._dense_forward(h, gate_w, wi, wo, token_valid, activation, gate_seed,
                                    mesh if ep > 1 else None, select_bias)
 
     def _grouped_forward(self, h, gate_w, wi, wo, token_valid, activation, gate_seed,
-                         select_bias=None):
+                         select_bias=None, banks_out=None):
         """Single-replica path that routes by SORTING: the T x k assignments
         ordered by expert, one gather of their rows, one grouped matmul a
         projection (each expert's rows against its own bank), the routing
         weights applied in float32 and the rows summed back to their tokens.
         Every assignment has a row whatever the skew: dropless by construction,
         ``capacity_factor`` has no part in it. An invalid token's assignments
-        sort behind every expert's and belong to no group."""
+        sort behind every expert's and belong to no group. ``banks_out``, a
+        list, is appended the int32 count of experts that have a row: the banks
+        the two GEMMs read (an invalid token's and the padding's rows count for
+        none)."""
         import jax
         import jax.numpy as jnp
         from deepspeed_tpu.ops.pallas.grouped_matmul import padded_rows
@@ -331,6 +339,8 @@ class RaggedMoE:
             # stable: an expert's rows stay in token order
             e_sorted, order = jax.lax.sort((e_flat, slots), num_keys=1, is_stable=True)
             group_sizes = (e_flat[:, None] == jnp.arange(E)[None, :]).sum(0, dtype=jnp.int32)
+            if banks_out is not None:
+                banks_out.append((group_sizes > 0).sum(dtype=jnp.int32))
             # where each assignment's row went: the inverse permutation
             _, back = jax.lax.sort((order, slots), num_keys=1)
         with jax.named_scope("dispatch"):

@@ -81,14 +81,15 @@ class _PutStep:
     """A ``put`` step dispatched and not yet fetched: its plan, the device
     ids drawn for it, and per plan entry what the id is to its request —
     ``"first"`` (the chunk that completed the prompt), ``"decode"``, or None
-    (a mid-prompt chunk: meaningless)."""
+    (a mid-prompt chunk: meaningless). ``moe``: what the engine handed over
+    for the span of the step's fetch (``engine.last_moe_fetch``), or None."""
 
-    __slots__ = ("plan", "ids", "rows", "row_of", "phases", "t0_us", "tick")
+    __slots__ = ("plan", "ids", "rows", "row_of", "phases", "t0_us", "tick", "moe")
 
-    def __init__(self, plan, ids, rows, phases, t0_us, tick):
+    def __init__(self, plan, ids, rows, phases, t0_us, tick, moe=None):
         self.plan, self.ids, self.rows = plan, ids, rows
         self.row_of = {req.uid: i for i, (req, _) in enumerate(plan)}
-        self.phases, self.t0_us, self.tick = phases, t0_us, tick
+        self.phases, self.t0_us, self.tick, self.moe = phases, t0_us, tick, moe
 
 
 def _validate_drafter_pin(drafter) -> Optional[str]:
@@ -2100,7 +2101,8 @@ class ServingScheduler:
             if row is not None:
                 req._pending += 1
             rows.append(row)
-        return _PutStep(plan, ids, rows, phases, t0_us, tick_no)
+        return _PutStep(plan, ids, rows, phases, t0_us, tick_no,
+                        getattr(self._engine, "last_moe_fetch", None))
 
     def _complete(self, step: _PutStep, reason: Optional[str]) -> int:
         """Fetch ``step``'s ids and emit them: everything that needs token
@@ -2112,7 +2114,7 @@ class ServingScheduler:
         self._count_fetched(reason)
         spans = self._tick_spans
         try:
-            ids = self._fetch(step.ids)
+            ids = self._fetch(step.ids, step.moe)
         except Exception as e:  # pragma: no cover - defensive, as for the dispatch
             logger.exception("serving: fetching a step's ids failed; failing the batch")
             for req, _ in step.plan:
@@ -2161,10 +2163,15 @@ class ServingScheduler:
                                   min(seq.seen_tokens, int(req.prompt.size)))
             self._push_drawn(req, int(ids[i]))
 
-    def _fetch(self, result) -> np.ndarray:
+    def _fetch(self, result, moe=None) -> np.ndarray:
         """The blocking transfer of an engine call's result to the host, apart
         from the call itself (span ``fetch``: the wait for the device is here,
-        the call's own span is the dispatch)."""
+        the call's own span is the dispatch). ``moe``: a grouped ``put`` step's
+        ``moe_path``, ``moe_assignments`` and its count of expert banks touched,
+        the last a device array on its way to the host since the launch
+        (``engine.last_moe_fetch``): the span carries the three, the count
+        summed over the expert layers — read behind the result, when the
+        device is done with the step."""
         spans = self._tick_spans
         if spans is None:
             return np.asarray(result)
@@ -2172,6 +2179,8 @@ class ServingScheduler:
         with spans.span("fetch", "sched", args):
             out = np.asarray(result)
             args["bytes"] = int(out.nbytes)
+            if moe is not None:
+                args.update(moe, moe_banks=int(np.asarray(moe["moe_banks"]).sum()))
         return out
 
     def _emit_phase(self, spans):
@@ -2333,7 +2342,8 @@ class ServingScheduler:
             prefill = self._dispatch_put(
                 prefill_plan,
                 [("prefill", int(t.size)) for _, t in prefill_plan]) if prefill_plan else None
-            prefill_ids = self._fetch(prefill.ids) if prefill is not None else None
+            prefill_ids = (self._fetch(prefill.ids, prefill.moe)
+                           if prefill is not None else None)
         except Exception as e:  # pragma: no cover - defensive: same contract
             # as the put path — the scheduler thread must survive
             logger.exception("serving: verify tick failed; failing the batch")

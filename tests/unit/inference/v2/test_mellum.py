@@ -339,9 +339,11 @@ def test_ragged_moe_refuses_a_top_k_it_cannot_route():
 def test_the_put_span_counts_expert_rows_and_assignments(model):
     engine = _engine(model)
     # 20 live tokens in a 32-token bucket: 8 layers x 16 experts x 32 slots
+    # ... and on the capacity path every bank of every layer is read
     assert engine.model.dispatch_counts(32, 20) == {"moe_path": "capacity",
                                                     "moe_rows": 8 * 16 * 32,
-                                                    "moe_assignments": 20 * 4 * 8}
+                                                    "moe_assignments": 20 * 4 * 8,
+                                                    "moe_banks": 8 * 16}
 
 
 def test_the_put_span_counts_the_rows_of_the_path_the_bucket_takes(many_experts):
@@ -354,7 +356,7 @@ def test_the_put_span_counts_the_rows_of_the_path_the_bucket_takes(many_experts)
     assert counts(128, 100) == {"moe_path": "grouped", "moe_rows": 4 * 128 * 8,
                                 "moe_assignments": 100 * 8 * 4}
     assert counts(64, 50) == {"moe_path": "capacity", "moe_rows": 4 * 64 * 64,
-                              "moe_assignments": 50 * 8 * 4}
+                              "moe_assignments": 50 * 8 * 4, "moe_banks": 4 * 64}
     session = telemetry.configure(telemetry.TelemetryConfig(enabled=True))
     try:
         engine.put([0], [_ids(40, 128)])
