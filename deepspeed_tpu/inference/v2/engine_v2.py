@@ -32,6 +32,53 @@ from deepspeed_tpu.utils import groups
 from deepspeed_tpu.utils.logging import logger
 
 
+class DecodeChunk:
+    """A ``decode_loop`` chunk on the device, not fetched
+    (:meth:`InferenceEngineV2.dispatch_decode_loop`). ``tokens`` is the
+    program's device ``[n_steps, S_bucket]`` result, column i sequence i of
+    the batch; ``ids`` its last row, taken on the device: what a later
+    ``put_draw`` or chunk takes as ``prev``'s ids, so the step after a chunk
+    can be dispatched while the chunk runs. :meth:`fetch` (``np.asarray`` of
+    the chunk is the same) is the wait for the device."""
+
+    __slots__ = ("tokens", "n_seqs", "_ids", "_banks", "_args")
+
+    def __init__(self, tokens, n_seqs, banks=None, args=None):
+        self.tokens, self.n_seqs = tokens, n_seqs
+        self._ids = None
+        # on the grouped path the banks its routing touched, int32 [n_steps,
+        # expert layers], and under a telemetry session the chunk's
+        # ``decode_loop`` span args (only then are the banks ever read: their
+        # copy to the host is under way)
+        self._banks, self._args = banks, args
+
+    @property
+    def ids(self):
+        if self._ids is None:
+            from deepspeed_tpu.inference.v2 import sampling
+            self._ids = sampling.last_row(self.tokens)
+        return self._ids
+
+    def fetch(self) -> np.ndarray:
+        """The generated tokens ``[n_seqs, n_steps]`` on the host. The chunk's
+        span learns here what only the fetch can say (the ring keeps a span's
+        args dict, so what is written now is read with the span):
+        ``fetch_us``, the blocking transfer, and on the grouped path
+        ``moe_banks``, the banks touched over the chunk's steps and expert
+        layers — 4 bytes a layer-step that came out behind the tokens."""
+        args, self._args = self._args, None
+        t0 = _tel_now_us()
+        tokens = np.asarray(self.tokens)
+        if args is not None:
+            args["fetch_us"] = _tel_now_us() - t0
+            if self._banks is not None:
+                args["moe_banks"] = int(np.asarray(self._banks).sum())
+        return tokens[:, :self.n_seqs].T
+
+    def __array__(self, dtype=None, copy=None):
+        return self.fetch()
+
+
 class InferenceEngineV2:
 
     def __init__(self, model, engine_config: RaggedInferenceEngineConfig) -> None:
@@ -188,9 +235,10 @@ class InferenceEngineV2:
     # ``put`` / ``decode_loop`` / ``verify_tree`` time the DISPATCH
     # of that call (plus, where the method itself fetches, the fetch) — not the
     # device: JAX returns before the device finishes, and the caller's
-    # ``np.asarray`` is where the wait shows. A ``decode_loop`` span says which
-    # part is which: ``launch_us`` (entry until the jitted call has returned)
-    # and ``fetch_us`` (the blocking transfer of the tokens).
+    # ``np.asarray`` is where the wait shows. A ``decode_loop`` span is the
+    # launch alone, ``launch_us`` (entry until the jitted call has returned);
+    # ``fetch_us`` (0 until then) is written when the chunk is fetched, under
+    # whatever span the fetcher is in, and is the blocking transfer's time.
     def _prepare_forward(self, spans, batch_uids, feeds, do_checks, n_tokens, trees=None):
         """The host side of one ragged forward, under the ``prepare`` span:
         admission check, restore of offloaded sequences, KV allocation and the
@@ -313,12 +361,14 @@ class InferenceEngineV2:
         return self._put(batch_uids, batch_tokens, do_checks,
                          (temperature, seed, draw_index), prev)
 
-    def warm_draw(self) -> None:
+    def warm_draw(self, chunk_steps: int = 0) -> None:
         """Compile :meth:`put_draw`'s draw for every sequence bucket this
         engine can produce, and its ``prev`` merge for every token bucket
         beside (a ``ServingScheduler`` calls it when it is constructed:
-        set-up, never a first request's stall)."""
-        self._model.warm_draw()
+        set-up, never a first request's stall). ``chunk_steps``: the steps of
+        the ``decode_loop`` chunks whose last row will be handed on as
+        ``prev`` (:attr:`DecodeChunk.ids`): that program too, a bucket each."""
+        self._model.warm_draw(chunk_steps)
 
     def _put(self, batch_uids, batch_tokens, do_checks, draw, prev=None):
         batch_uids = list(batch_uids)
@@ -341,15 +391,7 @@ class InferenceEngineV2:
             # path, moe_banks: every bank)
             args["attention"] = self._model.attention_arm(n_padded)
             args.update(self._model.dispatch_counts(n_padded, n_tokens))
-        if prev is not None:
-            # per sequence -> per token slot: a sequence's first token
-            ids, index = prev
-            src = np.full(n_padded, -1, np.int32)
-            first = np.cumsum([0] + [t.size for t in batch_tokens[:-1]])
-            src[first] = index
-            prev = (ids, src)
-            if args is not None:
-                args["chained"] = int((src >= 0).sum())
+        prev = self._prev_by_slot(prev, batch_tokens, n_padded, args)
         with _tel_live_span(spans, "put", "inference", args):
             if observer is not None:
                 _t0 = _tel_now_us()
@@ -373,6 +415,20 @@ class InferenceEngineV2:
         if metrics is not None:
             self._write_telemetry(metrics, batch_tokens=n_tokens)
         return out
+
+    @staticmethod
+    def _prev_by_slot(prev, batch_tokens, n_padded, args):
+        """``prev`` = ``(ids, index)`` a sequence → ``(ids, src)`` a token
+        slot: a sequence's FIRST token is the one fed from the device. The
+        dispatch span's ``chained`` counts the slots fed so."""
+        if prev is None:
+            return None
+        ids, index = prev
+        src = np.full(n_padded, -1, np.int32)
+        src[np.cumsum([0] + [t.size for t in batch_tokens[:-1]])] = index
+        if args is not None:
+            args["chained"] = int((src >= 0).sum())
+        return ids, src
 
     @staticmethod
     def _build_tel_metrics(reg) -> dict:
@@ -447,7 +503,29 @@ class InferenceEngineV2:
         EOS is not monitored on device: the loop always runs ``n_steps``; the
         caller trims at the first EOS (the fixed-shape scan is what makes the
         loop a single compiled program).
+
+        This is :meth:`dispatch_decode_loop` fetched at once; a caller that has
+        other work for the host while the chunk runs takes the two apart.
         """
+        return self.dispatch_decode_loop(batch_uids, batch_tokens, n_steps, do_checks,
+                                         temperature, rng).fetch()
+
+    def dispatch_decode_loop(self, batch_uids: Iterable[int], batch_tokens: Iterable,
+                             n_steps: int, do_checks: bool = True, temperature: float = 0.0,
+                             rng=None, prev=None) -> DecodeChunk:
+        """:meth:`decode_loop`, launched and NOT fetched: everything that needs
+        only counts is done when the call returns — the checks, the KV blocks
+        of all ``n_steps`` tokens, ``seen_tokens``, the rolling release — and
+        the :class:`DecodeChunk` holds the program's tokens on the device.
+        ``prev`` = ``(ids, index)`` with :meth:`put_draw`'s meaning: sequence
+        i's input token is entry ``index[i]`` of ``ids`` — a ``put_draw``'s
+        ids or an earlier chunk's :attr:`DecodeChunk.ids`, fetched or not —
+        unless ``index[i]`` is -1 (the token in ``batch_tokens`` stands). The
+        merge is ``put_draw``'s (one tiny program in front of the chunk's,
+        which is the one a host-fed chunk runs, under the same cache key), so
+        a chunk that continues the step before is dispatched while that one
+        still runs. :meth:`warm_draw` builds the merge, and the program that
+        takes a chunk's last row."""
         batch_uids = list(batch_uids)
         batch_tokens = [np.atleast_1d(np.asarray(t)) for t in batch_tokens]
         if any(t.size != 1 for t in batch_tokens):
@@ -503,24 +581,17 @@ class InferenceEngineV2:
             # a sparse model's moe_path, and the chunk's moe_rows and
             # moe_assignments: every step of it routes this bucket
             args.update(self._model.dispatch_counts(n_padded, len(batch_uids), n_steps))
+        prev = self._prev_by_slot(prev, batch_tokens, n_padded, args)
         with _tel_live_span(spans, "decode_loop", "inference", args):
             if observer is not None or spans is not None:
                 _t0 = _tel_now_us()
             tokens, banks = self._model.decode_loop(self._batch, n_steps,
-                                                    temperature=temperature, rng=rng)
+                                                    temperature=temperature, rng=rng, prev=prev)
             if spans is not None:
-                launched = _tel_now_us()
+                # the call's two parts: the second is the fetcher's to write
+                args.update(launch_us=_tel_now_us() - _t0, fetch_us=0)
                 if banks is not None:
                     banks.copy_to_host_async()  # rides behind the tokens, not after them
-            tokens = np.asarray(tokens)  # [n_steps, S_bucket]: the wait for the device
-            if spans is not None:
-                # the call's two parts (the ring keeps args as they are at exit)
-                args["launch_us"] = launched - _t0
-                args["fetch_us"] = _tel_now_us() - launched
-                if banks is not None:
-                    # the banks the chunk's routing touched, over its steps and
-                    # expert layers: 4 bytes a layer-step, on the host by now
-                    args["moe_banks"] = int(np.asarray(banks).sum())
             if observer is not None:
                 observer("decode_loop", len(batch_uids),
                          len(batch_uids) * n_steps, (_tel_now_us() - _t0) / 1e6)
@@ -533,7 +604,7 @@ class InferenceEngineV2:
                 seq_desc.pre_forward(n_steps - 1)
                 seq_desc.post_forward()
             self._released_blocks += self._model.maybe_free_kv(seq_desc)
-        return tokens[:, :len(batch_uids)].T
+        return DecodeChunk(tokens, len(batch_uids), banks, args)
 
     # ------------------------------------------------------ speculative verify --
     def verify_tree(self, batch_uids: Iterable[int], trees: Iterable,

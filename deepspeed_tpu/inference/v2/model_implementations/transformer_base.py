@@ -52,6 +52,9 @@ class DSTransformerModelBase:
         # the newest forward program's count of expert banks touched, int32
         # [expert layers] ON THE DEVICE (a bucket on the grouped path), else None
         self.last_moe_banks = None
+        # (float32(0), PRNGKey(0)) on the device: what every greedy
+        # ``decode_loop`` chunk passes for its temperature and key
+        self._greedy_sampler = None
         self._group_windows = None
         if state_manager is not None:
             self.set_state_manager(state_manager)
@@ -220,7 +223,7 @@ class DSTransformerModelBase:
         self.last_moe_banks = banks[0] if banks else None  # left on the device
         return logits, int(batch["n_seqs"])
 
-    def warm_draw(self) -> None:
+    def warm_draw(self, chunk_steps: int = 0) -> None:
         """Compile the draw for every sequence bucket this engine's
         configuration can produce, placed as the forward's logits are: on the
         KV pool's mesh, replicated, or on the default device of a mesh-less
@@ -228,7 +231,9 @@ class DSTransformerModelBase:
         split over the vocabulary; that draw is then built at its first
         step.) And the program that feeds a step from the ids of the one
         before (``sampling.chain``), for every pair of a token bucket and a
-        sequence bucket: a few operations each."""
+        sequence bucket: a few operations each. With ``chunk_steps`` > 1,
+        also the program that takes the last row of a ``decode_loop`` chunk of
+        that many steps (``sampling.last_row``), a sequence bucket each."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
         pool = self._state_manager.kv_cache.sharding
@@ -242,6 +247,8 @@ class DSTransformerModelBase:
             sampling.compiled(rows, self.vocab_size, placed)
             for tokens in token_buckets(sm.max_ragged_batch_size):
                 sampling.compiled_chain(tokens, rows)
+            if chunk_steps > 1:
+                sampling.compiled_last_row(chunk_steps, rows)
 
     def empty_run(self) -> None:
         """Participate in collectives with zero live tokens (fork engine_v2.py:308).
@@ -353,7 +360,7 @@ class DSTransformerModelBase:
 
     # ------------------------------------------------------------ decode loop --
     def decode_loop(self, ragged_batch, n_steps: int, temperature: float = 0.0,
-                    rng=None):
+                    rng=None, prev=None):
         """Decode ``n_steps`` tokens per sequence in ONE device program —
         greedy argmax at ``temperature`` 0, categorical sampling otherwise
         (``rng`` folded per step; REQUIRED when sampling — a silent fixed
@@ -374,6 +381,10 @@ class DSTransformerModelBase:
         expert layers]``, where the bucket routes by sorting, else None. The
         cache is updated in place with the n_steps inserted tokens (the last
         generated token is not yet inserted, matching the host-loop semantics).
+
+        ``prev`` = ``(ids, src)`` as for :meth:`forward_draw`: token slot t's
+        input id is ``ids[src[t]]``, still on the device, wherever
+        ``src[t] >= 0`` (``sampling.chain`` in front of the same program).
         """
         import jax
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
@@ -394,10 +405,18 @@ class DSTransformerModelBase:
         if temperature > 0 and rng is None:
             raise ValueError("decode_loop(temperature>0) requires an rng key — a fixed "
                              "default would return identical 'samples' every call")
-        rng = rng if rng is not None else jax.random.PRNGKey(0)
+        if temperature > 0:
+            sampler = (jax.numpy.float32(temperature), rng)
+        else:
+            # a greedy chunk's temperature and the key it carries untouched:
+            # put on the device once, not as two programs ahead of every chunk
+            if self._greedy_sampler is None:
+                self._greedy_sampler = (jax.numpy.float32(0.0), jax.random.PRNGKey(0))
+            zero, kept = self._greedy_sampler
+            sampler = (zero, kept if rng is None else rng)
+        tok_meta = batch["tok_meta"] if prev is None else sampling.chain(batch["tok_meta"], *prev)
         tokens, new_cache, *banks = self._compiled[key](
-            self._params, cache, {"tok_meta": batch["tok_meta"], "seq_meta": batch["seq_meta"]},
-            jax.numpy.float32(temperature), rng)
+            self._params, cache, {"tok_meta": tok_meta, "seq_meta": batch["seq_meta"]}, *sampler)
         self._state_manager.kv_cache.set_cache(new_cache)
         return tokens, (banks[0] if banks else None)
 

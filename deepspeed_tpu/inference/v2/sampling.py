@@ -16,7 +16,8 @@ flag: greedy and sampled rows share one program.
 A step's ids can feed the next step without leaving the device
 (``chain``): the serving scheduler dispatches a step behind the one in flight
 when no arrival could have joined it, and its decode rows take their input
-ids from the ids being drawn.
+ids from the ids being drawn. A ``decode_loop`` chunk hands on the last row
+of its ``[steps, rows]`` tokens the same way (``last_row``).
 
 The program is compiled ahead of time (``compiled``) — per row count, vocab
 and the logits' sharding — and kept for the process: a ``jax.jit`` cache
@@ -32,6 +33,21 @@ from deepspeed_tpu.telemetry import compile_watch
 
 _EXECUTABLES = {}
 _LOCK = threading.Lock()
+
+
+def _executable(key, site, watched, build):
+    """``build()``'s executable under ``key``: built on the first call (its
+    compile reported to the compile watch as ``site`` / ``watched``), then
+    shared by every engine of the process."""
+    exe = _EXECUTABLES.get(key)
+    if exe is None:
+        with _LOCK:
+            exe = _EXECUTABLES.get(key)
+            if exe is None:
+                cw = compile_watch.get()
+                exe = (build if cw is None else cw.wrap(site, watched, build))()
+                _EXECUTABLES[key] = exe
+    return exe
 
 
 def draw_tokens(logits, temperature, seed, draw_index):
@@ -58,33 +74,23 @@ def compiled(rows: int, vocab: int, sharding):
     """The draw's executable for ``[rows, vocab]`` logits placed as
     ``sharding``; built on the first call, then shared by every engine of the
     process."""
-    key = (rows, vocab, sharding)
-    exe = _EXECUTABLES.get(key)
-    if exe is not None:
-        return exe
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    # the per-row vectors arrive as host arrays: replicated wherever the
-    # logits live
-    vec = (NamedSharding(sharding.mesh, PartitionSpec())
-           if isinstance(sharding, NamedSharding) else sharding)
 
     def build():
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        # the per-row vectors arrive as host arrays: replicated wherever the
+        # logits live
+        vec = (NamedSharding(sharding.mesh, PartitionSpec())
+               if isinstance(sharding, NamedSharding) else sharding)
         return jax.jit(draw_tokens).lower(
             jax.ShapeDtypeStruct((rows, vocab), jnp.float32, sharding=sharding),
             jax.ShapeDtypeStruct((rows, ), jnp.float32, sharding=vec),
             jax.ShapeDtypeStruct((rows, ), jnp.uint32, sharding=vec),
             jax.ShapeDtypeStruct((rows, ), jnp.int32, sharding=vec)).compile()
 
-    with _LOCK:
-        exe = _EXECUTABLES.get(key)
-        if exe is None:
-            cw = compile_watch.get()
-            exe = (build if cw is None else cw.wrap("inference_draw", key[:2], build))()
-            _EXECUTABLES[key] = exe
-    return exe
+    return _executable((rows, vocab, sharding), "inference_draw", (rows, vocab), build)
 
 
 def chain_ids(tok_meta, ids, src):
@@ -105,26 +111,16 @@ def compiled_chain(tokens: int, rows: int):
     to place as the host array it stands in for: the forward program that
     takes it next sees the argument it was compiled for and is not built a
     second time."""
-    key = ("chain", tokens, rows)
-    exe = _EXECUTABLES.get(key)
-    if exe is not None:
-        return exe
-    import jax
-    import jax.numpy as jnp
 
     def build():
+        import jax
+        import jax.numpy as jnp
         return jax.jit(chain_ids).lower(
             jax.ShapeDtypeStruct((4, tokens), jnp.int32),
             jax.ShapeDtypeStruct((rows, ), jnp.int32),
             jax.ShapeDtypeStruct((tokens, ), jnp.int32)).compile()
 
-    with _LOCK:
-        exe = _EXECUTABLES.get(key)
-        if exe is None:
-            cw = compile_watch.get()
-            exe = (build if cw is None else cw.wrap("inference_chain", key[1:], build))()
-            _EXECUTABLES[key] = exe
-    return exe
+    return _executable(("chain", tokens, rows), "inference_chain", (tokens, rows), build)
 
 
 def chain(tok_meta: np.ndarray, ids, src: np.ndarray):
@@ -141,6 +137,31 @@ def chain(tok_meta: np.ndarray, ids, src: np.ndarray):
                  if s.device == default] if ids.is_fully_replicated else []
         ids = local[0] if local else np.asarray(ids)
     return compiled_chain(tok_meta.shape[1], ids.shape[0])(tok_meta, ids, src)
+
+
+def compiled_last_row(steps: int, rows: int):
+    """The executable that takes row ``steps - 1`` of a ``decode_loop``
+    chunk's int32 ``[steps, rows]`` tokens: the ids the chunk's successor is
+    fed from (``chain``'s ``ids``)."""
+
+    def build():
+        import jax
+        import jax.numpy as jnp
+        return jax.jit(lambda tokens: tokens[-1]).lower(
+            jax.ShapeDtypeStruct((steps, rows), jnp.int32)).compile()
+
+    return _executable(("last_row", steps, rows), "inference_last_row", (steps, rows), build)
+
+
+def last_row(tokens):
+    """``tokens[-1]`` of a chunk's device ``[steps, rows]`` tokens, as a device
+    array: nothing is fetched. Tokens that do not lie whole on the default
+    device are indexed where they lie (``chain`` then reads or fetches them
+    as it does a draw's ids)."""
+    import jax
+    if tokens.sharding.device_set != {jax.local_devices()[0]}:
+        return tokens[-1]
+    return compiled_last_row(*tokens.shape)(tokens)
 
 
 def _padded(values, rows: int, dtype) -> np.ndarray:

@@ -22,13 +22,14 @@ Batch composition per tick (``step()``):
    coldest idle sequence via ``engine.offload_sequence`` (restore-on-touch is
    transparent) and retry;
 5. decode-only batches with ``decode_chunk > 1`` run through the on-device
-   ``engine.decode_loop`` (one dispatch per K tokens);
+   ``engine.dispatch_decode_loop`` (one dispatch per K tokens), a step like
+   any other: in flight under point 7's rule;
 6. idle ticks heartbeat ``engine.empty_run()`` so idle EP replicas stay in
    collective lock-step with busy ones;
-7. a ``put`` step whose plan is closed to arrivals (it uses the whole token
-   budget or the whole sequence cap) is left on the device unfetched, and the
-   next tick dispatches its step behind it before fetching it, if that plan
-   is closed too (``step()``).
+7. a step — a ``put`` step or a ``decode_loop`` chunk — whose plan is closed
+   to arrivals (it uses the whole token budget or the whole sequence cap) is
+   left on the device unfetched, and the next tick dispatches its step behind
+   it before fetching it, if that plan is closed too (``step()``).
 """
 
 import itertools
@@ -74,22 +75,33 @@ _DRAFTER_PINS = ("prompt_lookup", "learned", "auto")
 
 # why a step was fetched before the step after it was dispatched
 # (``drained_steps_<reason>`` in stats()["counters"], ``drain`` on a tick span)
-_DRAIN_REASONS = ("open", "decode_loop", "verify", "pressure", "control", "stop")
+_DRAIN_REASONS = ("open", "verify", "pressure", "control", "stop")
 
 
-class _PutStep:
-    """A ``put`` step dispatched and not yet fetched: its plan, the device
-    ids drawn for it, and per plan entry what the id is to its request —
-    ``"first"`` (the chunk that completed the prompt), ``"decode"``, or None
-    (a mid-prompt chunk: meaningless). ``moe``: what the engine handed over
-    for the span of the step's fetch (``engine.last_moe_fetch``), or None."""
+class _Step:
+    """An engine step dispatched and not yet fetched: a ``put`` step, or a
+    ``decode_loop`` chunk of ``loop_steps`` steps (0: a ``put`` step). Its
+    plan, its ``result`` on the device — the ids drawn for a ``put`` step, a
+    chunk's :class:`~deepspeed_tpu.inference.v2.engine_v2.DecodeChunk` — and
+    per plan entry what the result is to its request — ``"first"`` (the chunk
+    that completed the prompt), ``"decode"``, or None (a mid-prompt chunk:
+    meaningless). ``moe``: what the engine handed over for the span of a
+    ``put`` step's fetch (``engine.last_moe_fetch``), or None."""
 
-    __slots__ = ("plan", "ids", "rows", "row_of", "phases", "t0_us", "tick", "moe")
+    __slots__ = ("plan", "result", "rows", "row_of", "phases", "t0_us", "tick", "moe",
+                 "loop_steps")
 
-    def __init__(self, plan, ids, rows, phases, t0_us, tick, moe=None):
-        self.plan, self.ids, self.rows = plan, ids, rows
+    def __init__(self, plan, result, rows, phases, t0_us, tick, moe=None, loop_steps=0):
+        self.plan, self.result, self.rows = plan, result, rows
         self.row_of = {req.uid: i for i, (req, _) in enumerate(plan)}
         self.phases, self.t0_us, self.tick, self.moe = phases, t0_us, tick, moe
+        self.loop_steps = loop_steps
+
+    @property
+    def ids(self):
+        """The device ids the step after this one feeds its decode rows from,
+        entry ``row_of[uid]`` a request: a chunk's are its last row."""
+        return self.result.ids if self.loop_steps else self.result
 
 
 def _validate_drafter_pin(drafter) -> Optional[str]:
@@ -209,13 +221,14 @@ class ServingScheduler:
                            "device_draws", "host_draws",
                            "put_steps", "pipelined_steps", "overrun_rows",
                            "moe_grouped_steps", "moe_capacity_steps",
-                           "moe_grouped_chunks", "moe_capacity_chunks")
+                           "moe_grouped_chunks", "moe_capacity_chunks",
+                           "pipelined_chunks")
                           + tuple(f"drained_steps_{r}" for r in _DRAIN_REASONS)}
-        # the put step on the device that no tick has fetched yet (step()),
+        # the step on the device that no tick has fetched yet (step()),
         # why the newest fetched step was fetched before its successor was
         # dispatched, and whether the batch being built behind a step in
         # flight met something that needs it fetched first
-        self._inflight: Optional[_PutStep] = None
+        self._inflight: Optional[_Step] = None
         self._sync_reason = "open"
         self._behind_block: Optional[str] = None
         self._stopping = False   # no new submits
@@ -369,8 +382,9 @@ class ServingScheduler:
             engine, self._config.kv_tiers, metrics=self._metrics)
 
         # every token is drawn on the device (inference/v2/sampling.py): its
-        # programs are built here, as set-up, never under a first request
-        engine.warm_draw()
+        # programs are built here, as set-up, never under a first request (and
+        # the one that hands a chunk's last row to the step behind it)
+        engine.warm_draw(self._config.decode_chunk)
 
         engine._serving_scheduler = self
         # armed last: flight_state() must never observe a half-built
@@ -948,37 +962,38 @@ class ServingScheduler:
         step in flight was fetched. Runs on the scheduler thread — or inline
         when ``start=False``.
 
-        A tick dispatches ONE engine step. The tick of a ``put`` step whose
-        plan leaves room for an arrival — fewer tokens than the budget and
-        fewer sequences than the cap — and of every ``decode_loop`` chunk and
-        verify step, runs its phases in this order: ``admit``,
-        ``build_batch``, then in :meth:`_execute` the engine's ``prepare`` and
-        dispatch, ``fetch``, ``emit``. A ``put`` step whose plan is CLOSED to
-        arrivals stays on the device when its tick ends; the next tick builds
-        its plan from what is counted (who finishes by length, whose prompt is
+        A tick dispatches ONE engine step: a ``put`` step, a ``decode_loop``
+        chunk of K steps, or a verify step. The tick of a step whose plan
+        leaves room for an arrival — fewer tokens than the budget and fewer
+        sequences than the cap — and of every verify step, runs its phases in
+        this order: ``admit``, ``build_batch``, then in :meth:`_execute` the
+        engine's ``prepare`` and dispatch, ``fetch``, ``emit``. A ``put`` step
+        or a chunk whose plan is CLOSED to arrivals stays on the device when
+        its tick ends; the next tick builds its plan from what is counted (who
+        finishes by length — inside a chunk in flight too —, whose prompt is
         fed) and, if that plan is closed too, dispatches it BEHIND the step in
         flight — its decode rows take their input ids from the ids being
-        drawn, on the device — and only then fetches and emits the step before
-        it: ``admit``, ``build_batch``, ``prepare`` + dispatch (step i+1),
-        ``fetch`` (step i), ``emit`` (step i). The device goes from one step
-        into the next while the host emits. An arrival loses nothing: it had
-        no room in that plan whenever it was built. A tick that finds the
-        step in flight in the way — the plan it would put behind is open or a
-        ``decode_loop`` chunk, drafts need token values, the build would have
-        to evict, a control call, ``stop`` — fetches and emits it first
-        (``drained_steps_<reason>``) and then runs as the first kind; a
-        request's tokens are the same either way (its draws are keyed by a
-        counted position).
+        drawn, or from the last row of the chunk being run, on the device —
+        and only then fetches and emits the step before it: ``admit``,
+        ``build_batch``, ``prepare`` + dispatch (step i+1), ``fetch`` (step
+        i), ``emit`` (step i). The device goes from one step into the next
+        while the host emits. An arrival loses nothing: it had no room in
+        that plan whenever it was built. A tick that finds the step in flight
+        in the way — the plan it would put behind is open, drafts need token
+        values, the build would have to evict, a control call, ``stop`` —
+        fetches and emits it first (``drained_steps_<reason>``) and then runs
+        as the first kind; a request's tokens are the same either way (its
+        draws are keyed by a counted position, a chunk is greedy).
 
         With telemetry on, a tick that has work is one ``tick`` span (cat
         ``sched``) holding those phases as spans (the engine's are cat
         ``inference``); its args name the step it dispatched: ``seqs``,
-        ``tokens``, ``kind``, ``pipelined`` (1: dispatched before the step
-        before it was fetched) and, when 0, ``drain`` (why that step had been
-        fetched first). Each is also a ``dstpu.sched.*`` annotation on this
-        thread's line of a jax.profiler trace. An idle poll (nothing queued,
-        nothing active) records nothing; :meth:`_run` covers it with
-        ``no_work``."""
+        ``tokens``, ``kind`` (``put`` / ``decode_loop`` / ``verify_tree``),
+        ``pipelined`` (1: dispatched before the step before it was fetched)
+        and, when 0, ``drain`` (why that step had been fetched first). Each
+        is also a ``dstpu.sched.*`` annotation on this thread's line of a
+        jax.profiler trace. An idle poll (nothing queued, nothing active)
+        records nothing; :meth:`_run` covers it with ``no_work``."""
         spans = self._spans
         if spans is not None and not self._has_work():
             spans = None
@@ -1078,10 +1093,6 @@ class ServingScheduler:
     def _drain_reason(self, plan) -> Optional[str]:
         """Why ``plan`` must wait for the step in flight to be fetched; None
         when it can be dispatched behind it."""
-        if not plan:
-            return "open"
-        if self._chunk_steps(plan):
-            return "decode_loop"  # its chunk returns host tokens: synchronous
         return None if self._closed(plan) else "open"
 
     def _evicted_total(self) -> int:
@@ -1744,10 +1755,10 @@ class ServingScheduler:
     def _build_batch(self) -> List[Tuple[Request, np.ndarray]]:
         """The next step's plan. It reads counts only — what is fed, who
         decodes, how many tokens each request has or has in flight — so it can
-        run while a step is on the device unfetched: a request whose token in
-        flight is its last (by length or context) is left out, a decode row
+        run while a step is on the device unfetched: a request whose tokens in
+        flight hold its last (by length or context) is left out, a decode row
         whose input is in flight carries a placeholder the engine overwrites
-        from the device ids (:meth:`_dispatch_put`), and what needs more than
+        from the device ids (:meth:`_feed`), and what needs more than
         counts (a draft, an eviction) sets ``_behind_block`` for the tick to
         fetch that step first and build again."""
         engine = self._engine
@@ -1791,7 +1802,7 @@ class ServingScheduler:
             if len(lens) + 1 > sm_cfg.max_ragged_sequence_count or sum(lens) + 1 > budget:
                 break
             if req._pending and len(req.tokens) + req._pending >= req.max_new_tokens:
-                continue  # the token in flight is its last: nothing to feed
+                continue  # its last token is in flight: nothing to feed
             seq = engine._state_manager.get_sequence(req.uid)
             if seq is not None and seq.seen_tokens + 1 > sm_cfg.max_context:
                 if req._pending:
@@ -1909,26 +1920,24 @@ class ServingScheduler:
 
     # --------------------------------------------------------------- execute --
     def _execute(self, plan: List[Tuple[Request, np.ndarray]]) -> None:
-        """Dispatch ``plan`` as one engine step. A verify step and a
-        ``decode_loop`` chunk are fetched and emitted here, at once (no step
-        is in flight beside them: :meth:`_drain_reason`). A ``put`` step is
-        dispatched (:meth:`_dispatch_put`: everything that needs only counts
-        happens there); then the step before it, if it is still in flight, is
-        fetched and emitted UNDER it (:meth:`_complete`: everything that needs
-        token values); and it stays in flight itself for the next tick to do
-        the same iff its plan is closed to arrivals (:meth:`_closed`) —
-        otherwise it is fetched and emitted now, and the next tick builds its
-        plan after every arrival this step's run time brought."""
-        engine = self._engine
-        uids = [req.uid for req, _ in plan]
-        tokens = [t for _, t in plan]
+        """Dispatch ``plan`` as one engine step. A verify step is fetched and
+        emitted here, at once (no step is in flight beside it:
+        ``_behind_block``). A ``decode_loop`` chunk or a ``put`` step is
+        dispatched (:meth:`_dispatch_chunk` / :meth:`_dispatch_put`:
+        everything that needs only counts happens there); then the step
+        before it, if it is still in flight, is fetched and emitted UNDER it
+        (:meth:`_complete`: everything that needs token values); and it stays
+        in flight itself for the next tick to do the same iff its plan is
+        closed to arrivals (:meth:`_closed`) — otherwise it is fetched and
+        emitted now, and the next tick builds its plan after every arrival
+        this step's run time brought. A chunk the KV pool has no room for
+        (``SchedulingError``: K steps a member) runs as a ``put`` step."""
         now = time.monotonic()
         for req, _ in plan:
             req._last_touch_s = now
         # close + re-anchor each member's KV block-second segment at its
         # pre-dispatch occupancy (the final segment closes at finalize)
         self._touch_kv_plan(plan)
-        spans = self._tick_spans
         tick = self._tick
         # each request's phase, before dispatch flips a PREFILL whose final
         # chunk this is to DECODE
@@ -1940,54 +1949,33 @@ class ServingScheduler:
             tick_no = tick["tick"]
             tick.update(seqs=len(plan), tokens=sum(n for _, n in phases), kind="put")
 
-        def record_phase_spans(counts=None):
-            self._record_phases(spans, plan, phases, t0, now_us(), tick_no, counts)
-
         # speculative verify: any decode entry carrying a TokenTree (a draft,
         # or the root alone for a learned head's hidden state) routes the
         # tick through ONE engine.verify_tree dispatch
         if any(req._spec_tree is not None for req, _ in plan):
-            self._synchronous_tick("verify_tree", "verify")
-            self._execute_verify_tree(plan, record_phase_spans)
+            self._count_fetched("verify")
+            if tick is not None:
+                tick.update(kind="verify_tree", pipelined=0, drain="verify")
+            self._execute_verify_tree(
+                plan, lambda counts=None: self._record_phases(
+                    self._tick_spans, plan, phases, t0, now_us(), tick_no, counts))
             return
 
         K = self._chunk_steps(plan)
-        if K:
-            try:
-                # decode_loop returns host tokens: the wait is inside its span
-                rows = self._fetch(engine.decode_loop(uids, tokens, K))
-            except SchedulingError:
-                rows = None  # KV too tight for K steps — single-step fallback
-            if rows is not None:
-                self._count_moe_path("chunks")
-                self._synchronous_tick("decode_loop", "decode_loop")
-                with self._emit_phase(spans):
-                    # record before pushing: the final token finalizes the
-                    # request and closes the root span, which children must
-                    # nest inside — with the kept-token counts driving BOTH the
-                    # span args and the push loop, so trace and stream cannot
-                    # disagree
-                    counts = [self._kept_tokens(req, row)
-                              for (req, _), row in zip(plan, rows)]
-                    self._rate.observe(sum(counts))
-                    # billed work is what the device ran: K decode steps per
-                    # member, kept or not (the discarded over-run still computed)
-                    self._charge_members([(req, "decode", K) for req, _ in plan])
-                    record_phase_spans(counts=counts)
-                    for (req, _), row, kept in zip(plan, rows, counts):
-                        req.decode_steps += 1
-                        # eos/cap discard the over-generated tail
-                        self._push_burst(req, row[:kept])
-                return
-
-        step = self._dispatch_put(plan, phases, t0, tick_no)
+        step = self._dispatch_chunk(plan, K, phases, t0, tick_no) if K else None
         if step is None:
-            return
-        self._counters["put_steps"] += 1
-        self._count_moe_path("steps")
+            step = self._dispatch_put(plan, phases, t0, tick_no)
+            if step is None:
+                return
+            self._counters["put_steps"] += 1
+            self._count_moe_path("steps")
         prev, self._inflight = self._inflight, step
+        if step.loop_steps and prev is not None:
+            self._counters["pipelined_chunks"] += 1
         if tick is not None:
             tick["pipelined"] = int(prev is not None)
+            if step.loop_steps:
+                tick["kind"] = "decode_loop"
             if prev is None:
                 tick["drain"] = self._sync_reason
         if prev is not None:
@@ -2034,13 +2022,6 @@ class ServingScheduler:
         return K if all(req.state is RequestState.DECODE and chunk_safe(req)
                         for req, _ in plan) else 0
 
-    def _synchronous_tick(self, kind: str, reason: str) -> None:
-        """A tick whose step is fetched inside its own engine call (a
-        ``decode_loop`` chunk, a verify step): nothing goes behind it."""
-        self._count_fetched(reason)
-        if self._tick is not None:
-            self._tick.update(kind=kind, pipelined=0, drain=reason)
-
     def _count_fetched(self, reason: Optional[str]) -> None:
         """A step is fetched: behind its successor (None), or before any was
         dispatched and why."""
@@ -2062,7 +2043,17 @@ class ServingScheduler:
                          args={"uid": req.uid, "tick": tick_no,
                                "tokens": ntok if counts is None else counts[i]})
 
-    def _dispatch_put(self, plan, phases, t0_us=0, tick_no=None) -> Optional[_PutStep]:
+    def _feed(self, reqs):
+        """``prev`` for the engine call that dispatches a step over ``reqs``
+        behind the step in flight: that step's device ids and, a request,
+        its row of them if its input token is still in flight (-1: the host's
+        token stands). None with no step in flight."""
+        prev = self._inflight
+        if prev is None:
+            return None
+        return prev.ids, [prev.row_of[req.uid] if req._pending else -1 for req in reqs]
+
+    def _dispatch_put(self, plan, phases, t0_us=0, tick_no=None) -> Optional[_Step]:
         """``plan`` through ``engine.put_draw``, NOT fetched, and everything a
         step changes that can be counted without its token values: ``_fed``,
         the PREFILL→DECODE flip of a request whose last chunk this is,
@@ -2074,12 +2065,9 @@ class ServingScheduler:
         takes it from that step's device ids. None if the engine raised (the
         plan's requests have then failed)."""
         reqs = [req for req, _ in plan]
-        prev = self._inflight
-        feed = None if prev is None else (
-            prev.ids, [prev.row_of[req.uid] if req._pending else -1 for req in reqs])
         try:
             ids = self._engine.put_draw([req.uid for req in reqs], [toks for _, toks in plan],
-                                        *self._draw_inputs(reqs), prev=feed)
+                                        *self._draw_inputs(reqs), prev=self._feed(reqs))
         except Exception as e:  # pragma: no cover - defensive: the scheduler
             # thread must survive an engine fault; the batch's requests fail
             logger.exception("serving: engine.put_draw failed; failing the batch")
@@ -2101,31 +2089,56 @@ class ServingScheduler:
             if row is not None:
                 req._pending += 1
             rows.append(row)
-        return _PutStep(plan, ids, rows, phases, t0_us, tick_no,
-                        getattr(self._engine, "last_moe_fetch", None))
+        return _Step(plan, ids, rows, phases, t0_us, tick_no,
+                     getattr(self._engine, "last_moe_fetch", None))
 
-    def _complete(self, step: _PutStep, reason: Optional[str]) -> int:
-        """Fetch ``step``'s ids and emit them: everything that needs token
-        VALUES (:meth:`_emit_rows`), and the step's phase spans. ``reason``
-        is why this happens before the step after it is dispatched
-        (``drained_steps_<reason>``), or None when that step is on the device
-        already (``pipelined_steps``). Returns when the fetch returned, on
-        the span clock."""
+    def _dispatch_chunk(self, plan, K, phases, t0_us=0, tick_no=None) -> Optional[_Step]:
+        """``plan`` — decode rows only — through
+        ``engine.dispatch_decode_loop`` as one chunk of ``K`` steps, NOT
+        fetched, and what a chunk changes that can be counted without its
+        token values, as :meth:`_dispatch_put` does for a ``put`` step: one
+        dispatch a member (``decode_steps``), K tokens in flight each
+        (``_pending``), billing — what the device runs, K decode steps per
+        member, kept or not. None, with nothing changed, if the KV pool has no
+        room for K steps a member."""
+        reqs = [req for req, _ in plan]
+        try:
+            chunk = self._engine.dispatch_decode_loop(
+                [req.uid for req in reqs], [toks for _, toks in plan], K, prev=self._feed(reqs))
+        except SchedulingError:
+            return None
+        self._count_moe_path("chunks")
+        self._charge_members([(req, "decode", K) for req in reqs])
+        for req in reqs:
+            req.decode_steps += 1
+            req._pending += K
+        return _Step(plan, chunk, ["decode"] * len(plan), phases, t0_us, tick_no, loop_steps=K)
+
+    def _complete(self, step: _Step, reason: Optional[str]) -> int:
+        """Fetch ``step``'s result and emit it: everything that needs token
+        VALUES (:meth:`_emit_rows` / :meth:`_emit_chunk`), and the step's
+        phase spans. ``reason`` is why this happens before the step after it
+        is dispatched (``drained_steps_<reason>``), or None when that step is
+        on the device already (``pipelined_steps``). Returns when the fetch
+        returned, on the span clock."""
         self._count_fetched(reason)
         spans = self._tick_spans
         try:
-            ids = self._fetch(step.ids, step.moe)
+            out = self._fetch(step.result, step.moe)
         except Exception as e:  # pragma: no cover - defensive, as for the dispatch
-            logger.exception("serving: fetching a step's ids failed; failing the batch")
+            logger.exception("serving: fetching a step's result failed; failing the batch")
             for req, _ in step.plan:
                 self._finalize(req, RequestState.FAILED, error=f"engine error: {e}")
             return now_us()
         fetched_us = now_us()
         with self._emit_phase(spans):
-            self._rate.observe(sum(n for _, n in step.phases))
-            self._record_phases(spans, step.plan, step.phases, step.t0_us, fetched_us,
-                                step.tick)
-            self._emit_rows(step, ids)
+            if step.loop_steps:
+                self._emit_chunk(step, out, fetched_us)
+            else:
+                self._rate.observe(sum(n for _, n in step.phases))
+                self._record_phases(spans, step.plan, step.phases, step.t0_us, fetched_us,
+                                    step.tick)
+                self._emit_rows(step, out)
         return fetched_us
 
     def _sync(self, reason: str) -> bool:
@@ -2137,7 +2150,33 @@ class ServingScheduler:
         self._complete(step, reason)
         return True
 
-    def _emit_rows(self, step: _PutStep, ids: np.ndarray) -> None:
+    def _emit_chunk(self, step: _Step, rows: np.ndarray, fetched_us: int) -> None:
+        """Stream what a ``decode_loop`` chunk generated: row i of ``rows``
+        ``[members, K]`` is ``plan[i]``'s. The device loop always runs K
+        steps; eos and the ``max_new_tokens`` cap cut a row's tail
+        (:meth:`_kept_tokens`), and a request that ended while the chunk was
+        in flight — eos in the chunk before it, cancel, deadline — has its
+        whole row discarded (``overrun_rows``), as :meth:`_emit_rows` does
+        with a ``put`` row. The kept counts drive BOTH the phase spans' args
+        and the push loop, so trace and stream cannot disagree; the spans are
+        recorded before pushing: the final token finalizes the request and
+        closes the root span, which children must nest inside."""
+        counts = []
+        for (req, _), row in zip(step.plan, rows):
+            if req.finished:
+                self._counters["overrun_rows"] += 1
+                counts.append(0)
+            else:
+                req._pending -= step.loop_steps
+                counts.append(self._kept_tokens(req, row))
+        self._rate.observe(sum(counts))
+        self._record_phases(self._tick_spans, step.plan, step.phases, step.t0_us, fetched_us,
+                            step.tick, counts)
+        for (req, _), row, kept in zip(step.plan, rows, counts):
+            if kept:
+                self._push_burst(req, row[:kept])
+
+    def _emit_rows(self, step: _Step, ids: np.ndarray) -> None:
         """Stream what ``step`` drew: entry i of ``ids`` is ``plan[i]``'s
         token. On a prompt's first token its blocks are published (peers
         sharing the prefix are likely already queued behind it — the burst
@@ -2158,7 +2197,7 @@ class ServingScheduler:
             if row == "first" and self._prefix_cache is not None:
                 seq = self._engine._state_manager.get_sequence(req.uid)
                 if seq is not None:
-                    # a step behind this one may have committed a position more
+                    # a step behind this one may have committed positions more
                     self._publish(req, seq, req.prompt,
                                   min(seq.seen_tokens, int(req.prompt.size)))
             self._push_drawn(req, int(ids[i]))
@@ -2171,7 +2210,9 @@ class ServingScheduler:
         the last a device array on its way to the host since the launch
         (``engine.last_moe_fetch``): the span carries the three, the count
         summed over the expert layers — read behind the result, when the
-        device is done with the step."""
+        device is done with the step. (A chunk's result,
+        :class:`DecodeChunk`, converts itself: its tokens ``[members, K]``,
+        and its own ``decode_loop`` span learns its count and ``fetch_us``.)"""
         spans = self._tick_spans
         if spans is None:
             return np.asarray(result)

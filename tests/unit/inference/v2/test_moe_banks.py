@@ -3,8 +3,9 @@
 ``(group_sizes > 0).sum()`` of every expert layer; the engine reads it only
 under a telemetry session, where the step's result is already on the host, and
 the spans that carry ``moe_banks`` carry ``moe_assignments`` and ``moe_path``
-of the same step(s). A ``decode_loop`` span also says how its call splits into
-launch and fetch. Tiny afmoe (top-4 of 64: the 8-token bucket and the
+of the same step(s). A ``decode_loop`` span is the chunk's launch; what only
+the fetch can say (``fetch_us``, a grouped chunk's ``moe_banks``) is written
+into its args when the chunk is fetched, in the call or ticks later (PR 38). Tiny afmoe (top-4 of 64: the 8-token bucket and the
 ``decode_loop``s are grouped) and tiny Mellum (top-8 of 64: the 128-token
 bucket is) engines on the CPU."""
 
@@ -151,9 +152,21 @@ def test_a_grouped_chunk_returns_its_banks_a_step_a_layer_and_the_span_sums_them
     args = span["args"]
     assert args["moe_banks"] == banks.sum() and isinstance(args["moe_banks"], int)
     assert args["moe_path"] == "grouped" and args["moe_assignments"] == n_seqs * 4 * 4 * 4
-    # the call's two parts, on the span's own clock
-    assert args["launch_us"] >= 0 and args["fetch_us"] >= 0
-    assert args["launch_us"] + args["fetch_us"] <= span["dur_us"] + 1
+    # the span is the launch; the fetch wrote its own part when it happened
+    assert 0 <= args["launch_us"] <= span["dur_us"] + 1 and args["fetch_us"] > 0
+
+
+def test_a_grouped_chunk_left_in_flight_says_its_banks_when_it_is_fetched(wide_model, session):
+    engine = test_afmoe._engine(wide_model)
+    engine.put([0], [_ids(10, 6)])
+    chunk = engine.dispatch_decode_loop([0], [_ids(20, 1)], 4)
+    (span, ) = _spans(session, "decode_loop", "inference")
+    assert span["args"]["fetch_us"] == 0 and "moe_banks" not in span["args"]
+    assert span["args"]["steps"] == 4 and span["args"]["moe_path"] == "grouped"
+    assert chunk.fetch().shape == (1, 4)
+    (span, ) = _spans(session, "decode_loop", "inference")
+    # one live row touches exactly top-k banks a layer-step
+    assert span["args"]["fetch_us"] > 0 and span["args"]["moe_banks"] == 4 * 4 * 4
 
 
 def test_a_capacity_chunks_span_says_every_bank_at_entry(narrow_model, session):
@@ -207,6 +220,32 @@ def test_the_fetch_of_a_grouped_put_step_carries_its_banks_with_its_assignments(
     # each step's assignments are counted once among the spans that carry banks
     assert sorted(s["args"]["moe_assignments"] for s in fetches) == \
         sorted(s["args"]["moe_assignments"] for s in grouped_puts)
+
+
+def test_chunks_in_flight_are_each_in_one_carrier_with_their_steps(wide_model, session):
+    """Eight greedy requests fill the sequence cap: their chunks of 4 go behind
+    one another, each fetched under its successor by the scheduler's ``fetch``
+    span — which carries no banks for a chunk: the chunk's own ``decode_loop``
+    span does, with the ``steps`` a reader prices them over, so a step is in
+    exactly one span that carries ``moe_banks``."""
+    counters = _serve(test_afmoe._engine(wide_model), decode_chunk=4, lengths=(5, ) * 8,
+                      new_tokens=14)
+    assert counters["pipelined_chunks"] >= 2 and counters["moe_grouped_chunks"] >= 3
+    rows = session.spans.export_since(0)["spans"]
+    loops = [s for s in rows if (s["name"], s["cat"]) == ("decode_loop", "inference")]
+    assert len(loops) == counters["moe_grouped_chunks"]
+    for loop in loops:
+        args = loop["args"]
+        live = len(args["uids"])
+        assert args["steps"] == 4 and args["moe_assignments"] == live * 4 * 4 * 4
+        assert 4 * 4 * 4 <= args["moe_banks"] <= args["moe_assignments"] and args["fetch_us"] > 0
+    carrying = [s for s in rows if "moe_banks" in (s.get("args") or {})
+                and s["args"].get("moe_path") == "grouped"]
+    fetches = [s for s in carrying if (s["name"], s["cat"]) == ("fetch", "sched")]
+    assert len(carrying) == len(loops) + len(fetches)
+    assert len(fetches) == counters["moe_grouped_steps"]   # the grouped put steps, no chunk
+    assert all("steps" not in s["args"] for s in fetches)
+    assert any(s["args"].get("chained") for s in loops)   # fed from the step before, on the device
 
 
 # -------------------------------------------- nothing more is fetched when off ---

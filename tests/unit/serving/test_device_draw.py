@@ -258,6 +258,108 @@ def test_put_path_fetches_ids_builds_nothing_and_counts_its_draws(make_engine, l
     assert all(e["args"]["sample_us"] == 0 for e in emits)
 
 
+# ---------------------------------------------- chunks fed from device ids --
+def test_a_chunk_fed_through_prev_returns_the_tokens_of_one_fed_from_the_host(make_engine,
+                                                                              llama_setup):
+    """``put_draw`` -> chunk -> chunk -> ``put_draw`` with nothing fetched
+    between (each fed from the ids, or the last row, of the one before, and
+    one sequence of the second chunk from the host's token), against the same
+    four calls each fetched before the next is made."""
+    cfg, _, _ = llama_setup
+    prompts = [np.asarray(_prompt(cfg, n, seed=40 + n), np.int32) for n in (9, 13)]
+    uids, greedy = [0, 1], (np.zeros(2, np.float32), np.zeros(2, np.uint32),
+                            np.zeros(2, np.int32))
+    placeholder = [np.zeros(1, np.int32)] * 2
+
+    host = make_engine()
+    first = np.asarray(host.put_draw(uids, prompts, *greedy))[:2]
+    one = host.decode_loop(uids, list(first), 4)
+    two = host.decode_loop(uids, list(one[:, -1]), 4)
+    last = np.asarray(host.put_draw(uids, list(two[:, -1]), *greedy))[:2]
+
+    engine = make_engine()
+    ids = engine.put_draw(uids, prompts, *greedy)
+    chunk_one = engine.dispatch_decode_loop(uids, placeholder, 4, prev=(ids, [0, 1]))
+    assert chunk_one.ids.shape == (8, ) and chunk_one.tokens.shape == (4, 8)
+    # sequence 1 from the host's token: what a request new to the batch is fed
+    chunk_two = engine.dispatch_decode_loop(
+        uids, [np.zeros(1, np.int32), one[1, -1:]], 4, prev=(chunk_one.ids, [0, -1]))
+    after = engine.put_draw(uids, placeholder, *greedy, prev=(chunk_two.ids, [0, 1]))
+    # the bookkeeping was all done at dispatch: nothing is fetched yet
+    assert engine._state_manager.get_sequence(0).seen_tokens == 9 + 4 + 4 + 1
+    assert np.asarray(ids)[:2].tolist() == first.tolist()
+    assert chunk_one.fetch().tolist() == one.tolist()
+    assert np.asarray(chunk_two).tolist() == two.tolist()   # np.asarray of a chunk fetches it
+    assert np.asarray(after)[:2].tolist() == last.tolist()
+    assert np.asarray(chunk_two.ids)[:2].tolist() == two[:, -1].tolist()
+
+
+def test_warm_draw_builds_the_last_row_program_and_chunks_in_flight_compile_nothing(
+        make_engine, llama_setup, monkeypatch):
+    """A scheduler that runs chunks builds, in ``__init__``, the program that
+    takes a chunk's last row, a sequence bucket each (one that does not,
+    none); then two requests under a cap of two sequences, chunk behind chunk:
+    a second pass over the warmed buckets compiles nothing, the chunk's program
+    is the host-fed one under its key, and the streams repeat."""
+    import jax.monitoring
+    cfg, _, _ = llama_setup
+    monkeypatch.setattr(sampling, "_EXECUTABLES", {})
+    mgr = dict(max_ragged_batch_size=64, max_ragged_sequence_count=2)
+    plain = ServingScheduler(make_engine(**mgr), ServingConfig(), start=False)
+    plain.stop(drain=False)
+    assert not [k for k in sampling._EXECUTABLES if k[0] == "last_row"]
+    engine = make_engine(**mgr)
+
+    def serve():
+        sched = ServingScheduler(engine, ServingConfig(decode_chunk=4), start=False)
+        built = dict(sampling._EXECUTABLES)
+        reqs = [sched.submit(_prompt(cfg, n, seed=n), max_new_tokens=14) for n in (9, 11)]
+        _run_until(sched, lambda: all(r.finished for r in reqs))
+        counters = sched.stats()["counters"]
+        sched.stop(drain=False)
+        assert dict(sampling._EXECUTABLES) == built   # none after __init__
+        return [r.result() for r in reqs], counters
+
+    first, _ = serve()
+    assert [k for k in sampling._EXECUTABLES if k[0] == "last_row"] == [("last_row", 4, 8)]
+    keys = set(engine.model._compiled)
+    compiled = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, *_a, **_k: compiled.append(name) if "backend_compile" in name else None)
+    again, counters = serve()
+    assert again == first and counters["pipelined_chunks"] >= 3
+    assert compiled == [] and set(engine.model._compiled) == keys
+    # engine.decode_loop lands in the program the chunks in flight ran
+    engine.put([10_001], [np.zeros(20, np.int32)])
+    engine.decode_loop([10_001], [np.zeros(1, np.int32)], 4)
+    engine.flush(10_001)
+    assert set(engine.model._compiled) == keys
+
+
+def test_a_greedy_chunks_key_and_temperature_are_put_on_the_device_once(make_engine, llama_setup,
+                                                                        monkeypatch):
+    import jax
+    cfg, _, _ = llama_setup
+    made = []
+    key = jax.random.PRNGKey
+    monkeypatch.setattr(jax.random, "PRNGKey", lambda seed: made.append(seed) or key(seed))
+    engine = make_engine()
+    prompt = np.asarray(_prompt(cfg, 9, seed=5), np.int32)
+    nxt = np.asarray(engine.put_draw([0], [prompt], [0.0], [0], [0]))[:1]
+    assert engine.model._greedy_sampler is None
+    out = []
+    for _ in range(3):
+        out.append(engine.decode_loop([0], [nxt], 4))
+        nxt = out[-1][0, -1:]
+        kept = kept if len(out) > 1 else engine.model._greedy_sampler
+        assert engine.model._greedy_sampler is kept
+    assert made == [0]
+    # the tokens are a fresh engine's, one chunk of twelve
+    twin = make_engine()
+    first = np.asarray(twin.put_draw([0], [prompt], [0.0], [0], [0]))[:1]
+    assert np.concatenate(out, axis=1).tolist() == twin.decode_loop([0], [first], 12).tolist()
+
+
 def test_speculation_draws_from_host_rows_and_says_so(make_engine, llama_setup):
     cfg, _, _ = llama_setup
     spec = ServingConfig(speculative=SpeculativeConfig(enabled=True, max_draft_tokens=3),

@@ -1,8 +1,9 @@
-"""The one-deep pipeline of the scheduler's ``put`` path: a step whose plan is
-closed to arrivals is dispatched before the step before it is fetched, its
-decode rows fed from that step's device ids; anything that needs token values
-or an idle engine fetches the step in flight first. Whatever a tick does, a
-request's tokens are the same.
+"""The scheduler's one-deep pipeline: a step — a ``put`` step or a
+``decode_loop`` chunk — whose plan is closed to arrivals is dispatched before
+the step before it is fetched, its decode rows fed from that step's device
+ids (a chunk's: its last row); anything that needs token values or an idle
+engine fetches the step in flight first. Whatever a tick does, a request's
+tokens are the same.
 """
 
 import threading
@@ -14,6 +15,7 @@ import pytest
 import jax.monitoring
 
 from deepspeed_tpu import telemetry
+from deepspeed_tpu.inference.v2.scheduling_utils import SchedulingError, SchedulingResult
 from deepspeed_tpu.serving import (RequestState, ServingConfig, ServingScheduler,
                                    SpeculativeConfig)
 
@@ -22,6 +24,12 @@ MAX_STEPS = 600
 WORK = [(40, 6), (23, 9), (70, 4), (9, 12)]
 CLOSED = dict(max_ragged_batch_size=16, max_ragged_sequence_count=8)
 OPEN = dict(max_ragged_batch_size=512, max_ragged_sequence_count=16)
+# chunks of four steps under a cap of two sequences: two decoding requests are
+# a closed chunk plan. A finishes by length inside the second chunk, C's prompt
+# then rides beside B's decode row (a put step), then B and C decode together
+CHUNKED = ServingConfig(decode_chunk=4)
+TWO_SEQS = dict(max_ragged_batch_size=64, max_ragged_sequence_count=2)
+CHUNK_WORK = [(9, 6), (11, 17), (7, 9)]
 
 
 def _run_until(sched, pred, max_steps=MAX_STEPS):
@@ -112,9 +120,25 @@ class _Ids:
         return np.asarray(self.ids)
 
 
+class _Chunk:
+    """A chunk in flight; fetching it, and handing on its last row, is logged."""
+
+    def __init__(self, chunk, n, uids, log):
+        self.chunk, self.n, self.uids, self.log = chunk, n, uids, log
+
+    @property
+    def ids(self):
+        return _Ids(self.chunk.ids, self.n, self.log)
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("fetch", self.n))
+        return self.chunk.fetch()
+
+
 class _RecordingEngine:
-    """The engine, with every ``put_draw`` and every fetch of its ids logged
-    in the order the scheduler made them."""
+    """The engine, with every ``put_draw``, every ``dispatch_decode_loop`` and
+    every fetch of what they returned logged in the order the scheduler made
+    them; steps are numbered in dispatch order, whatever their kind."""
 
     def __init__(self, engine):
         self.__dict__["_engine"] = engine
@@ -127,14 +151,25 @@ class _RecordingEngine:
     def __setattr__(self, name, value):
         setattr(self._engine, name, value)
 
-    def put_draw(self, uids, tokens, *draw, **kw):
-        n = sum(1 for e in self.log if e[0] == "put_draw") + 1
+    def _dispatched(self, kind, uids, kw):
+        n = sum(1 for e in self.log if e[0] != "fetch") + 1
         if kw.get("prev") is not None:
             ids, index = kw["prev"]
             self.chained[n] = (ids.n, dict(zip(uids, index)))
             kw["prev"] = (ids.ids, index)
-        self.log.append(("put_draw", n))
+        self.log.append((kind, n))
+        return n
+
+    def put_draw(self, uids, tokens, *draw, **kw):
+        n = self._dispatched("put_draw", uids, kw)
         return _Ids(self._engine.put_draw(uids, tokens, *draw, **kw), n, self.log)
+
+    def dispatch_decode_loop(self, uids, tokens, n_steps, **kw):
+        if self.__dict__.get("refuse_chunks"):
+            raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
+        n = self._dispatched("decode_loop", uids, kw)
+        return _Chunk(self._engine.dispatch_decode_loop(uids, tokens, n_steps, **kw), n,
+                      list(uids), self.log)
 
 
 def test_a_pipelined_tick_dispatches_the_next_step_before_it_fetches_the_last(
@@ -185,6 +220,139 @@ def test_a_prompts_last_chunk_in_flight_feeds_its_first_decode_row_from_the_devi
     assert list(req.tokens) == list(ref.tokens) and len(req.tokens) == 5
 
 
+# ------------------------------------------------------- chunks in flight --
+def _serve_recorded(make_engine, cfg, serving, work, submit=None, **mgr):
+    """``work`` through a recording engine: the streams, the counters, the
+    recorder, and the uid of each request."""
+    engine = _RecordingEngine(make_engine(**mgr))
+    start = engine.free_blocks
+    sched = ServingScheduler(engine, serving, start=False)
+    reqs = (submit or _submit_all)(sched, cfg, work=work)
+    _run_until(sched, lambda: all(r.finished for r in reqs))
+    counters = sched.stats()["counters"]
+    sched.stop(drain=False)
+    assert engine.free_blocks == start
+    return [list(r.tokens) for r in reqs], counters, engine, [r.uid for r in reqs]
+
+
+@pytest.fixture(scope="module")
+def chunked(llama_setup):
+    """CHUNK_WORK under CHUNKED: through the draining scheduler (plans that
+    stay open: every chunk fetched in its own tick) and with chunks in flight."""
+    return {}
+
+
+def _chunked(chunked, make_engine, cfg):
+    if not chunked:
+        want, drained = _serve(make_engine, cfg, serving=CHUNKED, work=CHUNK_WORK, **OPEN)
+        assert drained["pipelined_steps"] == drained["pipelined_chunks"] == 0
+        assert drained["batches"] - drained["put_steps"] >= 4   # it did run chunks
+        chunked.update(want=want, run=_serve_recorded(make_engine, cfg, CHUNKED, CHUNK_WORK,
+                                                      **TWO_SEQS))
+    return chunked["want"], chunked["run"]
+
+
+@pytest.mark.parametrize("before, after", [("decode_loop", "decode_loop"),
+                                           ("put_draw", "decode_loop"),
+                                           ("decode_loop", "put_draw")],
+                         ids=["chunk->chunk", "put->chunk", "chunk->put"])
+def test_a_step_goes_behind_a_chunk_and_a_chunk_behind_a_step_with_the_same_streams(
+        make_engine, llama_setup, chunked, before, after):
+    """Request by request the streams are the draining scheduler's; and the
+    transition named happened: the successor was dispatched before its
+    predecessor was fetched, fed from the predecessor's device ids, and the
+    predecessor was fetched right after."""
+    cfg, _, _ = llama_setup
+    want, (tokens, counters, engine, _) = _chunked(chunked, make_engine, cfg)
+    assert tokens == want and [len(t) for t in tokens] == [m for _, m in CHUNK_WORK]
+    log = engine.log
+    at = {e: i for i, e in enumerate(log)}
+    kind = {n: k for k, n in log if k != "fetch"}
+    behind = [n for n in engine.chained
+              if (kind[n - 1], kind[n]) == (before, after)
+              and at[(kind[n], n)] < at[("fetch", n - 1)]]
+    assert behind, log
+    for n in behind:
+        assert log[at[(kind[n], n)] + 1] == ("fetch", n - 1)
+        fed_from, rows = engine.chained[n]
+        assert fed_from == n - 1 and any(r >= 0 for r in rows.values())
+    assert counters["overrun_rows"] == 0
+    assert _counted(counters) == counters["batches"]
+    chunks = [n for n in kind if kind[n] == "decode_loop"]
+    assert counters["batches"] - counters["put_steps"] == len(chunks)
+    assert counters["pipelined_chunks"] == sum(
+        1 for n in chunks if n in engine.chained and at[("decode_loop", n)] < at[("fetch", n - 1)])
+    assert counters["pipelined_chunks"] >= 3
+
+
+def test_a_member_that_finishes_by_length_inside_a_chunk_is_not_in_the_next(
+        make_engine, llama_setup, chunked):
+    """A needs 6 tokens: one from its prompt's step, four from the first
+    chunk, one from the second. The second chunk is dispatched with the first
+    in flight and holds A (5 counted of 6); the step after it does not, though
+    nothing of the second chunk has been fetched when it is built — and no row
+    ran for a request that had ended."""
+    cfg, _, _ = llama_setup
+    _, (tokens, counters, engine, uids) = _chunked(chunked, make_engine, cfg)
+    a = uids[0]
+    kind = {n: k for k, n in engine.log if k != "fetch"}
+    with_a = [n for n, (_, rows) in engine.chained.items() if a in rows]
+    assert [kind[n] for n in with_a] == ["decode_loop", "decode_loop"]
+    last = with_a[-1]
+    assert kind[last + 1] == "put_draw" and a not in engine.chained[last + 1][1]
+    at = {e: i for i, e in enumerate(engine.log)}
+    assert at[("put_draw", last + 1)] < at[("fetch", last)]
+    assert len(tokens[0]) == 6 and counters["overrun_rows"] == 0
+
+
+def test_a_finished_members_kv_is_freed_when_its_chunk_is_fetched(make_engine, llama_setup):
+    cfg, _, _ = llama_setup
+    engine = make_engine(**TWO_SEQS)
+    sched = ServingScheduler(engine, CHUNKED, start=False)
+    a, b = _submit_all(sched, cfg, work=CHUNK_WORK[:2])
+    _run_until(sched, lambda: a.finished)
+    assert a.finish_reason == "length" and len(a.tokens) == 6
+    sm = engine._state_manager
+    assert sm.get_sequence(a.uid) is None and sm.get_sequence(b.uid) is not None
+    _run_until(sched, lambda: b.finished)
+    sched.stop(drain=False)
+    assert sm.n_tracked_sequences == 0
+
+
+def test_a_chunk_the_pool_has_no_room_for_runs_as_a_put_step_behind_the_step_in_flight(
+        make_engine, llama_setup, chunked):
+    """``SchedulingError`` from the chunk's dispatch (K steps a member do not
+    fit the KV pool) leaves nothing changed: the same plan goes as a ``put``
+    step, behind the step in flight as it would have."""
+    cfg, _, _ = llama_setup
+    want, _ = _chunked(chunked, make_engine, cfg)
+    engine = _RecordingEngine(make_engine(**TWO_SEQS))
+    engine.__dict__["refuse_chunks"] = True
+    sched = ServingScheduler(engine, CHUNKED, start=False)
+    reqs = _submit_all(sched, cfg, work=CHUNK_WORK)
+    _run_until(sched, lambda: all(r.finished for r in reqs))
+    counters = sched.stats()["counters"]
+    sched.stop(drain=False)
+    assert [list(r.tokens) for r in reqs] == want
+    assert all(k != "decode_loop" for k, _ in engine.log)
+    assert counters["put_steps"] == counters["batches"] and counters["pipelined_chunks"] == 0
+    assert counters["pipelined_steps"] >= 10 and _counted(counters) == counters["batches"]
+
+
+def test_a_pool_too_tight_for_a_chunk_falls_back_for_real(make_engine, llama_setup):
+    """Two blocks of 16, a sequence each: a chunk of 8 from position 11 would
+    need a second block a member. Every decode step is a ``put`` step, and the
+    streams are those of a pool with room."""
+    cfg, _, _ = llama_setup
+    work = [(10, 6), (9, 6)]
+    serving = ServingConfig(decode_chunk=8)
+    want, roomy = _serve(make_engine, cfg, serving=serving, work=work, **TWO_SEQS)
+    assert roomy["batches"] > roomy["put_steps"]
+    got, tight = _serve(make_engine, cfg, serving=serving, work=work, num_blocks=2, **TWO_SEQS)
+    assert got == want and tight["batches"] == tight["put_steps"] == 6
+    assert tight["pipelined_steps"] >= 4
+
+
 # ------------------------------------------------ ends counted one step early --
 @pytest.mark.parametrize("end", ["length", "context"])
 def test_a_request_whose_token_in_flight_is_its_last_is_not_in_the_next_plan(
@@ -221,17 +389,24 @@ def test_a_request_whose_token_in_flight_is_its_last_is_not_in_the_next_plan(
 
 
 # ------------------------------------------- ends the host cannot count ahead --
+@pytest.mark.parametrize("chunk", [0, 4], ids=["put_row", "chunk_row"])
 @pytest.mark.parametrize("end", ["eos", "cancel", "deadline"])
 def test_a_row_in_flight_for_a_request_that_ended_is_discarded_never_streamed(
-        make_engine, llama_setup, end):
+        make_engine, llama_setup, end, chunk):
+    """``chunk``: the row in flight is a ``put`` step's (sampled requests), or
+    a ``decode_loop`` chunk's four tokens (greedy ones): the eos falls inside
+    one chunk with the next already dispatched, the cancel and the deadline
+    between two fetches."""
     cfg, _, _ = llama_setup
     mgr = dict(max_ragged_batch_size=64, max_ragged_sequence_count=2)
     work = [(9, 12), (11, 12)]
-    full, _ = _serve(make_engine, cfg, 0.8, work=work, **mgr)
+    temperature = 0.0 if chunk else 0.8
+    serving = ServingConfig(decode_chunk=chunk) if chunk else ServingConfig()
+    full, _ = _serve(make_engine, cfg, temperature, serving=serving, work=work, **mgr)
 
     engine = make_engine(**mgr)
     start = engine.free_blocks
-    sched = ServingScheduler(engine, ServingConfig(), start=False)
+    sched = ServingScheduler(engine, serving, start=False)
     kw = {}
     cut = 5
     if end == "eos":
@@ -239,8 +414,8 @@ def test_a_row_in_flight_for_a_request_that_ended_is_discarded_never_streamed(
         cut = next(i for i, t in enumerate(full[0]) if i >= 3 and t not in full[0][:i])
         kw["eos_token_id"] = full[0][cut]
     prompts = _prompts(cfg, work)
-    a = sched.submit(prompts[0], max_new_tokens=12, temperature=0.8, seed=7, **kw)
-    b = sched.submit(prompts[1], max_new_tokens=12, temperature=0.8, seed=8)
+    a = sched.submit(prompts[0], max_new_tokens=12, temperature=temperature, seed=7, **kw)
+    b = sched.submit(prompts[1], max_new_tokens=12, temperature=temperature, seed=8)
     if end == "eos":
         _run_until(sched, lambda: a.finished)
         assert a.finish_reason == "eos" and list(a.tokens) == full[0][:cut + 1]
@@ -265,54 +440,69 @@ def test_a_row_in_flight_for_a_request_that_ended_is_discarded_never_streamed(
 
 
 # ------------------------------------------------------------ drain reasons --
-def _drain_case(reason):
-    """A configuration and workload under which a step in flight meets
-    ``reason``."""
-    if reason == "decode_loop":
-        # greedy, chunks of 4: after the (closed) prompt chunks every plan is
-        # decode-only and takes decode_loop
-        return dict(serving=ServingConfig(decode_chunk=4), mgr=CLOSED, temperature=0.0)
+# four sequences a step and four requests: the decode plans are closed while
+# all four decode, and every prompt chunk fills the token budget
+FOUR_SEQS = dict(max_ragged_batch_size=16, max_ragged_sequence_count=4)
+FOUR_SHORT = [(9, 10), (11, 13), (7, 18), (12, 16)]
+
+
+def _drain_case(reason, chunk):
+    """A configuration and workload under which a step in flight — with
+    ``chunk``, a ``decode_loop`` chunk of that many steps — meets ``reason``."""
     if reason == "verify":
         return dict(serving=ServingConfig(speculative=SpeculativeConfig(
             enabled=True, max_draft_tokens=3)), mgr=CLOSED, temperature=0.0)
     if reason == "pressure":
         # 8 blocks of 16 under three 60-token prompts, two sequences a step:
         # a plan behind a step in flight would have to evict
-        return dict(serving=ServingConfig(), temperature=0.0,
+        return dict(serving=ServingConfig(decode_chunk=chunk), temperature=0.0,
                     mgr=dict(num_blocks=8, max_context=128, max_ragged_batch_size=16,
                              max_ragged_sequence_count=2),
                     work=[(60, 8), (60, 8), (60, 8)])
+    if chunk > 1:
+        return dict(serving=ServingConfig(decode_chunk=chunk), mgr=FOUR_SEQS, work=FOUR_SHORT,
+                    temperature=0.0)
     return dict(serving=ServingConfig(), mgr=CLOSED, temperature=0.8)
 
 
-@pytest.mark.parametrize("reason", ["open", "decode_loop", "verify", "pressure", "control",
-                                    "stop"])
+# (a verify step behind a chunk: these greedy streams repeat, so a request
+# that decodes always has a draft and no tick of a speculating scheduler is a
+# chunk; the rule that drains for it is the build's, whatever is in flight)
+@pytest.mark.parametrize("reason, chunk", [
+    ("open", 1), ("verify", 1), ("pressure", 1), ("control", 1), ("stop", 1),
+    ("open", 4), ("pressure", 4), ("control", 4), ("stop", 4)],
+    ids=lambda v: {1: "put_steps", 4: "chunks"}.get(v, v))
 def test_each_drain_reason_fires_where_it_should_and_the_stream_is_unchanged(
-        make_engine, llama_setup, reason):
+        make_engine, llama_setup, reason, chunk):
     cfg, _, _ = llama_setup
-    case = _drain_case(reason)
+    case = _drain_case(reason, chunk)
     work = case.get("work", WORK)
     open_mgr = dict(OPEN, **{k: v for k, v in case["mgr"].items()
                              if k in ("max_context", )})
-    want, _ = _serve(make_engine, cfg, case["temperature"], work=work, **open_mgr)
+    want, _ = _serve(make_engine, cfg, case["temperature"], work=work,
+                     serving=ServingConfig(decode_chunk=chunk), **open_mgr)
 
     engine = make_engine(**case["mgr"])
     start = engine.free_blocks
     sched = ServingScheduler(engine, case["serving"], start=False)
     reqs = _submit_all(sched, cfg, case["temperature"], work)
     ran = []
+    def in_flight():
+        step = sched._inflight
+        return step is not None and (chunk == 1 or step.loop_steps == chunk)
+
     if reason == "control":
-        _run_until(sched, lambda: sched._inflight is not None)
+        _run_until(sched, in_flight)
         box = {"done": threading.Event(), "result": None, "error": None}
         # as a handler thread queues it (serving/scheduler.py:_call_on_loop)
         sched._control.append((lambda: ran.append(sched._inflight), box))
         sched.step()
         assert box["done"].is_set() and ran == [None]   # it ran beside an idle engine
         # a manually stepped scheduler runs a control call inline: same rule
-        _run_until(sched, lambda: sched._inflight is not None)
+        _run_until(sched, in_flight)
         assert sched._call_on_loop(lambda: sched._inflight) is None
     if reason == "stop":
-        _run_until(sched, lambda: sched._inflight is not None
+        _run_until(sched, lambda: in_flight()
                    and sched.stats()["counters"]["pipelined_steps"] >= 1)
         sched.stop(drain=True, timeout=60.0)
     else:
@@ -325,6 +515,10 @@ def test_each_drain_reason_fires_where_it_should_and_the_stream_is_unchanged(
     assert counters[f"drained_steps_{reason}"] >= (2 if reason == "control" else 1), counters
     assert counters["overrun_rows"] == 0
     assert _counted(counters) == counters["batches"]
+    if chunk > 1:
+        assert counters["batches"] - counters["put_steps"] - counters["spec_steps"] >= 1
+        if reason != "pressure":   # its chunks hold one sequence of two: open plans
+            assert counters["pipelined_chunks"] >= 1, counters
     if reason == "pressure":
         assert counters["evictions"] >= 1
 
@@ -410,6 +604,48 @@ def test_tick_spans_say_whether_their_step_went_behind_the_last_and_why_not(
     assert len(steps) == counters["put_steps"]
     assert all(b[0] >= a[1] for a, b in zip(steps, steps[1:]))
     assert sum(b[0] == a[1] for a, b in zip(steps, steps[1:])) == counters["pipelined_steps"]
+
+
+def test_chunk_ticks_say_the_same_and_their_spans_keep_what_readers_index(
+        make_engine, llama_setup):
+    cfg, _, _ = llama_setup
+    telemetry.configure(telemetry.TelemetryConfig(enabled=True))
+    sched = ServingScheduler(make_engine(**TWO_SEQS), CHUNKED, start=False)
+    reqs = _submit_all(sched, cfg, work=CHUNK_WORK)
+    _run_until(sched, lambda: all(r.finished for r in reqs))
+    counters = sched.stats()["counters"]
+    sched.stop(drain=False)
+    spans = telemetry.get_span_recorder().export_since(0)["spans"]
+    ticks = [s for s in spans if s["cat"] == "sched" and s["name"] == "tick"
+             and s["args"]["kind"] in ("put", "decode_loop")]
+    chunk_ticks = [t for t in ticks if t["args"]["kind"] == "decode_loop"]
+    assert len(ticks) == counters["batches"]
+    assert sum(t["args"]["pipelined"] for t in ticks) == counters["pipelined_steps"]
+    assert sum(t["args"]["pipelined"] for t in chunk_ticks) == counters["pipelined_chunks"] >= 3
+    for t in chunk_ticks:
+        assert ("drain" in t["args"]) == (t["args"]["pipelined"] == 0)
+        inside = sorted((s for s in spans if s is not t and s["cat"] in ("sched", "inference")
+                         and t["ts_us"] <= s["ts_us"] < t["ts_us"] + t["dur_us"]),
+                        key=lambda s: s["ts_us"])
+        if t["args"]["pipelined"]:
+            # step i+1's launch, then step i's fetch and emit
+            assert [s["name"] for s in inside][:6] == \
+                ["admit", "build_batch", "prepare", "decode_loop", "fetch", "emit"]
+    loops = [s for s in spans if s["cat"] == "inference" and s["name"] == "decode_loop"]
+    assert len(loops) == len(chunk_ticks)
+    for loop in loops:
+        # launch and fetch apart: the fetch wrote its time when it happened
+        assert loop["args"]["steps"] == 4 and loop["args"]["launch_us"] <= loop["dur_us"] + 1
+        assert loop["args"]["fetch_us"] > 0 and "chained" in loop["args"]
+    # a step's member spans: one start a step, none overlapping, K tokens a
+    # chunk's member unless its request's cap cut the row
+    steps = sorted({(s["ts_us"], s["ts_us"] + s["dur_us"]) for s in spans
+                    if s["cat"] == "serving" and s["name"] in ("prefill", "decode")})
+    assert len(steps) == counters["batches"]
+    assert all(b[0] >= a[1] for a, b in zip(steps, steps[1:]))
+    kept = sum(s["args"]["tokens"] for s in spans
+               if s["cat"] == "serving" and s["name"] == "decode")
+    assert kept == sum(len(r.tokens) for r in reqs) - len(reqs)   # all but the first tokens
 
 
 # ------------------------------------------------------------- the merge --

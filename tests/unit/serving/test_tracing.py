@@ -331,8 +331,9 @@ def test_decode_loop_tick_is_named_for_its_dispatch(make_engine):
                           lambda s: [s.submit([1, 2, 3], max_new_tokens=9)], decode_chunk=4)
     kinds = [s["args"]["kind"] for s in spans if s["cat"] == "sched" and s["name"] == "tick"]
     assert kinds[0] == "put" and "decode_loop" in kinds
-    # a chunk is fetched inside its own engine call: nothing goes behind it
-    assert all(s["args"]["pipelined"] == 0 and s["args"]["drain"] == "decode_loop"
+    # one sequence under a cap of many: the chunk's plan is open, so it is
+    # fetched in its own tick and nothing goes behind it
+    assert all(s["args"]["pipelined"] == 0 and s["args"]["drain"] == "open"
                for s in spans if s["name"] == "tick" and s["args"]["kind"] == "decode_loop")
     loop = next(s for s in spans if s["cat"] == "inference" and s["name"] == "decode_loop")
     assert loop["args"]["steps"] == 4
