@@ -41,10 +41,12 @@ class DecodeChunk:
     can be dispatched while the chunk runs. :meth:`fetch` (``np.asarray`` of
     the chunk is the same) is the wait for the device."""
 
-    __slots__ = ("tokens", "n_seqs", "_ids", "_banks", "_args")
+    __slots__ = ("tokens", "n_seqs", "_ids", "_banks", "_args", "_count")
 
-    def __init__(self, tokens, n_seqs, banks=None, args=None):
+    def __init__(self, tokens, n_seqs, banks=None, args=None, count=None):
         self.tokens, self.n_seqs = tokens, n_seqs
+        # how the banks array reads as span args (``InferenceEngineV2.moe_counts``)
+        self._count = count
         self._ids = None
         # on the grouped path the banks its routing touched, int32 [n_steps,
         # expert layers], and under a telemetry session the chunk's
@@ -72,7 +74,7 @@ class DecodeChunk:
         if args is not None:
             args["fetch_us"] = _tel_now_us() - t0
             if self._banks is not None:
-                args["moe_banks"] = int(np.asarray(self._banks).sum())
+                args.update(self._count(self._banks))
         return tokens[:, :self.n_seqs].T
 
     def __array__(self, dtype=None, copy=None):
@@ -99,7 +101,8 @@ class InferenceEngineV2:
         kv_config = model.kv_cache_config()
         self._batch = RaggedBatchWrapper(engine_config.state_manager,
                                          block_size=engine_config.kv_block_size,
-                                         num_groups=kv_config.num_allocation_groups)
+                                         num_groups=kv_config.num_allocation_groups,
+                                         min_table_bucket=kv_config.min_table_bucket)
         self._state_manager = DSStateManager(engine_config.state_manager, kv_config)
         self._model.set_state_manager(self._state_manager)
 
@@ -370,6 +373,16 @@ class InferenceEngineV2:
         ``prev`` (:attr:`DecodeChunk.ids`): that program too, a bucket each."""
         self._model.warm_draw(chunk_steps)
 
+    def moe_counts(self, banks) -> dict:
+        """A grouped program's count of routed work, fetched, as span args:
+        ``moe_banks`` summed over the expert layers (and a chunk's steps); a
+        model whose layers hold a share of their experts counts more than one
+        thing (``model.moe_count_names``, the array's last axis)."""
+        names, counts = self._model.moe_count_names, np.asarray(banks)
+        if len(names) == 1:
+            return {names[0]: int(counts.sum())}
+        return {name: int(counts[..., i].sum()) for i, name in enumerate(names)}
+
     def _put(self, batch_uids, batch_tokens, do_checks, draw, prev=None):
         batch_uids = list(batch_uids)
         batch_tokens = [np.atleast_1d(np.asarray(t)) for t in batch_tokens]
@@ -391,6 +404,7 @@ class InferenceEngineV2:
             # path, moe_banks: every bank)
             args["attention"] = self._model.attention_arm(n_padded)
             args.update(self._model.dispatch_counts(n_padded, n_tokens))
+            args.update(self._model.batch_counts(self._batch))
         prev = self._prev_by_slot(prev, batch_tokens, n_padded, args)
         with _tel_live_span(spans, "put", "inference", args):
             if observer is not None:
@@ -581,6 +595,7 @@ class InferenceEngineV2:
             # a sparse model's moe_path, and the chunk's moe_rows and
             # moe_assignments: every step of it routes this bucket
             args.update(self._model.dispatch_counts(n_padded, len(batch_uids), n_steps))
+            args.update(self._model.batch_counts(self._batch, n_steps))
         prev = self._prev_by_slot(prev, batch_tokens, n_padded, args)
         with _tel_live_span(spans, "decode_loop", "inference", args):
             if observer is not None or spans is not None:
@@ -604,7 +619,7 @@ class InferenceEngineV2:
                 seq_desc.pre_forward(n_steps - 1)
                 seq_desc.post_forward()
             self._released_blocks += self._model.maybe_free_kv(seq_desc)
-        return DecodeChunk(tokens, len(batch_uids), banks, args)
+        return DecodeChunk(tokens, len(batch_uids), banks, args, self.moe_counts)
 
     # ------------------------------------------------------ speculative verify --
     def verify_tree(self, batch_uids: Iterable[int], trees: Iterable,

@@ -34,7 +34,8 @@ class AfmoeV2Model(LayerTypedMoEModel):
         super().__init__(params, config, engine_config, state_manager,
                          sparse_layers=config.num_hidden_layers - config.num_dense_layers,
                          norm_topk_prob=config.route_norm, score_func=config.score_func,
-                         route_scale=config.route_scale)
+                         route_scale=config.route_scale, n_group=config.n_group,
+                         topk_group=config.topk_group)
 
     def _attn_out(self, lp, y):
         return _rms(y, lp["post_attention_layernorm"]["weight"], self._config.rms_norm_eps)

@@ -7,10 +7,13 @@ Reference: ``deepspeed/inference/v2/engine_factory.py:66-120`` — the
 ``build_hf_engine(path)`` resolve through one table.
 """
 
-from typing import Dict, Tuple, Type
+from typing import Callable, Dict, Tuple
 
 _BY_CONFIG: Dict[type, type] = {}
 _BY_NAME: Dict[str, Tuple[type, type]] = {}
+# model_type -> a function that imports the family and registers it: a family
+# no other shares code with is imported when a config first names it
+_ON_FIRST_USE: Dict[str, Callable[[], None]] = {}
 
 
 def register_policy(model_type: str, config_cls, model_cls) -> None:
@@ -22,6 +25,8 @@ def register_policy(model_type: str, config_cls, model_cls) -> None:
 
 def model_cls_for(model_config) -> type:
     mt = getattr(model_config, "model_type", None)
+    if mt in _ON_FIRST_USE:
+        _ON_FIRST_USE.pop(mt)()
     if mt in _BY_NAME:
         return _BY_NAME[mt][1]
     for cfg_cls, model_cls in _BY_CONFIG.items():
@@ -32,7 +37,13 @@ def model_cls_for(model_config) -> type:
 
 
 def supported_model_types():
-    return sorted(_BY_NAME)
+    return sorted(set(_BY_NAME) | set(_ON_FIRST_USE))
+
+
+def _register_deepseek_v32():
+    from deepspeed_tpu.models.deepseek_v32 import DeepseekV32Config
+    from deepspeed_tpu.inference.v2.model_implementations.deepseek_v32_v2 import DeepseekV32V2Model
+    register_policy("deepseek_v32", DeepseekV32Config, DeepseekV32V2Model)
 
 
 def _register_builtin():
@@ -58,9 +69,12 @@ def _register_builtin():
     # config's constructor
     register_policy("mellum", MellumConfig, MellumV2Model)
     # serving only: sigmoid-scored experts beside a shared one, leading dense
-    # layers, gated attention with q/k norm, rotary on the window layers alone;
-    # grouped top-k over expert groups is refused by the config's constructor
+    # layers, gated attention with q/k norm, rotary on the window layers alone
     register_policy("afmoe", AfmoeConfig, AfmoeV2Model)
+    # serving only, and as one chip's share of a layer that several chips share:
+    # a latent cache with absorbed decode, a learned index of keys that selects
+    # what attention reads, group-limited sigmoid routing
+    _ON_FIRST_USE["deepseek_v32"] = _register_deepseek_v32
     register_policy("opt", DecoderConfig, DecoderV2Model)
     register_policy("falcon", DecoderConfig, DecoderV2Model)
     register_policy("phi", DecoderConfig, DecoderV2Model)

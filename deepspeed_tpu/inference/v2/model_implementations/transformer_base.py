@@ -102,9 +102,34 @@ class DSTransformerModelBase:
         return KVCacheConfig(block_size=self._engine_config.kv_block_size,
                              num_allocation_groups=self.kv_groups,
                              cache_shape=(self.num_layers, self.num_kv_heads, self.head_dim),
+                             state_widths=self.kv_state_widths,
+                             min_table_bucket=self.min_table_bucket,
                              cache_dtype=cache_dtype,
                              max_blocks_per_allocation_group=(sm.max_context + self._engine_config.kv_block_size - 1)
                              // self._engine_config.kv_block_size)
+
+    @property
+    def kv_state_widths(self) -> Tuple[int, ...]:
+        """The widths of the rows a token keeps a layer where its cached state
+        is not a K/V pair of heads (``KVCacheConfig.state_widths``); empty for
+        K and V."""
+        return ()
+
+    @property
+    def min_table_bucket(self) -> int:
+        """The smallest block-table bucket (``KVCacheConfig.min_table_bucket``);
+        a model with one program for every table up to some length says so."""
+        return 4
+
+    # what a forward program's count of routed work holds, last axis of the
+    # device array it returns beside its result where there is more than one
+    moe_count_names: Tuple[str, ...] = ("moe_banks", )
+
+    def batch_counts(self, ragged_batch, steps: int = 1) -> dict:
+        """Work counters of a step that depend on the batch's positions (the
+        host's copy of them), for the dispatch's span; ``steps`` > 1: over a
+        ``decode_loop`` chunk."""
+        return {}
 
     def set_state_manager(self, state_manager):
         self._state_manager = state_manager
@@ -256,7 +281,8 @@ class DSTransformerModelBase:
         from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import RaggedBatchWrapper
         wrapper = RaggedBatchWrapper(self._engine_config.state_manager,
                                      block_size=self._engine_config.kv_block_size,
-                                     num_groups=self.kv_groups)
+                                     num_groups=self.kv_groups,
+                                     min_table_bucket=self.min_table_bucket)
         batch = wrapper.finalize()  # zero live sequences/tokens
         dev = {"tok_meta": batch["tok_meta"], "seq_meta": batch["seq_meta"]}
         fn = self._get_compiled(self._bucket_of(batch))
@@ -312,7 +338,7 @@ class DSTransformerModelBase:
             from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (_pad_to,
                                                                           _pow2_pad,
                                                                           to_padded)
-            bucket = (to_padded(1), _pad_to(1, 8), _pow2_pad(1, 4))
+            bucket = (to_padded(1), _pad_to(1, 8), _pow2_pad(1, self.min_table_bucket))
         T, S, MB = bucket
         return {"tok_meta": np.zeros((4, T), np.int32),
                 "seq_meta": np.full((S, 4 + self.kv_groups * MB), -1, np.int32)}
@@ -885,6 +911,9 @@ class DSTransformerModelBase:
         import jax
         from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import _pow2_pad
 
+        if self.kv_state_widths:
+            raise NotImplementedError("compact_kv (a tree-verify re-pack) is written for the K/V "
+                                      "array: a latent KV group has no speculative verify")
         src = np.asarray(src_slots, np.int64).reshape(-1)
         dst = np.asarray(dst_slots, np.int64).reshape(-1)
         if src.size != dst.size:

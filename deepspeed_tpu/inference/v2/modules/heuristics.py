@@ -78,7 +78,7 @@ MOE_BANKS_TOUCHED_MAX = 1 / 2
 
 
 def moe_implementation(tokens: int, num_experts: int, top_k: int, capacity: int,
-                       intermediate: int, expert_parallel: int = 1) -> str:
+                       intermediate: int, expert_parallel: int = 1, held=None) -> str:
     """How a ``tokens``-token bucket reaches its experts: ``"grouped"`` (rows
     sorted by expert, one grouped matmul a projection that reads only the banks
     that have rows, dropless whatever the skew) or ``"capacity"``
@@ -88,9 +88,15 @@ def moe_implementation(tokens: int, num_experts: int, top_k: int, capacity: int,
     an expert for this bucket, ``intermediate`` the experts' width F,
     ``expert_parallel`` the size of the mesh's expert axis (its two
     all-to-alls need the static per-destination buffers, so anything over 1
-    answers ``capacity``)."""
+    answers ``capacity``), ``held`` the experts a layer holds where that is a
+    SHARE of the ``num_experts`` it routes over: most assignments then land on
+    no bank of the layer's, the capacity path would give every held expert a
+    slot a token to stay dropless, and such a layer routes by sorting whatever
+    the bucket."""
     if expert_parallel > 1:
         return "capacity"
+    if held is not None and held < num_experts:
+        return "grouped"
     if tokens * top_k <= num_experts * MOE_BANKS_TOUCHED_MAX:
         return "grouped"
     mask_share = 2 * tokens / (3 * intermediate)

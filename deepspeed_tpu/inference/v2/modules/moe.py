@@ -99,13 +99,21 @@ class RaggedMoE:
     def __init__(self, num_experts: int, top_k: int = 2, capacity_factor: float = 2.0,
                  expert_axis: str = groups.EXPERT_AXIS, layer_id: int = 0,
                  norm_topk_prob: bool = True, score_func: str = "softmax",
-                 route_scale: float = 1.0):
+                 route_scale: float = 1.0, n_group: int = 1, topk_group: int = 1,
+                 held: Optional[int] = None, first_held: int = 0):
         """``norm_topk_prob``: renormalise the ``top_k`` chosen probabilities to
         sum to 1, as the model states it (Mixtral does; a top-1 router that
         weights by the raw probability passes False). ``score_func``: how a
         router logit becomes an expert's score, ``softmax`` over the experts or
         ``sigmoid`` of each alone, in float32 either way. ``route_scale``
-        multiplies the routing weights after the renormalisation."""
+        multiplies the routing weights after the renormalisation. ``n_group`` /
+        ``topk_group``: the group limit (:meth:`_choose`); 1 is none.
+        ``held`` / ``first_held``: this layer holds experts ``first_held ..
+        first_held + held`` of the ``num_experts`` it routes over (one chip's
+        share of a layer that several chips share): its banks are ``[held,
+        ...]``, it computes the assignments that land on them and nothing for
+        the rest: no exchange, no stand-in for the absent chips. The weights
+        are renormalised over all the chosen, held here or not."""
         if score_func not in ("softmax", "sigmoid"):
             raise ValueError(f"ragged MoE scores by softmax or sigmoid, not {score_func!r}")
         if not 1 <= top_k <= num_experts:
@@ -119,6 +127,29 @@ class RaggedMoE:
         self.capacity_factor = capacity_factor
         self.expert_axis = expert_axis
         self.layer_id = layer_id
+        if num_experts % n_group or not 1 <= topk_group <= n_group \
+                or top_k > topk_group * (num_experts // n_group):
+            raise ValueError(f"{num_experts} experts in {n_group} groups, {topk_group} kept, "
+                             f"top_k={top_k}")
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
+        if held is not None and not 0 <= first_held <= num_experts - held:
+            raise ValueError(f"experts {first_held}..{first_held + held} of {num_experts}")
+        self.held, self.first_held = held, int(first_held)
+
+    @property
+    def experts_here(self) -> int:
+        """Experts whose banks this layer holds: all it routes over, or its share."""
+        return self.num_experts if self.held is None else self.held
+
+    def _here(self, topk_e):
+        """The chosen experts as indices into THIS layer's banks: themselves,
+        or for a share the local index, ``experts_here`` (no bank: every arm
+        drops it) for an expert another chip holds."""
+        import jax.numpy as jnp
+        if self.held is None:
+            return topk_e
+        local = topk_e - self.first_held
+        return jnp.where((local >= 0) & (local < self.held), local, self.held)
 
     def capacity(self, tokens: int) -> int:
         """Slots an expert has for a batch of ``tokens``. With ``capacity_factor
@@ -131,7 +162,7 @@ class RaggedMoE:
         through experts ``intermediate`` wide (``modules/heuristics.py``)."""
         from deepspeed_tpu.inference.v2.modules.heuristics import moe_implementation
         return moe_implementation(tokens, self.num_experts, self.top_k, self.capacity(tokens),
-                                  intermediate, ep)
+                                  intermediate, ep, held=self.held)
 
     def expert_rows(self, tokens: int, ep: int = 1, path: str = "capacity") -> int:
         """Rows the expert GEMMs compute for a ``tokens``-token bucket, live or
@@ -171,10 +202,22 @@ class RaggedMoE:
         ``[T, k]``: what all three arms share. With a ``select_bias`` [E] the
         experts are the largest of score + bias and the weights their SCORES
         (the bias picks, it does not weigh); then the renormalisation over the
-        chosen and the route scale, as the model states them."""
+        chosen and the route scale, as the model states them. Under a group
+        limit (``n_group`` > 1) the experts are first cut to the ``topk_group``
+        groups of largest score, a group's score the sum of its two largest
+        score + bias; the pick is then among the kept groups' experts."""
         import jax
         import jax.numpy as jnp
-        if select_bias is None:
+        if self.n_group > 1:
+            T, E = probs.shape
+            biased = probs if select_bias is None else probs + select_bias.astype(probs.dtype)
+            per_group = biased.reshape(T, self.n_group, E // self.n_group)
+            group_score = jax.lax.top_k(per_group, 2)[0].sum(-1)  # [T, groups]
+            floor = jax.lax.top_k(group_score, self.topk_group)[0][:, -1:]
+            kept = jnp.repeat(group_score >= floor, E // self.n_group, axis=1)
+            _, topk_e = jax.lax.top_k(jnp.where(kept, biased, -jnp.inf), self.top_k)
+            topk_p = jnp.take_along_axis(probs, topk_e, axis=-1)
+        elif select_bias is None:
             topk_p, topk_e = jax.lax.top_k(probs, self.top_k)  # [T, k]
         else:
             _, topk_e = jax.lax.top_k(probs + select_bias.astype(probs.dtype), self.top_k)
@@ -198,10 +241,11 @@ class RaggedMoE:
         import jax
         import jax.numpy as jnp
 
-        T, E = probs.shape
+        T, E = probs.shape[0], self.experts_here
         combine = jnp.zeros((T, E, C), jnp.float32)
         dispatch = jnp.zeros((T, E, C), dtype)
         topk_p, topk_e = self._choose(probs, select_bias)
+        topk_e = self._here(topk_e)
         fill = self._fill_level_by_level if self.top_k <= 2 else self._fill_in_one_pass
         return fill(topk_p, topk_e, token_valid, C, combine, dispatch)
 
@@ -211,7 +255,7 @@ class RaggedMoE:
         import jax
         import jax.numpy as jnp
 
-        T, E = topk_e.shape[0], self.num_experts
+        T, E = topk_e.shape[0], self.experts_here
         base = jnp.zeros((E, ), jnp.int32)
         for j in range(topk_e.shape[1]):
             e_j = topk_e[:, j]  # [T]
@@ -246,7 +290,7 @@ class RaggedMoE:
         import jax.numpy as jnp
 
         T, k = topk_e.shape
-        E = self.num_experts
+        E = self.experts_here
         e_flat = topk_e.T.reshape(k * T)
         p_flat = topk_p.T.reshape(k * T)
         t_flat = jnp.tile(jnp.arange(T), k)
@@ -295,6 +339,10 @@ class RaggedMoE:
             except Exception:
                 mesh = None
         ep = int(mesh.shape.get(self.expert_axis, 1)) if mesh is not None else 1
+        if ep > 1 and self.held is not None:
+            raise NotImplementedError(
+                "a layer that holds a share of its experts runs on one replica: the exchange "
+                "of an expert-parallel mesh would need the other chips' shares")
         if ep > 1 and self.num_experts % ep == 0:
             return self._ep_forward(h, gate_w, wi, wo, token_valid, activation, mesh,
                                     ep, gate_seed, select_bias)
@@ -320,18 +368,20 @@ class RaggedMoE:
         sort behind every expert's and belong to no group. ``banks_out``, a
         list, is appended the int32 count of experts that have a row: the banks
         the two GEMMs read (an invalid token's and the padding's rows count for
-        none)."""
+        none). A layer that holds a SHARE of its experts (``held``) sorts the
+        assignments of the others' experts behind its own, as an invalid
+        token's, and appends ``[banks, assignments]`` that landed here."""
         import jax
         import jax.numpy as jnp
         from deepspeed_tpu.ops.pallas.grouped_matmul import padded_rows
 
         T, M = h.shape
-        E, k = self.num_experts, self.top_k
+        E, k = self.experts_here, self.top_k
         rows = padded_rows(T * k)
         with jax.named_scope("route"):
             probs = self._router_probs(h, gate_w, gate_seed=gate_seed)  # [T, E] float32
             topk_p, topk_e = self._choose(probs, select_bias)  # [T, k]
-            e_flat = topk_e.reshape(T * k)  # token-major: assignment a is token a // k
+            e_flat = self._here(topk_e).reshape(T * k)  # token-major: assignment a is token a // k
             if token_valid is not None:
                 e_flat = jnp.where(jnp.repeat(token_valid, k), e_flat, E)
             e_flat = jnp.pad(e_flat, (0, rows - T * k), constant_values=E)
@@ -339,8 +389,11 @@ class RaggedMoE:
             # stable: an expert's rows stay in token order
             e_sorted, order = jax.lax.sort((e_flat, slots), num_keys=1, is_stable=True)
             group_sizes = (e_flat[:, None] == jnp.arange(E)[None, :]).sum(0, dtype=jnp.int32)
-            if banks_out is not None:
+            if banks_out is not None and self.held is None:
                 banks_out.append((group_sizes > 0).sum(dtype=jnp.int32))
+            elif banks_out is not None:
+                banks_out.append(jnp.stack([(group_sizes > 0).sum(dtype=jnp.int32),
+                                            group_sizes.sum(dtype=jnp.int32)]))
             # where each assignment's row went: the inverse permutation
             _, back = jax.lax.sort((order, slots), num_keys=1)
         with jax.named_scope("dispatch"):

@@ -20,6 +20,14 @@ takes a block id per group for each block of positions, and gives a window
 group's back alone. One pool, one allocator, one ``free_blocks``. A model whose
 layers all see alike is one group of ``num_layers`` layers: the array it always
 had.
+
+A LATENT group (``KVCacheConfig.state_widths``, a latent-attention model): a
+token's state a layer is a row of each stated width, not K and V of heads. The
+cache is then a TUPLE of pools ``[layers, num_blocks, block_size, width]``, one
+a width, that the one allocator's block ids address alike; a block id's bytes
+are ``block_size x layers x sum(widths)`` values. What moves block CONTENTS
+(fork, offload / restore, handoff frames) is written for the K/V array and
+refuses a latent group by name.
 """
 
 import os
@@ -83,7 +91,10 @@ class BlockedKVCache:
                              f"{config.num_allocation_groups} KV layer groups of equal depth")
         num_layers //= config.num_allocation_groups  # the layers one block id holds
         self._layers_per_group = num_layers
-        block_bytes = (config.block_size * 2 * num_layers * kv_heads * head_dim *
+        widths = tuple(config.state_widths)
+        # values a token keeps a layer: its state rows, or K and V of every head
+        token_values = sum(widths) if widths else 2 * kv_heads * head_dim
+        block_bytes = (config.block_size * num_layers * token_values *
                        _dtype_size(config.cache_dtype))
         if memory_config.mode == AllocationMode.RESERVE:
             num_blocks = max(1, int(memory_config.size // block_bytes))
@@ -93,9 +104,19 @@ class BlockedKVCache:
         self._allocator = BlockedAllocator(num_blocks)
 
         dtype = {"bfloat16": jnp.bfloat16, "float16": jnp.float16, "float32": jnp.float32}[config.cache_dtype]
-        self._sharding = _cache_sharding(kv_heads)
-        self._cache = jnp.zeros((num_layers, 2, num_blocks, kv_heads, config.block_size, head_dim), dtype,
-                                device=self._sharding)
+        if widths:
+            # every head reads the one row: nothing to split, replicated over a mesh
+            self._sharding = _cache_sharding(0)
+            if self._sharding is not None:
+                from jax.sharding import NamedSharding, PartitionSpec
+                self._sharding = NamedSharding(self._sharding.mesh, PartitionSpec())
+            self._cache = tuple(jnp.zeros((num_layers, num_blocks, config.block_size, w), dtype,
+                                          device=self._sharding) for w in widths)
+        else:
+            self._sharding = _cache_sharding(kv_heads)
+            self._cache = jnp.zeros((num_layers, 2, num_blocks, kv_heads, config.block_size,
+                                     head_dim), dtype, device=self._sharding)
+        self._block_bytes = block_bytes
         logger.info(f"BlockedKVCache: {num_blocks} blocks x {config.block_size} tokens "
                     f"({num_blocks * block_bytes / 1e9:.2f} GB)")
 
@@ -131,6 +152,19 @@ class BlockedKVCache:
         return self._cache
 
     @property
+    def block_bytes(self) -> int:
+        """Bytes one block id holds: ``block_size`` tokens of every layer of a group."""
+        return self._block_bytes
+
+    def _kv_pairs_only(self, what: str) -> None:
+        if self._config.state_widths:
+            raise NotImplementedError(
+                f"{what}: this cache is a latent group (rows of widths "
+                f"{tuple(self._config.state_widths)} a token a layer, not K and V of heads); "
+                f"what moves block contents is written for the K/V array — recompute the "
+                f"sequence instead")
+
+    @property
     def sharding(self):
         """The cache's ``NamedSharding`` on the engine's mesh; None for a cache
         on the default device of a mesh-less engine."""
@@ -163,6 +197,7 @@ class BlockedKVCache:
         import jax
         import jax.numpy as jnp
 
+        self._kv_pairs_only("fork_blocks (a prefix-cache copy-on-write)")
         src_blocks = np.atleast_1d(np.asarray(src_blocks)).astype(np.int64)
         new_blocks = self._allocator.allocate(src_blocks.size)
         if self._fork_fn is None:
@@ -187,6 +222,7 @@ class BlockedKVCache:
         import jax
         import jax.numpy as jnp
 
+        self._kv_pairs_only("gather_blocks (offload, a handoff or park frame)")
         blocks = np.atleast_1d(np.asarray(blocks)).astype(np.int64)
         return np.asarray(jax.device_get(self._cache[:, :, jnp.asarray(blocks)]))
 
@@ -197,6 +233,7 @@ class BlockedKVCache:
         the new block ids — the write half of :meth:`restore`, reused by the
         fleet KV-handoff importer. A failed allocation or write consumes
         nothing."""
+        self._kv_pairs_only("scatter_blocks (restore, an imported frame)")
         data = np.asarray(data)
         _, kv_heads, head_dim = self._config.cache_shape
         num_layers = self._layers_per_group

@@ -24,6 +24,16 @@ class KVCacheConfig(DeepSpeedConfigModel):
     # (kv_cache.py). One group is one table for every layer.
     num_allocation_groups: int = Field(1, gt=0)
     cache_shape: Tuple[int, int, int] = (0, 0, 0)  # (num_layers, num_heads, head_size)
+    # A token's state a layer where it is NOT a K/V pair of heads: one row of
+    # each of these widths (a latent-attention model: its latent row and its
+    # index key), one pool ``[layers, blocks, block_size, width]`` a width, all
+    # addressed by the one block table. Empty = the K/V pair ``cache_shape``
+    # says; with it ``cache_shape``'s heads and head_size are not read.
+    state_widths: Tuple[int, ...] = ()
+    # the smallest block-table bucket a batch is padded to (a power of two):
+    # programs differ by bucket, and a model that has one program for every
+    # table up to some length says so here
+    min_table_bucket: int = Field(4, gt=0)
     cache_dtype: str = "bfloat16"
     max_blocks_per_allocation_group: int = Field(0, ge=0)
 

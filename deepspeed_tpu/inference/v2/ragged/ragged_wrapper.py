@@ -63,12 +63,15 @@ class RaggedBatchWrapper:
     """Host-side composition of one ragged forward batch."""
 
     def __init__(self, config: DSStateManagerConfig, block_size: int = 128,
-                 num_groups: int = 1) -> None:
+                 num_groups: int = 1, min_table_bucket: int = 4) -> None:
         """``num_groups``: block tables a sequence (KV layer groups,
-        ``ragged/kv_cache.py``); the batch carries them side by side."""
+        ``ragged/kv_cache.py``); the batch carries them side by side.
+        ``min_table_bucket``: the smallest block-table bucket
+        (``KVCacheConfig.min_table_bucket``)."""
         self._config = config
         self._block_size = block_size
         self._num_groups = num_groups
+        self._min_table_bucket = min_table_bucket
         self.clear()
 
     def clear(self) -> None:
@@ -144,7 +147,7 @@ class RaggedBatchWrapper:
         T = to_padded(max(1, self.current_tokens))
         S = _pad_to(max(1, self.current_sequences), 8)
         mb = max((b.shape[1] for b in self._seq_blocks), default=1)
-        MB = _pow2_pad(mb, 4)
+        MB = _pow2_pad(mb, self._min_table_bucket)
         G = self._num_groups
         cw = compile_watch.get()
         if cw is not None:

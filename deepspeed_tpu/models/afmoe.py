@@ -30,9 +30,9 @@ routed experts are named as Mixtral's are (``block_sparse_moe.{gate,
 ExpertFFN_0.{wi, wo}}``, ``wi`` = (gate | up) side by side), beside them
 ``expert_bias`` and ``shared_experts``; a dense layer's ``mlp`` is Llama's.
 
-Refused rather than served wrong: grouped top-k over expert groups
-(``n_group`` / ``topk_group`` other than 1), a ``rope_scaling``, tied
-embeddings, another activation than ``silu``.
+The group limit (``n_group`` / ``topk_group``; 1 in the published model) is
+``RaggedMoE._choose``'s. Refused rather than served wrong: a ``rope_scaling``,
+tied embeddings, another activation than ``silu``.
 """
 
 import math
@@ -95,10 +95,13 @@ class AfmoeConfig:
         if unknown:
             raise ValueError(f"layer_types {unknown}: only {FULL!r} and {SLIDING!r} are served")
         # refuse what is not implemented rather than serve wrong logits
-        if {self.n_group, self.topk_group, self.num_expert_groups, self.num_limited_groups} != {1}:
+        if {self.num_expert_groups, self.num_limited_groups} != {1}:
             raise NotImplementedError(
-                "grouped top-k over expert groups (n_group / topk_group / num_expert_groups / "
-                "num_limited_groups other than 1) is not implemented")
+                "expert groups by num_expert_groups / num_limited_groups other than 1 are not "
+                "implemented: the group limit is n_group / topk_group")
+        if self.num_experts % self.n_group or not 1 <= self.topk_group <= self.n_group:
+            raise ValueError(f"{self.num_experts} experts in {self.n_group} groups, "
+                             f"{self.topk_group} kept")
         if self.score_func not in _SCORE_FUNCS:
             raise NotImplementedError(f"score_func {self.score_func!r}: only {_SCORE_FUNCS}")
         if self.rope_scaling:

@@ -327,6 +327,13 @@ class ServingScheduler:
                 f"attention window is {self._window}): the trie shares and the tier ladder "
                 f"moves whole block tables, and this model's sequences release the blocks "
                 f"their window has passed. Turn both off for this model.")
+        widths = getattr(getattr(engine, "model", None), "kv_state_widths", ())
+        if widths and (self._config.prefix_cache.enabled or self._config.kv_tiers.enabled):
+            raise ValueError(
+                f"prefix_cache / kv_tiers cannot serve a latent KV group (rows of widths "
+                f"{tuple(widths)} a token a layer): the trie's copy-on-write and the tier ladder "
+                f"move block contents, which is written for the K/V array. Turn both off for "
+                f"this model.")
         if self._config.prefix_cache.enabled:
             from deepspeed_tpu.inference.v2.ragged.prefix_cache import PrefixCache
             self._prefix_cache = PrefixCache(
@@ -2221,7 +2228,7 @@ class ServingScheduler:
             out = np.asarray(result)
             args["bytes"] = int(out.nbytes)
             if moe is not None:
-                args.update(moe, moe_banks=int(np.asarray(moe["moe_banks"]).sum()))
+                args.update(moe, **self._engine.moe_counts(moe["moe_banks"]))
         return out
 
     def _emit_phase(self, spans):

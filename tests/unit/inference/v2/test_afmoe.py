@@ -123,8 +123,8 @@ def test_the_model_is_registered_and_reads_its_layers_from_the_config(model):
 
 
 @pytest.mark.parametrize("changed, error, match", [
-    (dict(n_group=2), NotImplementedError, "expert groups"),
-    (dict(topk_group=2), NotImplementedError, "expert groups"),
+    (dict(num_expert_groups=2), NotImplementedError, "expert groups"),
+    (dict(topk_group=2), ValueError, "1 groups, 2 kept"),
     (dict(score_func="tanh"), NotImplementedError, "score_func"),
     (dict(tie_word_embeddings=True), NotImplementedError, "tied"),
     (dict(rope_scaling={"rope_type": "yarn", "factor": 4.0}), NotImplementedError, "rope_scaling"),

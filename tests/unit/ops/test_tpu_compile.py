@@ -478,3 +478,85 @@ def test_trinity_decode_loop_program_fits_one_chip(v5e, trinity_model):
     assert "[8,128,8]" not in text  # no mask of the capacity path
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert not _pool_sized_results(text, cache.shape)
+
+
+# ---- deepseek-v32-serve-1chip: the latent cache's kernels and whole programs (PR 40) ----
+DEEPSEEK_POOL_BLOCKS, DEEPSEEK_BLOCK = 2752, 128
+
+
+@pytest.fixture(scope="module")
+def deepseek_model():
+    """``deepseek-v32-serve-1chip``: DeepSeek-V3.2's published widths, one dense
+    layer and four expert layers, 16 of the 256 routed experts held, an eighth
+    of the vocabulary, contexts to 8192, over ``jax.eval_shape``d parameters."""
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.model_implementations.registry import model_cls_for
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import DSStateManagerConfig
+    from deepspeed_tpu.models import deepseek_v32
+    cfg = deepseek_v32.DeepseekV32Config(
+        num_hidden_layers=5, first_k_dense_replace=1, vocab_size=16160, experts_held=16,
+        expert_rank=5, rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
+                                     "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                                     "original_max_position_embeddings": 4096})
+    abstract = jax.eval_shape(lambda: deepseek_v32.init_params(cfg, param_dtype=cfg.dtype)[1])
+    engine_config = RaggedInferenceEngineConfig(
+        state_manager=DSStateManagerConfig(max_context=8192, max_ragged_batch_size=256,
+                                           max_ragged_sequence_count=8),
+        kv_block_size=DEEPSEEK_BLOCK, use_paged_kernel=True,
+        expert_parallel={"capacity_factor": 32.0})
+    model = model_cls_for(cfg)(abstract, cfg, engine_config)
+    assert model.kv_state_widths == (640, 128) and model.min_table_bucket == 64
+    return model, abstract
+
+
+def _deepseek_args(device, model, abstract, bucket):
+    one = SingleDeviceSharding(device)
+    tokens, seqs, max_blocks = bucket
+    params = jax.tree.map(lambda leaf: _on(one, leaf.shape, leaf.dtype), abstract)
+    cache = tuple(_on(one, (5, DEEPSEEK_POOL_BLOCKS, DEEPSEEK_BLOCK, width), jnp.bfloat16)
+                  for width in model.kv_state_widths)
+    batch = {"tok_meta": _on(one, (4, tokens), jnp.int32),
+             "seq_meta": _on(one, (seqs, 4 + max_blocks), jnp.int32)}
+    return one, params, cache, batch
+
+
+def _latent_pool_copies(text):
+    """``copy`` instructions whose result is a whole latent or index pool."""
+    import re
+    return [line.strip()[:160] for line in text.splitlines()
+            if re.search(rf"= bf16\[5,{DEEPSEEK_POOL_BLOCKS},{DEEPSEEK_BLOCK},\d+\]\S* copy\(", line)]
+
+
+@pytest.mark.parametrize("bucket,kernel,selects", [
+    ((8, 8, 64), "latent_paged_attention_token", True),
+    ((256, 8, 64), "latent_paged_attention_tiled", True),
+    ((256, 8, 16), "latent_paged_attention_tiled", False)],
+    ids=["decode-bucket", "chunk-bucket", "chunk-under-index-topk"])
+def test_deepseek_put_program_fits_one_chip(v5e, deepseek_model, bucket, kernel, selects):
+    """8.65 GiB of weights beside two pools (2.52 GiB) that the scatters update in
+    place and the kernels read: no copy of a pool; every bucket routes by sorting
+    over the 16 held banks; a table of no more than ``index_topk`` keys carries no
+    indexer scores."""
+    model, abstract = deepseek_model
+    assert model.attention_arm(bucket[0]) == kernel.replace("_paged_attention", "")
+    assert model.selects(bucket[2]) == selects and model.moe_path(bucket[0]) == "grouped"
+    _, params, cache, batch = _deepseek_args(v5e[0], model, abstract, bucket)
+    compiled = jax.jit(model._forward_impl, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and kernel in text and "grouped_matmul" in text
+    assert ("latent_index_scores" in text) == selects
+    assert _device_bytes(compiled) < 0.8 * HBM_BYTES
+    assert not _latent_pool_copies(text)
+
+
+def test_deepseek_decode_loop_program_fits_one_chip(v5e, deepseek_model):
+    model, abstract = deepseek_model
+    one, params, cache, batch = _deepseek_args(v5e[0], model, abstract, (8, 8, 64))
+    loop = functools.partial(model._decode_loop_impl, n_steps=8, sampled=False)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(
+        params, cache, batch, _on(one, (), jnp.float32), _on(one, (2, ), jnp.uint32)).compile()
+    text = compiled.as_text()
+    assert "latent_paged_attention_token" in text and "latent_index_scores" in text
+    assert "grouped_matmul" in text
+    assert _device_bytes(compiled) < 0.8 * HBM_BYTES
+    assert not _latent_pool_copies(text)
