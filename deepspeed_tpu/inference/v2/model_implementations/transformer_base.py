@@ -128,8 +128,18 @@ class DSTransformerModelBase:
     def batch_counts(self, ragged_batch, steps: int = 1) -> dict:
         """Work counters of a step that depend on the batch's positions (the
         host's copy of them), for the dispatch's span; ``steps`` > 1: over a
-        ``decode_loop`` chunk."""
-        return {}
+        ``decode_loop`` chunk. A bucket on the query-tiled grid: the passes its
+        kernels make over the layers, and those of them that own one token
+        (``ops/pallas/paged_attention.py:tiled_passes``)."""
+        batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
+        bucket_tokens = batch["tok_meta"].shape[1]
+        if self.attention_arm(bucket_tokens) != "paged_tiled":
+            return {}
+        from deepspeed_tpu.ops.pallas.paged_attention import tiled_passes
+        seq = np.asarray(batch["seq_meta"])
+        passes, one_token = tiled_passes(seq[:, 1], seq[:, 2], bucket_tokens)
+        return {"tiled_passes": passes * self.num_layers,
+                "tiled_one_token_passes": one_token * self.num_layers}
 
     def set_state_manager(self, state_manager):
         self._state_manager = state_manager

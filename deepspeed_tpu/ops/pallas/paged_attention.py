@@ -34,9 +34,12 @@ in place (at most ``TQ / bs + 1`` block read-modify-writes, the rows moved to
 their offsets by a 0/1 selection matmul), then walks the sequence's table
 ONCE: per KV head a ``[rep·TQ, 8·bs]`` logits tile on the MXU, operands in the
 wider of the queries' and the pool's dtype, float32 accumulation, causal mask
-from positions. A decode row
-riding along is a pass with one live query row; a tile with no tokens writes
-zeros and touches nothing.
+from positions. A pass pays for the rows it owns: one that owns ONE token of
+its tile (a decode row riding beside a chunk, a chunk's last token alone in
+the next tile) picks that token's ``rep`` rows a KV head out of the slab and
+walks the same chunks with a ``[KVH, rep, 8·bs]`` logits tile, the per-token
+grid's, then puts its softmax state into the token's rows of the tile's. A
+tile with no tokens writes zeros and touches nothing.
 
 Sliding window (``window`` > 0, static; 0 = full causal and the program it
 always was): both grids mask ``kv_pos > q_pos - window`` AND start their walk
@@ -51,6 +54,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -65,14 +69,35 @@ def _stage_blocks(bs):
     return 1 + -(-(TQ - 1) // bs)
 
 
+def _one_token_rows(rep, itemsize):
+    """Query rows a KV head of a one-token pass: ``rep``, padded to a sublane
+    tile of the operands' dtype."""
+    tile = 8 * (4 // itemsize)
+    return -(-rep // tile) * tile
+
+
 def tile_grid_vmem_bytes(H, KVH, D, bs, itemsize=2):
     """VMEM a query-tiled call holds: the double-buffered K/V chunks, the
     insert's staging blocks, the grouped queries, the softmax state (a per-row
-    scalar pads to 128 lanes) and the pipelined q / out / new-K/V blocks."""
+    scalar pads to 128 lanes), a one-token pass's queries and state, and the
+    pipelined q / out / new-K/V blocks."""
     block = KVH * bs * D * itemsize
     tile = H * TQ * D
+    one = KVH * _one_token_rows(H // KVH, itemsize)
     return ((2 * 2 * CHUNK + 2 * _stage_blocks(bs)) * block + tile * itemsize + tile * 4
-            + 2 * H * TQ * 128 * 4 + 2 * 2 * (tile + KVH * TQ * D) * itemsize)
+            + 2 * H * TQ * 128 * 4 + one * (D * itemsize + D * 4 + 128 * 4)
+            + 2 * 2 * (tile + KVH * TQ * D) * itemsize)
+
+
+def tiled_passes(seq_ntok, last_tok, bucket_tokens):
+    """The (sequence, tile) pairs a query-tiled call works through, and those
+    that own ONE token of their tile, by ``_tiled_kernel``'s rule, from the
+    host's copy of the scalar prefetch (numpy ``[S]``)."""
+    n, last = np.asarray(seq_ntok)[:, None], np.asarray(last_tok)[:, None]
+    t0 = np.arange(0, bucket_tokens, TQ)[None, :]
+    lo, hi = np.maximum(last - n + 1, t0), np.minimum(last, t0 + TQ - 1)
+    owned = (n > 0) & (lo <= hi)
+    return int(owned.sum()), int((owned & (lo == hi)).sum())
 
 
 def _first_visible_block(pos, window, bs):
@@ -267,10 +292,12 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision, window,
                   # outputs
                   out_ref, cache_out_ref,
                   # scratch
-                  q_s, m_s, l_s, acc_s, k_buf, v_buf, kv_stage, sems, wsem):
+                  q_s, m_s, l_s, acc_s, q1_s, l1_s, acc1_s, k_buf, v_buf, kv_stage, sems, wsem):
     # Loops over heads and blocks are ``fori_loop``s, not Python loops: one
     # kernel is traced and lowered for every bucket's program at every start
-    # of the server, and an unrolled body costs that 8 x over.
+    # of the server, and an unrolled body costs that 8 x over. (A one-token
+    # pass takes its KV heads as the batch of one ``dot_general``, as the
+    # per-token grid does: its chains of a few vregs a head must overlap.)
     t0 = pl.program_id(0) * TQ
     li = layer_ref[0]
     KVH, D = k_buf.shape[1], k_buf.shape[3]
@@ -360,8 +387,6 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision, window,
             nchunks = pl.cdiv(b1 + 1 - b_first, CHUNK)
         else:
             nchunks = pl.cdiv(b1 + 1, CHUNK)
-        # a row of another sequence (or of padding) sees no key
-        q_pos = jnp.where((row_tok >= lo) & (row_tok <= hi), row_tok + shift, -1)
 
         def chunk_copies(c, slot, j):
             bid = block_id(c * CHUNK + j + b_first if window else c * CHUNK + j)
@@ -383,44 +408,114 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision, window,
                     cp.wait()
             each(CHUNK, wait)
 
-        start_chunk(0, 0)
+        def walk(attend, state=None):
+            """``attend(slot, visible, state) -> state`` over the chunks in
+            turn; ``visible(q_pos)``: the chunk's keys a query at ``q_pos``
+            (any shape that broadcasts against ``[..., CHUNK*bs]``) sees."""
+            start_chunk(0, 0)
 
-        def chunk(c):
-            slot = jax.lax.rem(c, 2)
+            def chunk(c, state):
+                slot = jax.lax.rem(c, 2)
 
-            @pl.when(c + 1 < nchunks)
-            def _():
-                start_chunk(c + 1, 1 - slot)
+                @pl.when(c + 1 < nchunks)
+                def _():
+                    start_chunk(c + 1, 1 - slot)
 
-            wait_chunk(c, slot)
-            kv_pos = c * (CHUNK * bs) + jax.lax.broadcasted_iota(
-                jnp.int32, (1, CHUNK * bs), 1)
-            if window:
-                kv_pos = kv_pos + b_first * bs
-            mask = kv_pos <= q_pos  # [rep*TQ, CHUNK*bs]
-            if window:
-                mask &= kv_pos > q_pos - window
+                wait_chunk(c, slot)
 
-            def head(g):
+                def visible(q_pos):
+                    kv_pos = c * (CHUNK * bs) + jax.lax.broadcasted_iota(
+                        jnp.int32, (1, ) * (q_pos.ndim - 1) + (CHUNK * bs, ), q_pos.ndim - 1)
+                    if window:
+                        kv_pos = kv_pos + b_first * bs
+                    mask = kv_pos <= q_pos
+                    if window:
+                        mask &= kv_pos > q_pos - window
+                    return mask
+
+                return attend(slot, visible, state)
+
+            return jax.lax.fori_loop(0, nchunks, chunk, state)
+
+        @pl.when(lo != hi)
+        def _():
+            # the tile's rows under the pass's mask: a row of another sequence
+            # (or of padding) sees no key
+            q_pos = jnp.where((row_tok >= lo) & (row_tok <= hi), row_tok + shift, -1)
+
+            def attend(slot, visible, _):
+                mask = visible(q_pos)  # [rep*TQ, CHUNK*bs]
+
+                def head(g):
+                    logits = jax.lax.dot_general(
+                        q_s[g], k_buf[slot, g].astype(op_dtype), (((1, ), (1, )), ((), ())),
+                        preferred_element_type=jnp.float32, precision=precision) * scale
+                    # masked logits sit BELOW the running max's floor, so their exp
+                    # is 0 even for a row that has seen no key yet
+                    logits = jnp.where(mask, logits, 2 * NEG_INF)
+                    m_prev = m_s[g]
+                    m_new = jnp.maximum(m_prev, logits.max(axis=1, keepdims=True))
+                    p = jnp.exp(logits - m_new)
+                    alpha = jnp.exp(m_prev - m_new)
+                    l_s[g] = l_s[g] * alpha + p.sum(axis=1, keepdims=True)
+                    acc_s[g] = acc_s[g] * alpha + jnp.dot(
+                        p.astype(op_dtype), v_buf[slot, g].astype(op_dtype),
+                        preferred_element_type=jnp.float32, precision=precision)
+                    m_s[g] = m_new
+
+                each(KVH, head)
+
+            walk(attend)
+
+        @pl.when(lo == hi)
+        def _():
+            # ONE token of the tile (a decode row beside a chunk, a chunk's end
+            # alone in the next tile): its ``rep`` rows a KV head, not the slab
+            t = lo - t0
+            R = q1_s.shape[1]  # rep, padded to a sublane tile; the rows past rep are zero
+            r = jax.lax.broadcasted_iota(jnp.int32, (R, rep * TQ), 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, (R, rep * TQ), 1)
+            # 0/1 selection of the token's rows out of the slab, exact on the MXU
+            sel = ((col == r * TQ + t) & (r < rep)).astype(op_dtype)
+
+            def pick(g):
+                q1_s[g] = jnp.dot(sel, q_s[g], preferred_element_type=jnp.float32,
+                                  precision=precision).astype(op_dtype)
+
+            each(KVH, pick)
+            q1 = q1_s[...]
+            pos = jnp.full((1, 1, 1), p_lo, jnp.int32)
+
+            def attend(slot, visible, state):
+                m_prev, l, acc = state
                 logits = jax.lax.dot_general(
-                    q_s[g], k_buf[slot, g].astype(op_dtype), (((1, ), (1, )), ((), ())),
+                    q1, k_buf[slot].astype(op_dtype), (((2, ), (2, )), ((0, ), (0, ))),
                     preferred_element_type=jnp.float32, precision=precision) * scale
-                # masked logits sit BELOW the running max's floor, so their exp
-                # is 0 even for a row that has seen no key yet
-                logits = jnp.where(mask, logits, 2 * NEG_INF)
-                m_prev = m_s[g]
-                m_new = jnp.maximum(m_prev, logits.max(axis=1, keepdims=True))
+                logits = jnp.where(visible(pos), logits, 2 * NEG_INF)  # [KVH, R, CHUNK*bs]
+                m_new = jnp.maximum(m_prev, logits.max(axis=2, keepdims=True))
                 p = jnp.exp(logits - m_new)
                 alpha = jnp.exp(m_prev - m_new)
-                l_s[g] = l_s[g] * alpha + p.sum(axis=1, keepdims=True)
-                acc_s[g] = acc_s[g] * alpha + jnp.dot(
-                    p.astype(op_dtype), v_buf[slot, g].astype(op_dtype),
+                pv = jax.lax.dot_general(
+                    p.astype(op_dtype), v_buf[slot].astype(op_dtype),
+                    (((2, ), (1, )), ((0, ), (0, ))),
                     preferred_element_type=jnp.float32, precision=precision)
-                m_s[g] = m_new
+                return m_new, l * alpha + p.sum(axis=2, keepdims=True), acc * alpha + pv
 
-            each(KVH, head)
+            _, l1_s[...], acc1_s[...] = walk(attend, (
+                jnp.full((KVH, R, 1), NEG_INF, jnp.float32), jnp.zeros((KVH, R, 1), jnp.float32),
+                jnp.zeros((KVH, R, D), jnp.float32)))
 
-        each(nchunks, chunk)
+            # the token's rows of the tile's state (a row belongs to ONE pass:
+            # nothing was there), by a select: row t of each head's TQ rows
+            hit = jax.lax.broadcasted_iota(jnp.int32, (TQ, 1), 0) == t
+
+            def place(h):
+                slab, _ = head_rows(h)
+                row = pl.ds(h % rep, 1)
+                l_s[slab] = jnp.where(hit, l1_s[h // rep, row], l_s[slab])
+                acc_s[slab] = jnp.where(hit, acc1_s[h // rep, row], acc_s[slab])
+
+            each(KVH * rep, place)
 
     def sequence(s):
         n, last = ntok_ref[s], last_ref[s]
@@ -453,8 +548,10 @@ def paged_attention_prefill(q, k_new, v_new, cache, layer_idx, block_table, seq_
     ``seq_ntok <= 0`` is empty. ``layer_idx`` is an operand, not a constant:
     a model's layers share ONE kernel in the compiled program. ``window`` as
     in :func:`paged_attention_update`; a pass walks from the first block its
-    first query sees. Returns (attn_out [T, H, D], cache); rows of no sequence
-    are zero."""
+    first query sees. A (sequence, tile) pass that owns one token computes that
+    token's ``H`` rows alone; one that owns more, the tile's ``H * TQ`` under
+    the tokens' masks (:func:`tiled_passes` counts both on the host). Returns
+    (attn_out [T, H, D], cache); rows of no sequence are zero."""
     T, H, D = q.shape
     L, _, NB, KVH, bs, Dc = cache.shape
     assert D == Dc and H % KVH == 0 and T % TQ == 0
@@ -467,6 +564,7 @@ def paged_attention_prefill(q, k_new, v_new, cache, layer_idx, block_table, seq_
     # float32 operands (tests, a float32 pool) must not pass through bf16 on the MXU
     precision = jax.lax.Precision.HIGHEST if op_dtype == jnp.float32 else None
     n_stage = _stage_blocks(bs)
+    one_rows = _one_token_rows(rep, jnp.dtype(op_dtype).itemsize)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
@@ -486,6 +584,9 @@ def paged_attention_prefill(q, k_new, v_new, cache, layer_idx, block_table, seq_
             pltpu.VMEM((KVH, rep * TQ, 1), jnp.float32),        # running max
             pltpu.VMEM((KVH, rep * TQ, 1), jnp.float32),        # running sum
             pltpu.VMEM((KVH, rep * TQ, D), jnp.float32),        # accumulator
+            pltpu.VMEM((KVH, one_rows, D), op_dtype),           # a one-token pass: its queries,
+            pltpu.VMEM((KVH, one_rows, 1), jnp.float32),        # running sum
+            pltpu.VMEM((KVH, one_rows, D), jnp.float32),        # and accumulator
             pltpu.VMEM((2, KVH, CHUNK * bs, D), cache.dtype),
             pltpu.VMEM((2, KVH, CHUNK * bs, D), cache.dtype),
             pltpu.VMEM((n_stage, 2, KVH, bs, D), cache.dtype),

@@ -698,13 +698,15 @@ def test_float32_holds_what_the_loose_tolerance_lets_through(model, control):
 # The two 128-token forwards (the grouped bucket) were re-recorded in PR 37: the
 # one difference is one more output, the banks each expert layer's routing
 # touched (``(group_sizes > 0).sum()`` a layer, stacked); the four programs on
-# the capacity path are the parent's still.
+# the capacity path are the parent's still. ``mellum.kernel.forward.128x8x8`` was
+# re-recorded again in PR 42 (the query-tiled kernel's one-token pass); the gather
+# arm's, the 8-token and the chunk's programs held.
 _MELLUM_PARENT = {
     "mellum.gather.forward.8x8x4": "98d1d447896ec022ef33d977031c4731e75ba00e9aae31b2e52c2ddfbc751fea",
     "mellum.gather.forward.128x8x8": "baf95ba27f841e05ba61035349e3b77b1a59a50e1f29c3b903dcf1e648782f1a",
     "mellum.gather.decode_loop": "75e1e5614b850f8e5450809412955dc7f4d7948127eb1e9ce68708c56c6b8a8c",
     "mellum.kernel.forward.8x8x4": "2857ae3f6a0f05a300e1c4d552b4455cb6ee85431770ab01a80eaea76e50f73b",
-    "mellum.kernel.forward.128x8x8": "1b3eaa0622febca9ad6aeb292ddca71f7d57d421562ef1e5c8545ae0552c5552",
+    "mellum.kernel.forward.128x8x8": "de051196db4d56ac6f7db2dc36ce95a3049e00f61a2e25c83298c0c6531462f5",
     "mellum.kernel.decode_loop": "6effb84eedcefc8b75f935c54044c3e77e3a6198b0d121bbc9161bc98f6c7644",
 }
 

@@ -90,31 +90,10 @@ def test_paged_attention_matches_dense(kvh):
     np.testing.assert_allclose(np.asarray(cache2), exp_cache, rtol=0, atol=0)
 
 
-# tiled mode: (seen, new tokens) per sequence, in batch order; bucket tokens
-TILED_BATCHES = {
-    # one sequence's chunk starting mid-block (seen > 0) and crossing block
-    # boundaries (16-token blocks) and the tile boundary at token 64
-    "chunk-crossing-blocks": ([(37, 70)], 128),
-    # a 2-tile chunk with decode rows riding along, before and after it
-    "chunk-plus-decode-rows": ([(3, 1), (20, 100), (33, 1), (0, 1)], 128),
-    # a whole tile (tokens 64..127) of padding
-    "padding-tile": ([(0, 50)], 128),
-    "first-prefill-one-tile": ([(0, 64)], 64),
-}
-
-
-@pytest.mark.parametrize("kvh", [4, 2])  # MHA and GQA
-@pytest.mark.parametrize("batch", list(TILED_BATCHES))
-def test_paged_attention_prefill_matches_dense(kvh, batch):
-    """The query-tiled grid against the dense reference, and the pool's blocks:
-    every inserted row lands, every other element is bit-identical."""
-    seqs, T = TILED_BATCHES[batch]
-    rng = np.random.default_rng(0)
-    L, NB, bs, D, H = 2, 40, 16, 128, 4
-    S, MB = 8, 8
-    cache0 = rng.normal(size=(L, 2, NB, kvh, bs, D)).astype(np.float32)
-    # distinct blocks per sequence; the pool's LAST block belongs to nobody
-    free = list(rng.permutation(NB - 1))
+def _ragged_batch(seqs, T, S, MB, bs, free):
+    """``seqs``: (seen, new tokens) per sequence, in batch order, in a bucket of
+    ``T`` tokens; blocks drawn from ``free``. Returns the block table, the
+    per-token and the per-sequence metadata, and the number of live tokens."""
     table = np.full((S, MB), -1, np.int32)
     token_seq = np.full(T, S - 1, np.int32)
     token_pos = np.zeros(T, np.int32)
@@ -129,34 +108,7 @@ def test_paged_attention_prefill_matches_dense(kvh, batch):
         token_valid[cursor:cursor + n] = 1
         cursor += n
         seq_seen[s], seq_ntok[s], last_tok[s] = seen, n, cursor - 1
-    q = rng.normal(size=(T, H, D)).astype(np.float32)
-    k_new = rng.normal(size=(T, kvh, D)).astype(np.float32)
-    v_new = rng.normal(size=(T, kvh, D)).astype(np.float32)
-
-    exp_cache = cache0.copy()
-    for t in range(cursor):
-        bid = table[token_seq[t], token_pos[t] // bs]
-        exp_cache[:, 0, bid, :, token_pos[t] % bs] = k_new[t]
-        exp_cache[:, 1, bid, :, token_pos[t] % bs] = v_new[t]
-
-    cache = jnp.asarray(cache0)
-    for li in range(L):
-        got, cache = paged_attention_prefill(q, k_new, v_new, cache, li, table, seq_seen,
-                                             seq_ntok, last_tok)
-        want = _dense_reference(q, exp_cache, li, table, token_seq, token_pos, token_valid)
-        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
-        assert not np.any(np.asarray(got)[cursor:])  # padding rows are zero
-    np.testing.assert_array_equal(np.asarray(cache), exp_cache)
-
-    # no live sequence: no output, no cache mutation
-    out2, cache2 = paged_attention_prefill(q, k_new, v_new, jnp.asarray(exp_cache), 0, table,
-                                           seq_seen, np.zeros(S, np.int32), last_tok)
-    assert not np.any(np.asarray(out2))
-    np.testing.assert_array_equal(np.asarray(cache2), exp_cache)
-
-
-# ---- sliding window: both grids, window 16 over 4-token blocks ---------------
-WINDOW, WBS = 16, 4
+    return table, (token_seq, token_pos, token_valid), (seq_seen, seq_ntok, last_tok), cursor
 
 
 def _release_passed(table, cache, seq_next_pos, window, bs):
@@ -170,6 +122,88 @@ def _release_passed(table, cache, seq_next_pos, window, bs):
             cache[:, :, table[s, b]] = np.nan
             table[s, b] = -1
     return table, cache
+
+
+def _check_tile_grid(seqs, T, *, H, kvh, bs, NB, MB, window=0, S=8):
+    """The query-tiled grid against the dense reference, and the pool's blocks:
+    every inserted row lands, every other element is bit-identical. Under a
+    ``window`` the blocks behind each sequence's FIRST query of the step were
+    released before it: holes in the table, NaN in the pool."""
+    rng = np.random.default_rng(0)
+    L, D = 2, 128
+    cache0 = rng.normal(size=(L, 2, NB, kvh, bs, D)).astype(np.float32)
+    cache0[:, :, 0] = 0.0  # block 0 is nobody's: where a hole's -1 clamps to
+    # distinct blocks per sequence; the pool's LAST block belongs to nobody
+    free = list(rng.permutation(np.arange(1, NB - 1)))
+    table, tok, seq, cursor = _ragged_batch(seqs, T, S, MB, bs, free)
+    q = rng.normal(size=(T, H, D)).astype(np.float32)
+    k_new = rng.normal(size=(T, kvh, D)).astype(np.float32)
+    v_new = rng.normal(size=(T, kvh, D)).astype(np.float32)
+
+    exp_cache = cache0.copy()
+    for t in range(cursor):
+        bid = table[tok[0][t], tok[1][t] // bs]
+        exp_cache[:, 0, bid, :, tok[1][t] % bs] = k_new[t]
+        exp_cache[:, 1, bid, :, tok[1][t] % bs] = v_new[t]
+
+    walked, pool = table, cache0
+    if window:
+        walked, pool = _release_passed(table, cache0, {s: seen for s, (seen, _) in enumerate(seqs)},
+                                       window, bs)
+    cache = jnp.asarray(pool)
+    for li in range(L):
+        got, cache = paged_attention_prefill(q, k_new, v_new, cache, li, walked, *seq,
+                                             window=window)
+        want = _dense_reference(q, exp_cache, li, table, *tok, window=window)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+        assert not np.any(np.asarray(got)[cursor:])  # padding rows are zero
+    live = ~np.isnan(pool)
+    np.testing.assert_array_equal(np.asarray(cache)[live], exp_cache[live])
+    return q, k_new, v_new, exp_cache, table, seq
+
+
+# tiled mode: (seen, new tokens) per sequence, in batch order; bucket tokens
+TILED_BATCHES = {
+    # one sequence's chunk starting mid-block (seen > 0) and crossing block
+    # boundaries (16-token blocks) and the tile boundary at token 64
+    "chunk-crossing-blocks": ([(37, 70)], 128),
+    # a 2-tile chunk with decode rows riding along, before and after it
+    "chunk-plus-decode-rows": ([(3, 1), (20, 100), (33, 1), (0, 1)], 128),
+    # a whole tile (tokens 64..127) of padding
+    "padding-tile": ([(0, 50)], 128),
+    "first-prefill-one-tile": ([(0, 64)], 64),
+    # ---- one-token passes (lo == hi in the kernel) beside a chunk's pass ----
+    # k = 1 at tile row 0 (and at position 0: its only key is its own), the chunk behind it
+    "one-token-row-0": ([(0, 1), (5, 63)], 64),
+    # k = 3 in the middle of the tile, padding behind them
+    "three-one-token-rows-mid-tile": ([(12, 30), (40, 1), (3, 1), (60, 1)], 64),
+    # k = 7 behind the chunk: rows 57..63, the tile's last row among them
+    "seven-one-token-rows-to-row-63": ([(9, 57)] + [(11 * i + 2, 1) for i in range(7)], 64),
+    # a chunk of 65: its last token falls alone into the next tile
+    "chunk-end-alone-in-next-tile": ([(37, 65)], 128),
+    # no chunk at all: every pass owns one token, each row must land in its block
+    "only-one-token-rows": ([(17, 1), (0, 1), (33, 1), (63, 1), (64, 1)], 64),
+}
+
+
+@pytest.mark.parametrize("kvh", [4, 2])  # MHA and GQA
+@pytest.mark.parametrize("batch", list(TILED_BATCHES))
+def test_paged_attention_prefill_matches_dense(kvh, batch):
+    """The query-tiled grid against the dense reference, and the pool's blocks:
+    every inserted row lands, every other element is bit-identical."""
+    seqs, T = TILED_BATCHES[batch]
+    q, k_new, v_new, exp_cache, table, (seq_seen, _, last_tok) = _check_tile_grid(
+        seqs, T, H=4, kvh=kvh, bs=16, NB=40, MB=8)
+
+    # no live sequence: no output, no cache mutation
+    out2, cache2 = paged_attention_prefill(q, k_new, v_new, jnp.asarray(exp_cache), 0, table,
+                                           seq_seen, np.zeros(8, np.int32), last_tok)
+    assert not np.any(np.asarray(out2))
+    np.testing.assert_array_equal(np.asarray(cache2), exp_cache)
+
+
+# ---- sliding window: both grids, window 16 over 4-token blocks ---------------
+WINDOW, WBS = 16, 4
 
 
 @pytest.mark.parametrize("kvh", [4, 2])  # MHA and GQA
@@ -224,6 +258,11 @@ WINDOW_TILED_BATCHES = {
     # tile boundary, with decode rows riding along past and inside the window
     "late-chunk-plus-decode-rows": ([(61, 1), (45, 100), (7, 1), (WINDOW - 1, 1)], 128),
     "one-tile-at-the-edge": ([(WINDOW - 3, 64)], 64),
+    # one-token passes PAST the window (first visible block > 0, the entries
+    # before it released), one at its edge, beside a chunk past it too
+    "one-token-rows-past-the-window": ([(61, 1), (90, 1), (30, 57), (WINDOW, 1), (77, 1)], 64),
+    # the chunk's last token alone in the next tile, past the window
+    "chunk-end-alone-past-the-window": ([(40, 65), (120, 1)], 128),
 }
 
 
@@ -231,57 +270,62 @@ WINDOW_TILED_BATCHES = {
 @pytest.mark.parametrize("batch", list(WINDOW_TILED_BATCHES))
 def test_tile_grid_window_matches_dense_masked(kvh, batch):
     seqs, T = WINDOW_TILED_BATCHES[batch]
-    rng = np.random.default_rng(0)
-    L, NB, bs, D, H = 2, 120, WBS, 128, 4
-    S, MB = 8, 64
-    cache0 = rng.normal(size=(L, 2, NB, kvh, bs, D)).astype(np.float32)
-    cache0[:, :, 0] = 0.0
-    free = list(rng.permutation(np.arange(1, NB)))
-    table = np.full((S, MB), -1, np.int32)
-    token_seq = np.full(T, S - 1, np.int32)
-    token_pos = np.zeros(T, np.int32)
-    token_valid = np.zeros(T, np.int32)
-    seq_seen, seq_ntok, last_tok = (np.zeros(S, np.int32) for _ in range(3))
-    cursor = 0
-    for s, (seen, n) in enumerate(seqs):
-        for b in range(-(-(seen + n) // bs)):
-            table[s, b] = free.pop()
-        token_seq[cursor:cursor + n] = s
-        token_pos[cursor:cursor + n] = np.arange(seen, seen + n)
-        token_valid[cursor:cursor + n] = 1
-        cursor += n
-        seq_seen[s], seq_ntok[s], last_tok[s] = seen, n, cursor - 1
-    q = rng.normal(size=(T, H, D)).astype(np.float32)
-    k_new = rng.normal(size=(T, kvh, D)).astype(np.float32)
-    v_new = rng.normal(size=(T, kvh, D)).astype(np.float32)
-    exp_cache = cache0.copy()
-    for t in range(cursor):
-        bid = table[token_seq[t], token_pos[t] // bs]
-        exp_cache[:, 0, bid, :, token_pos[t] % bs] = k_new[t]
-        exp_cache[:, 1, bid, :, token_pos[t] % bs] = v_new[t]
+    _check_tile_grid(seqs, T, H=4, kvh=kvh, bs=WBS, NB=120, MB=64, window=WINDOW)
 
-    # released before this step: what is behind the window of each sequence's
-    # FIRST query of the step
-    holes, poisoned = _release_passed(table, cache0, {s: seen for s, (seen, _) in enumerate(seqs)},
-                                      WINDOW, bs)
-    cache = jnp.asarray(poisoned)
-    for li in range(L):
-        got, cache = paged_attention_prefill(q, k_new, v_new, cache, li, holes, seq_seen,
-                                             seq_ntok, last_tok, window=WINDOW)
-        want = _dense_reference(q, exp_cache, li, table, token_seq, token_pos, token_valid,
-                                window=WINDOW)
-        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
-        assert not np.any(np.asarray(got)[cursor:])
-    live = ~np.isnan(poisoned)
-    np.testing.assert_array_equal(np.asarray(cache)[live], exp_cache[live])
+
+@pytest.mark.parametrize("window", [0, WINDOW], ids=["full", "window"])
+@pytest.mark.parametrize("rep", [1, 4, 8])
+def test_one_token_passes_for_every_group_size(rep, window):
+    """``rep`` query heads a KV head: a one-token pass's rows a head are ``rep``
+    (padded to a sublane tile in the kernel), whatever the tile's slab holds."""
+    seqs = [(70, 1), (21, 40), (3, 1), (100, 1), (0, 1)]
+    _check_tile_grid(seqs, 64, H=2 * rep, kvh=2, bs=WBS, NB=80, MB=32, window=window)
+
+
+def _passes_by_the_kernels_rule(seq_ntok, last_tok, bucket_tokens, tq=64):
+    """(sequence, tile) pairs and those with lo == hi, as ``_tiled_kernel``'s
+    ``sequence`` decides them, one pair at a time."""
+    passes = one_token = 0
+    for n, last in zip(seq_ntok, last_tok):
+        for t0 in range(0, bucket_tokens, tq):
+            lo, hi = max(last - n + 1, t0), min(last, t0 + tq - 1)
+            if n > 0 and lo <= hi:
+                passes += 1
+                one_token += lo == hi
+    return passes, one_token
+
+
+@pytest.mark.parametrize("batch", list(TILED_BATCHES) + list(WINDOW_TILED_BATCHES))
+def test_tiled_passes_counts_what_the_kernel_walks(batch):
+    from deepspeed_tpu.ops.pallas.paged_attention import tiled_passes
+    seqs, T = {**TILED_BATCHES, **WINDOW_TILED_BATCHES}[batch]
+    _, _, (_, seq_ntok, last_tok), _ = _ragged_batch(seqs, T, 8, 64, 4, list(range(1000)))
+    assert tiled_passes(seq_ntok, last_tok, T) == _passes_by_the_kernels_rule(seq_ntok, last_tok, T)
+    assert tiled_passes(np.zeros(8, np.int32), last_tok, T) == (0, 0)
+
+
+def test_tiled_passes_of_known_batches():
+    from deepspeed_tpu.ops.pallas.paged_attention import tiled_passes
+
+    def count(batch):
+        seqs, T = batch
+        _, _, (_, seq_ntok, last_tok), _ = _ragged_batch(seqs, T, 8, 64, 4, list(range(1000)))
+        return tiled_passes(seq_ntok, last_tok, T)
+
+    assert count(TILED_BATCHES["chunk-plus-decode-rows"]) == (5, 3)  # the chunk: tiles 0 and 1
+    assert count(TILED_BATCHES["chunk-end-alone-in-next-tile"]) == (2, 1)
+    assert count(TILED_BATCHES["seven-one-token-rows-to-row-63"]) == (8, 7)
+    assert count(TILED_BATCHES["padding-tile"]) == (1, 0)
 
 
 # sha256 of str(jax.make_jaxpr(...)) (addresses blanked) of both grids at the
 # shapes below, taken from the commit BEFORE the kernel had a window argument
 # (d15f72e, jax 0.9.0): with window == 0 the traced program is that one.
+# ``prefill`` was re-recorded in PR 42, whose one-token pass changed the tiled
+# kernel's body with and without a window; ``update`` is still d15f72e's.
 _PRE_WINDOW_JAXPR = {
     "update": "eac6774739b3692404a729d6558959bb2d91e2e90a4447e8f1ae91da79a697f7",
-    "prefill": "ab0af99cf22339658ef1e5723906e91a18e334a90a28f839deefe5955e66ed0e",
+    "prefill": "945c9830c7b821503ea79685fd39e36518e322a26b34d3b93fa12c1f3871424a",
 }
 
 
@@ -377,10 +421,14 @@ def test_engine_kernel_vs_dense_path(prompt_tokens):
         np.testing.assert_allclose(a, b, rtol=3e-5, atol=3e-5)
 
 
-def test_engine_mixed_put_kernel_vs_gather_path():
-    """One ``put`` carrying a decode row and another sequence's second chunk
-    (``seq_seen`` > 0, 70 tokens: a bucket of 128 on the query-tiled grid):
-    the kernel arm's logits are the gather arm's."""
+@pytest.mark.parametrize("decode_rows", [1, 2])
+def test_engine_mixed_put_kernel_vs_gather_path(decode_rows):
+    """One ``put`` carrying ``decode_rows`` decode rows and another sequence's
+    second chunk (``seq_seen`` > 0, 70 tokens: a bucket of 128 on the
+    query-tiled grid): the kernel arm's logits are the gather arm's, and under
+    a telemetry session the kernel arm's span counts the grid's passes (the
+    decode rows one token each; the chunk the rest of tile 0 and tile 1)."""
+    from deepspeed_tpu import telemetry
     from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
     from deepspeed_tpu.inference.v2.engine_factory import build_engine
     from deepspeed_tpu.inference.v2.ragged.manager_configs import (AllocationMode,
@@ -393,19 +441,78 @@ def test_engine_mixed_put_kernel_vs_gather_path():
     cfg = LlamaConfig.tiny(dtype=jnp.float32)
     _, params = init_params(cfg)
     rng = np.random.default_rng(5)
-    first, chunk_a, chunk_b = (rng.integers(0, cfg.vocab_size, n) for n in (19, 40, 70))
+    firsts = [rng.integers(0, cfg.vocab_size, n) for n in (19, 33)[:decode_rows]]
+    chunk_a, chunk_b = (rng.integers(0, cfg.vocab_size, n) for n in (40, 70))
+    chunk_uid = decode_rows
 
-    outs = {}
+    outs, args = {}, {}
     for kernel in (False, True):
         mgr = DSStateManagerConfig(memory_config=MemoryConfig(mode=AllocationMode.ALLOCATE,
                                                               size=64), max_context=512)
         eng = build_engine(params, cfg, RaggedInferenceEngineConfig(
             state_manager=mgr, kv_block_size=16, use_paged_kernel=kernel))
         assert eng.model.attention_arm(128) == ("paged_tiled" if kernel else "xla_gather")
-        nxt = int(np.argmax(np.asarray(eng.put([0], [first]))[0]))
-        eng.put([1], [chunk_a])
-        outs[kernel] = np.asarray(eng.put([0, 1], [np.asarray([nxt]), chunk_b]))
+        nxt = [np.asarray([int(np.argmax(np.asarray(eng.put([uid], [first]))[0]))])
+               for uid, first in enumerate(firsts)]
+        eng.put([chunk_uid], [chunk_a])
+        session = telemetry.configure({"enabled": True, "compile_watch": False})
+        try:
+            outs[kernel] = np.asarray(eng.put(list(range(decode_rows)) + [chunk_uid],
+                                              nxt + [chunk_b]))
+            (span, ) = [s for s in session.spans.export_since(0)["spans"]
+                        if s["name"] == "put" and s["cat"] == "inference"]
+            args[kernel] = span["args"]
+        finally:
+            telemetry.shutdown()
     np.testing.assert_allclose(outs[False], outs[True], rtol=3e-5, atol=3e-5)
+    layers = cfg.num_hidden_layers
+    assert args[True]["attention"] == "paged_tiled"
+    assert args[True]["tiled_passes"] == (decode_rows + 2) * layers
+    assert args[True]["tiled_one_token_passes"] == decode_rows * layers
+    assert "tiled_passes" not in args[False] and "tiled_one_token_passes" not in args[False]
+
+
+def test_batch_counts_are_the_tiled_grids_alone():
+    """``batch_counts`` on hand-built batches: the kernel's rule times the
+    layers on the query-tiled arm, nothing on the per-token grid or the gather
+    arm."""
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.engine_factory import build_engine
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import (AllocationMode,
+                                                                   DSStateManagerConfig,
+                                                                   MemoryConfig)
+    from deepspeed_tpu.models.llama import LlamaConfig, init_params
+    from deepspeed_tpu.utils import groups
+
+    groups.initialize_mesh(force=True)
+    cfg = LlamaConfig.tiny(dtype=jnp.float32)
+    _, params = init_params(cfg)
+
+    def model(kernel):
+        mgr = DSStateManagerConfig(memory_config=MemoryConfig(mode=AllocationMode.ALLOCATE,
+                                                              size=64), max_context=512)
+        return build_engine(params, cfg, RaggedInferenceEngineConfig(
+            state_manager=mgr, kv_block_size=16, use_paged_kernel=kernel)).model
+
+    def batch(seqs, T):
+        _, _, seq, _ = _ragged_batch(seqs, T, 8, 32, 16, list(range(1000)))
+        seq_meta = np.concatenate([np.stack(seq + (np.ones(8, np.int32), ), axis=1),
+                                   np.zeros((8, 32), np.int32)], axis=1)
+        return {"tok_meta": np.zeros((4, T), np.int32), "seq_meta": seq_meta}
+
+    tiled, gather = model(True), model(False)
+    for seqs, T in list(TILED_BATCHES.values()) + [([(5, 250)] + [(9 * i, 1) for i in range(6)],
+                                                    256)]:
+        passes, one_token = _passes_by_the_kernels_rule(*batch(seqs, T)["seq_meta"][:, 1:3].T, T)
+        assert one_token <= passes and passes > 0
+        assert tiled.batch_counts(batch(seqs, T)) == {
+            "tiled_passes": passes * tiled.num_layers,
+            "tiled_one_token_passes": one_token * tiled.num_layers}
+        assert gather.batch_counts(batch(seqs, T)) == {}
+    # a decode bucket is the per-token grid's: nothing to count, a chunk of steps neither
+    decode = batch([(7, 1), (30, 1)], 8)
+    assert tiled.attention_arm(8) == "paged_token"
+    assert tiled.batch_counts(decode) == {} and tiled.batch_counts(decode, 4) == {}
 
 
 def test_decode_loop_kernel_vs_gather_path():
