@@ -102,7 +102,8 @@ class InferenceEngineV2:
         self._batch = RaggedBatchWrapper(engine_config.state_manager,
                                          block_size=engine_config.kv_block_size,
                                          num_groups=kv_config.num_allocation_groups,
-                                         min_table_bucket=kv_config.min_table_bucket)
+                                         min_table_bucket=kv_config.min_table_bucket,
+                                         state_slots=kv_config.sequence_slots)
         self._state_manager = DSStateManager(engine_config.state_manager, kv_config)
         self._model.set_state_manager(self._state_manager)
 
@@ -562,9 +563,11 @@ class InferenceEngineV2:
                 if len(batch_uids) > self._config.state_manager.max_ragged_batch_size:
                     raise SchedulingError(SchedulingResult.BatchTokenLimitExceeded)
                 free_blocks = self._state_manager.free_blocks
+                cur_seqs = self._state_manager.n_tracked_sequences
                 for uid in batch_uids:
                     seq_desc = self._state_manager.get_sequence(uid)
                     if seq_desc is None:
+                        cur_seqs += 1
                         seq_desc = PlaceholderSequenceDescriptor()
                     restore = self._restore_cost(uid, seq_desc)
                     sched_len, sched_blocks = self._model.get_kv_requirements(
@@ -572,6 +575,9 @@ class InferenceEngineV2:
                     if sched_len != n_steps:
                         raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
                     free_blocks -= sched_blocks + restore
+                # before any sequence is touched, as ``can_schedule`` has it
+                if cur_seqs > self._config.state_manager.max_tracked_sequences:
+                    raise SchedulingError(SchedulingResult.EngineSequenceLimitExceeded)
             self._restore_offloaded(batch_uids)
 
             self._batch.clear()
@@ -646,6 +652,10 @@ class InferenceEngineV2:
         (``seen_tokens`` advances by ``n_nodes``); the caller walks the tree
         with the spec-off sampling rule and re-packs/truncates via
         :meth:`compact_accepted`."""
+        if self._state_manager.num_slots:
+            raise NotImplementedError(
+                "verify_tree: this model keeps a per-sequence state group, and a recurrent "
+                "state cannot be rolled back to an accepted prefix without a snapshot a draft")
         batch_uids = list(batch_uids)
         trees = list(trees)
         spans, observer, metrics = self._telemetry_sinks()
@@ -712,6 +722,10 @@ class InferenceEngineV2:
         relies on). The blocks stay allocated for the sequence."""
         if n_tokens <= 0:
             return
+        if self._state_manager.num_slots:
+            raise NotImplementedError(
+                "rollback: this model keeps a per-sequence state group; the slot's state has "
+                "the truncated tokens in it and cannot be wound back without a snapshot")
         seq_desc = self._state_manager.get_sequence(uid)
         if seq_desc is None:
             raise ValueError(f"rollback: unknown uid {uid}")

@@ -46,6 +46,12 @@ def _register_deepseek_v32():
     register_policy("deepseek_v32", DeepseekV32Config, DeepseekV32V2Model)
 
 
+def _register_nemotron_h():
+    from deepspeed_tpu.models.nemotron_h import NemotronHConfig
+    from deepspeed_tpu.inference.v2.model_implementations.nemotron_h_v2 import NemotronHV2Model
+    register_policy("nemotron_h", NemotronHConfig, NemotronHV2Model)
+
+
 def _register_builtin():
     from deepspeed_tpu.models.afmoe import AfmoeConfig
     from deepspeed_tpu.models.decoder import DecoderConfig
@@ -75,6 +81,10 @@ def _register_builtin():
     # a latent cache with absorbed decode, a learned index of keys that selects
     # what attention reads, group-limited sigmoid routing
     _ON_FIRST_USE["deepseek_v32"] = _register_deepseek_v32
+    # serving only, as one chip's share: Mamba-2 blocks whose state is a
+    # sequence's (a per-sequence state group beside the K/V array), relu^2
+    # experts, attention without position encoding, one mixer a block
+    _ON_FIRST_USE["nemotron_h"] = _register_nemotron_h
     register_policy("opt", DecoderConfig, DecoderV2Model)
     register_policy("falcon", DecoderConfig, DecoderV2Model)
     register_policy("phi", DecoderConfig, DecoderV2Model)

@@ -69,6 +69,13 @@ class DSTransformerModelBase:
         raise NotImplementedError
 
     @property
+    def num_kv_layers(self) -> int:
+        """Layers that keep K/V (a row a token): the depth of the K/V array and
+        what a layer's cache index counts. Every layer, unless the model says
+        otherwise (one whose blocks are not all attention)."""
+        return self.num_layers
+
+    @property
     def num_kv_heads(self) -> int:
         raise NotImplementedError
 
@@ -101,10 +108,12 @@ class DSTransformerModelBase:
             cache_dtype = "bfloat16"
         return KVCacheConfig(block_size=self._engine_config.kv_block_size,
                              num_allocation_groups=self.kv_groups,
-                             cache_shape=(self.num_layers, self.num_kv_heads, self.head_dim),
+                             cache_shape=(self.num_kv_layers, self.num_kv_heads, self.head_dim),
                              state_widths=self.kv_state_widths,
                              min_table_bucket=self.min_table_bucket,
                              cache_dtype=cache_dtype,
+                             sequence_state=self.sequence_state,
+                             sequence_slots=sm.max_tracked_sequences if self.sequence_state else 0,
                              max_blocks_per_allocation_group=(sm.max_context + self._engine_config.kv_block_size - 1)
                              // self._engine_config.kv_block_size)
 
@@ -113,6 +122,17 @@ class DSTransformerModelBase:
         """The widths of the rows a token keeps a layer where its cached state
         is not a K/V pair of heads (``KVCacheConfig.state_widths``); empty for
         K and V."""
+        return ()
+
+    @property
+    def sequence_state(self) -> tuple:
+        """The pools of a per-SEQUENCE state group (``SequenceStateSpec``s,
+        ``KVCacheConfig.sequence_state``): state a layer keeps a sequence
+        whatever its length, a slot a tracked sequence
+        (``max_tracked_sequences`` of them: the limit on tracked sequences,
+        which ``can_schedule``, ``query`` and ``dispatch_decode_loop`` count,
+        is the count of free slots, so admission needs no second counter).
+        Empty for a model whose every layer keeps a row a token."""
         return ()
 
     @property
@@ -138,8 +158,8 @@ class DSTransformerModelBase:
         from deepspeed_tpu.ops.pallas.paged_attention import tiled_passes
         seq = np.asarray(batch["seq_meta"])
         passes, one_token = tiled_passes(seq[:, 1], seq[:, 2], bucket_tokens)
-        return {"tiled_passes": passes * self.num_layers,
-                "tiled_one_token_passes": one_token * self.num_layers}
+        return {"tiled_passes": passes * self.num_kv_layers,
+                "tiled_one_token_passes": one_token * self.num_kv_layers}
 
     def set_state_manager(self, state_manager):
         self._state_manager = state_manager
@@ -292,7 +312,8 @@ class DSTransformerModelBase:
         wrapper = RaggedBatchWrapper(self._engine_config.state_manager,
                                      block_size=self._engine_config.kv_block_size,
                                      num_groups=self.kv_groups,
-                                     min_table_bucket=self.min_table_bucket)
+                                     min_table_bucket=self.min_table_bucket,
+                                     state_slots=self._state_manager.num_slots)
         batch = wrapper.finalize()  # zero live sequences/tokens
         dev = {"tok_meta": batch["tok_meta"], "seq_meta": batch["seq_meta"]}
         fn = self._get_compiled(self._bucket_of(batch))
@@ -351,7 +372,8 @@ class DSTransformerModelBase:
             bucket = (to_padded(1), _pad_to(1, 8), _pow2_pad(1, self.min_table_bucket))
         T, S, MB = bucket
         return {"tok_meta": np.zeros((4, T), np.int32),
-                "seq_meta": np.full((S, 4 + self.kv_groups * MB), -1, np.int32)}
+                "seq_meta": np.full((S, 4 + self.kv_groups * MB + self._slot_columns), -1,
+                                    np.int32)}
 
     def lower_forward(self, bucket=None):
         """Lower the ragged forward at ``bucket`` (``(T, S, MB)``; default
@@ -468,7 +490,8 @@ class DSTransformerModelBase:
             cache, tok_meta, seq_meta, r = carry
             # banks: the forward's count of expert banks touched, where it has one
             logits, cache, *banks = self._forward_impl(
-                params, cache, {"tok_meta": tok_meta, "seq_meta": seq_meta})
+                params, cache, {"tok_meta": tok_meta, "seq_meta": seq_meta,
+                                "one_token_rows": True})
             if sampled:
                 r, sub = jax.random.split(r)
                 next_ids = jax.random.categorical(
@@ -489,22 +512,34 @@ class DSTransformerModelBase:
             step, (cache, tok_meta, seq_meta, rng), None, length=n_steps)
         return (tokens, cache, *banks)
 
+    @property
+    def _slot_columns(self) -> int:
+        """Columns of ``seq_meta`` behind the block tables: a sequence's slot
+        in the per-sequence state group, where the model has one."""
+        return 1 if self.sequence_state else 0
+
     def _bucket_of(self, batch):
         """``(T, S, MB)`` of a packed batch: the jit cache key."""
         seq_meta = batch["seq_meta"]
         return (batch["tok_meta"].shape[1], seq_meta.shape[0],
-                (seq_meta.shape[1] - 4) // self.kv_groups)
+                (seq_meta.shape[1] - 4 - self._slot_columns) // self.kv_groups)
 
     def _unpack_batch(self, batch):
         """Packed [4,T]/[S,4+G*MB] metadata → the named per-field views (built
         inside jit: free slices, no extra transfers). ``block_table`` is
         ``[S, MB]``, or ``[S, G, MB]`` for a model with G > 1 KV layer groups
-        (:meth:`_kv_view` hands a layer its own)."""
+        (:meth:`_kv_view` hands a layer its own). A model with a per-sequence
+        state group reads ``state_slot`` [S] from the column behind the
+        tables, and ``one_token_rows``: the step is ``decode_loop``'s, every
+        sequence feeds one token and token i is sequence i's."""
         tok, seq = batch["tok_meta"], batch["seq_meta"]
         out = dict(input_ids=tok[0], token_seq=tok[1], token_pos=tok[2],
                    token_valid=tok[3].astype(bool), seq_seen=seq[:, 0],
                    seq_ntok=seq[:, 1], last_tok=seq[:, 2],
                    seq_valid=seq[:, 3].astype(bool), block_table=seq[:, 4:])
+        if self._slot_columns:
+            out.update(block_table=seq[:, 4:-1], state_slot=seq[:, -1],
+                       one_token_rows=bool(batch.get("one_token_rows", False)))
         if self.kv_groups > 1:
             out["block_table"] = out["block_table"].reshape(seq.shape[0], self.kv_groups, -1)
         return out
@@ -624,7 +659,7 @@ class DSTransformerModelBase:
         group ``li % len(group_windows)`` (``ragged/kv_cache.py``). One window
         for every layer — none included — is one group."""
         if self._group_windows is None:
-            windows = [int(self.attention_window_of(li)) for li in range(self.num_layers)]
+            windows = [int(self.attention_window_of(li)) for li in range(self.num_kv_layers)]
             period = next(p for p in range(1, len(windows) + 1)
                           if len(windows) % p == 0
                           and all(w == windows[i % p] for i, w in enumerate(windows)))
@@ -924,6 +959,11 @@ class DSTransformerModelBase:
         if self.kv_state_widths:
             raise NotImplementedError("compact_kv (a tree-verify re-pack) is written for the K/V "
                                       "array: a latent KV group has no speculative verify")
+        if self.sequence_state:
+            raise NotImplementedError(
+                "compact_kv (a tree-verify re-pack): a per-sequence state group has no "
+                "speculative verify — a recurrent state cannot be rolled back to an accepted "
+                "prefix without a snapshot a draft")
         src = np.asarray(src_slots, np.int64).reshape(-1)
         dst = np.asarray(dst_slots, np.int64).reshape(-1)
         if src.size != dst.size:

@@ -306,6 +306,27 @@ class RaggedMoE:
             jnp.where(ok, 1.0, 0.0).astype(dispatch.dtype), mode="drop")
         return combine, dispatch
 
+    @staticmethod
+    def banks_in_lane_tiles(wi, wo):
+        """An UNGATED bank pair ``wi [E, M, F]`` / ``wo [E, F, M]`` with the
+        intermediate width F padded with zeros to whole lane tiles
+        (``grouped_matmul.lane_padded``), as given where it is whole already:
+        the grouped kernel takes whole lane tiles and falls to ``ragged_dot``
+        on anything else. Exact for an activation with ``act(0) = 0``: a zero
+        column of ``wi`` meets a zero row of ``wo``. Once, where the weights
+        are loaded — never inside a step."""
+        import jax.numpy as jnp
+
+        from deepspeed_tpu.ops.pallas.grouped_matmul import lane_padded
+        F = wo.shape[-2]
+        if wi.shape[-1] != F:
+            raise NotImplementedError(f"banks_in_lane_tiles: a gated bank (wi {wi.shape[-1]} "
+                                      f"wide over wo's {F}) interleaves two projections")
+        pad = lane_padded(F) - F
+        if not pad:
+            return wi, wo
+        return (jnp.pad(wi, ((0, 0), (0, 0), (0, pad))), jnp.pad(wo, ((0, 0), (0, pad), (0, 0))))
+
     def _expert_ffn(self, buf, wi, wo, activation):
         """Grouped expert GEMM over an expert-major buffer [E?, C?, M] (the
         reference's CUTLASS multi-GEMM, moe_gemm.cu:175 role)."""

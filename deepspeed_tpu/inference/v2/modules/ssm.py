@@ -1,0 +1,166 @@
+"""The Mamba-2 mixer's state-space part over a ragged batch, in two forms that
+agree (tier-1 holds them against each other and against the token-by-token
+reference, ``tests/unit/inference/v2/test_nemotron_h.py``).
+
+A head's state is ``h_t = exp(dt_t a) h_{t-1} + dt_t x_t (x) B_t`` in
+``R^{P x N}``, float32, and ``y_t = h_t C_t``; heads read B and C by group
+(head h reads group ``h // (H / G)``). The state belongs to a SEQUENCE: a step
+is handed each sequence's state ``[S, H, P, N]`` as it was left by the step
+before and hands back what the step leaves. Neither form makes a state a
+token.
+
+:func:`scan_ragged`, a ``put`` step: flat tokens of several sequences, a
+sequence's tokens side by side (its SEGMENT), decode rows of one token beside
+a prefill chunk. The published chunked form over that axis: the batch is cut
+into chunks of ``chunk`` tokens; inside a chunk ``y = (L * C B^T) (dt x)``
+with ``L[t, s]`` the decay from s to t where s and t are one sequence's and
+``s <= t`` and 0 elsewhere, plus each token's reading of its sequence's state
+AS IT ENTERED THE CHUNK through the decay from the segment's start; across
+chunks each sequence's state is carried: decayed by the whole of its segment's
+part in the chunk, plus what that part adds. A segment may straddle chunks, a
+chunk may hold many segments. The sums of log-decays are float32 (the masks'
+matmuls at ``highest`` precision). The products that read or make the state
+run at ``high`` precision (three bf16 passes where the backend's default for
+float32 operands is one): a float32 state read through one bf16 pass is a bf16
+state, and the noise it puts on every later row's hidden state is enough to
+flip a router's choice at gaps the comparison with the float32 reference holds
+to its tight tolerance (read on the chip: PERF.md section 6, PR 43).
+
+:func:`step`, a ``decode_loop`` step: one token a sequence, the recurrence as
+written above.
+
+:func:`conv_ragged` / :func:`conv_step`: the causal depthwise convolution in
+front of the scan, a token seeing the ``K - 1`` rows before it of ITS OWN
+sequence, those ahead of a segment's first row from the sequence's kept tail.
+"""
+
+import jax
+import jax.numpy as jnp
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+_HIGH = jax.lax.Precision.HIGH
+
+
+def segments(token_seq, token_valid, n_seqs: int):
+    """``[T, S]`` bool: token t is a live token of sequence i."""
+    return (token_seq[:, None] == jnp.arange(n_seqs)[None, :]) & token_valid[:, None]
+
+
+# ------------------------------------------------------------- convolution --
+def conv_ragged(xbc, weight, bias, tail, token_seq, seq_start, seq_ntok):
+    """``xbc`` [T, C]; ``weight`` [C, K]; ``tail`` [S, K - 1, C], a sequence's
+    last K - 1 rows before this step (zeros for a new one); ``token_seq`` [T]
+    each token's sequence (a padding row's output is nobody's); ``seq_start``
+    [S] the flat index of each segment's first token, ``seq_ntok`` [S] its
+    length. Returns ``(out [T, C] float32, the new tails [S, K - 1, C])``; a
+    sequence without tokens keeps its tail."""
+    T = xbc.shape[0]
+    K = weight.shape[1]
+    local = jnp.arange(T) - seq_start[token_seq]  # the token's place in its segment
+    x32 = xbc.astype(jnp.float32)
+    w = weight.astype(jnp.float32)
+    out = x32 * w[None, :, K - 1] + bias.astype(jnp.float32)[None, :]
+    for d in range(1, K):  # the row d places back
+        here = jnp.pad(x32, ((d, 0), (0, 0)))[:T]
+        kept = tail[token_seq, jnp.clip(K - 1 + local - d, 0, K - 2)].astype(jnp.float32)
+        out = out + jnp.where((local >= d)[:, None], here, kept) * w[None, :, K - 1 - d]
+    # the tail a segment leaves: the last K - 1 rows of (old tail ++ segment)
+    j = jnp.arange(K - 1)[None, :]
+    at = seq_ntok[:, None] - (K - 1) + j  # [S, K - 1], the row's place in the segment
+    new = x32[jnp.clip(seq_start[:, None] + at, 0, T - 1)]
+    old = jnp.take_along_axis(tail.astype(jnp.float32),
+                              jnp.clip(at + K - 1, 0, K - 2)[:, :, None], axis=1)
+    return out, jnp.where((at >= 0)[:, :, None], new, old).astype(tail.dtype)
+
+
+def conv_step(xbc, weight, bias, tail):
+    """One token a sequence: ``xbc`` [S, C], ``tail`` [S, K - 1, C]."""
+    window = jnp.concatenate([tail.astype(jnp.float32), xbc.astype(jnp.float32)[:, None]], axis=1)
+    out = jnp.einsum("skc,ck->sc", window, weight.astype(jnp.float32)) \
+        + bias.astype(jnp.float32)[None, :]
+    return out, window[:, 1:].astype(tail.dtype)
+
+
+# -------------------------------------------------------------------- scan --
+def _chunk(x, dt, a, B, C, h, onehot):
+    """One chunk of Q tokens. x [Q, G, R, P]; dt, a [Q, G, R] (a = dt x A, 0
+    for a row that is no sequence's); B, C [Q, G, N]; h [S, G, R, P, N];
+    onehot [Q, S]."""
+    Q = x.shape[0]
+    f32 = jnp.float32
+    oh = onehot.astype(f32)
+    same = (oh @ oh.T) > 0  # 0 / 1 operands: exact whatever the precision
+    at = jnp.arange(Q)
+    causal = same & (at[None, :] <= at[:, None])  # [t, s]: s is t's own, at or before it
+    after = same & (at[None, :] > at[:, None])
+    # float32 sums of log-decays: from the segment's start to t (inclusive),
+    # from behind t to the end of the segment's part in this chunk, the whole part
+    lcs = jnp.einsum("ts,sgr->tgr", causal.astype(f32), a, precision=_HIGHEST)
+    rest = jnp.einsum("ts,sgr->tgr", after.astype(f32), a, precision=_HIGHEST)
+    whole = jnp.einsum("ti,tgr->igr", oh, a, precision=_HIGHEST)
+    # inside the chunk
+    decay = jnp.where(causal[:, :, None, None],
+                      jnp.exp(jnp.minimum(lcs[:, None] - lcs[None, :], 0.0)), 0.0)  # [t, s, G, R]
+    cb = jnp.einsum("tgn,sgn->tsg", C, B, precision=_HIGH)
+    y = jnp.einsum("tsgr,sgrp->tgrp", decay * cb[..., None] * dt[None], x, precision=_HIGH)
+    # the state that entered: each token reads ITS sequence's, one contraction
+    # over (sequence, n) with the others' rows zero
+    c_own = oh[:, None, :, None] * C[:, :, None, :]  # [Q, G, S, N]
+    y = y + jnp.exp(lcs)[..., None] * jnp.einsum("tgin,igrpn->tgrp", c_own, h, precision=_HIGH)
+    # the state that leaves
+    b_own = oh[:, :, None, None] * B[:, None, :, :]  # [Q, S, G, N]
+    add = jnp.einsum("tgrp,tign->igrpn", x * (dt * jnp.exp(rest))[..., None], b_own,
+                     precision=_HIGH)
+    return y, jnp.exp(whole)[..., None, None] * h + add
+
+
+def scan_ragged(x, dt, A, B, C, h0, onehot, chunk: int):
+    """x [T, H, P]; dt [T, H] float32 (after the softplus); A [H] (negative);
+    B, C [T, G, N]; h0 [S, H, P, N] float32; onehot :func:`segments`. Returns
+    ``(y [T, H, P] float32, h [S, H, P, N])``; a row that is no sequence's
+    changes no state, a sequence without tokens keeps its own."""
+    T, H, P = x.shape
+    G, N = B.shape[1:]
+    R = H // G
+    S = h0.shape[0]
+    Q = min(chunk, T)
+    assert T % Q == 0, (T, chunk)
+    f32 = jnp.float32
+    live = onehot.any(axis=1)
+    dt = dt.astype(f32).reshape(T, G, R)
+    a = jnp.where(live[:, None, None], dt * A.astype(f32).reshape(1, G, R), 0.0)
+    x = x.astype(f32).reshape(T, G, R, P)
+    B, C = B.astype(f32), C.astype(f32)
+    h = h0.reshape(S, G, R, P, N)
+    ys = []
+    for c in range(T // Q):
+        rows = slice(c * Q, (c + 1) * Q)
+        y, h = _chunk(x[rows], dt[rows], a[rows], B[rows], C[rows], h, onehot[rows])
+        ys.append(y)
+    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
+    return y.reshape(T, H, P), h.reshape(S, H, P, N)
+
+
+def step(x, dt, A, B, C, h):
+    """The recurrence, one token a sequence: x [S, H, P]; dt [S, H]; B, C
+    [S, G, N]; h [S, H, P, N] float32. Returns ``(y [S, H, P], h)``."""
+    S, H, P = x.shape
+    G, N = B.shape[1:]
+    R = H // G
+    f32 = jnp.float32
+    dt = dt.astype(f32).reshape(S, G, R)
+    decay = jnp.exp(dt * A.astype(f32).reshape(1, G, R))
+    x = x.astype(f32).reshape(S, G, R, P) * dt[..., None]
+    h = h.reshape(S, G, R, P, N) * decay[..., None, None] \
+        + x[..., None] * B.astype(f32)[:, :, None, None, :]
+    y = (h * C.astype(f32)[:, :, None, None, :]).sum(-1)
+    return y.reshape(S, H, P), h.reshape(S, H, P, N)
+
+
+def gated_norm(y, z, weight, groups: int, eps: float):
+    """``RMSNorm_grouped(y silu(z)) weight``: the gate BEFORE the norm, the
+    norm over ``groups`` groups of the channels. y, z [T, D]."""
+    T, D = y.shape
+    g = (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))).reshape(T, groups, D // groups)
+    g = g * jax.lax.rsqrt(jnp.square(g).mean(axis=-1, keepdims=True) + eps)
+    return g.reshape(T, D) * weight.astype(jnp.float32)[None, :]

@@ -28,6 +28,17 @@ a width, that the one allocator's block ids address alike; a block id's bytes
 are ``block_size x layers x sum(widths)`` values. What moves block CONTENTS
 (fork, offload / restore, handoff frames) is written for the K/V array and
 refuses a latent group by name.
+
+A per-SEQUENCE state group (``KVCacheConfig.sequence_state``, a model with
+state-space layers): layers whose state is one array a live sequence whatever
+its length. One pool a spec, ``[layers, slots, ...]``, zero-initialised, beside
+the K/V array in the cache pytree: ``cache`` is then ``(K/V array, pool, ...)``.
+A SLOT is what a block is to the K/V array: handed out by an allocator of the
+same kind whose unit is a sequence (``reserve_slot`` / ``free_slot``), at the
+sequence's first token and until its flush. The K/V array holds the layers that
+keep K/V (``cache_shape[0]``), which such a model counts apart from its blocks.
+What moves block contents refuses a per-sequence state group by name: a slot is
+in no block table.
 """
 
 import os
@@ -119,6 +130,21 @@ class BlockedKVCache:
         self._block_bytes = block_bytes
         logger.info(f"BlockedKVCache: {num_blocks} blocks x {config.block_size} tokens "
                     f"({num_blocks * block_bytes / 1e9:.2f} GB)")
+        # a per-sequence state group: its pools ride in the cache pytree, its
+        # slots come from an allocator of the blocks' kind
+        self._slots = None
+        if config.sequence_state:
+            if config.sequence_slots < 1:
+                raise ValueError("a per-sequence state group needs sequence_slots >= 1")
+            whole = self._pool_sharding(config)
+            self._slots = BlockedAllocator(config.sequence_slots)
+            pools = tuple(jnp.zeros((spec.layers, config.sequence_slots) + tuple(spec.shape),
+                                    jnp.dtype(spec.dtype), device=whole)
+                          for spec in config.sequence_state)
+            self._cache = (self._cache, ) + pools
+            logger.info(f"BlockedKVCache: {config.sequence_slots} sequence slots "
+                        f"({sum(p.nbytes for p in pools) / 1e9:.2f} GB in "
+                        f"{[spec.name for spec in config.sequence_state]})")
 
         # off-device tiers (reference BlockedKVCache:40 declares
         # offload/restore and raises NotImplementedError — implemented here
@@ -156,7 +182,60 @@ class BlockedKVCache:
         """Bytes one block id holds: ``block_size`` tokens of every layer of a group."""
         return self._block_bytes
 
+    def _pool_sharding(self, config: KVCacheConfig):
+        """Where a per-sequence state group's pools live: whole on every chip
+        of the engine's mesh, as a latent group's pools are (every chip scans
+        every sequence; under expert parallelism only the MoE exchanges
+        tokens). Refused by name: a ``model`` axis, which splits attention by
+        head and would have to split the pools by theirs; and more bytes than
+        the device has free (``sequence_slots`` is the engine's
+        ``max_tracked_sequences``, 2048 unless the deployment says)."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from deepspeed_tpu.accelerator import get_accelerator
+        from deepspeed_tpu.utils import groups
+        names = [spec.name for spec in config.sequence_state]
+        mesh = self._sharding.mesh if self._sharding is not None else None
+        if mesh is not None and mesh.shape[groups.MODEL_AXIS] > 1:
+            raise NotImplementedError(
+                f"a per-sequence state group ({names}) on a mesh with model="
+                f"{mesh.shape[groups.MODEL_AXIS]}: tensor parallelism splits the K/V array by "
+                f"head, and these pools (a state a sequence a layer) are not split by theirs")
+        need = sum(spec.layers * config.sequence_slots * int(np.prod(spec.shape))
+                   * _dtype_size(spec.dtype) for spec in config.sequence_state)
+        free = get_accelerator().available_memory()
+        if get_accelerator().total_memory() and need > free:
+            raise ValueError(
+                f"a per-sequence state group ({names}) of {config.sequence_slots} slots "
+                f"(state_manager.max_tracked_sequences: a slot a tracked sequence) needs "
+                f"{need / 1e9:.2f} GB, {need / config.sequence_slots / 1e6:.2f} MB a slot, and "
+                f"the device has {free / 1e9:.2f} GB free beside the weights and the K/V "
+                f"blocks: lower max_tracked_sequences to what the deployment serves at once")
+        return None if mesh is None else NamedSharding(mesh, PartitionSpec())
+
+    @property
+    def num_slots(self) -> int:
+        """Slots of the per-sequence state group; 0 for a cache without one."""
+        return self._config.sequence_slots if self._slots is not None else 0
+
+    @property
+    def free_slots(self):
+        """Free slots of the per-sequence state group; None without one."""
+        return None if self._slots is None else self._slots.free_blocks
+
+    def reserve_slot(self) -> int:
+        return int(self._slots.allocate(1)[0])
+
+    def free_slot(self, slot: int) -> None:
+        self._slots.free([slot])
+
     def _kv_pairs_only(self, what: str) -> None:
+        if self._slots is not None:
+            raise NotImplementedError(
+                f"{what}: this cache has a per-sequence state group "
+                f"({[spec.name for spec in self._config.sequence_state]}: one slot a sequence, "
+                f"in no block table); what moves block contents is written for the K/V array "
+                f"alone and would leave the slot behind — recompute the sequence instead")
         if self._config.state_widths:
             raise NotImplementedError(
                 f"{what}: this cache is a latent group (rows of widths "

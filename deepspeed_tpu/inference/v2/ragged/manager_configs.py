@@ -17,13 +17,24 @@ class AllocationMode(Enum):
     ALLOCATE = "allocate"
 
 
+class SequenceStateSpec(DeepSpeedConfigModel):
+    """One pool of a per-SEQUENCE state group: ``[layers, slots, *shape]`` of
+    ``dtype``, a slot a live sequence whatever its length."""
+    name: str
+    layers: int = Field(1, gt=0)
+    shape: Tuple[int, ...]
+    dtype: str = "float32"
+
+
 class KVCacheConfig(DeepSpeedConfigModel):
     block_size: int = 128
     # KV layer groups: layer li reads block table li % groups at cache layer
     # li // groups, so a block id holds num_layers / groups layers of ONE group
     # (kv_cache.py). One group is one table for every layer.
     num_allocation_groups: int = Field(1, gt=0)
-    cache_shape: Tuple[int, int, int] = (0, 0, 0)  # (num_layers, num_heads, head_size)
+    # (layers that keep K/V, num_heads, head_size): the first is the count of
+    # layers with a row a token, not of the model's blocks
+    cache_shape: Tuple[int, int, int] = (0, 0, 0)
     # A token's state a layer where it is NOT a K/V pair of heads: one row of
     # each of these widths (a latent-attention model: its latent row and its
     # index key), one pool ``[layers, blocks, block_size, width]`` a width, all
@@ -35,6 +46,13 @@ class KVCacheConfig(DeepSpeedConfigModel):
     # table up to some length says so here
     min_table_bucket: int = Field(4, gt=0)
     cache_dtype: str = "bfloat16"
+    # A per-SEQUENCE state group (a state-space layer's recurrent state, its
+    # convolution's tail): one pool a spec, ``sequence_slots`` slots each, a
+    # slot a tracked sequence from its first token to its flush, whatever its
+    # length; beside the K/V array in the ONE cache pytree the programs take.
+    # Empty = every layer's state is a row a token.
+    sequence_state: Tuple[SequenceStateSpec, ...] = ()
+    sequence_slots: int = Field(0, ge=0)
     max_blocks_per_allocation_group: int = Field(0, ge=0)
 
 

@@ -43,6 +43,11 @@ class DSStateManager:
         max_blocks = (self._config.max_context + self._kv_config.block_size - 1) // self._kv_config.block_size
         seq = DSSequenceDescriptor(uid, max_blocks_per_seq=max_blocks,
                                    num_groups=self._kv_config.num_allocation_groups)
+        if self._kv_cache.num_slots:
+            if not self._kv_cache.free_slots:
+                raise RuntimeError(f"sequence {uid}: no free slot in the per-sequence state "
+                                   f"group ({self._kv_cache.num_slots} slots, all held)")
+            seq.state_slot = self._kv_cache.reserve_slot()
         self._seqs[uid] = seq
         return seq
 
@@ -54,6 +59,7 @@ class DSStateManager:
         ``flush_sequence`` returns). The next forward continues at position
         ``seen_tokens`` exactly like a restored or imported sequence."""
         self._one_table_only("create_cached_sequence (a prefix-cache hit)")
+        self._no_sequence_state("create_cached_sequence (a prefix-cache hit)")
         blocks = np.atleast_1d(np.asarray(blocks)).astype(np.int64)
         seen_tokens = int(seen_tokens)
         if seen_tokens < 0 or seen_tokens > blocks.size * self._kv_config.block_size:
@@ -79,6 +85,14 @@ class DSStateManager:
                              f"shared or exported table cannot stand for them — recompute "
                              f"the sequence instead")
 
+    def _no_sequence_state(self, what: str) -> None:
+        """A block table says nothing of a per-sequence state group's slot."""
+        if self._kv_cache.num_slots:
+            raise NotImplementedError(
+                f"{what}: this model keeps a per-sequence state group (a slot a sequence, in "
+                f"no block table): shared blocks, an offloaded table or an exported frame "
+                f"would leave the slot's state behind — recompute the sequence instead")
+
     def flush_sequence(self, uid: int) -> None:
         """Release all state for a sequence (reference ragged_manager.py:110)."""
         seq = self._seqs.pop(uid, None)
@@ -90,6 +104,11 @@ class DSStateManager:
             self._kv_cache.drop_offloaded(handle)
         elif seq.live_blocks > 0:
             self._kv_cache.free(seq.live_kv_blocks)
+        if seq.state_slot is not None:
+            # back with the allocator as it is: whoever takes it next starts
+            # from zero by its own count of tokens seen, not by a wipe
+            self._kv_cache.free_slot(seq.state_slot)
+            seq.state_slot = None
 
     def release_passed_blocks(self, seq: DSSequenceDescriptor, window: int,
                               group: int = 0) -> int:
@@ -124,6 +143,7 @@ class DSStateManager:
         """Evict a (cold) sequence's KV blocks to the host tier, freeing its
         device blocks for other sequences. The sequence stays tracked; the
         next forward that touches it restores it (engine put/decode_loop)."""
+        self._no_sequence_state("offload_sequence")
         seq = self._seqs.get(uid)
         if seq is None:
             raise ValueError(f"offload_sequence: unknown uid {uid}")
@@ -176,6 +196,7 @@ class DSStateManager:
         payload is already host-side, but export must observe one canonical
         path). The sequence stays tracked and resident here; the caller
         flushes once the recipient has taken over."""
+        self._no_sequence_state("export_sequence (a handoff or park frame)")
         seq = self._seqs.get(uid)
         if seq is None:
             raise ValueError(f"export_sequence: unknown uid {uid}")
@@ -200,6 +221,7 @@ class DSStateManager:
         already tracked, the payload's geometry doesn't fit this cache, or
         the device pool can't hold it (evict and retry)."""
         self._one_table_only("import_sequence")
+        self._no_sequence_state("import_sequence")
         uid = int(snapshot["uid"] if uid is None else uid)
         if uid in self._seqs:
             raise ValueError(f"import_sequence: uid {uid} already tracked")
@@ -240,6 +262,15 @@ class DSStateManager:
     @property
     def free_blocks(self) -> int:
         return self._kv_cache.free_blocks
+
+    @property
+    def free_slots(self):
+        """Free slots of the per-sequence state group; None for a model without one."""
+        return self._kv_cache.free_slots
+
+    @property
+    def num_slots(self) -> int:
+        return self._kv_cache.num_slots
 
     @property
     def num_groups(self) -> int:

@@ -63,15 +63,19 @@ class RaggedBatchWrapper:
     """Host-side composition of one ragged forward batch."""
 
     def __init__(self, config: DSStateManagerConfig, block_size: int = 128,
-                 num_groups: int = 1, min_table_bucket: int = 4) -> None:
+                 num_groups: int = 1, min_table_bucket: int = 4, state_slots: int = 0) -> None:
         """``num_groups``: block tables a sequence (KV layer groups,
         ``ragged/kv_cache.py``); the batch carries them side by side.
         ``min_table_bucket``: the smallest block-table bucket
-        (``KVCacheConfig.min_table_bucket``)."""
+        (``KVCacheConfig.min_table_bucket``). ``state_slots``: the slots of a
+        per-sequence state group (``KVCacheConfig.sequence_slots``); over 0,
+        ``seq_meta`` carries each sequence's slot as one more column behind
+        its block tables."""
         self._config = config
         self._block_size = block_size
         self._num_groups = num_groups
         self._min_table_bucket = min_table_bucket
+        self._state_slots = state_slots
         self.clear()
 
     def clear(self) -> None:
@@ -89,6 +93,7 @@ class RaggedBatchWrapper:
         self._seq_seen: List[int] = []
         self._seq_ntok: List[int] = []
         self._seq_blocks: List[np.ndarray] = []
+        self._seq_slots: List[int] = []
         self._device_batch = None
 
     @property
@@ -131,6 +136,8 @@ class RaggedBatchWrapper:
         self._seq_seen.append(seen)
         self._seq_ntok.append(int(tokens.size))
         self._seq_blocks.append(seq_desc.block_tables)
+        if self._state_slots:
+            self._seq_slots.append(seq_desc.state_slot)
         self._token_ids.extend(int(t) for t in tokens)
         self._token_seq.extend([seq_idx] * tokens.size)
         self._token_pos.extend(range(seen, seen + tokens.size))
@@ -193,6 +200,12 @@ class RaggedBatchWrapper:
             np.stack([seq_seen, seq_ntok, last_tok, seq_valid.astype(np.int32)], axis=1),
             block_table.reshape(S, G * MB)
         ], axis=1)  # [S, 4 + G * MB]
+        if self._state_slots:
+            # a padding row points one past the last slot, so that its scatter
+            # drops: the block table's convention
+            slots = np.full((S, 1), self._state_slots, np.int32)
+            slots[:n_seq, 0] = self._seq_slots
+            seq_meta = np.concatenate([seq_meta, slots], axis=1)  # [S, 4 + G * MB + 1]
         self._device_batch = dict(
             tok_meta=tok_meta,
             seq_meta=seq_meta,

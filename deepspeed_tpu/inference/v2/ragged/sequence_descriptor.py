@@ -38,6 +38,10 @@ class DSSequenceDescriptor:
         # state manager flips it to the store-reported tier across an
         # offload (ragged_manager.offload_sequence / restore_sequence)
         self.kv_tier: str = "device"
+        # the sequence's slot in a per-sequence state group (``kv_cache.py``):
+        # the state manager's, from the sequence's creation to its flush; None
+        # for a model whose every layer keeps a row a token
+        self.state_slot: Optional[int] = None
 
     @property
     def seen_tokens(self) -> int:

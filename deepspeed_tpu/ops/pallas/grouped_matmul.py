@@ -66,6 +66,12 @@ def padded_rows(rows: int) -> int:
     return -(-rows // ROW_TILE) * ROW_TILE
 
 
+def lane_padded(width: int) -> int:
+    """``width`` in whole 128-lane tiles: what :func:`column_tile` asks of a
+    bank's output width (a device array's minor dimension is tiled so anyway)."""
+    return -(-width // 128) * 128
+
+
 def column_tile(K: int, N: int, itemsize: int):
     """The column block ``tn`` for rows ``[R, K]`` against a bank ``[G, K, N]``,
     or None where the shapes are not the kernel's (a width that is no multiple

@@ -334,6 +334,20 @@ class ServingScheduler:
                 f"{tuple(widths)} a token a layer): the trie's copy-on-write and the tier ladder "
                 f"move block contents, which is written for the K/V array. Turn both off for "
                 f"this model.")
+        # a per-sequence state group (a model with state-space layers): a slot a
+        # sequence, in no block table. What shares, moves or rolls back block
+        # tables would leave the slot's state behind.
+        self._sequence_state = bool(getattr(getattr(engine, "model", None), "sequence_state", ()))
+        if self._sequence_state:
+            for name, on in (("prefix_cache", self._config.prefix_cache.enabled),
+                             ("kv_tiers", self._config.kv_tiers.enabled),
+                             ("speculative", self._config.speculative.enabled)):
+                if on:
+                    raise ValueError(
+                        f"{name} cannot serve a per-sequence state group (this model keeps a "
+                        f"recurrent state a sequence, in a slot and in no block table): a "
+                        f"shared prefix's blocks, a tier's payload or a rolled-back draft "
+                        f"would leave the slot's state behind. Turn it off for this model.")
         if self._config.prefix_cache.enabled:
             from deepspeed_tpu.inference.v2.ragged.prefix_cache import PrefixCache
             self._prefix_cache = PrefixCache(
@@ -654,6 +668,12 @@ class ServingScheduler:
                 f"handoff, park and resume frames carry a sequence's whole KV block table; "
                 f"a sliding-window model (a layer's attention window is {self._window}) releases "
                 f"the blocks its window has passed. Send the prompt for recompute instead.")
+        if self._sequence_state and (handoff or req.park_requested
+                                     or req._resume_header is not None):
+            raise ValueError(
+                "handoff, park and resume frames carry a sequence's KV block table; this model "
+                "keeps a per-sequence state group (a recurrent state in a slot, in no block "
+                "table) that a frame would leave behind. Send the prompt for recompute instead.")
         req.handoff_requested = bool(handoff)
         if self._ledger is not None:
             # every admitted request carries a RequestCost from birth (the
@@ -1908,6 +1928,10 @@ class ServingScheduler:
                     self._metrics.prefix_evictions.inc(freed)
                     self._metrics.prefix_trie_blocks.set(self._prefix_cache.n_blocks)
                 return True
+        if self._sequence_state:
+            # offload would move the coldest sequence's blocks and leave its
+            # slot's state behind: the request waits for a sequence to finish
+            return False
         engine = self._engine
         candidates = []
         for req in self._active.values():

@@ -560,3 +560,105 @@ def test_deepseek_decode_loop_program_fits_one_chip(v5e, deepseek_model):
     assert "grouped_matmul" in text
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert not _latent_pool_copies(text)
+
+
+# ---- nemotron3-nano-serve-1chip: a per-sequence state group beside K/V (PR 43) ----
+NEMOTRON_SLOTS, NEMOTRON_BLOCKS, NEMOTRON_BLOCK = 128, 1536, 128
+
+
+@pytest.fixture(scope="module")
+def nemotron_model():
+    """``nemotron3-nano-serve-1chip``: Nemotron-3-Nano-30B-A3B's published
+    widths, the pattern's first 14 blocks (6 Mamba-2, 6 expert, 2 attention), 64
+    of the 128 routed experts held, half the vocabulary, contexts to 4096, over
+    ``jax.eval_shape``d parameters."""
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.model_implementations.registry import model_cls_for
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import DSStateManagerConfig
+    from deepspeed_tpu.models import nemotron_h
+    cfg = nemotron_h.NemotronHConfig(num_hidden_layers=14,
+                                     hybrid_override_pattern="MEMEM*EMEMEM*E",
+                                     vocab_size=65536, experts_held=64, expert_rank=0)
+    abstract = jax.eval_shape(lambda: nemotron_h.init_params(cfg, param_dtype=cfg.dtype)[1])
+    engine_config = RaggedInferenceEngineConfig(
+        state_manager=DSStateManagerConfig(max_context=4096, max_ragged_batch_size=256,
+                                           max_ragged_sequence_count=8,
+                                           max_tracked_sequences=NEMOTRON_SLOTS),
+        kv_block_size=NEMOTRON_BLOCK, use_paged_kernel=True,
+        expert_parallel={"capacity_factor": 22.0})
+    model = model_cls_for(cfg)(abstract, cfg, engine_config)
+    assert model.num_kv_layers == 2 and model.min_table_bucket == 32
+    assert [(s.name, s.layers, s.shape, s.dtype) for s in model.sequence_state] == [
+        ("ssm", 6, (64, 64, 128), "float32"), ("conv", 6, (3, 6144), "bfloat16")]
+    return model, abstract
+
+
+def _nemotron_args(device, model, abstract, bucket):
+    one = SingleDeviceSharding(device)
+    tokens, seqs, max_blocks = bucket
+    params = jax.tree.map(lambda leaf: _on(one, leaf.shape, leaf.dtype), abstract)
+    cache = (_on(one, (2, 2, NEMOTRON_BLOCKS, 2, NEMOTRON_BLOCK, 128), jnp.bfloat16),
+             _on(one, (6, NEMOTRON_SLOTS, 64, 64, 128), jnp.float32),
+             _on(one, (6, NEMOTRON_SLOTS, 3, 6144), jnp.bfloat16))
+    batch = {"tok_meta": _on(one, (4, tokens), jnp.int32),
+             "seq_meta": _on(one, (seqs, 4 + max_blocks + 1), jnp.int32)}
+    return one, params, cache, batch
+
+
+def _state_sized_results(text, rows):
+    """Instructions whose result holds a Mamba-2 state a ROW of the batch
+    (``rows`` x 64 x 64 x 128 float32 or more) and is no pool: the two forms
+    keep a state a sequence, never a state a token."""
+    import re
+    per_row = 64 * 64 * 128
+    out = []
+    for line in text.splitlines():
+        m = re.search(r"= f32\[([\d,]+)\]", line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",")]
+        if dims[:2] == [6, NEMOTRON_SLOTS]:
+            continue  # the pool itself, updated in place
+        if int(np.prod(dims)) >= rows * per_row:
+            out.append(line.strip()[:160])
+    return out
+
+
+@pytest.mark.parametrize("bucket,kernel,scope", [
+    ((8, 8, 32), "paged_attention_update", "ssm/scan"),
+    ((256, 8, 32), "paged_attention_prefill", "ssm/scan")],
+    ids=["decode-bucket", "chunk-bucket"])
+def test_nemotron_put_program_fits_one_chip(v5e, nemotron_model, bucket, kernel, scope):
+    """8.6 GiB of weights beside the K/V array and the two state pools (1.5 GiB
+    of float32 state in 128 slots): both grids of the paged kernel at 32 query
+    heads over 2 K/V heads, the grouped matmul over the 64 held banks at the
+    banks' 1920 lanes, the chunked scan at the published widths with the state
+    a SEQUENCE (nothing 9 x a state, let alone 256)."""
+    model, abstract = nemotron_model
+    assert model.moe_path(bucket[0]) == "grouped"
+    _, params, cache, batch = _nemotron_args(v5e[0], model, abstract, bucket)
+    compiled = jax.jit(model._forward_impl, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and kernel in text and "grouped_matmul" in text
+    assert scope in text and "ssm/step" not in text
+    assert _device_bytes(compiled) < 0.8 * HBM_BYTES
+    assert not _state_sized_results(text, rows=9)
+    out = jax.eval_shape(model._forward_impl, params, cache, batch)
+    assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
+
+
+def test_nemotron_decode_loop_program_fits_one_chip(v5e, nemotron_model):
+    """The recurrence inside ``decode_loop``'s scan: the pools ride in the
+    carry and come out in the shapes and dtypes they went in."""
+    model, abstract = nemotron_model
+    one, params, cache, batch = _nemotron_args(v5e[0], model, abstract, (8, 8, 32))
+    loop = functools.partial(model._decode_loop_impl, n_steps=8, sampled=False)
+    args = (params, cache, batch, _on(one, (), jnp.float32), _on(one, (2, ), jnp.uint32))
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(*args).compile()
+    text = compiled.as_text()
+    assert "paged_attention_update" in text and "grouped_matmul" in text
+    assert "ssm/step" in text and "ssm/scan" not in text
+    assert _device_bytes(compiled) < 0.8 * HBM_BYTES
+    assert not _state_sized_results(text, rows=9)
+    out = jax.eval_shape(loop, *args)
+    assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
