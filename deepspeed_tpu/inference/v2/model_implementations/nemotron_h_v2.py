@@ -15,8 +15,12 @@ the blocks of its kind. What the architecture asks of the engine:
   point one past the last slot and their writes drop;
 - **two forms of the scan** (``modules/ssm.py``): a ``put`` step runs the
   chunked form over the ragged batch, each sequence's segment starting from
-  its slot and leaving its final state there; a ``decode_loop`` step
-  (``one_token_rows``) runs the recurrence, one token a sequence;
+  its slot's state, gathered, and leaving its final state there, scattered; a
+  ``decode_loop`` step (``one_token_rows``) runs the recurrence, one token a
+  sequence, IN the pool: one kernel a block reads a row's slot, updates it
+  and writes it back (``ops/pallas/ssm_step.py``), so no ``[rows, H, P, N]``
+  exists in that program. A pool off the kernel's shape rule
+  (``ssm.in_place``) runs ``ssm.step`` between a gather and a scatter;
 - **the K/V array holds the attention blocks only** (``num_kv_layers``), at
   ``num_key_value_heads`` heads; no rotary embedding;
 - **one chip's share of the experts**: ``RaggedMoE`` told ``held`` /
@@ -157,12 +161,17 @@ class NemotronHV2Model(DSTransformerModelBase):
             counts["moe_banks"] = steps * sum(m.experts_here for m in self._moes)
         return counts
 
-    def batch_counts(self, ragged_batch, steps=1):
+    def batch_counts(self, ragged_batch, steps=None):
         """Beside the attention kernels' passes: ``ssm_tokens``, rows that went
         through a Mamba-2 block (live tokens x such blocks, over the ``steps``
         of a chunk); ``ssm_segments``, sequence segments scanned (a segment a
         live sequence a block a step); ``ssm_slots_live`` / ``ssm_slots_total``,
-        the per-sequence state group's slots held as the step is dispatched."""
+        the per-sequence state group's slots held as the step is dispatched;
+        on a ``decode_loop`` chunk (the engine gives its ``steps``; a ``put``
+        gives none) ``ssm_rows_in_place``, those of ``ssm_tokens`` whose state
+        the kernel updated in its slot: all of them, or 0 where the pool is off
+        its shape rule."""
+        chunk, steps = steps is not None, steps or 1
         counts = super().batch_counts(ragged_batch, steps)
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
         blocks = len(self._config.layers_of(MAMBA))
@@ -171,6 +180,9 @@ class NemotronHV2Model(DSTransformerModelBase):
                       ssm_segments=steps * int(batch["n_seqs"]) * blocks,
                       ssm_slots_live=kv.num_slots - (kv.free_slots or 0),
                       ssm_slots_total=kv.num_slots)
+        if chunk:
+            in_place = ssm.in_place(kv.cache[1], self._config.n_groups)
+            counts["ssm_rows_in_place"] = counts["ssm_tokens"] if in_place else 0
         return counts
 
     # --------------------------------------------------------------- phases --
@@ -183,6 +195,18 @@ class NemotronHV2Model(DSTransformerModelBase):
         r = _root(params)
         x = _rms(x, r["norm_f"]["weight"], self._config.layer_norm_epsilon)
         return x @ r["lm_head"]["kernel"].astype(x.dtype)
+
+    def _step_in_place(self, pool, mi, *rows):
+        """``ssm.step_in_place`` on block ``mi`` of the pool. The SPMD
+        partitioner cannot split a Mosaic kernel: on a mesh every device runs
+        it over the pool it holds whole (``kv_cache._pool_sharding``), as
+        ``_paged_attention`` runs its kernel."""
+        placed = None if self._state_manager is None else self._state_manager.kv_cache.sharding
+        if placed is None or placed.mesh.size == 1 or not ssm.in_place(pool, rows[-1].shape[1]):
+            return ssm.step_in_place(pool, mi, *rows)
+        from jax.sharding import PartitionSpec as P
+        return jax.shard_map(ssm.step_in_place, mesh=placed.mesh, in_specs=P(), out_specs=P(),
+                             check_vma=False)(pool, jnp.int32(mi), *rows)
 
     @jax.named_scope("ssm")
     def _mamba_phase(self, mp, mi, h, pools, batch):
@@ -226,13 +250,14 @@ class NemotronHV2Model(DSTransformerModelBase):
             x, B, C = jnp.split(xbc, [D, D + G * N], axis=-1)
             x, B, C = x.reshape(T, H, P), B.reshape(T, G, N), C.reshape(T, G, N)
         with jax.named_scope("step" if one_token else "scan"):
-            state = jnp.where(started[:, None, None, None], ssm_pool[mi, read], 0.0)
             if one_token:
-                y, state = ssm.step(x, dt, A, B, C, state)
+                y, ssm_pool = self._step_in_place(ssm_pool, mi, slot, batch["token_valid"],
+                                                  started, x, dt, A, B, C)
             else:
+                state = jnp.where(started[:, None, None, None], ssm_pool[mi, read], 0.0)
                 onehot = ssm.segments(batch["token_seq"], batch["token_valid"], slot.shape[0])
                 y, state = ssm.scan_ragged(x, dt, A, B, C, state, onehot, cfg.chunk_size)
-            ssm_pool = ssm_pool.at[mi, write].set(state.astype(ssm_pool.dtype), mode="drop")
+                ssm_pool = ssm_pool.at[mi, write].set(state.astype(ssm_pool.dtype), mode="drop")
             y = y + mp["D"].astype(jnp.float32)[None, :, None] * x.astype(jnp.float32)
         with jax.named_scope("gate_norm"):
             y = ssm.gated_norm(y.reshape(T, D), z, mp["norm"]["weight"], G,

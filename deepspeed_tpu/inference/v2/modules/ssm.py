@@ -1,6 +1,10 @@
 """The Mamba-2 mixer's state-space part over a ragged batch, in two forms that
 agree (tier-1 holds them against each other and against the token-by-token
-reference, ``tests/unit/inference/v2/test_nemotron_h.py``).
+reference, ``tests/unit/inference/v2/test_nemotron_h.py``). Which runs where:
+a ``put`` step runs :func:`scan_ragged` on its sequences' gathered states; a
+``decode_loop`` step runs :func:`step_in_place`, the recurrence inside the
+engine's pool; :func:`step` is the recurrence as written, the reference the
+other two are held to and what a shape off the kernel's rule falls back to.
 
 A head's state is ``h_t = exp(dt_t a) h_{t-1} + dt_t x_t (x) B_t`` in
 ``R^{P x N}``, float32, and ``y_t = h_t C_t``; heads read B and C by group
@@ -26,8 +30,12 @@ state, and the noise it puts on every later row's hidden state is enough to
 flip a router's choice at gaps the comparison with the float32 reference holds
 to its tight tolerance (read on the chip: PERF.md section 6, PR 43).
 
-:func:`step`, a ``decode_loop`` step: one token a sequence, the recurrence as
-written above.
+:func:`step`: one token a sequence, the recurrence as written above, on states
+handed in and handed back. :func:`step_in_place`, a ``decode_loop`` step: the
+same over the engine's pool ``[blocks, slots, H, P, N]``, a row's state read
+from its slot and left there — one Pallas kernel
+(``ops/pallas/ssm_step.py``) where :func:`in_place` says the shapes allow it,
+:func:`step` between a gather and a scatter where not.
 
 :func:`conv_ragged` / :func:`conv_step`: the causal depthwise convolution in
 front of the scan, a token seeing the ``K - 1`` rows before it of ITS OWN
@@ -36,6 +44,8 @@ sequence, those ahead of a segment's first row from the sequence's kept tail.
 
 import jax
 import jax.numpy as jnp
+
+from deepspeed_tpu.ops.pallas import ssm_step
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 _HIGH = jax.lax.Precision.HIGH
@@ -155,6 +165,28 @@ def step(x, dt, A, B, C, h):
         + x[..., None] * B.astype(f32)[:, :, None, None, :]
     y = (h * C.astype(f32)[:, :, None, None, :]).sum(-1)
     return y.reshape(S, H, P), h.reshape(S, H, P, N)
+
+
+def in_place(pool, groups: int) -> bool:
+    """Whether :func:`step_in_place` runs the kernel on this pool ``[blocks,
+    slots, H, P, N]``: by its type alone, the same answer on every backend."""
+    return pool.dtype == jnp.float32 and ssm_step.supported(*pool.shape[2:], groups)
+
+
+def step_in_place(pool, block, slot, live, started, x, dt, A, B, C):
+    """:func:`step` over the pool's block ``block``: row t's state is slot
+    ``slot[t]``'s (zeros where ``started[t]`` is false, whatever the slot
+    held) and is left there where ``live[t]``; a row that is not live writes
+    nothing. Live rows hold distinct slots. Returns ``(y [T, H, P], pool)``; a
+    dead row's ``y`` is nobody's."""
+    if in_place(pool, B.shape[1]):
+        return ssm_step.ssm_step_in_place(pool, block, slot, live, started, x, dt, A, B, C)
+    n_slots = pool.shape[1]
+    state = jnp.where(started[:, None, None, None],
+                      pool[block, jnp.minimum(slot, n_slots - 1)], 0.0)
+    y, state = step(x, dt, A, B, C, state)
+    return y, pool.at[block, jnp.where(live, slot, n_slots)].set(state.astype(pool.dtype),
+                                                                 mode="drop")
 
 
 def gated_norm(y, z, weight, groups: int, eps: float):

@@ -3,7 +3,11 @@ Mamba-2 scan against each other and the token-by-token reference; prefill in
 uneven chunks, ``put`` and ``decode_loop`` through the per-sequence state group
 against the plain float32 reference's full forward; continuous batching; the
 slots (reuse, padding, admission); the shares of an expert layer adding up to
-the uncut layer; and each refusal by its message."""
+the uncut layer; and each refusal by its message. Since PR 44 a ``decode_loop``
+step's recurrence runs in the pool where the pool is on the kernel's shape rule
+(``ssm.in_place``): ``model`` (state 16 wide) keeps covering the fallback,
+``model_in_place`` (state 128 wide; the kernel in interpret mode) runs three of
+the engine's tests beside it and says so in a chunk's counts."""
 
 import dataclasses
 
@@ -54,6 +58,18 @@ def engine_of(cfg, params, kernel=False, blocks=96, slots=6, **overrides):
 def model():
     cfg = nh.NemotronHConfig.tiny(dtype=jnp.float32, experts_held=4, expert_rank=1)
     return cfg, nh.init_params(cfg, rng=jax.random.PRNGKey(3))[1]
+
+
+@pytest.fixture(scope="module")
+def model_in_place():
+    """As ``model`` with a state of one lane tile: the pool is on the rule."""
+    cfg = nh.NemotronHConfig.tiny(dtype=jnp.float32, experts_held=4, expert_rank=1,
+                                  ssm_state_size=128)
+    return cfg, nh.init_params(cfg, rng=jax.random.PRNGKey(3))[1]
+
+
+BOTH_POOLS = pytest.mark.parametrize("which", ["model", "model_in_place"],
+                                     ids=["fallback-state-16", "in-place-state-128"])
 
 
 def _ids(seed, n):
@@ -156,16 +172,22 @@ def test_one_mixer_in_both_forms_is_the_references(model):
 
 
 # --------------------------------------------------------------- (b) engine --
-@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "pallas-interpret"])
-def test_prefill_in_uneven_chunks_then_decode_is_the_references_full_forward(model, kernel):
-    cfg, params = model
+@pytest.mark.parametrize("which, kernel", [("model", False), ("model", True),
+                                           ("model_in_place", False)],
+                         ids=["xla", "pallas-interpret", "xla-state-in-place"])
+def test_prefill_in_uneven_chunks_then_decode_is_the_references_full_forward(request, which,
+                                                                             kernel):
+    cfg, params = request.getfixturevalue(which)
     engine = engine_of(cfg, params, kernel)
+    assert ssm.in_place(engine._state_manager.kv_cache.cache[1], cfg.n_groups) \
+        == (which == "model_in_place")
     assert registry.model_cls_for(cfg) is type(engine.model)
     assert "nemotron_h" in registry.supported_model_types()
     assert engine.model.num_kv_layers == 1 and engine.model.min_table_bucket == 16
     kv, ssm_pool, conv_pool = engine._state_manager.kv_cache.cache
-    assert kv.shape[0] == 1 and ssm_pool.shape == (3, 6, 8, 8, 16) \
-        and ssm_pool.dtype == jnp.float32 and conv_pool.shape == (3, 6, 3, 128)
+    assert kv.shape[0] == 1 and ssm_pool.shape == (3, 6, 8, 8, cfg.ssm_state_size) \
+        and ssm_pool.dtype == jnp.float32 and conv_pool.shape == (3, 6, 3, cfg.conv_dim)
+    assert cfg.conv_dim == 64 + 4 * cfg.ssm_state_size
     prompt, feed = _ids(1, 75), _ids(2, 6)
     want = _want(cfg, params, prompt, feed)
     got, at = [], 0
@@ -187,8 +209,9 @@ def test_prefill_in_uneven_chunks_then_decode_is_the_references_full_forward(mod
 
 
 # ------------------------------------------------- (c) continuous batching --
-def test_one_prefilling_while_two_decode_each_equal_to_its_solo_run(model):
-    cfg, params = model
+@BOTH_POOLS
+def test_one_prefilling_while_two_decode_each_equal_to_its_solo_run(request, which):
+    cfg, params = request.getfixturevalue(which)
     prompts = [_ids(10, 9), _ids(11, 14), _ids(12, 70)]
     feeds = [_ids(20, 8), _ids(21, 8), _ids(22, 2)]
     want = [_want(cfg, params, p, f) for p, f in zip(prompts, feeds)]
@@ -219,8 +242,9 @@ def test_one_prefilling_while_two_decode_each_equal_to_its_solo_run(model):
 
 
 # ------------------------------------------------------------- (d) (e) slots --
-def test_a_slot_reused_after_flush_starts_from_zero_and_padding_writes_nothing(model):
-    cfg, params = model
+@BOTH_POOLS
+def test_a_slot_reused_after_flush_starts_from_zero_and_padding_writes_nothing(request, which):
+    cfg, params = request.getfixturevalue(which)
     engine = engine_of(cfg, params, slots=2)
     manager = engine._state_manager
     prompt, other = _ids(30, 40), _ids(31, 33)
@@ -230,6 +254,11 @@ def test_a_slot_reused_after_flush_starts_from_zero_and_padding_writes_nothing(m
     assert np.abs(pools[0][:, slot]).max() > 0
     # rows of the bucket beyond the one live sequence, and the 24 padding tokens, wrote nothing
     assert not np.delete(pools[0], slot, axis=1).any() and not np.delete(pools[1], slot, axis=1).any()
+    # nor do the seven padding rows of a decode_loop chunk's steps, which move the one slot
+    engine.decode_loop([7], [_ids(32, 1)], 3)
+    after = [np.asarray(p) for p in manager.kv_cache.cache[1:]]
+    assert np.abs(after[0][:, slot] - pools[0][:, slot]).max() > 0
+    assert not np.delete(after[0], slot, axis=1).any() and not np.delete(after[1], slot, axis=1).any()
     engine.flush(7)
     assert manager.free_slots == 2 and manager.get_sequence(7) is None
     engine.put([8], [other])  # takes the slot 7 held, its old state still in it
@@ -238,6 +267,24 @@ def test_a_slot_reused_after_flush_starts_from_zero_and_padding_writes_nothing(m
     again = np.asarray(engine.put([9], [prompt]))
     assert manager.get_sequence(9).state_slot == slot
     assert np.abs(again - first).max() < 1e-6
+
+
+@pytest.mark.parametrize("which, share", [("model", 0), ("model_in_place", 1)],
+                         ids=["fallback-state-16", "in-place-state-128"])
+def test_a_chunks_counts_say_which_rows_the_kernel_served(request, which, share):
+    """``ssm_rows_in_place`` beside ``ssm_tokens`` on a ``decode_loop`` chunk's
+    counts: every row where the pool is on the kernel's rule, 0 where it falls
+    back; a ``put``'s counts do not have the key."""
+    cfg, params = request.getfixturevalue(which)
+    engine = engine_of(cfg, params)
+    engine.put([0, 1], [_ids(40, 9), _ids(41, 5)])
+    put = engine.model.batch_counts(engine._batch)
+    assert put["ssm_tokens"] == 14 * 3 and "ssm_rows_in_place" not in put
+    engine.decode_loop([0, 1], [_ids(42, 1), _ids(43, 1)], 4)
+    chunk = engine.model.batch_counts(engine._batch, 4)
+    assert chunk["ssm_tokens"] == 2 * 3 * 4 and chunk["ssm_segments"] == 2 * 3 * 4
+    assert chunk["ssm_rows_in_place"] == share * chunk["ssm_tokens"]
+    assert engine.model.batch_counts(engine._batch, 1)["ssm_rows_in_place"] == share * 2 * 3
 
 
 def test_admission_stops_at_the_last_free_slot(model):

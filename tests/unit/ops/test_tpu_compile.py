@@ -649,7 +649,9 @@ def test_nemotron_put_program_fits_one_chip(v5e, nemotron_model, bucket, kernel,
 
 def test_nemotron_decode_loop_program_fits_one_chip(v5e, nemotron_model):
     """The recurrence inside ``decode_loop``'s scan: the pools ride in the
-    carry and come out in the shapes and dtypes they went in."""
+    carry and come out in the shapes and dtypes they went in. Since PR 44 a
+    block's recurrence is ONE kernel over the pool itself, under ``ssm/step``:
+    no row's state exists outside the pool (the gather was 8 of them)."""
     model, abstract = nemotron_model
     one, params, cache, batch = _nemotron_args(v5e[0], model, abstract, (8, 8, 32))
     loop = functools.partial(model._decode_loop_impl, n_steps=8, sampled=False)
@@ -658,7 +660,10 @@ def test_nemotron_decode_loop_program_fits_one_chip(v5e, nemotron_model):
     text = compiled.as_text()
     assert "paged_attention_update" in text and "grouped_matmul" in text
     assert "ssm/step" in text and "ssm/scan" not in text
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "ssm_step_in_place" in line]
+    assert len(kernels) == 6 and all("ssm/step" in line for line in kernels), kernels
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
-    assert not _state_sized_results(text, rows=9)
+    assert not _state_sized_results(text, rows=8)
     out = jax.eval_shape(loop, *args)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
