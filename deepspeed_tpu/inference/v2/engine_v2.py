@@ -137,6 +137,11 @@ class InferenceEngineV2:
         # routed its tokens to experts (``grouped`` / ``capacity``; None for a
         # dense model, or before any)
         self.last_moe_path = None
+        # the program the newest ``put`` step or ``decode_loop`` chunk was
+        # compiled for: the chunk's steps (0: a ``put`` step) and the padded
+        # shapes of the batch it took, ``(steps, T, S, seq_meta columns)`` —
+        # what a caller that times steps keys them by (None before any)
+        self.last_step_key = None
         # under a telemetry session, what the fetch of the newest ``put`` step
         # reports of its experts where its bucket routes by sorting:
         # ``moe_path``, ``moe_assignments`` and ``moe_banks``, the last the
@@ -396,6 +401,7 @@ class InferenceEngineV2:
         # how the bucket's program routes its tokens to experts (grouped /
         # capacity; None for a dense model): the scheduler counts steps by it
         self.last_moe_path = self._model.moe_path(n_padded)
+        self.last_step_key = self._step_key(0)
         self.last_moe_fetch = None
         args = self._dispatch_args(spans, batch_uids, tokens=n_tokens)
         if args is not None:
@@ -430,6 +436,11 @@ class InferenceEngineV2:
         if metrics is not None:
             self._write_telemetry(metrics, batch_tokens=n_tokens)
         return out
+
+    def _step_key(self, loop_steps: int) -> tuple:
+        """:attr:`last_step_key` of the batch just finalized."""
+        batch = self._batch.device_batch
+        return (loop_steps, batch["tok_meta"].shape[1], *batch["seq_meta"].shape)
 
     @staticmethod
     def _prev_by_slot(prev, batch_tokens, n_padded, args):
@@ -596,6 +607,7 @@ class InferenceEngineV2:
 
         n_padded = self._batch.device_batch["tok_meta"].shape[1]
         self.last_moe_path = self._model.moe_path(n_padded)
+        self.last_step_key = self._step_key(n_steps)
         args = self._dispatch_args(spans, batch_uids, steps=n_steps)
         if args is not None:
             # a sparse model's moe_path, and the chunk's moe_rows and

@@ -123,6 +123,21 @@ def compiled_chain(tokens: int, rows: int):
     return _executable(("chain", tokens, rows), "inference_chain", (tokens, rows), build)
 
 
+def _on_default_device(array):
+    """``array`` as the default device holds it whole — itself, or that
+    device's replica of an array replicated over a mesh — else None (split
+    over a mesh, or on another chip)."""
+    import jax
+    default = jax.local_devices()[0]
+    if array.sharding.device_set == {default}:
+        return array
+    if array.is_fully_replicated:
+        for shard in array.addressable_shards:
+            if shard.device == default:
+                return shard.data
+    return None
+
+
 def chain(tok_meta: np.ndarray, ids, src: np.ndarray):
     """``tok_meta`` (host) with the slots ``src`` names fed from the device
     ids ``ids`` of the step before (:func:`chain_ids`), as a device array:
@@ -130,12 +145,8 @@ def chain(tok_meta: np.ndarray, ids, src: np.ndarray):
     one that draws ``ids`` still runs. Ids that do not lie whole on the
     default device (split over a mesh, or on another chip) are fetched and
     merged from the host instead: the same batch, one step later."""
-    import jax
-    default = jax.local_devices()[0]
-    if ids.sharding.device_set != {default}:
-        local = [s.data for s in ids.addressable_shards
-                 if s.device == default] if ids.is_fully_replicated else []
-        ids = local[0] if local else np.asarray(ids)
+    local = _on_default_device(ids)
+    ids = np.asarray(ids) if local is None else local
     return compiled_chain(tok_meta.shape[1], ids.shape[0])(tok_meta, ids, src)
 
 
@@ -155,13 +166,12 @@ def compiled_last_row(steps: int, rows: int):
 
 def last_row(tokens):
     """``tokens[-1]`` of a chunk's device ``[steps, rows]`` tokens, as a device
-    array: nothing is fetched. Tokens that do not lie whole on the default
-    device are indexed where they lie (``chain`` then reads or fetches them
-    as it does a draw's ids)."""
-    import jax
-    if tokens.sharding.device_set != {jax.local_devices()[0]}:
-        return tokens[-1]
-    return compiled_last_row(*tokens.shape)(tokens)
+    array: nothing is fetched. Tokens replicated over a mesh are read from
+    the default device's replica, as ``chain`` reads a draw's ids (the
+    compiled program, built ahead of the first step); tokens split over it are
+    indexed where they lie (``chain`` then fetches them)."""
+    local = _on_default_device(tokens)
+    return tokens[-1] if local is None else compiled_last_row(*local.shape)(local)
 
 
 def _padded(values, rows: int, dtype) -> np.ndarray:

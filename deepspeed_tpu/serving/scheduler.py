@@ -60,6 +60,20 @@ from deepspeed_tpu.utils.logging import logger
 # permanent-infeasibility admission checks cannot see)
 _STARVATION_FAIL_TICKS = 5000
 
+# just-in-time commit of an open plan (step()): what the commit time leaves
+# between the dispatch's predicted return and the predicted end of the step in
+# flight. It covers what the prediction cannot see: a step's start is read off
+# the fetch of the step before it, which returns the fetch's latency after the
+# device was done (0.3-0.4 ms on a v5e, PERF.md §6 PR 45), and the last slice
+# of the wait oversleeps by ~0.1 ms. Too large and an arrival in the margin
+# waits one step more (0.5 ms = 4 % of chat's arrivals); too small and the
+# device idles for the shortfall at every step
+_COMMIT_MARGIN_S = 0.0005
+# observations kept: a program's step time is the LEAST of its last few
+# periods (a late commit lengthens a period and must not teach the predictor
+# that steps are long), the host's lead the LARGEST of the last few ticks'
+_COMMIT_OBSERVATIONS = 8
+
 # flight-recorder channel disambiguator for multiple schedulers per process
 _SCHEDULER_IDS = itertools.count()
 
@@ -86,16 +100,22 @@ class _Step:
     per plan entry what the result is to its request — ``"first"`` (the chunk
     that completed the prompt), ``"decode"``, or None (a mid-prompt chunk:
     meaningless). ``moe``: what the engine handed over for the span of a
-    ``put`` step's fetch (``engine.last_moe_fetch``), or None."""
+    ``put`` step's fetch (``engine.last_moe_fetch``), or None. For the commit
+    time of the plan after it (``ServingScheduler._commit_wait``): ``key``, the
+    program the engine compiled it for (``engine.last_step_key``), ``open``
+    (its plan left room for an arrival) and ``began``, when the device could
+    first have begun it, on the scheduler's clock."""
 
     __slots__ = ("plan", "result", "rows", "row_of", "phases", "t0_us", "tick", "moe",
-                 "loop_steps")
+                 "loop_steps", "key", "open", "began")
 
-    def __init__(self, plan, result, rows, phases, t0_us, tick, moe=None, loop_steps=0):
+    def __init__(self, plan, result, rows, phases, t0_us, tick, moe=None, loop_steps=0,
+                 key=None):
         self.plan, self.result, self.rows = plan, result, rows
         self.row_of = {req.uid: i for i, (req, _) in enumerate(plan)}
         self.phases, self.t0_us, self.tick, self.moe = phases, t0_us, tick, moe
-        self.loop_steps = loop_steps
+        self.loop_steps, self.key = loop_steps, key
+        self.open, self.began = False, 0.0
 
     @property
     def ids(self):
@@ -222,7 +242,7 @@ class ServingScheduler:
                            "put_steps", "pipelined_steps", "overrun_rows",
                            "moe_grouped_steps", "moe_capacity_steps",
                            "moe_grouped_chunks", "moe_capacity_chunks",
-                           "pipelined_chunks")
+                           "pipelined_chunks", "open_behind_steps", "late_commits")
                           + tuple(f"drained_steps_{r}" for r in _DRAIN_REASONS)}
         # the step on the device that no tick has fetched yet (step()),
         # why the newest fetched step was fetched before its successor was
@@ -231,6 +251,15 @@ class ServingScheduler:
         self._inflight: Optional[_Step] = None
         self._sync_reason = "open"
         self._behind_block: Optional[str] = None
+        # what the commit time of an open plan is made of (_commit_wait), all
+        # observed: a program's last step periods by ``engine.last_step_key``,
+        # the last ticks' spans from ``admit``'s start to the dispatch's
+        # return, when this tick's ``admit`` began and when the newest fetch
+        # returned (the scheduler's clock, seconds)
+        self._periods: Dict[tuple, deque] = {}
+        self._leads: deque = deque(maxlen=_COMMIT_OBSERVATIONS)
+        self._admit_began = 0.0
+        self._fetched_at = 0.0
         self._stopping = False   # no new submits
         self._shutdown = False   # thread exit
         self._stopped = False
@@ -990,34 +1019,54 @@ class ServingScheduler:
         when ``start=False``.
 
         A tick dispatches ONE engine step: a ``put`` step, a ``decode_loop``
-        chunk of K steps, or a verify step. The tick of a step whose plan
-        leaves room for an arrival — fewer tokens than the budget and fewer
-        sequences than the cap — and of every verify step, runs its phases in
-        this order: ``admit``, ``build_batch``, then in :meth:`_execute` the
-        engine's ``prepare`` and dispatch, ``fetch``, ``emit``. A ``put`` step
-        or a chunk whose plan is CLOSED to arrivals stays on the device when
-        its tick ends; the next tick builds its plan from what is counted (who
-        finishes by length — inside a chunk in flight too —, whose prompt is
-        fed) and, if that plan is closed too, dispatches it BEHIND the step in
-        flight — its decode rows take their input ids from the ids being
-        drawn, or from the last row of the chunk being run, on the device —
-        and only then fetches and emits the step before it: ``admit``,
-        ``build_batch``, ``prepare`` + dispatch (step i+1), ``fetch`` (step
-        i), ``emit`` (step i). The device goes from one step into the next
-        while the host emits. An arrival loses nothing: it had no room in
-        that plan whenever it was built. A tick that finds the step in flight
-        in the way — the plan it would put behind is open, drafts need token
-        values, the build would have to evict, a control call, ``stop`` —
-        fetches and emits it first (``drained_steps_<reason>``) and then runs
-        as the first kind; a request's tokens are the same either way (its
-        draws are keyed by a counted position, a chunk is greedy).
+        chunk of K steps, or a verify step. A verify step is fetched and
+        emitted in its own tick: ``admit``, ``build_batch``, then in
+        :meth:`_execute` the engine's ``prepare`` and dispatch, ``fetch``,
+        ``emit``. A ``put`` step or a chunk stays on the device when its tick
+        ends, and the next tick dispatches its plan BEHIND the step in flight
+        — its decode rows take their input ids from the ids being drawn, or
+        from the last row of the chunk being run, on the device — and only
+        then fetches and emits the step before it: ``admit``, ``build_batch``,
+        ``prepare`` + dispatch (step i+1), ``fetch`` (step i), ``emit`` (step
+        i). The plan is built from what is counted (who finishes by length —
+        inside a chunk in flight too —, whose prompt is fed). The device goes
+        from one step into the next while the host emits.
+
+        WHEN that tick commits its plan depends on the step in flight. A step
+        whose plan was CLOSED to arrivals (:meth:`_closed`: the whole token
+        budget or the whole sequence cap) is followed at once, and by a closed
+        plan only: no arrival had room in either, whenever they were built. A
+        step whose plan was OPEN is followed just in time
+        (:meth:`_commit_wait`): the tick waits (span ``commit_wait``) until the
+        step's predicted end less the host's own lead — what ``admit``,
+        ``build_batch``, ``prepare`` and the dispatch took in the last few
+        ticks — and a margin, and only then admits and builds, whatever the
+        plan. An arrival loses at most the host's lead, which it lost before:
+        one that landed while the host prepared a step has always waited for
+        the step after it; now the device runs the step before meanwhile. The
+        wait ends at once on ``stop``, ``kill``, a control call or a cancel.
+
+        A tick that finds the step in flight in the way fetches and emits it
+        first (``drained_steps_<reason>``) and then runs as a verify tick
+        does: drafts need token values (``verify``), the build would have to
+        evict (``pressure``), a control call, ``stop``; and ``open``: the plan
+        it would put behind a closed step is open, the plan is empty, or the
+        open step in flight is the first of its program in this process and
+        nothing is known of its duration (fetching it is the observation). A
+        request's tokens are the same either way (its draws are keyed by a
+        counted position, a chunk is greedy).
 
         With telemetry on, a tick that has work is one ``tick`` span (cat
         ``sched``) holding those phases as spans (the engine's are cat
         ``inference``); its args name the step it dispatched: ``seqs``,
         ``tokens``, ``kind`` (``put`` / ``decode_loop`` / ``verify_tree``),
         ``pipelined`` (1: dispatched before the step before it was fetched)
-        and, when 0, ``drain`` (why that step had been fetched first). Each
+        and, when 0, ``drain`` (why that step had been fetched first); for a
+        ``put`` step or a chunk also ``open`` (1: its plan left room for an
+        arrival), ``open_behind`` (1: open AND dispatched behind a step in
+        flight), ``lead_us`` (``admit``'s start to the dispatch's return) and
+        ``predicted_us`` (the duration the tick's commit time took for the
+        step in flight; 0: it did not wait). Each
         is also a ``dstpu.sched.*`` annotation on this thread's line of a
         jax.profiler trace. An idle poll (nothing queued, nothing active)
         records nothing; :meth:`_run` covers it with ``no_work``."""
@@ -1042,9 +1091,12 @@ class ServingScheduler:
     def _step_phases(self, spans) -> bool:
         fetched = False
         if self._inflight is not None:
-            # a closed step is on the device, unfetched: its successor goes
-            # behind it unless something needs its values, or an idle engine
-            reason = "control" if self._control else "stop" if self._stopping else None
+            # a step is on the device, unfetched: its successor goes behind it
+            # (an open step's at its commit time) unless something needs its
+            # values, or an idle engine
+            reason = self._interrupt()
+            if reason is None and self._inflight.open:
+                reason = self._commit_wait(spans)
             if reason is None:
                 self._behind_block = None
                 self._admit_phase(spans, control=False)
@@ -1072,6 +1124,52 @@ class ServingScheduler:
         self._run_plan(plan)
         return True
 
+    def _interrupt(self) -> Optional[str]:
+        """What needs an idle engine now, whatever is in flight."""
+        return "control" if self._control else "stop" if self._stopping else None
+
+    def _predicted_s(self, key) -> Optional[float]:
+        """How long a step of program ``key`` takes: the least of its last
+        few observed periods; None before the first."""
+        periods = self._periods.get(key)
+        return min(periods) if periods else None
+
+    def _commit_wait(self, spans) -> Optional[str]:
+        """Hold the tick until the commit time of the OPEN step in flight:
+
+            began + duration(its program) - lead - margin
+
+        all observed (``_Step.began``, :meth:`_predicted_s`, the largest of the
+        last few ticks' leads, ``_COMMIT_MARGIN_S``), so that this tick's
+        dispatch returns as the device ends that step and an arrival until
+        then is in the plan. Returns why the step must be fetched first after
+        all: ``open`` (no step of its program has been observed), or what
+        ended the wait (``control``, ``stop``); None to go on — at the commit
+        time, at once when it has passed (``late_commits``), or early on a
+        cancel, which ``admit`` acts on. The wait is slices of at most
+        ``scheduler_tick_s``, each after a look at what those calls set."""
+        step = self._inflight
+        predicted = self._predicted_s(step.key)
+        if predicted is None:
+            return "open"
+        commit = step.began + predicted - max(self._leads, default=0.0) - _COMMIT_MARGIN_S
+        if self._tick is not None:
+            self._tick["predicted_us"] = int(predicted * 1e6)
+        left = commit - self._now()
+        if left <= 0:
+            self._counters["late_commits"] += 1
+            return None
+        with live_span(spans, "commit_wait", "sched"):
+            # ``kill`` sets what ``stop`` sets
+            while (left > 0 and self._interrupt() is None
+                   and not any(req.cancel_requested for req in self._active.values())):
+                self._pause(min(left, self._config.scheduler_tick_s))
+                left = commit - self._now()
+        return self._interrupt()
+
+    _now = staticmethod(time.perf_counter)   # the scheduler's clock, seconds
+    _pause = staticmethod(time.sleep)
+
     def _run_plan(self, plan) -> None:
         self._starved_ticks = 0
         self._execute(plan)
@@ -1081,6 +1179,7 @@ class ServingScheduler:
         # args are filled in when known: what is there at entry rides on the
         # profiler annotation, and a placeholder would read as a value there
         args = None if spans is None else {}
+        self._admit_began = self._now()
         with live_span(spans, "admit", "sched", args):
             if control:
                 # control calls read sequence state: never beside a step in flight
@@ -1112,15 +1211,22 @@ class ServingScheduler:
     def _closed(self, plan) -> bool:
         """No arrival could have joined ``plan``: it uses the whole token
         budget or the whole sequence cap, so :meth:`_build_batch` had no room
-        for a newcomer whenever it ran."""
+        for a newcomer whenever it ran. The step after a closed one is
+        committed at once; an open plan's step is followed at its commit time
+        (:meth:`_commit_wait`)."""
         sm = self._engine._config.state_manager
         return (len(plan) >= sm.max_ragged_sequence_count
                 or sum(int(toks.size) for _, toks in plan) >= sm.max_ragged_batch_size)
 
     def _drain_reason(self, plan) -> Optional[str]:
         """Why ``plan`` must wait for the step in flight to be fetched; None
-        when it can be dispatched behind it."""
-        return None if self._closed(plan) else "open"
+        when it can be dispatched behind it. Behind an open step, after its
+        commit time, any plan can; behind a closed one, committed at once, an
+        open plan would shut out every arrival of that step's run time. An
+        empty plan leaves nothing to do but fetch."""
+        if plan and (self._inflight.open or self._closed(plan)):
+            return None
+        return "open"
 
     def _evicted_total(self) -> int:
         c = self._counters
@@ -1958,11 +2064,13 @@ class ServingScheduler:
         everything that needs only counts happens there); then the step
         before it, if it is still in flight, is fetched and emitted UNDER it
         (:meth:`_complete`: everything that needs token values); and it stays
-        in flight itself for the next tick to do the same iff its plan is
-        closed to arrivals (:meth:`_closed`) — otherwise it is fetched and
-        emitted now, and the next tick builds its plan after every arrival
-        this step's run time brought. A chunk the KV pool has no room for
-        (``SchedulingError``: K steps a member) runs as a ``put`` step."""
+        in flight itself for the next tick to do the same, whether its plan is
+        closed to arrivals or open: the next tick commits its plan at once
+        behind a closed one and at this step's commit time behind an open one
+        (:meth:`_commit_wait`), so the plan after an open step is still built
+        after every arrival this step's run time brought, less the host's
+        lead. A chunk the KV pool has no room for (``SchedulingError``: K
+        steps a member) runs as a ``put`` step."""
         now = time.monotonic()
         for req, _ in plan:
             req._last_touch_s = now
@@ -2000,22 +2108,31 @@ class ServingScheduler:
                 return
             self._counters["put_steps"] += 1
             self._count_moe_path("steps")
+        dispatched = self._now()
+        lead = dispatched - self._admit_began
+        self._leads.append(lead)
         prev, self._inflight = self._inflight, step
-        if step.loop_steps and prev is not None:
-            self._counters["pipelined_chunks"] += 1
+        step.open = not self._closed(plan)
+        if prev is not None:
+            self._counters["pipelined_chunks"] += bool(step.loop_steps)
+            self._counters["open_behind_steps"] += step.open
         if tick is not None:
-            tick["pipelined"] = int(prev is not None)
+            tick.update(pipelined=int(prev is not None), open=int(step.open),
+                        open_behind=int(step.open and prev is not None),
+                        lead_us=int(lead * 1e6))
+            tick.setdefault("predicted_us", 0)
             if step.loop_steps:
                 tick["kind"] = "decode_loop"
             if prev is None:
                 tick["drain"] = self._sync_reason
+        # when the device could first have begun this step: when the dispatch
+        # returned, or when it finished the step before — read off that step's
+        # fetch. Its phase spans start there (they do not overlap)
+        step.began = dispatched
         if prev is not None:
-            # the device could first have begun this step when it finished the
-            # one before: its phase spans start there (they do not overlap)
             step.t0_us = self._complete(prev, None)
-        if not self._closed(plan):
-            self._sync("open")
-        elif self._stopping:
+            step.began = self._fetched_at
+        if self._stopping:
             self._sync("stop")
 
     def _count_moe_path(self, unit: str) -> None:
@@ -2121,7 +2238,8 @@ class ServingScheduler:
                 req._pending += 1
             rows.append(row)
         return _Step(plan, ids, rows, phases, t0_us, tick_no,
-                     getattr(self._engine, "last_moe_fetch", None))
+                     getattr(self._engine, "last_moe_fetch", None),
+                     key=getattr(self._engine, "last_step_key", None))
 
     def _dispatch_chunk(self, plan, K, phases, t0_us=0, tick_no=None) -> Optional[_Step]:
         """``plan`` — decode rows only — through
@@ -2143,7 +2261,8 @@ class ServingScheduler:
         for req in reqs:
             req.decode_steps += 1
             req._pending += K
-        return _Step(plan, chunk, ["decode"] * len(plan), phases, t0_us, tick_no, loop_steps=K)
+        return _Step(plan, chunk, ["decode"] * len(plan), phases, t0_us, tick_no, loop_steps=K,
+                     key=getattr(self._engine, "last_step_key", None))
 
     def _complete(self, step: _Step, reason: Optional[str]) -> int:
         """Fetch ``step``'s result and emit it: everything that needs token
@@ -2151,7 +2270,10 @@ class ServingScheduler:
         phase spans. ``reason`` is why this happens before the step after it
         is dispatched (``drained_steps_<reason>``), or None when that step is
         on the device already (``pipelined_steps``). Returns when the fetch
-        returned, on the span clock."""
+        returned, on the span clock; on the scheduler's that is
+        ``_fetched_at``, and the time since the step began is one observation
+        of its program's period (:meth:`_predicted_s`): fetch to fetch behind
+        another step, the fetch's latency cancelled."""
         self._count_fetched(reason)
         spans = self._tick_spans
         try:
@@ -2160,8 +2282,12 @@ class ServingScheduler:
             logger.exception("serving: fetching a step's result failed; failing the batch")
             for req, _ in step.plan:
                 self._finalize(req, RequestState.FAILED, error=f"engine error: {e}")
+            self._fetched_at = self._now()
             return now_us()
         fetched_us = now_us()
+        self._fetched_at = self._now()
+        self._periods.setdefault(step.key, deque(maxlen=_COMMIT_OBSERVATIONS)).append(
+            self._fetched_at - step.began)
         with self._emit_phase(spans):
             if step.loop_steps:
                 self._emit_chunk(step, out, fetched_us)

@@ -233,11 +233,14 @@ def test_put_path_fetches_ids_builds_nothing_and_counts_its_draws(make_engine, l
     emits = [s for s in spans if s["cat"] == "sched" and s["name"] == "emit"]
     put_ticks = [t for t in ticks if t["args"]["kind"] == "put"]
     assert put_ticks and any(t["args"]["kind"] == "decode_loop" for t in ticks)
-    for tick in put_ticks:
-        inside = [f for f in fetches if tick["ts_us"] <= f["ts_us"]
-                  and f["ts_us"] + f["dur_us"] <= tick["ts_us"] + tick["dur_us"]]
-        assert len(inside) == 1
-        assert inside[0]["args"]["bytes"] == 4 * 8  # at most five sequences: the bucket of 8
+    # a step is fetched in the tick after the one that dispatched it, one at a
+    # time and in order: the i-th fetch is the i-th step's
+    steps = [t["args"]["kind"] for t in ticks if t["args"]["kind"] != "none"]
+    fetches.sort(key=lambda f: f["ts_us"])
+    assert len(fetches) == len(steps)
+    for kind, fetch in zip(steps, fetches):
+        if kind == "put":
+            assert fetch["args"]["bytes"] == 4 * 8  # at most five sequences: the bucket of 8
 
     # the engine's forward programs are engine.put's: (T, S, MB) buckets only
     programs = engine.lowerable_callables()

@@ -276,7 +276,9 @@ def _flood_config(fair_share_on):
     # FIFO admission models the realistic arrival order (same priority
     # class); admission control off so the flood actually queues — the
     # policy under test is fair-share, not deadline feasibility
-    return _fs_config(queue_capacity=256,
+    # room for the largest flood the gate sizes (400) and the good requests
+    # behind it: a faster scheduler measures a higher rate and sizes more
+    return _fs_config(queue_capacity=512,
                       fair_share_enabled=fair_share_on,
                       priority_ordering=False,
                       admission_control=False)

@@ -1,9 +1,10 @@
 """The scheduler's one-deep pipeline: a step — a ``put`` step or a
-``decode_loop`` chunk — whose plan is closed to arrivals is dispatched before
-the step before it is fetched, its decode rows fed from that step's device
-ids (a chunk's: its last row); anything that needs token values or an idle
-engine fetches the step in flight first. Whatever a tick does, a request's
-tokens are the same.
+``decode_loop`` chunk — is dispatched before the step before it is fetched, its
+decode rows fed from that step's device ids (a chunk's: its last row): at once
+behind a step whose plan was closed to arrivals, at that step's commit time —
+its predicted end less the host's lead — behind one whose plan was open;
+anything that needs token values or an idle engine fetches the step in flight
+first. Whatever a tick does, a request's tokens are the same.
 """
 
 import threading
@@ -50,10 +51,19 @@ def _submit_all(sched, cfg, temperature=0.0, work=WORK, **kw):
             for i, (p, (_, m)) in enumerate(zip(_prompts(cfg, work), work))]
 
 
-def _serve(make_engine, cfg, temperature=0.0, serving=None, work=WORK, **mgr):
+def _drain_every_step(sched):
+    """Make ``sched`` the draining scheduler the references come from: no
+    step's duration is ever known, so each is fetched in its own tick."""
+    sched._predicted_s = lambda key: None
+    return sched
+
+
+def _serve(make_engine, cfg, temperature=0.0, serving=None, work=WORK, drained=False, **mgr):
     engine = make_engine(**mgr)
     start = engine.free_blocks
     sched = ServingScheduler(engine, serving or ServingConfig(), start=False)
+    if drained:
+        _drain_every_step(sched)
     reqs = _submit_all(sched, cfg, temperature, work)
     _run_until(sched, lambda: all(r.finished for r in reqs))
     counters = sched.stats()["counters"]
@@ -69,14 +79,15 @@ def _counted(counters):
 
 @pytest.fixture(scope="module")
 def reference(llama_setup):
-    """The streams of WORK through a scheduler whose plans all stay open."""
+    """The streams of WORK through a scheduler whose plans all stay open and
+    that drains every step."""
     return {}
 
 
 def _reference(reference, make_engine, cfg, temperature):
     if temperature not in reference:
-        tokens, counters = _serve(make_engine, cfg, temperature, **OPEN)
-        assert counters["pipelined_steps"] == 0
+        tokens, counters = _serve(make_engine, cfg, temperature, drained=True, **OPEN)
+        assert counters["pipelined_steps"] == counters["open_behind_steps"] == 0
         assert counters["drained_steps_open"] == counters["put_steps"] == counters["batches"]
         reference[temperature] = tokens
     return reference[temperature]
@@ -103,9 +114,9 @@ def test_the_sequence_cap_closes_a_plan_as_the_token_budget_does(make_engine, ll
     work = [(5, 10), (7, 10)]
     got, counters = _serve(make_engine, cfg, 0.8, work=work, max_ragged_batch_size=64,
                            max_ragged_sequence_count=2)
-    want, _ = _serve(make_engine, cfg, 0.8, work=work, **OPEN)
+    want, _ = _serve(make_engine, cfg, 0.8, work=work, drained=True, **OPEN)
     assert got == want
-    assert counters["pipelined_steps"] >= 8
+    assert counters["pipelined_steps"] >= 8 and counters["open_behind_steps"] == 0
 
 
 # ----------------------------------------------------- the recording engine --
@@ -213,7 +224,7 @@ def test_a_prompts_last_chunk_in_flight_feeds_its_first_decode_row_from_the_devi
     _run_until(sched, lambda: req.finished)
     sched.stop(drain=False)
 
-    plain = ServingScheduler(make_engine(**OPEN), ServingConfig(), start=False)
+    plain = _drain_every_step(ServingScheduler(make_engine(**OPEN), ServingConfig(), start=False))
     ref = plain.submit(prompt, max_new_tokens=5, temperature=0.8, seed=3)
     _run_until(plain, lambda: ref.finished)
     plain.stop(drain=False)
@@ -244,7 +255,8 @@ def chunked(llama_setup):
 
 def _chunked(chunked, make_engine, cfg):
     if not chunked:
-        want, drained = _serve(make_engine, cfg, serving=CHUNKED, work=CHUNK_WORK, **OPEN)
+        want, drained = _serve(make_engine, cfg, serving=CHUNKED, work=CHUNK_WORK, drained=True,
+                               **OPEN)
         assert drained["pipelined_steps"] == drained["pipelined_chunks"] == 0
         assert drained["batches"] - drained["put_steps"] >= 4   # it did run chunks
         chunked.update(want=want, run=_serve_recorded(make_engine, cfg, CHUNKED, CHUNK_WORK,
@@ -379,9 +391,9 @@ def test_a_request_whose_token_in_flight_is_its_last_is_not_in_the_next_plan(
         # positions 0..15 hold the prompt and all but the last generated token
         assert [len(r.tokens) for r in reqs] == [6, 16 - 11 + 1]
         assert reqs[1].finish_reason == "context"
-    ref = ServingScheduler(make_engine(**dict(OPEN, **{k: v for k, v in mgr.items()
-                                                        if k == "max_context"})),
-                           ServingConfig(), start=False)
+    ref = _drain_every_step(ServingScheduler(
+        make_engine(**dict(OPEN, **{k: v for k, v in mgr.items() if k == "max_context"})),
+        ServingConfig(), start=False))
     want = _submit_all(ref, cfg, 0.8, work)
     _run_until(ref, lambda: all(r.finished for r in want))
     ref.stop(drain=False)
@@ -479,7 +491,7 @@ def test_each_drain_reason_fires_where_it_should_and_the_stream_is_unchanged(
     work = case.get("work", WORK)
     open_mgr = dict(OPEN, **{k: v for k, v in case["mgr"].items()
                              if k in ("max_context", )})
-    want, _ = _serve(make_engine, cfg, case["temperature"], work=work,
+    want, _ = _serve(make_engine, cfg, case["temperature"], work=work, drained=True,
                      serving=ServingConfig(decode_chunk=chunk), **open_mgr)
 
     engine = make_engine(**case["mgr"])
@@ -536,15 +548,243 @@ def test_kill_drops_the_step_in_flight_without_waiting_for_the_device(make_engin
     assert engine.free_blocks == start
 
 
-# ----------------------------------------------------- programs and compiles --
-def test_pipelining_builds_no_program_after_the_scheduler_is_constructed(
+# ------------------------------------------- an open plan, just in time --
+STEP_S, HOST_S, FETCH_S = 0.010, 0.001, 0.0003
+
+
+class _SimulatedDevice(_RecordingEngine):
+    """The recording engine on a simulated clock, which the scheduler is given
+    too: a dispatch costs the host ``HOST_S``, a step takes the device
+    ``STEP_S`` from when it is dispatched or the step before it ends, and a
+    fetch returns ``FETCH_S`` after its step ended. Time moves for nothing
+    else but the scheduler's own waits (``pause``); ``on_dispatch`` runs at
+    the start of every dispatch."""
+
+    def __init__(self, engine):
+        super().__init__(engine)
+        self.__dict__.update(t=0.0, free_at=0.0, starts={}, ends={}, members={}, keys={},
+                             pauses=[], on_dispatch=None, on_pause=None)
+
+    def attach(self, sched):
+        sched._now = lambda: self.t
+        sched._pause = self.pause
+        return sched
+
+    def pause(self, seconds):
+        self.pauses.append(seconds)
+        self.__dict__["t"] = self.t + seconds
+        if self.on_pause is not None:
+            self.on_pause()
+
+    def _dispatched(self, kind, uids, kw):
+        if self.on_dispatch is not None:
+            self.on_dispatch()
+        n = super()._dispatched(kind, uids, kw)
+        self.__dict__["t"] = self.t + HOST_S
+        self.starts[n] = max(self.t, self.free_at)
+        self.__dict__["free_at"] = self.ends[n] = self.starts[n] + STEP_S
+        self.members[n] = list(uids)
+        return n
+
+    def put_draw(self, uids, tokens, *draw, **kw):
+        ids = super().put_draw(uids, tokens, *draw, **kw)
+        self.keys[ids.n] = self._engine.last_step_key
+        return _SimulatedIds(ids, self)
+
+
+class _SimulatedIds(_Ids):
+    def __init__(self, ids, device):
+        super().__init__(ids.ids, ids.n, ids.log)
+        self.device = device
+
+    def __array__(self, dtype=None, copy=None):
+        device = self.device
+        device.__dict__["t"] = max(device.t, device.ends[self.n]) + FETCH_S
+        return super().__array__(dtype, copy)
+
+
+def _simulated(make_engine, **mgr):
+    device = _SimulatedDevice(make_engine(**mgr))
+    return device, device.attach(ServingScheduler(device, ServingConfig(), start=False))
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8])
+def test_open_plans_go_behind_the_step_in_flight_and_the_streams_are_the_drained_schedulers(
+        make_engine, llama_setup, reference, temperature):
+    """Every plan is open (a budget of 512 tokens, a cap of 16 sequences). The
+    first step of each program is fetched in its own tick — that is how its
+    duration is first observed —, every other step is dispatched behind the
+    step in flight after a wait for that step's commit time, and the
+    simulated device goes from one into the next."""
+    cfg, _, _ = llama_setup
+    device, sched = _simulated(make_engine, **OPEN)
+    reqs = _submit_all(sched, cfg, temperature)
+    _run_until(sched, lambda: all(r.finished for r in reqs))
+    counters = sched.stats()["counters"]
+    sched.stop(drain=False)
+    assert [list(r.tokens) for r in reqs] == _reference(reference, make_engine, cfg, temperature)
+    steps = counters["put_steps"]
+    programs = len(set(device.keys.values()))
+    # one `open` drain a program and one when the last step left nothing to plan
+    assert counters["drained_steps_open"] == programs + 1
+    assert counters["open_behind_steps"] == counters["pipelined_steps"] == steps - programs - 1
+    assert _counted(counters) == counters["batches"] == steps and counters["overrun_rows"] == 0
+    at = {e: i for i, e in enumerate(device.log)}
+    behind = [n for n in device.chained if at[("put_draw", n)] < at[("fetch", n - 1)]]
+    assert len(behind) == counters["open_behind_steps"] >= 8
+    # the wait was made of slices no longer than a tick, and it was worth it:
+    # behind a step in flight the device idles at most the fetch's latency
+    # (the first observation of a program holds it), and not at all once a
+    # period behind another step has been observed
+    assert device.pauses and max(device.pauses) <= sched._config.scheduler_tick_s + 1e-12
+    idle = [device.starts[n] - device.ends[n - 1] for n in behind]
+    assert max(idle) <= FETCH_S + 1e-9 and idle[-1] == 0.0
+    assert counters["late_commits"] == 0
+
+
+@pytest.mark.parametrize("arrives", ["before_the_commit", "after_the_commit"])
+def test_an_arrival_before_the_commit_time_is_in_the_next_step_one_after_it_in_the_step_after(
+        make_engine, llama_setup, arrives):
+    cfg, _, _ = llama_setup
+    device, sched = _simulated(make_engine, **OPEN)
+    a = sched.submit(_prompts(cfg, [(9, 0)])[0], max_new_tokens=40, temperature=0.8, seed=1)
+    _run_until(sched, lambda: sched.stats()["counters"]["open_behind_steps"] >= 3)
+    late = []
+
+    def arrive():
+        if not late:
+            late.append(sched.submit(_prompts(cfg, [(12, 0)], seed=5)[0], max_new_tokens=4,
+                                     temperature=0.8, seed=2))
+
+    last = max(device.members)
+    if arrives == "before_the_commit":
+        device.__dict__["on_pause"] = arrive      # inside the wait: the plan is not built yet
+    else:
+        device.__dict__["on_dispatch"] = arrive   # the plan is built and on its way
+    sched.step()
+    b, = late
+    assert device.members[last + 1] == ([a.uid, b.uid] if arrives == "before_the_commit"
+                                        else [a.uid])
+    sched.step()
+    assert device.members[last + 2] == [a.uid, b.uid]
+    _run_until(sched, lambda: a.finished and b.finished)
+    sched.stop(drain=False)
+    assert len(a.tokens) == 40 and len(b.tokens) == 4
+
+
+def test_a_closed_plan_in_flight_never_waits(make_engine, llama_setup):
+    cfg, _, _ = llama_setup
+    telemetry.configure(telemetry.TelemetryConfig(enabled=True))
+    device, sched = _simulated(make_engine, max_ragged_batch_size=64, max_ragged_sequence_count=2)
+    reqs = _submit_all(sched, cfg, 0.8, [(5, 10), (7, 10)])
+    _run_until(sched, lambda: all(r.finished for r in reqs))
+    counters = sched.stats()["counters"]
+    sched.stop(drain=False)
+    assert counters["pipelined_steps"] >= 8
+    assert counters["open_behind_steps"] == counters["late_commits"] == 0 and device.pauses == []
+    spans = telemetry.get_span_recorder().export_since(0)["spans"]
+    assert not [s for s in spans if s["name"] == "commit_wait"]
+    ticks = [s["args"] for s in spans if s["cat"] == "sched" and s["name"] == "tick"
+             and s["args"]["kind"] == "put"]
+    assert sum(t["pipelined"] for t in ticks) >= 8
+    assert all(t["predicted_us"] == 0 and not t["open_behind"] for t in ticks)
+
+
+def test_a_program_with_no_observation_drains_open_once_then_never(make_engine, llama_setup):
+    cfg, _, _ = llama_setup
+    device, sched = _simulated(make_engine, **OPEN)
+    asked = []
+    commit_wait = sched._commit_wait
+
+    def logged(spans):
+        out = commit_wait(spans)
+        asked.append((sched._inflight.key, out))
+        return out
+
+    sched._commit_wait = logged
+    reqs = _submit_all(sched, cfg, 0.8)
+    _run_until(sched, lambda: all(r.finished for r in reqs))
+    sched.stop(drain=False)
+    unknown = [key for key, out in asked if out == "open"]
+    assert len(unknown) == len(set(unknown)) == len(set(device.keys.values())) >= 2
+    for key in set(unknown):
+        first = asked.index((key, "open"))
+        assert all(out is None for k, out in asked[first + 1:] if k == key)
+    assert all(out in (None, "open") for _, out in asked)
+
+
+def test_a_late_commit_lengthens_a_period_and_not_the_predicted_duration(
         make_engine, llama_setup):
+    cfg, _, _ = llama_setup
+    device, sched = _simulated(make_engine, **OPEN)
+    req = sched.submit(_prompts(cfg, [(9, 0)])[0], max_new_tokens=40, temperature=0.8, seed=1)
+    _run_until(sched, lambda: sched.stats()["counters"]["open_behind_steps"] >= 4)
+    key = sched._inflight.key
+    assert sched._predicted_s(key) == pytest.approx(STEP_S, abs=1e-9)
+    # the host oversleeps by a whole step, once: the step after is dispatched
+    # onto a device that has been idle, and its period reads that much longer
+    device.__dict__["on_pause"] = lambda: (device.__dict__.update(t=device.t + STEP_S,
+                                                                  on_pause=None))
+    sched.step()
+    sched.step()
+    assert max(sched._periods[key]) > 1.15 * STEP_S
+    assert sched._predicted_s(key) == pytest.approx(STEP_S, abs=1e-9)
+    # a tick that starts after its commit time commits at once
+    late = sched.stats()["counters"]["late_commits"]
+    device.__dict__["t"] = device.t + 2 * STEP_S
+    pauses = len(device.pauses)
+    sched.step()
+    assert sched.stats()["counters"]["late_commits"] == late + 1 and len(device.pauses) == pauses
+    _run_until(sched, lambda: req.finished)
+    sched.stop(drain=False)
+    assert sched._predicted_s(key) == pytest.approx(STEP_S, abs=1e-9)
+
+
+@pytest.mark.parametrize("how", ["stop", "kill", "control", "cancel"])
+def test_the_wait_ends_within_a_tick_on(make_engine, llama_setup, how):
+    """A running scheduler that believes its step in flight has a minute to
+    go: each of the four is served at once, not when the minute is over."""
+    cfg, _, _ = llama_setup
+    engine = make_engine(**OPEN)
+    sched = ServingScheduler(engine, ServingConfig(), start=True)
+    predicted = sched._predicted_s
+    sched._predicted_s = lambda key: None if predicted(key) is None else 60.0
+    waits = []
+    pause = sched._pause
+    sched._pause = lambda s: (waits.append(s), pause(s))
+    req = sched.submit(_prompts(cfg, [(9, 0)])[0], max_new_tokens=200, temperature=0.8, seed=1)
+    deadline = time.monotonic() + 60
+    while len(waits) < 3 and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert len(waits) >= 3 and not req.finished   # it is inside the wait
+    t0 = time.monotonic()
+    if how == "stop":
+        sched.stop(drain=False)
+    elif how == "kill":
+        sched.kill("test")
+    elif how == "control":
+        assert sched._call_on_loop(lambda: sched._inflight, timeout=30.0) is None
+    else:
+        req.cancel()
+        while not req.finished and time.monotonic() < t0 + 30:
+            time.sleep(0.001)
+        assert req.state is RequestState.CANCELLED
+    assert time.monotonic() - t0 < 10.0
+    assert max(waits) <= sched._config.scheduler_tick_s
+    sched.stop(drain=False)
+
+
+# ----------------------------------------------------- programs and compiles --
+@pytest.mark.parametrize("mgr", [CLOSED, OPEN], ids=["closed_plans", "open_plans"])
+def test_pipelining_builds_no_program_after_the_scheduler_is_constructed(
+        make_engine, llama_setup, mgr):
     """The merge in front of a chained step is compiled when the scheduler is
     constructed, and the forward a chained step runs is the one ``engine.put``
     runs, under its cache key: a second pass over warmed buckets compiles
-    nothing, pipelined or not."""
+    nothing, pipelined or not — behind a closed step or, at its commit time,
+    behind an open one."""
     cfg, _, _ = llama_setup
-    engine = make_engine(**CLOSED)
+    engine = make_engine(**mgr)
     first = ServingScheduler(engine, ServingConfig(), start=False)
     reqs = _submit_all(first, cfg, 0.8)
     _run_until(first, lambda: all(r.finished for r in reqs))
@@ -561,13 +801,16 @@ def test_pipelining_builds_no_program_after_the_scheduler_is_constructed(
     counters = sched.stats()["counters"]
     sched.stop(drain=False)
     assert counters["pipelined_steps"] >= 5
+    if mgr is OPEN:
+        assert counters["open_behind_steps"] >= 5
     assert [list(r.tokens) for r in again] == [list(r.tokens) for r in reqs]
     assert set(engine.model._compiled) == keys
     assert compiled == []
-    # engine.put lands in the same programs: a full 16-token chunk, one sequence
-    engine.put([10_001], [np.zeros(16, np.int32)])
-    engine.flush(10_001)
-    assert set(engine.model._compiled) == keys
+    if mgr is CLOSED:
+        # engine.put lands in the same programs: a full 16-token chunk, one sequence
+        engine.put([10_001], [np.zeros(16, np.int32)])
+        engine.flush(10_001)
+        assert set(engine.model._compiled) == keys
 
 
 # ------------------------------------------------------------------- tracing --
@@ -674,3 +917,31 @@ def test_chain_feeds_the_named_slots_from_the_ids_wherever_they_lie(placed):
     want = tok_meta.copy()
     want[0, [0, 3, 15]] = [107, 100, 102]
     assert out.tolist() == want.tolist()
+
+
+@pytest.mark.parametrize("placed", ["one_device", "replicated_on_a_mesh", "split_over_a_mesh"])
+def test_last_row_takes_a_chunks_last_ids_wherever_they_lie(placed):
+    """``sampling.last_row``: row ``steps - 1`` of a chunk's ``[steps, rows]``
+    tokens. On one device, and replicated over a mesh (read from the default
+    device's replica), by the program built ahead of the first step: nothing
+    compiles at the first chunk that has a successor behind it."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from deepspeed_tpu.inference.v2 import sampling
+
+    tokens = np.arange(32, dtype=np.int32).reshape(4, 8)
+    if placed == "one_device":
+        on_device = jax.device_put(tokens, jax.devices()[0])
+    else:
+        mesh = Mesh(np.array(jax.devices()[:2]), ("x", ))
+        spec = PartitionSpec() if placed == "replicated_on_a_mesh" else PartitionSpec(None, "x")
+        on_device = jax.device_put(tokens, NamedSharding(mesh, spec))
+    sampling.compiled_last_row(4, 8)
+    compiled = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, *_a, **_k: compiled.append(name) if "backend_compile" in name else None)
+    out = sampling.last_row(on_device)
+    if placed != "split_over_a_mesh":
+        assert compiled == [] and out.sharding.device_set == {jax.devices()[0]}
+    assert np.asarray(out).tolist() == tokens[-1].tolist()

@@ -234,11 +234,24 @@ def _sched_spans():
     return [s for s in telemetry.get_span_recorder().export_since(0)["spans"]]
 
 
-def _serve_inline(make_engine, submit, **serving):
+def _simulated_clock(sched, step_s=0.004):
+    """The scheduler's clock moves only while it waits, and a program whose
+    step has been observed once is said to take ``step_s``: every tick behind
+    such a step waits, in whole slices, whatever the machine's speed."""
+    t = [0.0]
+    sched._now = lambda: t[0]
+    sched._pause = lambda seconds: t.__setitem__(0, t[0] + seconds)
+    observed = sched._predicted_s
+    sched._predicted_s = lambda key: None if observed(key) is None else step_s
+
+
+def _serve_inline(make_engine, submit, prepare=None, **serving):
     """Serve through a manually stepped scheduler with telemetry on; returns
     the recorded spans."""
     telemetry.configure(telemetry.TelemetryConfig(enabled=True))
     sched = ServingScheduler(make_engine(), ServingConfig(**serving), start=False)
+    if prepare is not None:
+        prepare(sched)
     reqs = submit(sched)
     for _ in range(200):
         sched.step()
@@ -258,29 +271,46 @@ def _inside(child, parent):
 def test_tick_spans_hold_their_phases_in_order(make_engine):
     spans = _serve_inline(
         make_engine,
-        lambda s: [s.submit([1, 2, 3, 4, 5], max_new_tokens=4, temperature=0.7, seed=1)])
+        lambda s: [s.submit([1, 2, 3, 4, 5], max_new_tokens=5, temperature=0.7, seed=1)],
+        prepare=_simulated_clock)
     ticks = [s for s in spans if s["cat"] == "sched" and s["name"] == "tick"]
-    assert len(ticks) == 4  # the prefill (first token) + three decode steps
     assert [t["args"]["tick"] for t in ticks] == sorted(t["args"]["tick"] for t in ticks)
-    for tick in ticks:
-        assert tick["args"]["kind"] == "put" and tick["args"]["seqs"] == 1
-        # an open plan (one sequence, a few tokens): fetched in its own tick,
-        # as was the step before it, and for that reason
-        assert tick["args"]["pipelined"] == 0 and tick["args"]["drain"] == "open"
+    # the prefill (first token) + four decode steps, and the tick that fetched the last
+    assert [t["args"]["kind"] for t in ticks] == ["put"] * 5 + ["none"]
+    dispatch = [("sched", "admit"), ("sched", "build_batch"), ("inference", "prepare"),
+                ("inference", "put")]
+    complete = [("sched", "fetch"), ("sched", "emit")]
+    # an open plan (one sequence, a few tokens) stays in flight. The first
+    # step of a program — the prompt's five tokens and a decode row share the
+    # 8-token bucket — is fetched before anything else in the tick after it
+    # (nothing is known of its duration: `open`); every later one has its
+    # successor dispatched behind it at its commit time, after a wait, and is
+    # fetched under that successor; the last leaves nothing to plan
+    waited = [("sched", "commit_wait")] + dispatch + complete
+    wanted = [dispatch, complete + dispatch, waited, waited, waited,
+              [("sched", "commit_wait")] + dispatch[:2] + complete + dispatch[:2]]
+    for i, (tick, want) in enumerate(zip(ticks, wanted)):
+        args = tick["args"]
         children = sorted((s for s in spans if s is not tick and _inside(s, tick)
                            and s["cat"] in ("sched", "inference")), key=lambda s: s["ts_us"])
-        assert [(c["cat"], c["name"]) for c in children] == [
-            ("sched", "admit"), ("sched", "build_batch"), ("inference", "prepare"),
-            ("inference", "put"), ("sched", "fetch"), ("sched", "emit")]
+        assert [(c["cat"], c["name"]) for c in children] == want
         for a, b in zip(children, children[1:]):
             assert a["ts_us"] + a["dur_us"] <= b["ts_us"], "phases do not overlap"
-        emit = children[-1]
-        # the token was drawn on the device: no host-side draw, and the fetch
-        # brought the bucket's 8 int32 ids, not a row of logits
-        assert emit["args"]["sample_us"] == 0 and emit["args"]["device_draws"] == 1
-        assert emit["args"]["pushed"] == 1
-        assert children[2]["args"]["sequences"] == 1
-        assert children[4]["args"]["bytes"] == 4 * 8
+        by_name = {c["name"]: c for c in children}
+        if args["kind"] == "put":
+            behind = i >= 2
+            assert args["seqs"] == 1 and args["open"] == 1
+            assert args["pipelined"] == args["open_behind"] == int(behind)
+            assert ("drain" in args) == (not behind) and args.get("drain", "open") == "open"
+            assert args["predicted_us"] == (4000 if behind else 0) and args["lead_us"] >= 0
+            assert by_name["prepare"]["args"]["sequences"] == 1
+        if "emit" in by_name:
+            # the token was drawn on the device: no host-side draw, and the fetch
+            # brought the bucket's 8 int32 ids, not a row of logits
+            emit = by_name["emit"]
+            assert emit["args"]["sample_us"] == 0 and emit["args"]["device_draws"] == 1
+            assert emit["args"]["pushed"] == 1
+            assert by_name["fetch"]["args"]["bytes"] == 4 * 8
     assert ticks[0]["args"]["tokens"] == 5 and ticks[1]["args"]["tokens"] == 1
     assert sum(s["args"]["finished"] for s in spans if s["name"] == "emit") == 1
     first_admit = min((s for s in spans if s["name"] == "admit"), key=lambda s: s["ts_us"])
@@ -328,13 +358,20 @@ def test_request_phase_spans_carry_the_tick_that_ran_them(make_engine):
 
 def test_decode_loop_tick_is_named_for_its_dispatch(make_engine):
     spans = _serve_inline(make_engine,
-                          lambda s: [s.submit([1, 2, 3], max_new_tokens=9)], decode_chunk=4)
+                          lambda s: [s.submit([1, 2, 3], max_new_tokens=13)], decode_chunk=4,
+                          prepare=_simulated_clock)
     kinds = [s["args"]["kind"] for s in spans if s["cat"] == "sched" and s["name"] == "tick"]
-    assert kinds[0] == "put" and "decode_loop" in kinds
-    # one sequence under a cap of many: the chunk's plan is open, so it is
-    # fetched in its own tick and nothing goes behind it
-    assert all(s["args"]["pipelined"] == 0 and s["args"]["drain"] == "open"
-               for s in spans if s["name"] == "tick" and s["args"]["kind"] == "decode_loop")
+    assert kinds == ["put", "decode_loop", "decode_loop", "decode_loop", "none"]
+    # one sequence under a cap of many: every chunk's plan is open. The first
+    # chunk follows the prompt's step, the first of ITS program, fetched first;
+    # the second follows the first chunk, the first of the chunks' program,
+    # fetched first too; the third goes behind the second at its commit time
+    chunk_ticks = [s["args"] for s in spans
+                   if s["name"] == "tick" and s["args"]["kind"] == "decode_loop"]
+    assert [(t["open"], t["pipelined"], t["open_behind"], t.get("drain"), t["predicted_us"])
+            for t in chunk_ticks] == [(1, 0, 0, "open", 0), (1, 0, 0, "open", 0),
+                                      (1, 1, 1, None, 4000)]
+    assert len([s for s in spans if s["name"] == "commit_wait"]) == 2
     loop = next(s for s in spans if s["cat"] == "inference" and s["name"] == "decode_loop")
     assert loop["args"]["steps"] == 4
     prepare = max((s for s in spans if s["name"] == "prepare" and s["ts_us"] <= loop["ts_us"]),
@@ -353,12 +390,15 @@ def test_idle_polls_record_nothing_but_no_work(make_engine):
     sched = ServingScheduler(make_engine(), ServingConfig(scheduler_tick_s=0.001))
     time.sleep(0.15)
     req = sched.submit([1, 2, 3], max_new_tokens=2)
-    assert req.stream.get(timeout=60) is not None
+    while req.stream.get(timeout=60) is not None:
+        pass
     sched.stop()
     spans = _sched_spans()
     idle = [s for s in spans if s["cat"] == "sched" and s["name"] == "no_work"]
     ticks = [s for s in spans if s["cat"] == "sched" and s["name"] == "tick"]
-    assert idle and len(ticks) == 2
+    # the prompt's step, the decode step, and the tick that fetched the second
+    # token (an open step stays in flight when its tick ends)
+    assert idle and len(ticks) == 3
     before = [s for s in idle if s["ts_us"] + s["dur_us"] <= ticks[0]["ts_us"]]
     # ~150 polls of 1 ms: a handful of spans, not one a poll
     assert 1 <= len(before) <= 150 // _NO_WORK_SPAN_POLLS + 2
@@ -366,7 +406,9 @@ def test_idle_polls_record_nothing_but_no_work(make_engine):
     for s in idle:  # never over a tick
         assert all(s["ts_us"] + s["dur_us"] <= t["ts_us"] or t["ts_us"] + t["dur_us"] <= s["ts_us"]
                    for t in ticks)
-    assert len([s for s in spans if s["name"] == "admit"]) == len(ticks)
+    # a phase is a tick's: none a poll (the last tick looks twice: before and
+    # after it fetched the step in flight)
+    assert len([s for s in spans if s["name"] == "admit"]) == len(ticks) + 1
 
 
 def test_profiler_trace_shows_the_scheduler_threads_phases(make_engine, tmp_path):
@@ -382,11 +424,17 @@ def test_profiler_trace_shows_the_scheduler_threads_phases(make_engine, tmp_path
     warm = sched.submit([1, 2, 3], max_new_tokens=2)
     while not warm.finished:
         assert warm.stream.get(timeout=60) is not None or True
+    # the tick that fetched a request's last token looks once more for work
+    # before it ends: let it end inside the trace (an annotation is written
+    # when it closes), so that no phase is traced without its tick
+    import time
+    time.sleep(0.2)
     jax.profiler.start_trace(str(tmp_path))
     try:
         req = sched.submit([1, 2, 3, 4], max_new_tokens=3)
         while req.stream.get(timeout=60) is not None:
             pass
+        time.sleep(0.2)
     finally:
         jax.profiler.stop_trace()
         sched.stop()
