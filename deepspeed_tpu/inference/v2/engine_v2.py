@@ -248,10 +248,11 @@ class InferenceEngineV2:
     # launch alone, ``launch_us`` (entry until the jitted call has returned);
     # ``fetch_us`` (0 until then) is written when the chunk is fetched, under
     # whatever span the fetcher is in, and is the blocking transfer's time.
-    def _prepare_forward(self, spans, batch_uids, feeds, do_checks, n_tokens, trees=None):
-        """The host side of one ragged forward, under the ``prepare`` span:
-        admission check, restore of offloaded sequences, KV allocation and the
-        ragged batch. ``feeds``: each sequence's token array."""
+    def _prepare(self, spans, batch_uids, feeds, do_checks, n_tokens, steps=0, trees=None):
+        """The host side of one step, under the ``prepare`` span: admission
+        check, restore of offloaded sequences, KV allocation and the ragged
+        batch. ``feeds``: each sequence's token array. ``steps``: the step is a
+        ``decode_loop`` chunk of that many steps (0: one ragged forward)."""
         args = None
         if spans is not None:
             free_before = self._state_manager.free_blocks
@@ -261,24 +262,27 @@ class InferenceEngineV2:
                 # BEFORE restoring: can_schedule counts offloaded sequences'
                 # restore cost, so admission failure is a SchedulingError here,
                 # never a raw allocator error mid-restore
-                schedule_check = self.can_schedule(batch_uids, [t.size for t in feeds])
+                schedule_check = self.can_schedule(batch_uids, [t.size for t in feeds], steps)
                 if schedule_check != SchedulingResult.Success:
                     raise SchedulingError(schedule_check)
-            self._restore_offloaded(batch_uids)
+            # touching an offloaded sequence restores it first (ZeRO-Inference
+            # KV-offload choreography; see ragged_manager.offload_sequence)
+            for uid in batch_uids:
+                self._state_manager.restore_sequence(uid)
 
             self._batch.clear()
             for i, (uid, tokens) in enumerate(zip(batch_uids, feeds)):
                 seq_desc = self._state_manager.get_or_create_sequence(uid)
-                self._model.maybe_allocate_kv(seq_desc, tokens.size)
+                # a chunk's KV blocks are allocated for the WHOLE generation:
+                # the device loop cannot allocate mid-scan, and the block
+                # table is static inside it
+                self._model.maybe_allocate_kv(seq_desc, steps or tokens.size)
                 seq_desc.pre_forward(tokens.size)
-                if trees is None:
-                    self._batch.insert_sequence(seq_desc, tokens, do_checks=do_checks)
-                else:
-                    self._batch.insert_sequence(seq_desc, tokens, do_checks=do_checks,
-                                                tree=(trees[i].parents, trees[i].depths))
+                self._batch.insert_sequence(
+                    seq_desc, tokens, do_checks=do_checks,
+                    tree=None if trees is None else (trees[i].parents, trees[i].depths))
 
             self._batch.finalize()
-            self._model.prepare_batch(self._batch)
             if args is not None:
                 args["allocated_blocks"] = free_before - self._state_manager.free_blocks
 
@@ -288,8 +292,9 @@ class InferenceEngineV2:
         as this step begins, in full-causal and in sliding-window layer
         groups."""
         held = self._live_blocks_by_kind()
-        return {"sequences": n_sequences, "tokens": n_tokens,
-                "released_blocks": self._released_since_prepare(),
+        released = self._released_blocks - self._released_at_prepare
+        self._released_at_prepare = self._released_blocks
+        return {"sequences": n_sequences, "tokens": n_tokens, "released_blocks": released,
                 "live_blocks_full": held["full"], "live_blocks_window": held["window"]}
 
     def _live_blocks_by_kind(self) -> dict:
@@ -305,17 +310,13 @@ class InferenceEngineV2:
                 held["window" if window > 0 else "full"] += seq.live_blocks_in(group)
         return held
 
-    def _released_since_prepare(self) -> int:
-        """Blocks the rolling release gave back since the last ``prepare``
-        span (after the step before this one): its ``released_blocks``."""
-        n = self._released_blocks - self._released_at_prepare
-        self._released_at_prepare = self._released_blocks
-        return n
-
     def _telemetry_sinks(self):
         """``(spans, observer, metrics)``: all None with telemetry off and no
         scheduler cost plane attached."""
-        return self._resolve_spans(), self.dispatch_observer, self._resolve_tel_metrics()
+        # the engine session's recorder, or a globally-configured session's
+        # (same fallback policy as :meth:`_resolve_tel_metrics`)
+        spans = self._telemetry.spans if self._telemetry is not None else _tel_get_spans()
+        return spans, self.dispatch_observer, self._resolve_tel_metrics()
 
     @staticmethod
     def _dispatch_args(spans, batch_uids, **counts):
@@ -326,13 +327,43 @@ class InferenceEngineV2:
             return None
         return dict(counts, sequences=len(batch_uids), uids=[int(u) for u in batch_uids])
 
-    def _post_forward(self, batch_uids, release: bool = True) -> None:
-        """Commit the fed tokens and, unless the caller may still roll some
-        back (the verify steps: a released block cannot come back), let the
-        model release what the window has passed."""
+    def _dispatch(self, spans, batch_uids, batch_tokens, prev, steps=0):
+        """What a ``put`` step or a ``decode_loop`` chunk (``steps`` of it)
+        says of the batch just prepared as it is dispatched:
+        :attr:`last_moe_path` and :attr:`last_step_key`, the dispatch span's
+        args, and ``prev`` by token slot."""
+        n_padded = self._batch.device_batch["tok_meta"].shape[1]
+        # how the bucket's program routes its tokens to experts (grouped /
+        # capacity; None for a dense model): the scheduler counts steps by it
+        self.last_moe_path = self._model.moe_path(n_padded)
+        self.last_step_key = (steps, n_padded, *self._batch.device_batch["seq_meta"].shape)
+        args = None
+        if spans is not None:
+            n_tokens = int(sum(t.size for t in batch_tokens))
+            args = self._dispatch_args(spans, batch_uids,
+                                       **({"steps": steps} if steps else {"tokens": n_tokens}))
+            if not steps:
+                # the arm the bucket's program takes (modules/heuristics.py):
+                # paged_tiled / paged_token / xla_gather
+                args["attention"] = self._model.attention_arm(n_padded)
+            # a sparse model's moe_path, moe_rows and moe_assignments (and, on
+            # the capacity path, moe_banks: every bank); every step of a chunk
+            # routes this bucket
+            args.update(self._model.dispatch_counts(n_padded, n_tokens, steps or 1))
+            args.update(self._model.batch_counts(self._batch, steps or 1))
+        return args, self._prev_by_slot(prev, batch_tokens, n_padded, args)
+
+    def _post_forward(self, batch_uids, steps: int = 1, release: bool = True) -> None:
+        """Commit the fed tokens (and the ``steps - 1`` a chunk's loop
+        inserted behind them) and, unless the caller may still roll some back
+        (the verify steps: a released block cannot come back), let the model
+        release what the window has passed."""
         for uid in batch_uids:
             seq_desc = self._state_manager.get_sequence(uid)
             seq_desc.post_forward()
+            if steps > 1:
+                seq_desc.pre_forward(steps - 1)
+                seq_desc.post_forward()
             if release:
                 self._released_blocks += self._model.maybe_free_kv(seq_desc)
 
@@ -396,23 +427,9 @@ class InferenceEngineV2:
         live = spans is not None or observer is not None or metrics is not None
         n_tokens = int(sum(t.size for t in batch_tokens)) if live else 0
 
-        self._prepare_forward(spans, batch_uids, batch_tokens, do_checks, n_tokens)
-        n_padded = self._batch.device_batch["tok_meta"].shape[1]
-        # how the bucket's program routes its tokens to experts (grouped /
-        # capacity; None for a dense model): the scheduler counts steps by it
-        self.last_moe_path = self._model.moe_path(n_padded)
-        self.last_step_key = self._step_key(0)
+        self._prepare(spans, batch_uids, batch_tokens, do_checks, n_tokens)
+        args, prev = self._dispatch(spans, batch_uids, batch_tokens, prev)
         self.last_moe_fetch = None
-        args = self._dispatch_args(spans, batch_uids, tokens=n_tokens)
-        if args is not None:
-            # the arm the bucket's program takes (modules/heuristics.py):
-            # paged_tiled / paged_token / xla_gather; a sparse model's
-            # moe_path, moe_rows and moe_assignments (and, on the capacity
-            # path, moe_banks: every bank)
-            args["attention"] = self._model.attention_arm(n_padded)
-            args.update(self._model.dispatch_counts(n_padded, n_tokens))
-            args.update(self._model.batch_counts(self._batch))
-        prev = self._prev_by_slot(prev, batch_tokens, n_padded, args)
         with _tel_live_span(spans, "put", "inference", args):
             if observer is not None:
                 _t0 = _tel_now_us()
@@ -436,11 +453,6 @@ class InferenceEngineV2:
         if metrics is not None:
             self._write_telemetry(metrics, batch_tokens=n_tokens)
         return out
-
-    def _step_key(self, loop_steps: int) -> tuple:
-        """:attr:`last_step_key` of the batch just finalized."""
-        batch = self._batch.device_batch
-        return (loop_steps, batch["tok_meta"].shape[1], *batch["seq_meta"].shape)
 
     @staticmethod
     def _prev_by_slot(prev, batch_tokens, n_padded, args):
@@ -495,11 +507,6 @@ class InferenceEngineV2:
             self._tel_metrics = self._build_tel_metrics(telemetry.get_registry())
         return self._tel_metrics
 
-    def _resolve_spans(self):
-        """The engine session's recorder — or a globally-configured
-        session's (same fallback policy as :meth:`_resolve_tel_metrics`)."""
-        return self._telemetry.spans if self._telemetry is not None else _tel_get_spans()
-
     def _write_telemetry(self, metrics: dict, batch_tokens: int) -> None:
         metrics["batches"].inc()
         metrics["tokens"].inc(batch_tokens)
@@ -512,19 +519,16 @@ class InferenceEngineV2:
 
     # ------------------------------------------------------------ decode_loop --
     def decode_loop(self, batch_uids: Iterable[int], batch_tokens: Iterable,
-                    n_steps: int, do_checks: bool = True, temperature: float = 0.0,
-                    rng=None) -> np.ndarray:
+                    n_steps: int, do_checks: bool = True) -> np.ndarray:
         """Generate ``n_steps`` tokens per sequence in ONE device program (no
-        host round-trip per token — see DSTransformerModelBase.decode_loop).
-        ``batch_tokens`` holds each sequence's next-input token(s); returns
-        generated tokens ``[n_seqs, n_steps]``. ``temperature`` 0 = greedy;
-        > 0 samples categorically with the (per-step folded) ``rng``.
+        host round-trip per token — see DSTransformerModelBase.decode_loop),
+        each the argmax of its logits. ``batch_tokens`` holds each sequence's
+        next-input token; returns generated tokens ``[n_seqs, n_steps]``.
 
         Each entry is ONE token: a feed of several (a next-input token plus
         drafts) is a speculative verify step, :meth:`verify_tree`'s. A
         sampled request is drawn at its own ``(seed, draw_index)`` by
-        :meth:`put_draw`; this loop's ``rng`` is one key for the whole batch,
-        folded per step.
+        :meth:`put_draw`, a step at a time.
 
         EOS is not monitored on device: the loop always runs ``n_steps``; the
         caller trims at the first EOS (the fixed-shape scan is what makes the
@@ -533,12 +537,10 @@ class InferenceEngineV2:
         This is :meth:`dispatch_decode_loop` fetched at once; a caller that has
         other work for the host while the chunk runs takes the two apart.
         """
-        return self.dispatch_decode_loop(batch_uids, batch_tokens, n_steps, do_checks,
-                                         temperature, rng).fetch()
+        return self.dispatch_decode_loop(batch_uids, batch_tokens, n_steps, do_checks).fetch()
 
     def dispatch_decode_loop(self, batch_uids: Iterable[int], batch_tokens: Iterable,
-                             n_steps: int, do_checks: bool = True, temperature: float = 0.0,
-                             rng=None, prev=None) -> DecodeChunk:
+                             n_steps: int, do_checks: bool = True, prev=None) -> DecodeChunk:
         """:meth:`decode_loop`, launched and NOT fetched: everything that needs
         only counts is done when the call returns — the checks, the KV blocks
         of all ``n_steps`` tokens, ``seen_tokens``, the rolling release — and
@@ -560,83 +562,23 @@ class InferenceEngineV2:
         if n_steps < 1:
             raise ValueError("n_steps must be >= 1")
         spans, observer, metrics = self._telemetry_sinks()
-        prep = None
-        if spans is not None:
-            free_before = self._state_manager.free_blocks
-            prep = self._prepare_args(len(batch_uids), len(batch_uids) * n_steps)
-        with _tel_live_span(spans, "prepare", "inference", prep):
-            if do_checks:
-                # each SCAN STEP's ragged batch holds one token per sequence, so
-                # the token budget is checked against n_seqs — but the KV-block
-                # budget must cover all n_steps appended tokens per sequence
-                if len(batch_uids) > self._config.state_manager.max_ragged_sequence_count:
-                    raise SchedulingError(SchedulingResult.BatchSequenceLimitExceeded)
-                if len(batch_uids) > self._config.state_manager.max_ragged_batch_size:
-                    raise SchedulingError(SchedulingResult.BatchTokenLimitExceeded)
-                free_blocks = self._state_manager.free_blocks
-                cur_seqs = self._state_manager.n_tracked_sequences
-                for uid in batch_uids:
-                    seq_desc = self._state_manager.get_sequence(uid)
-                    if seq_desc is None:
-                        cur_seqs += 1
-                        seq_desc = PlaceholderSequenceDescriptor()
-                    restore = self._restore_cost(uid, seq_desc)
-                    sched_len, sched_blocks = self._model.get_kv_requirements(
-                        seq_desc, n_steps, free_blocks - restore)
-                    if sched_len != n_steps:
-                        raise SchedulingError(SchedulingResult.KVCacheLimitExceeded)
-                    free_blocks -= sched_blocks + restore
-                # before any sequence is touched, as ``can_schedule`` has it
-                if cur_seqs > self._config.state_manager.max_tracked_sequences:
-                    raise SchedulingError(SchedulingResult.EngineSequenceLimitExceeded)
-            self._restore_offloaded(batch_uids)
-
-            self._batch.clear()
-            for uid, tokens in zip(batch_uids, batch_tokens):
-                seq_desc = self._state_manager.get_or_create_sequence(uid)
-                # pre-allocate KV blocks for the WHOLE generation: the device
-                # loop cannot allocate mid-scan, and the block table is static
-                # inside it
-                self._model.maybe_allocate_kv(seq_desc, n_steps)
-                seq_desc.pre_forward(tokens.size)
-                self._batch.insert_sequence(seq_desc, tokens, do_checks=do_checks)
-
-            self._batch.finalize()
-            if prep is not None:
-                prep["allocated_blocks"] = free_before - self._state_manager.free_blocks
-
-        n_padded = self._batch.device_batch["tok_meta"].shape[1]
-        self.last_moe_path = self._model.moe_path(n_padded)
-        self.last_step_key = self._step_key(n_steps)
-        args = self._dispatch_args(spans, batch_uids, steps=n_steps)
-        if args is not None:
-            # a sparse model's moe_path, and the chunk's moe_rows and
-            # moe_assignments: every step of it routes this bucket
-            args.update(self._model.dispatch_counts(n_padded, len(batch_uids), n_steps))
-            args.update(self._model.batch_counts(self._batch, n_steps))
-        prev = self._prev_by_slot(prev, batch_tokens, n_padded, args)
+        n_tokens = len(batch_uids) * n_steps
+        self._prepare(spans, batch_uids, batch_tokens, do_checks, n_tokens, steps=n_steps)
+        args, prev = self._dispatch(spans, batch_uids, batch_tokens, prev, steps=n_steps)
         with _tel_live_span(spans, "decode_loop", "inference", args):
             if observer is not None or spans is not None:
                 _t0 = _tel_now_us()
-            tokens, banks = self._model.decode_loop(self._batch, n_steps,
-                                                    temperature=temperature, rng=rng, prev=prev)
+            tokens, banks = self._model.decode_loop(self._batch, n_steps, prev=prev)
             if spans is not None:
                 # the call's two parts: the second is the fetcher's to write
                 args.update(launch_us=_tel_now_us() - _t0, fetch_us=0)
                 if banks is not None:
                     banks.copy_to_host_async()  # rides behind the tokens, not after them
             if observer is not None:
-                observer("decode_loop", len(batch_uids),
-                         len(batch_uids) * n_steps, (_tel_now_us() - _t0) / 1e6)
+                observer("decode_loop", len(batch_uids), n_tokens, (_tel_now_us() - _t0) / 1e6)
         if metrics is not None:
-            self._write_telemetry(metrics, batch_tokens=len(batch_uids) * n_steps)
-        for uid in batch_uids:
-            seq_desc = self._state_manager.get_sequence(uid)
-            seq_desc.post_forward()           # the token passed in
-            if n_steps > 1:                   # the n_steps-1 tokens the loop inserted
-                seq_desc.pre_forward(n_steps - 1)
-                seq_desc.post_forward()
-            self._released_blocks += self._model.maybe_free_kv(seq_desc)
+            self._write_telemetry(metrics, batch_tokens=n_tokens)
+        self._post_forward(batch_uids, steps=n_steps)
         return DecodeChunk(tokens, len(batch_uids), banks, args, self.moe_counts)
 
     # ------------------------------------------------------ speculative verify --
@@ -664,17 +606,13 @@ class InferenceEngineV2:
         (``seen_tokens`` advances by ``n_nodes``); the caller walks the tree
         with the spec-off sampling rule and re-packs/truncates via
         :meth:`compact_accepted`."""
-        if self._state_manager.num_slots:
-            raise NotImplementedError(
-                "verify_tree: this model keeps a per-sequence state group, and a recurrent "
-                "state cannot be rolled back to an accepted prefix without a snapshot a draft")
+        self._state_manager.kv_cache.refuse("verify_tree")
         batch_uids = list(batch_uids)
         trees = list(trees)
         spans, observer, metrics = self._telemetry_sinks()
         n_tokens = int(sum(t.size for t in trees))
-        self._prepare_forward(spans, batch_uids, [t.tokens for t in trees], do_checks,
-                              n_tokens,
-                              trees=None if all(t.is_chain for t in trees) else trees)
+        self._prepare(spans, batch_uids, [t.tokens for t in trees], do_checks, n_tokens,
+                      trees=None if all(t.is_chain for t in trees) else trees)
         args = self._dispatch_args(spans, batch_uids, tokens=n_tokens)
         with _tel_live_span(spans, "verify_tree", "inference", args):
             if observer is not None:
@@ -734,10 +672,7 @@ class InferenceEngineV2:
         relies on). The blocks stay allocated for the sequence."""
         if n_tokens <= 0:
             return
-        if self._state_manager.num_slots:
-            raise NotImplementedError(
-                "rollback: this model keeps a per-sequence state group; the slot's state has "
-                "the truncated tokens in it and cannot be wound back without a snapshot")
+        self._state_manager.kv_cache.refuse("rollback")
         seq_desc = self._state_manager.get_sequence(uid)
         if seq_desc is None:
             raise ValueError(f"rollback: unknown uid {uid}")
@@ -771,14 +706,24 @@ class InferenceEngineV2:
         blocks as resident."""
         return seq_desc.live_blocks if self._state_manager.is_offloaded(uid) else 0
 
-    def can_schedule(self, uids: Iterable[int], lengths: Iterable[int]) -> SchedulingResult:
+    def can_schedule(self, uids: Iterable[int], lengths: Iterable[int],
+                     steps: int = 0) -> SchedulingResult:
+        """Whether one step can take ``lengths[i]`` tokens of sequence
+        ``uids[i]``. ``steps``: the step is a ``decode_loop`` chunk of that
+        many steps, a token a sequence. Each SCAN STEP's ragged batch then
+        holds one token per sequence, so the token budget is the sequence
+        count's (and is looked at before any sequence), while the KV-block
+        budget must cover all ``steps`` appended tokens per sequence."""
         uids, lengths = list(uids), list(lengths)
+        limits = self._config.state_manager
         cur_seqs = self._state_manager.n_tracked_sequences
         free_blocks = self._state_manager.free_blocks
-        batch_len = 0
+        batch_len = sum(lengths)
 
-        if len(uids) > self._config.state_manager.max_ragged_sequence_count:
+        if len(uids) > limits.max_ragged_sequence_count:
             return SchedulingResult.BatchSequenceLimitExceeded
+        if steps and batch_len > limits.max_ragged_batch_size:
+            return SchedulingResult.BatchTokenLimitExceeded
 
         for uid, length in zip(uids, lengths):
             seq_desc = self._state_manager.get_sequence(uid)
@@ -787,35 +732,27 @@ class InferenceEngineV2:
                 seq_desc = PlaceholderSequenceDescriptor()
             restore = self._restore_cost(uid, seq_desc)
             sched_len, sched_blocks = self._model.get_kv_requirements(
-                seq_desc, length, free_blocks - restore)
-            if sched_len != length:
+                seq_desc, steps or length, free_blocks - restore)
+            if sched_len != (steps or length):
                 return SchedulingResult.KVCacheLimitExceeded
-            batch_len += length
             free_blocks -= sched_blocks + restore
 
-        if cur_seqs > self._config.state_manager.max_tracked_sequences:
+        if cur_seqs > limits.max_tracked_sequences:
             return SchedulingResult.EngineSequenceLimitExceeded
-        if batch_len > self._config.state_manager.max_ragged_batch_size:
+        if batch_len > limits.max_ragged_batch_size:
             return SchedulingResult.BatchTokenLimitExceeded
         return SchedulingResult.Success
-
-    def get_remaining_block_capacity(self, uid: int) -> int:
-        seq_desc = self._state_manager.get_sequence(uid)
-        if seq_desc is None:
-            return 0
-        return self._model.get_remaining_block_capacity(seq_desc)
 
     def flush(self, uid: int) -> None:
         self._state_manager.flush_sequence(uid)
 
-    # ------------------------------------------------------------- kv offload --
-    def _restore_offloaded(self, batch_uids) -> None:
-        """Touching an offloaded sequence restores it first (ZeRO-Inference
-        KV-offload choreography; see ragged_manager.offload_sequence)."""
-        for uid in batch_uids:
-            if self._state_manager.is_offloaded(uid):
-                self._state_manager.restore_sequence(uid)
+    def cache_refusal(self, operation: str) -> Optional[Exception]:
+        """Why this engine's cache cannot share, move or roll back what
+        ``operation`` does (``ragged/kv_cache.py``: ``CACHE_OPERATIONS``), as
+        the error to raise; None where it can."""
+        return self._state_manager.kv_cache.refusal(operation)
 
+    # ------------------------------------------------------------- kv offload --
     def offload_sequence(self, uid: int) -> None:
         """Evict a cold sequence's KV blocks to the host (or NVMe, when
         ``state_manager.offload_path`` is set), freeing device blocks for
@@ -866,12 +803,12 @@ class InferenceEngineV2:
     # ---------------------------------------------------------- lowering hooks --
     def lowerable_callables(self) -> dict:
         """The engine's jitted device programs as raw ``jax.jit`` callables
-        (``.lower()``-able): ``forward`` keyed by ``(T, S, MB)`` pad bucket,
-        ``decode_loop`` keyed by ``(bucket, n_steps, sampled)`` and ``verify``
-        keyed by ``("verify", bucket, tree, greedy)``. This is the official hook for
-        HLO-level analysis (the deepspeed_tpu/perf/ gates); the jit-cache
-        entries themselves may be compile-watch wrappers shared with
-        telemetry and cannot lower."""
+        (``.lower()``-able), those that have run: ``forward`` keyed by ``(T, S,
+        MB)`` pad bucket, ``decode_loop`` by ``(bucket, n_steps, False)``,
+        ``verify`` by ``("verify", bucket, tree, greedy)`` and ``compact`` by
+        ``("compact", n_pairs)``. This is the official hook for HLO-level
+        analysis (the deepspeed_tpu/perf/ gates); what a step calls may be a
+        compile-watch wrapper shared with telemetry and cannot lower."""
         return self._model.lowerable_callables()
 
     def lower_forward(self, bucket=None):
@@ -879,10 +816,9 @@ class InferenceEngineV2:
         (default: the smallest bucket). Never executes."""
         return self._model.lower_forward(bucket)
 
-    def lower_decode_loop(self, n_steps: int, bucket=None, temperature: float = 0.0):
+    def lower_decode_loop(self, n_steps: int, bucket=None):
         """``jax.stages.Lowered`` of the on-device ``n_steps`` decode scan."""
-        return self._model.lower_decode_loop(n_steps, bucket=bucket,
-                                             temperature=temperature)
+        return self._model.lower_decode_loop(n_steps, bucket=bucket)
 
     def lower_verify(self, bucket=None, tree: bool = False, greedy: bool = False):
         """``jax.stages.Lowered`` of the speculative verify step (one ragged
@@ -894,11 +830,14 @@ class InferenceEngineV2:
     # -------------------------------------------------------------- empty_run --
     def empty_run(self) -> None:
         """Participate in EP collectives with zero live tokens (fork
-        engine_v2.py:308) — keeps idle replicas in lock-step with busy ones."""
+        engine_v2.py:308) — keeps idle replicas in lock-step with busy ones:
+        the smallest bucket's forward with every validity mask false."""
         metrics = self._resolve_tel_metrics()
         if metrics is not None:
             metrics["empty_runs"].inc()
-        self._model.empty_run()
+        self._batch.clear()
+        self._batch.finalize()
+        self._model.forward(self._batch)
 
     # -------------------------------------------------------------- serialize --
     def serialize(self, save_path: str) -> None:

@@ -279,6 +279,3 @@ class DeepseekV32V2Model(DSTransformerModelBase):
     def layer_forward(self, params, li, x, cache, attn_fn, batch):
         x, cache = self._attn_phase(params, li, x, cache, batch)
         return self._ffn_phase(params, li, x, batch), cache
-
-    def _tree_paged_attention(self, *args, **kwargs):
-        raise NotImplementedError("a latent KV group has no tree-verify attention")

@@ -304,12 +304,3 @@ class NemotronHV2Model(DSTransformerModelBase):
             with jax.named_scope("mlp"):
                 out = _relu2_mlp(h, lp["mixer"])
         return x + out.astype(x.dtype), (kv, *pools)
-
-    # -------------------------------------------------------------- refusals --
-    def forward_verify(self, ragged_batch, greedy: bool = False):
-        raise NotImplementedError(
-            "a speculative verify step over a per-sequence state group: a recurrent state "
-            "cannot be rolled back to an accepted prefix without a snapshot a draft")
-
-    def _tree_paged_attention(self, *args, **kwargs):
-        raise NotImplementedError("a per-sequence state group has no tree-verify attention")

@@ -47,14 +47,12 @@ class DSTransformerModelBase:
         self._config = config
         self._engine_config = engine_config
         self._state_manager = None
-        self._compiled = {}
-        self._lowerable = {}  # same keys, UNwrapped jit fns (perf-gate hook)
+        # (kind, key) -> [the jit, what a step calls: the jit or its
+        # compile-watch wrapper; None until the program runs] (``_program``)
+        self._programs = {}
         # the newest forward program's count of expert banks touched, int32
         # [expert layers] ON THE DEVICE (a bucket on the grouped path), else None
         self.last_moe_banks = None
-        # (float32(0), PRNGKey(0)) on the device: what every greedy
-        # ``decode_loop`` chunk passes for its temperature and key
-        self._greedy_sampler = None
         self._group_windows = None
         if state_manager is not None:
             self.set_state_manager(state_manager)
@@ -108,14 +106,13 @@ class DSTransformerModelBase:
             cache_dtype = "bfloat16"
         return KVCacheConfig(block_size=self._engine_config.kv_block_size,
                              num_allocation_groups=self.kv_groups,
+                             group_windows=self.group_windows,
                              cache_shape=(self.num_kv_layers, self.num_kv_heads, self.head_dim),
                              state_widths=self.kv_state_widths,
                              min_table_bucket=self.min_table_bucket,
                              cache_dtype=cache_dtype,
                              sequence_state=self.sequence_state,
-                             sequence_slots=sm.max_tracked_sequences if self.sequence_state else 0,
-                             max_blocks_per_allocation_group=(sm.max_context + self._engine_config.kv_block_size - 1)
-                             // self._engine_config.kv_block_size)
+                             sequence_slots=sm.max_tracked_sequences if self.sequence_state else 0)
 
     @property
     def kv_state_widths(self) -> Tuple[int, ...]:
@@ -193,10 +190,6 @@ class DSTransformerModelBase:
         capacity = (seq_desc.cur_allocated_blocks + max_new_entries) * bs - seq_desc.seen_tokens
         return max(0, capacity), max_new_entries * groups
 
-    def get_remaining_block_capacity(self, seq_desc: DSSequenceDescriptor) -> int:
-        bs = self._state_manager.kv_block_size
-        return seq_desc.cur_allocated_blocks * bs - seq_desc.seen_tokens
-
     def maybe_allocate_kv(self, seq_desc: DSSequenceDescriptor, n_new_tokens: int) -> None:
         sched, n_blocks = self.get_kv_requirements(seq_desc, n_new_tokens,
                                                    self._state_manager.free_blocks)
@@ -241,9 +234,6 @@ class DSTransformerModelBase:
         return min(whole, (window + feed - 1) // bs + 2)
 
     # ---------------------------------------------------------------- forward --
-    def prepare_batch(self, ragged_batch) -> None:
-        """Amortized pre-forward work (reference engine_v2.py prepare_batch)."""
-
     def forward(self, ragged_batch):
         """Run the ragged forward; returns logits [n_seqs, vocab] (one row per
         sequence — its final token), and updates the paged KV cache in place."""
@@ -269,7 +259,7 @@ class DSTransformerModelBase:
         ``[S_bucket, vocab]`` logits and the live sequence count."""
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
         bucket = self._bucket_of(batch)
-        fn = self._get_compiled(bucket)
+        fn = self._program("forward", bucket)
         cache = self._state_manager.kv_cache.cache
         tok_meta = batch["tok_meta"] if prev is None else sampling.chain(batch["tok_meta"], *prev)
         dev = {"tok_meta": tok_meta, "seq_meta": batch["seq_meta"]}
@@ -305,57 +295,55 @@ class DSTransformerModelBase:
             if chunk_steps > 1:
                 sampling.compiled_last_row(chunk_steps, rows)
 
-    def empty_run(self) -> None:
-        """Participate in collectives with zero live tokens (fork engine_v2.py:308).
-        Uses the smallest bucket with every validity mask false."""
-        from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import RaggedBatchWrapper
-        wrapper = RaggedBatchWrapper(self._engine_config.state_manager,
-                                     block_size=self._engine_config.kv_block_size,
-                                     num_groups=self.kv_groups,
-                                     min_table_bucket=self.min_table_bucket,
-                                     state_slots=self._state_manager.num_slots)
-        batch = wrapper.finalize()  # zero live sequences/tokens
-        dev = {"tok_meta": batch["tok_meta"], "seq_meta": batch["seq_meta"]}
-        fn = self._get_compiled(self._bucket_of(batch))
-        _, new_cache, *_ = fn(self._params, self._state_manager.kv_cache.cache, dev)
-        self._state_manager.kv_cache.set_cache(new_cache)
+    # ---------------------------------------------------------- the programs --
+    # kind -> (compile-watch site, the donated argument: the cache, the method
+    # traced, the entries of the key that are static arguments of it)
+    _PROGRAM_KINDS = {
+        "forward": ("inference_forward", 1, "_forward_impl", {}),
+        "decode_loop": ("inference_decode_loop", 1, "_decode_loop_impl", {"n_steps": 1}),
+        "verify": ("inference_verify", 1, "_verify_impl", {"greedy": 3}),
+        "compact": ("inference_kv_compact", 0, "_compact_impl", {})}
 
-    def _get_compiled(self, bucket):
-        import jax
-        if bucket not in self._compiled:
-            fn = jax.jit(self._forward_impl, donate_argnums=(1, ))
-            self._lowerable[bucket] = fn
+    def _program(self, kind, key, run=True):
+        """The jitted program of ``kind`` at ``key``: ``forward`` at a ``(T, S,
+        MB)`` bucket, ``decode_loop`` at :meth:`_loop_key`, ``verify`` at
+        :meth:`_verify_key`, ``compact`` at ``("compact", n_pairs)``. The one
+        place that makes a ``jax.jit`` and keeps it. A step (``run``) is
+        handed what it calls: at its first, the jit is wrapped for the compile
+        watch, which attributes the key's XLA compile (and any later internal
+        recompile) to the kind's site in the compile_* metrics and the trace.
+        ``run=False`` hands out the jit itself, which can ``.lower()`` as the
+        wrapper cannot, and leaves the watch alone: lowering a program that
+        never ran is analysis, not a cache entry."""
+        entry = self._programs.get((kind, key))
+        if entry is None:
+            import jax
+            _, donated, impl, static = self._PROGRAM_KINDS[kind]
+            impl = getattr(self, impl)
+            if static:
+                impl = partial(impl, **{name: key[i] for name, i in static.items()})
+            entry = self._programs[kind, key] = [jax.jit(impl, donate_argnums=(donated, )), None]
+        if not run:
+            return entry[0]
+        if entry[1] is None:
             cw = compile_watch.get()
-            if cw is not None:
-                # attribute the bucket's XLA compile (and any later internal
-                # recompile) to this site in the compile_* metrics and trace
-                fn = cw.wrap("inference_forward", bucket, fn)
-            self._compiled[bucket] = fn
-        return self._compiled[bucket]
-
-    # -------------------------------------------------------- lowering hooks --
-    @staticmethod
-    def _lowerable_kind(key) -> str:
-        """Program-kind classification of a ``_compiled``/``_lowerable`` jit
-        cache key: ``(T, S, MB)`` int tuples are forward programs,
-        ``(bucket, n_steps, sampled)`` are decode loops, and a tuple with a
-        string head is named after that head (``verify``, ``compact``)."""
-        if isinstance(key[0], str):
-            return key[0]
-        return "decode_loop" if isinstance(key[0], tuple) else "forward"
+            entry[1] = entry[0] if cw is None else cw.wrap(self._PROGRAM_KINDS[kind][0], key,
+                                                           entry[0])
+        return entry[1]
 
     def lowerable_callables(self):
-        """Raw ``jax.jit`` callables (they support ``.lower()``) grouped by
-        program kind and keyed exactly like ``_compiled``: forward programs by
-        ``(T, S, MB)`` bucket, decode programs by ``(bucket, n_steps,
-        sampled)``, the speculative verify step by ``("verify", bucket,
-        tree, greedy)`` and the accepted-path KV re-pack by ``("compact",
-        n_pairs)``. The official hook for HLO-level analysis
-        (deepspeed_tpu/perf/) — the entries in ``_compiled`` may be
-        compile-watch wrappers, which cannot lower."""
-        out = {"forward": {}, "decode_loop": {}, "verify": {}}
-        for k, v in self._lowerable.items():
-            out.setdefault(self._lowerable_kind(k), {})[k] = v
+        """The programs that have run, as raw ``jax.jit`` callables (they
+        support ``.lower()``), by kind and under their cache keys: ``forward``
+        by ``(T, S, MB)`` bucket, ``decode_loop`` by ``(bucket, n_steps,
+        False)``, ``verify`` (the speculative verify step) by ``("verify",
+        bucket, tree, greedy)`` and ``compact`` (the accepted-path KV re-pack)
+        by ``("compact", n_pairs)``. The official hook for HLO-level analysis
+        (deepspeed_tpu/perf/): what a step calls may be a compile-watch
+        wrapper, which cannot lower."""
+        out = {kind: {} for kind in self._PROGRAM_KINDS}
+        for (kind, key), (fn, called) in self._programs.items():
+            if called is not None:
+                out[kind][key] = fn
         return out
 
     def _synthetic_batch(self, bucket=None):
@@ -378,28 +366,18 @@ class DSTransformerModelBase:
     def lower_forward(self, bucket=None):
         """Lower the ragged forward at ``bucket`` (``(T, S, MB)``; default
         smallest) against the live params + paged KV cache and return the
-        ``jax.stages.Lowered``. Never executes; the program is the same
+        ``jax.stages.Lowered``. Never executes; the program is the
         ``_forward_impl`` jit :meth:`forward` runs for that bucket."""
-        import jax
         dev = self._synthetic_batch(bucket)
-        key = self._bucket_of(dev)
-        # reuse the engine's own jit entry when the bucket has run already
-        fn = self._lowerable.get(key) or jax.jit(self._forward_impl, donate_argnums=(1, ))
-        return fn.lower(self._params, self._state_manager.kv_cache.cache, dev)
+        return self._program("forward", self._bucket_of(dev), run=False).lower(
+            self._params, self._state_manager.kv_cache.cache, dev)
 
-    def lower_decode_loop(self, n_steps: int, bucket=None, temperature: float = 0.0):
-        """Lower the ``n_steps`` on-device decode program (same
-        ``_decode_loop_impl`` jit as :meth:`decode_loop`)."""
-        import jax
-        import jax.numpy as jnp
+    def lower_decode_loop(self, n_steps: int, bucket=None):
+        """Lower the ``n_steps`` on-device decode program (the
+        ``_decode_loop_impl`` jit :meth:`decode_loop` runs)."""
         dev = self._synthetic_batch(bucket)
-        key = (self._bucket_of(dev), int(n_steps), temperature > 0)
-        fn = self._lowerable.get(key) or jax.jit(
-            partial(self._decode_loop_impl, n_steps=int(n_steps),
-                    sampled=temperature > 0),
-            donate_argnums=(1, ))
-        return fn.lower(self._params, self._state_manager.kv_cache.cache, dev,
-                        jnp.float32(temperature), jax.random.PRNGKey(0))
+        return self._program("decode_loop", self._loop_key(dev, n_steps), run=False).lower(
+            self._params, self._state_manager.kv_cache.cache, dev)
 
     def lower_verify(self, bucket=None, tree: bool = False, greedy: bool = False):
         """Lower the speculative verify step at ``bucket`` (default smallest)
@@ -413,16 +391,14 @@ class DSTransformerModelBase:
             T = dev["tok_meta"].shape[1]
             dev["tree_meta"] = np.stack([np.arange(-1, T - 1, dtype=np.int32),
                                          np.arange(T, dtype=np.int32)])
-        fn = self._verify_jit(self._verify_key(dev, greedy))
-        return fn.lower(self._params, self._state_manager.kv_cache.cache, dev)
+        return self._program("verify", self._verify_key(dev, greedy), run=False).lower(
+            self._params, self._state_manager.kv_cache.cache, dev)
 
     # ------------------------------------------------------------ decode loop --
-    def decode_loop(self, ragged_batch, n_steps: int, temperature: float = 0.0,
-                    rng=None, prev=None):
-        """Decode ``n_steps`` tokens per sequence in ONE device program —
-        greedy argmax at ``temperature`` 0, categorical sampling otherwise
-        (``rng`` folded per step; REQUIRED when sampling — a silent fixed
-        default would make "sampling" deterministic across calls).
+    def decode_loop(self, ragged_batch, n_steps: int, prev=None):
+        """Decode ``n_steps`` tokens per sequence in ONE device program, each
+        the argmax of its logits (a sampled request is drawn at its own
+        ``(seed, draw_index)`` by :meth:`forward_draw`, a step at a time).
 
         The host-loop decode (one ``put`` per generated token) pays a full
         host→device dispatch round-trip and a logits transfer per token. This
@@ -444,42 +420,22 @@ class DSTransformerModelBase:
         input id is ``ids[src[t]]``, still on the device, wherever
         ``src[t] >= 0`` (``sampling.chain`` in front of the same program).
         """
-        import jax
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
-        bucket = self._bucket_of(batch)
-        temperature = float(temperature)
-        key = (bucket, int(n_steps), temperature > 0)
-        if key not in self._compiled:
-            fn = jax.jit(
-                partial(self._decode_loop_impl, n_steps=int(n_steps),
-                        sampled=temperature > 0),
-                donate_argnums=(1, ))
-            self._lowerable[key] = fn
-            cw = compile_watch.get()
-            if cw is not None:
-                fn = cw.wrap("inference_decode_loop", key, fn)
-            self._compiled[key] = fn
-        cache = self._state_manager.kv_cache.cache
-        if temperature > 0 and rng is None:
-            raise ValueError("decode_loop(temperature>0) requires an rng key — a fixed "
-                             "default would return identical 'samples' every call")
-        if temperature > 0:
-            sampler = (jax.numpy.float32(temperature), rng)
-        else:
-            # a greedy chunk's temperature and the key it carries untouched:
-            # put on the device once, not as two programs ahead of every chunk
-            if self._greedy_sampler is None:
-                self._greedy_sampler = (jax.numpy.float32(0.0), jax.random.PRNGKey(0))
-            zero, kept = self._greedy_sampler
-            sampler = (zero, kept if rng is None else rng)
+        fn = self._program("decode_loop", self._loop_key(batch, n_steps))
         tok_meta = batch["tok_meta"] if prev is None else sampling.chain(batch["tok_meta"], *prev)
-        tokens, new_cache, *banks = self._compiled[key](
-            self._params, cache, {"tok_meta": tok_meta, "seq_meta": batch["seq_meta"]}, *sampler)
+        tokens, new_cache, *banks = fn(self._params, self._state_manager.kv_cache.cache,
+                                       {"tok_meta": tok_meta, "seq_meta": batch["seq_meta"]})
         self._state_manager.kv_cache.set_cache(new_cache)
         return tokens, (banks[0] if banks else None)
 
-    def _decode_loop_impl(self, params, cache, batch, temperature, rng, *, n_steps,
-                          sampled=False):
+    def _loop_key(self, batch, n_steps):
+        """``(bucket, n_steps, False)``. The constant is the benchmark's: its
+        warm-up unpacks three entries and compares keys it guesses with these
+        (``benchmark/runners/serve.py``; it said ``sampled`` while the loop
+        could draw)."""
+        return (self._bucket_of(batch), int(n_steps), False)
+
+    def _decode_loop_impl(self, params, cache, batch, *, n_steps):
         import jax
         import jax.numpy as jnp
 
@@ -487,29 +443,23 @@ class DSTransformerModelBase:
         seq_meta = jnp.asarray(batch["seq_meta"])
 
         def step(carry, _):
-            cache, tok_meta, seq_meta, r = carry
+            cache, tok_meta, seq_meta = carry
             # banks: the forward's count of expert banks touched, where it has one
             logits, cache, *banks = self._forward_impl(
                 params, cache, {"tok_meta": tok_meta, "seq_meta": seq_meta,
                                 "one_token_rows": True})
-            if sampled:
-                r, sub = jax.random.split(r)
-                next_ids = jax.random.categorical(
-                    sub, logits / jnp.maximum(temperature, 1e-6), axis=-1).astype(jnp.int32)
-            else:  # greedy: the key is carried untouched (no dead per-step split)
-                next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S]
+            next_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S]
             tv = tok_meta[3] > 0
             # decode batches carry one token per sequence: slot i ↔ sequence i
             new_ids = jnp.where(tv, next_ids[tok_meta[1]], tok_meta[0])
             tok_meta = tok_meta.at[0].set(new_ids).at[2].add(tv.astype(tok_meta.dtype))
             sv = (seq_meta[:, 3] > 0).astype(seq_meta.dtype)
             seq_meta = seq_meta.at[:, 0].add(sv)
-            return (cache, tok_meta, seq_meta, r), (next_ids, *banks)
+            return (cache, tok_meta, seq_meta), (next_ids, *banks)
 
-        # static per-compile sampling flag rides on the jit-cache key; the scan
-        # stacks the steps' tokens and, beside them, their bank counts
-        (cache, _, _, _), (tokens, *banks) = jax.lax.scan(
-            step, (cache, tok_meta, seq_meta, rng), None, length=n_steps)
+        # the scan stacks the steps' tokens and, beside them, their bank counts
+        (cache, _, _), (tokens, *banks) = jax.lax.scan(
+            step, (cache, tok_meta, seq_meta), None, length=n_steps)
         return (tokens, cache, *banks)
 
     @property
@@ -569,13 +519,6 @@ class DSTransformerModelBase:
         verify program at a bucket are named in its key."""
         return ("verify", self._bucket_of(dev), "tree_meta" in dev, bool(greedy))
 
-    def _verify_jit(self, key):
-        """The raw jit of the verify program ``key`` names: the engine's own
-        entry when that program has run, else a fresh one."""
-        import jax
-        return self._lowerable.get(key) or jax.jit(
-            partial(self._verify_impl, greedy=key[3]), donate_argnums=(1, ))
-
     def forward_verify(self, ragged_batch, greedy: bool = False):
         """The speculative verify step: the layer compute of :meth:`forward`
         with EVERY fed position unembedded, so one ragged pass prices each
@@ -597,15 +540,8 @@ class DSTransformerModelBase:
         rest (``engine_v2.compact_accepted``)."""
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
         dev = {k: batch[k] for k in ("tok_meta", "seq_meta", "tree_meta") if k in batch}
-        key = self._verify_key(dev, greedy)
-        if key not in self._compiled:
-            fn = self._lowerable[key] = self._verify_jit(key)
-            cw = compile_watch.get()
-            if cw is not None:
-                fn = cw.wrap("inference_verify", key, fn)
-            self._compiled[key] = fn
-        out, hidden, new_cache = self._compiled[key](
-            self._params, self._state_manager.kv_cache.cache, dev)
+        fn = self._program("verify", self._verify_key(dev, greedy))
+        out, hidden, new_cache = fn(self._params, self._state_manager.kv_cache.cache, dev)
         self._state_manager.kv_cache.set_cache(new_cache)
         return out, hidden
 
@@ -774,6 +710,29 @@ class DSTransformerModelBase:
             cache = cache.at[li, 0, blk_ids, :, offs].set(k_new.astype(cache.dtype), mode="drop")
             return cache.at[li, 1, blk_ids, :, offs].set(v_new.astype(cache.dtype), mode="drop")
 
+    def _gather_history(self, cache, li, table, dtype):
+        """Each sequence's keys and values of cache layer ``li``, gathered
+        through its block ``table`` (released entries read block 0: the
+        caller masks them): two ``[S, MB * bs, H, D]`` arrays of ``dtype``,
+        the KV heads repeated to the query heads'."""
+        import jax.numpy as jnp
+
+        S, MB = table.shape
+        KVH, D = self.num_kv_heads, self.head_dim
+        table = jnp.maximum(table, 0)  # [S, MB]
+        k_hist = cache[li, 0][table]  # [S, MB, KVH, bs, D]
+        v_hist = cache[li, 1][table]
+        KV = MB * cache.shape[4]
+        k_hist = k_hist.transpose(0, 2, 1, 3, 4).reshape(S, KVH, KV, D) \
+            .transpose(0, 2, 1, 3).astype(dtype)
+        v_hist = v_hist.transpose(0, 2, 1, 3, 4).reshape(S, KVH, KV, D) \
+            .transpose(0, 2, 1, 3).astype(dtype)
+        if KVH != self.num_heads:  # GQA
+            rep = self.num_heads // KVH
+            k_hist = jnp.repeat(k_hist, rep, axis=2)
+            v_hist = jnp.repeat(v_hist, rep, axis=2)
+        return k_hist, v_hist
+
     def _gather_attention(self, q, cache, li, batch, table, window):
         """The XLA arm: gather each sequence's history from the layer's block
         ``table`` and attend densely under its ``window``. q: [T, H, D];
@@ -781,27 +740,13 @@ class DSTransformerModelBase:
         import jax
         import jax.numpy as jnp
 
-        S, MB = table.shape
-        bs = cache.shape[4]
+        S = table.shape[0]
         H, D = self.num_heads, self.head_dim
-        KVH = self.num_kv_heads
         token_seq = batch["token_seq"]
         token_pos = batch["token_pos"]
         token_valid = batch["token_valid"]
-
-        # --- gather per-sequence history (XLA fallback) ----------------------
-        table = jnp.maximum(table, 0)  # [S, MB]
-        k_hist = cache[li, 0][table]  # [S, MB, KVH, bs, D]
-        v_hist = cache[li, 1][table]
-        KV = MB * bs
-        k_hist = k_hist.transpose(0, 2, 1, 3, 4).reshape(S, KVH, KV, D) \
-            .transpose(0, 2, 1, 3).astype(q.dtype)
-        v_hist = v_hist.transpose(0, 2, 1, 3, 4).reshape(S, KVH, KV, D) \
-            .transpose(0, 2, 1, 3).astype(q.dtype)
-        if KVH != H:  # GQA
-            rep = H // KVH
-            k_hist = jnp.repeat(k_hist, rep, axis=2)
-            v_hist = jnp.repeat(v_hist, rep, axis=2)
+        k_hist, v_hist = self._gather_history(cache, li, table, q.dtype)
+        KV = k_hist.shape[1]
 
         # --- densify queries per sequence ------------------------------------
         local_q = token_pos - batch["seq_seen"][token_seq]
@@ -853,30 +798,16 @@ class DSTransformerModelBase:
         T = q.shape[0]
         window = self.attention_window_of(li)
         table, li = self._kv_view(batch, li)
-        S, MB = table.shape
-        bs = cache.shape[4]
+        S = table.shape[0]
         H, D = self.num_heads, self.head_dim
-        KVH = self.num_kv_heads
 
         token_seq = batch["token_seq"]
         token_valid = batch["token_valid"]
 
         # --- scatter new kv at slot positions --------------------------------
         cache = self._kv_write(cache, li, k_new, v_new, slot_pos, batch, table)
-
-        # --- gather per-sequence history -------------------------------------
-        table = jnp.maximum(table, 0)  # [S, MB]
-        k_hist = cache[li, 0][table]
-        v_hist = cache[li, 1][table]
-        KV = MB * bs
-        k_hist = k_hist.transpose(0, 2, 1, 3, 4).reshape(S, KVH, KV, D) \
-            .transpose(0, 2, 1, 3).astype(q.dtype)
-        v_hist = v_hist.transpose(0, 2, 1, 3, 4).reshape(S, KVH, KV, D) \
-            .transpose(0, 2, 1, 3).astype(q.dtype)
-        if KVH != H:  # GQA
-            rep = H // KVH
-            k_hist = jnp.repeat(k_hist, rep, axis=2)
-            v_hist = jnp.repeat(v_hist, rep, axis=2)
+        k_hist, v_hist = self._gather_history(cache, li, table, q.dtype)
+        KV = k_hist.shape[1]
 
         # --- densify queries + tree metadata per sequence --------------------
         local_q = slot_pos - batch["seq_seen"][token_seq]  # node index in feed
@@ -953,17 +884,9 @@ class DSTransformerModelBase:
         The gather reads the pre-copy cache, so overlapping src/dst pairs are
         safe. Jitted per pow2-padded copy count; padded pairs scatter to an
         out-of-range block and drop."""
-        import jax
         from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import _pow2_pad
 
-        if self.kv_state_widths:
-            raise NotImplementedError("compact_kv (a tree-verify re-pack) is written for the K/V "
-                                      "array: a latent KV group has no speculative verify")
-        if self.sequence_state:
-            raise NotImplementedError(
-                "compact_kv (a tree-verify re-pack): a per-sequence state group has no "
-                "speculative verify — a recurrent state cannot be rolled back to an accepted "
-                "prefix without a snapshot a draft")
+        self._state_manager.kv_cache.refuse("compact_kv")
         src = np.asarray(src_slots, np.int64).reshape(-1)
         dst = np.asarray(dst_slots, np.int64).reshape(-1)
         if src.size != dst.size:
@@ -987,16 +910,8 @@ class DSTransformerModelBase:
         dst_blk[:n] = tables[:, dst // bs].reshape(-1)
         dst_off[:n] = np.tile(dst % bs, tables.shape[0])
 
-        key = ("compact", P)
-        if key not in self._compiled:
-            fn = jax.jit(self._compact_impl, donate_argnums=(0, ))
-            self._lowerable[key] = fn
-            cw = compile_watch.get()
-            if cw is not None:
-                fn = cw.wrap("inference_kv_compact", key, fn)
-            self._compiled[key] = fn
-        new_cache = self._compiled[key](self._state_manager.kv_cache.cache,
-                                        src_blk, src_off, dst_blk, dst_off)
+        new_cache = self._program("compact", ("compact", P))(
+            self._state_manager.kv_cache.cache, src_blk, src_off, dst_blk, dst_off)
         self._state_manager.kv_cache.set_cache(new_cache)
 
     @staticmethod

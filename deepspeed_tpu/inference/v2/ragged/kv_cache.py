@@ -26,8 +26,8 @@ token's state a layer is a row of each stated width, not K and V of heads. The
 cache is then a TUPLE of pools ``[layers, num_blocks, block_size, width]``, one
 a width, that the one allocator's block ids address alike; a block id's bytes
 are ``block_size x layers x sum(widths)`` values. What moves block CONTENTS
-(fork, offload / restore, handoff frames) is written for the K/V array and
-refuses a latent group by name.
+(fork, offload / restore, handoff frames) is written for the K/V array
+(``CACHE_OPERATIONS``).
 
 A per-SEQUENCE state group (``KVCacheConfig.sequence_state``, a model with
 state-space layers): layers whose state is one array a live sequence whatever
@@ -37,8 +37,7 @@ A SLOT is what a block is to the K/V array: handed out by an allocator of the
 same kind whose unit is a sequence (``reserve_slot`` / ``free_slot``), at the
 sequence's first token and until its flush. The K/V array holds the layers that
 keep K/V (``cache_shape[0]``), which such a model counts apart from its blocks.
-What moves block contents refuses a per-sequence state group by name: a slot is
-in no block table.
+A slot is in no block table (``CACHE_OPERATIONS``: what such a cache refuses).
 """
 
 import os
@@ -73,6 +72,54 @@ def _cache_sharding(kv_heads: int):
     return NamedSharding(mesh, P(None, None, None, heads_axis, None, None))
 
 
+# What SHARES a sequence's blocks (a prefix hit, its copy-on-write), MOVES them
+# (offload / restore and the tier ladder, handoff / park / resume frames, export
+# / import) or ROLLS tokens BACK (a speculative verify step, its re-pack, a
+# rollback), and the kinds of cache that refuse each: operation -> (class, its
+# name in a refusal where that says more than the key, kinds). A kind an
+# operation does not name it serves, or leaves to the operation under it
+# (``offload_sequence`` a latent group to ``gather_blocks``) or to its own look
+# at the one sequence (``export_sequence`` and ``rollback`` under a window: the
+# sequence may not have released anything yet; ``export_sequence`` of several
+# tables: ``DSSequenceDescriptor.kv_blocks`` reads one, where there are blocks).
+CACHE_OPERATIONS = {
+    # what a deployment's configuration or a request asks for: theirs to fix
+    "prefix_cache": ("share", "", "window latent slots"),
+    "kv_tiers": ("move", "", "window latent slots"),
+    "speculative": ("rollback", "", "slots"),
+    "frames": ("move", "handoff, park and resume frames", "window slots"),
+    # what a caller of the state manager, the pool, the engine or the model does
+    "create_cached_sequence": ("share", "create_cached_sequence (a prefix-cache hit)",
+                               "tables slots"),
+    "fork_blocks": ("share", "fork_blocks (a prefix-cache copy-on-write)", "latent slots"),
+    "offload_sequence": ("move", "", "slots"),
+    "export_sequence": ("move", "export_sequence (a handoff or park frame)", "slots"),
+    "import_sequence": ("move", "", "tables slots"),
+    "gather_blocks": ("move", "gather_blocks (offload, a handoff or park frame)",
+                      "latent slots"),
+    "scatter_blocks": ("move", "scatter_blocks (restore, an imported frame)", "latent slots"),
+    "verify_tree": ("rollback", "", "slots"),
+    "compact_kv": ("rollback", "compact_kv (a tree-verify re-pack)", "latent slots"),
+    "rollback": ("rollback", "", "slots"),
+}
+_ASKED = ("prefix_cache", "kv_tiers", "speculative", "frames")
+# kind -> (what the cache is and why it refuses, the error of a call that runs into it)
+_CACHE_KINDS = {
+    "window": ("a sliding-window model (a layer's attention window is {}): its sequences release "
+               "the blocks their window has passed, and what shares or moves a sequence carries "
+               "its whole block table", ValueError),
+    "tables": ("a model that keeps {} block tables a sequence (KV layer groups, some with a "
+               "sliding window): a shared or exported table cannot stand for them", ValueError),
+    "latent": ("a latent KV group (rows of widths {} a token a layer, not K and V of heads): what "
+               "shares, moves or re-packs block contents is written for the K/V array, and a "
+               "latent group has no speculative verify", NotImplementedError),
+    "slots": ("a per-sequence state group ({}: a recurrent state a sequence, in a slot and in no "
+              "block table): shared blocks, a moved table or a rolled-back draft would leave the "
+              "slot's state behind, and it cannot be wound back without a snapshot a draft",
+              NotImplementedError),
+}
+
+
 class _LazyAIO:
     """Spill-file I/O for the tiered store that defers to the cache's AIO
     engine — built lazily so a cache that never spills never imports
@@ -90,8 +137,8 @@ class _LazyAIO:
 
 class BlockedKVCache:
 
-    def __init__(self, config: KVCacheConfig, memory_config: MemoryConfig, mp_group=None,
-                 offload: bool = False, offload_path: Optional[str] = None):
+    def __init__(self, config: KVCacheConfig, memory_config: MemoryConfig,
+                 offload_path: Optional[str] = None):
         import jax
         import jax.numpy as jnp
 
@@ -229,19 +276,32 @@ class BlockedKVCache:
     def free_slot(self, slot: int) -> None:
         self._slots.free([slot])
 
-    def _kv_pairs_only(self, what: str) -> None:
-        if self._slots is not None:
-            raise NotImplementedError(
-                f"{what}: this cache has a per-sequence state group "
-                f"({[spec.name for spec in self._config.sequence_state]}: one slot a sequence, "
-                f"in no block table); what moves block contents is written for the K/V array "
-                f"alone and would leave the slot behind — recompute the sequence instead")
-        if self._config.state_widths:
-            raise NotImplementedError(
-                f"{what}: this cache is a latent group (rows of widths "
-                f"{tuple(self._config.state_widths)} a token a layer, not K and V of heads); "
-                f"what moves block contents is written for the K/V array — recompute the "
-                f"sequence instead")
+    def refusal(self, operation: str) -> Optional[Exception]:
+        """Why this cache cannot serve ``operation`` (a name of
+        ``CACHE_OPERATIONS``), as the error to raise, or None where it can.
+        Read off what the cache holds: a window over some group (holes in a
+        table), more tables than one a sequence, latent rows, slots. A
+        configuration's or a request's refusal is a ``ValueError``; a call's is
+        its kind's."""
+        config = self._config
+        _, said, kinds = CACHE_OPERATIONS[operation]
+        mine = {"window": max(config.group_windows),
+                "tables": config.num_allocation_groups > 1 and config.num_allocation_groups,
+                "latent": tuple(config.state_widths),
+                "slots": [spec.name for spec in config.sequence_state]}
+        for kind in kinds.split():
+            if mine[kind]:
+                is_a, error = _CACHE_KINDS[kind]
+                return (ValueError if operation in _ASKED else error)(
+                    f"{said or operation} cannot serve {is_a.format(mine[kind])} — recompute the "
+                    f"sequence instead")
+        return None
+
+    def refuse(self, operation: str) -> None:
+        """Raise :meth:`refusal`'s answer, where it has one."""
+        error = self.refusal(operation)
+        if error is not None:
+            raise error
 
     @property
     def sharding(self):
@@ -276,7 +336,7 @@ class BlockedKVCache:
         import jax
         import jax.numpy as jnp
 
-        self._kv_pairs_only("fork_blocks (a prefix-cache copy-on-write)")
+        self.refuse("fork_blocks")
         src_blocks = np.atleast_1d(np.asarray(src_blocks)).astype(np.int64)
         new_blocks = self._allocator.allocate(src_blocks.size)
         if self._fork_fn is None:
@@ -301,7 +361,7 @@ class BlockedKVCache:
         import jax
         import jax.numpy as jnp
 
-        self._kv_pairs_only("gather_blocks (offload, a handoff or park frame)")
+        self.refuse("gather_blocks")
         blocks = np.atleast_1d(np.asarray(blocks)).astype(np.int64)
         return np.asarray(jax.device_get(self._cache[:, :, jnp.asarray(blocks)]))
 
@@ -312,7 +372,7 @@ class BlockedKVCache:
         the new block ids — the write half of :meth:`restore`, reused by the
         fleet KV-handoff importer. A failed allocation or write consumes
         nothing."""
-        self._kv_pairs_only("scatter_blocks (restore, an imported frame)")
+        self.refuse("scatter_blocks")
         data = np.asarray(data)
         _, kv_heads, head_dim = self._config.cache_shape
         num_layers = self._layers_per_group

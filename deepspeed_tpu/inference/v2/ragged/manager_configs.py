@@ -32,6 +32,10 @@ class KVCacheConfig(DeepSpeedConfigModel):
     # li // groups, so a block id holds num_layers / groups layers of ONE group
     # (kv_cache.py). One group is one table for every layer.
     num_allocation_groups: int = Field(1, gt=0)
+    # each group's sliding attention window in tokens (0: it keeps every key).
+    # The model's to say, beside its groups: under a window a sequence gives
+    # blocks back as it grows, and its table has holes (kv_cache.py, ``refusal``)
+    group_windows: Tuple[int, ...] = (0, )
     # (layers that keep K/V, num_heads, head_size): the first is the count of
     # layers with a row a token, not of the model's blocks
     cache_shape: Tuple[int, int, int] = (0, 0, 0)
@@ -53,7 +57,6 @@ class KVCacheConfig(DeepSpeedConfigModel):
     # Empty = every layer's state is a row a token.
     sequence_state: Tuple[SequenceStateSpec, ...] = ()
     sequence_slots: int = Field(0, ge=0)
-    max_blocks_per_allocation_group: int = Field(0, ge=0)
 
 
 class MemoryConfig(DeepSpeedConfigModel):
@@ -67,7 +70,6 @@ class DSStateManagerConfig(DeepSpeedConfigModel):
     max_ragged_sequence_count: int = Field(512, gt=0)
     max_context: int = Field(8192, gt=0)
     memory_config: MemoryConfig = MemoryConfig()
-    offload: bool = Field(False)
     # spill offloaded KV blocks to files under this dir (NVMe tier, via the
     # native AIO engine) instead of holding them in host memory
     offload_path: Optional[str] = None

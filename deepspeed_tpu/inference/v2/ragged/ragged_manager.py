@@ -16,13 +16,12 @@ from deepspeed_tpu.utils.logging import logger
 
 class DSStateManager:
 
-    def __init__(self, config: DSStateManagerConfig, kv_config: KVCacheConfig, mp_group=None):
+    def __init__(self, config: DSStateManagerConfig, kv_config: KVCacheConfig):
         self._config = config
         self._kv_config = kv_config
         self._seqs: Dict[int, DSSequenceDescriptor] = {}
         self._offloaded: Dict[int, int] = {}  # uid -> host-pool handle
-        self._kv_cache = BlockedKVCache(kv_config, config.memory_config, mp_group=mp_group,
-                                        offload=config.offload,
+        self._kv_cache = BlockedKVCache(kv_config, config.memory_config,
                                         offload_path=config.offload_path)
 
     # ------------------------------------------------------------- sequences --
@@ -58,8 +57,7 @@ class DSStateManager:
         one reference per block on this sequence's behalf, which
         ``flush_sequence`` returns). The next forward continues at position
         ``seen_tokens`` exactly like a restored or imported sequence."""
-        self._one_table_only("create_cached_sequence (a prefix-cache hit)")
-        self._no_sequence_state("create_cached_sequence (a prefix-cache hit)")
+        self._kv_cache.refuse("create_cached_sequence")
         blocks = np.atleast_1d(np.asarray(blocks)).astype(np.int64)
         seen_tokens = int(seen_tokens)
         if seen_tokens < 0 or seen_tokens > blocks.size * self._kv_config.block_size:
@@ -76,22 +74,6 @@ class DSStateManager:
             del self._seqs[uid]  # the caller still owns the block references
             raise
         return seq
-
-    def _one_table_only(self, what: str) -> None:
-        """Shared prefixes and handoff frames carry ONE block table a sequence."""
-        if self.num_groups != 1:
-            raise ValueError(f"{what}: this model keeps {self.num_groups} block tables a "
-                             f"sequence (KV layer groups, some with a sliding window); a "
-                             f"shared or exported table cannot stand for them — recompute "
-                             f"the sequence instead")
-
-    def _no_sequence_state(self, what: str) -> None:
-        """A block table says nothing of a per-sequence state group's slot."""
-        if self._kv_cache.num_slots:
-            raise NotImplementedError(
-                f"{what}: this model keeps a per-sequence state group (a slot a sequence, in "
-                f"no block table): shared blocks, an offloaded table or an exported frame "
-                f"would leave the slot's state behind — recompute the sequence instead")
 
     def flush_sequence(self, uid: int) -> None:
         """Release all state for a sequence (reference ragged_manager.py:110)."""
@@ -143,7 +125,7 @@ class DSStateManager:
         """Evict a (cold) sequence's KV blocks to the host tier, freeing its
         device blocks for other sequences. The sequence stays tracked; the
         next forward that touches it restores it (engine put/decode_loop)."""
-        self._no_sequence_state("offload_sequence")
+        self._kv_cache.refuse("offload_sequence")
         seq = self._seqs.get(uid)
         if seq is None:
             raise ValueError(f"offload_sequence: unknown uid {uid}")
@@ -196,7 +178,7 @@ class DSStateManager:
         payload is already host-side, but export must observe one canonical
         path). The sequence stays tracked and resident here; the caller
         flushes once the recipient has taken over."""
-        self._no_sequence_state("export_sequence (a handoff or park frame)")
+        self._kv_cache.refuse("export_sequence")
         seq = self._seqs.get(uid)
         if seq is None:
             raise ValueError(f"export_sequence: unknown uid {uid}")
@@ -220,8 +202,7 @@ class DSStateManager:
         count restored. Raises without consuming anything when the uid is
         already tracked, the payload's geometry doesn't fit this cache, or
         the device pool can't hold it (evict and retry)."""
-        self._one_table_only("import_sequence")
-        self._no_sequence_state("import_sequence")
+        self._kv_cache.refuse("import_sequence")
         uid = int(snapshot["uid"] if uid is None else uid)
         if uid in self._seqs:
             raise ValueError(f"import_sequence: uid {uid} already tracked")

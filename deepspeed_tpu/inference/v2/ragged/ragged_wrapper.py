@@ -227,5 +227,3 @@ class RaggedBatchWrapper:
         assert self._device_batch is not None, "finalize() the batch first"
         return self._device_batch
 
-    def masked_input_ids(self) -> np.ndarray:
-        return self.device_batch["tok_meta"][0, :self.current_tokens]

@@ -103,9 +103,6 @@ class DSSequenceDescriptor:
         return np.asarray([b for t, r in zip(self._kv_blocks, self._released) for b in t[r:]],
                           dtype=np.int64)
 
-    def kv_cache_ids(self, on_device: bool = False) -> np.ndarray:
-        return self.kv_blocks
-
     def extend_kv_cache(self, new_blocks) -> None:
         """Append ``new_blocks`` — ``num_groups`` x n ids, group-major (a flat
         list of n for a one-group sequence): n more entries in every table."""

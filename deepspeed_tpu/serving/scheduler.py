@@ -345,38 +345,13 @@ class ServingScheduler:
         # mutation happens on the scheduler thread — the same thread that owns
         # every other engine touch.
         self._prefix_cache = None
-        # a sliding-window model releases KV blocks as its window passes them
-        # (transformer_base.maybe_free_kv); what needs a sequence's whole block
-        # table refuses here, or at submission, instead of serving wrong keys
-        # (some layer has a window: the others keep every block, that one does not)
-        self._window = max(getattr(getattr(engine, "model", None), "group_windows", (0, )))
-        if self._window and (self._config.prefix_cache.enabled or self._config.kv_tiers.enabled):
-            raise ValueError(
-                f"prefix_cache / kv_tiers cannot serve a sliding-window model (a layer's "
-                f"attention window is {self._window}): the trie shares and the tier ladder "
-                f"moves whole block tables, and this model's sequences release the blocks "
-                f"their window has passed. Turn both off for this model.")
-        widths = getattr(getattr(engine, "model", None), "kv_state_widths", ())
-        if widths and (self._config.prefix_cache.enabled or self._config.kv_tiers.enabled):
-            raise ValueError(
-                f"prefix_cache / kv_tiers cannot serve a latent KV group (rows of widths "
-                f"{tuple(widths)} a token a layer): the trie's copy-on-write and the tier ladder "
-                f"move block contents, which is written for the K/V array. Turn both off for "
-                f"this model.")
-        # a per-sequence state group (a model with state-space layers): a slot a
-        # sequence, in no block table. What shares, moves or rolls back block
-        # tables would leave the slot's state behind.
-        self._sequence_state = bool(getattr(getattr(engine, "model", None), "sequence_state", ()))
-        if self._sequence_state:
-            for name, on in (("prefix_cache", self._config.prefix_cache.enabled),
-                             ("kv_tiers", self._config.kv_tiers.enabled),
-                             ("speculative", self._config.speculative.enabled)):
-                if on:
-                    raise ValueError(
-                        f"{name} cannot serve a per-sequence state group (this model keeps a "
-                        f"recurrent state a sequence, in a slot and in no block table): a "
-                        f"shared prefix's blocks, a tier's payload or a rolled-back draft "
-                        f"would leave the slot's state behind. Turn it off for this model.")
+        # what shares, moves or rolls back a sequence's cache is refused here, or
+        # at submission, where the engine's cache cannot serve it (a window's
+        # rolling release, latent rows, a state slot) instead of serving wrong keys
+        for feature in ("prefix_cache", "kv_tiers", "speculative"):
+            if getattr(self._config, feature).enabled and \
+                    (refusal := engine.cache_refusal(feature)) is not None:
+                raise refusal
         if self._config.prefix_cache.enabled:
             from deepspeed_tpu.inference.v2.ragged.prefix_cache import PrefixCache
             self._prefix_cache = PrefixCache(
@@ -692,17 +667,9 @@ class ServingScheduler:
 
     def _enqueue(self, req: Request, trace_id: Optional[str],
                  parent_span_id: Optional[int], handoff: bool) -> Request:
-        if self._window and (handoff or req.park_requested or req._resume_header is not None):
-            raise ValueError(
-                f"handoff, park and resume frames carry a sequence's whole KV block table; "
-                f"a sliding-window model (a layer's attention window is {self._window}) releases "
-                f"the blocks its window has passed. Send the prompt for recompute instead.")
-        if self._sequence_state and (handoff or req.park_requested
-                                     or req._resume_header is not None):
-            raise ValueError(
-                "handoff, park and resume frames carry a sequence's KV block table; this model "
-                "keeps a per-sequence state group (a recurrent state in a slot, in no block "
-                "table) that a frame would leave behind. Send the prompt for recompute instead.")
+        if (handoff or req.park_requested or req._resume_header is not None) and \
+                (refusal := self._engine.cache_refusal("frames")) is not None:
+            raise refusal
         req.handoff_requested = bool(handoff)
         if self._ledger is not None:
             # every admitted request carries a RequestCost from birth (the
@@ -2034,7 +2001,7 @@ class ServingScheduler:
                     self._metrics.prefix_evictions.inc(freed)
                     self._metrics.prefix_trie_blocks.set(self._prefix_cache.n_blocks)
                 return True
-        if self._sequence_state:
+        if self._engine.cache_refusal("offload_sequence") is not None:
             # offload would move the coldest sequence's blocks and leave its
             # slot's state behind: the request waits for a sequence to finish
             return False

@@ -60,9 +60,8 @@ def traced_program_texts():
                 jaxpr = jax.make_jaxpr(model._forward_impl)(model._params, cache, dev)
                 out[f"{kind}.{arm}.forward.{'x'.join(map(str, bucket))}"] = _stable(jaxpr)
             dev = model._synthetic_batch(BUCKETS[0])
-            jaxpr = jax.make_jaxpr(lambda p, c, d: model._decode_loop_impl(
-                p, c, d, jnp.float32(0.0), jax.random.PRNGKey(0), n_steps=4))(
-                    model._params, cache, dev)
+            jaxpr = jax.make_jaxpr(lambda p, c, d: model._decode_loop_impl(p, c, d, n_steps=4))(
+                model._params, cache, dev)
             out[f"{kind}.{arm}.decode_loop"] = _stable(jaxpr)
             engine.close()
     return out

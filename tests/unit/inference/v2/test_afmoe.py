@@ -702,14 +702,16 @@ def test_float32_holds_what_the_loose_tolerance_lets_through(model, control):
 # touched (``(group_sizes > 0).sum()`` a layer, stacked); the four programs on
 # the capacity path are the parent's still. ``mellum.kernel.forward.128x8x8`` was
 # re-recorded again in PR 42 (the query-tiled kernel's one-token pass); the gather
-# arm's, the 8-token and the chunk's programs held.
+# arm's, the 8-token and the chunk's programs held. The two ``decode_loop``
+# programs were re-recorded in PR 46 with ``test_one_group_programs.py``'s four:
+# the loop lost its unused temperature and key, and nothing else.
 _MELLUM_PARENT = {
     "mellum.gather.forward.8x8x4": "98d1d447896ec022ef33d977031c4731e75ba00e9aae31b2e52c2ddfbc751fea",
     "mellum.gather.forward.128x8x8": "baf95ba27f841e05ba61035349e3b77b1a59a50e1f29c3b903dcf1e648782f1a",
-    "mellum.gather.decode_loop": "75e1e5614b850f8e5450809412955dc7f4d7948127eb1e9ce68708c56c6b8a8c",
+    "mellum.gather.decode_loop": "9c67c90e4a53015f5d646b9a49c696c15a20da16a633a0668829b71759c08121",
     "mellum.kernel.forward.8x8x4": "2857ae3f6a0f05a300e1c4d552b4455cb6ee85431770ab01a80eaea76e50f73b",
     "mellum.kernel.forward.128x8x8": "de051196db4d56ac6f7db2dc36ce95a3049e00f61a2e25c83298c0c6531462f5",
-    "mellum.kernel.decode_loop": "6effb84eedcefc8b75f935c54044c3e77e3a6198b0d121bbc9161bc98f6c7644",
+    "mellum.kernel.decode_loop": "ea7d90a737b8ecd9974fea2c7be90f64289de2a83ac6fe37c2a137a1da8ff349",
 }
 
 
@@ -738,9 +740,8 @@ def mellum_texts():
         for bucket in BUCKETS:
             jaxpr = jax.make_jaxpr(m._forward_impl)(m._params, cache, m._synthetic_batch(bucket))
             out[f"mellum.{arm}.forward.{'x'.join(map(str, bucket))}"] = _stable(jaxpr)
-        jaxpr = jax.make_jaxpr(lambda p, c, d: m._decode_loop_impl(
-            p, c, d, jnp.float32(0.0), jax.random.PRNGKey(0), n_steps=4))(
-                m._params, cache, m._synthetic_batch(BUCKETS[0]))
+        jaxpr = jax.make_jaxpr(lambda p, c, d: m._decode_loop_impl(p, c, d, n_steps=4))(
+            m._params, cache, m._synthetic_batch(BUCKETS[0]))
         out[f"mellum.{arm}.decode_loop"] = _stable(jaxpr)
         engine.close()
     return out

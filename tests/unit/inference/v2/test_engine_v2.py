@@ -110,6 +110,24 @@ def test_scheduling_limits(llama_setup):
         engine.put([0], [np.arange(64) % cfg.vocab_size])
 
 
+def test_empty_run_changes_nothing_a_sequence_can_see(llama_setup):
+    """An idle replica's lock-step forward: the engine's own batch with no
+    sequence in it, through the smallest bucket's program. The sequences'
+    state and what they read next are as without it."""
+    cfg, _, params = llama_setup
+    prompt = np.arange(9) % cfg.vocab_size
+    idle, busy = (build_engine(params, cfg, _engine_config()) for _ in range(2))
+    for engine in (idle, busy):
+        engine.put([0], [prompt])
+    idle.empty_run()
+    idle.empty_run()
+    assert sorted(idle.lowerable_callables()["forward"]) == [(8, 8, 4), (16, 8, 4)]
+    assert idle.free_blocks == busy.free_blocks
+    assert idle._state_manager.get_sequence(0).seen_tokens == 9
+    np.testing.assert_array_equal(np.asarray(idle.put([0], [np.array([3])])),
+                                  np.asarray(busy.put([0], [np.array([3])])))
+
+
 def test_flush_recycles_blocks(llama_setup):
     cfg, _, params = llama_setup
     engine = build_engine(params, cfg, _engine_config(num_blocks=8, block_size=16))
@@ -279,28 +297,71 @@ def test_decode_loop_rejects_past_max_context(llama_setup):
     assert engine.free_blocks == free_before  # nothing leaked
 
 
-def test_decode_loop_sampling(llama_setup):
-    """temperature>0 samples with the provided rng: reproducible for a fixed
-    key, different for different keys, and greedy (0.0) is unchanged."""
-    import jax as _jax
+def _chunk_check_reference(engine, uids, n_steps):
+    """The admission check ``dispatch_decode_loop`` carried inline until PR 46,
+    kept as the reference ``can_schedule(..., steps=n_steps)`` is held to."""
+    from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import PlaceholderSequenceDescriptor
+    limits, manager = engine._config.state_manager, engine._state_manager
+    if len(uids) > limits.max_ragged_sequence_count:
+        return SchedulingResult.BatchSequenceLimitExceeded
+    if len(uids) > limits.max_ragged_batch_size:
+        return SchedulingResult.BatchTokenLimitExceeded
+    free_blocks, cur_seqs = manager.free_blocks, manager.n_tracked_sequences
+    for uid in uids:
+        seq_desc = manager.get_sequence(uid)
+        if seq_desc is None:
+            cur_seqs += 1
+            seq_desc = PlaceholderSequenceDescriptor()
+        restore = seq_desc.live_blocks if manager.is_offloaded(uid) else 0
+        sched_len, sched_blocks = engine.model.get_kv_requirements(
+            seq_desc, n_steps, free_blocks - restore)
+        if sched_len != n_steps:
+            return SchedulingResult.KVCacheLimitExceeded
+        free_blocks -= sched_blocks + restore
+    if cur_seqs > limits.max_tracked_sequences:
+        return SchedulingResult.EngineSequenceLimitExceeded
+    return SchedulingResult.Success
 
-    cfg, model, params = llama_setup
-    prompt = np.arange(21) % cfg.vocab_size
 
-    def gen(temp, seed):
-        eng = build_engine(params, cfg, _engine_config())
-        first = int(np.argmax(np.asarray(eng.put([0], [prompt]))[0]))
-        return eng.decode_loop([0], [np.array([first])], 6, temperature=temp,
-                               rng=_jax.random.PRNGKey(seed))
-
-    a = gen(1.5, 0)
-    b = gen(1.5, 0)
-    c = gen(1.5, 123)
-    g1 = gen(0.0, 0)
-    g2 = gen(0.0, 7)
-    np.testing.assert_array_equal(a, b)           # reproducible
-    assert not np.array_equal(a, c)               # rng really used
-    np.testing.assert_array_equal(g1, g2)         # greedy ignores the rng
+@pytest.mark.parametrize("uids, n_steps, offload, expected", [
+    ([0, 1, 2, 3, 4], 2, False, "BatchSequenceLimitExceeded"),
+    # four sequences are four tokens a scan step, over a budget of three: said
+    # before any sequence is looked at, though their KV would not fit either
+    ([0, 1, 2, 3], 1000, False, "BatchTokenLimitExceeded"),
+    ([0], 5 * 16, False, "Success"),            # 8 blocks, 3 held by uid 10: 5 free
+    ([0], 5 * 16 + 1, False, "KVCacheLimitExceeded"),
+    ([0, 10], 2 * 16, False, "Success"),        # uid 10 has 40 tokens in 3 blocks: 2 + 2 new
+    ([0, 10], 2 * 16 + 9, False, "KVCacheLimitExceeded"),
+    ([0, 1, 2], 1, False, "EngineSequenceLimitExceeded"),   # 1 tracked + 3 new > 3
+    ([10], 88, True, "Success"),                # 8 free, 3 to restore: 40 + 88 fill 8 blocks
+    ([10], 89, True, "KVCacheLimitExceeded"),   # its stale table would count 3 + 8 blocks
+], ids=["sequences", "tokens-by-count", "kv-fits", "kv-over-k-steps", "kv-two-fit",
+        "kv-two-over", "tracked", "restore-cost-fits", "restore-cost-over"])
+def test_can_schedule_takes_the_chunk_case(llama_setup, uids, n_steps, offload, expected):
+    """``can_schedule(uids, lengths, steps=k)`` answers what the chunk's own
+    check answered: the token budget by the sequence count, the KV budget over
+    ``k`` steps a sequence; and ``dispatch_decode_loop`` raises that answer
+    with nothing changed."""
+    cfg, _, params = llama_setup
+    engine = build_engine(params, cfg, _engine_config(
+        num_blocks=8, max_ragged_batch_size=3, max_ragged_sequence_count=4,
+        max_tracked_sequences=3))
+    seq = engine._state_manager.get_or_create_sequence(10)  # tracked, 40 tokens committed
+    engine.model.maybe_allocate_kv(seq, 40)
+    seq.pre_forward(40)
+    seq.post_forward()
+    if offload:
+        engine.offload_sequence(10)
+    free, tracked = engine.free_blocks, engine._state_manager.n_tracked_sequences
+    got = engine.can_schedule(uids, [1] * len(uids), steps=n_steps)
+    assert got == _chunk_check_reference(engine, uids, n_steps) == getattr(SchedulingResult, expected)
+    if got != SchedulingResult.Success:
+        with pytest.raises(SchedulingError) as said:
+            engine.dispatch_decode_loop(uids, [np.array([1])] * len(uids), n_steps)
+        assert said.value.status == got
+        assert (engine.free_blocks, engine._state_manager.n_tracked_sequences) == (free, tracked)
+        assert engine.is_offloaded(10) == offload
+    engine.close()
 
 
 def test_generate_chunked_matches_stepwise(llama_setup):

@@ -454,8 +454,6 @@ def test_the_state_manager_refuses_what_moves_block_tables_by_name(model):
     with pytest.raises(NotImplementedError, match=said):
         engine.verify_tree([0], [TokenTree.chain(_ids(61, 3))])
     assert engine._state_manager.get_sequence(0).in_flight_tokens == 0  # refused before any change
-    with pytest.raises(NotImplementedError, match=said):
-        engine.model.forward_verify(None)
 
 
 @pytest.mark.parametrize("serving, said", [

@@ -16,20 +16,24 @@ from tests.unit.inference.v2.program_hashes import traced_program_texts
 # The two ``kernel.forward.128x8x8`` programs (the query-tiled grid) were
 # re-recorded in PR 42: the one difference is the kernel's body, where a pass that
 # owns one token of its tile computes that token alone; the per-token grid's, the
-# chunk's and the gather arm's ten are the parent's still.
+# chunk's and the gather arm's ten are the parent's still. The four ``decode_loop``
+# programs were re-recorded in PR 46, which took the loop's unused temperature
+# and key away: two input variables and one constant of the scan (the key, which
+# JAX had moved out of the carry it was never changed in) fewer, the equations
+# the same, one for one (CHANGES.md, PR 46).
 _PARENT = {
     "mixtral.gather.forward.8x8x4": "86f8f4891c9c332a20f1ab9c7b3102c75d6abc68b5de33296a2c91e4354762fe",
     "mixtral.gather.forward.128x8x8": "cd3542097441a1448f98af3dfc5355e9e87e4cad4a485bf9a4955d6651b04514",
-    "mixtral.gather.decode_loop": "69c2e3adc303bf697af611f189c281f05997a6ff7d020d2cfa7e0519269ef128",
+    "mixtral.gather.decode_loop": "3b53573caf8b45176d5c1df4077f2e8c9ad48a9c93a71e53a1b9f66903f06011",
     "mixtral.kernel.forward.8x8x4": "9c9226f37fb527248717741de88c14b927be58fec10ed5a431c07501ec327cfb",
     "mixtral.kernel.forward.128x8x8": "cd5d57360d06e4fe37c0f8858380e66a9ecf261ea38c1d260dd24915a4c1697a",
-    "mixtral.kernel.decode_loop": "670c2538ca3544eda49d944fba4a110975e36331349af780970a248e0b06f5e2",
+    "mixtral.kernel.decode_loop": "13fc8526920cc178cc3036371c2acd7bc2f6b9d4f033a627f72dd92bce3037c0",
     "mistral.gather.forward.8x8x4": "751033dec5183c6a1e986d04784c353762cbd51dd4d420ba32ee2857ecb4e0d1",
     "mistral.gather.forward.128x8x8": "c84cdeeec59041dd601cf9c67a5908720e358b67563d25bcea0e1ddff1b05d9b",
-    "mistral.gather.decode_loop": "591efcd9832514c3c7d516f49468e6ffbcc5721e2ed9bf68f9350d72f244592d",
+    "mistral.gather.decode_loop": "fb03027c1dc3f3f5a560949add887b9bda804516d6851336308a5d110725c81a",
     "mistral.kernel.forward.8x8x4": "7863a4ef1c64d31ba1fe38293e800230cf9d248ba3fc33430b9d5c787374b774",
     "mistral.kernel.forward.128x8x8": "b6ec4c3ddd6861b294d28b9927f4beb62fe3113603772ff5584f04d4a17d7e6f",
-    "mistral.kernel.decode_loop": "20c75d25d5a970e466ef28a4311cc801643aa6a0e808cb58f0a6273fdaf82c7c",
+    "mistral.kernel.decode_loop": "8f0a59ff5d887619ae5a0c9ea912d5e35c38714807a0567cb6163780de18a707",
 }
 
 

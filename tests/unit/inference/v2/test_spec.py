@@ -51,8 +51,7 @@ def trie():
                                                                    MemoryConfig)
     from deepspeed_tpu.inference.v2.ragged.prefix_cache import PrefixCache
     kv = BlockedKVCache(
-        KVCacheConfig(block_size=4, cache_shape=(1, 1, 4), cache_dtype="float32",
-                      max_blocks_per_allocation_group=64),
+        KVCacheConfig(block_size=4, cache_shape=(1, 1, 4), cache_dtype="float32"),
         MemoryConfig(mode=AllocationMode.ALLOCATE, size=32))
     return PrefixCache(kv), kv
 

@@ -248,11 +248,9 @@ def test_compact_accepted_chain_path_skips_device_copy(tree_engine_setup):
     t1 = _prefill_argmax(eng, prompt)
     tree = TokenTree([t1, 1, 2, 3], [-1, 0, 1, 2])
     eng.verify_tree([0], [tree])
-    before = [k for k in eng.model._lowerable if k[0] == "compact"]
+    before = list(eng.lowerable_callables()["compact"])
     assert eng.compact_accepted(0, tree.size, [1, 2]) == 1
-    after = [k for k in eng.model._lowerable
-             if isinstance(k, tuple) and k[0] == "compact"]
-    assert before == after  # contiguous path: pure rollback
+    assert list(eng.lowerable_callables()["compact"]) == before  # contiguous path: pure rollback
     assert eng._state_manager.get_sequence(0).seen_tokens == prompt.size + 3
 
 

@@ -219,9 +219,8 @@ def test_serve_decode_loop_program_fits_one_chip(v5e, sizes, serve_model):
     serve, _ = sizes
     model, abstract = serve_model
     one, params, cache, batch = _serve_args(v5e[0], sizes, abstract, (8, 8, 4))
-    loop = functools.partial(model._decode_loop_impl, n_steps=serve.decode_chunk, sampled=False)
-    compiled = jax.jit(loop, donate_argnums=(1, )).lower(
-        params, cache, batch, _on(one, (), jnp.float32), _on(one, (2, ), jnp.uint32)).compile()
+    loop = functools.partial(model._decode_loop_impl, n_steps=serve.decode_chunk)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(params, cache, batch).compile()
     assert "paged_attention_update" in compiled.as_text()
     assert _device_bytes(compiled) < HBM_BYTES
 
@@ -325,9 +324,8 @@ def test_window_model_put_program_fits_one_chip(v5e, window_model, bucket, kerne
 def test_window_model_decode_loop_program_fits_one_chip(v5e, window_model):
     model, abstract = window_model
     one, params, cache, batch = _window_args(v5e[0], abstract, (8, 8, 128))
-    loop = functools.partial(model._decode_loop_impl, n_steps=8, sampled=False)
-    compiled = jax.jit(loop, donate_argnums=(1, )).lower(
-        params, cache, batch, _on(one, (), jnp.float32), _on(one, (2, ), jnp.uint32)).compile()
+    loop = functools.partial(model._decode_loop_impl, n_steps=8)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(params, cache, batch).compile()
     text = compiled.as_text()
     assert "paged_attention_update" in text
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
@@ -397,9 +395,8 @@ def test_mellum_put_program_fits_one_chip(v5e, mellum_model, bucket, kernel):
 def test_mellum_decode_loop_program_fits_one_chip(v5e, mellum_model):
     model, abstract = mellum_model
     one, params, cache, batch = _mellum_args(v5e[0], model, abstract, (8, 8, 256))
-    loop = functools.partial(model._decode_loop_impl, n_steps=8, sampled=False)
-    compiled = jax.jit(loop, donate_argnums=(1, )).lower(
-        params, cache, batch, _on(one, (), jnp.float32), _on(one, (2, ), jnp.uint32)).compile()
+    loop = functools.partial(model._decode_loop_impl, n_steps=8)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(params, cache, batch).compile()
     text = compiled.as_text()
     assert "paged_attention_update" in text
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
@@ -470,9 +467,8 @@ def test_trinity_decode_loop_program_fits_one_chip(v5e, trinity_model):
     """The grouped kernel with its dynamic visit count inside the loop's scan."""
     model, abstract = trinity_model
     one, params, cache, batch = _trinity_args(v5e[0], model, abstract, (8, 8, 64))
-    loop = functools.partial(model._decode_loop_impl, n_steps=8, sampled=False)
-    compiled = jax.jit(loop, donate_argnums=(1, )).lower(
-        params, cache, batch, _on(one, (), jnp.float32), _on(one, (2, ), jnp.uint32)).compile()
+    loop = functools.partial(model._decode_loop_impl, n_steps=8)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(params, cache, batch).compile()
     text = compiled.as_text()
     assert "paged_attention_update" in text and "grouped_matmul" in text
     assert "[8,128,8]" not in text  # no mask of the capacity path
@@ -552,9 +548,8 @@ def test_deepseek_put_program_fits_one_chip(v5e, deepseek_model, bucket, kernel,
 def test_deepseek_decode_loop_program_fits_one_chip(v5e, deepseek_model):
     model, abstract = deepseek_model
     one, params, cache, batch = _deepseek_args(v5e[0], model, abstract, (8, 8, 64))
-    loop = functools.partial(model._decode_loop_impl, n_steps=8, sampled=False)
-    compiled = jax.jit(loop, donate_argnums=(1, )).lower(
-        params, cache, batch, _on(one, (), jnp.float32), _on(one, (2, ), jnp.uint32)).compile()
+    loop = functools.partial(model._decode_loop_impl, n_steps=8)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(params, cache, batch).compile()
     text = compiled.as_text()
     assert "latent_paged_attention_token" in text and "latent_index_scores" in text
     assert "grouped_matmul" in text
@@ -654,9 +649,8 @@ def test_nemotron_decode_loop_program_fits_one_chip(v5e, nemotron_model):
     no row's state exists outside the pool (the gather was 8 of them)."""
     model, abstract = nemotron_model
     one, params, cache, batch = _nemotron_args(v5e[0], model, abstract, (8, 8, 32))
-    loop = functools.partial(model._decode_loop_impl, n_steps=8, sampled=False)
-    args = (params, cache, batch, _on(one, (), jnp.float32), _on(one, (2, ), jnp.uint32))
-    compiled = jax.jit(loop, donate_argnums=(1, )).lower(*args).compile()
+    loop = functools.partial(model._decode_loop_impl, n_steps=8)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(params, cache, batch).compile()
     text = compiled.as_text()
     assert "paged_attention_update" in text and "grouped_matmul" in text
     assert "ssm/step" in text and "ssm/scan" not in text
@@ -665,5 +659,5 @@ def test_nemotron_decode_loop_program_fits_one_chip(v5e, nemotron_model):
     assert len(kernels) == 6 and all("ssm/step" in line for line in kernels), kernels
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert not _state_sized_results(text, rows=8)
-    out = jax.eval_shape(loop, *args)
+    out = jax.eval_shape(loop, params, cache, batch)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]

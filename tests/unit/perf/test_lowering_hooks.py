@@ -113,6 +113,91 @@ def test_lower_decode_loop_matches_executed_program(v2):
     lowered = engine.lower_decode_loop(4, bucket=dkey[0])
     model = engine.model
     dev = model._synthetic_batch(dkey[0])
-    again = raw.lower(model._params, model.state_manager.kv_cache.cache, dev,
-                      jnp.float32(0.0), jax.random.PRNGKey(0))
+    again = raw.lower(model._params, model.state_manager.kv_cache.cache, dev)
     assert lowered.as_text() == again.as_text()
+    assert model._program("decode_loop", dkey, run=False) is raw  # the cached jit, not a fresh one
+
+
+@pytest.mark.parametrize("call", [
+    lambda e: e.decode_loop([0], [np.asarray([1], np.int32)], 2, True, temperature=0.7),
+    lambda e: e.dispatch_decode_loop([0], [np.asarray([1], np.int32)], 2, temperature=0.7),
+    lambda e: e.lower_decode_loop(2, temperature=0.7),
+    lambda e: e.model.lower_decode_loop(2, temperature=0.7),
+], ids=["engine.decode_loop", "engine.dispatch_decode_loop", "engine.lower_decode_loop",
+        "model.lower_decode_loop"])
+def test_the_decode_loop_takes_no_temperature(v2, call):
+    """The loop is greedy: a sampled request is drawn a step at a time
+    (``put_draw``), and a temperature handed to the loop is an error, not a
+    silent argmax."""
+    engine, _ = v2
+    seen = engine._state_manager.get_sequence(0).seen_tokens
+    with pytest.raises(TypeError, match="temperature"):
+        call(engine)
+    assert engine._state_manager.get_sequence(0).seen_tokens == seen
+
+
+def test_one_program_a_kind_and_key_built_once(v2):
+    """The model's program cache: one jit a (kind, key), under the keys the
+    lowering hooks have always shown, and a step that finds its program builds
+    nothing."""
+    from deepspeed_tpu.inference.v2.spec.tree import TokenTree
+    engine, _ = v2
+    model = engine.model
+    engine.verify_tree([0], [TokenTree([1, 2, 3, 4], [-1, 0, 0, 1])], greedy=True)
+    engine.compact_accepted(0, 4, [2])
+    fns = engine.lowerable_callables()
+    assert list(fns) == ["forward", "decode_loop", "verify", "compact"]
+    (bucket, forward), = fns["forward"].items()
+    (loop_key, loop), = fns["decode_loop"].items()
+    (verify_key, verify), = fns["verify"].items()
+    (compact_key, compact), = fns["compact"].items()
+    assert loop_key == (loop_key[0], 4, False) and len(loop_key[0]) == 3
+    assert verify_key == ("verify", verify_key[1], True, True) and compact_key == ("compact", 2)
+    ran = [at for at, (_, called) in model._programs.items() if called is not None]
+    assert sorted(ran) == sorted((kind, key) for kind, keyed in fns.items() for key in keyed)
+    # the same steps again, twice: every program found, none built, none traced
+    # again (the forward's first call took the pool as it was made, every later
+    # one as a program left it: two signatures, then no more)
+    sizes = []
+    for _ in range(2):
+        engine.put([1], [np.arange(24, dtype=np.int32)])
+        engine.flush(1)
+        engine.decode_loop([0], [np.asarray([1], np.int32)], 4)
+        engine.verify_tree([0], [TokenTree([1, 2, 3, 4], [-1, 0, 0, 1])], greedy=True)
+        engine.compact_accepted(0, 4, [2])
+        sizes.append([fn._cache_size() for fn in (forward, loop, verify, compact)])
+    assert sizes[0] == sizes[1] and sizes[1][1:] == [1, 1, 1]
+    again = engine.lowerable_callables()
+    assert {kind: list(keyed) for kind, keyed in again.items()} == \
+        {kind: list(keyed) for kind, keyed in fns.items()}
+    assert all(again[kind][key] is fn for kind, keyed in fns.items() for key, fn in keyed.items())
+
+
+def test_lowering_a_program_that_never_ran_leaves_the_compile_watch_alone(v2):
+    """``lower_*`` of a bucket no step has taken: the jit is made (once) and
+    lowers, the compile watch counts no cache entry, and
+    ``lowerable_callables`` — the programs that RAN — does not list it."""
+    from deepspeed_tpu import telemetry
+    from deepspeed_tpu.telemetry.config import TelemetryConfig
+
+    engine, _ = v2
+    telemetry.shutdown()
+    telemetry.state.registry = None
+    try:
+        telemetry.configure(TelemetryConfig(enabled=True))
+        watch = telemetry.compile_watch.get()
+        ran = {kind: list(keyed) for kind, keyed in engine.lowerable_callables().items()}
+        entries = {site: watch._metrics_for(site)[2].value
+                   for site, *_ in engine.model._PROGRAM_KINDS.values()}
+        lowered = [engine.lower_forward((32, 8, 8)), engine.lower_decode_loop(3, (16, 8, 8)),
+                   engine.lower_verify((32, 8, 8), tree=True)]
+        assert all(low.as_text().startswith("module") for low in lowered)
+        assert {site: watch._metrics_for(site)[2].value
+                for site, *_ in engine.model._PROGRAM_KINDS.values()} == entries
+        assert {kind: list(keyed) for kind, keyed in engine.lowerable_callables().items()} == ran
+        made = engine.model._program("forward", (32, 8, 8), run=False)
+        engine.lower_forward((32, 8, 8))
+        assert engine.model._program("forward", (32, 8, 8), run=False) is made
+    finally:
+        telemetry.shutdown()
+        telemetry.state.registry = None

@@ -244,13 +244,13 @@ def test_put_path_fetches_ids_builds_nothing_and_counts_its_draws(make_engine, l
 
     # the engine's forward programs are engine.put's: (T, S, MB) buckets only
     programs = engine.lowerable_callables()
-    assert set(programs) == {"forward", "decode_loop", "verify"}
+    assert set(programs) == {"forward", "decode_loop", "verify", "compact"}
     assert programs["forward"] and all(
         isinstance(k, tuple) and len(k) == 3 and all(isinstance(d, int) for d in k)
         for k in programs["forward"])
     twin = make_engine()
     for key in sorted(programs["forward"]):
-        twin._model._get_compiled(key)
+        twin._model._program("forward", key)
     assert sorted(twin.lowerable_callables()["forward"]) == sorted(programs["forward"])
 
     streamed = sum(len(r.tokens) for r in again)
@@ -325,22 +325,24 @@ def test_warm_draw_builds_the_last_row_program_and_chunks_in_flight_compile_noth
 
     first, _ = serve()
     assert [k for k in sampling._EXECUTABLES if k[0] == "last_row"] == [("last_row", 4, 8)]
-    keys = set(engine.model._compiled)
+    keys = set(engine.model._programs)
     compiled = []
     jax.monitoring.register_event_duration_secs_listener(
         lambda name, *_a, **_k: compiled.append(name) if "backend_compile" in name else None)
     again, counters = serve()
     assert again == first and counters["pipelined_chunks"] >= 3
-    assert compiled == [] and set(engine.model._compiled) == keys
+    assert compiled == [] and set(engine.model._programs) == keys
     # engine.decode_loop lands in the program the chunks in flight ran
     engine.put([10_001], [np.zeros(20, np.int32)])
     engine.decode_loop([10_001], [np.zeros(1, np.int32)], 4)
     engine.flush(10_001)
-    assert set(engine.model._compiled) == keys
+    assert set(engine.model._programs) == keys
 
 
-def test_a_greedy_chunks_key_and_temperature_are_put_on_the_device_once(make_engine, llama_setup,
-                                                                        monkeypatch):
+def test_a_greedy_chunk_puts_nothing_on_the_device_ahead_of_its_program(make_engine, llama_setup,
+                                                                       monkeypatch):
+    """A chunk's program takes the params, the cache and the batch: no key
+    and no temperature are made for it, on the host or the device."""
     import jax
     cfg, _, _ = llama_setup
     made = []
@@ -349,14 +351,13 @@ def test_a_greedy_chunks_key_and_temperature_are_put_on_the_device_once(make_eng
     engine = make_engine()
     prompt = np.asarray(_prompt(cfg, 9, seed=5), np.int32)
     nxt = np.asarray(engine.put_draw([0], [prompt], [0.0], [0], [0]))[:1]
-    assert engine.model._greedy_sampler is None
     out = []
     for _ in range(3):
         out.append(engine.decode_loop([0], [nxt], 4))
         nxt = out[-1][0, -1:]
-        kept = kept if len(out) > 1 else engine.model._greedy_sampler
-        assert engine.model._greedy_sampler is kept
-    assert made == [0]
+    assert made == []
+    loop, = engine.lowerable_callables()["decode_loop"].values()
+    assert loop._cache_size() == 1  # the three chunks ran one program
     # the tokens are a fresh engine's, one chunk of twelve
     twin = make_engine()
     first = np.asarray(twin.put_draw([0], [prompt], [0.0], [0], [0]))[:1]

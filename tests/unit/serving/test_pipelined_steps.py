@@ -789,8 +789,9 @@ def test_pipelining_builds_no_program_after_the_scheduler_is_constructed(
     reqs = _submit_all(first, cfg, 0.8)
     _run_until(first, lambda: all(r.finished for r in reqs))
     first.stop(drain=False)
-    keys = set(engine.model._compiled)
-    assert keys and all(len(k) == 3 and all(isinstance(n, int) for n in k) for k in keys)
+    keys = set(engine.model._programs)
+    assert keys and all(kind == "forward" and len(k) == 3 and all(isinstance(n, int) for n in k)
+                        for kind, k in keys)
 
     sched = ServingScheduler(engine, ServingConfig(), start=False)
     compiled = []
@@ -804,13 +805,13 @@ def test_pipelining_builds_no_program_after_the_scheduler_is_constructed(
     if mgr is OPEN:
         assert counters["open_behind_steps"] >= 5
     assert [list(r.tokens) for r in again] == [list(r.tokens) for r in reqs]
-    assert set(engine.model._compiled) == keys
+    assert set(engine.model._programs) == keys
     assert compiled == []
     if mgr is CLOSED:
         # engine.put lands in the same programs: a full 16-token chunk, one sequence
         engine.put([10_001], [np.zeros(16, np.int32)])
         engine.flush(10_001)
-        assert set(engine.model._compiled) == keys
+        assert set(engine.model._programs) == keys
 
 
 # ------------------------------------------------------------------- tracing --
