@@ -103,6 +103,7 @@ class InferenceEngineV2:
                                          block_size=engine_config.kv_block_size,
                                          num_groups=kv_config.num_allocation_groups,
                                          min_table_bucket=kv_config.min_table_bucket,
+                                         min_sequence_bucket=kv_config.min_sequence_bucket,
                                          state_slots=kv_config.sequence_slots)
         self._state_manager = DSStateManager(engine_config.state_manager, kv_config)
         self._model.set_state_manager(self._state_manager)
@@ -340,7 +341,9 @@ class InferenceEngineV2:
         args = None
         if spans is not None:
             n_tokens = int(sum(t.size for t in batch_tokens))
-            args = self._dispatch_args(spans, batch_uids,
+            # the step's live sequences and the sequence count it was padded to
+            args = self._dispatch_args(spans, batch_uids, seqs_live=len(batch_uids),
+                                       seq_bucket=self._batch.device_batch["seq_meta"].shape[0],
                                        **({"steps": steps} if steps else {"tokens": n_tokens}))
             if not steps:
                 # the arm the bucket's program takes (modules/heuristics.py):

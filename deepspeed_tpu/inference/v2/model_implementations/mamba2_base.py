@@ -1,0 +1,184 @@
+"""What the ragged models with Mamba-2 mixers share (``nemotron_h_v2.py``: one
+mixer a block, some of them Mamba-2; ``falcon_h1_v2.py``: a Mamba-2 mixer
+beside attention in every layer): the per-sequence state group they ask of the
+engine, the mixer over a step's rows, and the step's counters.
+
+- **a per-sequence state group** (``sequence_state``): a Mamba-2 mixer keeps,
+  for each live sequence and whatever its length, a float32 state ``[heads,
+  head_dim, state]`` and the last ``conv_kernel - 1`` rows of its convolution's
+  input. Two pools ``[Mamba-2 mixers, slots, ...]`` ride beside the K/V array
+  in the one cache pytree (``ragged/kv_cache.py``); a sequence's slot is a
+  column of ``seq_meta``. A slot's content counts from the sequence's first
+  token: a sequence with nothing seen reads zeros whatever the slot held.
+  Padding rows point one past the last slot and their writes drop;
+- **two forms of the scan** (``modules/ssm.py``): a ``put`` step runs the
+  chunked form over the ragged batch, each sequence's segment starting from
+  its slot's state, gathered, and leaving its final state there, scattered; a
+  ``decode_loop`` step (``one_token_rows``) runs the recurrence, one token a
+  sequence, IN the pool: one kernel a mixer reads a row's slot, updates it
+  and writes it back (``ops/pallas/ssm_step.py``), so no ``[rows, H, P, N]``
+  exists in that program. A pool off the kernel's shape rule
+  (``ssm.in_place``) runs ``ssm.step`` between a gather and a scatter.
+
+Scopes in the device trace, under ``ssm``: ``in_proj``, ``conv``, ``scan``
+(the chunked form) or ``step`` (the recurrence), ``gate_norm``, ``out_proj``.
+"""
+
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deepspeed_tpu.inference.v2.model_implementations.transformer_base import \
+    DSTransformerModelBase
+from deepspeed_tpu.inference.v2.modules import ssm
+from deepspeed_tpu.inference.v2.ragged.manager_configs import SequenceStateSpec
+
+
+def scaled_dot(x, kernel, scale: float):
+    """``(x kernel) scale`` with the scale on the float32 product: a
+    multiplier that is no power of two would otherwise be rounded to the
+    activations' type once itself and round the product once more."""
+    return (jnp.dot(x, kernel.astype(x.dtype), preferred_element_type=jnp.float32)
+            * scale).astype(x.dtype)
+
+
+class Mamba2Shape(NamedTuple):
+    """A model's Mamba-2 mixers: how many, their widths, and the scalars the
+    family puts around the projections (1 / None: none)."""
+    mixers: int
+    heads: int
+    head_dim: int
+    groups: int
+    state: int
+    conv_kernel: int
+    chunk: int
+    eps: float
+    in_scale: float = 1.0  # on the mixer's input
+    column_scale: Optional[np.ndarray] = None  # float32 [in_proj's width]: on its output
+    out_scale: float = 1.0  # on out_proj's output
+
+    @property
+    def d_inner(self):
+        return self.heads * self.head_dim
+
+    @property
+    def conv_dim(self):
+        return self.d_inner + 2 * self.groups * self.state
+
+
+class Mamba2Model(DSTransformerModelBase):
+    """A subclass states :attr:`mamba2` and calls :meth:`_mamba_phase` where a
+    layer has such a mixer, with the mixer's ordinal among them."""
+
+    @property
+    def mamba2(self) -> Mamba2Shape:
+        raise NotImplementedError
+
+    @property
+    def sequence_state(self):
+        w = self.mamba2
+        return (SequenceStateSpec(name="ssm", layers=w.mixers, dtype="float32",
+                                  shape=(w.heads, w.head_dim, w.state)),
+                SequenceStateSpec(name="conv", layers=w.mixers,
+                                  dtype=np.dtype(self._config.dtype).name,
+                                  shape=(w.conv_kernel - 1, w.conv_dim)))
+
+    def batch_counts(self, ragged_batch, steps=None):
+        """Beside the attention kernels' passes: ``ssm_tokens``, rows that went
+        through a Mamba-2 mixer (live tokens x such mixers, over the ``steps``
+        of a chunk); ``ssm_segments``, sequence segments scanned (a segment a
+        live sequence a mixer a step); ``ssm_slots_live`` / ``ssm_slots_total``,
+        the per-sequence state group's slots held as the step is dispatched;
+        where the caller gives ``steps`` (the engine does, a chunk's or 1 for a
+        ``put``, whose entry nothing reads) ``ssm_rows_in_place``, those of a
+        ``decode_loop`` chunk's ``ssm_tokens`` whose state the kernel updated in
+        its slot: all of them, or 0 where the pool is off its shape rule."""
+        chunk, steps = steps is not None, steps or 1
+        counts = super().batch_counts(ragged_batch, steps)
+        batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
+        w = self.mamba2
+        kv = self._state_manager.kv_cache
+        counts.update(ssm_tokens=steps * int(batch["n_tokens"]) * w.mixers,
+                      ssm_segments=steps * int(batch["n_seqs"]) * w.mixers,
+                      ssm_slots_live=kv.num_slots - (kv.free_slots or 0),
+                      ssm_slots_total=kv.num_slots)
+        if chunk:
+            in_place = ssm.in_place(kv.cache[1], w.groups)
+            counts["ssm_rows_in_place"] = counts["ssm_tokens"] if in_place else 0
+        return counts
+
+    def _step_in_place(self, pool, mi, *rows):
+        """``ssm.step_in_place`` on mixer ``mi`` of the pool. The SPMD
+        partitioner cannot split a Mosaic kernel: on a mesh every device runs
+        it over the pool it holds whole (``kv_cache._pool_sharding``), as
+        ``_paged_attention`` runs its kernel."""
+        placed = None if self._state_manager is None else self._state_manager.kv_cache.sharding
+        if placed is None or placed.mesh.size == 1 or not ssm.in_place(pool, rows[-1].shape[1]):
+            return ssm.step_in_place(pool, mi, *rows)
+        from jax.sharding import PartitionSpec as P
+        return jax.shard_map(ssm.step_in_place, mesh=placed.mesh, in_specs=P(), out_specs=P(),
+                             check_vma=False)(pool, jnp.int32(mi), *rows)
+
+    @jax.named_scope("ssm")
+    def _mamba_phase(self, mp, mi, h, pools, batch):
+        """Mamba-2 mixer ``mi`` (its ordinal) over the step's rows ``h`` [T, M];
+        ``pools`` = (ssm [mixers, slots, H, P, N], conv [mixers, slots, K - 1,
+        C]). Returns the mixer's output and the pools with the step's states."""
+        w = self.mamba2
+        T = h.shape[0]
+        H, P, G, N, D = w.heads, w.head_dim, w.groups, w.state, w.d_inner
+        ssm_pool, conv_pool = pools
+        n_slots = ssm_pool.shape[1]
+        with jax.named_scope("in_proj"):
+            if w.in_scale != 1.0:
+                h = h * jnp.asarray(w.in_scale, h.dtype)
+            if w.column_scale is None:
+                zxbcdt = h @ mp["in_proj"]["kernel"].astype(h.dtype)
+            else:
+                zxbcdt = scaled_dot(h, mp["in_proj"]["kernel"], w.column_scale[None, :])
+            z, xbc, dt = jnp.split(zxbcdt, [D, D + w.conv_dim], axis=-1)
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + mp["dt_bias"][None, :])
+        A = -jnp.exp(mp["A_log"].astype(jnp.float32))
+        slot = batch["state_slot"]
+        # a sequence with nothing seen starts from zero whatever its slot held
+        started = batch["seq_valid"] & (batch["seq_seen"] > 0)
+        one_token = batch["one_token_rows"]
+        if one_token:  # decode_loop: row t is sequence token_seq[t]'s one token
+            of = batch["token_seq"]
+            slot, started = slot[of], started[of]
+            write = jnp.where(batch["token_valid"], slot, n_slots)
+        else:
+            write = jnp.where(batch["seq_valid"] & (batch["seq_ntok"] > 0), slot, n_slots)
+        read = jnp.minimum(slot, n_slots - 1)
+
+        with jax.named_scope("conv"):
+            tail = jnp.where(started[:, None, None], conv_pool[mi, read], 0)
+            wt, b = mp["conv1d"]["kernel"], mp["conv1d"]["bias"]
+            if one_token:
+                xbc, tail = ssm.conv_step(xbc, wt, b, tail)
+            else:
+                xbc, tail = ssm.conv_ragged(xbc, wt, b, tail, batch["token_seq"],
+                                            batch["last_tok"] - batch["seq_ntok"] + 1,
+                                            batch["seq_ntok"])
+            conv_pool = conv_pool.at[mi, write].set(tail, mode="drop")
+            xbc = jax.nn.silu(xbc).astype(h.dtype)
+            x, B, C = jnp.split(xbc, [D, D + G * N], axis=-1)
+            x, B, C = x.reshape(T, H, P), B.reshape(T, G, N), C.reshape(T, G, N)
+        with jax.named_scope("step" if one_token else "scan"):
+            if one_token:
+                y, ssm_pool = self._step_in_place(ssm_pool, mi, slot, batch["token_valid"],
+                                                  started, x, dt, A, B, C)
+            else:
+                state = jnp.where(started[:, None, None, None], ssm_pool[mi, read], 0.0)
+                onehot = ssm.segments(batch["token_seq"], batch["token_valid"], slot.shape[0])
+                y, state = ssm.scan_ragged(x, dt, A, B, C, state, onehot, w.chunk)
+                ssm_pool = ssm_pool.at[mi, write].set(state.astype(ssm_pool.dtype), mode="drop")
+            y = y + mp["D"].astype(jnp.float32)[None, :, None] * x.astype(jnp.float32)
+        with jax.named_scope("gate_norm"):
+            y = ssm.gated_norm(y.reshape(T, D), z, mp["norm"]["weight"], G, w.eps).astype(h.dtype)
+        with jax.named_scope("out_proj"):
+            if w.out_scale != 1.0:
+                return scaled_dot(y, mp["out_proj"]["kernel"], w.out_scale), (ssm_pool, conv_pool)
+            return y @ mp["out_proj"]["kernel"].astype(h.dtype), (ssm_pool, conv_pool)

@@ -52,6 +52,12 @@ def _register_nemotron_h():
     register_policy("nemotron_h", NemotronHConfig, NemotronHV2Model)
 
 
+def _register_falcon_h1():
+    from deepspeed_tpu.models.falcon_h1 import FalconH1Config
+    from deepspeed_tpu.inference.v2.model_implementations.falcon_h1_v2 import FalconH1V2Model
+    register_policy("falcon_h1", FalconH1Config, FalconH1V2Model)
+
+
 def _register_builtin():
     from deepspeed_tpu.models.afmoe import AfmoeConfig
     from deepspeed_tpu.models.decoder import DecoderConfig
@@ -85,6 +91,10 @@ def _register_builtin():
     # sequence's (a per-sequence state group beside the K/V array), relu^2
     # experts, attention without position encoding, one mixer a block
     _ON_FIRST_USE["nemotron_h"] = _register_nemotron_h
+    # serving only: a Mamba-2 mixer beside attention in EVERY layer (K/V and a
+    # per-sequence state in each), fourteen forward multipliers, one sequence
+    # bucket; nothing in common with "falcon" below but the name
+    _ON_FIRST_USE["falcon_h1"] = _register_falcon_h1
     register_policy("opt", DecoderConfig, DecoderV2Model)
     register_policy("falcon", DecoderConfig, DecoderV2Model)
     register_policy("phi", DecoderConfig, DecoderV2Model)

@@ -110,6 +110,7 @@ class DSTransformerModelBase:
                              cache_shape=(self.num_kv_layers, self.num_kv_heads, self.head_dim),
                              state_widths=self.kv_state_widths,
                              min_table_bucket=self.min_table_bucket,
+                             min_sequence_bucket=self.min_sequence_bucket,
                              cache_dtype=cache_dtype,
                              sequence_state=self.sequence_state,
                              sequence_slots=sm.max_tracked_sequences if self.sequence_state else 0)
@@ -137,6 +138,13 @@ class DSTransformerModelBase:
         """The smallest block-table bucket (``KVCacheConfig.min_table_bucket``);
         a model with one program for every table up to some length says so."""
         return 4
+
+    @property
+    def min_sequence_bucket(self) -> int:
+        """The smallest sequence bucket (``KVCacheConfig.min_sequence_bucket``),
+        which the token bucket starts at too; a model with one program for
+        every batch up to some count of sequences says so."""
+        return 8
 
     # what a forward program's count of routed work holds, last axis of the
     # device array it returns beside its result where there is more than one
@@ -288,9 +296,10 @@ class DSTransformerModelBase:
         # a batch holds a token and a KV block for each of its sequences
         most = min(sm.max_ragged_sequence_count, sm.max_ragged_batch_size,
                    self._state_manager.kv_cache.num_blocks)
-        for rows in sequence_buckets(most):
+        least = self.min_sequence_bucket
+        for rows in sequence_buckets(most, least):
             sampling.compiled(rows, self.vocab_size, placed)
-            for tokens in token_buckets(sm.max_ragged_batch_size):
+            for tokens in token_buckets(sm.max_ragged_batch_size, least):
                 sampling.compiled_chain(tokens, rows)
             if chunk_steps > 1:
                 sampling.compiled_last_row(chunk_steps, rows)
@@ -354,10 +363,12 @@ class DSTransformerModelBase:
         the bucket to the compile watch, and an analysis-only lowering must
         not pollute the bucket-churn recompile telemetry."""
         if bucket is None:
-            from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (_pad_to,
-                                                                          _pow2_pad,
-                                                                          to_padded)
-            bucket = (to_padded(1), _pad_to(1, 8), _pow2_pad(1, self.min_table_bucket))
+            from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (_pow2_pad,
+                                                                          padded_sequences,
+                                                                          padded_tokens)
+            least = self.min_sequence_bucket
+            bucket = (padded_tokens(1, least), padded_sequences(1, least),
+                      _pow2_pad(1, self.min_table_bucket))
         T, S, MB = bucket
         return {"tok_meta": np.zeros((4, T), np.int32),
                 "seq_meta": np.full((S, 4 + self.kv_groups * MB + self._slot_columns), -1,

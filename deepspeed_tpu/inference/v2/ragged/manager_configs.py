@@ -49,6 +49,10 @@ class KVCacheConfig(DeepSpeedConfigModel):
     # programs differ by bucket, and a model that has one program for every
     # table up to some length says so here
     min_table_bucket: int = Field(4, gt=0)
+    # the smallest sequence bucket a batch is padded to (a multiple of 8; the
+    # token bucket starts at it): a model with one program for every batch up
+    # to some count of sequences says so here
+    min_sequence_bucket: int = Field(8, gt=0)
     cache_dtype: str = "bfloat16"
     # A per-SEQUENCE state group (a state-space layer's recurrent state, its
     # convolution's tail): one pool a spec, ``sequence_slots`` slots each, a

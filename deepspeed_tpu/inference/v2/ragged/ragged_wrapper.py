@@ -6,7 +6,8 @@ KV block lists, finalized into device tensors once per forward).
 
 TPU design: XLA needs static shapes, so ``finalize()`` pads every buffer to a
 *bucket*: token count rounded up with :func:`to_padded`, sequence count to a
-multiple of 8, per-sequence block count to a multiple of 4. Each distinct bucket
+multiple of 8 (at least the model's ``min_sequence_bucket``, which the token
+count then starts at too), per-sequence block count to a power of two. Each distinct bucket
 shape compiles once; steady-state decode reuses one bucket. Padded token slots
 carry an out-of-range KV block id so cache scatters drop them (XLA scatter
 ``mode=drop`` — no masking pass needed).
@@ -37,16 +38,28 @@ def _pad_to(n: int, mult: int) -> int:
     return max(mult, (n + mult - 1) // mult * mult)
 
 
-def sequence_buckets(most: int):
+def padded_sequences(n: int, least: int = 8) -> int:
+    """A batch of ``n`` sequences' bucket S: a multiple of 8, at least the
+    model's smallest (``KVCacheConfig.min_sequence_bucket``)."""
+    return max(_pad_to(n, 8), least)
+
+
+def padded_tokens(n: int, least: int = 8) -> int:
+    """A batch of ``n`` tokens' bucket T under a smallest sequence bucket of
+    ``least``: a batch is padded to at least a token a sequence row."""
+    return to_padded(max(n, least))
+
+
+def sequence_buckets(most: int, least: int = 8):
     """The padded sequence counts (a bucket's S) that batches of 1 to
     ``most`` sequences land in."""
-    return sorted({_pad_to(n, 8) for n in range(1, most + 1)})
+    return sorted({padded_sequences(n, least) for n in range(1, most + 1)})
 
 
-def token_buckets(most: int):
+def token_buckets(most: int, least: int = 8):
     """The padded token counts (a bucket's T) that batches of 1 to ``most``
     tokens land in."""
-    return sorted({to_padded(n) for n in range(1, most + 1)})
+    return sorted({padded_tokens(n, least) for n in range(1, most + 1)})
 
 
 def _pow2_pad(n: int, minimum: int = 4) -> int:
@@ -63,11 +76,14 @@ class RaggedBatchWrapper:
     """Host-side composition of one ragged forward batch."""
 
     def __init__(self, config: DSStateManagerConfig, block_size: int = 128,
-                 num_groups: int = 1, min_table_bucket: int = 4, state_slots: int = 0) -> None:
+                 num_groups: int = 1, min_table_bucket: int = 4, state_slots: int = 0,
+                 min_sequence_bucket: int = 8) -> None:
         """``num_groups``: block tables a sequence (KV layer groups,
         ``ragged/kv_cache.py``); the batch carries them side by side.
         ``min_table_bucket``: the smallest block-table bucket
-        (``KVCacheConfig.min_table_bucket``). ``state_slots``: the slots of a
+        (``KVCacheConfig.min_table_bucket``), ``min_sequence_bucket`` the
+        smallest sequence bucket (``KVCacheConfig.min_sequence_bucket``).
+        ``state_slots``: the slots of a
         per-sequence state group (``KVCacheConfig.sequence_slots``); over 0,
         ``seq_meta`` carries each sequence's slot as one more column behind
         its block tables."""
@@ -75,6 +91,7 @@ class RaggedBatchWrapper:
         self._block_size = block_size
         self._num_groups = num_groups
         self._min_table_bucket = min_table_bucket
+        self._min_sequence_bucket = min_sequence_bucket
         self._state_slots = state_slots
         self.clear()
 
@@ -151,8 +168,8 @@ class RaggedBatchWrapper:
 
     def finalize(self):
         """Pad to the bucket and build the device-ready numpy struct."""
-        T = to_padded(max(1, self.current_tokens))
-        S = _pad_to(max(1, self.current_sequences), 8)
+        T = padded_tokens(self.current_tokens, self._min_sequence_bucket)
+        S = padded_sequences(self.current_sequences, self._min_sequence_bucket)
         mb = max((b.shape[1] for b in self._seq_blocks), default=1)
         MB = _pow2_pad(mb, self._min_table_bucket)
         G = self._num_groups

@@ -282,6 +282,44 @@ def test_one_token_passes_for_every_group_size(rep, window):
     _check_tile_grid(seqs, 64, H=2 * rep, kvh=2, bs=WBS, NB=80, MB=32, window=window)
 
 
+@pytest.mark.parametrize("grid", ["token-grid", "tile-chunk-and-decode-rows",
+                                  "tile-one-token-rows"])
+def test_five_queries_a_kv_head_on_both_grids(grid):
+    """Falcon-H1's 20 query heads over 4 K/V heads (PR 47): FIVE queries a K/V
+    head, the first group size served that is no power of two (a one-token
+    pass pads 5 rows to a sublane tile; the per-token grid reshapes 20 heads to
+    4 x 5)."""
+    H, kvh = 20, 4
+    if grid != "token-grid":
+        seqs, T = {"tile-chunk-and-decode-rows": ([(3, 1), (20, 100), (33, 1), (0, 1)], 128),
+                   "tile-one-token-rows": ([(17, 1), (0, 1), (33, 1), (63, 1), (64, 1)], 64)}[grid]
+        _check_tile_grid(seqs, T, H=H, kvh=kvh, bs=16, NB=40, MB=8)
+        return
+    rng = np.random.default_rng(5)
+    L, NB, bs, D, S, MB, T = 2, 12, 16, 128, 3, 4, 8
+    cache0 = rng.normal(size=(L, 2, NB, kvh, bs, D)).astype(np.float32)
+    table = rng.permutation(NB)[:S * MB].reshape(S, MB).astype(np.int32)
+    token_seq = np.array([0, 1, 2] + [S] * 5, np.int32)
+    token_pos = np.array([20, 7, 0] + [0] * 5, np.int32)
+    token_valid = (np.arange(T) < 3).astype(np.int32)
+    q = rng.normal(size=(T, H, D)).astype(np.float32)
+    k_new = rng.normal(size=(T, kvh, D)).astype(np.float32)
+    v_new = rng.normal(size=(T, kvh, D)).astype(np.float32)
+    exp_cache = cache0.copy()
+    for t in range(3):
+        bid = table[token_seq[t], token_pos[t] // bs]
+        exp_cache[:, 0, bid, :, token_pos[t] % bs] = k_new[t]
+        exp_cache[:, 1, bid, :, token_pos[t] % bs] = v_new[t]
+    cache = jnp.asarray(cache0)
+    for li in range(L):
+        got, cache = paged_attention_update(q, k_new, v_new, cache, li, jnp.asarray(table),
+                                            token_seq, token_pos, token_valid)
+        want = _dense_reference(q, jnp.asarray(exp_cache), li, table,
+                                np.minimum(token_seq, S - 1), token_pos, token_valid)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(cache), exp_cache)
+
+
 def _passes_by_the_kernels_rule(seq_ntok, last_tok, bucket_tokens, tq=64):
     """(sequence, tile) pairs and those with lo == hi, as ``_tiled_kernel``'s
     ``sequence`` decides them, one pair at a time."""

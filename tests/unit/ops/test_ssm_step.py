@@ -14,10 +14,14 @@ from deepspeed_tpu.ops.pallas import ssm_step
 TOL = 1e-5
 # (H, P, N, G): the published widths cut in heads only (units of two heads); a
 # tiny shape whose every head is less than one transpose high (one padded unit);
-# two lane tiles of state, a group a head, six heads where a unit would be eight
-PUBLISHED, TINY, WIDE = (16, 64, 128, 2), (8, 8, 128, 2), (6, 16, 256, 3)
-SHAPES = pytest.mark.parametrize("shape", [PUBLISHED, TINY, WIDE],
-                                 ids=["published-16-heads", "tiny", "two-lane-tiles"])
+# two lane tiles of state, a group a head, six heads where a unit would be eight;
+# Falcon-H1-34B's published widths whole (PR 47): a unit is ONE head (P = 128 is
+# a whole transpose), two lane tiles a state row, 16 heads a group, 8 tiles a row
+PUBLISHED, TINY, WIDE, FALCON_H1 = (16, 64, 128, 2), (8, 8, 128, 2), (6, 16, 256, 3), \
+    (32, 128, 256, 2)
+SHAPES = pytest.mark.parametrize("shape", [PUBLISHED, TINY, WIDE, FALCON_H1],
+                                 ids=["published-16-heads", "tiny", "two-lane-tiles",
+                                      "falcon-h1-32-heads-of-128x256"])
 
 
 def _case(shape, slot, live, started, blocks=2, slots=8, seed=0, fill=None):
@@ -56,7 +60,7 @@ def _close(got, want):
 @SHAPES
 def test_every_row_live_is_the_recurrence_on_the_gathered_states(shape):
     assert ssm_step.supported(*shape)
-    assert ssm_step.tiling(*shape[:3]) == {PUBLISHED: (2, 16), TINY: (8, 8), WIDE: (6, 6)}[shape]
+    assert ssm_step.tiling(*shape[:3]) == {PUBLISHED: (2, 16), TINY: (8, 8), WIDE: (6, 6), FALCON_H1: (1, 4)}[shape]
     pool, rows = _case(shape, slot=[5, 0, 3, 6], live=[1] * 4, started=[1] * 4)
     y, got = ssm_step.ssm_step_in_place(jnp.asarray(pool), 1, *rows)
     want_y, want = _reference(pool, 1, *rows)

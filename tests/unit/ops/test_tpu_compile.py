@@ -600,19 +600,19 @@ def _nemotron_args(device, model, abstract, bucket):
     return one, params, cache, batch
 
 
-def _state_sized_results(text, rows):
+def _state_sized_results(text, rows, per_row=64 * 64 * 128, pool=(6, NEMOTRON_SLOTS)):
     """Instructions whose result holds a Mamba-2 state a ROW of the batch
-    (``rows`` x 64 x 64 x 128 float32 or more) and is no pool: the two forms
-    keep a state a sequence, never a state a token."""
+    (``rows`` x ``per_row`` float32 or more: Nemotron's 64 x 64 x 128 unless
+    told) and is no pool (leading dimensions ``pool``): the two forms keep a
+    state a sequence, never a state a token."""
     import re
-    per_row = 64 * 64 * 128
     out = []
     for line in text.splitlines():
         m = re.search(r"= f32\[([\d,]+)\]", line)
         if not m:
             continue
         dims = [int(d) for d in m.group(1).split(",")]
-        if dims[:2] == [6, NEMOTRON_SLOTS]:
+        if tuple(dims[:2]) == tuple(pool):
             continue  # the pool itself, updated in place
         if int(np.prod(dims)) >= rows * per_row:
             out.append(line.strip()[:160])
@@ -659,5 +659,93 @@ def test_nemotron_decode_loop_program_fits_one_chip(v5e, nemotron_model):
     assert len(kernels) == 6 and all("ssm/step" in line for line in kernels), kernels
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert not _state_sized_results(text, rows=8)
+    out = jax.eval_shape(loop, params, cache, batch)
+    assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
+
+
+# ---- falcon-h1-34b-serve-1chip: K/V AND a per-sequence state in every layer (PR 47) ----
+FALCON_LAYERS, FALCON_SLOTS, FALCON_BLOCKS, FALCON_BLOCK, FALCON_SEQS = 6, 64, 704, 128, 32
+
+
+@pytest.fixture(scope="module")
+def falcon_h1_model():
+    """``falcon-h1-34b-serve-1chip``: Falcon-H1-34B-Instruct's published widths
+    and whole vocabulary, 6 of 72 layers, contexts to 2048, 32 sequences a
+    step, over ``jax.eval_shape``d parameters."""
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.model_implementations.registry import model_cls_for
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import DSStateManagerConfig
+    from deepspeed_tpu.models import falcon_h1
+    cfg = falcon_h1.FalconH1Config(num_hidden_layers=FALCON_LAYERS)
+    abstract = jax.eval_shape(lambda: falcon_h1.init_params(cfg, param_dtype=cfg.dtype)[1])
+    engine_config = RaggedInferenceEngineConfig(
+        state_manager=DSStateManagerConfig(max_context=2048, max_ragged_batch_size=256,
+                                           max_ragged_sequence_count=FALCON_SEQS,
+                                           max_tracked_sequences=FALCON_SLOTS),
+        kv_block_size=FALCON_BLOCK, use_paged_kernel=True)
+    model = model_cls_for(cfg)(abstract, cfg, engine_config)
+    assert model.num_kv_layers == FALCON_LAYERS and model.min_table_bucket == 16
+    assert model.min_sequence_bucket == FALCON_SEQS
+    assert [(s.name, s.layers, s.shape, s.dtype) for s in model.sequence_state] == [
+        ("ssm", FALCON_LAYERS, (32, 128, 256), "float32"),
+        ("conv", FALCON_LAYERS, (3, 5120), "bfloat16")]
+    return model, abstract
+
+
+def _falcon_h1_args(device, abstract, bucket):
+    one = SingleDeviceSharding(device)
+    tokens, seqs, max_blocks = bucket
+    params = jax.tree.map(lambda leaf: _on(one, leaf.shape, leaf.dtype), abstract)
+    cache = (_on(one, (FALCON_LAYERS, 2, FALCON_BLOCKS, 4, FALCON_BLOCK, 128), jnp.bfloat16),
+             _on(one, (FALCON_LAYERS, FALCON_SLOTS, 32, 128, 256), jnp.float32),
+             _on(one, (FALCON_LAYERS, FALCON_SLOTS, 3, 5120), jnp.bfloat16))
+    batch = {"tok_meta": _on(one, (4, tokens), jnp.int32),
+             "seq_meta": _on(one, (seqs, 4 + max_blocks + 1), jnp.int32)}
+    return params, cache, batch
+
+
+def _falcon_state_sized_results(text, rows):
+    return _state_sized_results(text, rows, per_row=32 * 128 * 256,
+                                pool=(FALCON_LAYERS, FALCON_SLOTS))
+
+
+@pytest.mark.parametrize("bucket,kernel", [((32, 32, 16), "paged_attention_update"),
+                                           ((256, 32, 16), "paged_attention_prefill")],
+                         ids=["smallest-bucket", "chunk-bucket"])
+def test_falcon_h1_put_program_fits_one_chip(v5e, falcon_h1_model, bucket, kernel):
+    """9.8 GiB of weights beside 1 GiB of K/V and 1.5 GiB of float32 state in 64
+    slots: both grids of the paged kernel at FIVE query heads a K/V head, the
+    chunked scan at (heads, head, state) = (32, 128, 256) with the state a
+    SEQUENCE (32 of them gathered, never a state a token), every layer writing
+    its own layer of the K/V array and its own slot pools."""
+    model, abstract = falcon_h1_model
+    assert model._synthetic_batch()["seq_meta"].shape[0] == FALCON_SEQS
+    params, cache, batch = _falcon_h1_args(v5e[0], abstract, bucket)
+    compiled = jax.jit(model._forward_impl, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and kernel in text
+    assert "ssm/scan" in text and "ssm/step" not in text
+    assert _device_bytes(compiled) < 0.95 * HBM_BYTES
+    assert not _falcon_state_sized_results(text, rows=4 * FALCON_SEQS)
+    out = jax.eval_shape(model._forward_impl, params, cache, batch)
+    assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
+
+
+def test_falcon_h1_decode_loop_program_fits_one_chip(v5e, falcon_h1_model):
+    """The real-width ``decode_loop`` program of 32 rows: one ``ssm_step_in_place``
+    a layer at (32, 128, 256, 2) over the pool itself (no row's state outside
+    it), the per-token paged kernel at five queries a K/V head, and the pools
+    handed back in the types they came in."""
+    model, abstract = falcon_h1_model
+    params, cache, batch = _falcon_h1_args(v5e[0], abstract, (32, 32, 16))
+    loop = functools.partial(model._decode_loop_impl, n_steps=8)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert "paged_attention_update" in text and "ssm/scan" not in text
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "ssm_step_in_place" in line]
+    assert len(kernels) == FALCON_LAYERS and all("ssm/step" in line for line in kernels), kernels
+    assert _device_bytes(compiled) < 0.95 * HBM_BYTES
+    assert not _falcon_state_sized_results(text, rows=8)
     out = jax.eval_shape(loop, params, cache, batch)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
