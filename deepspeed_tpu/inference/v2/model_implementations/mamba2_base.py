@@ -13,12 +13,15 @@ engine, the mixer over a step's rows, and the step's counters.
   Padding rows point one past the last slot and their writes drop;
 - **two forms of the scan** (``modules/ssm.py``): a ``put`` step runs the
   chunked form over the ragged batch, each sequence's segment starting from
-  its slot's state, gathered, and leaving its final state there, scattered; a
+  its slot's state and leaving its final state there: two kernels a mixer
+  copy each live sequence's state out of and into its slot of the pool
+  itself (``ops/pallas/ssm_store.py``; XLA's gather sliced the whole pool); a
   ``decode_loop`` step (``one_token_rows``) runs the recurrence, one token a
   sequence, IN the pool: one kernel a mixer reads a row's slot, updates it
   and writes it back (``ops/pallas/ssm_step.py``), so no ``[rows, H, P, N]``
-  exists in that program. A pool off the kernel's shape rule
-  (``ssm.in_place``) runs ``ssm.step`` between a gather and a scatter.
+  exists in that program. A pool off a kernel's shape rule
+  (``ssm.whole_slots`` / ``ssm.in_place``) is gathered and scattered by XLA /
+  runs ``ssm.step`` between the two.
 
 Scopes in the device trace, under ``ssm``: ``in_proj``, ``conv``, ``scan``
 (the chunked form) or ``step`` (the recurrence), ``gate_norm``, ``out_proj``.
@@ -91,6 +94,10 @@ class Mamba2Model(DSTransformerModelBase):
         of a chunk); ``ssm_segments``, sequence segments scanned (a segment a
         live sequence a mixer a step); ``ssm_slots_live`` / ``ssm_slots_total``,
         the per-sequence state group's slots held as the step is dispatched;
+        ``ssm_segments_in_place``, those of a ``put``'s ``ssm_segments`` whose
+        final state a kernel left in its slot of the pool: all of them, or 0
+        where the pool is off ``ssm.whole_slots``'s rule (nothing reads a
+        chunk's entry: its rows are ``ssm_rows_in_place``'s);
         where the caller gives ``steps`` (the engine does, a chunk's or 1 for a
         ``put``, whose entry nothing reads) ``ssm_rows_in_place``, those of a
         ``decode_loop`` chunk's ``ssm_tokens`` whose state the kernel updated in
@@ -104,21 +111,24 @@ class Mamba2Model(DSTransformerModelBase):
                       ssm_segments=steps * int(batch["n_seqs"]) * w.mixers,
                       ssm_slots_live=kv.num_slots - (kv.free_slots or 0),
                       ssm_slots_total=kv.num_slots)
+        stored = ssm.whole_slots(kv.cache[1])
+        counts["ssm_segments_in_place"] = counts["ssm_segments"] if stored else 0
         if chunk:
             in_place = ssm.in_place(kv.cache[1], w.groups)
             counts["ssm_rows_in_place"] = counts["ssm_tokens"] if in_place else 0
         return counts
 
-    def _step_in_place(self, pool, mi, *rows):
-        """``ssm.step_in_place`` on mixer ``mi`` of the pool. The SPMD
+    def _in_the_pool(self, update, pool, mi, *rows):
+        """``update(pool, mi, *rows)``: ``ssm.step_in_place``, ``ssm.load`` or
+        ``ssm.store_in_place`` on mixer ``mi`` of the pool. The SPMD
         partitioner cannot split a Mosaic kernel: on a mesh every device runs
         it over the pool it holds whole (``kv_cache._pool_sharding``), as
         ``_paged_attention`` runs its kernel."""
         placed = None if self._state_manager is None else self._state_manager.kv_cache.sharding
-        if placed is None or placed.mesh.size == 1 or not ssm.in_place(pool, rows[-1].shape[1]):
-            return ssm.step_in_place(pool, mi, *rows)
+        if placed is None or placed.mesh.size == 1:
+            return update(pool, mi, *rows)
         from jax.sharding import PartitionSpec as P
-        return jax.shard_map(ssm.step_in_place, mesh=placed.mesh, in_specs=P(), out_specs=P(),
+        return jax.shard_map(update, mesh=placed.mesh, in_specs=P(), out_specs=P(),
                              check_vma=False)(pool, jnp.int32(mi), *rows)
 
     @jax.named_scope("ssm")
@@ -148,9 +158,10 @@ class Mamba2Model(DSTransformerModelBase):
         if one_token:  # decode_loop: row t is sequence token_seq[t]'s one token
             of = batch["token_seq"]
             slot, started = slot[of], started[of]
-            write = jnp.where(batch["token_valid"], slot, n_slots)
-        else:
-            write = jnp.where(batch["seq_valid"] & (batch["seq_ntok"] > 0), slot, n_slots)
+            live = batch["token_valid"]
+        else:  # put: a sequence without tokens in the step keeps its state
+            live = batch["seq_valid"] & (batch["seq_ntok"] > 0)
+        write = jnp.where(live, slot, n_slots)
         read = jnp.minimum(slot, n_slots - 1)
 
         with jax.named_scope("conv"):
@@ -168,13 +179,13 @@ class Mamba2Model(DSTransformerModelBase):
             x, B, C = x.reshape(T, H, P), B.reshape(T, G, N), C.reshape(T, G, N)
         with jax.named_scope("step" if one_token else "scan"):
             if one_token:
-                y, ssm_pool = self._step_in_place(ssm_pool, mi, slot, batch["token_valid"],
-                                                  started, x, dt, A, B, C)
+                y, ssm_pool = self._in_the_pool(ssm.step_in_place, ssm_pool, mi, slot, live,
+                                                started, x, dt, A, B, C)
             else:
-                state = jnp.where(started[:, None, None, None], ssm_pool[mi, read], 0.0)
+                state = self._in_the_pool(ssm.load, ssm_pool, mi, slot, started)
                 onehot = ssm.segments(batch["token_seq"], batch["token_valid"], slot.shape[0])
                 y, state = ssm.scan_ragged(x, dt, A, B, C, state, onehot, w.chunk)
-                ssm_pool = ssm_pool.at[mi, write].set(state.astype(ssm_pool.dtype), mode="drop")
+                ssm_pool = self._in_the_pool(ssm.store_in_place, ssm_pool, mi, slot, live, state)
             y = y + mp["D"].astype(jnp.float32)[None, :, None] * x.astype(jnp.float32)
         with jax.named_scope("gate_norm"):
             y = ssm.gated_norm(y.reshape(T, D), z, mp["norm"]["weight"], G, w.eps).astype(h.dtype)

@@ -1,10 +1,12 @@
 """The Mamba-2 mixer's state-space part over a ragged batch, in two forms that
 agree (tier-1 holds them against each other and against the token-by-token
 reference, ``tests/unit/inference/v2/test_nemotron_h.py``). Which runs where:
-a ``put`` step runs :func:`scan_ragged` on its sequences' gathered states; a
-``decode_loop`` step runs :func:`step_in_place`, the recurrence inside the
-engine's pool; :func:`step` is the recurrence as written, the reference the
-other two are held to and what a shape off the kernel's rule falls back to.
+a ``put`` step runs :func:`scan_ragged` on its sequences' states, each read
+from its slot of the engine's pool by :func:`load` and left there by
+:func:`store_in_place`; a ``decode_loop`` step runs :func:`step_in_place`, the
+recurrence inside that pool; :func:`step` is the recurrence as written, the
+reference the other two are held to and what a shape off the kernel's rule
+falls back to.
 
 A head's state is ``h_t = exp(dt_t a) h_{t-1} + dt_t x_t (x) B_t`` in
 ``R^{P x N}``, float32, and ``y_t = h_t C_t``; heads read B and C by group
@@ -45,7 +47,7 @@ sequence, those ahead of a segment's first row from the sequence's kept tail.
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.ops.pallas import ssm_step
+from deepspeed_tpu.ops.pallas import ssm_step, ssm_store
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 _HIGH = jax.lax.Precision.HIGH
@@ -181,12 +183,39 @@ def step_in_place(pool, block, slot, live, started, x, dt, A, B, C):
     dead row's ``y`` is nobody's."""
     if in_place(pool, B.shape[1]):
         return ssm_step.ssm_step_in_place(pool, block, slot, live, started, x, dt, A, B, C)
-    n_slots = pool.shape[1]
-    state = jnp.where(started[:, None, None, None],
-                      pool[block, jnp.minimum(slot, n_slots - 1)], 0.0)
-    y, state = step(x, dt, A, B, C, state)
-    return y, pool.at[block, jnp.where(live, slot, n_slots)].set(state.astype(pool.dtype),
-                                                                 mode="drop")
+    y, state = step(x, dt, A, B, C, load(pool, block, slot, started))
+    return y, store_in_place(pool, block, slot, live, state)
+
+
+def whole_slots(pool) -> bool:
+    """Whether :func:`load` and :func:`store_in_place` run their kernel on this
+    pool ``[blocks, slots, ...]``: by its type alone, the same answer on every
+    backend."""
+    return ssm_store.supported(pool.shape)
+
+
+def load(pool, block, slot, started):
+    """``[S, ...]``: row i is slot ``slot[i]`` of the pool's block ``block``
+    where ``started[i]`` and zeros where not, whatever the slot held (one past
+    the last included). One Pallas kernel over the pool itself
+    (``ops/pallas/ssm_store.py``) where :func:`whole_slots`: XLA's gather of
+    rows above 2 MiB first slices the whole pool."""
+    if whole_slots(pool):
+        rows = ssm_store.ssm_load(pool, block, slot, started)
+    else:
+        rows = pool[block, jnp.minimum(slot, pool.shape[1] - 1)]
+    return jnp.where(started.reshape((-1, ) + (1, ) * (rows.ndim - 1)), rows, 0)
+
+
+def store_in_place(pool, block, slot, live, states):
+    """``states[i]`` ``[S, ...]`` left in slot ``slot[i]`` of the pool's block
+    ``block`` where ``live[i]``; a row that is not live writes nothing. Live
+    rows hold distinct slots. The same kernel the other way, the pool aliased
+    in and out, where :func:`whole_slots`. Returns the pool."""
+    states = states.astype(pool.dtype)
+    if whole_slots(pool):
+        return ssm_store.ssm_store_in_place(pool, block, slot, live, states)
+    return pool.at[block, jnp.where(live, slot, pool.shape[1])].set(states, mode="drop")
 
 
 def gated_norm(y, z, weight, groups: int, eps: float):

@@ -131,8 +131,9 @@ def test_prefill_in_uneven_chunks_then_decode_is_the_references_full_forward(req
 def test_the_dispatch_spans_carry_the_states_counters_and_the_sequence_bucket(model_in_place):
     """``inference.put`` and ``inference.decode_loop``: ``ssm_tokens`` /
     ``ssm_segments`` over the model's layers (each has a Mamba-2 mixer), the
-    slots held, a chunk's rows updated in place, and the step's live sequences
-    beside the sequence count it was padded to."""
+    slots held, a ``put``'s final states left in their slots by the kernel, a
+    chunk's rows updated in place, and the step's live sequences beside the
+    sequence count it was padded to."""
     from deepspeed_tpu import telemetry
     cfg, params = model_in_place
     session = telemetry.configure({"enabled": True, "compile_watch": False})
@@ -145,6 +146,7 @@ def test_the_dispatch_spans_carry_the_states_counters_and_the_sequence_bucket(mo
         loop = next(s for s in rows if s["name"] == "decode_loop"
                     and s["cat"] == "inference")["args"]
         assert (put["ssm_tokens"], put["ssm_segments"]) == (19 * 3, 3 * 3)
+        assert put["ssm_segments_in_place"] == put["ssm_segments"]  # a slot is whole tiles
         assert (put["ssm_slots_live"], put["ssm_slots_total"]) == (3, 5)
         assert put["tokens"] == 19
         assert loop["ssm_tokens"] == loop["ssm_rows_in_place"] == 2 * 3 * 3 and loop["steps"] == 2

@@ -274,12 +274,16 @@ def test_a_slot_reused_after_flush_starts_from_zero_and_padding_writes_nothing(r
 def test_a_chunks_counts_say_which_rows_the_kernel_served(request, which, share):
     """``ssm_rows_in_place`` beside ``ssm_tokens`` on a ``decode_loop`` chunk's
     counts: every row where the pool is on the kernel's rule, 0 where it falls
-    back; a ``put``'s counts do not have the key."""
+    back; a ``put``'s counts do not have the key. ``ssm_segments_in_place``
+    beside ``ssm_segments`` on a ``put``'s: every segment where a slot of the
+    pool is whole tiles (``ssm.whole_slots``), 0 where XLA scatters it."""
     cfg, params = request.getfixturevalue(which)
     engine = engine_of(cfg, params)
     engine.put([0, 1], [_ids(40, 9), _ids(41, 5)])
     put = engine.model.batch_counts(engine._batch)
     assert put["ssm_tokens"] == 14 * 3 and "ssm_rows_in_place" not in put
+    assert ssm.whole_slots(engine._state_manager.kv_cache.cache[1]) == bool(share)
+    assert put["ssm_segments"] == 2 * 3 and put["ssm_segments_in_place"] == share * 2 * 3
     engine.decode_loop([0, 1], [_ids(42, 1), _ids(43, 1)], 4)
     chunk = engine.model.batch_counts(engine._batch, 4)
     assert chunk["ssm_tokens"] == 2 * 3 * 4 and chunk["ssm_segments"] == 2 * 3 * 4

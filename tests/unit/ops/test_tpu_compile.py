@@ -613,10 +613,45 @@ def _state_sized_results(text, rows, per_row=64 * 64 * 128, pool=(6, NEMOTRON_SL
             continue
         dims = [int(d) for d in m.group(1).split(",")]
         if tuple(dims[:2]) == tuple(pool):
-            continue  # the pool itself, updated in place
+            continue  # the pool itself: _pool_shaped_results' to judge
         if int(np.prod(dims)) >= rows * per_row:
             out.append(line.strip()[:160])
     return out
+
+
+def _pool_shaped_results(text, pool):
+    """Instructions with a result (or a member of a tuple result) that has the
+    state pool's leading dimensions ``pool`` = (mixers, slots, heads, head) in
+    float32 — the pool, or a piece of it whatever its last dimension — and that
+    are not the pool passing through: parameters, tuples and their elements,
+    bitcasts, and the store kernel's result, which IS its operand (aliased).
+    XLA cuts a gather of rows above 2 MiB by first slicing its operand: a pass
+    over the whole pool a mixer that PR 47's check, which skipped pool-shaped
+    results as "updated in place", could not see (PERF.md section 6, PR 48)."""
+    import re
+    out = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z\-]*)\(", line)
+        if not m or m.group(2) in ("parameter", "tuple", "get-tuple-element", "bitcast"):
+            continue
+        shapes = [tuple(int(d) for d in dims.split(","))
+                  for dims in re.findall(r"f32\[([\d,]+)\]", m.group(1))]
+        if any(shape[:len(pool)] == tuple(pool) and len(shape) == len(pool) + 1 for shape in shapes) \
+                and not (m.group(2) == "custom-call" and "ssm_store_in_place" in line):
+            out.append(line.strip()[:200])
+    return out
+
+
+def _slot_copies(text, mixers):
+    """The ``put`` program's slot copies: one ``ssm_load`` and one
+    ``ssm_store_in_place`` a mixer, under ``ssm/scan`` (the scope the scan's
+    roofline readers sum). Returns False where they are not just those."""
+    for kernel in ("ssm_load", "ssm_store_in_place"):
+        calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and f"ssm/scan/{kernel}/" in line]
+        if len(calls) != mixers:
+            return False
+    return True
 
 
 @pytest.mark.parametrize("bucket,kernel,scope", [
@@ -638,6 +673,8 @@ def test_nemotron_put_program_fits_one_chip(v5e, nemotron_model, bucket, kernel,
     assert scope in text and "ssm/step" not in text
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert not _state_sized_results(text, rows=9)
+    assert _slot_copies(text, mixers=6)
+    assert not _pool_shaped_results(text, (6, NEMOTRON_SLOTS, 64, 64))
     out = jax.eval_shape(model._forward_impl, params, cache, batch)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
 
@@ -716,8 +753,10 @@ def test_falcon_h1_put_program_fits_one_chip(v5e, falcon_h1_model, bucket, kerne
     """9.8 GiB of weights beside 1 GiB of K/V and 1.5 GiB of float32 state in 64
     slots: both grids of the paged kernel at FIVE query heads a K/V head, the
     chunked scan at (heads, head, state) = (32, 128, 256) with the state a
-    SEQUENCE (32 of them gathered, never a state a token), every layer writing
-    its own layer of the K/V array and its own slot pools."""
+    SEQUENCE (32 of them read from their slots, never a state a token), every
+    layer writing its own layer of the K/V array and its own slot pools: the
+    states by one kernel each way over the pool itself, nothing else in the
+    program shaped like the pool or a piece of it (PR 48)."""
     model, abstract = falcon_h1_model
     assert model._synthetic_batch()["seq_meta"].shape[0] == FALCON_SEQS
     params, cache, batch = _falcon_h1_args(v5e[0], abstract, bucket)
@@ -725,8 +764,11 @@ def test_falcon_h1_put_program_fits_one_chip(v5e, falcon_h1_model, bucket, kerne
     text = compiled.as_text()
     assert "tpu_custom_call" in text and kernel in text
     assert "ssm/scan" in text and "ssm/step" not in text
-    assert _device_bytes(compiled) < 0.95 * HBM_BYTES
+    # 12.6-12.7 GiB: PR 47's gather held a second pool's worth of temporaries (14.0 GiB)
+    assert _device_bytes(compiled) < 0.82 * HBM_BYTES
     assert not _falcon_state_sized_results(text, rows=4 * FALCON_SEQS)
+    assert _slot_copies(text, mixers=FALCON_LAYERS)
+    assert not _pool_shaped_results(text, (FALCON_LAYERS, FALCON_SLOTS, 32, 128))
     out = jax.eval_shape(model._forward_impl, params, cache, batch)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
 
