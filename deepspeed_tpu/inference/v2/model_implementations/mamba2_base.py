@@ -11,22 +11,25 @@ engine, the mixer over a step's rows, and the step's counters.
   column of ``seq_meta``. A slot's content counts from the sequence's first
   token: a sequence with nothing seen reads zeros whatever the slot held.
   Padding rows point one past the last slot and their writes drop;
-- **two forms of the scan** (``modules/ssm.py``): a ``put`` step runs the
-  chunked form over the ragged batch, each sequence's segment starting from
-  its slot's state and leaving its final state there: two kernels a mixer
-  copy each live sequence's state out of and into its slot of the pool
-  itself (``ops/pallas/ssm_store.py``; XLA's gather sliced the whole pool); a
-  ``decode_loop`` step (``one_token_rows``) runs the recurrence, one token a
-  sequence, IN the pool: one kernel a mixer reads a row's slot, updates it
-  and writes it back (``ops/pallas/ssm_step.py``), so no ``[rows, H, P, N]``
-  exists in that program. A pool off a kernel's shape rule
-  (``ssm.whole_slots`` / ``ssm.in_place``) is gathered and scattered by XLA /
-  runs ``ssm.step`` between the two.
+- **two forms of the scan** (``modules/ssm.py``), both IN the pool: a ``put``
+  step scans by segment (``ssm.scan_in_place``), each sequence's rows
+  starting from its slot's state and leaving its final state there — the
+  segments of one row through the recurrence's kernel (row i the sequence's
+  one row), a longer one through the chunked form against ITS state alone, a
+  visit a chunk of its rows; a ``decode_loop`` step (``one_token_rows``) runs
+  the recurrence, one token a sequence: one kernel a mixer reads a row's
+  slot, updates it and writes it back (``ops/pallas/ssm_step.py``). Neither
+  program holds a ``[rows, H, P, N]`` or a ``[sequences, H, P, N]``. A pool
+  off the kernel's shape rule (``ssm.in_place``) runs ``ssm.scan_ragged`` on
+  every sequence's state between two slot-copy kernels
+  (``ops/pallas/ssm_store.py``; XLA's gather and scatter off
+  ``ssm.whole_slots`` too) / ``ssm.step`` between a gather and a scatter.
 
 Scopes in the device trace, under ``ssm``: ``in_proj``, ``conv``, ``scan``
 (the chunked form) or ``step`` (the recurrence), ``gate_norm``, ``out_proj``.
 """
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -98,6 +101,10 @@ class Mamba2Model(DSTransformerModelBase):
         final state a kernel left in its slot of the pool: all of them, or 0
         where the pool is off ``ssm.whole_slots``'s rule (nothing reads a
         chunk's entry: its rows are ``ssm_rows_in_place``'s);
+        ``ssm_segments_scanned_in_place``, those of a ``put``'s ``ssm_segments``
+        scanned in their slot by their own rows alone (``ssm.scan_in_place`` on a
+        pool on ``ssm.in_place``'s rule): all of them, or 0 where the step falls
+        back to ``ssm.scan_ragged`` on every state between the slot copies;
         where the caller gives ``steps`` (the engine does, a chunk's or 1 for a
         ``put``, whose entry nothing reads) ``ssm_rows_in_place``, those of a
         ``decode_loop`` chunk's ``ssm_tokens`` whose state the kernel updated in
@@ -112,15 +119,16 @@ class Mamba2Model(DSTransformerModelBase):
                       ssm_slots_live=kv.num_slots - (kv.free_slots or 0),
                       ssm_slots_total=kv.num_slots)
         stored = ssm.whole_slots(kv.cache[1])
+        in_place = ssm.in_place(kv.cache[1], w.groups)
         counts["ssm_segments_in_place"] = counts["ssm_segments"] if stored else 0
+        counts["ssm_segments_scanned_in_place"] = counts["ssm_segments"] if in_place else 0
         if chunk:
-            in_place = ssm.in_place(kv.cache[1], w.groups)
             counts["ssm_rows_in_place"] = counts["ssm_tokens"] if in_place else 0
         return counts
 
     def _in_the_pool(self, update, pool, mi, *rows):
-        """``update(pool, mi, *rows)``: ``ssm.step_in_place``, ``ssm.load`` or
-        ``ssm.store_in_place`` on mixer ``mi`` of the pool. The SPMD
+        """``update(pool, mi, *rows)``: ``ssm.step_in_place`` or
+        ``ssm.scan_in_place`` on mixer ``mi`` of the pool. The SPMD
         partitioner cannot split a Mosaic kernel: on a mesh every device runs
         it over the pool it holds whole (``kv_cache._pool_sharding``), as
         ``_paged_attention`` runs its kernel."""
@@ -182,10 +190,10 @@ class Mamba2Model(DSTransformerModelBase):
                 y, ssm_pool = self._in_the_pool(ssm.step_in_place, ssm_pool, mi, slot, live,
                                                 started, x, dt, A, B, C)
             else:
-                state = self._in_the_pool(ssm.load, ssm_pool, mi, slot, started)
-                onehot = ssm.segments(batch["token_seq"], batch["token_valid"], slot.shape[0])
-                y, state = ssm.scan_ragged(x, dt, A, B, C, state, onehot, w.chunk)
-                ssm_pool = self._in_the_pool(ssm.store_in_place, ssm_pool, mi, slot, live, state)
+                y, ssm_pool = self._in_the_pool(
+                    functools.partial(ssm.scan_in_place, chunk=w.chunk), ssm_pool, mi, slot, live,
+                    started, batch["last_tok"] - batch["seq_ntok"] + 1, batch["seq_ntok"],
+                    batch["token_seq"], batch["token_valid"], x, dt, A, B, C)
             y = y + mp["D"].astype(jnp.float32)[None, :, None] * x.astype(jnp.float32)
         with jax.named_scope("gate_norm"):
             y = ssm.gated_norm(y.reshape(T, D), z, mp["norm"]["weight"], G, w.eps).astype(h.dtype)

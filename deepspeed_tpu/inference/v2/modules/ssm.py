@@ -1,12 +1,22 @@
 """The Mamba-2 mixer's state-space part over a ragged batch, in two forms that
 agree (tier-1 holds them against each other and against the token-by-token
-reference, ``tests/unit/inference/v2/test_nemotron_h.py``). Which runs where:
-a ``put`` step runs :func:`scan_ragged` on its sequences' states, each read
-from its slot of the engine's pool by :func:`load` and left there by
-:func:`store_in_place`; a ``decode_loop`` step runs :func:`step_in_place`, the
-recurrence inside that pool; :func:`step` is the recurrence as written, the
-reference the other two are held to and what a shape off the kernel's rule
-falls back to.
+reference, ``tests/unit/inference/v2/test_nemotron_h.py``,
+``tests/unit/ops/test_ssm_scan.py``). Which runs where: a ``put`` step runs
+:func:`scan_in_place`, the scan by SEGMENT inside the engine's pool — a
+sequence's state is visited once a mixer, where it lies in its slot, by that
+sequence's rows alone: a segment of one row (31 of a chat step's 32) is the
+recurrence, all of them in one call of the step kernel; a longer one goes
+through the matrix form (:func:`_chunk`) against its own state, a visit a
+chunk of the batch it has rows in; a ``decode_loop`` step runs :func:`step_in_place`, the recurrence
+inside that pool; :func:`step` is the recurrence as written, the reference the
+others are held to. A pool off the kernel's shape rule (:func:`in_place`)
+falls back: a ``put`` step to :func:`scan_ragged` on EVERY sequence's state,
+each copied out of its slot by :func:`load` and back by
+:func:`store_in_place` (until PR 49 every ``put`` step: at 32 sequences a step
+that form contracts every row against every sequence's state and re-lays and
+carries all of them through HBM, 3.6 ms a mixer at Falcon-H1-34B's widths
+where the visits take 0.7; PERF.md section 6, PR 49), a ``decode_loop`` step
+to :func:`step` between a gather and a scatter.
 
 A head's state is ``h_t = exp(dt_t a) h_{t-1} + dt_t x_t (x) B_t`` in
 ``R^{P x N}``, float32, and ``y_t = h_t C_t``; heads read B and C by group
@@ -185,6 +195,76 @@ def step_in_place(pool, block, slot, live, started, x, dt, A, B, C):
         return ssm_step.ssm_step_in_place(pool, block, slot, live, started, x, dt, A, B, C)
     y, state = step(x, dt, A, B, C, load(pool, block, slot, started))
     return y, store_in_place(pool, block, slot, live, state)
+
+
+def scan_in_place(pool, block, slot, live, started, seq_start, seq_ntok, token_seq, token_valid,
+                  x, dt, A, B, C, chunk: int):
+    """A ``put`` step's scan over the pool's block ``block``, by SEGMENT:
+    sequence i's rows are the ``seq_ntok[i]`` rows from ``seq_start[i]`` of the
+    flat batch (``token_seq`` [T] each row's sequence, ``token_valid`` whether
+    it is anybody's); its state is slot ``slot[i]``'s (zeros where
+    ``started[i]`` is false, whatever the slot held) and its final state is
+    left there where ``live[i]``; a sequence that is not live, or without
+    rows, keeps its slot bit for bit. x [T, H, P]; dt [T, H]; A [H]; B, C
+    [T, G, N]. Returns ``(y [T, H, P] float32, pool)``; nobody's row reads
+    zeros.
+
+    Where :func:`in_place`, the LENGTH of a segment decides its visit, and no
+    state leaves its slot but the one being visited: a segment of one row is
+    the recurrence, all of them in one call of the step kernel (row i the
+    sequence's one row); a longer one goes through :func:`_chunk` against ITS
+    state alone, a visit a chunk of the batch it has rows in, a loop over the
+    step's visits in the segments' order (a device-side count: any number of
+    prompt chunks a step). Elsewhere :func:`scan_ragged` on every sequence's
+    state between :func:`load` and :func:`store_in_place`."""
+    T, H, P = x.shape
+    G, N = B.shape[1:]
+    S = slot.shape[0]
+    if not in_place(pool, G):
+        state = load(pool, block, slot, started)
+        y, state = scan_ragged(x, dt, A, B, C, state, segments(token_seq, token_valid, S), chunk)
+        return y, store_in_place(pool, block, slot, live, state)
+    f32 = jnp.float32
+    R, Q = H // G, min(chunk, T)
+    assert T % Q == 0, (T, chunk)
+    # one row: the recurrence in the slot
+    first = jnp.clip(seq_start, 0, T - 1)
+    one = live & (seq_ntok == 1)
+    y_one, pool = ssm_step.ssm_step_in_place(pool, block, slot, one, started, x[first], dt[first],
+                                             A, B[first], C[first])
+    # longer: the matrix form. The batch is cut into chunks of Q rows as
+    # scan_ragged cuts it; a visit is one segment's part in one chunk, its rows
+    # picked by the mask, so a chunk of y is read and written whole
+    dt = dt.astype(f32).reshape(T, G, R)
+    a = jnp.where(token_valid[:, None, None], dt * A.astype(f32).reshape(1, G, R), 0.0)
+    rows = [r.reshape((T // Q, Q) + r.shape[1:])
+            for r in (x.astype(f32).reshape(T, G, R, P), dt, a, B.astype(f32), C.astype(f32))]
+    owner = jnp.where(token_valid, token_seq, -1).reshape(T // Q, Q)  # a row's segment, -1 nobody
+    enters = first // Q  # the chunk a segment's first row lies in
+    visits = jnp.where(live & (seq_ntok > 1), (first + seq_ntok - 1) // Q - enters + 1, 0)
+    ends = jnp.cumsum(visits)
+
+    def visit(v, carry):
+        y, pool = carry
+        i = jnp.minimum(jnp.sum(ends <= v), S - 1)  # the visit's segment
+        k = v - (ends[i] - visits[i])  # its k-th chunk
+        c = enters[i] + k
+        mine = owner[c] == i  # [Q]: the chunk's rows of segment i
+        where = (block, jnp.minimum(slot[i], pool.shape[1] - 1), 0, 0, 0)
+        h = jax.lax.dynamic_slice(pool, where, (1, 1, H, P, N))
+        h = jnp.where(started[i] | (k > 0), h, 0.0).reshape(1, G, R, P, N)
+        y_c, h = _chunk(*(r[c] for r in rows), h, mine[:, None])
+        y = y.at[c].set(jnp.where(mine, jnp.moveaxis(y_c.reshape(Q, H, P), 0, -1), y[c]))
+        return y, jax.lax.dynamic_update_slice(pool, h.reshape(1, 1, H, P, N), where)
+
+    # the visits' y rides as [chunks, H, P, Q]: rows on the lanes is the layout the
+    # products' results have, and a chunk is then one whole piece of the carry (as
+    # [chunks, Q, H, P] the compiler laid Nemotron's with the chunks inside the
+    # rows' tiles, and a chunk's update cost 64 us, a third of the scan)
+    y, pool = jax.lax.fori_loop(0, ends[-1], visit, (jnp.zeros((T // Q, H, P, Q), f32), pool))
+    y = jnp.moveaxis(y, -1, 1).reshape(T, H * P)
+    y_one = y_one.reshape(S, H * P)[token_seq]  # whole rows: a gather of [H, 64] pieces is 4 x slower
+    return jnp.where((one[token_seq] & token_valid)[:, None], y_one, y).reshape(T, H, P), pool
 
 
 def whole_slots(pool) -> bool:

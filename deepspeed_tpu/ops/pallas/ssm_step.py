@@ -7,7 +7,11 @@ published widths. Gathering the rows' slots, selecting them against zeros and
 scattering them back moves each state three times for one update
 (PERF.md section 6, PR 44); here the pool stays in HBM, is ALIASED in and out
 (``input_output_aliases``, as ``paged_attention_update`` aliases the K/V
-array), and the kernel walks the step's rows:
+array), and the kernel walks the step's rows. A ``put`` step runs it too
+(``modules/ssm.py:scan_in_place``, PR 49): its segments of ONE row, 31 of a
+chat step's 32, are such rows — row i the sequence's one row, ``live`` false
+for a sequence with no row or with many — and the kernel is the one a
+``decode_loop`` step runs, unchanged. The walk:
 
 - grid over ROWS, executed in order. A live row's slot is cut into tiles of
   heads ``[tile, P, N]`` (:func:`tiling`); a tile is copied into VMEM, updated

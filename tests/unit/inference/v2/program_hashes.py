@@ -4,6 +4,8 @@ Mistral (one window for every layer) engine, as text: what
 table are the programs the benchmark's cells run, so the layer groups, the
 general top-k and the per-layer window must leave their traces as they were."""
 
+import functools
+import hashlib
 import re
 
 import jax
@@ -43,6 +45,15 @@ def _stable(jaxpr):
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
     return re.sub(r"frozenset\(\{([^}]*)\}\)",
                   lambda m: "frozenset({" + ", ".join(sorted(m.group(1).split(", "))) + "})", text)
+
+
+def decode_loop_hash(model, n_steps=4):
+    """sha256 of a served model's traced ``decode_loop`` program (:func:`_stable`)
+    at its default bucket: what a PR that must leave that program alone pins."""
+    loop = functools.partial(model._decode_loop_impl, n_steps=n_steps)
+    jaxpr = jax.make_jaxpr(loop)(model._params, model.state_manager.kv_cache.cache,
+                                 model._synthetic_batch(None))
+    return hashlib.sha256(_stable(jaxpr).encode()).hexdigest()
 
 
 def traced_program_texts():

@@ -24,6 +24,7 @@ from deepspeed_tpu.inference.v2.ragged.manager_configs import (AllocationMode,
 from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import sequence_buckets, token_buckets
 from deepspeed_tpu.models import falcon_h1 as fh
 from deepspeed_tpu.utils import groups
+from tests.unit.inference.v2.program_hashes import decode_loop_hash
 
 BLOCK = 16
 TOL = 1e-4
@@ -128,6 +129,15 @@ def test_prefill_in_uneven_chunks_then_decode_is_the_references_full_forward(req
     engine.close()
 
 
+def test_the_decode_loop_program_is_the_one_it_was_before_the_scan_went_by_segment(model_in_place):
+    """PR 49 changed what a ``put`` step's Mamba-2 mixers run and nothing a
+    ``decode_loop`` chunk runs: its traced program (addresses blanked) hashes
+    to what it did at the commit before."""
+    cfg, params = model_in_place
+    assert decode_loop_hash(engine_of(cfg, params).model) == \
+        "352dc33e5290228aa5291cbe2ea5c4a9bf71f3061c54de5efe0a301b89376001"
+
+
 def test_the_dispatch_spans_carry_the_states_counters_and_the_sequence_bucket(model_in_place):
     """``inference.put`` and ``inference.decode_loop``: ``ssm_tokens`` /
     ``ssm_segments`` over the model's layers (each has a Mamba-2 mixer), the
@@ -147,6 +157,7 @@ def test_the_dispatch_spans_carry_the_states_counters_and_the_sequence_bucket(mo
                     and s["cat"] == "inference")["args"]
         assert (put["ssm_tokens"], put["ssm_segments"]) == (19 * 3, 3 * 3)
         assert put["ssm_segments_in_place"] == put["ssm_segments"]  # a slot is whole tiles
+        assert put["ssm_segments_scanned_in_place"] == put["ssm_segments"]  # and on the kernel's rule
         assert (put["ssm_slots_live"], put["ssm_slots_total"]) == (3, 5)
         assert put["tokens"] == 19
         assert loop["ssm_tokens"] == loop["ssm_rows_in_place"] == 2 * 3 * 3 and loop["steps"] == 2

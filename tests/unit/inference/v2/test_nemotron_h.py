@@ -28,6 +28,7 @@ from deepspeed_tpu.inference.v2.ragged.manager_configs import (AllocationMode,
 from deepspeed_tpu.inference.v2.scheduling_utils import SchedulingError, SchedulingResult
 from deepspeed_tpu.models import nemotron_h as nh
 from deepspeed_tpu.utils import groups
+from tests.unit.inference.v2.program_hashes import decode_loop_hash
 
 BLOCK = 16
 TOL = 1e-4
@@ -289,6 +290,38 @@ def test_a_chunks_counts_say_which_rows_the_kernel_served(request, which, share)
     assert chunk["ssm_tokens"] == 2 * 3 * 4 and chunk["ssm_segments"] == 2 * 3 * 4
     assert chunk["ssm_rows_in_place"] == share * chunk["ssm_tokens"]
     assert engine.model.batch_counts(engine._batch, 1)["ssm_rows_in_place"] == share * 2 * 3
+
+
+@pytest.mark.parametrize("which, share", [("model", 0), ("model_in_place", 1)],
+                         ids=["fallback-state-16", "in-place-state-128"])
+def test_a_puts_counts_say_which_segments_were_scanned_in_their_slot(request, which, share):
+    """``ssm_segments_scanned_in_place`` beside ``ssm_segments`` on a ``put``'s
+    counts: every segment where the pool is on ``ssm.in_place``'s rule (the scan
+    goes by segment, in the pool), 0 where it falls back to ``scan_ragged`` on
+    every state; every arg the spans had keeps its value."""
+    cfg, params = request.getfixturevalue(which)
+    engine = engine_of(cfg, params)
+    engine.put([0, 1], [_ids(40, 9), _ids(41, 5)])
+    pool = engine._state_manager.kv_cache.cache[1]
+    assert ssm.in_place(pool, cfg.n_groups) == bool(share)
+    put = engine.model.batch_counts(engine._batch)
+    assert put["ssm_segments_scanned_in_place"] == share * put["ssm_segments"] == share * 2 * 3
+    had = {"ssm_tokens": 14 * 3, "ssm_segments": 2 * 3, "ssm_slots_live": 2,
+           "ssm_segments_in_place": int(ssm.whole_slots(pool)) * 2 * 3}
+    assert {k: put[k] for k in had} == had and put["ssm_slots_total"] == pool.shape[1]
+    assert "ssm_rows_in_place" not in put
+    engine.put([0], [_ids(42, 1)])  # one row: the same rule
+    put = engine.model.batch_counts(engine._batch)
+    assert put["ssm_segments_scanned_in_place"] == share * put["ssm_segments"] == share * 3
+
+
+def test_the_decode_loop_program_is_the_one_it_was_before_the_scan_went_by_segment(model_in_place):
+    """PR 49 changed what a ``put`` step's Mamba-2 mixers run and nothing a
+    ``decode_loop`` chunk runs: its traced program (addresses blanked) hashes
+    to what it did at the commit before."""
+    cfg, params = model_in_place
+    assert decode_loop_hash(engine_of(cfg, params).model) == \
+        "75fb52b6454aa5793028d601d672b95daf8df83bb963f34efbf4e87b6d65e638"
 
 
 def test_admission_stops_at_the_last_free_slot(model):

@@ -624,34 +624,57 @@ def _pool_shaped_results(text, pool):
     state pool's leading dimensions ``pool`` = (mixers, slots, heads, head) in
     float32 — the pool, or a piece of it whatever its last dimension — and that
     are not the pool passing through: parameters, tuples and their elements,
-    bitcasts, and the store kernel's result, which IS its operand (aliased).
-    XLA cuts a gather of rows above 2 MiB by first slicing its operand: a pass
-    over the whole pool a mixer that PR 47's check, which skipped pool-shaped
-    results as "updated in place", could not see (PERF.md section 6, PR 48)."""
+    bitcasts, a loop that carries it, a kernel's result that IS its operand
+    (aliased: ``ssm_step_in_place``, ``ssm_store_in_place``) and the update of
+    one slot in place (a ``dynamic-update-slice``, alone or the root of its
+    fusion). XLA cuts a gather of rows above 2 MiB by first slicing its operand:
+    a pass over the whole pool a mixer that PR 47's check, which skipped
+    pool-shaped results as "updated in place", could not see (PERF.md section 6,
+    PR 48)."""
     import re
+    roots = dict(re.findall(r"^%(\S+) \([^\n]*\{\n(?:(?!^\}).*\n)*?\s*ROOT %\S+ = \S+ ([a-z][a-z\-]*)\(",
+                            text, flags=re.M))
     out = []
     for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z\-]*)\(", line)
-        if not m or m.group(2) in ("parameter", "tuple", "get-tuple-element", "bitcast"):
+        if not m or m.group(2) in ("parameter", "tuple", "get-tuple-element", "bitcast", "while",
+                                   "dynamic-update-slice"):
             continue
         shapes = [tuple(int(d) for d in dims.split(","))
                   for dims in re.findall(r"f32\[([\d,]+)\]", m.group(1))]
-        if any(shape[:len(pool)] == tuple(pool) and len(shape) == len(pool) + 1 for shape in shapes) \
-                and not (m.group(2) == "custom-call" and "ssm_store_in_place" in line):
-            out.append(line.strip()[:200])
+        if not any(shape[:len(pool)] == tuple(pool) and len(shape) == len(pool) + 1 for shape in shapes):
+            continue
+        if m.group(2) == "custom-call" and "output_to_operand_aliasing" in line \
+                and re.search(r"ssm_(store|step)_in_place", line):
+            continue
+        called = re.search(r"calls=%(\S+?)[,\s}]", line)
+        if m.group(2) == "fusion" and called and roots.get(called.group(1)) == "dynamic-update-slice":
+            continue
+        out.append(line.strip()[:200])
     return out
 
 
-def _slot_copies(text, mixers):
-    """The ``put`` program's slot copies: one ``ssm_load`` and one
-    ``ssm_store_in_place`` a mixer, under ``ssm/scan`` (the scope the scan's
-    roofline readers sum). Returns False where they are not just those."""
-    for kernel in ("ssm_load", "ssm_store_in_place"):
-        calls = [line for line in text.splitlines()
-                 if "tpu_custom_call" in line and f"ssm/scan/{kernel}/" in line]
-        if len(calls) != mixers:
-            return False
-    return True
+def _step_states(text, seqs, heads, head, state, groups):
+    """Float32 results shaped like the step's states, ``[seqs, heads, head,
+    state]`` or the scan's ``[seqs, groups, heads / groups, head, state]``,
+    whatever follows ``seqs``' place: since PR 49 a ``put`` step's scan visits a
+    state in its slot, and the one in hand is ``[1, ...]``."""
+    import re
+    forms = (f"{seqs},{heads},{head},{state}", f"{seqs},{groups},{heads // groups},{head},{state}")
+    return [line.strip()[:160] for line in text.splitlines()
+            if any(re.search(rf"= \(?f32\[{form}\]", line) for form in forms)]
+
+
+def _scans_in_the_pool(text, mixers):
+    """The ``put`` program's scan by segment: one ``ssm_step_in_place`` a mixer
+    (the one-row segments) and one loop of visits a mixer, under ``ssm/scan``
+    (the scope the scan's roofline readers sum), and neither slot-copy kernel."""
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "ssm/scan/" in line and "ssm_step_in_place" in line]
+    loops = [line for line in text.splitlines()
+             if " while(" in line and 'op_name="jit(_forward_impl)/ssm/scan/while"' in line]
+    return len(kernels) == mixers and len(loops) == mixers \
+        and "ssm_load" not in text and "ssm_store_in_place" not in text
 
 
 @pytest.mark.parametrize("bucket,kernel,scope", [
@@ -673,8 +696,9 @@ def test_nemotron_put_program_fits_one_chip(v5e, nemotron_model, bucket, kernel,
     assert scope in text and "ssm/step" not in text
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert not _state_sized_results(text, rows=9)
-    assert _slot_copies(text, mixers=6)
+    assert _scans_in_the_pool(text, mixers=6)
     assert not _pool_shaped_results(text, (6, NEMOTRON_SLOTS, 64, 64))
+    assert not _step_states(text, 8, 64, 64, 128, 8)
     out = jax.eval_shape(model._forward_impl, params, cache, batch)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
 
@@ -754,9 +778,10 @@ def test_falcon_h1_put_program_fits_one_chip(v5e, falcon_h1_model, bucket, kerne
     slots: both grids of the paged kernel at FIVE query heads a K/V head, the
     chunked scan at (heads, head, state) = (32, 128, 256) with the state a
     SEQUENCE (32 of them read from their slots, never a state a token), every
-    layer writing its own layer of the K/V array and its own slot pools: the
-    states by one kernel each way over the pool itself, nothing else in the
-    program shaped like the pool or a piece of it (PR 48)."""
+    layer writing its own layer of the K/V array and its own slot pools: a
+    state scanned IN its slot (the one-row segments by the step kernel over the
+    pool itself, a longer one a visit a chunk), nothing in the program shaped
+    like the pool or a piece of it (PR 48) or like the step's 32 states (PR 49)."""
     model, abstract = falcon_h1_model
     assert model._synthetic_batch()["seq_meta"].shape[0] == FALCON_SEQS
     params, cache, batch = _falcon_h1_args(v5e[0], abstract, bucket)
@@ -764,11 +789,15 @@ def test_falcon_h1_put_program_fits_one_chip(v5e, falcon_h1_model, bucket, kerne
     text = compiled.as_text()
     assert "tpu_custom_call" in text and kernel in text
     assert "ssm/scan" in text and "ssm/step" not in text
-    # 12.6-12.7 GiB: PR 47's gather held a second pool's worth of temporaries (14.0 GiB)
-    assert _device_bytes(compiled) < 0.82 * HBM_BYTES
+    # 12.4 GiB: PR 47's gather held a second pool's worth of temporaries (1.65 GiB: 14.0 in
+    # all), PR 48's copy of the step's 32 states and its re-laying 0.31 (12.7); a state
+    # visited in its slot leaves under 0.1 GiB of temporaries
+    assert _device_bytes(compiled) < 0.79 * HBM_BYTES
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1 * 2**30
     assert not _falcon_state_sized_results(text, rows=4 * FALCON_SEQS)
-    assert _slot_copies(text, mixers=FALCON_LAYERS)
+    assert _scans_in_the_pool(text, mixers=FALCON_LAYERS)
     assert not _pool_shaped_results(text, (FALCON_LAYERS, FALCON_SLOTS, 32, 128))
+    assert not _step_states(text, FALCON_SEQS, 32, 128, 256, 2)
     out = jax.eval_shape(model._forward_impl, params, cache, batch)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
 
