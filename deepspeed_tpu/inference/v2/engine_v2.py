@@ -68,17 +68,59 @@ class DecodeChunk:
         ``fetch_us``, the blocking transfer, and on the grouped path
         ``moe_banks``, the banks touched over the chunk's steps and expert
         layers — 4 bytes a layer-step that came out behind the tokens."""
+        return self._to_host(self.tokens)[:, :self.n_seqs].T
+
+    def _to_host(self, result) -> np.ndarray:
         args, self._args = self._args, None
         t0 = _tel_now_us()
-        tokens = np.asarray(self.tokens)
+        out = np.asarray(result)
         if args is not None:
             args["fetch_us"] = _tel_now_us() - t0
             if self._banks is not None:
                 args.update(self._count(self._banks))
-        return tokens[:, :self.n_seqs].T
+        return out
 
     def __array__(self, dtype=None, copy=None):
         return self.fetch()
+
+
+class BlockChunk(DecodeChunk):
+    """A block loop's chunk on the device, not fetched
+    (:meth:`InferenceEngineV2.dispatch_block_loop`): ``tokens`` is the
+    program's ``[rows, n_blocks * B]`` ids, row i sequence i of the batch, and
+    beside them the int8 denoise step at which each position took its token
+    (-1: it was given). :meth:`fetch` returns the ids ``[n_seqs, n_blocks *
+    B]`` and leaves the steps in :attr:`steps`; :attr:`confidences` fetches,
+    for whoever asks (a check; no serving path does), what the rows were chosen
+    on: float32 ``[n_seqs, n_blocks, denoising_steps, B]``, each still-masked
+    row's confidence behind each denoise forward, -1 where the row had its
+    token. The chunk after it needs none
+    of its tokens (a block starts all masked): there is no ``ids`` to hand on.
+    :meth:`note` adds to the chunk's ``block_loop`` span what its caller
+    learns later (the tokens it kept, the tick that dispatched it)."""
+
+    __slots__ = ("steps", "_taken", "_conf", "_span")
+
+    def __init__(self, tokens, taken, conf, n_seqs, banks=None, args=None, count=None):
+        super().__init__(tokens, n_seqs, banks, args, count)
+        self._taken, self._conf, self._span, self.steps = taken, conf, args, None
+
+    @property
+    def confidences(self) -> np.ndarray:
+        return np.asarray(self._conf)[:self.n_seqs]
+
+    @property
+    def ids(self):
+        raise ValueError("a block loop's chunk hands no ids on: the next block starts masked")
+
+    def fetch(self) -> np.ndarray:
+        ids = self._to_host(self.tokens)[:self.n_seqs]
+        self.steps = np.asarray(self._taken)[:self.n_seqs]
+        return ids
+
+    def note(self, **args) -> None:
+        if self._span is not None:
+            self._span.update(args)
 
 
 class InferenceEngineV2:
@@ -104,6 +146,8 @@ class InferenceEngineV2:
                                          num_groups=kv_config.num_allocation_groups,
                                          min_table_bucket=kv_config.min_table_bucket,
                                          min_sequence_bucket=kv_config.min_sequence_bucket,
+                                         min_token_bucket=kv_config.min_token_bucket,
+                                         attention_block=kv_config.attention_block,
                                          state_slots=kv_config.sequence_slots)
         self._state_manager = DSStateManager(engine_config.state_manager, kv_config)
         self._model.set_state_manager(self._state_manager)
@@ -356,16 +400,18 @@ class InferenceEngineV2:
             args.update(self._model.batch_counts(self._batch, steps or 1))
         return args, self._prev_by_slot(prev, batch_tokens, n_padded, args)
 
-    def _post_forward(self, batch_uids, steps: int = 1, release: bool = True) -> None:
+    def _post_forward(self, batch_uids, steps: int = 1, release: bool = True,
+                      unit: int = 1) -> None:
         """Commit the fed tokens (and the ``steps - 1`` a chunk's loop
-        inserted behind them) and, unless the caller may still roll some back
-        (the verify steps: a released block cannot come back), let the model
-        release what the window has passed."""
+        inserted behind them, each of ``unit`` positions: a block loop's are
+        blocks) and, unless the caller may still roll some back (the verify
+        steps: a released block cannot come back), let the model release what
+        the window has passed."""
         for uid in batch_uids:
             seq_desc = self._state_manager.get_sequence(uid)
             seq_desc.post_forward()
             if steps > 1:
-                seq_desc.pre_forward(steps - 1)
+                seq_desc.pre_forward((steps - 1) * unit)
                 seq_desc.post_forward()
             if release:
                 self._released_blocks += self._model.maybe_free_kv(seq_desc)
@@ -583,6 +629,112 @@ class InferenceEngineV2:
             self._write_telemetry(metrics, batch_tokens=n_tokens)
         self._post_forward(batch_uids, steps=n_steps)
         return DecodeChunk(tokens, len(batch_uids), banks, args, self.moe_counts)
+
+    # ------------------------------------------------------------ block steps --
+    # A model that generates by diffusion over blocks (``model.attention_block``
+    # = B): its prompts go through :meth:`put` in whole blocks (prefill, and a
+    # block's commit: the same program), its decode steps through the two
+    # below, on the same ``_prepare`` / ``_dispatch`` / ``_post_forward``.
+    def _block_feeds(self, batch_uids, blocks, flags):
+        """Each sequence's block as the program is fed it: the mask token where
+        its flag says the row has no token yet; and the flags by token slot."""
+        B = self._model.attention_block
+        if not B:
+            raise ValueError(f"a {type(self._model).__name__} does not generate by blocks "
+                             f"(attention_block is 0): block_forward and the block loop are a "
+                             f"block-diffusion model's")
+        mask_id = self._model.config.mask_token_id
+        blocks = [np.asarray(b, np.int32).reshape(-1) for b in blocks]
+        flags = [np.asarray(f).astype(bool).reshape(-1) for f in flags]
+        if any(b.size != B or f.size != B for b, f in zip(blocks, flags)) or \
+                not len(batch_uids) == len(blocks) == len(flags):
+            raise ValueError(f"a block step takes one block of {B} ids and {B} flags a sequence")
+        return ([np.where(f, mask_id, b).astype(np.int32) for b, f in zip(blocks, flags)],
+                np.concatenate(flags) if flags else np.zeros(0, bool))
+
+    def block_forward(self, batch_uids: Iterable[int], blocks: Iterable, flags: Iterable,
+                      do_checks: bool = True):
+        """ONE denoise forward of each sequence's next block: ``blocks[i]`` the
+        B ids of ``batch_uids[i]``'s rows at positions ``seen .. seen + B - 1``,
+        ``flags[i]`` which of them have no token yet (they are fed the mask
+        token, whatever their id). Returns float32 logits ``[n, B, vocab]`` on
+        the device, row j scoring the token AT position j. Nothing it writes
+        counts: the block's K/V lands in slots past ``seen_tokens``, which does
+        not move (the KV blocks those slots are in stay allocated; the block's
+        commit — a :meth:`put` of the finished block — overwrites them)."""
+        batch_uids = list(batch_uids)
+        feeds, _ = self._block_feeds(batch_uids, blocks, flags)
+        spans, observer, metrics = self._telemetry_sinks()
+        n_tokens = int(sum(t.size for t in feeds))
+        self._prepare(spans, batch_uids, feeds, do_checks, n_tokens)
+        args, _ = self._dispatch(spans, batch_uids, feeds, None)
+        with _tel_live_span(spans, "block_forward", "inference", args):
+            if observer is not None:
+                _t0 = _tel_now_us()
+            logits = self._model.block_forward(self._batch)
+            if observer is not None:
+                observer("block_forward", len(batch_uids), n_tokens, (_tel_now_us() - _t0) / 1e6)
+            for uid in batch_uids:  # the rows were in flight and are dropped, not committed
+                self._state_manager.get_sequence(uid).pre_forward(0)
+        if metrics is not None:
+            self._write_telemetry(metrics, batch_tokens=n_tokens)
+        B = self._model.attention_block
+        return logits[:n_tokens].reshape(len(batch_uids), B, -1)
+
+    def block_loop(self, batch_uids, blocks, flags, n_blocks: int, do_checks: bool = True):
+        """:meth:`dispatch_block_loop` fetched at once: ``(ids, steps)``."""
+        chunk = self.dispatch_block_loop(batch_uids, blocks, flags, n_blocks, do_checks)
+        return chunk.fetch(), chunk.steps
+
+    def dispatch_block_loop(self, batch_uids: Iterable[int], blocks: Iterable, flags: Iterable,
+                            n_blocks: int, do_checks: bool = True) -> BlockChunk:
+        """``n_blocks`` blocks a sequence in ONE device program, launched and
+        NOT fetched: per block ``denoising_steps`` denoise forwards, the choice
+        of rows on the device, one commit forward
+        (``DSTransformerModelBase._block_loop_impl``). ``blocks`` / ``flags``
+        are each sequence's FIRST block as for :meth:`block_forward` (the
+        prompt's rows past its last whole block, the rest masked); every later
+        block starts all masked, so a chunk needs nothing of the chunk before
+        it but program order. Everything that needs only counts is done when
+        the call returns: the KV blocks of all ``n_blocks * B`` positions,
+        ``seen_tokens`` (+ ``n_blocks * B``). The ``block_loop`` span is the
+        launch: ``seqs``, ``blocks`` and ``forwards`` (both a sequence: a
+        block is ``denoising_steps + 1`` forwards of B rows), ``rows``
+        (forwards x B), ``masked_rows`` and ``tokens`` (the positions that
+        take a token in the chunk; :meth:`BlockChunk.note` corrects ``tokens``
+        to what the caller kept), ``steps`` (the program's forwards of the
+        whole batch), ``launch_us``; ``fetch_us`` and a grouped bucket's
+        ``moe_banks`` are the fetch's to write."""
+        batch_uids = list(batch_uids)
+        feeds, masked = self._block_feeds(batch_uids, blocks, flags)
+        if n_blocks < 1:
+            raise ValueError("n_blocks must be >= 1")
+        B, n = self._model.attention_block, len(batch_uids)
+        forwards = n_blocks * (self._model.config.denoising_steps + 1)
+        spans, observer, metrics = self._telemetry_sinks()
+        n_tokens = n * n_blocks * B
+        self._prepare(spans, batch_uids, feeds, do_checks, n_tokens, steps=n_blocks * B)
+        args, _ = self._dispatch(spans, batch_uids, feeds, None, steps=forwards)
+        if args is not None:
+            taking = int(masked.sum()) + n * (n_blocks - 1) * B
+            args.update(seqs=n, blocks=n * n_blocks, forwards=n * forwards,
+                        rows=n * forwards * B, masked_rows=taking, tokens=taking)
+        slots = np.zeros(self._batch.device_batch["tok_meta"].shape[1], np.int32)
+        slots[:masked.size] = masked
+        with _tel_live_span(spans, "block_loop", "inference", args):
+            if observer is not None or spans is not None:
+                _t0 = _tel_now_us()
+            ids, taken, conf, banks = self._model.block_loop(self._batch, slots, n_blocks)
+            if spans is not None:
+                args.update(launch_us=_tel_now_us() - _t0, fetch_us=0)
+                if banks is not None:
+                    banks.copy_to_host_async()
+            if observer is not None:
+                observer("block_loop", n, n_tokens, (_tel_now_us() - _t0) / 1e6)
+        if metrics is not None:
+            self._write_telemetry(metrics, batch_tokens=n_tokens)
+        self._post_forward(batch_uids, steps=n_blocks, unit=B)
+        return BlockChunk(ids, taken, conf, n, banks, args, self.moe_counts)
 
     # ------------------------------------------------------ speculative verify --
     def verify_tree(self, batch_uids: Iterable[int], trees: Iterable,

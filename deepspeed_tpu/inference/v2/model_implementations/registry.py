@@ -58,6 +58,12 @@ def _register_falcon_h1():
     register_policy("falcon_h1", FalconH1Config, FalconH1V2Model)
 
 
+def _register_sdar_moe():
+    from deepspeed_tpu.models.sdar_moe import SdarMoeConfig
+    from deepspeed_tpu.inference.v2.model_implementations.sdar_moe_v2 import SdarMoeV2Model
+    register_policy("sdar_moe", SdarMoeConfig, SdarMoeV2Model)
+
+
 def _register_builtin():
     from deepspeed_tpu.models.afmoe import AfmoeConfig
     from deepspeed_tpu.models.decoder import DecoderConfig
@@ -95,6 +101,10 @@ def _register_builtin():
     # per-sequence state in each), fourteen forward multipliers, one sequence
     # bucket; nothing in common with "falcon" below but the name
     _ON_FIRST_USE["falcon_h1"] = _register_falcon_h1
+    # serving only: generation by diffusion over blocks — attention under a
+    # block mask, a decode step that rewrites a block of rows and commits its
+    # K/V once, several tokens a sequence a step — on softmax top-k experts
+    _ON_FIRST_USE["sdar_moe"] = _register_sdar_moe
     register_policy("opt", DecoderConfig, DecoderV2Model)
     register_policy("falcon", DecoderConfig, DecoderV2Model)
     register_policy("phi", DecoderConfig, DecoderV2Model)

@@ -111,6 +111,8 @@ class DSTransformerModelBase:
                              state_widths=self.kv_state_widths,
                              min_table_bucket=self.min_table_bucket,
                              min_sequence_bucket=self.min_sequence_bucket,
+                             min_token_bucket=self.min_token_bucket,
+                             attention_block=self.attention_block,
                              cache_dtype=cache_dtype,
                              sequence_state=self.sequence_state,
                              sequence_slots=sm.max_tracked_sequences if self.sequence_state else 0)
@@ -146,6 +148,21 @@ class DSTransformerModelBase:
         every batch up to some count of sequences says so."""
         return 8
 
+    @property
+    def attention_block(self) -> int:
+        """The length B of the blocks a model that generates by diffusion over
+        blocks attends by (``KVCacheConfig.attention_block``): a query sees
+        every key up to its block's end, blocks counted from position 0. 0: a
+        causal model, every other."""
+        return 0
+
+    @property
+    def min_token_bucket(self) -> int:
+        """The smallest token bucket (``KVCacheConfig.min_token_bucket``): a
+        row a sequence of the smallest sequence bucket, unless the model says
+        otherwise (one whose step feeds a block a sequence)."""
+        return self.min_sequence_bucket
+
     # what a forward program's count of routed work holds, last axis of the
     # device array it returns beside its result where there is more than one
     moe_count_names: Tuple[str, ...] = ("moe_banks", )
@@ -163,8 +180,9 @@ class DSTransformerModelBase:
         from deepspeed_tpu.ops.pallas.paged_attention import tiled_passes
         seq = np.asarray(batch["seq_meta"])
         passes, one_token = tiled_passes(seq[:, 1], seq[:, 2], bucket_tokens)
-        return {"tiled_passes": passes * self.num_kv_layers,
-                "tiled_one_token_passes": one_token * self.num_kv_layers}
+        # ``steps`` > 1 on this grid: the forwards of a block loop, all alike
+        return {"tiled_passes": passes * self.num_kv_layers * steps,
+                "tiled_one_token_passes": one_token * self.num_kv_layers * steps}
 
     def set_state_manager(self, state_manager):
         self._state_manager = state_manager
@@ -311,7 +329,9 @@ class DSTransformerModelBase:
         "forward": ("inference_forward", 1, "_forward_impl", {}),
         "decode_loop": ("inference_decode_loop", 1, "_decode_loop_impl", {"n_steps": 1}),
         "verify": ("inference_verify", 1, "_verify_impl", {"greedy": 3}),
-        "compact": ("inference_kv_compact", 0, "_compact_impl", {})}
+        "compact": ("inference_kv_compact", 0, "_compact_impl", {}),
+        "block_forward": ("inference_block_forward", 1, "_block_forward_impl", {}),
+        "block_loop": ("inference_block_loop", 1, "_block_loop_impl", {"n_blocks": 1})}
 
     def _program(self, kind, key, run=True):
         """The jitted program of ``kind`` at ``key``: ``forward`` at a ``(T, S,
@@ -473,6 +493,120 @@ class DSTransformerModelBase:
             step, (cache, tok_meta, seq_meta), None, length=n_steps)
         return (tokens, cache, *banks)
 
+    # ------------------------------------------------------------ block steps --
+    # A model that generates by diffusion over blocks (``attention_block`` = B >
+    # 0; its config states ``denoising_steps`` and ``mask_token_id``): a decode
+    # step of a sequence is a BLOCK of B rows at positions seen .. seen + B - 1,
+    # rewritten under the block mask until every row has its token, and then
+    # committed once. Every forward of a block writes the block's K/V into its
+    # slots of the pool (the paged kernel inserts before it attends); what
+    # makes it count is ``seen_tokens``, which only the commit moves
+    # (write-then-truncate, as ``rollback``).
+    def block_forward(self, ragged_batch):
+        """One DENOISE forward: the bucket's program over a batch that feeds one
+        block a sequence, with EVERY row unembedded. Returns float32 logits
+        ``[T_bucket, vocab]`` on the device, row ``i * B + j`` sequence i's
+        row j (row j scores the token AT position j: no shift)."""
+        batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
+        fn = self._program("block_forward", self._bucket_of(batch))
+        logits, new_cache, *_ = fn(self._params, self._state_manager.kv_cache.cache,
+                                   {"tok_meta": batch["tok_meta"], "seq_meta": batch["seq_meta"]})
+        self._state_manager.kv_cache.set_cache(new_cache)
+        return logits
+
+    def _block_forward_impl(self, params, cache, batch):
+        return self._forward_impl(params, cache, batch, rows="all")
+
+    def block_loop(self, ragged_batch, masked, n_blocks: int):
+        """``n_blocks`` blocks a sequence in ONE device program
+        (:meth:`_block_loop_impl`). The batch feeds each sequence's FIRST
+        block: its known ids, and ``masked`` (``[T_bucket]``, 1 = the row has
+        no token yet; every later block starts all masked). KV blocks for all
+        ``n_blocks * B`` positions must be allocated. Returns, on the device
+        and still being computed, ``(ids, steps, confidences, banks)``: int32
+        and int8 ``[T_bucket / B, n_blocks * B]``, row i sequence-slot i, the
+        token at each position and the denoise step at which it took it (-1:
+        it was given); float32 ``[T_bucket / B, n_blocks, denoising_steps,
+        B]``, what the choice of rows was made on — each still-masked row's
+        confidence behind each denoise forward, -1 for a row that had its
+        token; and where the bucket routes by sorting the expert banks each
+        block's forwards touched, int32 ``[n_blocks, expert layers]``, else
+        None."""
+        batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
+        fn = self._program("block_loop", (self._bucket_of(batch), int(n_blocks)))
+        ids, steps, conf, new_cache, *banks = fn(
+            self._params, self._state_manager.kv_cache.cache,
+            {"tok_meta": batch["tok_meta"], "seq_meta": batch["seq_meta"], "masked": masked})
+        self._state_manager.kv_cache.set_cache(new_cache)
+        return ids, steps, conf, (banks[0] if banks else None)
+
+    def _block_loop_impl(self, params, cache, batch, *, n_blocks):
+        """Per block: ``denoising_steps`` denoise forwards (scope
+        ``diffusion/denoise``), after each of which the ``B / denoising_steps``
+        masked rows of a sequence whose greedy token is most confident take it
+        (``diffusion/unmask``: argmax, its float32 softmax probability, the
+        best of the masked rows, ties to the earlier row —
+        ``low_confidence_static``); then the commit forward over the finished
+        block (``diffusion/commit``, no row unembedded: the published algorithm
+        reads no logits of it) and the metadata moved on by B. A sequence
+        whose first block came part given has nothing left to take in its
+        last denoise forwards: the program's shape is the batch's, not a
+        sequence's."""
+        import jax
+        import jax.numpy as jnp
+
+        cfg = self._config
+        B, n_denoise, mask_id = self.attention_block, cfg.denoising_steps, cfg.mask_token_id
+        tok_meta = jnp.asarray(batch["tok_meta"])
+        seq_meta = jnp.asarray(batch["seq_meta"])
+        T = tok_meta.shape[1]
+        valid = tok_meta[3] > 0
+        seq_valid = (seq_meta[:, 3] > 0).astype(seq_meta.dtype)
+
+        def forward(cache, ids, tok_meta, seq_meta, rows):
+            return self._forward_impl(params, cache, {"tok_meta": tok_meta.at[0].set(ids),
+                                                      "seq_meta": seq_meta}, rows=rows)
+
+        def block(carry, _):
+            cache, tok_meta, seq_meta, ids, masked = carry
+
+            def denoise(carry, step):
+                cache, ids, masked, taken = carry
+                with jax.named_scope("diffusion/denoise"):
+                    logits, cache, *banks = forward(cache, jnp.where(masked, mask_id, ids),
+                                                    tok_meta, seq_meta, "all")
+                with jax.named_scope("diffusion/unmask"):
+                    x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    conf = jnp.max(jax.nn.softmax(logits, axis=-1), axis=-1)
+                    conf = jnp.where(masked, conf, -1.0).reshape(T // B, B)
+                    _, best = jax.lax.top_k(conf, B // n_denoise)  # equal: the earlier row
+                    chosen = jnp.any(best[:, :, None] == jnp.arange(B)[None, None, :], axis=1)
+                    chosen = chosen.reshape(T) & masked
+                    ids = jnp.where(chosen, x0, ids)
+                    taken = jnp.where(chosen, step.astype(jnp.int8), taken)
+                return (cache, ids, masked & ~chosen, taken), (conf.reshape(T), *banks)
+
+            (cache, ids, masked, taken), (conf, *banks) = jax.lax.scan(
+                denoise, (cache, ids, masked, jnp.full((T, ), -1, jnp.int8)),
+                jnp.arange(n_denoise))
+            with jax.named_scope("diffusion/commit"):
+                _, cache, *committed = forward(cache, ids, tok_meta, seq_meta, "none")
+            tok_meta = tok_meta.at[2].add(B * valid.astype(tok_meta.dtype))
+            seq_meta = seq_meta.at[:, 0].add(B * seq_valid)
+            banks = tuple(b.sum(axis=0) + c for b, c in zip(banks, committed))
+            return (cache, tok_meta, seq_meta, ids, valid), (ids, taken, conf, *banks)
+
+        masked = (jnp.asarray(batch["masked"]) > 0) & valid
+        (cache, *_), (ids, taken, conf, *banks) = jax.lax.scan(
+            block, (cache, tok_meta, seq_meta, tok_meta[0], masked), None, length=n_blocks)
+
+        def by_sequence(a):  # [n_blocks, T] -> [T / B, n_blocks * B]
+            return a.reshape(n_blocks, T // B, B).transpose(1, 0, 2).reshape(T // B, n_blocks * B)
+
+        # [n_blocks, n_denoise, T] -> [T / B, n_blocks, n_denoise, B]
+        conf = conf.reshape(n_blocks, n_denoise, T // B, B).transpose(2, 0, 1, 3)
+        return (by_sequence(ids), by_sequence(taken), conf, cache, *banks)
+
     @property
     def _slot_columns(self) -> int:
         """Columns of ``seq_meta`` behind the block tables: a sequence's slot
@@ -505,7 +639,12 @@ class DSTransformerModelBase:
             out["block_table"] = out["block_table"].reshape(seq.shape[0], self.kv_groups, -1)
         return out
 
-    def _forward_impl(self, params, cache, batch):
+    def _forward_impl(self, params, cache, batch, rows="last"):
+        """One ragged forward. ``rows``: which rows are unembedded — each
+        sequence's ``last`` token (a step that yields one token a sequence),
+        ``all`` of the batch's (a denoise forward of a block step: ``[T,
+        vocab]``), or ``none`` (a block's commit: the K/V is all it is for,
+        and the logits are None)."""
         import jax.numpy as jnp
         from deepspeed_tpu.inference.v2.quantization import dequantize_tree
 
@@ -519,9 +658,12 @@ class DSTransformerModelBase:
         attn = partial(self._paged_attention, batch=batch)
         for li in range(self.num_layers):
             x, cache = self.layer_forward(params, li, x, cache, attn, batch)
-        # unembed ONLY each sequence's last token (reference logits_gather)
-        x_last = x[batch["last_tok"]]
-        logits = self.unembed(params, x_last).astype(jnp.float32)
+        if rows == "none":
+            logits = None
+        else:
+            # unembed ONLY each sequence's last token (reference logits_gather)
+            x_last = x[batch["last_tok"]] if rows == "last" else x
+            logits = self.unembed(params, x_last).astype(jnp.float32)
         return (logits, cache, jnp.stack(banks)) if banks else (logits, cache)
 
     # ----------------------------------------------------- speculative verify --
@@ -661,6 +803,9 @@ class DSTransformerModelBase:
         token_pos = batch["token_pos"]
         window = self.attention_window_of(li)
         table, li = self._kv_view(batch, li)
+        # a block mask is one more static argument of the kernels, named only
+        # where the model has one
+        masked = {"block": self.attention_block} if self.attention_block else {}
 
         # scopes (under the caller's ``attn``): ``paged_kernel`` / ``kv_write``
         # + ``gather`` name the arm a device operation belongs to in the trace
@@ -680,7 +825,7 @@ class DSTransformerModelBase:
                 meta = (table, batch["token_seq"], token_pos, batch["token_valid"])
 
             def kernel(q, k_new, v_new, cache, *meta):
-                return update(q, k_new, v_new, cache, li, *meta, window=window)
+                return update(q, k_new, v_new, cache, li, *meta, window=window, **masked)
 
             args = (q, k_new, v_new, cache) + meta
             placed = None if self._state_manager is None else self._state_manager.kv_cache.sharding
@@ -699,7 +844,7 @@ class DSTransformerModelBase:
 
         cache = self._kv_write(cache, li, k_new, v_new, token_pos, batch, table)
         with jax.named_scope("gather"):
-            return self._gather_attention(q, cache, li, batch, table, window), cache
+            return self._gather_attention(q, cache, li, batch, table, window, **masked), cache
 
     @staticmethod
     def _kv_write(cache, li, k_new, v_new, slot_pos, batch, table):
@@ -744,10 +889,11 @@ class DSTransformerModelBase:
             v_hist = jnp.repeat(v_hist, rep, axis=2)
         return k_hist, v_hist
 
-    def _gather_attention(self, q, cache, li, batch, table, window):
+    def _gather_attention(self, q, cache, li, batch, table, window, block=0):
         """The XLA arm: gather each sequence's history from the layer's block
-        ``table`` and attend densely under its ``window``. q: [T, H, D];
-        returns [T, H, D]."""
+        ``table`` and attend densely under its ``window``, or with ``block`` >
+        0 up to the end of each query's block. q: [T, H, D]; returns
+        [T, H, D]."""
         import jax
         import jax.numpy as jnp
 
@@ -770,6 +916,8 @@ class DSTransformerModelBase:
         logits = jnp.einsum("sqhd,skhd->shqk", q_dense, k_hist).astype(jnp.float32) * scale
         kv_pos = jnp.arange(KV)[None, None, None, :]              # [1,1,1,KV]
         q_pos = (batch["seq_seen"][:, None] + jnp.arange(Qm)[None, :])[:, None, :, None]
+        if block:
+            q_pos = q_pos | (block - 1)
         valid_kv = kv_pos <= q_pos                                # causal incl. self
         seq_len = (batch["seq_seen"] + batch["seq_ntok"])[:, None, None, None]
         valid_kv &= kv_pos < seq_len

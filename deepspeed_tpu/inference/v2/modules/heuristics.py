@@ -24,7 +24,11 @@ def attention_implementation(model, engine_config, bucket_tokens: int) -> str:
     §6 PR 24; what the CPU runs, and the check the kernel is tested against).
     A sliding-window model (``attention_window`` > 0) is chosen for like any
     other: every arm masks the window, and the kernel also starts its block
-    walk at the window's first block. Policy:
+    walk at the window's first block. A model that attends under a block mask
+    (``attention_block`` > 0: generation by diffusion over blocks) takes the
+    tile grid at EVERY bucket: a tile's pass inserts its rows before it walks,
+    so a row sees the later rows of its block; the token grid attends row t
+    before row t + 1 is in the pool, and refuses such a model by name. Policy:
 
     - an explicit ``use_paged_kernel`` config wins: kernel (grid by bucket) or
       gather;
@@ -34,7 +38,8 @@ def attention_implementation(model, engine_config, bucket_tokens: int) -> str:
     from deepspeed_tpu.ops.pallas.paged_attention import (CHUNK, TOKEN_GRID_MAX,
                                                           tile_grid_vmem_bytes)
     flag = getattr(engine_config, "use_paged_kernel", None)
-    kernel = "paged_token" if bucket_tokens <= TOKEN_GRID_MAX else "paged_tiled"
+    kernel = ("paged_token" if bucket_tokens <= TOKEN_GRID_MAX
+              and not getattr(model, "attention_block", 0) else "paged_tiled")
     if flag is not None:
         return kernel if flag else "xla_gather"
     import jax

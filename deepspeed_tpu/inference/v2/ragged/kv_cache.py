@@ -84,10 +84,10 @@ def _cache_sharding(kv_heads: int):
 # tables: ``DSSequenceDescriptor.kv_blocks`` reads one, where there are blocks).
 CACHE_OPERATIONS = {
     # what a deployment's configuration or a request asks for: theirs to fix
-    "prefix_cache": ("share", "", "window latent slots"),
-    "kv_tiers": ("move", "", "window latent slots"),
-    "speculative": ("rollback", "", "slots"),
-    "frames": ("move", "handoff, park and resume frames", "window slots"),
+    "prefix_cache": ("share", "", "window latent slots blocks"),
+    "kv_tiers": ("move", "", "window latent slots blocks"),
+    "speculative": ("rollback", "", "slots blocks"),
+    "frames": ("move", "handoff, park and resume frames", "window slots blocks"),
     # what a caller of the state manager, the pool, the engine or the model does
     "create_cached_sequence": ("share", "create_cached_sequence (a prefix-cache hit)",
                                "tables slots"),
@@ -98,8 +98,8 @@ CACHE_OPERATIONS = {
     "gather_blocks": ("move", "gather_blocks (offload, a handoff or park frame)",
                       "latent slots"),
     "scatter_blocks": ("move", "scatter_blocks (restore, an imported frame)", "latent slots"),
-    "verify_tree": ("rollback", "", "slots"),
-    "compact_kv": ("rollback", "compact_kv (a tree-verify re-pack)", "latent slots"),
+    "verify_tree": ("rollback", "", "slots blocks"),
+    "compact_kv": ("rollback", "compact_kv (a tree-verify re-pack)", "latent slots blocks"),
     "rollback": ("rollback", "", "slots"),
 }
 _ASKED = ("prefix_cache", "kv_tiers", "speculative", "frames")
@@ -117,6 +117,10 @@ _CACHE_KINDS = {
               "block table): shared blocks, a moved table or a rolled-back draft would leave the "
               "slot's state behind, and it cannot be wound back without a snapshot a draft",
               NotImplementedError),
+    "blocks": ("a model that generates by diffusion over blocks of {} positions: a step hands "
+               "over a block of tokens, not one, a draft has no causal step to be verified by, "
+               "and a request between two blocks holds rows that are not K/V yet, which a "
+               "shared prefix, a tier or a frame would have to carry", NotImplementedError),
 }
 
 
@@ -288,7 +292,8 @@ class BlockedKVCache:
         mine = {"window": max(config.group_windows),
                 "tables": config.num_allocation_groups > 1 and config.num_allocation_groups,
                 "latent": tuple(config.state_widths),
-                "slots": [spec.name for spec in config.sequence_state]}
+                "slots": [spec.name for spec in config.sequence_state],
+                "blocks": config.attention_block}
         for kind in kinds.split():
             if mine[kind]:
                 is_a, error = _CACHE_KINDS[kind]

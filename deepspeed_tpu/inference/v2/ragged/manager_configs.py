@@ -53,6 +53,15 @@ class KVCacheConfig(DeepSpeedConfigModel):
     # token bucket starts at it): a model with one program for every batch up
     # to some count of sequences says so here
     min_sequence_bucket: int = Field(8, gt=0)
+    # the smallest token bucket; 0 = a row a sequence of the smallest sequence
+    # bucket. A model whose step feeds a block a sequence says more
+    min_token_bucket: int = Field(0, ge=0)
+    # B > 0: the model generates by diffusion over blocks of B positions. Its
+    # attention sees up to the end of a query's block, so every feed is whole
+    # blocks (the batch checks it), and a block's K/V is rewritten until its
+    # commit: what shares, moves or rolls back a sequence's cache mid-block is
+    # refused (kv_cache.py, ``refusal``)
+    attention_block: int = Field(0, ge=0)
     cache_dtype: str = "bfloat16"
     # A per-SEQUENCE state group (a state-space layer's recurrent state, its
     # convolution's tail): one pool a spec, ``sequence_slots`` slots each, a

@@ -77,7 +77,8 @@ class RaggedBatchWrapper:
 
     def __init__(self, config: DSStateManagerConfig, block_size: int = 128,
                  num_groups: int = 1, min_table_bucket: int = 4, state_slots: int = 0,
-                 min_sequence_bucket: int = 8) -> None:
+                 min_sequence_bucket: int = 8, min_token_bucket: int = 0,
+                 attention_block: int = 0) -> None:
         """``num_groups``: block tables a sequence (KV layer groups,
         ``ragged/kv_cache.py``); the batch carries them side by side.
         ``min_table_bucket``: the smallest block-table bucket
@@ -86,12 +87,19 @@ class RaggedBatchWrapper:
         ``state_slots``: the slots of a
         per-sequence state group (``KVCacheConfig.sequence_slots``); over 0,
         ``seq_meta`` carries each sequence's slot as one more column behind
-        its block tables."""
+        its block tables. ``min_token_bucket``: the smallest token bucket
+        where that is more than a row a sequence of the smallest sequence
+        bucket. ``attention_block`` (``KVCacheConfig.attention_block``) = B >
+        0: the model attends under a block mask, which is right only where
+        every sequence's rows are whole blocks; :meth:`insert_sequence` holds
+        every feed to that."""
         self._config = config
         self._block_size = block_size
         self._num_groups = num_groups
         self._min_table_bucket = min_table_bucket
         self._min_sequence_bucket = min_sequence_bucket
+        self._min_token_bucket = max(min_token_bucket, min_sequence_bucket)
+        self._attention_block = attention_block
         self._state_slots = state_slots
         self.clear()
 
@@ -149,6 +157,18 @@ class RaggedBatchWrapper:
                     raise ValueError("tree parents must be topological local indices")
         seq_idx = len(self._seq_descs)
         seen = seq_desc.seen_tokens
+        B = self._attention_block
+        if B and (seen % B or tokens.size % B or tree is not None):
+            # checked or not: under the block mask a row sees its block's end,
+            # which is in the pool only if the block came whole, and the
+            # kernel's passes are whole blocks only if every feed before it
+            # in the batch was (earlier inserts passed here: rows start at a
+            # multiple of B)
+            raise ValueError(
+                f"sequence {seq_desc.tracking_id}: a feed of {tokens.size} tokens at position "
+                f"{seen} under a block mask of {B}: a sequence's rows in a step start at a "
+                f"multiple of the block and number a multiple of it"
+                + ("; a draft tree has no block" if tree is not None else ""))
         self._seq_descs.append(seq_desc)
         self._seq_seen.append(seen)
         self._seq_ntok.append(int(tokens.size))
@@ -168,7 +188,7 @@ class RaggedBatchWrapper:
 
     def finalize(self):
         """Pad to the bucket and build the device-ready numpy struct."""
-        T = padded_tokens(self.current_tokens, self._min_sequence_bucket)
+        T = padded_tokens(self.current_tokens, self._min_token_bucket)
         S = padded_sequences(self.current_sequences, self._min_sequence_bucket)
         mb = max((b.shape[1] for b in self._seq_blocks), default=1)
         MB = _pow2_pad(mb, self._min_table_bucket)

@@ -48,6 +48,16 @@ FIRST query — so a sequence's K/V traffic is bounded by ``window`` + the pass'
 queries (+ the last chunk's padding), not by its context. Table entries before
 that block are never read: the pool releases those blocks as the window passes
 them (``transformer_base.maybe_free_kv``) and leaves -1 there.
+
+Block mask (``block`` > 0, static, a power of two that divides ``TQ``; 0 =
+causal and the program it always was): a model that generates by diffusion over
+blocks lets every position of a block see the whole block, so a query at
+``q_pos`` sees the keys up to its block's END, ``q_pos | (block - 1)``. Only the
+TILE grid takes it: a pass inserts its rows before it walks, and the caller
+feeds whole blocks that start at multiples of ``block`` in the batch
+(``ragged/ragged_wrapper.py`` checks both), so a block never straddles two
+passes and the walk's last block is the one it always was. The token grid
+inserts row t and attends before row t + 1 is in the pool: it refuses.
 """
 
 import functools
@@ -229,16 +239,23 @@ def _kernel(li, S, MB, bs, rep, scale, window,
     out_ref[0] = out.reshape(1, KVH * rep, D).astype(out_ref.dtype)[0]
 
 
-@functools.partial(jax.jit, static_argnames=("layer_idx", "interpret", "window"),
+@functools.partial(jax.jit, static_argnames=("layer_idx", "interpret", "window", "block"),
                    donate_argnums=(3, ))
 def paged_attention_update(q, k_new, v_new, cache, layer_idx, block_table, token_seq,
-                           token_pos, token_valid, interpret=None, window=0):
+                           token_pos, token_valid, interpret=None, window=0, block=0):
     """Fused KV-insert + blocked attention for one layer.
 
     q: [T, H, D]; k_new/v_new: [T, KVH, D]; cache: [L, 2, NB, KVH, bs, D]
     (donated; updated in place). ``window`` > 0: a token at position p sees
     keys ``p - window + 1 .. p`` only, and table entries of blocks wholly
-    before them are never read. Returns (attn_out [T, H, D], cache)."""
+    before them are never read. ``block`` must be 0: this grid attends row t
+    before row t + 1 is in the pool. Returns (attn_out [T, H, D], cache)."""
+    if block:
+        raise ValueError(
+            f"paged_attention_update (the per-token grid) cannot serve a block mask (block="
+            f"{block}): it inserts a row and attends before the later rows of its block are in "
+            f"the pool; a model with a block mask takes the tile grid "
+            f"(paged_attention_prefill) at every bucket")
     T, H, D = q.shape
     L, _, NB, KVH, bs, Dc = cache.shape
     assert D == Dc and H % KVH == 0
@@ -284,7 +301,7 @@ def paged_attention_update(q, k_new, v_new, cache, layer_idx, block_table, token
     return out, new_cache
 
 
-def _tiled_kernel(S, MB, bs, rep, scale, precision, window,
+def _tiled_kernel(S, MB, bs, rep, scale, precision, window, block,
                   # scalar prefetch
                   layer_ref, table_ref, seen_ref, ntok_ref, last_ref,
                   # inputs
@@ -428,6 +445,8 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision, window,
                         jnp.int32, (1, ) * (q_pos.ndim - 1) + (CHUNK * bs, ), q_pos.ndim - 1)
                     if window:
                         kv_pos = kv_pos + b_first * bs
+                    if block:  # up to the END of the query's block (-1 stays -1)
+                        q_pos = jnp.bitwise_or(q_pos, block - 1)
                     mask = kv_pos <= q_pos
                     if window:
                         mask &= kv_pos > q_pos - window
@@ -535,9 +554,10 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision, window,
     each(KVH * rep, write_out)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "window"), donate_argnums=(3, ))
+@functools.partial(jax.jit, static_argnames=("interpret", "window", "block"),
+                   donate_argnums=(3, ))
 def paged_attention_prefill(q, k_new, v_new, cache, layer_idx, block_table, seq_seen,
-                            seq_ntok, last_tok, interpret=None, window=0):
+                            seq_ntok, last_tok, interpret=None, window=0, block=0):
     """Fused KV-insert + blocked attention for one layer, query-tiled: for the
     buckets of more than ``TOKEN_GRID_MAX`` tokens (a multiple of ``TQ``).
 
@@ -550,11 +570,17 @@ def paged_attention_prefill(q, k_new, v_new, cache, layer_idx, block_table, seq_
     in :func:`paged_attention_update`; a pass walks from the first block its
     first query sees. A (sequence, tile) pass that owns one token computes that
     token's ``H`` rows alone; one that owns more, the tile's ``H * TQ`` under
-    the tokens' masks (:func:`tiled_passes` counts both on the host). Returns
+    the tokens' masks (:func:`tiled_passes` counts both on the host).
+    ``block`` > 0: a query sees the keys up to its block's end; every
+    sequence's rows are then whole blocks that start at a multiple of ``block``
+    in the batch and in the sequence (the caller's to hold). Returns
     (attn_out [T, H, D], cache); rows of no sequence are zero."""
     T, H, D = q.shape
     L, _, NB, KVH, bs, Dc = cache.shape
     assert D == Dc and H % KVH == 0 and T % TQ == 0
+    if block and (block & (block - 1) or TQ % block or window):
+        raise ValueError(f"a block mask of {block} positions: a power of two that divides the "
+                         f"tile's {TQ} rows, and no sliding window ({window}) beside it")
     S, MB = block_table.shape
     rep = H // KVH
     scale = 1.0 / (D**0.5)
@@ -594,7 +620,8 @@ def paged_attention_prefill(q, k_new, v_new, cache, layer_idx, block_table, seq_
             pltpu.SemaphoreType.DMA((n_stage, 2)),
         ],
     )
-    kernel = functools.partial(_tiled_kernel, S, MB, bs, rep, scale, precision, int(window))
+    kernel = functools.partial(_tiled_kernel, S, MB, bs, rep, scale, precision, int(window),
+                               int(block))
     out, new_cache = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

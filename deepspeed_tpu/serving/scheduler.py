@@ -26,6 +26,16 @@ Batch composition per tick (``step()``):
    any other: in flight under point 7's rule;
 6. idle ticks heartbeat ``engine.empty_run()`` so idle EP replicas stay in
    collective lock-step with busy ones;
+5b. a model that generates by diffusion over blocks (``engine.model.
+   attention_block`` = B): a prompt is fed in whole blocks and its last chunk
+   yields no token; a decode row is a BLOCK (the prompt's ``len % B`` rows the
+   first time, masked rows after) and a decode-only batch runs through
+   ``engine.dispatch_block_loop`` (``decode_chunk / B`` blocks a chunk, up to
+   B tokens a block handed over); a batch with a prompt chunk in it is a
+   ``put`` step the decoding requests sit out, and the two kinds of step take
+   TURNS while both have work: behind a prompt step the decoding requests get
+   a block loop before the next prompt chunk, so arrivals hold a decoder back
+   by one ``put`` step a loop, never by their number;
 7. a step — a ``put`` step or a ``decode_loop`` chunk — whose plan is closed
    to arrivals (it uses the whole token budget or the whole sequence cap) is
    left on the device unfetched, and the next tick dispatches its step behind
@@ -242,7 +252,9 @@ class ServingScheduler:
                            "put_steps", "pipelined_steps", "overrun_rows",
                            "moe_grouped_steps", "moe_capacity_steps",
                            "moe_grouped_chunks", "moe_capacity_chunks",
-                           "pipelined_chunks", "open_behind_steps", "late_commits")
+                           "pipelined_chunks", "open_behind_steps", "late_commits",
+                           "block_loops", "blocks_committed", "denoise_forwards",
+                           "commit_forwards", "block_tokens_cut")
                           + tuple(f"drained_steps_{r}" for r in _DRAIN_REASONS)}
         # the step on the device that no tick has fetched yet (step()),
         # why the newest fetched step was fetched before its successor was
@@ -405,6 +417,18 @@ class ServingScheduler:
         from deepspeed_tpu.serving import kv_tiers as _kv_tiers_mod
         self._kv_tiers = _kv_tiers_mod.maybe_create(
             engine, self._config.kv_tiers, metrics=self._metrics)
+
+        # a model that generates by diffusion over blocks of B positions (0: a
+        # token a sequence a step): a decode chunk is whole blocks
+        self._block = int(getattr(engine.model, "attention_block", 0) or 0)
+        if self._block and self._config.decode_chunk % self._block:
+            raise ValueError(
+                f"decode_chunk {self._config.decode_chunk} with a model that generates by "
+                f"blocks of {self._block}: a chunk is whole blocks, so decode_chunk is a "
+                f"multiple of the block")
+        # whether the newest step dispatched was a block model's prompt step:
+        # the next build gives the decoding requests their turn (_build_batch)
+        self._prompt_turn_taken = False
 
         # every token is drawn on the device (inference/v2/sampling.py): its
         # programs are built here, as set-up, never under a first request (and
@@ -670,6 +694,11 @@ class ServingScheduler:
         if (handoff or req.park_requested or req._resume_header is not None) and \
                 (refusal := self._engine.cache_refusal("frames")) is not None:
             raise refusal
+        if self._block and req.temperature > 0.0:
+            raise ValueError(
+                f"temperature {req.temperature}: a model that generates by diffusion over "
+                f"blocks is served greedily (a row takes its most confident token; a sampled "
+                f"block has no (seed, draw index) rule here yet)")
         req.handoff_requested = bool(handoff)
         if self._ledger is not None:
             # every admitted request carries a RequestCost from birth (the
@@ -1262,9 +1291,12 @@ class ServingScheduler:
                 # a rehydrate enters PREFILL: its parked KV imported, the
                 # un-parked suffix still needs feeding (a handoff enters
                 # DECODE — its donor fed everything)
+                # (and so does a block model's prompt shorter than one block:
+                # it has no prefill, its tokens are its first block's given rows)
                 req._set_state(RequestState.DECODE
                                if (req._resume_header is not None
                                    and not req._rehydrate)
+                               or (self._block and not self._whole_blocks(req))
                                else RequestState.PREFILL)
                 with self._not_full:
                     self._active[req.uid] = req
@@ -1852,7 +1884,7 @@ class ServingScheduler:
         return None
 
     # -------------------------------------------------------- batch building --
-    def _build_batch(self) -> List[Tuple[Request, np.ndarray]]:
+    def _build_batch(self, prompts: Optional[bool] = None) -> List[Tuple[Request, np.ndarray]]:
         """The next step's plan. It reads counts only — what is fed, who
         decodes, how many tokens each request has or has in flight — so it can
         run while a step is on the device unfetched: a request whose tokens in
@@ -1894,17 +1926,38 @@ class ServingScheduler:
             # this a permanently-admitted peer could starve a deferred one
             return sorted(reqs, key=lambda r: (-r._deferred, r.uid))
 
+        # a block model (generation by diffusion over blocks of B positions):
+        # a decode row is a block, a prompt is fed by whole blocks (its last
+        # ``len % B`` tokens are its first decode block's given rows), and a
+        # step is either prompt chunks, which the decoding requests sit out, or
+        # decode blocks alone (a block loop). While both kinds have work they
+        # take turns (``prompts`` None: this build chooses; ``_prompt_turn_taken``
+        # is the dispatch's to write): the step behind a prompt step is a block
+        # loop, so a decoder waits for one ``put`` step a loop however many
+        # prompts arrive, and a prompt's chunks for one loop each
+        B = self._block
+        unit = B or 1
+        decoding = [r for r in list(self._active.values()) if r.state is RequestState.DECODE]
+        chosen_here = prompts is None
+        if B:
+            if chosen_here:
+                prompts = any(r.state is RequestState.PREFILL for r in self._active.values()) \
+                    and not (decoding and self._prompt_turn_taken)
+            if prompts:
+                decoding = []
+        else:
+            prompts = True
+
         # --- decode tokens first: one each (plus up to k draft tokens when
         # speculation is on), latency-critical
         draft_budget = self._spec_draft_budget()
-        for req in by_pressure_priority(
-                [r for r in list(self._active.values()) if r.state is RequestState.DECODE]):
-            if len(lens) + 1 > sm_cfg.max_ragged_sequence_count or sum(lens) + 1 > budget:
+        for req in by_pressure_priority(decoding):
+            if len(lens) + 1 > sm_cfg.max_ragged_sequence_count or sum(lens) + unit > budget:
                 break
             if req._pending and len(req.tokens) + req._pending >= req.max_new_tokens:
                 continue  # its last token is in flight: nothing to feed
             seq = engine._state_manager.get_sequence(req.uid)
-            if seq is not None and seq.seen_tokens + 1 > sm_cfg.max_context:
+            if seq is not None and seq.seen_tokens + unit > sm_cfg.max_context:
                 if req._pending:
                     continue  # cut below, once its last token has been emitted
                 # context window exhausted: a clean length-cut, not an error
@@ -1937,29 +1990,33 @@ class ServingScheduler:
                 req._spec_tree = tree
                 admit(req, tree.tokens)
                 draft_budget -= tree.size - 1
-            elif admit_under_pressure(req, 1):
+            elif admit_under_pressure(req, unit):
                 req._deferred = 0
                 if tree is not None and self._learned is not None:
                     # under pressure the root alone still rides the verify
                     # step: the learned drafter reads its hidden state next
                     req._spec_tree = TokenTree.chain([req._next])
-                admit(req, [0 if req._pending else req._next])
+                admit(req, self._block_feed(req)[0] if B
+                      else [0 if req._pending else req._next])
             else:
                 req._deferred += 1  # KV held by in-flight work; retry next tick
 
         # --- prompt chunks fill what's left (Dynamic SplitFuse)
         for req in by_pressure_priority(
-                [r for r in list(self._active.values()) if r.state is RequestState.PREFILL]):
+                [r for r in list(self._active.values())
+                 if r.state is RequestState.PREFILL and prompts]):
             room = budget - sum(lens)
             if self._config.max_prefill_chunk is not None:
                 room = min(room, self._config.max_prefill_chunk)
+            room = room // unit * unit
             if room < 1 or len(lens) + 1 > sm_cfg.max_ragged_sequence_count:
                 break
-            remaining = req.prompt[req._fed:]
+            remaining = req.prompt[req._fed:self._whole_blocks(req) if B else None]
             while True:
                 chunk = remaining[:room]
                 while chunk.size and admission(req.uid, chunk.size) != SchedulingResult.Success:
-                    chunk = chunk[:chunk.size // 2]  # shrink under KV pressure first
+                    # shrink under KV pressure first (a block model: by whole blocks)
+                    chunk = chunk[:chunk.size // 2 // unit * unit]
                 if chunk.size or not self._evict_one(set(uids) | {req.uid}):
                     break  # admitted something, or nothing left to evict
             if chunk.size:
@@ -1967,7 +2024,26 @@ class ServingScheduler:
                 admit(req, chunk)
             else:
                 req._deferred += 1
+        if B and chosen_here and not plan:
+            # this turn's kind found no room or has nothing to feed: the other
+            # kind does not wait for it
+            return self._build_batch(prompts=not prompts)
         return plan
+
+    def _whole_blocks(self, req: Request) -> int:
+        """A block model's prefill: the prompt's tokens in whole blocks."""
+        return int(req.prompt.size) // self._block * self._block
+
+    def _block_feed(self, req: Request):
+        """``(ids, flags)`` of a block model's decode row: a request's FIRST
+        block carries the prompt's rows past its last whole block and is masked
+        behind them; every later block is masked throughout (the ids under a
+        flag are not read)."""
+        known = req.prompt[self._whole_blocks(req):] if req.decode_steps == 0 \
+            else req.prompt[:0]
+        ids = np.zeros(self._block, np.int32)
+        ids[:known.size] = known
+        return ids, np.arange(self._block) >= known.size
 
     def _evict_one(self, exclude_uids) -> bool:
         """Free device KV blocks under pressure: evict an unreferenced prefix-
@@ -2069,6 +2145,8 @@ class ServingScheduler:
 
         K = self._chunk_steps(plan)
         step = self._dispatch_chunk(plan, K, phases, t0, tick_no) if K else None
+        if step is None and K and self._block:
+            return  # no room for a block a member: a put would commit masked rows
         if step is None:
             step = self._dispatch_put(plan, phases, t0, tick_no)
             if step is None:
@@ -2089,7 +2167,7 @@ class ServingScheduler:
                         lead_us=int(lead * 1e6))
             tick.setdefault("predicted_us", 0)
             if step.loop_steps:
-                tick["kind"] = "decode_loop"
+                tick["kind"] = "block_loop" if self._block else "decode_loop"
             if prev is None:
                 tick["drain"] = self._sync_reason
         # when the device could first have begun this step: when the dispatch
@@ -2117,10 +2195,19 @@ class ServingScheduler:
         K = self._config.decode_chunk
         if K > 1 and self._config.overload.enabled and self._brownout.stage >= 2:
             K = 1  # brownout stage >= 2: speculative extras disabled
-        if K <= 1:
-            return 0
         sm = self._engine._state_manager
         max_context = self._engine._config.state_manager.max_context
+        if self._block:
+            # a block model's decode plan is a block loop whatever K says: whole
+            # blocks, as many as every member's context still has room for (the
+            # build left out whoever has no room for one)
+            if not all(req.state is RequestState.DECODE for req, _ in plan):
+                return 0
+            seen = max((seq.seen_tokens for req, _ in plan
+                        if (seq := sm.get_sequence(req.uid)) is not None), default=0)
+            return max(min(K, max_context - seen) // self._block, 1) * self._block
+        if K <= 1:
+            return 0
 
         def chunk_safe(req):
             # greedy only. A sampled request's stream is keyed by (seed, draw
@@ -2164,8 +2251,8 @@ class ServingScheduler:
         its row of them if its input token is still in flight (-1: the host's
         token stands). None with no step in flight."""
         prev = self._inflight
-        if prev is None:
-            return None
+        if prev is None or self._block:
+            return None  # a block model's step takes no token of the step before
         return prev.ids, [prev.row_of[req.uid] if req._pending else -1 for req in reqs]
 
     def _dispatch_put(self, plan, phases, t0_us=0, tick_no=None) -> Optional[_Step]:
@@ -2196,7 +2283,12 @@ class ServingScheduler:
             if req.state is RequestState.PREFILL:
                 req._fed += toks.size
                 row = None  # mid-prompt logits are meaningless
-                if req._fed >= req.prompt.size:
+                if self._block:
+                    # no chunk of a block model's prompt yields a token: its
+                    # first tokens are its first decode block's
+                    if req._fed >= self._whole_blocks(req):
+                        req._set_state(RequestState.DECODE)
+                elif req._fed >= req.prompt.size:
                     req._set_state(RequestState.DECODE)
                     row = "first"
             else:
@@ -2204,6 +2296,7 @@ class ServingScheduler:
             if row is not None:
                 req._pending += 1
             rows.append(row)
+        self._prompt_turn_taken = bool(self._block)
         return _Step(plan, ids, rows, phases, t0_us, tick_no,
                      getattr(self._engine, "last_moe_fetch", None),
                      key=getattr(self._engine, "last_step_key", None))
@@ -2218,6 +2311,8 @@ class ServingScheduler:
         member, kept or not. None, with nothing changed, if the KV pool has no
         room for K steps a member."""
         reqs = [req for req, _ in plan]
+        if self._block:
+            return self._dispatch_blocks(plan, K, phases, t0_us, tick_no)
         try:
             chunk = self._engine.dispatch_decode_loop(
                 [req.uid for req in reqs], [toks for _, toks in plan], K, prev=self._feed(reqs))
@@ -2229,6 +2324,45 @@ class ServingScheduler:
             req.decode_steps += 1
             req._pending += K
         return _Step(plan, chunk, ["decode"] * len(plan), phases, t0_us, tick_no, loop_steps=K,
+                     key=getattr(self._engine, "last_step_key", None))
+
+    def _dispatch_blocks(self, plan, K, phases, t0_us=0, tick_no=None) -> Optional[_Step]:
+        """:meth:`_dispatch_chunk` for a block model: ``plan``'s decode blocks
+        through ``engine.dispatch_block_loop`` as one chunk of ``K / B`` blocks
+        a member, NOT fetched. A member's row of the chunk is K positions from
+        its block's first: the first ``rows[i]`` of them were given (the
+        prompt's rows of its first block) and are no tokens of its answer, so
+        ``K - rows[i]`` tokens are in flight for it. If the pool has no room
+        for K positions a member, one block each (the build admitted that);
+        None, with nothing changed, if not even that."""
+        B = self._block
+        reqs = [req for req, _ in plan]
+        feeds = [self._block_feed(req) for req in reqs]
+        for k in dict.fromkeys((K, B)):
+            try:
+                chunk = self._engine.dispatch_block_loop(
+                    [req.uid for req in reqs], [ids for ids, _ in feeds],
+                    [flags for _, flags in feeds], k // B)
+                break
+            except SchedulingError:
+                chunk = None
+        if chunk is None:
+            for req in reqs:
+                req._deferred += 1
+            return None
+        chunk.note(tick=tick_no)
+        self._prompt_turn_taken = False
+        self._counters["block_loops"] += 1
+        self._counters["blocks_committed"] += len(reqs) * (k // B)
+        self._counters["denoise_forwards"] += (k // B) * self._engine.model.config.denoising_steps
+        self._counters["commit_forwards"] += k // B
+        self._count_moe_path("chunks")
+        self._charge_members([(req, "decode", k) for req in reqs])
+        given = [int(B - flags.sum()) for _, flags in feeds]
+        for req, n in zip(reqs, given):
+            req.decode_steps += 1
+            req._pending += k - n
+        return _Step(plan, chunk, given, phases, t0_us, tick_no, loop_steps=k,
                      key=getattr(self._engine, "last_step_key", None))
 
     def _complete(self, step: _Step, reason: Optional[str]) -> int:
@@ -2286,13 +2420,21 @@ class ServingScheduler:
         recorded before pushing: the final token finalizes the request and
         closes the root span, which children must nest inside."""
         counts = []
+        if self._block:
+            # a block model's row starts with the rows its first block was
+            # given (``step.rows``: how many): its tokens are behind them
+            rows = [row[given:] for row, given in zip(rows, step.rows)]
         for (req, _), row in zip(step.plan, rows):
             if req.finished:
                 self._counters["overrun_rows"] += 1
                 counts.append(0)
             else:
-                req._pending -= step.loop_steps
+                req._pending -= len(row)
                 counts.append(self._kept_tokens(req, row))
+                if self._block:  # generated on the device and cut: the last block's tail
+                    self._counters["block_tokens_cut"] += len(row) - counts[-1]
+        if self._block:
+            step.result.note(tokens=sum(counts))
         self._rate.observe(sum(counts))
         self._record_phases(self._tick_spans, step.plan, step.phases, step.t0_us, fetched_us,
                             step.tick, counts)

@@ -12,10 +12,12 @@ from deepspeed_tpu.ops.pallas.paged_attention import (paged_attention_prefill,
                                                       paged_attention_update)
 
 
-def _dense_reference(q, cache, li, table, token_seq, token_pos, token_valid, window=0):
+def _dense_reference(q, cache, li, table, token_seq, token_pos, token_valid, window=0, block=0):
     """Per-token dense attention over the block-table history (cache already
     contains every token's K/V, including the queries' own); with ``window``
-    over the last ``window`` keys only, and no table entry before them read."""
+    over the last ``window`` keys only, and no table entry before them read;
+    with ``block`` over every key up to the END of the query's block of
+    ``block`` positions (blocks counted from position 0)."""
     T, H, D = q.shape
     L, _, NB, KVH, bs, _ = cache.shape
     S, MB = table.shape
@@ -26,6 +28,8 @@ def _dense_reference(q, cache, li, table, token_seq, token_pos, token_valid, win
             continue
         s, pos = int(token_seq[t]), int(token_pos[t])
         first = max(pos - window + 1, 0) if window else 0
+        if block:
+            pos = (pos // block + 1) * block - 1
         n = pos + 1 - first
         k = np.zeros((n, KVH, D), np.float32)
         v = np.zeros((n, KVH, D), np.float32)
@@ -124,7 +128,7 @@ def _release_passed(table, cache, seq_next_pos, window, bs):
     return table, cache
 
 
-def _check_tile_grid(seqs, T, *, H, kvh, bs, NB, MB, window=0, S=8):
+def _check_tile_grid(seqs, T, *, H, kvh, bs, NB, MB, window=0, S=8, block=0):
     """The query-tiled grid against the dense reference, and the pool's blocks:
     every inserted row lands, every other element is bit-identical. Under a
     ``window`` the blocks behind each sequence's FIRST query of the step were
@@ -153,8 +157,8 @@ def _check_tile_grid(seqs, T, *, H, kvh, bs, NB, MB, window=0, S=8):
     cache = jnp.asarray(pool)
     for li in range(L):
         got, cache = paged_attention_prefill(q, k_new, v_new, cache, li, walked, *seq,
-                                             window=window)
-        want = _dense_reference(q, exp_cache, li, table, *tok, window=window)
+                                             window=window, block=block)
+        want = _dense_reference(q, exp_cache, li, table, *tok, window=window, block=block)
         np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
         assert not np.any(np.asarray(got)[cursor:])  # padding rows are zero
     live = ~np.isnan(pool)
@@ -318,6 +322,56 @@ def test_five_queries_a_kv_head_on_both_grids(grid):
                                 np.minimum(token_seq, S - 1), token_pos, token_valid)
         np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
     np.testing.assert_array_equal(np.asarray(cache), exp_cache)
+
+
+# a block mask (PR 50): every sequence's rows are whole blocks of 4 that start at a
+# multiple of 4 in the batch and in the sequence
+BLOCK_MASK_BATCHES = {
+    # sixteen block steps: a block starts at EVERY multiple of 4 of the tile
+    "a-block-at-every-multiple-of-4": ([(4 * ((7 * i) % 16), 4) for i in range(16)], 64, 16),
+    # prompt chunks of whole blocks; the second sequence's 8 rows straddle the tile
+    # boundary (rows 60..67): a block on each side, none across it
+    "chunks-across-the-tile-boundary": ([(4, 60), (8, 8), (0, 44)], 128, 8),
+    # a chunk crossing KV blocks of 16 beside block steps deep in their contexts
+    "chunk-and-block-steps": ([(100, 4), (12, 40), (0, 4), (60, 4), (36, 12)], 64, 8),
+}
+
+
+@pytest.mark.parametrize("kvh", [4, 2])  # MHA and GQA
+@pytest.mark.parametrize("batch", list(BLOCK_MASK_BATCHES))
+def test_tile_grid_under_a_block_mask_matches_the_dense_mask(kvh, batch):
+    """``block`` 4: a row sees every key up to its block's end — the later rows
+    of its own block, inserted by the same pass, among them."""
+    seqs, T, S = BLOCK_MASK_BATCHES[batch]
+    _check_tile_grid(seqs, T, H=4, kvh=kvh, bs=16, NB=80, MB=8, S=S, block=4)
+
+
+def test_a_block_mask_changes_what_a_row_sees_and_eight_queries_a_kv_head_hold():
+    """SDAR's 32 query heads over 4 K/V heads, eight a head; and the causal
+    program on the same batch differs in every row but a block's last."""
+    seqs = [(8, 4), (0, 24), (40, 4)]
+    q, k_new, v_new, exp_cache, table, seq = _check_tile_grid(
+        seqs, 64, H=32, kvh=4, bs=16, NB=40, MB=8, block=4)
+    rng = np.random.default_rng(0)
+    cache0 = rng.normal(size=exp_cache.shape).astype(np.float32)
+    masked, _ = paged_attention_prefill(q, k_new, v_new, jnp.asarray(exp_cache), 0, table, *seq,
+                                        block=4)
+    causal, _ = paged_attention_prefill(q, k_new, v_new, jnp.asarray(exp_cache), 0, table, *seq)
+    differs = np.abs(np.asarray(masked) - np.asarray(causal)).max(axis=(1, 2)) > 1e-3
+    assert differs[:32].tolist() == [True, True, True, False] * 8 and cache0.shape
+
+
+def test_the_token_grid_and_a_window_refuse_a_block_mask_by_name():
+    args = (jnp.zeros((8, 4, 128)), jnp.zeros((8, 2, 128)), jnp.zeros((8, 2, 128)),
+            jnp.zeros((1, 2, 4, 2, 16, 128)), 0, jnp.zeros((8, 4), jnp.int32))
+    with pytest.raises(ValueError, match="per-token grid.* cannot serve a block mask"):
+        paged_attention_update(*args, jnp.zeros(8, jnp.int32), jnp.zeros(8, jnp.int32),
+                               jnp.ones(8, jnp.int32), block=4)
+    tiled = (jnp.zeros((64, 4, 128)), jnp.zeros((64, 2, 128)), jnp.zeros((64, 2, 128))) + args[3:]
+    meta = (jnp.zeros(8, jnp.int32), ) * 3
+    for kw in ({"block": 6}, {"block": 128}, {"block": 4, "window": 32}):
+        with pytest.raises(ValueError, match="a block mask of"):
+            paged_attention_prefill(*tiled, *meta, **kw)
 
 
 def _passes_by_the_kernels_rule(seq_ntok, last_tok, bucket_tokens, tq=64):

@@ -820,3 +820,96 @@ def test_falcon_h1_decode_loop_program_fits_one_chip(v5e, falcon_h1_model):
     assert not _falcon_state_sized_results(text, rows=8)
     out = jax.eval_shape(loop, params, cache, batch)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
+
+
+# ---- sdar-30b-a3b-serve-1chip: the block mask on the tile grid and the block loop (PR 50) ----
+SDAR_LAYERS, SDAR_POOL_BLOCKS, SDAR_MAX_BLOCKS, SDAR_SEQS = 7, 2304, 32, 32
+
+
+def test_the_tile_grid_under_a_block_mask_compiles(v5e):
+    """``paged_attention_prefill`` with ``block`` 4 at SDAR's heads (32 queries
+    over 4 K/V heads of 128: eight a K/V head); the token grid refuses the
+    same mask by name before anything is lowered."""
+    from deepspeed_tpu.ops.pallas import paged_attention
+    on = functools.partial(_on, SingleDeviceSharding(v5e[0]))
+
+    def step(q, k, v, cache, *meta):
+        return paged_attention.paged_attention_prefill(q, k, v, cache, 1, *meta, block=4)
+
+    compiled = jax.jit(step, donate_argnums=(3, )).lower(
+        on((128, 32, 128), jnp.bfloat16), on((128, 4, 128), jnp.bfloat16),
+        on((128, 4, 128), jnp.bfloat16), on((2, 2, 256, 4, BS, 128), jnp.bfloat16),
+        on((SDAR_SEQS, SDAR_MAX_BLOCKS), jnp.int32), *(on((SDAR_SEQS, ), jnp.int32), ) * 3).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_attention_prefill" in text
+    with pytest.raises(ValueError, match="per-token grid.*block mask"):
+        paged_attention.paged_attention_update(
+            jnp.zeros((8, 32, 128)), jnp.zeros((8, 4, 128)), jnp.zeros((8, 4, 128)),
+            jnp.zeros((1, 2, 4, 4, BS, 128)), 0, jnp.zeros((8, 4), jnp.int32),
+            jnp.zeros(8, jnp.int32), jnp.zeros(8, jnp.int32), jnp.ones(8, bool), block=4)
+
+
+@pytest.fixture(scope="module")
+def sdar_model():
+    """``sdar-30b-a3b-serve-1chip``: SDAR-30B-A3B-Chat's published widths at seven
+    layers, contexts to 2048, one sequence bucket of 32, over
+    ``jax.eval_shape``d parameters."""
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.model_implementations.registry import model_cls_for
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import DSStateManagerConfig
+    from deepspeed_tpu.models import sdar_moe
+    cfg = sdar_moe.SdarMoeConfig(num_hidden_layers=SDAR_LAYERS)
+    abstract = jax.eval_shape(lambda: sdar_moe.init_params(cfg, param_dtype=cfg.dtype)[1])
+    engine_config = RaggedInferenceEngineConfig(
+        state_manager=DSStateManagerConfig(max_context=SDAR_MAX_BLOCKS * BS,
+                                           max_ragged_batch_size=256,
+                                           max_ragged_sequence_count=SDAR_SEQS),
+        kv_block_size=BS, expert_parallel={"capacity_factor": 16.0})
+    model = model_cls_for(cfg)(abstract, cfg, engine_config)
+    assert model.attention_block == 4 and model.head_dim == 128 and model.group_windows == (0, )
+    assert (model.min_sequence_bucket, model.min_table_bucket, model.min_token_bucket) == \
+        (SDAR_SEQS, SDAR_MAX_BLOCKS, 128)
+    return model, abstract
+
+
+def _sdar_args(device, model, abstract, tokens):
+    one = SingleDeviceSharding(device)
+    params = jax.tree.map(lambda leaf: _on(one, leaf.shape, leaf.dtype), abstract)
+    cache = _on(one, (SDAR_LAYERS, 2, SDAR_POOL_BLOCKS, 4, BS, 128), jnp.bfloat16)
+    batch = {"tok_meta": _on(one, (4, tokens), jnp.int32),
+             "seq_meta": _on(one, (SDAR_SEQS, 4 + SDAR_MAX_BLOCKS), jnp.int32)}
+    return one, params, cache, batch
+
+
+@pytest.mark.parametrize("tokens", [128, 256], ids=["block-step-bucket", "chunk-bucket"])
+def test_sdar_put_program_fits_one_chip(v5e, sdar_model, tokens):
+    """9.3 GiB of weights beside a 2 GiB pool aliased through; EVERY bucket on
+    the tile grid (a block step of 128 rows too) and routed by sorting."""
+    model, abstract = sdar_model
+    assert model.attention_arm(tokens) == model.attention_arm(8) == "paged_tiled"
+    _, params, cache, batch = _sdar_args(v5e[0], model, abstract, tokens)
+    compiled = jax.jit(model._forward_impl, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert "paged_attention_prefill" in text and "paged_attention_update" not in text
+    assert model.moe_path(tokens) == "grouped" and "grouped_matmul" in text
+    assert _device_bytes(compiled) < 0.85 * HBM_BYTES
+    assert not _pool_sized_results(text, cache.shape)
+
+
+def test_sdar_block_loop_program_fits_one_chip(v5e, sdar_model):
+    """Two blocks a chunk: a scan of blocks around a scan of four denoise
+    forwards (every row unembedded, the unmasking's ``top_k``) and a commit
+    forward with no head; ids and int8 steps a row of ``[32, 8]``."""
+    model, abstract = sdar_model
+    one, params, cache, batch = _sdar_args(v5e[0], model, abstract, 128)
+    batch["masked"] = _on(one, (128, ), jnp.int32)
+    loop = functools.partial(model._block_loop_impl, n_blocks=2)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert "paged_attention_prefill" in text and "grouped_matmul" in text
+    assert "paged_attention_update" not in text
+    out = jax.eval_shape(loop, params, cache, batch)
+    assert (out[0].shape, out[0].dtype, out[1].shape, out[1].dtype) == \
+        ((32, 8), jnp.int32, (32, 8), jnp.int8)
+    assert _device_bytes(compiled) < 0.85 * HBM_BYTES
+    assert not _pool_sized_results(text, cache.shape)

@@ -146,7 +146,8 @@ def test_one_program_a_kind_and_key_built_once(v2):
     engine.verify_tree([0], [TokenTree([1, 2, 3, 4], [-1, 0, 0, 1])], greedy=True)
     engine.compact_accepted(0, 4, [2])
     fns = engine.lowerable_callables()
-    assert list(fns) == ["forward", "decode_loop", "verify", "compact"]
+    assert list(fns) == ["forward", "decode_loop", "verify", "compact", "block_forward",
+                         "block_loop"]
     (bucket, forward), = fns["forward"].items()
     (loop_key, loop), = fns["decode_loop"].items()
     (verify_key, verify), = fns["verify"].items()

@@ -244,7 +244,8 @@ def test_put_path_fetches_ids_builds_nothing_and_counts_its_draws(make_engine, l
 
     # the engine's forward programs are engine.put's: (T, S, MB) buckets only
     programs = engine.lowerable_callables()
-    assert set(programs) == {"forward", "decode_loop", "verify", "compact"}
+    assert set(programs) == {"forward", "decode_loop", "verify", "compact", "block_forward",
+                             "block_loop"}  # the last two: a block-diffusion model's, empty here
     assert programs["forward"] and all(
         isinstance(k, tuple) and len(k) == 3 and all(isinstance(d, int) for d in k)
         for k in programs["forward"])
