@@ -171,7 +171,9 @@ class DSTransformerModelBase:
         """Work counters of a step that depend on the batch's positions (the
         host's copy of them), for the dispatch's span; ``steps`` > 1: over a
         ``decode_loop`` chunk. A bucket on the query-tiled grid: the passes its
-        kernels make over the layers, and those of them that own one token
+        kernels make over the layers, those of them that own one token, and
+        those that take the kernel's few-row arm (no more rows than one block of
+        ``attention_block``; the one-token ones where there is no block mask)
         (``ops/pallas/paged_attention.py:tiled_passes``)."""
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
         bucket_tokens = batch["tok_meta"].shape[1]
@@ -179,10 +181,10 @@ class DSTransformerModelBase:
             return {}
         from deepspeed_tpu.ops.pallas.paged_attention import tiled_passes
         seq = np.asarray(batch["seq_meta"])
-        passes, one_token = tiled_passes(seq[:, 1], seq[:, 2], bucket_tokens)
+        counts = tiled_passes(seq[:, 1], seq[:, 2], bucket_tokens, self.attention_block)
         # ``steps`` > 1 on this grid: the forwards of a block loop, all alike
-        return {"tiled_passes": passes * self.num_kv_layers * steps,
-                "tiled_one_token_passes": one_token * self.num_kv_layers * steps}
+        names = ("tiled_passes", "tiled_one_token_passes", "tiled_few_row_passes")
+        return {name: n * self.num_kv_layers * steps for name, n in zip(names, counts)}
 
     def set_state_manager(self, state_manager):
         self._state_manager = state_manager

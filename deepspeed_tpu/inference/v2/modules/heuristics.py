@@ -48,7 +48,8 @@ def attention_implementation(model, engine_config, bucket_tokens: int) -> str:
     bs = engine_config.kv_block_size
     scratch_bytes = 2 * 2 * CHUNK * model.num_kv_heads * bs * model.head_dim * 2
     held = scratch_bytes if kernel == "paged_token" else tile_grid_vmem_bytes(
-        model.num_heads, model.num_kv_heads, model.head_dim, bs)
+        model.num_heads, model.num_kv_heads, model.head_dim, bs,
+        block=getattr(model, "attention_block", 0))
     # of the ~16MB a kernel may use: half for the chunks, three quarters in all
     if scratch_bytes > 8 * 1024 * 1024 or held > 12 * 1024 * 1024:
         logger.warning(f"paged kernel K/V scratch {scratch_bytes >> 20}MB ({held >> 20}MB held "

@@ -32,14 +32,27 @@ rows are whose is computed in the kernel from ``seq_seen`` / ``seq_ntok`` /
 ``last_tok``, the scalar prefetch). A pass inserts its rows into their blocks
 in place (at most ``TQ / bs + 1`` block read-modify-writes, the rows moved to
 their offsets by a 0/1 selection matmul), then walks the sequence's table
-ONCE: per KV head a ``[rep·TQ, 8·bs]`` logits tile on the MXU, operands in the
-wider of the queries' and the pool's dtype, float32 accumulation, causal mask
-from positions. A pass pays for the rows it owns: one that owns ONE token of
-its tile (a decode row riding beside a chunk, a chunk's last token alone in
-the next tile) picks that token's ``rep`` rows a KV head out of the slab and
-walks the same chunks with a ``[KVH, rep, 8·bs]`` logits tile, the per-token
-grid's, then puts its softmax state into the token's rows of the tile's. A
-tile with no tokens writes zeros and touches nothing.
+ONCE; the walk's first chunk is fetched UNDER the insert, all but the blocks
+the insert rewrites, which follow when they have landed. A pass pays for the
+rows it owns:
+
+- one of MORE rows than one block of the mask (than one token without a block
+  mask: a prompt chunk) computes per KV head a ``[rep·TQ, 8·bs]`` logits tile on
+  the MXU, operands in the wider of the queries' and the pool's dtype, float32
+  accumulation, the mask from positions; rows of other sequences see no key;
+- one of NO MORE rows than one block — a block step's block under a block
+  mask, a decode row riding beside a chunk, a chunk's last token alone in the
+  next tile — picks its ``rep`` rows a KV head for each row of the block out
+  of the slab and walks the same chunks with a ``[KVH, rep·block, 8·bs]`` logits
+  tile (the per-token grid's at ``block`` 0); the rows of one block see the same
+  keys, so one mask serves them. It then puts its softmax state into its rows
+  of the tile's. At SDAR's shape (32 block steps a call, four KV heads) such a
+  pass costs ~6.7 us where it cost ~19 as a tile's 512 rows a KV head (PERF.md
+  section 6, PR 51); what is left above its K/V bytes' ~2.6 us is the insert's
+  own chain between two passes (fetch a block, wait, merge, write, wait): a
+  pass's insert does not yet run under the previous pass's walk.
+
+A tile with no tokens writes zeros and touches nothing.
 
 Sliding window (``window`` > 0, static; 0 = full causal and the program it
 always was): both grids mask ``kv_pos > q_pos - window`` AND start their walk
@@ -79,35 +92,39 @@ def _stage_blocks(bs):
     return 1 + -(-(TQ - 1) // bs)
 
 
-def _one_token_rows(rep, itemsize):
-    """Query rows a KV head of a one-token pass: ``rep``, padded to a sublane
-    tile of the operands' dtype."""
+def _few_rows(rep, block, itemsize):
+    """Query rows a KV head of a few-row pass: ``rep`` for each row of a block
+    (of the one token without a block mask), padded to a sublane tile of the
+    operands' dtype."""
     tile = 8 * (4 // itemsize)
-    return -(-rep // tile) * tile
+    return -(-rep * max(block, 1) // tile) * tile
 
 
-def tile_grid_vmem_bytes(H, KVH, D, bs, itemsize=2):
+def tile_grid_vmem_bytes(H, KVH, D, bs, itemsize=2, block=0):
     """VMEM a query-tiled call holds: the double-buffered K/V chunks, the
     insert's staging blocks, the grouped queries, the softmax state (a per-row
-    scalar pads to 128 lanes), a one-token pass's queries and state, and the
+    scalar pads to 128 lanes), a few-row pass's queries and state, and the
     pipelined q / out / new-K/V blocks."""
-    block = KVH * bs * D * itemsize
+    kv_block = KVH * bs * D * itemsize
     tile = H * TQ * D
-    one = KVH * _one_token_rows(H // KVH, itemsize)
-    return ((2 * 2 * CHUNK + 2 * _stage_blocks(bs)) * block + tile * itemsize + tile * 4
-            + 2 * H * TQ * 128 * 4 + one * (D * itemsize + D * 4 + 128 * 4)
+    few = KVH * _few_rows(H // KVH, block, itemsize)
+    return ((2 * 2 * CHUNK + 2 * _stage_blocks(bs)) * kv_block + tile * itemsize
+            + tile * 4 + 2 * H * TQ * 128 * 4 + few * (D * itemsize + D * 4 + 128 * 4)
             + 2 * 2 * (tile + KVH * TQ * D) * itemsize)
 
 
-def tiled_passes(seq_ntok, last_tok, bucket_tokens):
-    """The (sequence, tile) pairs a query-tiled call works through, and those
-    that own ONE token of their tile, by ``_tiled_kernel``'s rule, from the
-    host's copy of the scalar prefetch (numpy ``[S]``)."""
+def tiled_passes(seq_ntok, last_tok, bucket_tokens, block=0):
+    """The (sequence, tile) pairs a query-tiled call works through, those that
+    own ONE token of their tile, and those that take the few-row arm (no more
+    rows than one block of ``block``; the one-token ones without a block mask),
+    by ``_tiled_kernel``'s rule, from the host's copy of the scalar prefetch
+    (numpy ``[S]``)."""
     n, last = np.asarray(seq_ntok)[:, None], np.asarray(last_tok)[:, None]
     t0 = np.arange(0, bucket_tokens, TQ)[None, :]
     lo, hi = np.maximum(last - n + 1, t0), np.minimum(last, t0 + TQ - 1)
     owned = (n > 0) & (lo <= hi)
-    return int(owned.sum()), int((owned & (lo == hi)).sum())
+    return (int(owned.sum()), int((owned & (lo == hi)).sum()),
+            int((owned & (hi - lo < max(block, 1))).sum()))
 
 
 def _first_visible_block(pos, window, bs):
@@ -312,7 +329,7 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision, window, block,
                   q_s, m_s, l_s, acc_s, q1_s, l1_s, acc1_s, k_buf, v_buf, kv_stage, sems, wsem):
     # Loops over heads and blocks are ``fori_loop``s, not Python loops: one
     # kernel is traced and lowered for every bucket's program at every start
-    # of the server, and an unrolled body costs that 8 x over. (A one-token
+    # of the server, and an unrolled body costs that 8 x over. (A few-row
     # pass takes its KV heads as the batch of one ``dot_general``, as the
     # per-token grid does: its chains of a few vregs a head must overlap.)
     t0 = pl.program_id(0) * TQ
@@ -320,6 +337,7 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision, window, block,
     KVH, D = k_buf.shape[1], k_buf.shape[3]
     dtype = k_buf.dtype  # the pool's
     op_dtype = q_s.dtype  # the matmuls' operands: the wider of the queries' and the pool's
+    B = max(block, 1)  # the rows a few-row pass owns, at most
 
     def each(n, fn):
         jax.lax.fori_loop(0, n, lambda i, carry: fn(i), None)
@@ -391,10 +409,6 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision, window, block,
             stage_copy(j, 0, True).wait()
             stage_copy(j, 1, True).wait()
 
-        each(n_touched, fetch)
-        each(n_touched, merge)
-        each(n_touched, landed)
-
         # ---- walk the block table once, double-buffered chunks --------------
         if window:
             # from the first block that holds a key the pass's FIRST query
@@ -403,21 +417,39 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision, window, block,
             b_first = _first_visible_block(p_lo, window, bs)
             nchunks = pl.cdiv(b1 + 1 - b_first, CHUNK)
         else:
+            b_first = 0
             nchunks = pl.cdiv(b1 + 1, CHUNK)
 
         def chunk_copies(c, slot, j):
-            bid = block_id(c * CHUNK + j + b_first if window else c * CHUNK + j)
+            bid = block_id(c * CHUNK + j + b_first)
             rows = pl.ds(pl.multiple_of(j * bs, bs), bs)
             return (pltpu.make_async_copy(cache_out_ref.at[li, 0, bid], k_buf.at[slot, :, rows],
                                           sems.at[0, slot, j]),
                     pltpu.make_async_copy(cache_out_ref.at[li, 1, bid], v_buf.at[slot, :, rows],
                                           sems.at[1, slot, j]))
 
-        def start_chunk(c, slot):
+        def start_chunk(c, slot, rewritten=None):
+            """Start chunk c's fetches: all of them, or (chunk 0) those of the
+            blocks the insert rewrites / of the others."""
             def start(j):
-                for cp in chunk_copies(c, slot, j):
-                    cp.start()
+                def go():
+                    for cp in chunk_copies(c, slot, j):
+                        cp.start()
+                if rewritten is None:
+                    go()
+                else:
+                    b = c * CHUNK + j + b_first
+                    pl.when(((b >= b0) & (b <= b1)) == rewritten)(go)
             each(CHUNK, start)
+
+        # the walk's first chunk is fetched UNDER the insert, but for the blocks
+        # the insert rewrites (its own: the last of the walk, so in chunk 0 only
+        # under a chunk's keys), which follow once they have landed
+        each(n_touched, fetch)
+        start_chunk(0, 0, rewritten=False)
+        each(n_touched, merge)
+        each(n_touched, landed)
+        start_chunk(0, 0, rewritten=True)
 
         def wait_chunk(c, slot):
             def wait(j):
@@ -428,9 +460,8 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision, window, block,
         def walk(attend, state=None):
             """``attend(slot, visible, state) -> state`` over the chunks in
             turn; ``visible(q_pos)``: the chunk's keys a query at ``q_pos``
-            (any shape that broadcasts against ``[..., CHUNK*bs]``) sees."""
-            start_chunk(0, 0)
-
+            (any shape that broadcasts against ``[..., CHUNK*bs]``) sees. Chunk 0
+            is on its way."""
             def chunk(c, state):
                 slot = jax.lax.rem(c, 2)
 
@@ -456,10 +487,10 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision, window, block,
 
             return jax.lax.fori_loop(0, nchunks, chunk, state)
 
-        @pl.when(lo != hi)
+        @pl.when(hi - lo >= B)
         def _():
-            # the tile's rows under the pass's mask: a row of another sequence
-            # (or of padding) sees no key
+            # more rows than one block: the tile's rows under the pass's mask;
+            # a row of another sequence (or of padding) sees no key
             q_pos = jnp.where((row_tok >= lo) & (row_tok <= hi), row_tok + shift, -1)
 
             def attend(slot, visible, _):
@@ -486,16 +517,21 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision, window, block,
 
             walk(attend)
 
-        @pl.when(lo == hi)
+        @pl.when(hi - lo < B)
         def _():
-            # ONE token of the tile (a decode row beside a chunk, a chunk's end
-            # alone in the next tile): its ``rep`` rows a KV head, not the slab
+            # no more rows than ONE block (a block step under a block mask; one
+            # token without one: a decode row beside a chunk, a chunk's end
+            # alone in the next tile): the pass's ``rep`` rows a KV head for
+            # each row of the block, not the slab
             t = lo - t0
-            R = q1_s.shape[1]  # rep, padded to a sublane tile; the rows past rep are zero
+            R = q1_s.shape[1]  # rep * B, padded to a sublane tile; the rows past are zero
             r = jax.lax.broadcasted_iota(jnp.int32, (R, rep * TQ), 0)
-            col = jax.lax.broadcasted_iota(jnp.int32, (R, rep * TQ), 1)
-            # 0/1 selection of the token's rows out of the slab, exact on the MXU
-            sel = ((col == r * TQ + t) & (r < rep)).astype(op_dtype)
+            slab_row = jax.lax.broadcasted_iota(jnp.int32, (R, rep * TQ), 1)
+            # row r of the pass: head r // B of the KV group, the pass's row r % B
+            r_head, r_row = jnp.right_shift(r, B.bit_length() - 1), jnp.bitwise_and(r, B - 1)
+            # 0/1 selection of the pass's rows out of the slab, exact on the MXU
+            sel = ((slab_row == r_head * TQ + t + r_row) & (r_head < rep)
+                   & (r_row <= hi - lo)).astype(op_dtype)
 
             def pick(g):
                 q1_s[g] = jnp.dot(sel, q_s[g], preferred_element_type=jnp.float32,
@@ -503,6 +539,7 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision, window, block,
 
             each(KVH, pick)
             q1 = q1_s[...]
+            # the rows of one block see the same keys: up to the block's end
             pos = jnp.full((1, 1, 1), p_lo, jnp.int32)
 
             def attend(slot, visible, state):
@@ -524,15 +561,26 @@ def _tiled_kernel(S, MB, bs, rep, scale, precision, window, block,
                 jnp.full((KVH, R, 1), NEG_INF, jnp.float32), jnp.zeros((KVH, R, 1), jnp.float32),
                 jnp.zeros((KVH, R, D), jnp.float32)))
 
-            # the token's rows of the tile's state (a row belongs to ONE pass:
-            # nothing was there), by a select: row t of each head's TQ rows
-            hit = jax.lax.broadcasted_iota(jnp.int32, (TQ, 1), 0) == t
+            # the pass's rows of the tile's state (a row belongs to ONE pass:
+            # nothing was there), by a select on the aligned group of rows
+            # that holds them
+            G = max(B, 8)
+            at = t - jax.lax.rem(t, G)
+            in_group = jax.lax.broadcasted_iota(jnp.int32, (G, 1), 0)
 
             def place(h):
-                slab, _ = head_rows(h)
-                row = pl.ds(h % rep, 1)
-                l_s[slab] = jnp.where(hit, l1_s[h // rep, row], l_s[slab])
-                acc_s[slab] = jnp.where(hit, acc1_s[h // rep, row], acc_s[slab])
+                g, r0 = h // rep, (h % rep) * B
+                group = (g, pl.ds(pl.multiple_of((h % rep) * TQ + at, 8), G))
+                l, acc = l_s[group], acc_s[group]
+                if B >= 8:  # a block starts at a multiple of B: it IS the group
+                    hit, rows = in_group <= hi - lo, pl.ds(pl.multiple_of(r0, 8), B)
+                    l, acc = jnp.where(hit, l1_s[g, rows], l), jnp.where(hit, acc1_s[g, rows], acc)
+                else:  # up to four rows of a group of 8, one by one
+                    for j in range(B):
+                        hit = in_group == jnp.where(j <= hi - lo, t - at + j, -1)
+                        l = jnp.where(hit, l1_s[g, pl.ds(r0 + j, 1)], l)
+                        acc = jnp.where(hit, acc1_s[g, pl.ds(r0 + j, 1)], acc)
+                l_s[group], acc_s[group] = l, acc
 
             each(KVH * rep, place)
 
@@ -568,12 +616,13 @@ def paged_attention_prefill(q, k_new, v_new, cache, layer_idx, block_table, seq_
     ``seq_ntok <= 0`` is empty. ``layer_idx`` is an operand, not a constant:
     a model's layers share ONE kernel in the compiled program. ``window`` as
     in :func:`paged_attention_update`; a pass walks from the first block its
-    first query sees. A (sequence, tile) pass that owns one token computes that
-    token's ``H`` rows alone; one that owns more, the tile's ``H * TQ`` under
-    the tokens' masks (:func:`tiled_passes` counts both on the host).
-    ``block`` > 0: a query sees the keys up to its block's end; every
-    sequence's rows are then whole blocks that start at a multiple of ``block``
-    in the batch and in the sequence (the caller's to hold). Returns
+    first query sees. A (sequence, tile) pass that owns no more rows than one
+    block (one token where ``block`` is 0) computes those rows' ``H`` alone;
+    one that owns more, the tile's ``H * TQ`` under the tokens' masks
+    (:func:`tiled_passes` counts them on the host). ``block`` > 0: a query
+    sees the keys up to its block's end; every sequence's rows are then whole
+    blocks that start at a multiple of ``block`` in the batch and in the
+    sequence (the caller's to hold). Returns
     (attn_out [T, H, D], cache); rows of no sequence are zero."""
     T, H, D = q.shape
     L, _, NB, KVH, bs, Dc = cache.shape
@@ -590,7 +639,7 @@ def paged_attention_prefill(q, k_new, v_new, cache, layer_idx, block_table, seq_
     # float32 operands (tests, a float32 pool) must not pass through bf16 on the MXU
     precision = jax.lax.Precision.HIGHEST if op_dtype == jnp.float32 else None
     n_stage = _stage_blocks(bs)
-    one_rows = _one_token_rows(rep, jnp.dtype(op_dtype).itemsize)
+    few_rows = _few_rows(rep, block, jnp.dtype(op_dtype).itemsize)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
@@ -610,9 +659,9 @@ def paged_attention_prefill(q, k_new, v_new, cache, layer_idx, block_table, seq_
             pltpu.VMEM((KVH, rep * TQ, 1), jnp.float32),        # running max
             pltpu.VMEM((KVH, rep * TQ, 1), jnp.float32),        # running sum
             pltpu.VMEM((KVH, rep * TQ, D), jnp.float32),        # accumulator
-            pltpu.VMEM((KVH, one_rows, D), op_dtype),           # a one-token pass: its queries,
-            pltpu.VMEM((KVH, one_rows, 1), jnp.float32),        # running sum
-            pltpu.VMEM((KVH, one_rows, D), jnp.float32),        # and accumulator
+            pltpu.VMEM((KVH, few_rows, D), op_dtype),           # a few-row pass: its queries,
+            pltpu.VMEM((KVH, few_rows, 1), jnp.float32),        # running sum
+            pltpu.VMEM((KVH, few_rows, D), jnp.float32),        # and accumulator
             pltpu.VMEM((2, KVH, CHUNK * bs, D), cache.dtype),
             pltpu.VMEM((2, KVH, CHUNK * bs, D), cache.dtype),
             pltpu.VMEM((n_stage, 2, KVH, bs, D), cache.dtype),

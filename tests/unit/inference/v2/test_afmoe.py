@@ -701,7 +701,9 @@ def test_float32_holds_what_the_loose_tolerance_lets_through(model, control):
 # one difference is one more output, the banks each expert layer's routing
 # touched (``(group_sizes > 0).sum()`` a layer, stacked); the four programs on
 # the capacity path are the parent's still. ``mellum.kernel.forward.128x8x8`` was
-# re-recorded again in PR 42 (the query-tiled kernel's one-token pass); the gather
+# re-recorded again in PR 42 (the query-tiled kernel's one-token pass) and in PR 51
+# (that kernel's few-row arm and its first chunk under the insert: the kernel's body alone, as
+# ``test_one_group_programs.py`` says); the gather
 # arm's, the 8-token and the chunk's programs held. The two ``decode_loop``
 # programs were re-recorded in PR 46 with ``test_one_group_programs.py``'s four:
 # the loop lost its unused temperature and key, and nothing else.
@@ -710,7 +712,7 @@ _MELLUM_PARENT = {
     "mellum.gather.forward.128x8x8": "baf95ba27f841e05ba61035349e3b77b1a59a50e1f29c3b903dcf1e648782f1a",
     "mellum.gather.decode_loop": "9c67c90e4a53015f5d646b9a49c696c15a20da16a633a0668829b71759c08121",
     "mellum.kernel.forward.8x8x4": "2857ae3f6a0f05a300e1c4d552b4455cb6ee85431770ab01a80eaea76e50f73b",
-    "mellum.kernel.forward.128x8x8": "de051196db4d56ac6f7db2dc36ce95a3049e00f61a2e25c83298c0c6531462f5",
+    "mellum.kernel.forward.128x8x8": "153d5d263798e50d1acc0e2ae8d3fd2804e3734a436c8f9b6742ecaacfc53563",
     "mellum.kernel.decode_loop": "ea7d90a737b8ecd9974fea2c7be90f64289de2a83ac6fe37c2a137a1da8ff349",
 }
 

@@ -219,6 +219,48 @@ def test_the_loop_from_a_part_given_first_block_is_generates(model):
     engine.close()
 
 
+def test_the_spans_count_the_tile_grids_few_row_passes(model):
+    """On the tile grid under a telemetry session: a block loop's span counts a
+    pass a sequence a layer a forward, every one the kernel's few-row arm's (a
+    block of 4 rows is one pass of one block); a prompt chunk's ``put`` counts
+    its pass as a many-row one; without a block mask (``batch_counts`` of a
+    causal model at the same positions) the arm is the one-token passes'."""
+    from deepspeed_tpu import telemetry
+    cfg, params = model
+    engine = engine_of(cfg, params, kernel=True)
+    prompts = _prompts()
+    uids = list(range(len(prompts)))
+    whole = _prefill(engine, prompts)
+    blocks, masked = _first_blocks(prompts, whole)
+    session = telemetry.configure({"enabled": True, "compile_watch": False})
+    try:
+        engine.put([9], [np.arange(24, dtype=np.int32)])  # a prompt chunk of six blocks
+        engine.dispatch_block_loop(uids, blocks, masked, 2).fetch()
+        spans = [s for s in session.spans.export_since(0)["spans"] if s["cat"] == "inference"]
+    finally:
+        telemetry.shutdown()
+    layers, forwards = cfg.num_hidden_layers, 2 * (cfg.denoising_steps + 1)
+    (loop, ) = [s["args"] for s in spans if s["name"] == "block_loop"]
+    assert loop["steps"] == forwards
+    assert loop["tiled_passes"] == loop["tiled_few_row_passes"] == len(uids) * layers * forwards
+    assert loop["tiled_one_token_passes"] == 0
+    (put, ) = [s["args"] for s in spans if s["name"] == "put"]
+    assert put["attention"] == "paged_tiled" and put["tiled_one_token_passes"] == 0
+    # 24 rows are one pass of more than one block: the many-row arm's
+    assert put["tiled_passes"] == layers and put["tiled_few_row_passes"] == 0
+    # the same batch without a block mask: one row a sequence is a one-token pass, and the
+    # few-row arm is theirs alone
+    causal = type("Causal", (type(engine.model), ), {"attention_block": 0})
+    decode = {"tok_meta": np.zeros((4, 64), np.int32),
+              "seq_meta": np.array([[0, 1, u, 1] + [0] * 16 for u in range(4)]
+                                   + [[0, 0, 0, 0] + [0] * 16] * 4, np.int32)}
+    counts = causal.batch_counts(engine.model, decode)
+    assert counts["tiled_few_row_passes"] == counts["tiled_one_token_passes"] == 4 * layers
+    with_block = engine.model.batch_counts(decode)
+    assert with_block["tiled_few_row_passes"] == with_block["tiled_passes"] == 4 * layers
+    engine.close()
+
+
 def test_a_feed_that_is_not_whole_blocks_is_refused_where_the_batch_is_built(model):
     cfg, params = model
     engine = engine_of(cfg, params)

@@ -346,6 +346,51 @@ def test_tile_grid_under_a_block_mask_matches_the_dense_mask(kvh, batch):
     _check_tile_grid(seqs, T, H=4, kvh=kvh, bs=16, NB=80, MB=8, S=S, block=4)
 
 
+# the few-row arm (PR 51): a pass of no more rows than one block computes its own rows.
+# (seen, new) per sequence, the bucket's tokens, the sequence slots; then the heads, the
+# K/V heads and the table's width
+FEW_ROW_BATCHES = {
+    **{name: batch + (4, 4, 8) for name, batch in BLOCK_MASK_BATCHES.items()},
+    # SDAR's block step: 32 sequences x one block over two tiles, eight queries a K/V
+    # head; contexts on both sides of a chunk's 8 x 16 = 128 keys, so some passes find
+    # their own block in chunk 0 and some in their last of two; two sequences start at
+    # position 0
+    "32-block-steps-over-two-tiles": ([(4 * ((11 * i) % 48), 4) for i in range(32)], 128, 32,
+                                      32, 4, 16),
+    # one tile: a prompt chunk of 24 rows (the many-row arm) between block steps, one of
+    # them past a chunk's keys, and padding behind them; the second tile all padding
+    "chunk-block-steps-and-padding": ([(40, 4), (8, 24), (0, 4), (132, 4)], 128, 8, 8, 2, 16),
+}
+
+
+@pytest.mark.parametrize("batch", list(FEW_ROW_BATCHES))
+def test_few_row_passes_under_a_block_mask_match_the_dense_mask(batch):
+    """Every pass of one block takes the few-row arm (``tiled_passes`` counts
+    them by the kernel's rule); outputs against the dense mask, the pool bit
+    for bit."""
+    from deepspeed_tpu.ops.pallas.paged_attention import tiled_passes
+    seqs, T, S, H, kvh, MB = FEW_ROW_BATCHES[batch]
+    blocks = sum(-(-(seen + n) // 16) for seen, n in seqs)
+    *_, (_, seq_ntok, last_tok) = _check_tile_grid(seqs, T, H=H, kvh=kvh, bs=16, NB=blocks + 8,
+                                                   MB=MB, S=S, block=4)
+    passes, one_token, few = tiled_passes(seq_ntok, last_tok, T, 4)
+    assert (passes, one_token, few) == _passes_by_the_kernels_rule(seq_ntok, last_tok, T, 4)
+    # a block step is one few-row pass; so is a chunk's one block alone in a tile
+    assert one_token == 0 and few >= sum(n == 4 for _, n in seqs) and few > 0
+
+
+@pytest.mark.parametrize("block", [8, 16])
+def test_a_few_row_pass_of_a_wider_block_places_its_rows_with_one_select(block):
+    """From a block of 8 on, a block is the aligned group of rows that holds it
+    in the tile's state: block steps (one at position 0, one past a chunk's
+    keys) around a chunk of two blocks (the many-row arm)."""
+    from deepspeed_tpu.ops.pallas.paged_attention import tiled_passes
+    seqs = [(2 * block, block), (0, block), (block, 2 * block), (9 * block, block)]
+    *_, (_, seq_ntok, last_tok) = _check_tile_grid(seqs, 128, H=4, kvh=2, bs=16, NB=40, MB=16,
+                                                   block=block)
+    assert tiled_passes(seq_ntok, last_tok, 128, block) == (4, 0, 3)
+
+
 def test_a_block_mask_changes_what_a_row_sees_and_eight_queries_a_kv_head_hold():
     """SDAR's 32 query heads over 4 K/V heads, eight a head; and the causal
     program on the same batch differs in every row but a block's last."""
@@ -374,50 +419,70 @@ def test_the_token_grid_and_a_window_refuse_a_block_mask_by_name():
             paged_attention_prefill(*tiled, *meta, **kw)
 
 
-def _passes_by_the_kernels_rule(seq_ntok, last_tok, bucket_tokens, tq=64):
-    """(sequence, tile) pairs and those with lo == hi, as ``_tiled_kernel``'s
-    ``sequence`` decides them, one pair at a time."""
-    passes = one_token = 0
+def _passes_by_the_kernels_rule(seq_ntok, last_tok, bucket_tokens, block=0, tq=64):
+    """(sequence, tile) pairs, those with lo == hi, and those of no more rows
+    than one block (the few-row arm's), as ``_tiled_kernel``'s ``sort`` decides
+    them, one pair at a time."""
+    passes = one_token = few = 0
     for n, last in zip(seq_ntok, last_tok):
         for t0 in range(0, bucket_tokens, tq):
             lo, hi = max(last - n + 1, t0), min(last, t0 + tq - 1)
             if n > 0 and lo <= hi:
                 passes += 1
                 one_token += lo == hi
-    return passes, one_token
+                few += hi - lo < max(block, 1)
+    return passes, one_token, few
 
 
-@pytest.mark.parametrize("batch", list(TILED_BATCHES) + list(WINDOW_TILED_BATCHES))
-def test_tiled_passes_counts_what_the_kernel_walks(batch):
+@pytest.mark.parametrize("block", [0, 4])
+@pytest.mark.parametrize("batch", list(TILED_BATCHES) + list(WINDOW_TILED_BATCHES)
+                         + list(BLOCK_MASK_BATCHES))
+def test_tiled_passes_counts_what_the_kernel_walks(batch, block):
     from deepspeed_tpu.ops.pallas.paged_attention import tiled_passes
-    seqs, T = {**TILED_BATCHES, **WINDOW_TILED_BATCHES}[batch]
-    _, _, (_, seq_ntok, last_tok), _ = _ragged_batch(seqs, T, 8, 64, 4, list(range(1000)))
-    assert tiled_passes(seq_ntok, last_tok, T) == _passes_by_the_kernels_rule(seq_ntok, last_tok, T)
-    assert tiled_passes(np.zeros(8, np.int32), last_tok, T) == (0, 0)
+    seqs, T, *slots = {**TILED_BATCHES, **WINDOW_TILED_BATCHES, **BLOCK_MASK_BATCHES}[batch]
+    _, _, (_, seq_ntok, last_tok), _ = _ragged_batch(seqs, T, *(slots or [8]), 64, 4,
+                                                     list(range(1000)))
+    counted = tiled_passes(seq_ntok, last_tok, T, block)
+    assert counted == _passes_by_the_kernels_rule(seq_ntok, last_tok, T, block)
+    # without a block mask the few-row arm is the one-token passes'; a block only adds to it
+    assert counted[2] >= counted[1] and (block or counted[2] == counted[1])
+    assert tiled_passes(np.zeros(8, np.int32), last_tok[:8], T, block) == (0, 0, 0)
 
 
 def test_tiled_passes_of_known_batches():
     from deepspeed_tpu.ops.pallas.paged_attention import tiled_passes
 
-    def count(batch):
-        seqs, T = batch
-        _, _, (_, seq_ntok, last_tok), _ = _ragged_batch(seqs, T, 8, 64, 4, list(range(1000)))
-        return tiled_passes(seq_ntok, last_tok, T)
+    def count(batch, block=0):
+        seqs, T, *slots = batch
+        _, _, (_, seq_ntok, last_tok), _ = _ragged_batch(seqs, T, *(slots or [8]), 64, 4,
+                                                         list(range(1000)))
+        return tiled_passes(seq_ntok, last_tok, T, block)
 
-    assert count(TILED_BATCHES["chunk-plus-decode-rows"]) == (5, 3)  # the chunk: tiles 0 and 1
-    assert count(TILED_BATCHES["chunk-end-alone-in-next-tile"]) == (2, 1)
-    assert count(TILED_BATCHES["seven-one-token-rows-to-row-63"]) == (8, 7)
-    assert count(TILED_BATCHES["padding-tile"]) == (1, 0)
+    assert count(TILED_BATCHES["chunk-plus-decode-rows"]) == (5, 3, 3)  # the chunk: tiles 0 and 1
+    assert count(TILED_BATCHES["chunk-end-alone-in-next-tile"]) == (2, 1, 1)
+    assert count(TILED_BATCHES["seven-one-token-rows-to-row-63"]) == (8, 7, 7)
+    assert count(TILED_BATCHES["padding-tile"]) == (1, 0, 0)
+    # under a block mask of 4: sixteen block steps, every pass the few-row arm's; a chunk's
+    # passes are not, whatever they own of a tile (rows 60..63 of the second sequence's 8
+    # are one block alone in tile 0: a few-row pass)
+    assert count(BLOCK_MASK_BATCHES["a-block-at-every-multiple-of-4"], 4) == (16, 0, 16)
+    assert count(BLOCK_MASK_BATCHES["chunks-across-the-tile-boundary"], 4) == (4, 0, 2)
+    assert count(BLOCK_MASK_BATCHES["chunk-and-block-steps"], 4) == (5, 0, 3)
+    assert count(BLOCK_MASK_BATCHES["chunk-and-block-steps"]) == (5, 0, 0)
 
 
 # sha256 of str(jax.make_jaxpr(...)) (addresses blanked) of both grids at the
 # shapes below, taken from the commit BEFORE the kernel had a window argument
 # (d15f72e, jax 0.9.0): with window == 0 the traced program is that one.
 # ``prefill`` was re-recorded in PR 42, whose one-token pass changed the tiled
-# kernel's body with and without a window; ``update`` is still d15f72e's.
+# kernel's body with and without a window, and again in PR 51, whose one-token
+# arm is the arm of every pass of no more rows than one block (at ``block`` 0 the
+# one-token passes still: the same arithmetic, the selection and the placing
+# written for a block's rows) and every pass starts its walk's first chunk under
+# its insert (the same copies, started earlier); ``update`` is still d15f72e's.
 _PRE_WINDOW_JAXPR = {
     "update": "eac6774739b3692404a729d6558959bb2d91e2e90a4447e8f1ae91da79a697f7",
-    "prefill": "945c9830c7b821503ea79685fd39e36518e322a26b34d3b93fa12c1f3871424a",
+    "prefill": "3fae050ac42b357558d0b4d4f23c839657184bc27b52405a311c7a058ff9a067",
 }
 
 
@@ -561,6 +626,8 @@ def test_engine_mixed_put_kernel_vs_gather_path(decode_rows):
     assert args[True]["attention"] == "paged_tiled"
     assert args[True]["tiled_passes"] == (decode_rows + 2) * layers
     assert args[True]["tiled_one_token_passes"] == decode_rows * layers
+    # no block mask: the few-row arm is the one-token passes'
+    assert args[True]["tiled_few_row_passes"] == args[True]["tiled_one_token_passes"]
     assert "tiled_passes" not in args[False] and "tiled_one_token_passes" not in args[False]
 
 
@@ -595,11 +662,13 @@ def test_batch_counts_are_the_tiled_grids_alone():
     tiled, gather = model(True), model(False)
     for seqs, T in list(TILED_BATCHES.values()) + [([(5, 250)] + [(9 * i, 1) for i in range(6)],
                                                     256)]:
-        passes, one_token = _passes_by_the_kernels_rule(*batch(seqs, T)["seq_meta"][:, 1:3].T, T)
-        assert one_token <= passes and passes > 0
+        passes, one_token, few = _passes_by_the_kernels_rule(
+            *batch(seqs, T)["seq_meta"][:, 1:3].T, T)
+        assert few == one_token <= passes and passes > 0
         assert tiled.batch_counts(batch(seqs, T)) == {
             "tiled_passes": passes * tiled.num_layers,
-            "tiled_one_token_passes": one_token * tiled.num_layers}
+            "tiled_one_token_passes": one_token * tiled.num_layers,
+            "tiled_few_row_passes": one_token * tiled.num_layers}
         assert gather.batch_counts(batch(seqs, T)) == {}
     # a decode bucket is the per-token grid's: nothing to count, a chunk of steps neither
     decode = batch([(7, 1), (30, 1)], 8)

@@ -71,6 +71,12 @@ def _device_bytes(compiled):
         + m.temp_size_in_bytes
 
 
+def _kernel_calls(text, name):
+    """The ``tpu_custom_call``s of a compiled program's text that run the kernel
+    called ``name``."""
+    return [line for line in text.splitlines() if "tpu_custom_call" in line and name in line]
+
+
 # serve-phase geometry: Mixtral-8x7B attention, the engine's default 64-token blocks
 H, KVH, D, BS = 32, 8, 128, 64
 
@@ -100,9 +106,9 @@ def test_paged_attention_update_compiles(v5e, sizes, tokens, max_blocks):
         on((tokens, KVH, D), jnp.bfloat16),
         on((serve.layers, 2, serve.kv_blocks, KVH, BS, D), jnp.bfloat16),
         on((seqs, max_blocks), jnp.int32), *meta).compile()
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    assert ("paged_attention_prefill" if tiled else "paged_attention_update") in text
+    # ONE kernel a layer, whatever arms and phases it holds inside
+    assert len(_kernel_calls(compiled.as_text(), "paged_attention_prefill" if tiled
+                             else "paged_attention_update")) == 1
 
 
 def test_flash_forward_and_backward_compile(v5e, sizes):
@@ -267,9 +273,9 @@ def test_paged_attention_with_a_window_compiles(v5e, tokens):
         on((tokens, KVH, D), jnp.bfloat16),
         on((2, 2, 256, KVH, BS, D), jnp.bfloat16),
         on((8, WINDOW_MAX_BLOCKS), jnp.int32), *meta).compile()
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    assert ("paged_attention_prefill" if tiled else "paged_attention_update") in text
+    # ONE kernel a layer, whatever arms and phases it holds inside
+    assert len(_kernel_calls(compiled.as_text(), "paged_attention_prefill" if tiled
+                             else "paged_attention_update")) == 1
 
 
 @pytest.fixture(scope="module")
@@ -715,8 +721,7 @@ def test_nemotron_decode_loop_program_fits_one_chip(v5e, nemotron_model):
     text = compiled.as_text()
     assert "paged_attention_update" in text and "grouped_matmul" in text
     assert "ssm/step" in text and "ssm/scan" not in text
-    kernels = [line for line in text.splitlines()
-               if "tpu_custom_call" in line and "ssm_step_in_place" in line]
+    kernels = _kernel_calls(text, "ssm_step_in_place")
     assert len(kernels) == 6 and all("ssm/step" in line for line in kernels), kernels
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert not _state_sized_results(text, rows=8)
@@ -813,8 +818,7 @@ def test_falcon_h1_decode_loop_program_fits_one_chip(v5e, falcon_h1_model):
     compiled = jax.jit(loop, donate_argnums=(1, )).lower(params, cache, batch).compile()
     text = compiled.as_text()
     assert "paged_attention_update" in text and "ssm/scan" not in text
-    kernels = [line for line in text.splitlines()
-               if "tpu_custom_call" in line and "ssm_step_in_place" in line]
+    kernels = _kernel_calls(text, "ssm_step_in_place")
     assert len(kernels) == FALCON_LAYERS and all("ssm/step" in line for line in kernels), kernels
     assert _device_bytes(compiled) < 0.95 * HBM_BYTES
     assert not _falcon_state_sized_results(text, rows=8)
@@ -826,22 +830,27 @@ def test_falcon_h1_decode_loop_program_fits_one_chip(v5e, falcon_h1_model):
 SDAR_LAYERS, SDAR_POOL_BLOCKS, SDAR_MAX_BLOCKS, SDAR_SEQS = 7, 2304, 32, 32
 
 
-def test_the_tile_grid_under_a_block_mask_compiles(v5e):
+@pytest.mark.parametrize("tokens,block", [(128, 4), (256, 4), (128, 16)],
+                         ids=["block-step-of-32-sequences", "prompt-chunk-bucket",
+                              "a-block-of-16"])
+def test_the_tile_grid_under_a_block_mask_compiles(v5e, tokens, block):
     """``paged_attention_prefill`` with ``block`` 4 at SDAR's heads (32 queries
-    over 4 K/V heads of 128: eight a K/V head); the token grid refuses the
-    same mask by name before anything is lowered."""
+    over 4 K/V heads of 128: eight a K/V head, so a few-row pass walks 32 rows
+    a K/V head) at the cell's two token buckets — 128 rows are a block step of
+    32 sequences, every pass the few-row arm's — and with a block of 16 (128
+    rows a K/V head, placed by one select); ONE kernel still. The token grid
+    refuses the same mask by name before anything is lowered."""
     from deepspeed_tpu.ops.pallas import paged_attention
     on = functools.partial(_on, SingleDeviceSharding(v5e[0]))
 
     def step(q, k, v, cache, *meta):
-        return paged_attention.paged_attention_prefill(q, k, v, cache, 1, *meta, block=4)
+        return paged_attention.paged_attention_prefill(q, k, v, cache, 1, *meta, block=block)
 
     compiled = jax.jit(step, donate_argnums=(3, )).lower(
-        on((128, 32, 128), jnp.bfloat16), on((128, 4, 128), jnp.bfloat16),
-        on((128, 4, 128), jnp.bfloat16), on((2, 2, 256, 4, BS, 128), jnp.bfloat16),
+        on((tokens, 32, 128), jnp.bfloat16), on((tokens, 4, 128), jnp.bfloat16),
+        on((tokens, 4, 128), jnp.bfloat16), on((2, 2, 256, 4, BS, 128), jnp.bfloat16),
         on((SDAR_SEQS, SDAR_MAX_BLOCKS), jnp.int32), *(on((SDAR_SEQS, ), jnp.int32), ) * 3).compile()
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text and "paged_attention_prefill" in text
+    assert len(_kernel_calls(compiled.as_text(), "paged_attention_prefill")) == 1
     with pytest.raises(ValueError, match="per-token grid.*block mask"):
         paged_attention.paged_attention_update(
             jnp.zeros((8, 32, 128)), jnp.zeros((8, 4, 128)), jnp.zeros((8, 4, 128)),
