@@ -699,10 +699,9 @@ class InferenceEngineV2:
         the call returns: the KV blocks of all ``n_blocks * B`` positions,
         ``seen_tokens`` (+ ``n_blocks * B``). The ``block_loop`` span is the
         launch: ``seqs``, ``blocks`` and ``forwards`` (both a sequence: a
-        block is ``denoising_steps + 1`` forwards of B rows), ``rows``
-        (forwards x B), ``masked_rows`` and ``tokens`` (the positions that
-        take a token in the chunk; :meth:`BlockChunk.note` corrects ``tokens``
-        to what the caller kept), ``steps`` (the program's forwards of the
+        block is ``denoising_steps + 1`` forwards of B rows), ``tokens`` (the
+        positions that take a token in the chunk; :meth:`BlockChunk.note`
+        corrects it to what the caller kept), ``steps`` (the program's forwards of the
         whole batch), ``launch_us``; ``fetch_us`` and a grouped bucket's
         ``moe_banks`` are the fetch's to write."""
         batch_uids = list(batch_uids)
@@ -717,8 +716,7 @@ class InferenceEngineV2:
         args, _ = self._dispatch(spans, batch_uids, feeds, None, steps=forwards)
         if args is not None:
             taking = int(masked.sum()) + n * (n_blocks - 1) * B
-            args.update(seqs=n, blocks=n * n_blocks, forwards=n * forwards,
-                        rows=n * forwards * B, masked_rows=taking, tokens=taking)
+            args.update(seqs=n, blocks=n * n_blocks, forwards=n * forwards, tokens=taking)
         slots = np.zeros(self._batch.device_batch["tok_meta"].shape[1], np.int32)
         slots[:masked.size] = masked
         with _tel_live_span(spans, "block_loop", "inference", args):

@@ -162,32 +162,41 @@ class AdmissionRejected(RuntimeError):
         self.retry_after_s = retry_after_s
 
 
-# consecutive idle polls one ``no_work`` span covers at most: an idle server
-# writes ~50 spans a second into the ring, not one per millisecond poll, and a
+# consecutive idle polls one waiting span covers at most: an idle server writes
+# ~50 spans a second into the ring, not one per millisecond poll, and a
 # profiler slice that starts inside a long idle stretch still sees the next one
 _NO_WORK_SPAN_POLLS = 20
 
 
 class _IdleSpan:
-    """The scheduler loop's ``no_work`` span (cat ``sched``): open from the
-    first poll that found nothing to run until work arrives (or
+    """The scheduler loop's waiting span (cat ``sched``), named for what is
+    waited for: ``no_work`` while nothing is queued or active, ``starved``
+    (args ``active``, ``queued``, ``free_blocks``) while requests are and
+    ``step()`` could run no batch for them. Open from the first poll that made
+    no progress until the state changes, work arrives (or
     ``_NO_WORK_SPAN_POLLS`` polls passed), covering the heartbeats and
-    ``time.sleep(scheduler_tick_s)`` between. Nothing while telemetry is off."""
+    ``time.sleep(scheduler_tick_s)`` between; a ``starved`` one ends before the
+    next ``step()``, whose ``tick`` span is its sibling. Nothing while telemetry
+    is off."""
 
     def __init__(self):
         self._ctx = None
+        self.name = None
         self.polls = 0
 
     @property
     def open(self) -> bool:
         return self._ctx is not None
 
-    def poll(self, spans) -> None:
+    def poll(self, spans, name="no_work", args=None) -> None:
+        if self._ctx is not None and self.name != name:
+            self.close()
         if self._ctx is None:
             if spans is None:
                 return
-            self._ctx = spans.span("no_work", "sched")
+            self._ctx = spans.span(name, "sched", args)
             self._ctx.__enter__()
+            self.name = name
             self.polls = 0
         self.polls += 1
 
@@ -1065,7 +1074,8 @@ class ServingScheduler:
         step in flight; 0: it did not wait). Each
         is also a ``dstpu.sched.*`` annotation on this thread's line of a
         jax.profiler trace. An idle poll (nothing queued, nothing active)
-        records nothing; :meth:`_run` covers it with ``no_work``."""
+        records nothing; :meth:`_run` covers it with ``no_work``, and the pause
+        after a tick that had work and ran no batch with ``starved``."""
         spans = self._spans
         if spans is not None and not self._has_work():
             spans = None
@@ -1458,7 +1468,7 @@ class ServingScheduler:
         spans.record("peer_prefix_fetch", cat="serving", ts_us=_t0,
                      dur_us=now_us() - _t0, trace_id=req.trace_id,
                      parent_id=req.root_span_id,
-                     args={"uid": req.uid, "have_blocks": have, "imported": ok})
+                     args={"uid": req.uid, "imported": ok})
         return ok
 
     def _import_peer_prefix_inner(self, req: Request, have: int) -> bool:
@@ -2494,18 +2504,16 @@ class ServingScheduler:
         """Everything a tick does after the fetch (span ``emit``): billing, the
         per-request phase spans, pushing tokens, finalizing. ``args``:
         ``device_draws`` (tokens whose ids this tick took from the device),
-        ``host_draws`` and ``sample_us`` (tokens a speculative step drew from
-        rows it holds on the host, and the time inside those
-        :meth:`_draw_rows` calls; both 0 on the ``put`` path), ``pushed``
-        (tokens streamed), ``finished`` (requests)."""
+        ``sample_us`` (the time inside the :meth:`_draw_rows` calls of a
+        speculative step, which draws from rows it holds on the host; 0 on the
+        ``put`` path), ``pushed`` (tokens streamed), ``finished`` (requests)."""
         if spans is None:
             return NULL_SPAN
         return self._emit_phase_live(spans)
 
     @contextmanager
     def _emit_phase_live(self, spans):
-        emit = self._emit = {"sample_us": 0.0, "device_draws": 0, "host_draws": 0,
-                             "pushed": 0, "finished": 0}
+        emit = self._emit = {"sample_us": 0.0, "device_draws": 0, "pushed": 0, "finished": 0}
         args = {}
         try:
             with spans.span("emit", "sched", args):
@@ -2543,7 +2551,7 @@ class ServingScheduler:
         """``device_draws``: tokens whose ids came off the device;
         ``host_draws``: tokens drawn from rows fetched to the host first."""
         self._counters[kind] += n
-        if self._emit is not None:
+        if self._emit is not None and kind in self._emit:  # the span carries the device's
             self._emit[kind] += n
 
     def _push_drawn(self, req: Request, tok: int) -> None:
@@ -2941,7 +2949,13 @@ class ServingScheduler:
                     logger.exception("serving scheduler: step() raised")
                     progressed = False
                 if not progressed:
-                    idle.poll(self._spans)
+                    spans = self._spans
+                    if spans is not None and self._has_work():
+                        idle.poll(spans, "starved", {
+                            "active": len(self._active), "queued": len(self._queue),
+                            "free_blocks": int(self._engine.free_blocks)})
+                    else:
+                        idle.poll(spans)
                     self._maybe_heartbeat()
                     time.sleep(self._config.scheduler_tick_s)
         finally:

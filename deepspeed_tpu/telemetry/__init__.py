@@ -21,6 +21,7 @@ Usage::
 import threading
 
 from deepspeed_tpu.telemetry import compile_watch as compile_watch
+from deepspeed_tpu.telemetry import runtime_watch as runtime_watch
 from deepspeed_tpu.telemetry.collector import TraceCollector
 from deepspeed_tpu.telemetry.config import (FlightRecorderConfig, SLOConfig,
                                             SLOObjectiveConfig, TelemetryConfig,
@@ -47,7 +48,8 @@ __all__ = [
     "get_timeseries", "get_slo_engine",
     "is_active", "record_comm_op", "wrap_timers", "start_http_server", "scrape_metrics",
     "parse_prometheus_text", "state", "now_us", "new_trace_id", "new_span_id",
-    "trace_context", "current_trace", "compile_watch", "live_span", "NULL_SPAN",
+    "trace_context", "current_trace", "compile_watch", "runtime_watch", "live_span",
+    "NULL_SPAN",
 ]
 
 # comm-op latencies live well under the default buckets' top decades; bytes
@@ -164,6 +166,9 @@ class TelemetrySession:
             if config.slo.enabled:
                 self.slo = SLOEngine(config.slo, self.timeseries, self.registry)
             self.timeseries.start()
+        # collections and host stalls as spans: no option, part of a session; last,
+        # so that a constructor that raised above leaves no thread behind
+        self.runtime_watch = runtime_watch.install(self.registry, self.spans)
         state.spans = self.spans
         state.flight_recorder = self.flight_recorder
         state.timeseries = self.timeseries
@@ -199,6 +204,7 @@ class TelemetrySession:
         if self.compile_watch is not None:
             compile_watch.uninstall(self.compile_watch)
             self.compile_watch = None
+        runtime_watch.uninstall(self.runtime_watch)
         if state.session is self:
             self.registry.close_jsonl()
             state.active = False

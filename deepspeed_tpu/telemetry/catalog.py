@@ -129,6 +129,10 @@ METRIC_FAMILIES = {
     # flight recorder (telemetry/flight_recorder.py)
     "flight_recorder_dumps_total": "flight-recorder dumps written, by trigger",
     "serving_stalled_total": "watchdog detections of a stalled scheduler loop",
+    # runtime watch (telemetry/runtime_watch.py)
+    "runtime_gc_pause_seconds": "garbage-collection pauses of generation 2 or >= 1 ms, by generation",
+    "runtime_host_late_seconds": "how late the runtime watch's thread woke, where later than "
+                                 "the stall threshold",
     # fleet layer (fleet/metrics.py)
     "fleet_replicas": "live (non-DOWN) replicas registered with the manager",
     "fleet_queue_depth": "fleet-wide queued requests at the last probe sweep",

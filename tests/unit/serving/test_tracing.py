@@ -411,6 +411,88 @@ def test_idle_polls_record_nothing_but_no_work(make_engine):
     assert len([s for s in spans if s["name"] == "admit"]) == len(ticks) + 1
 
 
+# PERF.md section 3's audit (PR 52): what each span of the serving path writes, on a
+# dense model. Every key has a reader: a benchmark metric, a README table, a finding
+# or a test of its own; a key that is not here was read by nothing and went
+# (``masked_rows`` / ``rows`` of ``block_loop``, ``host_draws`` of ``emit``,
+# ``have_blocks`` of ``peer_prefix_fetch``). A model with experts, a state or an
+# index adds its ``moe_*`` / ``ssm_*`` / ``index_*`` / ``tiled_*`` counts to the dispatch.
+AUDITED_ARGS = {
+    ("sched", "tick"): {"tick", "seqs", "tokens", "kind", "pipelined", "drain", "open",
+                        "open_behind", "lead_us", "predicted_us"},
+    ("sched", "commit_wait"): set(),
+    ("sched", "admit"): {"admitted"},
+    ("sched", "build_batch"): {"evicted"},
+    ("sched", "fetch"): {"bytes"},
+    ("sched", "emit"): {"sample_us", "device_draws", "pushed", "finished"},
+    ("serving", "queued"): {"uid"},
+    ("serving", "prefill"): {"uid", "tick", "tokens"},
+    ("serving", "decode"): {"uid", "tick", "tokens"},
+    ("serving", "request"): {"uid", "state", "finish_reason", "prompt_tokens", "cached_tokens",
+                             "generated", "resumed"},
+    ("inference", "prepare"): {"sequences", "tokens", "released_blocks", "live_blocks_full",
+                               "live_blocks_window", "allocated_blocks"},
+    ("inference", "put"): {"sequences", "uids", "seqs_live", "seq_bucket", "tokens", "attention",
+                           "chained"},
+    ("inference", "decode_loop"): {"sequences", "uids", "seqs_live", "seq_bucket", "steps",
+                                   "launch_us", "fetch_us", "chained"},
+}
+
+
+def test_every_span_of_the_serving_path_writes_the_audited_args_and_no_other(make_engine):
+    spans = _serve_inline(make_engine,
+                          lambda s: [s.submit([1, 2, 3], max_new_tokens=13),
+                                     s.submit([4, 5, 6, 7], max_new_tokens=2)], decode_chunk=4,
+                          prepare=_simulated_clock)
+    written = {}
+    for s in spans:
+        if s["cat"] in ("sched", "serving", "inference"):
+            written.setdefault((s["cat"], s["name"]), set()).update(s.get("args") or {})
+    assert set(written) == set(AUDITED_ARGS)
+    for span, keys in written.items():
+        assert keys <= AUDITED_ARGS[span], (span, keys - AUDITED_ARGS[span])
+    # what a step of every kind always says
+    for span in (("sched", "tick"), ("sched", "emit"), ("inference", "prepare"),
+                 ("serving", "request")):
+        assert written[span] == AUDITED_ARGS[span], span
+
+
+def test_active_requests_and_an_exhausted_pool_wait_under_starved_not_no_work(make_engine,
+                                                                              monkeypatch):
+    """The loop's waiting span says what is waited for: with a request active
+    and no KV block free for its next token the pauses are ``starved`` (with
+    the counts), and ``no_work`` only while nothing is queued or active."""
+    import time
+
+    from deepspeed_tpu.serving import scheduler as scheduler_module
+    monkeypatch.setattr(scheduler_module, "_STARVATION_FAIL_TICKS", 40)
+    telemetry.configure(telemetry.TelemetryConfig(enabled=True))
+    sched = ServingScheduler(make_engine(num_blocks=3), ServingConfig(scheduler_tick_s=0.001))
+    time.sleep(0.03)
+    # 40 tokens take the pool's three blocks of 16; the 49th has nowhere to go
+    req = sched.submit(list(range(1, 41)), max_new_tokens=20)
+    while req.stream.get(timeout=60) is not None:
+        pass
+    assert req.state is RequestState.FAILED and "starved" in req.error
+    time.sleep(0.03)
+    sched.stop()
+    spans = [s for s in _sched_spans() if s["cat"] == "sched"]
+    starved = [s for s in spans if s["name"] == "starved"]
+    idle = [s for s in spans if s["name"] == "no_work"]
+    ticks = [s for s in spans if s["name"] == "tick"]
+    assert 30 <= len(starved) <= 40 and idle
+    assert all(s["args"] == {"active": 1, "queued": 0, "free_blocks": 0} for s in starved)
+    first, last = starved[0]["ts_us"], starved[-1]["ts_us"] + starved[-1]["dur_us"]
+    # no ``no_work`` while the request was starved, and no waiting span over a tick
+    assert all(s["ts_us"] + s["dur_us"] <= first or s["ts_us"] >= last for s in idle)
+    for s in starved + idle:
+        assert all(s["ts_us"] + s["dur_us"] <= t["ts_us"] or t["ts_us"] + t["dur_us"] <= s["ts_us"]
+                   for t in ticks)
+    # the tick between two starved pauses had work and ran no batch
+    assert [t["args"]["kind"] for t in ticks if first < t["ts_us"] < last] == \
+        ["none"] * (len(starved) - 1)
+
+
 def test_profiler_trace_shows_the_scheduler_threads_phases(make_engine, tmp_path):
     """ISSUE acceptance: a ``jax.profiler`` trace of a serving run with
     telemetry on carries ``dstpu.sched.*`` and ``dstpu.inference.*`` events on

@@ -52,11 +52,13 @@ def test_sigusr1_triggers_a_dump_and_close_restores_handler(tmp_path):
     session = _session(tmp_path, signal_enabled=True)
     os.kill(os.getpid(), signal.SIGUSR1)
     # the handler hands the dump to a worker thread (inline dumping could
-    # deadlock on the recorder lock) — poll briefly
+    # deadlock on the recorder lock) — poll briefly, for the file: the worker
+    # makes the directory first and moves the finished dump into it last
     deadline = time.monotonic() + 5.0
-    while time.monotonic() < deadline and not os.path.exists(tmp_path / "flight"):
+    flight, dumps = tmp_path / "flight", []
+    while time.monotonic() < deadline and not dumps:
         time.sleep(0.01)
-    dumps = os.listdir(tmp_path / "flight")
+        dumps = [n for n in os.listdir(flight) if n.endswith(".json")] if flight.exists() else []
     assert len(dumps) == 1 and "sigusr1" in dumps[0]
     session.close()
     assert signal.getsignal(signal.SIGUSR1) == prev
