@@ -8,9 +8,13 @@ engine, the mixer over a step's rows, and the step's counters.
   head_dim, state]`` and the last ``conv_kernel - 1`` rows of its convolution's
   input. Two pools ``[Mamba-2 mixers, slots, ...]`` ride beside the K/V array
   in the one cache pytree (``ragged/kv_cache.py``); a sequence's slot is a
-  column of ``seq_meta``. A slot's content counts from the sequence's first
-  token: a sequence with nothing seen reads zeros whatever the slot held.
-  Padding rows point one past the last slot and their writes drop;
+  column of ``seq_meta``. Both slots are stated in whole (sublane, lane) tiles
+  where the widths allow (the state as it is; the tails folded,
+  ``ssm.conv_slot``), so that a step moves its own rows by a kernel over the
+  pool where it lies and the compiler adds no pass over the pool. A slot's
+  content counts from the sequence's first token: a sequence with nothing seen
+  reads zeros whatever the slot held. Padding rows point one past the last
+  slot and their writes drop;
 - **two forms of the scan** (``modules/ssm.py``), both IN the pool: a ``put``
   step scans by segment (``ssm.scan_in_place``), each sequence's rows
   starting from its slot's state and leaving its final state there — the
@@ -89,7 +93,7 @@ class Mamba2Model(DSTransformerModelBase):
                                   shape=(w.heads, w.head_dim, w.state)),
                 SequenceStateSpec(name="conv", layers=w.mixers,
                                   dtype=np.dtype(self._config.dtype).name,
-                                  shape=(w.conv_kernel - 1, w.conv_dim)))
+                                  shape=ssm.conv_slot(w.conv_kernel - 1, w.conv_dim)))
 
     def batch_counts(self, ragged_batch, steps=None):
         """Beside the attention kernels' passes: ``ssm_tokens``, rows that went
@@ -105,10 +109,14 @@ class Mamba2Model(DSTransformerModelBase):
         scanned in their slot by their own rows alone (``ssm.scan_in_place`` on a
         pool on ``ssm.in_place``'s rule): all of them, or 0 where the step falls
         back to ``ssm.scan_ragged`` on every state between the slot copies;
-        where the caller gives ``steps`` (the engine does, a chunk's or 1 for a
-        ``put``, whose entry nothing reads) ``ssm_rows_in_place``, those of a
-        ``decode_loop`` chunk's ``ssm_tokens`` whose state the kernel updated in
-        its slot: all of them, or 0 where the pool is off its shape rule."""
+        ``ssm_conv_rows_in_place``, those of the step's ``ssm_segments`` whose
+        convolution tails a kernel loaded from and left in their slots: all of
+        them, or 0 where the conv pool's slot is off ``ssm.whole_slots``'s rule
+        (XLA's gather and scatter); where the caller gives ``steps`` (the engine
+        does, a chunk's or 1 for a ``put``, whose entry nothing reads)
+        ``ssm_rows_in_place``, those of a ``decode_loop`` chunk's ``ssm_tokens``
+        whose state the kernel updated in its slot: all of them, or 0 where the
+        pool is off its shape rule."""
         chunk, steps = steps is not None, steps or 1
         counts = super().batch_counts(ragged_batch, steps)
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
@@ -122,13 +130,16 @@ class Mamba2Model(DSTransformerModelBase):
         in_place = ssm.in_place(kv.cache[1], w.groups)
         counts["ssm_segments_in_place"] = counts["ssm_segments"] if stored else 0
         counts["ssm_segments_scanned_in_place"] = counts["ssm_segments"] if in_place else 0
+        counts["ssm_conv_rows_in_place"] = \
+            counts["ssm_segments"] if ssm.whole_slots(kv.cache[2]) else 0
         if chunk:
             counts["ssm_rows_in_place"] = counts["ssm_tokens"] if in_place else 0
         return counts
 
     def _in_the_pool(self, update, pool, mi, *rows):
         """``update(pool, mi, *rows)``: ``ssm.step_in_place`` or
-        ``ssm.scan_in_place`` on mixer ``mi`` of the pool. The SPMD
+        ``ssm.scan_in_place`` on mixer ``mi`` of the state pool, ``ssm.load`` or
+        ``ssm.store_in_place`` on the conv pool's. The SPMD
         partitioner cannot split a Mosaic kernel: on a mesh every device runs
         it over the pool it holds whole (``kv_cache._pool_sharding``), as
         ``_paged_attention`` runs its kernel."""
@@ -142,13 +153,13 @@ class Mamba2Model(DSTransformerModelBase):
     @jax.named_scope("ssm")
     def _mamba_phase(self, mp, mi, h, pools, batch):
         """Mamba-2 mixer ``mi`` (its ordinal) over the step's rows ``h`` [T, M];
-        ``pools`` = (ssm [mixers, slots, H, P, N], conv [mixers, slots, K - 1,
-        C]). Returns the mixer's output and the pools with the step's states."""
+        ``pools`` = (ssm [mixers, slots, H, P, N], conv [mixers, slots,
+        *``ssm.conv_slot``]). Returns the mixer's output and the pools with the
+        step's states."""
         w = self.mamba2
         T = h.shape[0]
         H, P, G, N, D = w.heads, w.head_dim, w.groups, w.state, w.d_inner
         ssm_pool, conv_pool = pools
-        n_slots = ssm_pool.shape[1]
         with jax.named_scope("in_proj"):
             if w.in_scale != 1.0:
                 h = h * jnp.asarray(w.in_scale, h.dtype)
@@ -169,11 +180,12 @@ class Mamba2Model(DSTransformerModelBase):
             live = batch["token_valid"]
         else:  # put: a sequence without tokens in the step keeps its state
             live = batch["seq_valid"] & (batch["seq_ntok"] > 0)
-        write = jnp.where(live, slot, n_slots)
-        read = jnp.minimum(slot, n_slots - 1)
 
         with jax.named_scope("conv"):
-            tail = jnp.where(started[:, None, None], conv_pool[mi, read], 0)
+            # the step's own tails out of their slots and back (modules/ssm.py: one
+            # kernel a direction where a slot is whole tiles, as conv_slot states it)
+            tail = ssm.unfold_tails(self._in_the_pool(ssm.load, conv_pool, mi, slot, started),
+                                    w.conv_kernel - 1, w.conv_dim)
             wt, b = mp["conv1d"]["kernel"], mp["conv1d"]["bias"]
             if one_token:
                 xbc, tail = ssm.conv_step(xbc, wt, b, tail)
@@ -181,7 +193,8 @@ class Mamba2Model(DSTransformerModelBase):
                 xbc, tail = ssm.conv_ragged(xbc, wt, b, tail, batch["token_seq"],
                                             batch["last_tok"] - batch["seq_ntok"] + 1,
                                             batch["seq_ntok"])
-            conv_pool = conv_pool.at[mi, write].set(tail, mode="drop")
+            conv_pool = self._in_the_pool(ssm.store_in_place, conv_pool, mi, slot, live,
+                                          ssm.fold_tails(tail, conv_pool.shape[2:]))
             xbc = jax.nn.silu(xbc).astype(h.dtype)
             x, B, C = jnp.split(xbc, [D, D + G * N], axis=-1)
             x, B, C = x.reshape(T, H, P), B.reshape(T, G, N), C.reshape(T, G, N)

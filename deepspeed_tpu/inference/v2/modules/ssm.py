@@ -52,6 +52,10 @@ from its slot and left there — one Pallas kernel
 :func:`conv_ragged` / :func:`conv_step`: the causal depthwise convolution in
 front of the scan, a token seeing the ``K - 1`` rows before it of ITS OWN
 sequence, those ahead of a segment's first row from the sequence's kept tail.
+The pool keeps a sequence's tails FOLDED into whole tiles (:func:`conv_slot`,
+:func:`fold_tails` / :func:`unfold_tails`), so that :func:`load` and
+:func:`store_in_place` move a step's own tails by their kernel as they move a
+float32 state.
 """
 
 import jax
@@ -101,6 +105,35 @@ def conv_step(xbc, weight, bias, tail):
     out = jnp.einsum("skc,ck->sc", window, weight.astype(jnp.float32)) \
         + bias.astype(jnp.float32)[None, :]
     return out, window[:, 1:].astype(tail.dtype)
+
+
+def conv_slot(rows: int, channels: int):
+    """The slot a pool keeps a sequence's ``rows`` = K - 1 tails of ``channels``
+    channels in. Where they fill a tile: their ``rows x channels`` values in
+    order, folded to whole (sublane, lane) tiles ``[8, 128 k]`` with zeros behind
+    them where the widths do not divide (``ssm_store.supported``'s rule; a pool
+    of ``[3, C]`` rows the chip's compiler re-lays in tiles of four around a
+    chunk's steps and carries whole through vector memory twice a step: PERF.md
+    section 6, PR 53). Fewer values than one tile stay ``[rows, channels]``: the
+    padding would multiply them."""
+    tile = ssm_store.SUBLANES * ssm_store.LANES
+    if rows * channels < tile:
+        return rows, channels
+    return ssm_store.SUBLANES, -(-rows * channels // tile) * ssm_store.LANES
+
+
+def fold_tails(tail, slot):
+    """``tail`` [S, K - 1, C] as rows of the pool's ``slot`` shape
+    (:func:`conv_slot`): the same values in the same order, zeros behind."""
+    flat = tail.reshape(tail.shape[0], -1)
+    flat = jnp.pad(flat, ((0, 0), (0, slot[0] * slot[1] - flat.shape[1])))
+    return flat.reshape((-1, ) + tuple(slot))
+
+
+def unfold_tails(rows, k_less_one: int, channels: int):
+    """:func:`fold_tails` back: ``rows`` [S, *slot] as ``[S, K - 1, C]``."""
+    flat = rows.reshape(rows.shape[0], -1)[:, :k_less_one * channels]
+    return flat.reshape(-1, k_less_one, channels)
 
 
 # -------------------------------------------------------------------- scan --
@@ -279,7 +312,9 @@ def load(pool, block, slot, started):
     where ``started[i]`` and zeros where not, whatever the slot held (one past
     the last included). One Pallas kernel over the pool itself
     (``ops/pallas/ssm_store.py``) where :func:`whole_slots`: XLA's gather of
-    rows above 2 MiB first slices the whole pool."""
+    rows above 2 MiB first slices the whole pool, and around a gather of the
+    convolution's three-row tails it re-lays the pool (PERF.md section 6, PR
+    53)."""
     if whole_slots(pool):
         rows = ssm_store.ssm_load(pool, block, slot, started)
     else:

@@ -16,6 +16,13 @@ live rows hold distinct slots, so their copies never meet. A copy of whole
 slots needs only that a slot is whole tiles (:func:`supported`), whatever its
 rank and dtype. The block's ordinal is an operand, so a program's blocks share
 one traced and lowered kernel a direction.
+
+Since PR 53 the same two kernels move the convolution's tails of every step,
+``put`` and ``decode_loop`` (``modules/ssm.py:conv_slot``: a sequence's ``K -
+1`` rows folded into one bf16 slot ``[8, 128 k]``). That pool is small enough
+for the chip's vector memory (28 MiB), and XLA's memory-space assignment
+then carries it there and back around the kernels, a pass over the pool a
+block: the pool is held to HBM on both sides of the call.
 """
 
 import functools
@@ -78,13 +85,19 @@ def _call(store, pool, block, slot, live, states, interpret):
         interpret = jax.default_backend() != "tpu"
     in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
     out = pool if store else states
+    out_shape = jax.ShapeDtypeStruct(out.shape, out.dtype)
+    if not interpret:
+        # the pool stays in HBM on both sides of the call (the module's last paragraph)
+        pool = pltpu.with_memory_space_constraint(pool, pltpu.HBM)
+        if store:
+            out_shape = pltpu.HBM(out.shape, out.dtype)
     return pl.pallas_call(
         functools.partial(_kernel, store),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(1, ),
             in_specs=[in_hbm, in_hbm] if store else [in_hbm], out_specs=in_hbm,
             scratch_shapes=[pltpu.SemaphoreType.DMA((IN_FLIGHT, ))]),
-        out_shape=jax.ShapeDtypeStruct(out.shape, out.dtype),
+        out_shape=out_shape,
         input_output_aliases={4: 0} if store else {},  # the pool, after 3 scalars and the states
         interpret=interpret,
         name="ssm_store_in_place" if store else "ssm_load",

@@ -187,8 +187,14 @@ def test_prefill_in_uneven_chunks_then_decode_is_the_references_full_forward(req
     assert engine.model.num_kv_layers == 1 and engine.model.min_table_bucket == 16
     kv, ssm_pool, conv_pool = engine._state_manager.kv_cache.cache
     assert kv.shape[0] == 1 and ssm_pool.shape == (3, 6, 8, 8, cfg.ssm_state_size) \
-        and ssm_pool.dtype == jnp.float32 and conv_pool.shape == (3, 6, 3, cfg.conv_dim)
+        and ssm_pool.dtype == jnp.float32
     assert cfg.conv_dim == 64 + 4 * cfg.ssm_state_size
+    # the tails' slot as ``ssm.conv_slot`` states it: 3 x 128 values are no tile and
+    # stay [3, C] (XLA's gather and scatter); 3 x 576 fold into [8, 256] with 320
+    # zeros behind them (the slot-copy kernels, interpret mode, under the mesh)
+    slot = {"model": (3, 128), "model_in_place": (8, 256)}[which]
+    assert conv_pool.shape == (3, 6) + slot == (3, 6) + ssm.conv_slot(3, cfg.conv_dim)
+    assert ssm.whole_slots(conv_pool) == (which == "model_in_place")
     prompt, feed = _ids(1, 75), _ids(2, 6)
     want = _want(cfg, params, prompt, feed)
     got, at = [], 0
@@ -277,7 +283,10 @@ def test_a_chunks_counts_say_which_rows_the_kernel_served(request, which, share)
     counts: every row where the pool is on the kernel's rule, 0 where it falls
     back; a ``put``'s counts do not have the key. ``ssm_segments_in_place``
     beside ``ssm_segments`` on a ``put``'s: every segment where a slot of the
-    pool is whole tiles (``ssm.whole_slots``), 0 where XLA scatters it."""
+    pool is whole tiles (``ssm.whole_slots``), 0 where XLA scatters it.
+    ``ssm_conv_rows_in_place`` (PR 53) on both: every segment where the CONV
+    pool's slot is whole tiles (the tails folded: ``ssm.conv_slot``), 0 where
+    XLA gathers and scatters them."""
     cfg, params = request.getfixturevalue(which)
     engine = engine_of(cfg, params)
     engine.put([0, 1], [_ids(40, 9), _ids(41, 5)])
@@ -285,9 +294,12 @@ def test_a_chunks_counts_say_which_rows_the_kernel_served(request, which, share)
     assert put["ssm_tokens"] == 14 * 3 and "ssm_rows_in_place" not in put
     assert ssm.whole_slots(engine._state_manager.kv_cache.cache[1]) == bool(share)
     assert put["ssm_segments"] == 2 * 3 and put["ssm_segments_in_place"] == share * 2 * 3
+    assert ssm.whole_slots(engine._state_manager.kv_cache.cache[2]) == bool(share)
+    assert put["ssm_conv_rows_in_place"] == share * 2 * 3
     engine.decode_loop([0, 1], [_ids(42, 1), _ids(43, 1)], 4)
     chunk = engine.model.batch_counts(engine._batch, 4)
     assert chunk["ssm_tokens"] == 2 * 3 * 4 and chunk["ssm_segments"] == 2 * 3 * 4
+    assert chunk["ssm_conv_rows_in_place"] == share * chunk["ssm_segments"]
     assert chunk["ssm_rows_in_place"] == share * chunk["ssm_tokens"]
     assert engine.model.batch_counts(engine._batch, 1)["ssm_rows_in_place"] == share * 2 * 3
 
@@ -315,13 +327,49 @@ def test_a_puts_counts_say_which_segments_were_scanned_in_their_slot(request, wh
     assert put["ssm_segments_scanned_in_place"] == share * put["ssm_segments"] == share * 3
 
 
+def test_the_folded_tails_are_the_three_rows_bit_for_bit(model_in_place, monkeypatch):
+    """The same steps through an engine whose conv pool holds the tails folded
+    into whole tiles (``ssm.conv_slot``: the slot-copy kernels) and through one
+    told to keep ``[K - 1, C]`` (XLA's gather and scatter): every logit, every
+    token, the state pool and the tails themselves come out bit for bit — a
+    sequence without rows in a step, a padding row, a new sequence in a slot
+    another one left, and the slots nobody held included."""
+    cfg, params = model_in_place
+
+    def run():
+        engine = engine_of(cfg, params, slots=4)
+        outs = [engine.put([0, 1], [_ids(50, 9), _ids(51, 5)]),
+                engine.put([1, 2], [_ids(52, 1), _ids(53, 12)])]  # 0 has no row: keeps its tails
+        engine.flush(1)
+        outs.append(engine.put([3, 0], [_ids(54, 2), _ids(55, 1)]))  # 3 in 1's slot: from zeros
+        outs.append(engine.decode_loop([0, 2, 3], [_ids(56, 1), _ids(57, 1), _ids(58, 1)], 3))
+        pools = [np.asarray(p) for p in engine._state_manager.kv_cache.cache[1:]]
+        engine.close()
+        return [np.asarray(o) for o in outs], pools
+
+    outs, (state, tails) = run()
+    monkeypatch.setattr(ssm, "conv_slot", lambda rows, channels: (rows, channels))
+    plain_outs, (plain_state, plain_tails) = run()
+    assert tails.shape == (3, 4, 8, 256) and plain_tails.shape == (3, 4, 3, cfg.conv_dim)
+    for got, want in zip(outs, plain_outs):
+        np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(state, plain_state)
+    np.testing.assert_array_equal(
+        tails.reshape(12, 8, 256),
+        np.asarray(ssm.fold_tails(jnp.asarray(plain_tails.reshape(12, 3, -1)), (8, 256))))
+    assert np.abs(tails).max() > 0
+
+
 def test_the_decode_loop_program_is_the_one_it_was_before_the_scan_went_by_segment(model_in_place):
     """PR 49 changed what a ``put`` step's Mamba-2 mixers run and nothing a
     ``decode_loop`` chunk runs: its traced program (addresses blanked) hashes
-    to what it did at the commit before."""
+    to what it did at the commit before. Re-pinned by PR 53, which changed it
+    on purpose: the convolution's tails leave and enter their folded slots by
+    ``ssm.load`` / ``ssm.store_in_place`` (every other family's recorded
+    programs, ``test_one_group_programs.py`` and ``test_afmoe.py``, hold)."""
     cfg, params = model_in_place
     assert decode_loop_hash(engine_of(cfg, params).model) == \
-        "75fb52b6454aa5793028d601d672b95daf8df83bb963f34efbf4e87b6d65e638"
+        "55540c46817ba802dddfeec485bf026e41f061464470f23fa5cc010bf7ad3769"
 
 
 def test_admission_stops_at_the_last_free_slot(model):

@@ -15,15 +15,19 @@ from deepspeed_tpu.ops.pallas import ssm_step, ssm_store
 BLOCKS, SLOTS = 3, 8
 # a slot's shape and dtype: Mamba-2 states on the step kernel's rule; a bf16 pool
 # (the step kernel reads float32 alone); a slot of another rank; a head a unit of
-# the step kernel's that no tile holds (136 x 1024 float32 > 512 KiB)
+# the step kernel's that no tile holds (136 x 1024 float32 > 512 KiB); the
+# convolution's bf16 tails of Nemotron-3-Nano's and Falcon-H1-34B's published
+# widths, folded (``ssm.conv_slot``, PR 53)
 ON_RULE = [((4, 8, 128), jnp.float32), ((2, 16, 256), jnp.bfloat16), ((8, 256), jnp.float32),
-           ((1, 136, 1024), jnp.float32)]
+           ((1, 136, 1024), jnp.float32), ((8, 2304), jnp.bfloat16), ((8, 1920), jnp.bfloat16)]
 ON_RULE_IDS = ["f32-heads-of-8x128", "bf16-heads-of-16x256", "no-head-axis",
-               "off-the-step-kernels-tile"]
+               "off-the-step-kernels-tile", "bf16-conv-tails-nemotron", "bf16-conv-tails-falcon-h1"]
 # off the copies' rule (and ``ssm_step.supported``'s): a head's rows are no whole
 # sublane tile, its columns no whole lane tile
-OFF_RULE = [((4, 5, 128), jnp.float32), ((4, 8, 16), jnp.float32), ((3, 5, 16), jnp.bfloat16)]
-OFF_RULE_IDS = ["five-rows-a-head", "sixteen-columns", "bf16-off-both"]
+OFF_RULE = [((4, 5, 128), jnp.float32), ((4, 8, 16), jnp.float32), ((3, 5, 16), jnp.bfloat16),
+            ((3, 6144), jnp.bfloat16)]
+OFF_RULE_IDS = ["five-rows-a-head", "sixteen-columns", "bf16-off-both",
+                "bf16-conv-tails-as-three-rows"]
 
 
 def _case(slot_shape, dtype, slot, live, seed=0):
@@ -135,3 +139,29 @@ def test_a_pool_off_the_rule_is_refused_by_the_kernels_themselves():
         ssm_store.ssm_store_in_place(pool, 0, slot, live, states)
     with pytest.raises(AssertionError):
         ssm_store.ssm_load(pool, 0, slot, live)
+
+
+@pytest.mark.parametrize("rows, channels, slot", [
+    (3, 6144, (8, 2304)), (3, 5120, (8, 1920)), (3, 576, (8, 256)), (3, 128, (3, 128)),
+    (3, 112, (3, 112))],
+    ids=["nemotron-3-nano", "falcon-h1-34b", "padded-behind", "tiny-nemotron", "tiny-falcon-h1"])
+def test_the_conv_tails_slot_is_whole_tiles_where_the_tails_fill_one(rows, channels, slot):
+    """``ssm.conv_slot``: the published widths of both hybrid families fold
+    into whole (8, 128) tiles without padding and are on the kernels' rule; the
+    tiny test configurations' tails are no tile and stay ``[K - 1, C]``, off it
+    (so both paths stay covered); ``[K - 1, C]`` itself is never on the rule.
+    The fold keeps the values in their order, zeros behind, and comes back bit
+    for bit."""
+    assert ssm.conv_slot(rows, channels) == slot
+    folds = slot != (rows, channels)
+    assert ssm_store.supported((6, 128) + slot) == folds == (rows * channels >= 8 * 128)
+    assert not ssm_store.supported((6, 128, rows, channels))
+    tail = jnp.asarray(np.random.default_rng(3).standard_normal((5, rows, channels)), jnp.bfloat16)
+    folded = ssm.fold_tails(tail, slot)
+    assert folded.shape == (5, ) + slot and folded.dtype == tail.dtype
+    flat = np.asarray(folded.astype(jnp.float32)).reshape(5, -1)
+    np.testing.assert_array_equal(flat[:, :rows * channels],
+                                  np.asarray(tail.astype(jnp.float32)).reshape(5, -1))
+    assert not flat[:, rows * channels:].any()
+    assert flat.shape[1] - rows * channels == {(3, 576): 320}.get((rows, channels), 0)
+    _same(ssm.unfold_tails(folded, rows, channels), tail)

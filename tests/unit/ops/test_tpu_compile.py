@@ -565,6 +565,7 @@ def test_deepseek_decode_loop_program_fits_one_chip(v5e, deepseek_model):
 
 # ---- nemotron3-nano-serve-1chip: a per-sequence state group beside K/V (PR 43) ----
 NEMOTRON_SLOTS, NEMOTRON_BLOCKS, NEMOTRON_BLOCK = 128, 1536, 128
+NEMOTRON_TAILS = (8, 2304)  # the 3 x 6144 convolution tails a sequence, folded (ssm.conv_slot)
 
 
 @pytest.fixture(scope="module")
@@ -590,7 +591,7 @@ def nemotron_model():
     model = model_cls_for(cfg)(abstract, cfg, engine_config)
     assert model.num_kv_layers == 2 and model.min_table_bucket == 32
     assert [(s.name, s.layers, s.shape, s.dtype) for s in model.sequence_state] == [
-        ("ssm", 6, (64, 64, 128), "float32"), ("conv", 6, (3, 6144), "bfloat16")]
+        ("ssm", 6, (64, 64, 128), "float32"), ("conv", 6, NEMOTRON_TAILS, "bfloat16")]
     return model, abstract
 
 
@@ -600,7 +601,7 @@ def _nemotron_args(device, model, abstract, bucket):
     params = jax.tree.map(lambda leaf: _on(one, leaf.shape, leaf.dtype), abstract)
     cache = (_on(one, (2, 2, NEMOTRON_BLOCKS, 2, NEMOTRON_BLOCK, 128), jnp.bfloat16),
              _on(one, (6, NEMOTRON_SLOTS, 64, 64, 128), jnp.float32),
-             _on(one, (6, NEMOTRON_SLOTS, 3, 6144), jnp.bfloat16))
+             _on(one, (6, NEMOTRON_SLOTS) + NEMOTRON_TAILS, jnp.bfloat16))
     batch = {"tok_meta": _on(one, (4, tokens), jnp.int32),
              "seq_meta": _on(one, (seqs, 4 + max_blocks + 1), jnp.int32)}
     return one, params, cache, batch
@@ -660,6 +661,43 @@ def _pool_shaped_results(text, pool):
     return out
 
 
+def _conv_pool_results(text, pool):
+    """Instructions of a compiled program whose result (or a member of a tuple
+    result) has the CONV pool's shape ``pool`` in bfloat16, in any layout and
+    memory space, and that are not the pool passing through (parameters, tuples
+    and their elements, bitcasts, a loop that carries it) or the slot-copy
+    kernel's result that IS its operand (``ssm_store_in_place``, aliased). Until
+    PR 53 the pool was ``[mixers, slots, 3, C]``: XLA re-laid it around every
+    chunk, carried it into vector memory and back twice a step
+    (``fusion.*.remat_(un)compressed``: 6.2 % of Nemotron's device time) and
+    scattered into it a block (PERF.md section 6, PR 53)."""
+    import re
+    dims = ",".join(str(d) for d in pool)
+    out = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z\-]*)\(", line)
+        if not m or m.group(2) in ("parameter", "tuple", "get-tuple-element", "bitcast", "while"):
+            continue
+        if f"bf16[{dims}]" not in m.group(1):
+            continue
+        if m.group(2) == "custom-call" and "output_to_operand_aliasing" in line \
+                and "ssm_store_in_place" in line:
+            continue
+        out.append(line.strip()[:200])
+    return out
+
+
+def _tails_by_the_kernels(text, mixers):
+    """One ``ssm_load`` and one ``ssm_store_in_place`` a mixer, under
+    ``ssm/conv`` (the convolution's scope: nothing of it under ``ssm/step`` or
+    ``ssm/scan``, whose rooflines count the recurrence's and the scan's work)."""
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    loads = [line for line in calls if "/ssm_load/pallas_call" in line]
+    stores = [line for line in calls if "/ssm_store_in_place/pallas_call" in line]
+    return len(loads) == len(stores) == mixers \
+        and all("/ssm/conv/ssm_" in line for line in loads + stores)
+
+
 def _step_states(text, seqs, heads, head, state, groups):
     """Float32 results shaped like the step's states, ``[seqs, heads, head,
     state]`` or the scan's ``[seqs, groups, heads / groups, head, state]``,
@@ -674,13 +712,16 @@ def _step_states(text, seqs, heads, head, state, groups):
 def _scans_in_the_pool(text, mixers):
     """The ``put`` program's scan by segment: one ``ssm_step_in_place`` a mixer
     (the one-row segments) and one loop of visits a mixer, under ``ssm/scan``
-    (the scope the scan's roofline readers sum), and neither slot-copy kernel."""
+    (the scope the scan's roofline readers sum), and neither slot-copy kernel
+    under that scope (since PR 53 they move the convolution's tails, under
+    ``ssm/conv``: ``_tails_by_the_kernels``)."""
     kernels = [line for line in text.splitlines()
                if "tpu_custom_call" in line and "ssm/scan/" in line and "ssm_step_in_place" in line]
     loops = [line for line in text.splitlines()
              if " while(" in line and 'op_name="jit(_forward_impl)/ssm/scan/while"' in line]
-    return len(kernels) == mixers and len(loops) == mixers \
-        and "ssm_load" not in text and "ssm_store_in_place" not in text
+    copies = [line for line in text.splitlines() if "tpu_custom_call" in line
+              and ("/ssm/scan/ssm_load/" in line or "/ssm/scan/ssm_store_in_place/" in line)]
+    return len(kernels) == mixers and len(loops) == mixers and not copies
 
 
 @pytest.mark.parametrize("bucket,kernel,scope", [
@@ -705,6 +746,8 @@ def test_nemotron_put_program_fits_one_chip(v5e, nemotron_model, bucket, kernel,
     assert _scans_in_the_pool(text, mixers=6)
     assert not _pool_shaped_results(text, (6, NEMOTRON_SLOTS, 64, 64))
     assert not _step_states(text, 8, 64, 64, 128, 8)
+    assert _tails_by_the_kernels(text, mixers=6)
+    assert not _conv_pool_results(text, (6, NEMOTRON_SLOTS) + NEMOTRON_TAILS)
     out = jax.eval_shape(model._forward_impl, params, cache, batch)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
 
@@ -713,7 +756,11 @@ def test_nemotron_decode_loop_program_fits_one_chip(v5e, nemotron_model):
     """The recurrence inside ``decode_loop``'s scan: the pools ride in the
     carry and come out in the shapes and dtypes they went in. Since PR 44 a
     block's recurrence is ONE kernel over the pool itself, under ``ssm/step``:
-    no row's state exists outside the pool (the gather was 8 of them)."""
+    no row's state exists outside the pool (the gather was 8 of them). Since PR
+    53 the convolution's tails leave and enter their slots by the slot-copy
+    kernels: no operation of the chunk makes an array of the conv pool's shape
+    but the kernels' aliased pool (it was re-laid around the chunk and carried
+    through vector memory twice a step)."""
     model, abstract = nemotron_model
     one, params, cache, batch = _nemotron_args(v5e[0], model, abstract, (8, 8, 32))
     loop = functools.partial(model._decode_loop_impl, n_steps=8)
@@ -725,12 +772,15 @@ def test_nemotron_decode_loop_program_fits_one_chip(v5e, nemotron_model):
     assert len(kernels) == 6 and all("ssm/step" in line for line in kernels), kernels
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert not _state_sized_results(text, rows=8)
+    assert _tails_by_the_kernels(text, mixers=6)
+    assert not _conv_pool_results(text, (6, NEMOTRON_SLOTS) + NEMOTRON_TAILS)
     out = jax.eval_shape(loop, params, cache, batch)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
 
 
 # ---- falcon-h1-34b-serve-1chip: K/V AND a per-sequence state in every layer (PR 47) ----
 FALCON_LAYERS, FALCON_SLOTS, FALCON_BLOCKS, FALCON_BLOCK, FALCON_SEQS = 6, 64, 704, 128, 32
+FALCON_TAILS = (8, 1920)  # the 3 x 5120 convolution tails a sequence, folded (ssm.conv_slot)
 
 
 @pytest.fixture(scope="module")
@@ -754,7 +804,7 @@ def falcon_h1_model():
     assert model.min_sequence_bucket == FALCON_SEQS
     assert [(s.name, s.layers, s.shape, s.dtype) for s in model.sequence_state] == [
         ("ssm", FALCON_LAYERS, (32, 128, 256), "float32"),
-        ("conv", FALCON_LAYERS, (3, 5120), "bfloat16")]
+        ("conv", FALCON_LAYERS, FALCON_TAILS, "bfloat16")]
     return model, abstract
 
 
@@ -764,7 +814,7 @@ def _falcon_h1_args(device, abstract, bucket):
     params = jax.tree.map(lambda leaf: _on(one, leaf.shape, leaf.dtype), abstract)
     cache = (_on(one, (FALCON_LAYERS, 2, FALCON_BLOCKS, 4, FALCON_BLOCK, 128), jnp.bfloat16),
              _on(one, (FALCON_LAYERS, FALCON_SLOTS, 32, 128, 256), jnp.float32),
-             _on(one, (FALCON_LAYERS, FALCON_SLOTS, 3, 5120), jnp.bfloat16))
+             _on(one, (FALCON_LAYERS, FALCON_SLOTS) + FALCON_TAILS, jnp.bfloat16))
     batch = {"tok_meta": _on(one, (4, tokens), jnp.int32),
              "seq_meta": _on(one, (seqs, 4 + max_blocks + 1), jnp.int32)}
     return params, cache, batch
@@ -803,6 +853,8 @@ def test_falcon_h1_put_program_fits_one_chip(v5e, falcon_h1_model, bucket, kerne
     assert _scans_in_the_pool(text, mixers=FALCON_LAYERS)
     assert not _pool_shaped_results(text, (FALCON_LAYERS, FALCON_SLOTS, 32, 128))
     assert not _step_states(text, FALCON_SEQS, 32, 128, 256, 2)
+    assert _tails_by_the_kernels(text, mixers=FALCON_LAYERS)
+    assert not _conv_pool_results(text, (FALCON_LAYERS, FALCON_SLOTS) + FALCON_TAILS)
     out = jax.eval_shape(model._forward_impl, params, cache, batch)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
 
@@ -822,6 +874,8 @@ def test_falcon_h1_decode_loop_program_fits_one_chip(v5e, falcon_h1_model):
     assert len(kernels) == FALCON_LAYERS and all("ssm/step" in line for line in kernels), kernels
     assert _device_bytes(compiled) < 0.95 * HBM_BYTES
     assert not _falcon_state_sized_results(text, rows=8)
+    assert _tails_by_the_kernels(text, mixers=FALCON_LAYERS)
+    assert not _conv_pool_results(text, (FALCON_LAYERS, FALCON_SLOTS) + FALCON_TAILS)
     out = jax.eval_shape(loop, params, cache, batch)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
 
