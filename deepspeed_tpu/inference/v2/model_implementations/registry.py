@@ -64,6 +64,12 @@ def _register_sdar_moe():
     register_policy("sdar_moe", SdarMoeConfig, SdarMoeV2Model)
 
 
+def _register_solar_open2():
+    from deepspeed_tpu.models.solar_open2 import SolarOpen2Config
+    from deepspeed_tpu.inference.v2.model_implementations.solar_open2_v2 import SolarOpen2V2Model
+    register_policy("solar_open2", SolarOpen2Config, SolarOpen2V2Model)
+
+
 def _register_builtin():
     from deepspeed_tpu.models.afmoe import AfmoeConfig
     from deepspeed_tpu.models.decoder import DecoderConfig
@@ -105,6 +111,11 @@ def _register_builtin():
     # block mask, a decode step that rewrites a block of rows and commits its
     # K/V once, several tokens a sequence a step — on softmax top-k experts
     _ON_FIRST_USE["sdar_moe"] = _register_sdar_moe
+    # serving only, as one chip's share: gated delta-rule linear attention in
+    # three layers of four (a matrix state a head in the per-sequence state
+    # group, decayed by channel), gated position-free GQA in the fourth, SwiGLU
+    # experts beside a shared one in every layer
+    _ON_FIRST_USE["solar_open2"] = _register_solar_open2
     register_policy("opt", DecoderConfig, DecoderV2Model)
     register_policy("falcon", DecoderConfig, DecoderV2Model)
     register_policy("phi", DecoderConfig, DecoderV2Model)

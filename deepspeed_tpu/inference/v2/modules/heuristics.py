@@ -36,6 +36,7 @@ def attention_implementation(model, engine_config, bucket_tokens: int) -> str:
       double-buffered K/V chunks and, on the tile grid, the tile's state.
     """
     from deepspeed_tpu.ops.pallas.paged_attention import (CHUNK, TOKEN_GRID_MAX,
+                                                          VMEM_CEILING_BYTES,
                                                           tile_grid_vmem_bytes)
     flag = getattr(engine_config, "use_paged_kernel", None)
     kernel = ("paged_token" if bucket_tokens <= TOKEN_GRID_MAX
@@ -50,8 +51,9 @@ def attention_implementation(model, engine_config, bucket_tokens: int) -> str:
     held = scratch_bytes if kernel == "paged_token" else tile_grid_vmem_bytes(
         model.num_heads, model.num_kv_heads, model.head_dim, bs,
         block=getattr(model, "attention_block", 0))
-    # of the ~16MB a kernel may use: half for the chunks, three quarters in all
-    if scratch_bytes > 8 * 1024 * 1024 or held > 12 * 1024 * 1024:
+    # half of the ~16MB a kernel is granted unasked for the chunks; in all, three
+    # quarters of the most the kernel asks for (paged_attention.vmem_params)
+    if scratch_bytes > 8 * 1024 * 1024 or held > VMEM_CEILING_BYTES * 3 // 4:
         logger.warning(f"paged kernel K/V scratch {scratch_bytes >> 20}MB ({held >> 20}MB held "
                        f"in all) exceeds VMEM budget (kv_block_size={bs}); using the XLA "
                        f"gather path")

@@ -113,6 +113,24 @@ def tile_grid_vmem_bytes(H, KVH, D, bs, itemsize=2, block=0):
             + 2 * 2 * (tile + KVH * TQ * D) * itemsize)
 
 
+# VMEM the compiler grants a kernel that asks for nothing, and the most a kernel
+# here asks for (a v5e core has 128 MiB)
+SCOPED_VMEM_BYTES = 16 * 2**20
+VMEM_CEILING_BYTES = 48 * 2**20
+
+
+def vmem_params(held: int) -> dict:
+    """``pallas_call``'s keywords for a kernel that holds ``held`` bytes of
+    VMEM: none while a quarter of the compiler's own scoped limit is to spare
+    (every configuration up to 32 query heads of 128: their programs lower as
+    they always did), else a limit of its own with a quarter to spare (64 query
+    heads over 8 K/V heads of 128 hold 20.7 MiB on the tile grid)."""
+    if held <= SCOPED_VMEM_BYTES * 3 // 4:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=min(held * 5 // 4, VMEM_CEILING_BYTES))}
+
+
 def tiled_passes(seq_ntok, last_tok, bucket_tokens, block=0):
     """The (sequence, tile) pairs a query-tiled call works through, those that
     own ONE token of their tile, and those that take the few-row arm (no more
@@ -679,6 +697,7 @@ def paged_attention_prefill(q, k_new, v_new, cache, layer_idx, block_table, seq_
         input_output_aliases={8: 1},  # cache operand (after 5 scalar-prefetch args)
         interpret=interpret,
         name="paged_attention_prefill",
+        **vmem_params(tile_grid_vmem_bytes(H, KVH, D, bs, jnp.dtype(op_dtype).itemsize, block)),
     )(jnp.asarray(layer_idx, jnp.int32).reshape(1), block_table.astype(jnp.int32),
       seq_seen.astype(jnp.int32), seq_ntok.astype(jnp.int32), last_tok.astype(jnp.int32),
       q.astype(op_dtype).reshape(T, H * D), k_new.astype(cache.dtype).reshape(T, KVH * D),

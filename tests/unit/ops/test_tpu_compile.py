@@ -632,7 +632,7 @@ def _pool_shaped_results(text, pool):
     float32 — the pool, or a piece of it whatever its last dimension — and that
     are not the pool passing through: parameters, tuples and their elements,
     bitcasts, a loop that carries it, a kernel's result that IS its operand
-    (aliased: ``ssm_step_in_place``, ``ssm_store_in_place``) and the update of
+    (aliased: ``ssm_step_in_place``, ``ssm_store_in_place``, ``kda_step_in_place``) and the update of
     one slot in place (a ``dynamic-update-slice``, alone or the root of its
     fusion). XLA cuts a gather of rows above 2 MiB by first slicing its operand:
     a pass over the whole pool a mixer that PR 47's check, which skipped
@@ -652,7 +652,7 @@ def _pool_shaped_results(text, pool):
         if not any(shape[:len(pool)] == tuple(pool) and len(shape) == len(pool) + 1 for shape in shapes):
             continue
         if m.group(2) == "custom-call" and "output_to_operand_aliasing" in line \
-                and re.search(r"ssm_(store|step)_in_place", line):
+                and re.search(r"(ssm_(store|step)|kda_step)_in_place", line):
             continue
         called = re.search(r"calls=%(\S+?)[,\s}]", line)
         if m.group(2) == "fusion" and called and roots.get(called.group(1)) == "dynamic-update-slice":
@@ -687,15 +687,16 @@ def _conv_pool_results(text, pool):
     return out
 
 
-def _tails_by_the_kernels(text, mixers):
+def _tails_by_the_kernels(text, mixers, scope="ssm/conv"):
     """One ``ssm_load`` and one ``ssm_store_in_place`` a mixer, under
     ``ssm/conv`` (the convolution's scope: nothing of it under ``ssm/step`` or
-    ``ssm/scan``, whose rooflines count the recurrence's and the scan's work)."""
+    ``ssm/scan``, whose rooflines count the recurrence's and the scan's work),
+    or under the ``scope`` another family's convolution has."""
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     loads = [line for line in calls if "/ssm_load/pallas_call" in line]
     stores = [line for line in calls if "/ssm_store_in_place/pallas_call" in line]
     return len(loads) == len(stores) == mixers \
-        and all("/ssm/conv/ssm_" in line for line in loads + stores)
+        and all(f"/{scope}/ssm_" in line for line in loads + stores)
 
 
 def _step_states(text, seqs, heads, head, state, groups):
@@ -976,3 +977,119 @@ def test_sdar_block_loop_program_fits_one_chip(v5e, sdar_model):
         ((32, 8), jnp.int32, (32, 8), jnp.int8)
     assert _device_bytes(compiled) < 0.85 * HBM_BYTES
     assert not _pool_sized_results(text, cache.shape)
+
+
+# ---- solar-open2-250b-serve-1chip: a delta-rule state of 4 MiB a sequence a layer (PR 54) ----
+SOLAR_SLOTS, SOLAR_BLOCKS, SOLAR_BLOCK, SOLAR_TABLE = 128, 4096, 128, 64
+SOLAR_TAILS = (8, 9216)  # q, k and v's 3 x 24576 convolution tails a sequence, folded
+
+
+@pytest.fixture(scope="module")
+def solar_model():
+    """``solar-open2-250b-serve-1chip``: Solar-Open2-250B's published widths, one
+    period of four layers (GQA, KDA, KDA, KDA), 40 of the 320 routed experts
+    held, an eighth of the vocabulary, contexts to 8192, over
+    ``jax.eval_shape``d parameters."""
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.model_implementations.registry import model_cls_for
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import DSStateManagerConfig
+    from deepspeed_tpu.models import solar_open2
+    cfg = solar_open2.SolarOpen2Config(num_hidden_layers=4, vocab_size=24576, experts_held=40,
+                                       expert_rank=0)
+    abstract = jax.eval_shape(lambda: solar_open2.init_params(cfg, param_dtype=cfg.dtype)[1])
+    engine_config = RaggedInferenceEngineConfig(
+        state_manager=DSStateManagerConfig(max_context=8192, max_ragged_batch_size=256,
+                                           max_ragged_sequence_count=8,
+                                           max_tracked_sequences=SOLAR_SLOTS),
+        kv_block_size=SOLAR_BLOCK, use_paged_kernel=True,
+        expert_parallel={"capacity_factor": 40.0})
+    model = model_cls_for(cfg)(abstract, cfg, engine_config)
+    assert model.num_kv_layers == 1 and model.min_table_bucket == SOLAR_TABLE
+    assert [(s.name, s.layers, s.shape, s.dtype) for s in model.sequence_state] == [
+        ("kda", 3, (64, 128, 128), "float32"), ("conv", 3, SOLAR_TAILS, "bfloat16")]
+    return model, abstract
+
+
+def _solar_args(device, abstract, tokens):
+    one = SingleDeviceSharding(device)
+    params = jax.tree.map(lambda leaf: _on(one, leaf.shape, leaf.dtype), abstract)
+    cache = (_on(one, (1, 2, SOLAR_BLOCKS, 8, SOLAR_BLOCK, 128), jnp.bfloat16),
+             _on(one, (3, SOLAR_SLOTS, 64, 128, 128), jnp.float32),
+             _on(one, (3, SOLAR_SLOTS) + SOLAR_TAILS, jnp.bfloat16))
+    batch = {"tok_meta": _on(one, (4, tokens), jnp.int32),
+             "seq_meta": _on(one, (8, 4 + SOLAR_TABLE + 1), jnp.int32)}
+    return params, cache, batch
+
+
+def _solar_states(text):
+    """Float32 results that hold MORE than one delta-rule state (``[..., 64,
+    128, 128]`` with two or more ahead of it) and are no pool: both forms keep
+    a state a sequence in its slot, and the one in hand is ``[1, 1, ...]``."""
+    import re
+    out = []
+    for line in text.splitlines():
+        for dims in re.findall(r"= \(?f32\[([\d,]+),64,128,128\]", line):
+            ahead = [int(d) for d in dims.split(",")]
+            if ahead != [3, SOLAR_SLOTS] and int(np.prod(ahead)) > 1:
+                out.append(line.strip()[:160])
+    return out
+
+
+def _solar_no_pass_over_a_pool(text):
+    """No result shaped like the state pool or a piece of it but the kernel's
+    aliased pool and a slot's update in place (``_pool_shaped_results``), none
+    shaped like the conv pool but the slot-copy kernel's aliased pool, and the
+    tails moved by the two kernels under ``kda/conv``."""
+    return not _pool_shaped_results(text, (3, SOLAR_SLOTS, 64, 128)) \
+        and not _conv_pool_results(text, (3, SOLAR_SLOTS) + SOLAR_TAILS) \
+        and _tails_by_the_kernels(text, mixers=3, scope="kda/conv")
+
+
+def test_solar_put_program_fits_one_chip(v5e, solar_model):
+    """The chunk bucket's ``put`` program: 6.2 GiB of weights beside 2 GiB of
+    K/V and 1.6 GiB of state in 128 slots of 3 x 4 MiB. The tile grid of the
+    paged kernel at eight query heads a K/V head, the grouped matmul over the
+    40 held SwiGLU banks, and the scan by segment under ``kda/scan``: one
+    ``kda_step_in_place`` a delta-rule layer (the one-row segments) and one
+    loop of visits a layer through the chunked form, a state visited IN its
+    slot (nothing 2 x a state, let alone 256)."""
+    model, abstract = solar_model
+    assert model.moe_path(256) == "grouped"
+    params, cache, batch = _solar_args(v5e[0], abstract, 256)
+    compiled = jax.jit(model._forward_impl, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert "paged_attention_prefill" in text and "grouped_matmul" in text
+    assert "kda/scan" in text and "kda/step" not in text and "attn/gate" in text
+    kernels = _kernel_calls(text, "kda_step_in_place")
+    assert len(kernels) == 3 and all("kda/scan" in line for line in kernels), kernels
+    loops = [line for line in text.splitlines()
+             if " while(" in line and 'op_name="jit(_forward_impl)/kda/scan/while"' in line]
+    assert len(loops) == 3
+    assert _device_bytes(compiled) < 0.8 * HBM_BYTES
+    assert not _solar_states(text)
+    assert _solar_no_pass_over_a_pool(text)
+    out = jax.eval_shape(model._forward_impl, params, cache, batch)
+    assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
+
+
+def test_solar_decode_loop_program_fits_one_chip(v5e, solar_model):
+    """The recurrence inside ``decode_loop``'s scan: ONE ``kda_step_in_place`` a
+    delta-rule layer over the pool itself, under ``kda/step`` (no row's 4 MiB
+    state outside the pool: a gather of rows above 2 MiB slices the whole
+    pool), the per-token paged kernel at eight queries a K/V head, and the
+    pools handed back in the types they came in."""
+    model, abstract = solar_model
+    assert model.moe_path(8) == "grouped"
+    params, cache, batch = _solar_args(v5e[0], abstract, 8)
+    loop = functools.partial(model._decode_loop_impl, n_steps=8)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert "paged_attention_update" in text and "grouped_matmul" in text
+    assert "kda/step" in text and "kda/scan" not in text
+    kernels = _kernel_calls(text, "kda_step_in_place")
+    assert len(kernels) == 3 and all("kda/step" in line for line in kernels), kernels
+    assert _device_bytes(compiled) < 0.8 * HBM_BYTES
+    assert not _solar_states(text)
+    assert _solar_no_pass_over_a_pool(text)
+    out = jax.eval_shape(loop, params, cache, batch)
+    assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
