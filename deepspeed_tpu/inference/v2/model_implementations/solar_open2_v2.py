@@ -16,8 +16,9 @@ the layers of its kind. What the architecture asks of the engine:
   content counts from the sequence's first token, padding rows point one past
   the last slot and their writes drop;
 - **two forms of the delta rule** (``modules/kda.py``), both IN the pool: a
-  ``put`` step scans by segment (``kda.scan_in_place``), a ``decode_loop`` step
-  runs the recurrence by one kernel a layer over the pool
+  ``put`` step scans by segment (``kda.scan_in_place``: the chunked form's
+  visits by one kernel a layer, ``ops/pallas/kda_chunk.py``), a ``decode_loop``
+  step runs the recurrence by one kernel a layer over the pool
   (``ops/pallas/kda_step.py``). Neither program holds a state a row, nor a
   result shaped like the pool;
 - **the K/V array holds the softmax layers only** (``num_kv_layers``), ONE
@@ -139,10 +140,12 @@ class SolarOpen2V2Model(DSTransformerModelBase):
         visits the scan made through the chunked form (a visit a chunk of
         ``kda_chunk`` rows of the batch a segment of more than one row has rows
         in, a layer; 0 for a ``decode_loop`` chunk, whose segments are one
-        row); ``kda_rows_in_place``, the rows whose state the recurrence's
-        kernel updated in its slot (the segments of one row: every row of a
-        ``decode_loop`` chunk), or 0 where the pool is off the kernel's shape
-        rule; and the state group's slots held as the step is dispatched,
+        row); ``kda_chunk_visits_in_kernel``, those of them the chunk kernel
+        made in the pool (all, or 0 where the pool or the chunk is off its
+        shape rule); ``kda_rows_in_place``, the rows whose state the
+        recurrence's kernel updated in its slot (the segments of one row: every
+        row of a ``decode_loop`` chunk), or 0 where the pool is off the kernel's
+        shape rule; and the state group's slots held as the step is dispatched,
         under the Mamba-2 families' names (``ssm_slots_live`` /
         ``ssm_slots_total``)."""
         counts = super().batch_counts(ragged_batch, steps)
@@ -154,9 +157,11 @@ class SolarOpen2V2Model(DSTransformerModelBase):
         rows = min(self._config.kda_chunk, batch["tok_meta"].shape[1])
         _, visits = kda.visits_of(seq[:, 2] - ntok + 1, ntok, valid & (ntok > 1), rows)
         one_row = int((valid & (ntok == 1)).sum()) if kda.in_place(kv.cache[1]) else 0
+        in_kernel = int(visits.sum()) if kda.chunks_in_kernel(kv.cache[1], rows) else 0
         counts.update(kda_rows=steps * int(batch["n_tokens"]) * layers,
                       kda_segments=steps * int(batch["n_seqs"]) * layers,
                       kda_chunk_visits=steps * int(visits.sum()) * layers,
+                      kda_chunk_visits_in_kernel=steps * in_kernel * layers,
                       kda_rows_in_place=steps * one_row * layers,
                       ssm_slots_live=kv.num_slots - (kv.free_slots or 0),
                       ssm_slots_total=kv.num_slots)

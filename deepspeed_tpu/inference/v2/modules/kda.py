@@ -19,8 +19,11 @@ left there by one Pallas kernel (``ops/pallas/kda_step.py``) where
 and ``ssm.store_in_place`` where not. :func:`scan_in_place`, a ``put`` step:
 the scan by SEGMENT inside the pool, as ``ssm.scan_in_place`` visits it — a
 segment of one row is the recurrence, all of them in one call of the step
-kernel; a longer one goes through the chunked form (:func:`chunk`) against ITS
-state alone, a visit a chunk of the batch it has rows in.
+kernel; a longer one goes through the chunked form against ITS state alone, a
+visit a chunk of the batch it has rows in: the step's visits in ONE call of
+the chunk kernel (``ops/pallas/kda_chunk.py``) where :func:`chunks_in_kernel`
+says the shapes allow it, a loop of :func:`chunk` visits where not.
+:func:`chunk` is the form tier-1 holds that kernel to.
 
 **The chunked form** (one sequence's C rows, ``G_t`` the sum of ``g`` from the
 chunk's start through t)::
@@ -51,11 +54,11 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.v2.modules import ssm
-from deepspeed_tpu.ops.pallas import kda_step
+from deepspeed_tpu.ops.pallas import kda_chunk, kda_step
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 _HIGH = jax.lax.Precision.HIGH
-SUB = 16  # rows a sub-chunk
+SUB = kda_chunk.SUB  # rows a sub-chunk
 NORM_EPS = 1e-6  # under the square root of a head's L2 norm
 
 
@@ -100,6 +103,13 @@ def in_place(pool) -> bool:
     slots, H, d_k, d_v]``: by its type alone, the same answer on every
     backend."""
     return pool.dtype == jnp.float32 and kda_step.supported(*pool.shape[2:])
+
+
+def chunks_in_kernel(pool, rows: int) -> bool:
+    """Whether :func:`scan_in_place` runs its visits of ``rows`` rows by the
+    chunk kernel (``ops/pallas/kda_chunk.py``) on this pool: by its type and
+    the chunk's rows alone, the same answer on every backend."""
+    return pool.dtype == jnp.float32 and kda_chunk.supported(*pool.shape[2:], rows)
 
 
 def step_in_place(pool, block, slot, live, started, q, k, v, alpha, beta):
@@ -190,34 +200,17 @@ def visits_of(seq_start, seq_ntok, longer, rows: int):
     return enters, longer * ((seq_start + seq_ntok - 1) // rows - enters + 1)
 
 
-def scan_in_place(pool, block, slot, live, started, seq_start, seq_ntok, token_seq, token_valid,
-                  q, k, v, g, beta, rows: int):
-    """A ``put`` step's scan over the pool's layer ``block``, by SEGMENT
-    (``ssm.scan_in_place``'s walk): sequence i's rows are the ``seq_ntok[i]``
-    rows from ``seq_start[i]`` of the flat batch; its state is slot
-    ``slot[i]``'s (zeros where ``started[i]`` is false, whatever the slot held)
-    and its final state is left there where ``live[i]``; a sequence that is not
-    live, or without rows, keeps its slot bit for bit. q, k, g [T, H, d_k]; v
-    [T, H, d_v]; beta [T, H]. A segment of one row is the recurrence, all of
-    them in one call of :func:`step_in_place` (row i the sequence's one row); a
-    longer one goes through :func:`chunk` against ITS state alone, a visit a
-    chunk of ``rows`` rows of the batch it has rows in, a loop over the step's
-    visits in the segments' order; no state leaves its slot but the one being
-    visited. Returns ``(o [T, H, d_v] float32, pool)``; nobody's row reads
-    zeros."""
+def _visits_in_numpy(pool, block, slot, started, enters, visits, token_seq, token_valid, q, k, v,
+                      g, beta, Q):
+    """The step's visits through :func:`chunk`, a loop in the segments' order,
+    each against ITS state alone, sliced out of its slot and written back:
+    where the pool is off the kernel's rule. Returns ``(o [T, H x d_v], pool)``."""
     T, H, dk = q.shape
     dv = v.shape[-1]
     S = slot.shape[0]
-    Q = min(rows, T)
-    assert T % Q == 0, (T, rows)
     f32 = jnp.float32
-    first = jnp.clip(seq_start, 0, T - 1)
-    one = live & (seq_ntok == 1)
-    o_one, pool = step_in_place(pool, block, slot, one, started, q[first], k[first], v[first],
-                                jnp.exp(g[first]), beta[first])
     by_chunk = [a.astype(f32).reshape((T // Q, Q) + a.shape[1:]) for a in (q, k, v, g, beta)]
     owner = jnp.where(token_valid, token_seq, -1).reshape(T // Q, Q)  # a row's segment, -1 nobody
-    enters, visits = visits_of(first, seq_ntok, live & (seq_ntok > 1), Q)
     ends = jnp.cumsum(visits)
 
     def visit(n, carry):
@@ -237,6 +230,43 @@ def scan_in_place(pool, block, slot, live, started, seq_start, seq_ntok, token_s
         return o, jax.lax.dynamic_update_slice(pool, h, where)
 
     o, pool = jax.lax.fori_loop(0, ends[-1], visit, (jnp.zeros((T // Q, Q, H * dv), f32), pool))
+    return o.reshape(T, H * dv), pool
+
+
+def scan_in_place(pool, block, slot, live, started, seq_start, seq_ntok, token_seq, token_valid,
+                  q, k, v, g, beta, rows: int):
+    """A ``put`` step's scan over the pool's layer ``block``, by SEGMENT
+    (``ssm.scan_in_place``'s walk): sequence i's rows are the ``seq_ntok[i]``
+    rows from ``seq_start[i]`` of the flat batch; its state is slot
+    ``slot[i]``'s (zeros where ``started[i]`` is false, whatever the slot held)
+    and its final state is left there where ``live[i]``; a sequence that is not
+    live, or without rows, keeps its slot bit for bit. q, k, g [T, H, d_k]; v
+    [T, H, d_v]; beta [T, H]. A segment of one row is the recurrence, all of
+    them in one call of :func:`step_in_place` (row i the sequence's one row); a
+    longer one goes through the chunked form against ITS state alone, a visit a
+    chunk of ``rows`` rows of the batch it has rows in, the step's visits in
+    the segments' order: one call of the chunk kernel over the pool in place
+    (:func:`chunks_in_kernel`), or a loop of :func:`chunk` visits; no state
+    leaves its slot but the one being visited. Returns ``(o [T, H, d_v]
+    float32, pool)``; nobody's row reads zeros."""
+    T, H, dk = q.shape
+    dv = v.shape[-1]
+    S = slot.shape[0]
+    Q = min(rows, T)
+    assert T % Q == 0, (T, rows)
+    first = jnp.clip(seq_start, 0, T - 1)
+    one = live & (seq_ntok == 1)
+    o_one, pool = step_in_place(pool, block, slot, one, started, q[first], k[first], v[first],
+                                jnp.exp(g[first]), beta[first])
+    enters, visits = visits_of(first, seq_ntok, live & (seq_ntok > 1), Q)
+    if chunks_in_kernel(pool, Q):
+        o, pool = kda_chunk.kda_chunk_in_place(pool, block, slot, started, first, seq_ntok, enters,
+                                               visits, q, k, v, g, beta, rows=Q)
+        scanned = (visits > 0)[token_seq] & token_valid  # a chunk no visit reached is not written
+        o = jnp.where(scanned[:, None], o.reshape(T, H * dv), 0.0)
+    else:
+        o, pool = _visits_in_numpy(pool, block, slot, started, enters, visits, token_seq,
+                                   token_valid, q, k, v, g, beta, Q)
     o_one = o_one.reshape(S, H * dv)[token_seq]
-    o = jnp.where((one[token_seq] & token_valid)[:, None], o_one, o.reshape(T, H * dv))
+    o = jnp.where((one[token_seq] & token_valid)[:, None], o_one, o)
     return o.reshape(T, H, dv), pool

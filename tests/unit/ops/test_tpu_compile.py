@@ -11,6 +11,7 @@ or a time — only "the compiler did not refuse".
 
 import functools
 import os
+import re
 import sys
 
 import jax
@@ -632,7 +633,8 @@ def _pool_shaped_results(text, pool):
     float32 — the pool, or a piece of it whatever its last dimension — and that
     are not the pool passing through: parameters, tuples and their elements,
     bitcasts, a loop that carries it, a kernel's result that IS its operand
-    (aliased: ``ssm_step_in_place``, ``ssm_store_in_place``, ``kda_step_in_place``) and the update of
+    (aliased: ``ssm_step_in_place``, ``ssm_store_in_place``, ``kda_step_in_place``,
+    ``kda_chunk_in_place``) and the update of
     one slot in place (a ``dynamic-update-slice``, alone or the root of its
     fusion). XLA cuts a gather of rows above 2 MiB by first slicing its operand:
     a pass over the whole pool a mixer that PR 47's check, which skipped
@@ -652,7 +654,7 @@ def _pool_shaped_results(text, pool):
         if not any(shape[:len(pool)] == tuple(pool) and len(shape) == len(pool) + 1 for shape in shapes):
             continue
         if m.group(2) == "custom-call" and "output_to_operand_aliasing" in line \
-                and re.search(r"(ssm_(store|step)|kda_step)_in_place", line):
+                and re.search(r"(ssm_(store|step)|kda_(step|chunk))_in_place", line):
             continue
         called = re.search(r"calls=%(\S+?)[,\s}]", line)
         if m.group(2) == "fusion" and called and roots.get(called.group(1)) == "dynamic-update-slice":
@@ -1050,9 +1052,12 @@ def test_solar_put_program_fits_one_chip(v5e, solar_model):
     K/V and 1.6 GiB of state in 128 slots of 3 x 4 MiB. The tile grid of the
     paged kernel at eight query heads a K/V head, the grouped matmul over the
     40 held SwiGLU banks, and the scan by segment under ``kda/scan``: one
-    ``kda_step_in_place`` a delta-rule layer (the one-row segments) and one
-    loop of visits a layer through the chunked form, a state visited IN its
-    slot (nothing 2 x a state, let alone 256)."""
+    ``kda_step_in_place`` a delta-rule layer (the one-row segments) and, since
+    PR 55, ONE ``kda_chunk_in_place`` a layer for the step's visits of the
+    chunked form, the pool aliased through both and no loop of visits left: a
+    state is visited IN its slot (nothing 2 x a state, let alone 256, and no
+    whole state outside the kernels). The compiler granting the kernel its
+    vector memory is what compiling says."""
     model, abstract = solar_model
     assert model.moe_path(256) == "grouped"
     params, cache, batch = _solar_args(v5e[0], abstract, 256)
@@ -1060,11 +1065,15 @@ def test_solar_put_program_fits_one_chip(v5e, solar_model):
     text = compiled.as_text()
     assert "paged_attention_prefill" in text and "grouped_matmul" in text
     assert "kda/scan" in text and "kda/step" not in text and "attn/gate" in text
-    kernels = _kernel_calls(text, "kda_step_in_place")
-    assert len(kernels) == 3 and all("kda/scan" in line for line in kernels), kernels
-    loops = [line for line in text.splitlines()
-             if " while(" in line and 'op_name="jit(_forward_impl)/kda/scan/while"' in line]
-    assert len(loops) == 3
+    for name in ("kda_step_in_place", "kda_chunk_in_place"):
+        # by the call's own name: the pool one kernel leaves is the other's operand
+        kernels = [line for line in _kernel_calls(text, name) if line.strip().startswith(f"%{name}")]
+        assert len(kernels) == 3 and all("kda/scan" in line for line in kernels), (name, kernels)
+        assert all("output_to_operand_aliasing" in line for line in kernels), (name, kernels)
+    assert not [line for line in text.splitlines() if " while(" in line and "kda/scan" in line]
+    whole_states = [line.strip()[:160] for line in text.splitlines()
+                    if re.search(r"= \(?f32\[(1,)*64,128,128\]", line)]
+    assert not whole_states, whole_states
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert not _solar_states(text)
     assert _solar_no_pass_over_a_pool(text)
