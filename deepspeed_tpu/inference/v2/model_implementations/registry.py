@@ -5,6 +5,11 @@ Reference: ``deepspeed/inference/v2/engine_factory.py:66-120`` — the
 / falcon / phi / qwen. Registered here by model-config class AND by the HF
 ``model_type`` string, so both ``build_engine(params, config)`` and
 ``build_hf_engine(path)`` resolve through one table.
+
+Served beside them, each registered below with a line on what it asks of the
+engine: mellum, afmoe, deepseek_v32, nemotron_h, falcon_h1, sdar_moe,
+solar_open2, kimi_linear (the first to keep latent rows and per-sequence slots
+in one cache).
 """
 
 from typing import Callable, Dict, Tuple
@@ -70,6 +75,12 @@ def _register_solar_open2():
     register_policy("solar_open2", SolarOpen2Config, SolarOpen2V2Model)
 
 
+def _register_kimi_linear():
+    from deepspeed_tpu.models.kimi_linear import KimiLinearConfig
+    from deepspeed_tpu.inference.v2.model_implementations.kimi_linear_v2 import KimiLinearV2Model
+    register_policy("kimi_linear", KimiLinearConfig, KimiLinearV2Model)
+
+
 def _register_builtin():
     from deepspeed_tpu.models.afmoe import AfmoeConfig
     from deepspeed_tpu.models.decoder import DecoderConfig
@@ -116,6 +127,11 @@ def _register_builtin():
     # group, decayed by channel), gated position-free GQA in the fourth, SwiGLU
     # experts beside a shared one in every layer
     _ON_FIRST_USE["solar_open2"] = _register_solar_open2
+    # serving only, as one chip's share: the same delta rule (beta = sigmoid alone)
+    # in three layers of four and position-free LATENT attention in the fourth, so a
+    # latent pool and a slot pool in one cache; a leading dense layer, SwiGLU experts
+    # beside a shared one after it
+    _ON_FIRST_USE["kimi_linear"] = _register_kimi_linear
     register_policy("opt", DecoderConfig, DecoderV2Model)
     register_policy("falcon", DecoderConfig, DecoderV2Model)
     register_policy("phi", DecoderConfig, DecoderV2Model)

@@ -149,6 +149,12 @@ class SolarOpen2V2Model(DSTransformerModelBase):
         under the Mamba-2 families' names (``ssm_slots_live`` /
         ``ssm_slots_total``)."""
         counts = super().batch_counts(ragged_batch, steps)
+        counts.update(self._kda_counts(ragged_batch, steps))
+        return counts
+
+    def _kda_counts(self, ragged_batch, steps):
+        """:meth:`batch_counts`' own entries; ``cache[1]`` is the state pool
+        whatever holds the rows a token keeps."""
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
         layers = len(self._config.kda_here)
         kv = self._state_manager.kv_cache
@@ -158,14 +164,13 @@ class SolarOpen2V2Model(DSTransformerModelBase):
         _, visits = kda.visits_of(seq[:, 2] - ntok + 1, ntok, valid & (ntok > 1), rows)
         one_row = int((valid & (ntok == 1)).sum()) if kda.in_place(kv.cache[1]) else 0
         in_kernel = int(visits.sum()) if kda.chunks_in_kernel(kv.cache[1], rows) else 0
-        counts.update(kda_rows=steps * int(batch["n_tokens"]) * layers,
-                      kda_segments=steps * int(batch["n_seqs"]) * layers,
-                      kda_chunk_visits=steps * int(visits.sum()) * layers,
-                      kda_chunk_visits_in_kernel=steps * in_kernel * layers,
-                      kda_rows_in_place=steps * one_row * layers,
-                      ssm_slots_live=kv.num_slots - (kv.free_slots or 0),
-                      ssm_slots_total=kv.num_slots)
-        return counts
+        return dict(kda_rows=steps * int(batch["n_tokens"]) * layers,
+                    kda_segments=steps * int(batch["n_seqs"]) * layers,
+                    kda_chunk_visits=steps * int(visits.sum()) * layers,
+                    kda_chunk_visits_in_kernel=steps * in_kernel * layers,
+                    kda_rows_in_place=steps * one_row * layers,
+                    ssm_slots_live=kv.num_slots - (kv.free_slots or 0),
+                    ssm_slots_total=kv.num_slots)
 
     # --------------------------------------------------------------- phases --
     @jax.named_scope("embed")

@@ -32,12 +32,16 @@ are ``block_size x layers x sum(widths)`` values. What moves block CONTENTS
 A per-SEQUENCE state group (``KVCacheConfig.sequence_state``, a model with
 state-space layers): layers whose state is one array a live sequence whatever
 its length. One pool a spec, ``[layers, slots, ...]``, zero-initialised, beside
-the K/V array in the cache pytree: ``cache`` is then ``(K/V array, pool, ...)``.
-A SLOT is what a block is to the K/V array: handed out by an allocator of the
-same kind whose unit is a sequence (``reserve_slot`` / ``free_slot``), at the
-sequence's first token and until its flush. The K/V array holds the layers that
-keep K/V (``cache_shape[0]``), which such a model counts apart from its blocks.
-A slot is in no block table (``CACHE_OPERATIONS``: what such a cache refuses).
+what the blocks hold in the cache pytree: ``cache`` is then ``(K/V array, pool,
+...)``, or ``((latent pool, ...), pool, ...)`` for a model whose other layers
+keep latent rows (both groups in one cache: the tuple of latent pools stands
+where the K/V array stood). A SLOT is what a block is to the K/V array: handed
+out by an allocator of the same kind whose unit is a sequence (``reserve_slot``
+/ ``free_slot``), at the sequence's first token and until its flush. The blocks
+hold the layers that keep a row a token (``cache_shape[0]``), which such a
+model counts apart from its slots' layers. A slot is in no block table
+(``CACHE_OPERATIONS``: what such a cache refuses; one that is of several kinds
+refuses by the name of each).
 """
 
 import os
@@ -181,8 +185,9 @@ class BlockedKVCache:
         self._block_bytes = block_bytes
         logger.info(f"BlockedKVCache: {num_blocks} blocks x {config.block_size} tokens "
                     f"({num_blocks * block_bytes / 1e9:.2f} GB)")
-        # a per-sequence state group: its pools ride in the cache pytree, its
-        # slots come from an allocator of the blocks' kind
+        # a per-sequence state group: its pools ride in the cache pytree behind
+        # the K/V array or the tuple of latent pools, its slots come from an
+        # allocator of the blocks' kind
         self._slots = None
         if config.sequence_state:
             if config.sequence_slots < 1:
@@ -235,7 +240,8 @@ class BlockedKVCache:
 
     def _pool_sharding(self, config: KVCacheConfig):
         """Where a per-sequence state group's pools live: whole on every chip
-        of the engine's mesh, as a latent group's pools are (every chip scans
+        of the engine's mesh, as a latent group's pools are, beside them or
+        beside the K/V array (every chip scans
         every sequence; under expert parallelism only the MoE exchanges
         tokens). Refused by name: a ``model`` axis, which splits attention by
         head and would have to split the pools by theirs; and more bytes than
@@ -284,9 +290,10 @@ class BlockedKVCache:
         """Why this cache cannot serve ``operation`` (a name of
         ``CACHE_OPERATIONS``), as the error to raise, or None where it can.
         Read off what the cache holds: a window over some group (holes in a
-        table), more tables than one a sequence, latent rows, slots. A
-        configuration's or a request's refusal is a ``ValueError``; a call's is
-        its kind's."""
+        table), more tables than one a sequence, latent rows, slots; a cache of
+        several of the operation's kinds (latent rows AND slots) is refused by
+        the name of each. A configuration's or a request's refusal is a
+        ``ValueError``; a call's is its first kind's."""
         config = self._config
         _, said, kinds = CACHE_OPERATIONS[operation]
         mine = {"window": max(config.group_windows),
@@ -294,13 +301,12 @@ class BlockedKVCache:
                 "latent": tuple(config.state_widths),
                 "slots": [spec.name for spec in config.sequence_state],
                 "blocks": config.attention_block}
-        for kind in kinds.split():
-            if mine[kind]:
-                is_a, error = _CACHE_KINDS[kind]
-                return (ValueError if operation in _ASKED else error)(
-                    f"{said or operation} cannot serve {is_a.format(mine[kind])} — recompute the "
-                    f"sequence instead")
-        return None
+        held = [kind for kind in kinds.split() if mine[kind]]
+        if not held:
+            return None
+        is_a = " and ".join(_CACHE_KINDS[kind][0].format(mine[kind]) for kind in held)
+        return (ValueError if operation in _ASKED else _CACHE_KINDS[held[0]][1])(
+            f"{said or operation} cannot serve {is_a} — recompute the sequence instead")
 
     def refuse(self, operation: str) -> None:
         """Raise :meth:`refusal`'s answer, where it has one."""

@@ -1102,3 +1102,112 @@ def test_solar_decode_loop_program_fits_one_chip(v5e, solar_model):
     assert _solar_no_pass_over_a_pool(text)
     out = jax.eval_shape(loop, params, cache, batch)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
+
+
+# ---- kimi-linear-48b-a3b-serve-1chip: a latent pool and a slot pool in ONE cache (PR 56) ----
+KIMI_SLOTS, KIMI_BLOCKS, KIMI_BLOCK, KIMI_TABLE = 64, 8192, 128, 256
+KIMI_TAILS = (8, 4608)  # q, k and v's 3 x 12288 convolution tails a sequence, folded
+
+
+@pytest.fixture(scope="module")
+def kimi_model():
+    """``kimi-linear-48b-a3b-serve-1chip``: Kimi-Linear-48B-A3B's published
+    widths, two periods of four layers (KDA over a dense SwiGLU, KDA, KDA, MLA,
+    KDA, KDA, KDA, MLA), 64 of the 256 routed experts held, a quarter of the
+    vocabulary, contexts to 32768 = a 256-entry table, over ``jax.eval_shape``d
+    parameters."""
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.model_implementations.registry import model_cls_for
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import DSStateManagerConfig
+    from deepspeed_tpu.models import kimi_linear
+    cfg = kimi_linear.KimiLinearConfig(num_hidden_layers=8, vocab_size=40960, experts_held=64,
+                                       expert_rank=0)
+    abstract = jax.eval_shape(lambda: kimi_linear.init_params(cfg, param_dtype=cfg.dtype)[1])
+    engine_config = RaggedInferenceEngineConfig(
+        state_manager=DSStateManagerConfig(max_context=32768, max_ragged_batch_size=256,
+                                           max_ragged_sequence_count=8,
+                                           max_tracked_sequences=KIMI_SLOTS),
+        kv_block_size=KIMI_BLOCK, use_paged_kernel=True,
+        expert_parallel={"capacity_factor": 32.0})
+    model = model_cls_for(cfg)(abstract, cfg, engine_config)
+    assert model.num_kv_layers == 2 and model.min_table_bucket == KIMI_TABLE
+    assert model.kv_state_widths == (640, )
+    assert [(s.name, s.layers, s.shape, s.dtype) for s in model.sequence_state] == [
+        ("kda", 6, (32, 128, 128), "float32"), ("conv", 6, KIMI_TAILS, "bfloat16")]
+    return model, abstract
+
+
+def _kimi_args(device, abstract, tokens):
+    one = SingleDeviceSharding(device)
+    params = jax.tree.map(lambda leaf: _on(one, leaf.shape, leaf.dtype), abstract)
+    cache = ((_on(one, (2, KIMI_BLOCKS, KIMI_BLOCK, 640), jnp.bfloat16), ),
+             _on(one, (6, KIMI_SLOTS, 32, 128, 128), jnp.float32),
+             _on(one, (6, KIMI_SLOTS) + KIMI_TAILS, jnp.bfloat16))
+    batch = {"tok_meta": _on(one, (4, tokens), jnp.int32),
+             "seq_meta": _on(one, (8, 4 + KIMI_TABLE + 1), jnp.int32)}
+    return params, cache, batch
+
+
+def _kimi_pools_in_place(text):
+    """No result shaped like the state pool or a piece of it but the kernels'
+    aliased pool (a slot is exactly 2 MiB: the size XLA's gather rule turns
+    on), none shaped like the conv pool, the tails moved by the two slot-copy
+    kernels under ``kda/conv``, and no copy of the 2.5 GiB latent pool."""
+    import re
+    copies = [line for line in text.splitlines()
+              if re.search(rf"= bf16\[2,{KIMI_BLOCKS},{KIMI_BLOCK},640\]\S* copy\(", line)]
+    return not copies and not _pool_shaped_results(text, (6, KIMI_SLOTS, 32, 128)) \
+        and not _conv_pool_results(text, (6, KIMI_SLOTS) + KIMI_TAILS) \
+        and _tails_by_the_kernels(text, mixers=6, scope="kda/conv")
+
+
+def _kimi_same_cache(out, cache):
+    flat = jax.tree.leaves(out)
+    return [(c.shape, c.dtype) for c in flat] == [(c.shape, c.dtype) for c in jax.tree.leaves(cache)] \
+        and jax.tree.structure(out) == jax.tree.structure(cache)
+
+
+def test_kimi_put_program_fits_one_chip(v5e, kimi_model):
+    """The chunk bucket's ``put`` program: 7.0 GiB of weights beside 2.5 GiB of
+    latent rows and 0.8 GiB of state in 64 slots of 6 x 2 MiB, in one cache
+    pytree ``((latent, ), state, conv)``. The tiled latent grid against a
+    256-entry table in the two latent layers, the grouped matmul over the 64
+    held banks of width 1024 in seven layers, and in the six delta-rule layers
+    Solar Open 2's scan by segment at 32 heads (one ``kda_step_in_place`` and
+    one ``kda_chunk_in_place`` a layer, the pool aliased through both)."""
+    model, abstract = kimi_model
+    assert model.moe_path(256) == "grouped" and model.attention_arm(256) == "latent_tiled"
+    params, cache, batch = _kimi_args(v5e[0], abstract, 256)
+    compiled = jax.jit(model._forward_impl, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert len([line for line in _kernel_calls(text, "latent_paged_attention_tiled")
+                if "attn/latent_kernel" in line]) == 2
+    assert "grouped_matmul" in text and "kda/scan" in text and "kda/step" not in text
+    assert "latent_index_scores" not in text and "paged_attention_prefill" not in text
+    for name in ("kda_step_in_place", "kda_chunk_in_place"):
+        kernels = [line for line in _kernel_calls(text, name) if line.strip().startswith(f"%{name}")]
+        assert len(kernels) == 6 and all("kda/scan" in line for line in kernels), (name, kernels)
+        assert all("output_to_operand_aliasing" in line for line in kernels), (name, kernels)
+    assert _device_bytes(compiled) < 0.8 * HBM_BYTES
+    assert _kimi_pools_in_place(text)
+    assert _kimi_same_cache(jax.eval_shape(model._forward_impl, params, cache, batch)[1], cache)
+
+
+def test_kimi_decode_loop_program_fits_one_chip(v5e, kimi_model):
+    """``decode_loop``'s scan: the per-token latent grid walking a 256-entry
+    table in two layers, ONE ``kda_step_in_place`` a delta-rule layer over the
+    pool itself, and the pytree handed back in the types it came in."""
+    model, abstract = kimi_model
+    assert model.moe_path(8) == "grouped" and model.attention_arm(8) == "latent_token"
+    params, cache, batch = _kimi_args(v5e[0], abstract, 8)
+    loop = functools.partial(model._decode_loop_impl, n_steps=8)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert len([line for line in _kernel_calls(text, "latent_paged_attention_token")
+                if "attn/latent_kernel" in line]) == 2
+    assert "grouped_matmul" in text and "kda/step" in text and "kda/scan" not in text
+    kernels = _kernel_calls(text, "kda_step_in_place")
+    assert len(kernels) == 6 and all("kda/step" in line for line in kernels), kernels
+    assert _device_bytes(compiled) < 0.8 * HBM_BYTES
+    assert _kimi_pools_in_place(text)
+    assert _kimi_same_cache(jax.eval_shape(loop, params, cache, batch)[1], cache)
