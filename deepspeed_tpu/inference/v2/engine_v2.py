@@ -689,8 +689,10 @@ class InferenceEngineV2:
     def dispatch_block_loop(self, batch_uids: Iterable[int], blocks: Iterable, flags: Iterable,
                             n_blocks: int, do_checks: bool = True) -> BlockChunk:
         """``n_blocks`` blocks a sequence in ONE device program, launched and
-        NOT fetched: per block ``denoising_steps`` denoise forwards, the choice
-        of rows on the device, one commit forward
+        NOT fetched: per block ``denoising_steps`` denoise forwards and the
+        choice of rows on the device; a block's commit rides the next block's
+        first denoise forward, the chunk's last block's is a forward of its
+        own: ``n_blocks * denoising_steps + 1`` forwards of the batch
         (``DSTransformerModelBase._block_loop_impl``). ``blocks`` / ``flags``
         are each sequence's FIRST block as for :meth:`block_forward` (the
         prompt's rows past its last whole block, the rest masked); every later
@@ -698,25 +700,31 @@ class InferenceEngineV2:
         it but program order. Everything that needs only counts is done when
         the call returns: the KV blocks of all ``n_blocks * B`` positions,
         ``seen_tokens`` (+ ``n_blocks * B``). The ``block_loop`` span is the
-        launch: ``seqs``, ``blocks`` and ``forwards`` (both a sequence: a
-        block is ``denoising_steps + 1`` forwards of B rows), ``tokens`` (the
-        positions that take a token in the chunk; :meth:`BlockChunk.note`
-        corrects it to what the caller kept), ``steps`` (the program's forwards of the
-        whole batch), ``launch_us``; ``fetch_us`` and a grouped bucket's
-        ``moe_banks`` are the fetch's to write."""
+        launch: ``seqs``, ``blocks`` and ``forwards`` (both a sequence;
+        ``forwards`` counts forwards of B ROWS a sequence, ``denoising_steps +
+        1`` a block: what the attention kernel and the experts are handed,
+        however the program packs them — a fused forward is two), ``tokens``
+        (the positions that take a token in the chunk; :meth:`BlockChunk.note`
+        corrects it to what the caller kept), ``steps`` (the program's
+        forwards of the whole batch), ``fused_commits`` (those of them that
+        carry two blocks a sequence: ``n_blocks - 1``), the work counts over
+        both kinds (``model.block_loop_counts``), ``launch_us``; ``fetch_us``
+        and a grouped bucket's ``moe_banks`` are the fetch's to write."""
         batch_uids = list(batch_uids)
         feeds, masked = self._block_feeds(batch_uids, blocks, flags)
         if n_blocks < 1:
             raise ValueError("n_blocks must be >= 1")
         B, n = self._model.attention_block, len(batch_uids)
-        forwards = n_blocks * (self._model.config.denoising_steps + 1)
+        n_denoise = self._model.config.denoising_steps
         spans, observer, metrics = self._telemetry_sinks()
         n_tokens = n * n_blocks * B
         self._prepare(spans, batch_uids, feeds, do_checks, n_tokens, steps=n_blocks * B)
-        args, _ = self._dispatch(spans, batch_uids, feeds, None, steps=forwards)
+        args, _ = self._dispatch(spans, batch_uids, feeds, None, steps=n_blocks * n_denoise + 1)
         if args is not None:
             taking = int(masked.sum()) + n * (n_blocks - 1) * B
-            args.update(seqs=n, blocks=n * n_blocks, forwards=n * forwards, tokens=taking)
+            args.update(self._model.block_loop_counts(self._batch, n_blocks))
+            args.update(seqs=n, blocks=n * n_blocks, forwards=n * n_blocks * (n_denoise + 1),
+                        tokens=taking)
         slots = np.zeros(self._batch.device_batch["tok_meta"].shape[1], np.int32)
         slots[:masked.size] = masked
         with _tel_live_span(spans, "block_loop", "inference", args):

@@ -543,17 +543,22 @@ class DSTransformerModelBase:
         return ids, steps, conf, (banks[0] if banks else None)
 
     def _block_loop_impl(self, params, cache, batch, *, n_blocks):
-        """Per block: ``denoising_steps`` denoise forwards (scope
-        ``diffusion/denoise``), after each of which the ``B / denoising_steps``
-        masked rows of a sequence whose greedy token is most confident take it
-        (``diffusion/unmask``: argmax, its float32 softmax probability, the
-        best of the masked rows, ties to the earlier row —
-        ``low_confidence_static``); then the commit forward over the finished
-        block (``diffusion/commit``, no row unembedded: the published algorithm
-        reads no logits of it) and the metadata moved on by B. A sequence
-        whose first block came part given has nothing left to take in its
-        last denoise forwards: the program's shape is the batch's, not a
-        sequence's."""
+        """``n_blocks * denoising_steps + 1`` forwards. A block's
+        ``denoising_steps`` denoise forwards (scope ``diffusion/denoise``), after
+        each of which the ``B / denoising_steps`` masked rows of a sequence
+        whose greedy token is most confident take it (``diffusion/unmask``:
+        argmax, its float32 softmax probability, the best of the masked rows,
+        ties to the earlier row — ``low_confidence_static``). A block's COMMIT —
+        its finished rows through every layer once more, so that the pool holds
+        their final K/V — rides the next block's first denoise forward
+        (:meth:`_two_blocks`: one forward of 2B rows a sequence, the expert
+        banks read once for both; only the next block's rows are unembedded),
+        behind which the metadata moves on by B. The chunk's last block has no
+        successor in the program and keeps a commit forward of its own
+        (``diffusion/commit``, no row unembedded: the published algorithm reads
+        no logits of it). A sequence whose first block came part given has
+        nothing left to take in its last denoise forwards: the program's shape
+        is the batch's, not a sequence's."""
         import jax
         import jax.numpy as jnp
 
@@ -563,44 +568,74 @@ class DSTransformerModelBase:
         seq_meta = jnp.asarray(batch["seq_meta"])
         T = tok_meta.shape[1]
         valid = tok_meta[3] > 0
-        seq_valid = (seq_meta[:, 3] > 0).astype(seq_meta.dtype)
 
-        def forward(cache, ids, tok_meta, seq_meta, rows):
+        def forward(params, cache, ids, tok_meta, seq_meta, rows):
             return self._forward_impl(params, cache, {"tok_meta": tok_meta.at[0].set(ids),
                                                       "seq_meta": seq_meta}, rows=rows)
 
-        def block(carry, _):
-            cache, tok_meta, seq_meta, ids, masked = carry
+        def unmask(logits, ids, masked, taken, step):
+            with jax.named_scope("diffusion/unmask"):
+                x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                conf = jnp.max(jax.nn.softmax(logits, axis=-1), axis=-1)
+                conf = jnp.where(masked, conf, -1.0).reshape(T // B, B)
+                _, best = jax.lax.top_k(conf, B // n_denoise)  # equal: the earlier row
+                chosen = jnp.any(best[:, :, None] == jnp.arange(B)[None, None, :], axis=1)
+                chosen = chosen.reshape(T) & masked
+                ids = jnp.where(chosen, x0, ids)
+                taken = jnp.where(chosen, step.astype(jnp.int8), taken)
+            return ids, masked & ~chosen, taken, conf.reshape(T)
 
-            def denoise(carry, step):
-                cache, ids, masked, taken = carry
-                with jax.named_scope("diffusion/denoise"):
-                    logits, cache, *banks = forward(cache, jnp.where(masked, mask_id, ids),
-                                                    tok_meta, seq_meta, "all")
-                with jax.named_scope("diffusion/unmask"):
-                    x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    conf = jnp.max(jax.nn.softmax(logits, axis=-1), axis=-1)
-                    conf = jnp.where(masked, conf, -1.0).reshape(T // B, B)
-                    _, best = jax.lax.top_k(conf, B // n_denoise)  # equal: the earlier row
-                    chosen = jnp.any(best[:, :, None] == jnp.arange(B)[None, None, :], axis=1)
-                    chosen = chosen.reshape(T) & masked
-                    ids = jnp.where(chosen, x0, ids)
-                    taken = jnp.where(chosen, step.astype(jnp.int8), taken)
-                return (cache, ids, masked & ~chosen, taken), (conf.reshape(T), *banks)
+        # the B-row denoise forward has two uses (the first block's steps, a
+        # later block's steps behind its fused one): a jit of its own, so that
+        # the second is the first's trace
+        @jax.jit
+        def denoise(params, tok_meta, seq_meta, carry, step):
+            cache, ids, masked, taken = carry
+            with jax.named_scope("diffusion/denoise"):
+                logits, cache, *banks = forward(params, cache, jnp.where(masked, mask_id, ids),
+                                                tok_meta, seq_meta, "all")
+            ids, masked, taken, conf = unmask(logits, ids, masked, taken, step)
+            return (cache, ids, masked, taken), (conf, *banks)
 
-            (cache, ids, masked, taken), (conf, *banks) = jax.lax.scan(
-                denoise, (cache, ids, masked, jnp.full((T, ), -1, jnp.int8)),
-                jnp.arange(n_denoise))
-            with jax.named_scope("diffusion/commit"):
-                _, cache, *committed = forward(cache, ids, tok_meta, seq_meta, "none")
-            tok_meta = tok_meta.at[2].add(B * valid.astype(tok_meta.dtype))
-            seq_meta = seq_meta.at[:, 0].add(B * seq_valid)
-            banks = tuple(b.sum(axis=0) + c for b, c in zip(banks, committed))
-            return (cache, tok_meta, seq_meta, ids, valid), (ids, taken, conf, *banks)
+        def steps(cache, tok_meta, seq_meta, ids, masked, taken, first):
+            """Denoise forwards ``first .. denoising_steps - 1`` of a block."""
+            (cache, ids, _, taken), (conf, *banks) = jax.lax.scan(
+                partial(denoise, params, tok_meta, seq_meta), (cache, ids, masked, taken),
+                jnp.arange(first, n_denoise))
+            return cache, ids, taken, conf, tuple(b.sum(axis=0) for b in banks)
+
+        def next_block(carry, _):
+            cache, tok_meta, seq_meta, ids = carry
+            with jax.named_scope("diffusion/denoise"):
+                logits, cache, *fused = forward(
+                    params, cache, jnp.concatenate([ids, jnp.full((T, ), mask_id, ids.dtype)]),
+                    *self._two_blocks(tok_meta, seq_meta), slice(T, 2 * T))
+            tok_meta, seq_meta = self._next_block(tok_meta, seq_meta)
+            ids, masked, taken, conf0 = unmask(logits, ids, valid, jnp.full((T, ), -1, jnp.int8),
+                                               jnp.int8(0))
+            cache, ids, taken, conf, banks = steps(cache, tok_meta, seq_meta, ids, masked, taken, 1)
+            if banks and not fused:
+                raise ValueError(
+                    f"a block loop over {T} rows routes by sorting and counts the banks it "
+                    f"reads; its fused forward of {2 * T} rows takes the capacity path "
+                    f"(modules/heuristics.py:moe_implementation) and counts none")
+            # the banks are counted where the bucket's own forwards count them
+            banks = tuple(b + f for b, f in zip(banks, fused))
+            return (cache, tok_meta, seq_meta, ids), \
+                (ids, taken, jnp.concatenate([conf0[None], conf]), *banks)
 
         masked = (jnp.asarray(batch["masked"]) > 0) & valid
-        (cache, *_), (ids, taken, conf, *banks) = jax.lax.scan(
-            block, (cache, tok_meta, seq_meta, tok_meta[0], masked), None, length=n_blocks)
+        cache, ids, taken, conf, banks = steps(cache, tok_meta, seq_meta, tok_meta[0], masked,
+                                               jnp.full((T, ), -1, jnp.int8), 0)
+        blocks = [a[None] for a in (ids, taken, conf, *banks)]  # the first block's; then the rest
+        if n_blocks > 1:
+            (cache, tok_meta, seq_meta, ids), rest = jax.lax.scan(
+                next_block, (cache, tok_meta, seq_meta, ids), None, length=n_blocks - 1)
+            blocks = [jnp.concatenate(pair) for pair in zip(blocks, rest)]
+        with jax.named_scope("diffusion/commit"):
+            _, cache, *committed = forward(params, cache, ids, tok_meta, seq_meta, "none")
+        ids, taken, conf, *banks = blocks
+        banks = tuple(b.at[-1].add(c) for b, c in zip(banks, committed))
 
         def by_sequence(a):  # [n_blocks, T] -> [T / B, n_blocks * B]
             return a.reshape(n_blocks, T // B, B).transpose(1, 0, 2).reshape(T // B, n_blocks * B)
@@ -608,6 +643,61 @@ class DSTransformerModelBase:
         # [n_blocks, n_denoise, T] -> [T / B, n_blocks, n_denoise, B]
         conf = conf.reshape(n_blocks, n_denoise, T // B, B).transpose(2, 0, 1, 3)
         return (by_sequence(ids), by_sequence(taken), conf, cache, *banks)
+
+    def _next_block(self, tok_meta, seq_meta):
+        """The metadata of the block step one block on: every live row's and
+        sequence's position moved by B. numpy in, numpy out (the host's count
+        of what the program runs: :meth:`block_loop_counts`); traced arrays in
+        the program."""
+        B = self.attention_block
+        row, column = np.arange(4)[:, None] == 2, np.arange(seq_meta.shape[1]) == 0
+        return (tok_meta + B * (row & (tok_meta[3] > 0)).astype(np.int32),
+                seq_meta + B * (column & (seq_meta[:, 3:4] > 0)).astype(np.int32))
+
+    def _two_blocks(self, tok_meta, seq_meta):
+        """The metadata of ONE forward over a block step's rows and, behind
+        them, the rows of the block after (``[4, 2T]``, ``[2S, ...]``): the
+        second block as a batch of its own — its sequences are entries ``S ..
+        2S - 1``, each with the block table of its first-block entry and
+        ``seq_seen`` B further — so that the paged kernel's tile grid takes each
+        block as the few-row pass it always was. A tile's passes run in the
+        order of the entries and a pass's insert has landed before the next
+        pass walks (``ops/pallas/paged_attention.py:_tiled_kernel``, ``landed``),
+        the grid's tiles run in order, and the XLA arm scatters every row before
+        it gathers: the second block's queries see the first block's K/V of
+        this same forward."""
+        import jax.numpy as jnp
+        xp = np if isinstance(tok_meta, np.ndarray) else jnp
+        T, S = tok_meta.shape[1], seq_meta.shape[0]
+        tok_next, seq_next = self._next_block(tok_meta, seq_meta)
+        tok_next = tok_next + S * (np.arange(4)[:, None] == 1).astype(np.int32)  # its own entry
+        seq_next = seq_next + T * (np.arange(seq_meta.shape[1]) == 2).astype(np.int32)  # last_tok
+        return (xp.concatenate([tok_meta, tok_next], axis=1),
+                xp.concatenate([seq_meta, seq_next], axis=0))
+
+    def block_loop_counts(self, ragged_batch, n_blocks: int) -> dict:
+        """What a block loop of ``n_blocks`` blocks says on its span of the
+        forwards its program runs (:meth:`_block_loop_impl`): ``steps``, the
+        forwards of the whole batch, ``n_blocks * denoising_steps + 1``, of
+        which ``fused_commits`` (``n_blocks - 1``) carry two blocks a
+        sequence; and :meth:`dispatch_counts` / :meth:`batch_counts` summed
+        over both kinds — the fused forward's ``2T`` rows route twice the
+        assignments, and its passes on the tile grid are those of the metadata
+        it is fed (:meth:`_two_blocks`). ``moe_path`` is the bucket's own."""
+        batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
+        tok, seq = np.asarray(batch["tok_meta"]), np.asarray(batch["seq_meta"])
+        live, fused = int((tok[3] > 0).sum()), n_blocks - 1
+        alone = n_blocks * self._config.denoising_steps + 1 - fused
+        counts = {"steps": alone + fused, "fused_commits": fused}
+        two = dict(zip(("tok_meta", "seq_meta"), self._two_blocks(tok, seq)))
+        for k, rows, fed in ((alone, live, batch), (fused, 2 * live, two)):
+            if not k:
+                continue
+            n_padded = fed["tok_meta"].shape[1]
+            for name, n in {**self.dispatch_counts(n_padded, rows, k),
+                            **self.batch_counts(fed, k)}.items():
+                counts[name] = counts.get(name, n) if isinstance(n, str) else counts.get(name, 0) + n
+        return counts
 
     @property
     def _slot_columns(self) -> int:
@@ -645,8 +735,10 @@ class DSTransformerModelBase:
         """One ragged forward. ``rows``: which rows are unembedded — each
         sequence's ``last`` token (a step that yields one token a sequence),
         ``all`` of the batch's (a denoise forward of a block step: ``[T,
-        vocab]``), or ``none`` (a block's commit: the K/V is all it is for,
-        and the logits are None)."""
+        vocab]``), ``none`` (a block's commit: the K/V is all it is for, and
+        the logits are None), or a ``slice`` of the batch's rows (a block
+        loop's fused forward: the rows that still want a token, behind the
+        rows that are only committed)."""
         import jax.numpy as jnp
         from deepspeed_tpu.inference.v2.quantization import dequantize_tree
 
@@ -660,6 +752,8 @@ class DSTransformerModelBase:
         attn = partial(self._paged_attention, batch=batch)
         for li in range(self.num_layers):
             x, cache = self.layer_forward(params, li, x, cache, attn, batch)
+        if isinstance(rows, slice):
+            x = x[rows]
         if rows == "none":
             logits = None
         else:

@@ -31,7 +31,10 @@ Batch composition per tick (``step()``):
    yields no token; a decode row is a BLOCK (the prompt's ``len % B`` rows the
    first time, masked rows after) and a decode-only batch runs through
    ``engine.dispatch_block_loop`` (``decode_chunk / B`` blocks a chunk, up to
-   B tokens a block handed over); a batch with a prompt chunk in it is a
+   B tokens a block handed over; ``denoising_steps`` forwards a block and one
+   commit forward a CHUNK, its last block's: an earlier block's commit rides
+   the next block's first forward — ``commit_forwards`` and
+   ``fused_commit_forwards`` count the two); a batch with a prompt chunk in it is a
    ``put`` step the decoding requests sit out, and the two kinds of step take
    TURNS while both have work: behind a prompt step the decoding requests get
    a block loop before the next prompt chunk, so arrivals hold a decoder back
@@ -263,7 +266,7 @@ class ServingScheduler:
                            "moe_grouped_chunks", "moe_capacity_chunks",
                            "pipelined_chunks", "open_behind_steps", "late_commits",
                            "block_loops", "blocks_committed", "denoise_forwards",
-                           "commit_forwards", "block_tokens_cut")
+                           "commit_forwards", "fused_commit_forwards", "block_tokens_cut")
                           + tuple(f"drained_steps_{r}" for r in _DRAIN_REASONS)}
         # the step on the device that no tick has fetched yet (step()),
         # why the newest fetched step was fetched before its successor was
@@ -2365,7 +2368,8 @@ class ServingScheduler:
         self._counters["block_loops"] += 1
         self._counters["blocks_committed"] += len(reqs) * (k // B)
         self._counters["denoise_forwards"] += (k // B) * self._engine.model.config.denoising_steps
-        self._counters["commit_forwards"] += k // B
+        self._counters["commit_forwards"] += 1  # the chunk's last block's; the others' are fused
+        self._counters["fused_commit_forwards"] += k // B - 1
         self._count_moe_path("chunks")
         self._charge_members([(req, "decode", k) for req in reqs])
         given = [int(B - flags.sum()) for _, flags in feeds]

@@ -98,13 +98,41 @@ def _pool_rows(engine, uid, n):
 
 
 # ---------------------------------------------------------- (a) the engine --
-@pytest.mark.parametrize("kernel", [False, True], ids=["xla-arm", "tile-grid-interpret"])
-def test_every_forward_of_two_blocks_is_the_references_and_the_loop_is_generates(model, kernel):
+def _walk(engine, cfg, uids, n_blocks):
+    """``n_blocks`` all-masked blocks a sequence, a forward at a time:
+    ``block_forward`` x ``denoising_steps``, the reference's rule on its
+    logits, then the commit ``put``. ``(ids, steps)`` as a loop hands them."""
+    ids, steps = [], []
+    for _ in range(n_blocks):
+        blocks = [np.zeros(B, np.int32) for _ in uids]
+        masked = [np.ones(B, bool) for _ in uids]
+        taken = [np.full(B, -1, np.int8) for _ in uids]
+        for step in range(cfg.denoising_steps):
+            logits = np.asarray(engine.block_forward(uids, blocks, masked))
+            for i in range(len(uids)):
+                x0, conf = reference.confidence(logits[i])
+                for j in reference.most_confident(conf, masked[i], B // cfg.denoising_steps):
+                    blocks[i][j], masked[i][j], taken[i][j] = int(x0[j]), False, step
+        engine.put(uids, blocks)  # the commit
+        ids.append(np.stack(blocks))
+        steps.append(np.stack(taken))
+    return np.concatenate(ids, axis=1), np.concatenate(steps, axis=1)
+
+
+@pytest.mark.parametrize("kernel, n_blocks", [(False, 1), (False, 2), (False, 3), (True, 2),
+                                              (True, 3)],
+                         ids=["xla-arm-1-block", "xla-arm-2-blocks", "xla-arm-3-blocks",
+                              "tile-grid-interpret-2-blocks", "tile-grid-interpret-3-blocks"])
+def test_every_forward_of_two_blocks_is_the_references_and_the_loop_is_generates(model, kernel,
+                                                                                  n_blocks):
     """Prefill of whole blocks together; then a block a prompt, a forward at a
     time: every denoise forward's four rows of logits are the reference's of
     the whole sequence as it then stands, ``seen_tokens`` and the committed K/V
-    stay as they were, the commit ``put`` moves both; then the block loop, whose
-    ids and steps are ``generate``'s."""
+    stay as they were, the commit ``put`` moves both; then the block loop of
+    ``n_blocks`` blocks (contexts of 24 to 44 tokens; a block's commit fused
+    with the next block's first forward, the last block's alone), whose ids and
+    steps are ``generate``'s AND the forward-at-a-time walk's, and whose pool
+    is the walk's."""
     cfg, params = model
     engine = engine_of(cfg, params, kernel)
     served = engine.model
@@ -151,21 +179,22 @@ def test_every_forward_of_two_blocks_is_the_references_and_the_loop_is_generates
     fresh.close()
 
     # the block loop against generate, from the state the forwards left
-    chunk = engine.dispatch_block_loop(uids, [np.zeros(B, np.int32)] * 4, [np.ones(B, bool)] * 4, 2)
+    chunk = engine.dispatch_block_loop(uids, [np.zeros(B, np.int32)] * 4, [np.ones(B, bool)] * 4,
+                                       n_blocks)
     assert isinstance(chunk, BlockChunk)
     ids = chunk.fetch()
-    assert ids.shape == chunk.steps.shape == (4, 2 * B) and chunk.steps.dtype == np.int8
+    assert ids.shape == chunk.steps.shape == (4, n_blocks * B) and chunk.steps.dtype == np.int8
     for u in uids:
-        want_ids, want_steps = reference.generate(params, sizes_of(cfg), committed[u], 2 * B)
+        want_ids, want_steps = reference.generate(params, sizes_of(cfg), committed[u], n_blocks * B)
         assert ids[u].tolist() == want_ids.tolist(), u
         assert chunk.steps[u].tolist() == want_steps.tolist(), u
         assert sorted(chunk.steps[u][:B]) == [0, 1, 2, 3]
     # what the rows were chosen on: the reference's confidence of each masked row behind each
     # denoise forward, -1 where the row had its token; the row taken is the largest's
     conf = chunk.confidences
-    assert conf.shape == (4, 2, cfg.denoising_steps, B) and conf.dtype == np.float32
+    assert conf.shape == (4, n_blocks, cfg.denoising_steps, B) and conf.dtype == np.float32
     for u in uids[::3]:
-        for b in range(2):
+        for b in range(n_blocks):
             block, steps = ids[u][b * B:(b + 1) * B], chunk.steps[u][b * B:(b + 1) * B]
             for step in range(cfg.denoising_steps):
                 flags = steps >= step
@@ -175,31 +204,48 @@ def test_every_forward_of_two_blocks_is_the_references_and_the_loop_is_generates
                 assert np.abs(conf[u, b, step] - want).max() < TOL, (u, b, step)
                 assert int(np.argmax(conf[u, b, step])) == int(np.flatnonzero(steps == step)[0])
     assert [engine._state_manager.get_sequence(u).seen_tokens for u in uids] == \
-        [w + 3 * B for w in whole]
+        [w + (1 + n_blocks) * B for w in whole]
     with pytest.raises(ValueError, match="hands no ids on"):
         chunk.ids
     programs = engine.lowerable_callables()
     assert {key[1:] for key in programs["forward"]} == {(8, 16)}  # one sequence, one table bucket
-    assert list(programs["block_loop"]) == [((64, 8, 16), 2)]
+    assert list(programs["block_loop"]) == [((64, 8, 16), n_blocks)]
     assert list(programs["block_forward"]) == [(64, 8, 16)] and not programs["decode_loop"]
+
+    # the same blocks a forward at a time on twins of the sequences, each block committed by a
+    # put of its own: the loop's ids, its steps, and the K/V its commits (fused and alone) left
+    twins = [u + len(uids) for u in uids]
+    for u, twin in zip(uids, twins):
+        for at in range(0, committed[u].size, 32):
+            engine.put([twin], [committed[u][at:at + 32]])
+    walk_ids, walk_steps = _walk(engine, cfg, twins, n_blocks)
+    assert walk_ids.tolist() == ids.tolist() and walk_steps.tolist() == chunk.steps.tolist()
+    for u, twin in zip(uids, twins):
+        n = committed[u].size + n_blocks * B
+        assert engine._state_manager.get_sequence(twin).seen_tokens == n
+        assert np.abs(_pool_rows(engine, u, n) - _pool_rows(engine, twin, n)).max() < TOL, u
     engine.close()
 
 
-def test_the_loop_from_a_part_given_first_block_is_generates(model):
-    """Straight from the prefill: each prompt's ``len % 4`` rows are given (step
-    -1, its own ids), the rest take their tokens in ``generate``'s order; a
-    sequence whose first block is fully given changes nothing in it."""
+@pytest.mark.parametrize("kernel, n_blocks", [(False, 1), (False, 2), (False, 3), (True, 1)],
+                         ids=["xla-arm-1-block", "xla-arm-2-blocks", "xla-arm-3-blocks",
+                              "tile-grid-interpret-1-block"])
+def test_the_loop_from_a_part_given_first_block_is_generates(model, kernel, n_blocks):
+    """Straight from the prefill (contexts of 0 to 40 tokens): each prompt's
+    ``len % 4`` rows are given (step -1, its own ids), the rest take their
+    tokens in ``generate``'s order; a sequence whose first block is fully given
+    changes nothing in it."""
     cfg, params = model
-    engine = engine_of(cfg, params)
+    engine = engine_of(cfg, params, kernel)
     prompts = _prompts() + [np.random.default_rng(5).integers(0, 256, 3).astype(np.int32)]
     uids = list(range(len(prompts)))
     whole = _prefill(engine, prompts)
     assert whole[-1] == 0  # a prompt shorter than a block has nothing to prefill
     blocks, masked = _first_blocks(prompts, whole)
-    ids, steps = engine.block_loop(uids, blocks, masked, 3)
+    ids, steps = engine.block_loop(uids, blocks, masked, n_blocks)
     for u, p in enumerate(prompts):
         k = p.size - whole[u]
-        want_ids, want_steps = reference.generate(params, sizes_of(cfg), p, 3 * B - k)
+        want_ids, want_steps = reference.generate(params, sizes_of(cfg), p, n_blocks * B - k)
         assert ids[u][:k].tolist() == p[whole[u]:].tolist() and (steps[u][:k] == -1).all()
         assert ids[u][k:].tolist() == want_ids.tolist(), u
         assert steps[u][k:].tolist() == want_steps.tolist(), u
@@ -207,7 +253,7 @@ def test_the_loop_from_a_part_given_first_block_is_generates(model):
     assert len({int(t) for row in ids for t in row}) > 6
     # the loop's COMMITS: the pool holds what a block-masked prefill of the same tokens leaves
     # (a skipped commit leaves the last denoise forward's K/V, a row of it the mask token's)
-    fresh = engine_of(cfg, params)
+    fresh = engine_of(cfg, params, kernel)
     for u, p in enumerate(prompts):
         full = np.concatenate([p[:whole[u]], ids[u]]).astype(np.int32)
         for at in range(0, full.size, 32):
@@ -219,13 +265,19 @@ def test_the_loop_from_a_part_given_first_block_is_generates(model):
     engine.close()
 
 
-def test_the_spans_count_the_tile_grids_few_row_passes(model):
-    """On the tile grid under a telemetry session: a block loop's span counts a
-    pass a sequence a layer a forward, every one the kernel's few-row arm's (a
-    block of 4 rows is one pass of one block); a prompt chunk's ``put`` counts
-    its pass as a many-row one; without a block mask (``batch_counts`` of a
-    causal model at the same positions) the arm is the one-token passes'."""
+@pytest.mark.parametrize("n_blocks", [1, 2, 3], ids=lambda n: f"{n}-blocks-a-loop")
+def test_the_spans_count_the_tile_grids_few_row_passes(model, n_blocks):
+    """On the tile grid under a telemetry session: a block loop's span counts
+    the program's forwards (``steps``: ``n_blocks * denoising_steps + 1``, of
+    which ``fused_commits`` carry two blocks a sequence) beside ``forwards`` /
+    ``blocks``, which stay forwards of B rows a sequence; its passes are
+    ``tiled_passes()`` of what the program feeds the kernel, every one the
+    few-row arm's (a fused forward's two blocks are two entries, two passes of
+    one block); a prompt chunk's ``put`` counts its pass as a many-row one;
+    without a block mask (``batch_counts`` of a causal model at the same
+    positions) the arm is the one-token passes'."""
     from deepspeed_tpu import telemetry
+    from deepspeed_tpu.ops.pallas.paged_attention import tiled_passes
     cfg, params = model
     engine = engine_of(cfg, params, kernel=True)
     prompts = _prompts()
@@ -235,15 +287,30 @@ def test_the_spans_count_the_tile_grids_few_row_passes(model):
     session = telemetry.configure({"enabled": True, "compile_watch": False})
     try:
         engine.put([9], [np.arange(24, dtype=np.int32)])  # a prompt chunk of six blocks
-        engine.dispatch_block_loop(uids, blocks, masked, 2).fetch()
+        engine.dispatch_block_loop(uids, blocks, masked, n_blocks).fetch()
         spans = [s for s in session.spans.export_since(0)["spans"] if s["cat"] == "inference"]
     finally:
         telemetry.shutdown()
-    layers, forwards = cfg.num_hidden_layers, 2 * (cfg.denoising_steps + 1)
+    layers, n_denoise = cfg.num_hidden_layers, cfg.denoising_steps
     (loop, ) = [s["args"] for s in spans if s["name"] == "block_loop"]
-    assert loop["steps"] == forwards
-    assert loop["tiled_passes"] == loop["tiled_few_row_passes"] == len(uids) * layers * forwards
-    assert loop["tiled_one_token_passes"] == 0
+    assert loop["steps"] == n_blocks * n_denoise + 1 and loop["fused_commits"] == n_blocks - 1
+    assert (loop["seqs"], loop["blocks"]) == (len(uids), len(uids) * n_blocks)
+    assert loop["forwards"] == loop["blocks"] * (n_denoise + 1)  # of B rows a sequence
+    # the kernel's passes: what tiled_passes() says of the metadata the program's forwards are fed
+    fed = engine._batch.device_batch
+    tok, seq = np.asarray(fed["tok_meta"]), np.asarray(fed["seq_meta"])
+    two_tok, two_seq = engine.model._two_blocks(tok, seq)
+    assert two_tok.shape == (4, 2 * tok.shape[1]) and two_seq.shape[0] == 2 * seq.shape[0]
+    alone = tiled_passes(seq[:, 1], seq[:, 2], tok.shape[1], B)
+    fused = tiled_passes(two_seq[:, 1], two_seq[:, 2], two_tok.shape[1], B)
+    assert alone == (len(uids), 0, len(uids)) and fused == (2 * len(uids), 0, 2 * len(uids))
+    want = [layers * ((loop["steps"] - loop["fused_commits"]) * a + loop["fused_commits"] * f)
+            for a, f in zip(alone, fused)]
+    assert [loop["tiled_passes"], loop["tiled_one_token_passes"],
+            loop["tiled_few_row_passes"]] == want
+    assert loop["tiled_passes"] == len(uids) * layers * n_blocks * (n_denoise + 1)
+    # the experts' rows: a fused forward routes two blocks' assignments
+    assert loop["moe_assignments"] == loop["forwards"] * B * cfg.num_experts_per_tok * layers
     (put, ) = [s["args"] for s in spans if s["name"] == "put"]
     assert put["attention"] == "paged_tiled" and put["tiled_one_token_passes"] == 0
     # 24 rows are one pass of more than one block: the many-row arm's
@@ -323,12 +390,31 @@ def test_requests_get_exactly_max_new_tokens_eight_at_a_time_and_alone(served):
         assert len(got) == m and list(got) == w
     counters = scheduler.stats()["counters"]
     assert counters["block_loops"] >= 2 and counters["blocks_committed"] >= 8 * 2
-    assert counters["denoise_forwards"] == 4 * counters["commit_forwards"]
+    assert counters["denoise_forwards"] == \
+        4 * (counters["commit_forwards"] + counters["fused_commit_forwards"])
     assert counters["block_tokens_cut"] > 0 and counters["completed"] == 8
     assert not counters["moe_capacity_steps"] + counters["moe_grouped_steps"] < counters["put_steps"]
     for p, w, (_, m) in list(zip(prompts, want, REQUESTS))[:3]:
         assert list(scheduler.submit(p, max_new_tokens=m).result(timeout=600)) == w
     assert {key[1] for key in scheduler._engine.lowerable_callables()["block_loop"]} == {2}
+
+
+def test_every_block_is_committed_once_fused_or_alone(served):
+    """One request alone, 20 tokens at a ``decode_chunk`` of 8: three launches
+    of two blocks (the last's second block is cut whole), and every block the
+    scheduler counts as committed was committed by ONE forward: a launch's last
+    block's by the commit forward, every other by a fused one."""
+    cfg, params, scheduler = served
+    before = dict(scheduler.stats()["counters"])
+    prompt = np.random.default_rng(21).integers(0, 256, 12).astype(np.int32)
+    want = reference.generate(params, sizes_of(cfg), prompt, 20)[0].tolist()
+    assert list(scheduler.submit(prompt, max_new_tokens=20).result(timeout=600)) == want
+    after = scheduler.stats()["counters"]
+    new = {k: after[k] - before[k] for k in ("block_loops", "blocks_committed", "commit_forwards",
+                                             "fused_commit_forwards", "denoise_forwards")}
+    assert new["block_loops"] == new["commit_forwards"] == 3  # a commit forward a launch
+    assert new["commit_forwards"] + new["fused_commit_forwards"] == new["blocks_committed"] == 6
+    assert new["denoise_forwards"] == cfg.denoising_steps * new["blocks_committed"]
 
 
 @pytest.mark.parametrize("n", [1, 2, 3])

@@ -78,6 +78,13 @@ def _kernel_calls(text, name):
     return [line for line in text.splitlines() if "tpu_custom_call" in line and name in line]
 
 
+def _call_sites(text, name):
+    """The calls of the jitted function ``name`` (any of its traces: ``name``,
+    ``name_123``) in a LOWERED program's text: what was traced, before the
+    compiler inlines anything."""
+    return len(re.findall(rf"call @{name}(?:_\d+)?\(", text))
+
+
 # serve-phase geometry: Mixtral-8x7B attention, the engine's default 64-token blocks
 H, KVH, D, BS = 32, 8, 128, 64
 
@@ -963,22 +970,44 @@ def test_sdar_put_program_fits_one_chip(v5e, sdar_model, tokens):
 
 
 def test_sdar_block_loop_program_fits_one_chip(v5e, sdar_model):
-    """Two blocks a chunk: a scan of blocks around a scan of four denoise
-    forwards (every row unembedded, the unmasking's ``top_k``) and a commit
-    forward with no head; ids and int8 steps a row of ``[32, 8]``."""
+    """Two blocks a chunk, nine forwards: a scan of four denoise forwards
+    (every row unembedded, the unmasking's ``top_k``), the FUSED forward of 256
+    rows (the first block's commit beside the second's first denoise forward,
+    the head on the second's 128 rows) with three denoise forwards behind it,
+    and the last block's commit forward with no head; ids and int8 steps a row
+    of ``[32, 8]``. The forward is TRACED three times, not four: the B-row
+    denoise forward's two uses are one jit's (a kernel's trace is part of every
+    warm start: PERF.md section 6, PR 51), so the lowered program holds three
+    forwards' kernel call sites."""
     model, abstract = sdar_model
     one, params, cache, batch = _sdar_args(v5e[0], model, abstract, 128)
+    forward = jax.jit(model._forward_impl, donate_argnums=(1, )).lower(params, cache, batch).as_text()
     batch["masked"] = _on(one, (128, ), jnp.int32)
     loop = functools.partial(model._block_loop_impl, n_blocks=2)
-    compiled = jax.jit(loop, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    lowered = jax.jit(loop, donate_argnums=(1, )).lower(params, cache, batch)
+    # (``_projection``: the jit around the ``grouped_matmul`` kernel, a call a projection;
+    # nothing reads the stream behind the tail commit's last attention: that layer's experts
+    # are not in the program)
+    for kernel, dead in (("paged_attention_prefill", 0), ("_projection", 1)):
+        a_forward = _call_sites(forward, kernel)
+        assert a_forward >= SDAR_LAYERS
+        assert _call_sites(lowered.as_text(), kernel) == \
+            3 * a_forward - dead * a_forward // SDAR_LAYERS, kernel
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert "paged_attention_prefill" in text and "grouped_matmul" in text
     assert "paged_attention_update" not in text
     out = jax.eval_shape(loop, params, cache, batch)
     assert (out[0].shape, out[0].dtype, out[1].shape, out[1].dtype) == \
         ((32, 8), jnp.int32, (32, 8), jnp.int8)
+    assert out[4].shape == (2, SDAR_LAYERS)  # the banks each block's forwards read, a layer
     assert _device_bytes(compiled) < 0.85 * HBM_BYTES
     assert not _pool_sized_results(text, cache.shape)
+    # one block: no fused forward, the program it always was (a scan of four and a commit)
+    one_block = jax.jit(functools.partial(model._block_loop_impl, n_blocks=1),
+                        donate_argnums=(1, )).lower(params, cache, batch).as_text()
+    assert _call_sites(one_block, "paged_attention_prefill") == \
+        2 * _call_sites(forward, "paged_attention_prefill")
 
 
 # ---- solar-open2-250b-serve-1chip: a delta-rule state of 4 MiB a sequence a layer (PR 54) ----
