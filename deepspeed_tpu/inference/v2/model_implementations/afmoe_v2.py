@@ -57,11 +57,7 @@ class AfmoeV2Model(LayerTypedMoEModel):
                 out = _swiglu(h, lp["mlp"])
             else:
                 mp = lp["block_sparse_moe"]
-                out = self._moes[li - cfg.num_dense_layers](
-                    h, mp["gate"], mp["ExpertFFN_0"]["wi"], mp["ExpertFFN_0"]["wo"],
-                    activation=jax.nn.silu, select_bias=mp.get("expert_bias"),
-                    **self._gating_inputs(batch)).astype(x.dtype)
-                if "shared_experts" in mp:  # always on: every token, once
-                    with jax.named_scope("shared"):
-                        out = out + _swiglu(h, mp["shared_experts"])
+                out = self._routed_beside_shared(
+                    li - cfg.num_dense_layers, h, mp["gate"], mp["ExpertFFN_0"],
+                    mp.get("expert_bias"), mp.get("shared_experts"), batch)
             return x + _rms(out, lp["post_mlp_layernorm"]["weight"], cfg.rms_norm_eps)

@@ -7,8 +7,6 @@ MQA — see ``models/decoder.py``). Consumes the training pytree verbatim so
 logits are testable against the training forward.
 """
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 
@@ -69,32 +67,10 @@ class DecoderV2Model(DSTransformerModelBase):
                                                     rot, config.rope_theta, jnp.float32)
 
     @property
-    def num_layers(self):
-        return self._config.num_hidden_layers
-
-    @property
-    def num_heads(self):
-        return self._config.num_attention_heads
-
-    @property
-    def num_kv_heads(self):
-        return self._config.num_key_value_heads
-
-    @property
     def head_dim(self):
         return self._config.hidden_size // self._config.num_attention_heads
 
-    @property
-    def vocab_size(self):
-        return self._config.vocab_size
-
     # --------------------------------------------------------------- phases --
-    @jax.named_scope("embed")
-    def embed(self, params, ids):
-        r = _root(params)
-        x = r["embed_tokens"]["embedding"][ids].astype(self._config.dtype)
-        return x
-
     @jax.named_scope("embed")
     def _add_positions(self, params, x, batch):
         cfg = self._config

@@ -13,8 +13,8 @@ feed-forward. What the architecture asks of the engine:
   float32 (``mamba2_base.scaled_dot``): none is folded into a weight;
 - **rotary embedding** over the whole head at ``rope_theta`` (1e11), the keys
   scaled by ``key_multiplier`` before it; 20 query heads over 4 K/V heads;
-- **one block-table bucket** (``min_table_bucket``: the whole table) and **one
-  sequence bucket** (``min_sequence_bucket``: ``max_ragged_sequence_count``):
+- **one block-table bucket** (``one_table_bucket``: the whole table) and **one
+  sequence bucket** (``one_sequence_bucket``: ``max_ragged_sequence_count``):
   the mixers' state is a large share of a step only at many live sequences,
   and a forward program a sequence bucket would be four times the programs of
   a model this deep to compile; a step of few sequences pays for the padding
@@ -32,12 +32,13 @@ from deepspeed_tpu.inference.v2.model_implementations.llama_v2 import _rms, _roo
 from deepspeed_tpu.inference.v2.model_implementations.mamba2_base import (Mamba2Model,
                                                                           Mamba2Shape,
                                                                           scaled_dot)
-from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import _pow2_pad, padded_sequences
 from deepspeed_tpu.models.falcon_h1 import FalconH1Config
 from deepspeed_tpu.models.llama import rotary_embedding
 
 
 class FalconH1V2Model(Mamba2Model):
+    one_table_bucket = True
+    one_sequence_bucket = True
 
     def __init__(self, params, config: FalconH1Config, engine_config, state_manager=None):
         super().__init__(params, config, engine_config, state_manager)
@@ -55,39 +56,8 @@ class FalconH1V2Model(Mamba2Model):
 
     # ----------------------------------------------------------- properties --
     @property
-    def num_layers(self):
-        return self._config.num_hidden_layers
-
-    @property
-    def num_heads(self):
-        return self._config.num_attention_heads
-
-    @property
-    def num_kv_heads(self):
-        return self._config.num_key_value_heads
-
-    @property
-    def head_dim(self):
-        return self._config.head_dim
-
-    @property
-    def vocab_size(self):
-        return self._config.vocab_size
-
-    @property
     def mamba2(self):
         return self._mamba2
-
-    @property
-    def min_table_bucket(self):
-        """The whole table (``max_context``), a power of two of blocks."""
-        sm = self._engine_config.state_manager
-        return _pow2_pad(-(-sm.max_context // self._engine_config.kv_block_size))
-
-    @property
-    def min_sequence_bucket(self):
-        """The whole ``max_ragged_sequence_count``: one sequence bucket."""
-        return padded_sequences(self._engine_config.state_manager.max_ragged_sequence_count)
 
     # --------------------------------------------------------------- phases --
     @jax.named_scope("embed")

@@ -15,25 +15,12 @@ Every phase runs under a ``jax.named_scope`` (``embed``, ``attn``, ``mlp``,
 layer): metadata only, carried into the device trace with each operation.
 """
 
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2.model_implementations.transformer_base import DSTransformerModelBase
+from deepspeed_tpu.inference.v2.model_implementations.transformer_base import (
+    DSTransformerModelBase, _rms, _root)
 from deepspeed_tpu.models.llama import LlamaConfig, rotary_embedding
-
-
-def _rms(x, w, eps):
-    x32 = x.astype(jnp.float32)
-    normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (normed * w).astype(x.dtype)
-
-
-def _root(params):
-    """Normalize the two training-tree layouts: LlamaForCausalLM nests everything
-    under "model"; MixtralForCausalLM's tree is flat."""
-    return params["model"] if "model" in params else params
 
 
 def _rotate_half(x, cos, sin):
@@ -56,6 +43,29 @@ def _rotary_at(x, pos, cos_tab, sin_tab):
     return _rotate_half(x, cos_tab[pos][:, None, :], sin_tab[pos][:, None, :])
 
 
+class PositionFreeGQA:
+    """The softmax layers of the hybrids whose other mixers carry the order
+    (``nemotron_h_v2.py``, ``solar_open2_v2.py``): grouped-query, causal, no
+    position encoding, no norm or residual of its own (the caller's)."""
+
+    @jax.named_scope("attn")
+    def _attn_phase(self, ap, ai, h, kv, attn_fn):
+        """Softmax layer ``ai`` (its ordinal: its layer of the K/V array) over
+        the normed rows ``h``; where the tree has a ``gate_proj``, the heads'
+        output gated from the layer's input."""
+        T = h.shape[0]
+        H, KVH, D = self.num_heads, self.num_kv_heads, self.head_dim
+        q = (h @ ap["q_proj"]["kernel"].astype(h.dtype)).reshape(T, H, D)
+        k = (h @ ap["k_proj"]["kernel"].astype(h.dtype)).reshape(T, KVH, D)
+        v = (h @ ap["v_proj"]["kernel"].astype(h.dtype)).reshape(T, KVH, D)
+        out, kv = attn_fn(q, k, v, kv, ai)
+        out = out.reshape(T, H * D).astype(h.dtype)
+        if "gate_proj" in ap:
+            with jax.named_scope("gate"):
+                out = out * jax.nn.sigmoid(h @ ap["gate_proj"]["kernel"].astype(h.dtype))
+        return out @ ap["o_proj"]["kernel"].astype(h.dtype), kv
+
+
 class LlamaV2Model(DSTransformerModelBase):
 
     def __init__(self, params, config: LlamaConfig, engine_config, state_manager=None):
@@ -72,40 +82,13 @@ class LlamaV2Model(DSTransformerModelBase):
         return _rotary_at(x, pos, *self._rope)
 
     @property
-    def num_layers(self):
-        return self._config.num_hidden_layers
-
-    @property
-    def num_heads(self):
-        return self._config.num_attention_heads
-
-    @property
-    def num_kv_heads(self):
-        return self._config.num_key_value_heads
-
-    @property
     def head_dim(self):
         """The config's own ``head_dim`` where it has one (heads x head_dim
         need not be ``hidden_size``), else ``hidden_size / heads``."""
         return (getattr(self._config, "head_dim", None)
                 or self._config.hidden_size // self._config.num_attention_heads)
 
-    @property
-    def vocab_size(self):
-        return self._config.vocab_size
-
     # --------------------------------------------------------------- phases --
-    @jax.named_scope("embed")
-    def embed(self, params, ids):
-        emb = _root(params)["embed_tokens"]["embedding"]
-        return emb[ids].astype(self._config.dtype)
-
-    @jax.named_scope("unembed")
-    def unembed(self, params, x):
-        r = _root(params)
-        x = _rms(x, r["norm"]["weight"], self._config.rms_norm_eps)
-        return x @ r["lm_head"]["kernel"].astype(x.dtype)
-
     @jax.named_scope("attn")
     def _attn_phase(self, params, li, x, cache, attn_fn, batch):
         cfg = self._config

@@ -3,18 +3,15 @@ mixer a block, some of them Mamba-2; ``falcon_h1_v2.py``: a Mamba-2 mixer
 beside attention in every layer): the per-sequence state group they ask of the
 engine, the mixer over a step's rows, and the step's counters.
 
-- **a per-sequence state group** (``sequence_state``): a Mamba-2 mixer keeps,
-  for each live sequence and whatever its length, a float32 state ``[heads,
-  head_dim, state]`` and the last ``conv_kernel - 1`` rows of its convolution's
-  input. Two pools ``[Mamba-2 mixers, slots, ...]`` ride beside the K/V array
-  in the one cache pytree (``ragged/kv_cache.py``); a sequence's slot is a
-  column of ``seq_meta``. Both slots are stated in whole (sublane, lane) tiles
-  where the widths allow (the state as it is; the tails folded,
-  ``ssm.conv_slot``), so that a step moves its own rows by a kernel over the
-  pool where it lies and the compiler adds no pass over the pool. A slot's
-  content counts from the sequence's first token: a sequence with nothing seen
-  reads zeros whatever the slot held. Padding rows point one past the last
-  slot and their writes drop;
+- **a per-sequence state group** (``sequence_state``; ``sequence_slots.py``
+  has what every such group shares): a Mamba-2 mixer keeps, for each live
+  sequence and whatever its length, a float32 state ``[heads, head_dim,
+  state]`` and the last ``conv_kernel - 1`` rows of its convolution's input,
+  in two pools ``[Mamba-2 mixers, slots, ...]``. Both slots are stated in
+  whole (sublane, lane) tiles where the widths allow (the state as it is; the
+  tails folded, ``ssm.conv_slot``), so that a step moves its own rows by a
+  kernel over the pool where it lies and the compiler adds no pass over the
+  pool;
 - **two forms of the scan** (``modules/ssm.py``), both IN the pool: a ``put``
   step scans by segment (``ssm.scan_in_place``), each sequence's rows
   starting from its slot's state and leaving its final state there — the
@@ -40,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.inference.v2.model_implementations.sequence_slots import SequenceSlots
 from deepspeed_tpu.inference.v2.model_implementations.transformer_base import \
     DSTransformerModelBase
 from deepspeed_tpu.inference.v2.modules import ssm
@@ -78,7 +76,7 @@ class Mamba2Shape(NamedTuple):
         return self.d_inner + 2 * self.groups * self.state
 
 
-class Mamba2Model(DSTransformerModelBase):
+class Mamba2Model(SequenceSlots, DSTransformerModelBase):
     """A subclass states :attr:`mamba2` and calls :meth:`_mamba_phase` where a
     layer has such a mixer, with the mixer's ordinal among them."""
 
@@ -91,9 +89,7 @@ class Mamba2Model(DSTransformerModelBase):
         w = self.mamba2
         return (SequenceStateSpec(name="ssm", layers=w.mixers, dtype="float32",
                                   shape=(w.heads, w.head_dim, w.state)),
-                SequenceStateSpec(name="conv", layers=w.mixers,
-                                  dtype=np.dtype(self._config.dtype).name,
-                                  shape=ssm.conv_slot(w.conv_kernel - 1, w.conv_dim)))
+                self._conv_slot_spec(w.mixers, w.conv_kernel, w.conv_dim))
 
     def batch_counts(self, ragged_batch, steps=None):
         """Beside the attention kernels' passes: ``ssm_tokens``, rows that went
@@ -124,8 +120,7 @@ class Mamba2Model(DSTransformerModelBase):
         kv = self._state_manager.kv_cache
         counts.update(ssm_tokens=steps * int(batch["n_tokens"]) * w.mixers,
                       ssm_segments=steps * int(batch["n_seqs"]) * w.mixers,
-                      ssm_slots_live=kv.num_slots - (kv.free_slots or 0),
-                      ssm_slots_total=kv.num_slots)
+                      **self._slot_counts())
         stored = ssm.whole_slots(kv.cache[1])
         in_place = ssm.in_place(kv.cache[1], w.groups)
         counts["ssm_segments_in_place"] = counts["ssm_segments"] if stored else 0
@@ -135,20 +130,6 @@ class Mamba2Model(DSTransformerModelBase):
         if chunk:
             counts["ssm_rows_in_place"] = counts["ssm_tokens"] if in_place else 0
         return counts
-
-    def _in_the_pool(self, update, pool, mi, *rows):
-        """``update(pool, mi, *rows)``: ``ssm.step_in_place`` or
-        ``ssm.scan_in_place`` on mixer ``mi`` of the state pool, ``ssm.load`` or
-        ``ssm.store_in_place`` on the conv pool's. The SPMD
-        partitioner cannot split a Mosaic kernel: on a mesh every device runs
-        it over the pool it holds whole (``kv_cache._pool_sharding``), as
-        ``_paged_attention`` runs its kernel."""
-        placed = None if self._state_manager is None else self._state_manager.kv_cache.sharding
-        if placed is None or placed.mesh.size == 1:
-            return update(pool, mi, *rows)
-        from jax.sharding import PartitionSpec as P
-        return jax.shard_map(update, mesh=placed.mesh, in_specs=P(), out_specs=P(),
-                             check_vma=False)(pool, jnp.int32(mi), *rows)
 
     @jax.named_scope("ssm")
     def _mamba_phase(self, mp, mi, h, pools, batch):
@@ -170,16 +151,7 @@ class Mamba2Model(DSTransformerModelBase):
             z, xbc, dt = jnp.split(zxbcdt, [D, D + w.conv_dim], axis=-1)
             dt = jax.nn.softplus(dt.astype(jnp.float32) + mp["dt_bias"][None, :])
         A = -jnp.exp(mp["A_log"].astype(jnp.float32))
-        slot = batch["state_slot"]
-        # a sequence with nothing seen starts from zero whatever its slot held
-        started = batch["seq_valid"] & (batch["seq_seen"] > 0)
-        one_token = batch["one_token_rows"]
-        if one_token:  # decode_loop: row t is sequence token_seq[t]'s one token
-            of = batch["token_seq"]
-            slot, started = slot[of], started[of]
-            live = batch["token_valid"]
-        else:  # put: a sequence without tokens in the step keeps its state
-            live = batch["seq_valid"] & (batch["seq_ntok"] > 0)
+        slot, started, live, one_token = self._slot_rows(batch)
 
         with jax.named_scope("conv"):
             # the step's own tails out of their slots and back (modules/ssm.py: one

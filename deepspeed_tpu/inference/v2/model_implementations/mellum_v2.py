@@ -28,9 +28,8 @@ class LayerTypedMoEModel(MixtralV2Model):
         # LlamaV2Model's own constructor: MixtralV2Model's converts a MixtralConfig
         LlamaV2Model.__init__(self, params, config, engine_config, state_manager)
         self._moe_config = config
-        self._moes = self._build_moes(engine_config, sparse_layers, config.num_experts,
-                                      config.num_experts_per_tok, **router)
-        self._expert_width = config.moe_intermediate_size
+        self._build_moes(range(sparse_layers), config.num_experts, config.num_experts_per_tok,
+                         config.moe_intermediate_size, **router)
 
     def _build_rope(self, max_context):
         """No table: a layer type's rotary parameters. The angles are computed

@@ -21,12 +21,13 @@ the family adds is said in three properties and lives elsewhere:
 """
 
 from deepspeed_tpu.inference.v2.model_implementations.mellum_v2 import LayerTypedMoEModel
-from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import _pow2_pad, padded_sequences
 from deepspeed_tpu.models.sdar_moe import SdarMoeConfig
 from deepspeed_tpu.ops.pallas.paged_attention import TQ
 
 
 class SdarMoeV2Model(LayerTypedMoEModel):
+    one_table_bucket = True
+    one_sequence_bucket = True
 
     def __init__(self, params, config: SdarMoeConfig, engine_config, state_manager=None):
         super().__init__(params, config, engine_config, state_manager,
@@ -40,17 +41,6 @@ class SdarMoeV2Model(LayerTypedMoEModel):
     @property
     def attention_window(self):
         return 0
-
-    @property
-    def min_table_bucket(self):
-        """The whole table (``max_context``), a power of two of blocks."""
-        sm = self._engine_config.state_manager
-        return _pow2_pad(-(-sm.max_context // self._engine_config.kv_block_size))
-
-    @property
-    def min_sequence_bucket(self):
-        """The whole ``max_ragged_sequence_count``: one sequence bucket."""
-        return padded_sequences(self._engine_config.state_manager.max_ragged_sequence_count)
 
     @property
     def min_token_bucket(self):

@@ -15,21 +15,47 @@ sequence's final token is unembedded — reference ``logits_gather.cu`` semantic
 """
 
 from functools import partial
-from typing import Optional, Tuple
+from typing import Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference.v2 import sampling
 from deepspeed_tpu.inference.v2.ragged.manager_configs import KVCacheConfig
-from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import sequence_buckets, token_buckets
+from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (_pow2_pad, padded_sequences,
+                                                              padded_tokens, sequence_buckets,
+                                                              token_buckets)
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import DSSequenceDescriptor
 from deepspeed_tpu.telemetry import compile_watch
 
 
+def _rms(x, w, eps):
+    x32 = x.astype(jnp.float32)
+    normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (normed * w).astype(x.dtype)
+
+
+def _root(params):
+    """Normalize the two training-tree layouts: LlamaForCausalLM nests everything
+    under "model"; MixtralForCausalLM's tree is flat."""
+    return params["model"] if "model" in params else params
+
+
 class DSTransformerModelBase:
-    """Subclasses define: num_layers, num_kv_heads, head_dim, vocab_size,
-    ``embed(params, ids)``, ``layer_forward(params, li, x, attn_fn, batch)`` and
-    ``unembed(params, x)``."""
+    """Subclasses define ``layer_forward(params, li, x, attn_fn, batch)``, and
+    state where they differ from the defaults here: the shape properties (the
+    config's fields of the HF names), ``embed`` / ``unembed`` (the
+    ``embed_tokens`` / final norm / ``lm_head`` tree; :attr:`final_norm` names
+    the norm), the smallest buckets (:attr:`one_table_bucket`,
+    :attr:`one_sequence_bucket`)."""
+
+    # the final norm's name in the tree and its epsilon's in the config
+    final_norm: Tuple[str, str] = ("norm", "rms_norm_eps")
+    # one program for every block table / every count of sequences: the whole table
+    # (``max_context``) / the whole ``max_ragged_sequence_count`` is the one bucket
+    one_table_bucket: bool = False
+    one_sequence_bucket: bool = False
 
     def __init__(self, params, config, engine_config, state_manager=None):
         wq = getattr(engine_config, "quantization", None)
@@ -64,7 +90,7 @@ class DSTransformerModelBase:
 
     @property
     def num_layers(self) -> int:
-        raise NotImplementedError
+        return self._config.num_hidden_layers
 
     @property
     def num_kv_layers(self) -> int:
@@ -75,19 +101,19 @@ class DSTransformerModelBase:
 
     @property
     def num_kv_heads(self) -> int:
-        raise NotImplementedError
+        return self._config.num_key_value_heads
 
     @property
     def num_heads(self) -> int:
-        raise NotImplementedError
+        return self._config.num_attention_heads
 
     @property
     def head_dim(self) -> int:
-        raise NotImplementedError
+        return self._config.head_dim
 
     @property
     def vocab_size(self) -> int:
-        raise NotImplementedError
+        return self._config.vocab_size
 
     @property
     def max_context(self) -> int:
@@ -95,7 +121,6 @@ class DSTransformerModelBase:
 
     # ------------------------------------------------------------- kv sizing --
     def kv_cache_config(self) -> KVCacheConfig:
-        import jax.numpy as jnp
         sm = self._engine_config.state_manager
         model_dtype = getattr(self._config, "dtype", jnp.bfloat16)
         # normalize through np.dtype: keying on the jnp scalar OBJECTS would
@@ -138,15 +163,22 @@ class DSTransformerModelBase:
     @property
     def min_table_bucket(self) -> int:
         """The smallest block-table bucket (``KVCacheConfig.min_table_bucket``);
-        a model with one program for every table up to some length says so."""
-        return 4
+        a model with one program for every table up to some length says so:
+        :attr:`one_table_bucket`, the whole table, a power of two of blocks."""
+        if not self.one_table_bucket:
+            return 4
+        sm = self._engine_config.state_manager
+        return _pow2_pad(-(-sm.max_context // self._engine_config.kv_block_size))
 
     @property
     def min_sequence_bucket(self) -> int:
         """The smallest sequence bucket (``KVCacheConfig.min_sequence_bucket``),
         which the token bucket starts at too; a model with one program for
-        every batch up to some count of sequences says so."""
-        return 8
+        every batch up to some count of sequences says so
+        (:attr:`one_sequence_bucket`)."""
+        if not self.one_sequence_bucket:
+            return 8
+        return padded_sequences(self._engine_config.state_manager.max_ragged_sequence_count)
 
     @property
     def attention_block(self) -> int:
@@ -307,7 +339,6 @@ class DSTransformerModelBase:
         sequence bucket: a few operations each. With ``chunk_steps`` > 1,
         also the program that takes the last row of a ``decode_loop`` chunk of
         that many steps (``sampling.last_row``), a sequence bucket each."""
-        import jax
         from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
         pool = self._state_manager.kv_cache.sharding
         placed = (NamedSharding(pool.mesh, PartitionSpec()) if pool is not None
@@ -348,7 +379,6 @@ class DSTransformerModelBase:
         never ran is analysis, not a cache entry."""
         entry = self._programs.get((kind, key))
         if entry is None:
-            import jax
             _, donated, impl, static = self._PROGRAM_KINDS[kind]
             impl = getattr(self, impl)
             if static:
@@ -385,9 +415,6 @@ class DSTransformerModelBase:
         the bucket to the compile watch, and an analysis-only lowering must
         not pollute the bucket-churn recompile telemetry."""
         if bucket is None:
-            from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (_pow2_pad,
-                                                                          padded_sequences,
-                                                                          padded_tokens)
             least = self.min_sequence_bucket
             bucket = (padded_tokens(1, least), padded_sequences(1, least),
                       _pow2_pad(1, self.min_table_bucket))
@@ -469,9 +496,6 @@ class DSTransformerModelBase:
         return (self._bucket_of(batch), int(n_steps), False)
 
     def _decode_loop_impl(self, params, cache, batch, *, n_steps):
-        import jax
-        import jax.numpy as jnp
-
         tok_meta = jnp.asarray(batch["tok_meta"])
         seq_meta = jnp.asarray(batch["seq_meta"])
 
@@ -559,8 +583,6 @@ class DSTransformerModelBase:
         no logits of it). A sequence whose first block came part given has
         nothing left to take in its last denoise forwards: the program's shape
         is the batch's, not a sequence's."""
-        import jax
-        import jax.numpy as jnp
 
         cfg = self._config
         B, n_denoise, mask_id = self.attention_block, cfg.denoising_steps, cfg.mask_token_id
@@ -666,7 +688,6 @@ class DSTransformerModelBase:
         the grid's tiles run in order, and the XLA arm scatters every row before
         it gathers: the second block's queries see the first block's K/V of
         this same forward."""
-        import jax.numpy as jnp
         xp = np if isinstance(tok_meta, np.ndarray) else jnp
         T, S = tok_meta.shape[1], seq_meta.shape[0]
         tok_next, seq_next = self._next_block(tok_meta, seq_meta)
@@ -739,7 +760,6 @@ class DSTransformerModelBase:
         the logits are None), or a ``slice`` of the batch's rows (a block
         loop's fused forward: the rows that still want a token, behind the
         rows that are only committed)."""
-        import jax.numpy as jnp
         from deepspeed_tpu.inference.v2.quantization import dequantize_tree
 
         params = dequantize_tree(params)  # no-op without quantized leaves
@@ -800,7 +820,6 @@ class DSTransformerModelBase:
         node_index``) and the model sees the LOGICAL position ``seen + depth``
         (rotary embeddings encode tree depth, not slot); the attention closure
         keeps the slots for the cache scatter."""
-        import jax.numpy as jnp
         from deepspeed_tpu.inference.v2.quantization import dequantize_tree
 
         params = dequantize_tree(params)
@@ -894,7 +913,6 @@ class DSTransformerModelBase:
         q: [T, H, D]; k_new/v_new: [T, KVH, D];
         cache: [L / groups, 2, num_blocks, KVH, bs, D]. Window, block table
         and cache layer are layer ``li``'s own (:meth:`_kv_view`)."""
-        import jax
 
         token_pos = batch["token_pos"]
         window = self.attention_window_of(li)
@@ -946,8 +964,6 @@ class DSTransformerModelBase:
     def _kv_write(cache, li, k_new, v_new, slot_pos, batch, table):
         """Scatter the new K/V into cache layer ``li``'s blocks at ``slot_pos``
         (``table``: that layer's block table)."""
-        import jax
-        import jax.numpy as jnp
 
         with jax.named_scope("kv_write"):
             MB = table.shape[1]
@@ -967,7 +983,6 @@ class DSTransformerModelBase:
         through its block ``table`` (released entries read block 0: the
         caller masks them): two ``[S, MB * bs, H, D]`` arrays of ``dtype``,
         the KV heads repeated to the query heads'."""
-        import jax.numpy as jnp
 
         S, MB = table.shape
         KVH, D = self.num_kv_heads, self.head_dim
@@ -990,8 +1005,6 @@ class DSTransformerModelBase:
         ``table`` and attend densely under its ``window``, or with ``block`` >
         0 up to the end of each query's block. q: [T, H, D]; returns
         [T, H, D]."""
-        import jax
-        import jax.numpy as jnp
 
         S = table.shape[0]
         H, D = self.num_heads, self.head_dim
@@ -1047,8 +1060,6 @@ class DSTransformerModelBase:
 
         Always the XLA fallback path: the Pallas paged kernel assumes a
         contiguous causal feed and cannot express the ancestor view."""
-        import jax
-        import jax.numpy as jnp
 
         T = q.shape[0]
         window = self.attention_window_of(li)
@@ -1139,7 +1150,6 @@ class DSTransformerModelBase:
         The gather reads the pre-copy cache, so overlapping src/dst pairs are
         safe. Jitted per pow2-padded copy count; padded pairs scatter to an
         out-of-range block and drop."""
-        from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import _pow2_pad
 
         self._state_manager.kv_cache.refuse("compact_kv")
         src = np.asarray(src_slots, np.int64).reshape(-1)
@@ -1178,15 +1188,19 @@ class DSTransformerModelBase:
 
     # ------------------------------------------------------------- serialize --
     def flattened_params(self):
-        import jax
         return jax.tree.leaves(self._params)
 
     # Subclass hooks -----------------------------------------------------------
+    @jax.named_scope("embed")
     def embed(self, params, ids):
-        raise NotImplementedError
+        return _root(params)["embed_tokens"]["embedding"][ids].astype(self._config.dtype)
 
     def layer_forward(self, params, li, x, cache, attn_fn, batch):
         raise NotImplementedError
 
+    @jax.named_scope("unembed")
     def unembed(self, params, x):
-        raise NotImplementedError
+        r = _root(params)
+        norm, eps = self.final_norm
+        x = _rms(x, r[norm]["weight"], getattr(self._config, eps))
+        return x @ r["lm_head"]["kernel"].astype(x.dtype)

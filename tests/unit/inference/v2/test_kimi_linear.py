@@ -307,7 +307,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer(model, engine):
     lp = mine["layers_1"]
     x = jnp.asarray(np.random.default_rng(7).normal(size=(8, cfg.hidden_size)), jnp.float32)
     batch = {"token_valid": jnp.ones(8, bool)}
-    got = np.asarray(jax.jit(lambda lp, x: served._ffn_phase({"layers_1": lp}, 1, x, batch) - x)(lp, x))
+    got = np.asarray(jax.jit(lambda lp, x: served._ffn_phase(lp, 1, x, batch) - x)(lp, x))
     with jax.default_matmul_precision("highest"):
         h = reference.rms_norm(x, lp["post_attention_layernorm"]["weight"], cfg.rms_norm_eps)
         want, _ = reference.experts(h, lp["mlp"], first_held=4, **routed)
