@@ -461,13 +461,15 @@ class InferenceEngineV2:
 
     def moe_counts(self, banks) -> dict:
         """A grouped program's count of routed work, fetched, as span args:
-        ``moe_banks`` summed over the expert layers (and a chunk's steps); a
-        model whose layers hold a share of their experts counts more than one
-        thing (``model.moe_count_names``, the array's last axis)."""
-        names, counts = self._model.moe_count_names, np.asarray(banks)
-        if len(names) == 1:
-            return {names[0]: int(counts.sum())}
-        return {name: int(counts[..., i].sum()) for i, name in enumerate(names)}
+        ``moe_banks`` and ``moe_visits`` (the grouped kernel's (expert, row
+        tile) visits: ``(moe_visits - moe_banks) / moe_visits`` of them are an
+        expert's further row tile) summed over the expert layers (and a
+        chunk's steps); a model whose layers hold a share of their experts
+        counts what landed here too (``model.moe_count_names``, the array's
+        last axis)."""
+        counts = np.asarray(banks)
+        return {name: int(counts[..., i].sum())
+                for i, name in enumerate(self._model.moe_count_names)}
 
     def _put(self, batch_uids, batch_tokens, do_checks, draw, prev=None):
         batch_uids = list(batch_uids)

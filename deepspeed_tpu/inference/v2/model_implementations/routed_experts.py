@@ -38,7 +38,7 @@ class RoutedExperts:
             for li in layer_ids]
         self._expert_width, self._dense_layers = width, dense_layers
         if share:
-            self.moe_count_names = ("moe_banks", "moe_assignments_local")
+            self.moe_count_names = ("moe_banks", "moe_assignments_local", "moe_visits")
 
     def _expert_parallel(self):
         """The devices a layer's experts are spread over: 1, unless the model

@@ -76,8 +76,9 @@ class DSTransformerModelBase:
         # (kind, key) -> [the jit, what a step calls: the jit or its
         # compile-watch wrapper; None until the program runs] (``_program``)
         self._programs = {}
-        # the newest forward program's count of expert banks touched, int32
-        # [expert layers] ON THE DEVICE (a bucket on the grouped path), else None
+        # the newest forward program's counts of routed work (``moe_count_names``),
+        # int32 [expert layers, counts] ON THE DEVICE (a bucket on the grouped
+        # path), else None
         self.last_moe_banks = None
         self._group_windows = None
         if state_manager is not None:
@@ -195,9 +196,9 @@ class DSTransformerModelBase:
         otherwise (one whose step feeds a block a sequence)."""
         return self.min_sequence_bucket
 
-    # what a forward program's count of routed work holds, last axis of the
-    # device array it returns beside its result where there is more than one
-    moe_count_names: Tuple[str, ...] = ("moe_banks", )
+    # what a forward program's count of routed work holds: the last axis of the
+    # device array it returns beside its result (``RaggedMoE``'s ``banks_out``)
+    moe_count_names: Tuple[str, ...] = ("moe_banks", "moe_visits")
 
     def batch_counts(self, ragged_batch, steps: int = 1) -> dict:
         """Work counters of a step that depend on the batch's positions (the
@@ -471,8 +472,9 @@ class DSTransformerModelBase:
         Returns ``(tokens, banks)`` as the program left them ON THE DEVICE,
         still being computed (the caller's fetch is the wait): generated tokens
         ``[n_steps, S_bucket]``, column i sequence-slot i, rows steps; and the
-        expert banks each step touched in each expert layer, int32 ``[n_steps,
-        expert layers]``, where the bucket routes by sorting, else None. The
+        expert banks each step touched in each expert layer beside the
+        program's other counts (``moe_count_names``), int32 ``[n_steps, expert
+        layers, counts]``, where the bucket routes by sorting, else None. The
         cache is updated in place with the n_steps inserted tokens (the last
         generated token is not yet inserted, matching the host-loop semantics).
 
@@ -766,7 +768,8 @@ class DSTransformerModelBase:
         batch = self._unpack_batch(batch)
         # an expert layer that routes by sorting appends the banks it touched
         # (``RaggedMoE``'s ``banks_out``); where any did, the program returns
-        # them, int32 [expert layers], as one more output
+        # them with the kernel's visits (``moe_count_names``), int32 [expert
+        # layers, counts], as one more output
         banks = batch["moe_banks"] = []
         x = self.embed(params, batch["input_ids"])
         attn = partial(self._paged_attention, batch=batch)

@@ -37,7 +37,7 @@ gather of their rows, one grouped matmul a projection —
 ``ops/pallas/grouped_matmul.py`` — and the rows summed back to their tokens;
 dropless whatever the skew, no capacity, and no bank read that has no row; how
 many banks had a row is data on the device, and a caller that passes
-``banks_out`` is handed the count).
+``banks_out`` is handed the count, beside the kernel's count of visits).
 The grouped path is taken where the masks would cost a real share of the
 experts (many narrow experts, a full chunk of tokens), and where the bucket's
 assignments cannot touch more than half of the banks (a decode step's 8 rows
@@ -387,14 +387,18 @@ class RaggedMoE:
         Every assignment has a row whatever the skew: dropless by construction,
         ``capacity_factor`` has no part in it. An invalid token's assignments
         sort behind every expert's and belong to no group. ``banks_out``, a
-        list, is appended the int32 count of experts that have a row: the banks
-        the two GEMMs read (an invalid token's and the padding's rows count for
-        none). A layer that holds a SHARE of its experts (``held``) sorts the
+        list, is appended int32 ``[banks, visits]``: the experts that have a
+        row, whose banks the two GEMMs read (an invalid token's and the
+        padding's rows count for none), and the (expert, row tile) visits the
+        kernel's schedule makes of them (``grouped_matmul.visit_count``: what
+        passes the banks is the visits that are an expert's further row tile).
+        A layer that holds a SHARE of its experts (``held``) sorts the
         assignments of the others' experts behind its own, as an invalid
-        token's, and appends ``[banks, assignments]`` that landed here."""
+        token's, and appends ``[banks, assignments, visits]`` that landed
+        here."""
         import jax
         import jax.numpy as jnp
-        from deepspeed_tpu.ops.pallas.grouped_matmul import padded_rows
+        from deepspeed_tpu.ops.pallas.grouped_matmul import padded_rows, visit_count
 
         T, M = h.shape
         E, k = self.experts_here, self.top_k
@@ -410,11 +414,10 @@ class RaggedMoE:
             # stable: an expert's rows stay in token order
             e_sorted, order = jax.lax.sort((e_flat, slots), num_keys=1, is_stable=True)
             group_sizes = (e_flat[:, None] == jnp.arange(E)[None, :]).sum(0, dtype=jnp.int32)
-            if banks_out is not None and self.held is None:
-                banks_out.append((group_sizes > 0).sum(dtype=jnp.int32))
-            elif banks_out is not None:
-                banks_out.append(jnp.stack([(group_sizes > 0).sum(dtype=jnp.int32),
-                                            group_sizes.sum(dtype=jnp.int32)]))
+            if banks_out is not None:
+                here = [] if self.held is None else [group_sizes.sum(dtype=jnp.int32)]
+                banks_out.append(jnp.stack([(group_sizes > 0).sum(dtype=jnp.int32), *here,
+                                            visit_count(group_sizes)]))
             # where each assignment's row went: the inverse permutation
             _, back = jax.lax.sort((order, slots), num_keys=1)
         with jax.named_scope("dispatch"):

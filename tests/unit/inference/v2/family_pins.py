@@ -5,7 +5,10 @@ the scope paths in their lowered text (a jaxpr's text carries no
 span args the metrics read (``dispatch_counts`` / ``moe_path`` /
 ``batch_counts``) of one fixed ``put`` of mixed lengths and one chunk of 8
 positions. ``test_family_pins.py`` holds each family to ``family_pins.json``,
-which PR 59 recorded on its PARENT tree before it moved any code::
+which PR 59 recorded on its PARENT tree before it moved any code (PR 60
+re-recorded it: ``moe_count_names`` gained ``moe_visits`` in every family, and the
+``put`` / ``chunk`` hashes of the four families that hold a share of their experts
+moved with the one more count their programs return; every other hash held)::
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
         python -m tests.unit.inference.v2.family_pins --record

@@ -706,13 +706,16 @@ def test_float32_holds_what_the_loose_tolerance_lets_through(model, control):
 # ``test_one_group_programs.py`` says); the gather
 # arm's, the 8-token and the chunk's programs held. The two ``decode_loop``
 # programs were re-recorded in PR 46 with ``test_one_group_programs.py``'s four:
-# the loop lost its unused temperature and key, and nothing else.
+# the loop lost its unused temperature and key, and nothing else. The two
+# 128-token forwards were re-recorded in PR 60: the one difference is the
+# banks' output, now ``[expert layers, (banks, visits)]`` (the grouped
+# kernel's visits ride beside the banks); the four capacity programs hold.
 _MELLUM_PARENT = {
     "mellum.gather.forward.8x8x4": "98d1d447896ec022ef33d977031c4731e75ba00e9aae31b2e52c2ddfbc751fea",
-    "mellum.gather.forward.128x8x8": "baf95ba27f841e05ba61035349e3b77b1a59a50e1f29c3b903dcf1e648782f1a",
+    "mellum.gather.forward.128x8x8": "81830235be7d13a1bb8145af82ca61a87d51b0fb9ac87dff936588f8c9d30cde",
     "mellum.gather.decode_loop": "9c67c90e4a53015f5d646b9a49c696c15a20da16a633a0668829b71759c08121",
     "mellum.kernel.forward.8x8x4": "2857ae3f6a0f05a300e1c4d552b4455cb6ee85431770ab01a80eaea76e50f73b",
-    "mellum.kernel.forward.128x8x8": "153d5d263798e50d1acc0e2ae8d3fd2804e3734a436c8f9b6742ecaacfc53563",
+    "mellum.kernel.forward.128x8x8": "6676dd001e82bdc96885de9b4f7f55acaa52625a0226846adeec9ed52abe7642",
     "mellum.kernel.decode_loop": "ea7d90a737b8ecd9974fea2c7be90f64289de2a83ac6fe37c2a137a1da8ff349",
 }
 

@@ -33,7 +33,8 @@ from tests.unit.inference.v2.program_hashes import decode_loop_hash
 BLOCK = 16
 TOL = 1e-4
 # sha256 of the tiny model's traced decode_loop program (``program_hashes.decode_loop_hash``)
-DECODE_LOOP_HASH = "6d20ba616b99d56cd49323030adba83b60e6b8a0e2206b4f2f2a85df4d1a0199"
+# re-recorded in PR 60: the chunk's count of routed work holds the grouped kernel's visits too
+DECODE_LOOP_HASH = "a94aa031ae008c330e10cf9a48acf2f19f8707be00cad6c8195e97f6d254ebe9"
 # two periods of the tiny preset: KDA (dense), KDA, KDA, MLA, KDA, MLA
 LAYERS = dict(num_hidden_layers=6, kda_layers=(1, 2, 3, 5), full_attn_layers=(4, 6))
 
@@ -270,7 +271,8 @@ def test_the_counts_say_what_both_mixers_did(engine):
     assert chunk["latent_rows"] == chunk["latent_context_rows"] == (110 + 14) * 2
     counts = engine.model.dispatch_counts(8, 2, 4)
     assert counts["moe_path"] == "grouped" and counts["moe_assignments"] == 2 * 4 * 5 * 4
-    assert engine.model.moe_count_names == ("moe_banks", "moe_assignments_local")
+    assert engine.model.moe_count_names == ("moe_banks", "moe_assignments_local",
+                                             "moe_visits")
     engine.flush(0), engine.flush(1)
 
 

@@ -83,10 +83,19 @@ def test_the_devices_count_is_numpys_count_of_distinct_chosen_experts(
                                      capacity_factor=8.0, max_context=256)
     assert engine.model.moe_path(bucket) == "grouped"
     engine.put([0], [_ids(7, live)])
-    banks = np.asarray(engine.model.last_moe_banks)
+    counts = np.asarray(engine.model.last_moe_banks)
     jax.effects_barrier()
     layers = len(engine.model._moes)
-    assert banks.shape == (layers, ) and banks.dtype == np.int32
+    assert engine.model.moe_count_names == ("moe_banks", "moe_visits")
+    assert counts.shape == (layers, 2) and counts.dtype == np.int32
+    banks, visits = counts[:, 0], counts[:, 1]
+    # the kernel's (expert, row tile) visits (PR 60): a visit an expert that has
+    # rows and one more a row tile it runs on into; ``live * top_k`` rows lie
+    # in so many row tiles, and each tile boundary adds at most one visit
+    tiles = -(-live * engine.model._moes[0].top_k // 128)
+    assert (visits >= banks).all() and (visits <= banks + tiles - 1).all()
+    assert engine.moe_counts(counts) == {"moe_banks": int(banks.sum()),
+                                         "moe_visits": int(visits.sum())}
     for layer in range(layers):
         (topk_e, ) = chosen[layer]
         assert topk_e.shape[0] == bucket
@@ -143,14 +152,18 @@ def test_a_grouped_chunk_returns_its_banks_a_step_a_layer_and_the_span_sums_them
     assert tokens.shape == (n_seqs, 4)
     (dev_tokens, dev_banks), = handed
     assert isinstance(dev_tokens, jax.Array) and isinstance(dev_banks, jax.Array)
-    banks = np.asarray(dev_banks)
-    assert banks.shape == (4, 4) and banks.dtype == np.int32  # [n_steps, expert layers]
+    counts = np.asarray(dev_banks)
+    # [n_steps, expert layers, (banks, visits)]; one row tile: a visit a bank
+    assert counts.shape == (4, 4, 2) and counts.dtype == np.int32
+    banks = counts[..., 0]
+    np.testing.assert_array_equal(counts[..., 1], banks)
     # a token picks an expert at most once: one live row touches exactly top-k
     # banks a layer-step, and the bucket's padding rows none
     assert (banks >= 4).all() and (banks <= 4 * n_seqs).all()
     (span, ) = _spans(session, "decode_loop", "inference")
     args = span["args"]
-    assert args["moe_banks"] == banks.sum() and isinstance(args["moe_banks"], int)
+    assert args["moe_banks"] == args["moe_visits"] == banks.sum()
+    assert isinstance(args["moe_banks"], int)
     assert args["moe_path"] == "grouped" and args["moe_assignments"] == n_seqs * 4 * 4 * 4
     # the span is the launch; the fetch wrote its own part when it happened
     assert 0 <= args["launch_us"] <= span["dur_us"] + 1 and args["fetch_us"] > 0
@@ -214,6 +227,9 @@ def test_the_fetch_of_a_grouped_put_step_carries_its_banks_with_its_assignments(
         assert args["moe_path"] == "grouped" and isinstance(args["moe_banks"], int)
         rows_live = args["moe_assignments"] // (4 * 4)  # top-k x expert layers
         assert 4 * 4 <= args["moe_banks"] <= args["moe_assignments"] and 1 <= rows_live <= 2
+        # the grouped kernel's visits ride beside the banks (PR 60); 8 rows are ONE
+        # row tile, so a visit a bank: none is an expert's further row tile
+        assert args["moe_visits"] == args["moe_banks"]
     # the dispatch span of a grouped step keeps what it had; a capacity step's says every bank
     assert all("moe_banks" not in s["args"] for s in grouped_puts)
     assert all(s["args"]["moe_banks"] == 64 * 4 for s in puts if s["args"]["moe_path"] == "capacity")
@@ -310,6 +326,7 @@ def test_the_counts_way_to_the_host_starts_at_the_launch_and_only_under_a_sessio
     if not traced:
         assert started == [] and handed is None
         return
-    assert started == [(4, ), (4, 4)]  # [expert layers], then [n_steps, expert layers]
+    # [expert layers, (banks, visits)], then [n_steps, expert layers, (banks, visits)]
+    assert started == [(4, 2), (4, 4, 2)]
     assert handed["moe_banks"] is engine.model.last_moe_banks and handed["moe_path"] == "grouped"
     assert handed["moe_assignments"] == 5 * 4 * 4  # live tokens x top-k x expert layers

@@ -17,8 +17,10 @@ from deepspeed_tpu.inference.v2.modules import heuristics
 from deepspeed_tpu.inference.v2.modules.heuristics import moe_implementation
 from deepspeed_tpu.inference.v2.modules.moe import RaggedMoE
 from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import token_buckets
-from deepspeed_tpu.ops.pallas.grouped_matmul import (column_tile, group_visits, grouped_matmul,
-                                                      padded_rows)
+from deepspeed_tpu.ops.pallas.grouped_matmul import (RING_DEPTH, ROW_TILE, column_tile,
+                                                      group_visits, grouped_matmul, padded_rows,
+                                                      ring_vmem_bytes, visit_count)
+from deepspeed_tpu.ops.pallas.paged_attention import SCOPED_VMEM_BYTES, VMEM_CEILING_BYTES
 from deepspeed_tpu.utils import groups
 
 
@@ -284,6 +286,25 @@ def test_the_bank_tile_keeps_the_contraction_whole_and_fits_the_budget():
     assert column_tile(4096, 28672, 2) == 512   # Mixtral's, were it ever taken
     assert column_tile(48, 32, 4) is None and column_tile(128, 192, 4) is None  # not whole lanes
     assert column_tile(32768, 128, 2) is None   # a contraction over one block
+    # the VMEM the kernel states (PR 60): a ring of THREE bank tiles, the row
+    # tile and the output tile twice, the float32 product and what it merges
+    # into; past the compiler's own 16 MiB less a quarter it asks for its own
+    MiB = 2**20
+    assert RING_DEPTH == 3
+    for K, N, out in ((2304, 1792, 2), (896, 2304, 4), (2048, 2048, 2), (1024, 2048, 4)):
+        tn = column_tile(K, N, 2)
+        held = ring_vmem_bytes(K, tn, 2, out)
+        assert K * tn * 2 <= 4 * MiB
+        assert held == 3 * K * tn * 2 + 2 * 128 * K * 2 + 2 * 128 * tn * (out + 4)
+    assert ring_vmem_bytes(2304, 896, 2, 2) == 14.25 * MiB > SCOPED_VMEM_BYTES * 3 // 4
+    assert ring_vmem_bytes(896, 1152, 2, 4) < SCOPED_VMEM_BYTES * 3 // 4  # asks for nothing
+    # the most a shape the kernel admits holds (a contraction as long as one
+    # block allows, or the widest column block of a tile of 4 MiB) with its
+    # quarter to spare stays under the most a kernel here asks for
+    assert column_tile(16384, 128, 2) == 128 and column_tile(16384 + 128, 128, 2) is None
+    for K, tn in ((16384, 128), (1792, 1152), (2048, 1024)):
+        assert K * tn * 2 <= 4 * MiB
+        assert ring_vmem_bytes(K, tn, 2, 4) * 5 // 4 <= 20.25 * MiB * 5 // 4 < VMEM_CEILING_BYTES
 
 
 @pytest.mark.parametrize("sizes", [(37, 0, 91, 128), (256, 0, 0, 0), (0, 0, 0, 3), (128, 128, 0, 0),
@@ -292,7 +313,8 @@ def test_the_visits_cover_every_groups_rows_once_group_major(sizes):
     """Every (group, row tile) pair in which the group has rows is a visit, in
     group-major order, and nothing else is."""
     R, G = 256, len(sizes)
-    offsets, groups, tiles, n = (np.asarray(a) for a in group_visits(jnp.asarray(sizes, jnp.int32), R))
+    offsets, groups, tiles, rank, touched, n = (
+        np.asarray(a) for a in group_visits(jnp.asarray(sizes, jnp.int32), R))
     starts = np.cumsum([0] + list(sizes))
     np.testing.assert_array_equal(offsets, starts)
     want = [(g, t) for g in range(G) for t in range(R // 128)
@@ -300,6 +322,42 @@ def test_the_visits_cover_every_groups_rows_once_group_major(sizes):
     assert groups.shape == tiles.shape == (R // 128 + G - 1, )
     assert list(zip(groups[:n].tolist(), tiles[:n].tolist())) == want
     assert (tiles >= 0).all() and (tiles < R // 128).all() and (groups < G).all()
+    # the bank stream (PR 60): the groups that have rows, in order, and each
+    # group's place in it; the count alone is what the step's span carries
+    with_rows = [g for g in range(G) if sizes[g]]
+    assert rank.shape == touched.shape == (G, ) and (touched < G).all()
+    np.testing.assert_array_equal(rank, np.cumsum(np.asarray(sizes) > 0))
+    assert touched[:len(with_rows)].tolist() == with_rows
+    assert int(visit_count(jnp.asarray(sizes, jnp.int32))) == int(n) == len(want)
+
+
+def _parent_kernel(rows, bank, group_sizes, out_dtype):
+    """The kernel as PR 59 had it, in interpret mode: the bank a ``BlockSpec``
+    of Pallas's own pipeline (one fetch in flight, one visit ahead), the same
+    visits, the same whole-tile product and merge. What PR 60's ring must
+    equal bit for bit."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    (R, K), N = rows.shape, bank.shape[2]
+    tn = column_tile(K, N, bank.dtype.itemsize)
+    offsets, groups, tiles, _, _, n_visits = group_visits(group_sizes, R)
+
+    def kernel(offsets, groups, tiles, rows_ref, bank_ref, out_ref):
+        group, tile = groups[pl.program_id(1)], tiles[pl.program_id(1)]
+        row = tile * ROW_TILE + jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
+        mine = (row >= offsets[group]) & (row < offsets[group + 1])
+        acc = jnp.dot(rows_ref[...], bank_ref[...], preferred_element_type=jnp.float32)
+        out_ref[...] = jnp.where(mine, acc, out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
+
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((R, N), out_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            in_specs=[pl.BlockSpec((ROW_TILE, K), lambda n, v, _, groups, tiles: (tiles[v], 0)),
+                      pl.BlockSpec((None, K, tn), lambda n, v, _, groups, tiles: (groups[v], 0, n))],
+            out_specs=pl.BlockSpec((ROW_TILE, tn), lambda n, v, _, groups, tiles: (tiles[v], n)),
+            grid=(N // tn, n_visits)),
+        interpret=True)(offsets, groups, tiles, rows, bank)
 
 
 def _decode_step_sizes(kind, G=128, assignments=64):
@@ -329,11 +387,14 @@ def test_a_decode_steps_schedule_visits_each_group_that_has_rows_once(kind):
     sizes = _decode_step_sizes(kind)
     R = padded_rows(64)
     assert R == 128
-    _, groups, tiles, n = (np.asarray(a) for a in group_visits(jnp.asarray(sizes), R))
+    _, groups, tiles, rank, stream, n = (np.asarray(a) for a in group_visits(jnp.asarray(sizes), R))
     touched = np.flatnonzero(sizes)
     assert int(n) == touched.size <= 64
     assert {"one-group": 1, "distinct": 64}.get(kind, int(n)) == int(n)
     np.testing.assert_array_equal(groups[:n], touched)
+    # one row tile: every visit is its group's first, the stream is the visits
+    np.testing.assert_array_equal(stream[:n], touched)
+    assert rank[-1] == n == int(visit_count(jnp.asarray(sizes)))
     assert not tiles.any() and groups.shape == (1 + 128 - 1, )
 
 
@@ -356,27 +417,54 @@ def test_the_kernel_at_a_decode_steps_shape_is_the_xla_arm_and_reads_no_untouche
         want = np.asarray(grouped_matmul(rows, bank, group_sizes, jnp.float32))
         poisoned = jnp.where((group_sizes > 0)[:, None, None], bank, jnp.nan)
         alone = np.asarray(grouped_matmul(rows, poisoned, group_sizes, jnp.float32, interpret=True))
+        parent = np.asarray(_parent_kernel(rows, bank, group_sizes, jnp.float32))
     np.testing.assert_allclose(got[:covered], want[:covered], atol=1e-5, rtol=0)
+    np.testing.assert_array_equal(got[:covered], parent[:covered])  # bit for bit (PR 60)
     assert np.isfinite(alone[:covered]).all()
     np.testing.assert_array_equal(alone[:covered], got[:covered])
 
 
-@pytest.mark.parametrize("sizes", [(37, 0, 91, 128), (256, 0, 0, 0), (10, 20, 30, 40)],
-                         ids=["ragged", "one-group", "short"])
-def test_the_pallas_arm_in_interpret_mode_is_the_xla_arm(sizes):
+def _mellum_chunk_sizes(kind):
+    """Group sizes of a 256-token chunk's 2,048 assignments over 64 groups."""
+    if kind == "even":  # 32 rows a group: no group straddles a row tile
+        return (32, ) * 64
+    rng = np.random.default_rng(60)
+    sizes = np.zeros(64, np.int32)  # a random router: each token the top-8 of a random order
+    np.add.at(sizes, np.concatenate([rng.permutation(64)[:8] for _ in range(256)]), 1)
+    return tuple(sizes.tolist())
+
+
+@pytest.mark.parametrize("sizes,R,N,further", [
+    ((37, 0, 91, 128), 256, 256, 0), ((256, 0, 0, 0), 256, 256, 1), ((10, 20, 30, 40), 256, 256, 0),
+    (_mellum_chunk_sizes("routed"), 2048, 1280, 15), (_mellum_chunk_sizes("even"), 2048, 1280, 0),
+    ((0, 0, 640, 0, 0), 640, 1280, 4), ((100, 0, 412, 0, 1, 127), 640, 1280, 3),
+    ((0, 0, 0, 1), 128, 256, 0)],
+    ids=["ragged", "one-group", "short", "mellum-chunk-routed", "mellum-chunk-32-a-group",
+         "one-group-five-tiles", "four-tiles-between-others", "one-row"])
+def test_the_pallas_arm_in_interpret_mode_is_the_xla_arm(sizes, R, N, further):
     """The Pallas kernel through the interpreter against ``jax.lax.ragged_dot``
-    on the rows the groups cover (what lies behind them is undefined)."""
+    on the rows the groups cover (what lies behind them is undefined), and
+    against the parent's kernel BIT FOR BIT (PR 60: the ring changes when a
+    bank tile arrives, never what is multiplied). Mellum's chunk: 2,048 rows
+    over 64 groups, routed (groups straddle row tiles: ``further`` visits are
+    a group's further row tile) and 32 rows a group (none is); 1,280 columns
+    are two column blocks, so the stream runs on from one into the next; a
+    group of five row tiles (one expert takes every token); and fewer bank
+    tiles than the ring holds."""
     rng = np.random.default_rng(sum(sizes))
-    R, K, N, G = 256, 128, 256, len(sizes)
+    K, G = 128, len(sizes)
     rows = jnp.asarray(rng.normal(size=(R, K)), jnp.float32)
     bank = jnp.asarray(rng.normal(size=(G, K, N)) / np.sqrt(K), jnp.float32)
     group_sizes = jnp.asarray(sizes, jnp.int32)
-    assert column_tile(K, N, 4) == 256
+    assert column_tile(K, N, 4) == {256: 256, 1280: 640}[N] and R == padded_rows(R)
+    assert int(visit_count(group_sizes)) - int((group_sizes > 0).sum()) == further
     with jax.default_matmul_precision("highest"):
         got = np.asarray(grouped_matmul(rows, bank, group_sizes, jnp.float32, interpret=True))
         want = np.asarray(grouped_matmul(rows, bank, group_sizes, jnp.float32))
+        parent = np.asarray(_parent_kernel(rows, bank, group_sizes, jnp.float32))
     covered = sum(sizes)
     np.testing.assert_allclose(got[:covered], want[:covered], atol=1e-5, rtol=0)
+    np.testing.assert_array_equal(got[:covered], parent[:covered])
     starts = np.cumsum([0] + list(sizes))
     for g in range(G):
         rows_g = np.asarray(rows[starts[g]:starts[g + 1]], np.float64)

@@ -366,10 +366,12 @@ def test_the_decode_loop_program_is_the_one_it_was_before_the_scan_went_by_segme
     to what it did at the commit before. Re-pinned by PR 53, which changed it
     on purpose: the convolution's tails leave and enter their folded slots by
     ``ssm.load`` / ``ssm.store_in_place`` (every other family's recorded
-    programs, ``test_one_group_programs.py`` and ``test_afmoe.py``, hold)."""
+    programs, ``test_one_group_programs.py`` and ``test_afmoe.py``, hold).
+    Re-pinned by PR 60: the chunk's count of routed work holds the grouped
+    kernel's visits beside the banks and the local assignments."""
     cfg, params = model_in_place
     assert decode_loop_hash(engine_of(cfg, params).model) == \
-        "55540c46817ba802dddfeec485bf026e41f061464470f23fa5cc010bf7ad3769"
+        "5e3cffe624afe936e7d5cac39c2937c755c1a695eed0a30812053b554197911a"
 
 
 def test_admission_stops_at_the_last_free_slot(model):

@@ -33,7 +33,8 @@ from tests.unit.inference.v2.program_hashes import decode_loop_hash
 BLOCK = 16
 TOL = 1e-4
 # sha256 of the tiny model's traced decode_loop program (``program_hashes.decode_loop_hash``)
-DECODE_LOOP_HASH = "da3a097212ad44de65f1da31ca219ea9d2158b54bf4464d372651e3963164dbd"
+# re-recorded in PR 60: the chunk's count of routed work holds the grouped kernel's visits too
+DECODE_LOOP_HASH = "ef06eb07cb605b266f3b1667d88639e008f98d8b8ae2f6ac1dc93a7bb14f773b"
 
 
 def sizes_of(cfg):
@@ -364,7 +365,8 @@ def test_the_counts_say_what_the_delta_rule_did(engine):
     assert chunk["kda_chunk_visits"] == chunk["kda_chunk_visits_in_kernel"] == 0
     counts = engine.model.dispatch_counts(8, 2, 4)
     assert counts["moe_path"] == "grouped" and counts["moe_assignments"] == 2 * 4 * 3 * 4
-    assert engine.model.moe_count_names == ("moe_banks", "moe_assignments_local")
+    assert engine.model.moe_count_names == ("moe_banks", "moe_assignments_local",
+                                             "moe_visits")
     engine.flush(0), engine.flush(1)
 
 

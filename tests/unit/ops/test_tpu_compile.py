@@ -417,6 +417,40 @@ def test_mellum_decode_loop_program_fits_one_chip(v5e, mellum_model):
     assert not _pool_sized_results(text, cache.shape)
 
 
+# ---- the grouped matmul's ring of bank tiles, within the VMEM it states (PR 60) ----
+@pytest.mark.parametrize("rows,G,K,N,out_dtype", [
+    (2048, 64, 2304, 1792, jnp.bfloat16), (2048, 64, 896, 2304, jnp.float32),
+    (128, 128, 2048, 2048, jnp.bfloat16), (128, 128, 1024, 2048, jnp.float32)],
+    ids=["mellum-chunk-gate-up", "mellum-chunk-down", "trinity-decode-gate-up",
+         "trinity-decode-down"])
+def test_the_grouped_matmul_compiles_within_the_vmem_it_states(v5e, rows, G, K, N, out_dtype):
+    """The bank stays in HBM and the kernel holds a ring of THREE bank tiles
+    (interpret mode cannot see a buffer that does not fit): what the compiler
+    says the kernel uses is no more than ``ring_vmem_bytes`` states, and where
+    that passes three quarters of the compiler's own 16 MiB the call asks for
+    the stated bytes and a quarter (``paged_attention.vmem_params``)."""
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gm
+    from deepspeed_tpu.ops.pallas.paged_attention import SCOPED_VMEM_BYTES
+    on = functools.partial(_on, SingleDeviceSharding(v5e[0]))
+
+    def projection(rows, bank, sizes):
+        return gm.grouped_matmul(rows, bank, sizes, out_dtype)
+
+    text = jax.jit(projection).lower(on((rows, K), jnp.bfloat16), on((G, K, N), jnp.bfloat16),
+                                     on((G, ), jnp.int32)).compile().as_text()
+    call, = _kernel_calls(text, "grouped_matmul")
+    tn = gm.column_tile(K, N, 2)
+    stated = gm.ring_vmem_bytes(K, tn, 2, jnp.dtype(out_dtype).itemsize)
+    vmem = r'scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"'
+    used, = re.findall('"used_' + vmem, call)
+    asked = re.findall('"' + vmem, call)
+    assert gm.RING_DEPTH * K * tn * 2 < int(used) <= stated
+    if stated > SCOPED_VMEM_BYTES * 3 // 4:
+        assert [int(a) for a in asked] == [stated * 5 // 4]
+    else:
+        assert not asked
+
+
 # ---- sigmoid top-8 of 128 beside a shared expert, five layer groups (PR 34) ----
 TRINITY_LAYERS, TRINITY_POOL_BLOCKS, TRINITY_MAX_BLOCKS = 5, 26624, 64
 
@@ -1000,7 +1034,8 @@ def test_sdar_block_loop_program_fits_one_chip(v5e, sdar_model):
     out = jax.eval_shape(loop, params, cache, batch)
     assert (out[0].shape, out[0].dtype, out[1].shape, out[1].dtype) == \
         ((32, 8), jnp.int32, (32, 8), jnp.int8)
-    assert out[4].shape == (2, SDAR_LAYERS)  # the banks each block's forwards read, a layer
+    # the banks each block's forwards read and the kernel's visits of them, a layer
+    assert out[4].shape == (2, SDAR_LAYERS, 2)
     assert _device_bytes(compiled) < 0.85 * HBM_BYTES
     assert not _pool_sized_results(text, cache.shape)
     # one block: no fused forward, the program it always was (a scan of four and a commit)
