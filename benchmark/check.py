@@ -39,6 +39,44 @@ def logit_rel_tol(n_layers):
 ROUTING_TOSS_UP_GAP = 2.0**-5
 TOSS_UP_TOL_FACTOR = 4.0
 
+
+# A configuration may state the limits of its own rows, each set between two
+# readings on the chip (PERF.md section 2 keeps them): a ``check`` group of
+# ``tight_row_log2`` and ``loose_row_log2`` (a row's largest error, as log2 of
+# the largest logit, off and at a toss-up) and ``median_row_log2`` (the MEDIAN
+# row's error over all the rows of a run's check). The worst row is a widest
+# gap and swings from seed to seed (one row in some hundreds reads twice the
+# next worst), so the lower precision stands a bare 2.8 x over the largest honest
+# row; it moves EVERY row, and stands 5 x over the honest median, which twelve
+# seeds read within 0.22 bits. A configuration that states nothing is held as it
+# always was: 2^-7 x sqrt(layers), 4 x that at a toss-up, no median.
+def row_limits(sizes):
+    """``{"tight", "loose", "median"}``: the shares of the largest logit that a
+    served configuration's rows are held to (``median`` None where none is)."""
+    stated = sizes.get("check") or {}
+    tight = 2.0**stated["tight_row_log2"] if "tight_row_log2" in stated \
+        else logit_rel_tol(sizes["num_hidden_layers"])
+    loose = 2.0**stated["loose_row_log2"] if "loose_row_log2" in stated \
+        else tight * TOSS_UP_TOL_FACTOR
+    median = 2.0**stated["median_row_log2"] if "median_row_log2" in stated else None
+    return {"tight": float(tight), "loose": float(loose), "median": median}
+
+
+def rows_compared(row_errors, limits):
+    """``row_errors``: ``(error / largest logit, held to the loose limit)`` for
+    every row of a run's check. Returns ``(ok, compared)``: each number compared
+    beside its limit, ``{name: [value, limit]}``, and whether all are inside."""
+    err = np.asarray([e for e, _ in row_errors], np.float64)
+    loose = np.asarray([k for _, k in row_errors], bool)
+    compared = {}
+    if (~loose).any():
+        compared["worst_tight_row"] = [float(err[~loose].max()), limits["tight"]]
+    if loose.any():
+        compared["worst_loose_row"] = [float(err[loose].max()), limits["loose"]]
+    if limits["median"] is not None:
+        compared["median_row"] = [float(np.median(err)), limits["median"]]
+    return all(v <= limit for v, limit in compared.values()), compared
+
 # Training: the engine's first loss is a bf16 forward; the reference is float32.
 # The loss is a mean over 4096 tokens of a log-softmax near ln(vocab). Rounding of
 # the logits by e (zero mean) raises log-sum-exp by about var(e)/2: bf16 logits
@@ -61,12 +99,14 @@ LOSS_REL_TOL = 5e-4
 LOSS_MUST_FALL_TO = 0.9
 
 
-def logits_close(ref, other, rel_tol, routing_gaps=None):
+def logits_close(ref, other, rel_tol, routing_gaps=None, loose_tol=None, row_errors=None):
     """``other`` reproduces ``ref`` (rows of float32 logits). Returns
     ``(ok, detail)``: finite, every row within its tolerance, and the same
     greedy token wherever ``ref``'s own top-2 margin is outside it (inside the
     margin either token is a right answer). ``routing_gaps`` (one per row, from a
-    sparse model's reference) marks the rows held to the loose tolerance."""
+    sparse model's reference) marks the rows held to the loose tolerance:
+    ``loose_tol``, or ``TOSS_UP_TOL_FACTOR`` x ``rel_tol``. A list passed as
+    ``row_errors`` receives ``(error / largest logit, loose)`` of every row."""
     ref, other = np.asarray(ref, np.float32), np.asarray(other, np.float32)
     if ref.shape != other.shape:
         return False, f"shapes differ: {ref.shape} vs {other.shape}"
@@ -74,16 +114,19 @@ def logits_close(ref, other, rel_tol, routing_gaps=None):
         return False, "non-finite logits"
     scale = float(np.abs(ref).max())
     tol = np.full(ref.shape[0], rel_tol * scale)
+    loose_tol = rel_tol * TOSS_UP_TOL_FACTOR if loose_tol is None else loose_tol
     if routing_gaps is not None:
-        tol[np.asarray(routing_gaps) < ROUTING_TOSS_UP_GAP] *= TOSS_UP_TOL_FACTOR
+        tol[np.asarray(routing_gaps) < ROUTING_TOSS_UP_GAP] = loose_tol * scale
     worst = np.abs(ref - other).max(axis=-1)
+    if row_errors is not None:
+        row_errors.extend(zip((worst / scale).tolist(), (tol > rel_tol * scale).tolist()))
     top2 = np.sort(ref, axis=-1)[:, -2:]
     decided = (top2[:, 1] - top2[:, 0]) > 2 * tol
     same = ref.argmax(-1) == other.argmax(-1)
     loose = int((tol > rel_tol * scale).sum())
     detail = (f"|dlogit| by row, as log2 of the largest logit ({scale:.4g}): "
               f"{[round(float(np.log2(max(w, 1e-12) / scale)), 1) for w in worst]}; tolerance "
-              f"2^{np.log2(rel_tol):.1f}" + (f", {TOSS_UP_TOL_FACTOR:g} x that for the {loose} "
+              f"2^{np.log2(rel_tol):.1f}" + (f", {loose_tol / rel_tol:g} x that for the {loose} "
                                             f"rows at a routing toss-up" if loose else "")
               + f"; greedy tokens equal at {int(same.sum())}/{same.size}, "
               f"{int((~decided).sum())} inside the margin")

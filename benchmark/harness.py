@@ -187,6 +187,13 @@ def run_cell(root, workload, seed, seconds, trace, rehearsal=False, out=sys.stdo
             "metrics": metrics, "device": device}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    if run.get("compared"):
+        # each number that decided ``correct`` beside its limit: the line's last key,
+        # and the last lines of the standard error
+        line["compared"] = run["compared"]
+        for name, (value, limit) in run["compared"].items():
+            print(f"compared: {name} {value:.6g} limit {limit:.6g} "
+                  f"{'ok' if value <= limit else 'OVER'}", file=sys.stderr, flush=True)
     print(json.dumps(line), file=out, flush=True)
     return 0
 

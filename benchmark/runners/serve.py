@@ -208,26 +208,39 @@ def engine_rows(engine, budget, prompts, feeds, loop_steps):
     return [np.stack(r) for r in rows], looped
 
 
-def correctness(engine, family, sizes, budget, prompts, feeds, ref, loop_steps, log):
+def correctness(engine, family, sizes, budget, prompts, feeds, ref, loop_steps, log,
+                compared=None):
+    """The check's rows through ``engine`` against ``ref``: every row inside its
+    limit (``check.row_limits``: the configuration's own where it states them),
+    the median row inside its where there is one, ``decode_loop``'s first token
+    a right one. A dict passed as ``compared`` receives each number compared
+    beside its limit."""
     got, looped = engine_rows(engine, budget, prompts, feeds, loop_steps)
     ok = True
-    rel_tol = check.logit_rel_tol(sizes["num_hidden_layers"])
+    limits = check.row_limits(sizes)
+    rel_tol, row_errors = limits["tight"], []
     for i, ((r, gaps), g) in enumerate(zip(ref, got)):
         same, detail = check.logits_close(r[:g.shape[0]], g, rel_tol,
                                           routing_gaps=None if gaps is None
-                                          else gaps[:g.shape[0]])
+                                          else gaps[:g.shape[0]],
+                                          loose_tol=limits["loose"], row_errors=row_errors)
         log(f"correct[{i}] prompt of {prompts[i].size} tokens + {g.shape[0] - 1} fed: {detail}"
             f" -> {'ok' if same else 'WRONG'}")
         ok &= same
         if looped is not None:
             toss_up = gaps is not None and gaps[-1] < check.ROUTING_TOSS_UP_GAP
             hit = check.token_decided(r[-1], looped[i], scale=float(np.abs(r).max()),
-                                      rel_tol=rel_tol * (check.TOSS_UP_TOL_FACTOR if toss_up
-                                                         else 1.0))
+                                      rel_tol=limits["loose"] if toss_up else rel_tol)
             log(f"correct[{i}] decode_loop's first token {int(looped[i])} against the reference's "
                 f"last row -> {'ok' if hit else 'WRONG'}")
             ok &= hit
-    return ok
+    inside, numbers = check.rows_compared(row_errors, limits)
+    log("correct: " + "; ".join(f"{name} 2^{np.log2(max(v, 1e-12)):.2f} (limit 2^{np.log2(limit):.2f})"
+                                for name, (v, limit) in numbers.items())
+        + f" over {len(row_errors)} rows -> {'ok' if inside else 'WRONG'}")
+    if compared is not None:
+        compared.update(numbers)
+    return ok and inside
 
 
 # ------------------------------------------------------------- the system ---
@@ -307,11 +320,14 @@ def prepare(ctx):
         loops = sorted(set(loops) | {(tuple(k[0]), k[1], k[2])
                                      for k in learned.get("decode_loop", [])}, key=repr)
     t = time.perf_counter()
+    compared = {}
     correct = correctness(engine, family, config, budget, prompts, feeds, ref,
-                          config["serving"].get("decode_chunk", 1) if loops else 0, log)
+                          config["serving"].get("decode_chunk", 1) if loops else 0, log,
+                          compared=compared)
     log(f"correctness through the engine in {time.perf_counter() - t:.1f}s")
     warm(engine, engine_cfg, cfg.vocab_size, forward, loops, log)
-    return {"engine": engine, "cfg": cfg, "correct": bool(correct), "capacity": capacity,
+    return {"engine": engine, "cfg": cfg, "correct": bool(correct), "compared": compared,
+            "capacity": capacity,
             "warmed": _program_keys(engine), "forward": forward, "loops": loops,
             "learned_path": learned_path}
 
@@ -386,7 +402,8 @@ def run(ctx):
         log(f"failed request {r.index}: {r.detail or 'no first token before the drain ended'}")
     engine.close()
     return dict(
-        window, mode="serve", correct=prepared["correct"], attempted=len(judged),
+        window, mode="serve", correct=prepared["correct"], compared=prepared["compared"],
+        attempted=len(judged),
         failed=len(bad), spans=span_rows, kv_capacity_blocks=prepared["capacity"], trace_path=trace_path, trace_slice=slice_,
         model={"n_heads": cfg.num_attention_heads, "n_kv_heads": cfg.num_key_value_heads,
                "head_dim": cfg.hidden_size // cfg.num_attention_heads,

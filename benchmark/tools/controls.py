@@ -184,7 +184,7 @@ def main(argv=None):
     budget = engine_cfg["state_manager"]["max_ragged_batch_size"]
     loop_steps = config["serving"].get("decode_chunk", 1)
     result = {"workload": args.workload, "seed": args.seed,
-              "tolerance_log2": float(np.log2(check.logit_rel_tol(config["num_hidden_layers"]))),
+              "tolerance_log2": float(np.log2(check.row_limits(config)["tight"])),
               "controls": {}}
     wanted = args.controls.split(",")
     if "baseline" in wanted:  # first, on the tree the reference was computed from

@@ -35,9 +35,10 @@ run (restored after):
   on this family's tree (it finds the banks by Mixtral's names). Run last: it
   consumes a tree of its own, and two do not fit on the chip.
 
-Prints one JSON line: per control ``correct`` and the largest row error on the
+Prints one JSON line: per control ``correct``, the largest row error on the
 rows held to the tight and to the loose tolerance, as log2 of the largest
-logit. Runs on the chip (``--rehearsal 1`` runs wherever JAX runs, for the
+logit, and ``compared_log2``: each number the comparison held beside its limit
+(``check.rows_compared``; the median row where the configuration states one). Runs on the chip (``--rehearsal 1`` runs wherever JAX runs, for the
 tests, and proves nothing about a chip). The comparison's own file,
 ``controls.py``, is read for its row parser and its float8 rounding.
 """
@@ -184,7 +185,7 @@ def main(argv=None):
     budget = engine_cfg["state_manager"]["max_ragged_batch_size"]
     loop_steps = config["serving"].get("decode_chunk", 1)
     result = {"workload": args.workload, "seed": args.seed,
-              "tolerance_log2": float(np.log2(check.logit_rel_tol(config["num_hidden_layers"]))),
+              "tolerance_log2": float(np.log2(check.row_limits(config)["tight"])),
               "controls": {}}
     wanted = sorted(args.controls.split(","), key=lambda c: c == "fp8_weights")
     for control in wanted:
@@ -204,15 +205,19 @@ def main(argv=None):
             lines.append(message)
             log(f"{control}: {message}")
 
+        compared = {}
         with patch:
             engine = build_engine(its_params, its_cfg, RaggedInferenceEngineConfig(**engine_cfg))
             ok = serve.correctness(engine, family, config, budget, prompts, feeds, ref,
-                                   loop_steps, keep)
+                                   loop_steps, keep, compared=compared)
             engine.close()
         del engine, its_params
         gc.collect()  # the engine sits in reference cycles, and its KV pool with it
-        result["controls"][control] = dict(_worst(lines, ref, check.ROUTING_TOSS_UP_GAP),
-                                           correct=bool(ok))
+        result["controls"][control] = dict(
+            _worst(lines, ref, check.ROUTING_TOSS_UP_GAP), correct=bool(ok),
+            compared_log2={name: [round(float(np.log2(max(v, 1e-12))), 2),
+                                  round(float(np.log2(limit)), 2)]
+                           for name, (v, limit) in compared.items()})
         log(f"{control}: correct={ok}")
     print(json.dumps(result), flush=True)
     want = {c: c == "baseline" for c in result["controls"]}

@@ -121,7 +121,7 @@ def main(argv=None):
     budget = engine_cfg["state_manager"]["max_ragged_batch_size"]
     loop_steps = config["serving"].get("decode_chunk", 1)
     result = {"workload": args.workload, "seed": args.seed,
-              "tolerance_log2": float(np.log2(check.logit_rel_tol(config["num_hidden_layers"]))),
+              "tolerance_log2": float(np.log2(check.row_limits(config)["tight"])),
               "controls": {}}
     for control in sorted(args.controls.split(","), key=lambda c: c == "fp8_weights"):
         if control == "fp8_weights":

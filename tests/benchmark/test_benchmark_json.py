@@ -113,3 +113,46 @@ def test_layer_names_are_the_ones_perf_md_lists(bench):
         perf = f.read()
     for layer in {m["layer"] for m in bench["per_layer"]}:
         assert layer in perf, f"PERF.md's list of layers lacks {layer!r}"
+
+
+def _spec(name):
+    with open(os.path.join(tiny.REPO, "benchmark", "metrics", f"{name}.json")) as f:
+        return json.load(f)
+
+
+def _second_names(bench):
+    """Entries that are an earlier entry's reading (one reader, one ``params``, moving the same
+    end-to-end metric) under another name: a later cell joins the first name's list."""
+    first, again = {}, []
+    for m in bench["per_layer"]:
+        spec = _spec(m["name"])
+        key = (spec["reader"], json.dumps(spec.get("params"), sort_keys=True), m["moves"])
+        if first.setdefault(key, m["name"]) != m["name"]:
+            again.append((m["name"], first[key]))
+    return again
+
+
+def _without_a_list(bench):
+    # ``compiles_in_window`` is every cell's, those of later PRs too: the one entry without a list
+    return [m["name"] for m in bench["per_layer"]
+            if "workloads" not in m and m["name"] != "compiles_in_window"]
+
+
+def _listed_and_no_cell(bench):
+    cells = {w["name"] for w in bench["workloads"]}
+    return [(m["name"], w) for m in bench["end_to_end"] + bench["per_layer"]
+            for w in m.get("workloads", []) if w not in cells]
+
+
+def _files_without_an_entry(bench):
+    names = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]}
+    return sorted(f for f in os.listdir(os.path.join(tiny.REPO, "benchmark", "metrics"))
+                  if f[:-5] not in names)
+
+
+@pytest.mark.parametrize("found", [_second_names, _without_a_list, _listed_and_no_cell,
+                                   _files_without_an_entry], ids=lambda f: f.__name__.strip("_"))
+def test_one_name_a_reading(bench, found):
+    """The door PR 61 shut: ``per_layer`` reached its 128 because 21 entries were an accepted
+    reader with the very same ``params`` under a second name. A cell is added to a LIST."""
+    assert found(bench) == []

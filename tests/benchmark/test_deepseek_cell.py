@@ -25,11 +25,11 @@ NEW_METRICS = ("attn_index_busy_pct", "attn_index_topk_busy_pct", "attn_latent_p
                "paged_latent_token_roofline", "paged_latent_tiled_roofline",
                "paged_index_roofline", "latent_kernels_busy_pct", "moe_share_grouped_roofline",
                "moe_banks_per_local_assignment")
-# accepted readers and params under names of this cell's own: the accepted metrics' lists are
-# pinned to their cells by ``tests/benchmark/test_expert_and_chunk_readers.py``
-RENAMED = {"chunk_launch_latent_p50_ms": "chunk_launch_p50_ms",
-           "chunk_round_trip_latent_p50_ms": "chunk_round_trip_p50_ms",
-           "idle_in_chunk_run_latent_pct": "idle_in_chunk_run_pct"}
+# the names PR 40 had to give three accepted readers a second time, and the accepted names
+# that list this cell since PR 61: one name a reading
+FOLDED = {"chunk_launch_latent_p50_ms": "chunk_launch_p50_ms",
+          "chunk_round_trip_latent_p50_ms": "chunk_round_trip_p50_ms",
+          "idle_in_chunk_run_latent_pct": "idle_in_chunk_run_pct"}
 
 
 @pytest.fixture(scope="module")
@@ -147,21 +147,16 @@ def test_its_metrics_are_listed_and_the_ones_that_price_kv_heads_are_not(resolve
     assert {"moe_busy_pct", "attn_busy_pct", "step_decode_p50_ms", "step_any_p50_ms",
             "device_idle_pct", "kv_blocks_peak_pct", "compiles_in_window",
             "serve_generated_tokens_per_s", "dense_ffn_busy_pct", "moe_shared_busy_pct",
-            "unscoped_busy_pct"} | set(RENAMED) <= traced
+            "unscoped_busy_pct"} | set(FOLDED.values()) <= traced
     # ``moe_grouped_roofline`` prices a step by every assignment the router made: 16 x what
     # lands on this chip's experts; the cell reports ``moe_share_grouped_roofline`` instead
-    assert not ({"moe_grouped_roofline", "moe_banks_per_assignment"} | set(RENAMED.values())) \
-        & traced
-    for new, old in RENAMED.items():
-        with open(os.path.join(tiny.REPO, "benchmark", "metrics", f"{new}.json")) as f, \
-                open(os.path.join(tiny.REPO, "benchmark", "metrics", f"{old}.json")) as g:
-            assert json.load(f) == json.load(g)
+    assert not ({"moe_grouped_roofline", "moe_banks_per_assignment"} | set(FOLDED)) & traced
     assert {m["name"] for m in harness.metrics_for(bench, CELL, False)} == \
         {"tpot_p50_ms", "setup_s"}
     layers = {m["layer"] for m in bench["per_layer"]}
     for name in NEW_METRICS:
         entry = next(m for m in bench["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "tpot_p50_ms"
+        assert CELL in entry["workloads"] and entry["moves"] == "tpot_p50_ms"
         assert entry["layer"] in layers
         if name.endswith("_roofline"):
             assert entry["unit"] == "%" and entry["better"] == "higher"
@@ -317,6 +312,52 @@ def test_the_controls_run_through_the_harness_comparison_at_a_tiny_preset(tmp_pa
     from deepspeed_tpu.inference.v2.model_implementations import deepseek_v32_v2 as served
     from deepspeed_tpu.inference.v2.model_implementations.llama_v2 import _rotate_half
     assert served._rotate_half is _rotate_half
+
+
+def test_the_stated_row_limits_pass_the_seeds_weights_and_fail_them_through_fp8(tmp_path, capsys):
+    """The tiny cell under the REAL configuration's ``check`` group (PR 61: the
+    worst tight row at 2^-5.0, a toss-up row at 2^-3.84, the median row at
+    2^-5.5): the weights as the seed makes them are inside all three and the
+    result's numbers say so; every matrix through fp8, the nearest precision
+    below the configuration's, moves every row, and the MEDIAN row is over its
+    limit (what the chip reads: PERF.md section 2)."""
+    from benchmark.tools import controls_latent
+    root = _tiny_root(tmp_path)
+    _, _, real, _ = harness.resolve(tiny.REPO, CELL)
+    path = os.path.join(root, "benchmark", "configs", "tiny-deepseek.json")
+    tiny.write_json(path, dict(TINY, check=real["check"]))
+    rc = controls_latent.main(["--workload", "tiny-deepseek-reason", "--seed", str(2**31 + 41),
+                               "--controls", "baseline,fp8_weights", "--rehearsal", "1",
+                               "--root", root])
+    result = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rc == 0 and result["tolerance_log2"] == real["check"]["tight_row_log2"]
+    honest, spoilt = result["controls"]["baseline"], result["controls"]["fp8_weights"]
+    assert honest["correct"] is True and spoilt["correct"] is False
+    assert all(v <= limit for v, limit in honest["compared_log2"].values())
+    assert honest["compared_log2"]["median_row"][1] == real["check"]["median_row_log2"]
+    value, limit = spoilt["compared_log2"]["median_row"]
+    assert value > limit
+
+
+def test_the_tool_that_reads_the_rows_a_limit_is_set_from_runs_at_a_tiny_preset(tmp_path, capsys):
+    """``benchmark/tools/check_rows.py``: two seeds in one process, the second
+    through fp8 too; a line a seed with the worst tight, worst loose and median
+    row, and a file a seed with every row."""
+    from benchmark.tools import check_rows
+    root = _tiny_root(tmp_path)
+    seeds = [2**31 + 42, 7]
+    rc = check_rows.main(["--workload", "tiny-deepseek-reason", "--seeds", ",".join(map(str, seeds)),
+                          "--control-seeds", "7", "--rehearsal", "1", "--root", root,
+                          "--out", "rows"])
+    lines = [json.loads(line) for line in capsys.readouterr().out.strip().splitlines()]
+    assert rc == 0 and [line["seed"] for line in lines] == seeds
+    assert "fp8_weights" not in lines[0] and lines[0]["seed_weights"]["rows"] == 32
+    honest, spoilt = lines[1]["seed_weights"], lines[1]["fp8_weights"]
+    assert spoilt["median_log2"] > honest["median_log2"] + 3  # float32 against float8
+    with open(os.path.join(root, "rows", "tiny-deepseek-reason.7.json")) as f:
+        record = json.load(f)
+    assert len(record["fp8_weights"]["error"]) == len(record["seed_weights"]["loose"]) == 32
+    assert record["limits_log2"]["median"] is None  # the tiny preset states no limits
 
 
 def test_the_new_readers_find_nothing_on_a_program_without_the_family_and_do_not_raise(resolved):

@@ -19,7 +19,7 @@ from tests.benchmark import tiny
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures", "chip_slice_chunks.json")
 MS = 1_000_000  # ns
 PEAKS = opcount.PEAKS["TPU v5 lite"]
-NEW_METRICS = {
+NEW_METRICS = {  # the cells PR 37 listed; later cells join the lists
     "moe_grouped_roofline": ["mellum2-repoctx-closed", "trinity-mini-reason-closed"],
     "moe_capacity_roofline": ["mixtral-longgen-closed", "mixtral-rag-closed"],
     "moe_banks_per_assignment": ["trinity-mini-reason-closed"],
@@ -48,7 +48,7 @@ def test_each_new_metric_names_its_reader_its_cells_and_a_layer_perf_md_lists(na
     with open(os.path.join(tiny.REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == NEW_METRICS[name] and entry["moves"] == "tpot_p50_ms"
+    assert set(NEW_METRICS[name]) <= set(entry["workloads"]) and entry["moves"] == "tpot_p50_ms"
     with open(os.path.join(tiny.REPO, "benchmark", "metrics", f"{name}.json")) as f:
         spec = json.load(f)
     assert os.path.exists(os.path.join(tiny.REPO, "benchmark", "readers", f"{spec['reader']}.py"))

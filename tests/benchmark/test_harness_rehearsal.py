@@ -31,7 +31,11 @@ def _run(root, cell, trace, seconds=1.5, seed=0):
 
 
 def _check_line(line, root, cell, traced):
-    assert set(line) == LINE_KEYS | ({"breakdown"} if traced else set())
+    assert set(line) - {"compared"} == LINE_KEYS | ({"breakdown"} if traced else set())
+    if "train" not in cell:
+        # a served cell's line ends with each number that decided ``correct`` beside its limit
+        assert list(line)[-1] == "compared" and line["compared"]
+        assert all(0 <= value <= limit for value, limit in line["compared"].values())
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
     assert DEVICE_KEYS <= set(line["device"]) and line["device"]["platform"] == "cpu"
     with open(os.path.join(root, "BENCHMARK.json")) as f:
@@ -55,7 +59,7 @@ def _check_line(line, root, cell, traced):
 def test_untraced_run_prints_the_cells_end_to_end_metrics(root, cell, expected):
     line, _ = _run(root, cell, trace=0)
     values = _check_line(line, root, cell, traced=False)
-    assert set(values) == expected
+    assert set(values) >= expected  # a later metric may list the cell
     assert all(v > 0 for v in values.values()), "end-to-end metrics are never 0"
     assert line["device"]["count"] == (4 if cell.endswith("train") else 1)
 
@@ -74,7 +78,7 @@ def test_traced_run_prints_the_per_layer_metrics_it_can_read_off_the_chip(root, 
     the chip's trace, peaks or memory statistics finds nothing and is left out."""
     line, text = _run(root, cell, trace=1)
     values = _check_line(line, root, cell, traced=True)
-    assert set(values) == expected
+    assert set(values) >= expected  # a later metric may list the cell
     assert values["compiles_in_window"] == 0
     assert {"busy_s", "window_s"} <= set(line["device"])
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}

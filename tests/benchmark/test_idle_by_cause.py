@@ -226,19 +226,19 @@ def test_the_seconds_of_the_profilers_own_start_and_stop_are_left_out_of_the_lat
     assert _read("host_late_max_ms", run, _env()) == pytest.approx(2.6)
 
 
-def test_each_new_metric_names_a_reader_that_exists_and_lists_the_ten_serving_cells():
+def test_each_new_metric_names_a_reader_that_exists_and_lists_every_serving_cell():
     bench = harness._load_json(os.path.join(ROOT, "BENCHMARK.json"))
     serving = [w["name"] for w in bench["workloads"]
                if "tpot_p50_ms" in {m["name"] for m in harness.metrics_for(bench, w["name"], False)}]
-    assert len(serving) == 10
+    assert len(serving) >= 10  # every cell that reports ``tpot_p50_ms``, as the file has them
     entries = {m["name"]: m for m in bench["per_layer"]}
-    assert set(NEW) <= set(entries) and len(bench["per_layer"]) <= 128  # by name: no pin on the count
+    assert set(NEW) <= set(entries) and len(bench["per_layer"]) <= 128
     for name, (reader, source, unit) in NEW.items():
         entry = entries[name]
         assert os.path.exists(os.path.join(ROOT, "benchmark", "readers", f"{reader}.py"))
         assert (entry["source"], entry["unit"], entry["better"], entry["moves"]) == \
             (source, unit, "lower", "tpot_p50_ms")
-        assert entry["workloads"] == serving
+        assert set(entry["workloads"]) == set(serving)
         assert entry["layer"] == (SCHEDULER_LAYER if name in ("idle_waiting_pct",
                                                               "idle_unnamed_pct")
                                   else RUNTIME_LAYER)

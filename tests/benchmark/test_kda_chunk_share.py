@@ -30,8 +30,8 @@ def test_the_share_is_listed_by_name_beside_the_recurrences_share(bench):
     entry = next(m for m in bench["per_layer"] if m["name"] == NAME)
     beside = next(m for m in bench["per_layer"] if m["name"] == "kda_rows_in_place_share")
     assert entry == {**beside, "name": NAME}
-    assert (entry["unit"], entry["better"], entry["source"], entry["moves"],
-            entry["workloads"]) == ("ratio", "higher", "program_counter", "tpot_p50_ms", [CELL])
+    assert (entry["unit"], entry["better"], entry["source"], entry["moves"]) == \
+        ("ratio", "higher", "program_counter", "tpot_p50_ms") and CELL in entry["workloads"]
     assert NAME in {m["name"] for m in harness.metrics_for(bench, CELL, True)}
     with open(os.path.join(tiny.REPO, "PERF.md")) as f:
         assert f"`{NAME}`" in f.read()

@@ -22,20 +22,22 @@ from tests.benchmark import tiny
 CELL, CONFIG = "kimi-linear-longdoc-reason-closed", "kimi-linear-48b-a3b-serve-1chip"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 REDUCED = {"num_hidden_layers": 27, "num_experts": 256, "vocab_size": 163840}
-# each an accepted reader under a name of this cell's own (the accepted metrics' lists are pinned
-# to their cells by those cells' tests) but the latent kernels' two, whose count is new; the
-# latent pool's blocks are the generic ``kv_blocks_peak_pct``'s
-SAME_FILE_AS = {"kl_kda_busy_pct": "kda_busy_pct", "kl_kda_step_roofline": "kda_step_roofline",
-                "kl_kda_chunk_roofline": "kda_chunk_roofline",
-                "kl_latent_proj_busy_pct": "attn_latent_proj_busy_pct",
-                "kl_moe_held_grouped_roofline": "moe_swiglu_held_grouped_roofline",
-                "kl_state_slots_peak_pct": "ssm_state_slots_peak_pct",
-                "kl_kda_rows_in_place_share": "kda_rows_in_place_share",
-                "kl_kda_chunk_in_kernel_share": "kda_chunk_in_kernel_share",
-                "kl_chunk_launch_p50_ms": "chunk_launch_p50_ms",
-                "kl_idle_in_chunk_run_pct": "idle_in_chunk_run_pct"}
-NEW_METRICS = tuple(SAME_FILE_AS) + ("kl_latent_busy_pct", "kl_latent_token_roofline",
-                                     "kl_latent_tiled_roofline")
+# the ``kl_`` names PR 56 had to give ten accepted readers a second time (the accepted metrics'
+# lists were pinned to their cells by those cells' tests), and the accepted names that list this
+# cell since PR 61: one name a reading. The latent kernels' two, whose count is new, and the
+# latent layers' busy share keep names of their own; the latent pool's blocks are the generic
+# ``kv_blocks_peak_pct``'s
+FOLDED = {"kl_kda_busy_pct": "kda_busy_pct", "kl_kda_step_roofline": "kda_step_roofline",
+          "kl_kda_chunk_roofline": "kda_chunk_roofline",
+          "kl_latent_proj_busy_pct": "attn_latent_proj_busy_pct",
+          "kl_moe_held_grouped_roofline": "moe_share_grouped_roofline",
+          "kl_state_slots_peak_pct": "ssm_state_slots_peak_pct",
+          "kl_kda_rows_in_place_share": "kda_rows_in_place_share",
+          "kl_kda_chunk_in_kernel_share": "kda_chunk_in_kernel_share",
+          "kl_chunk_launch_p50_ms": "chunk_launch_p50_ms",
+          "kl_idle_in_chunk_run_pct": "idle_in_chunk_run_pct"}
+NEW_METRICS = tuple(FOLDED.values()) + ("kl_latent_busy_pct", "kl_latent_token_roofline",
+                                        "kl_latent_tiled_roofline")
 # accepted metrics that would MISREAD this cell and are not its: ``unscoped_*`` name no ``kda``
 # scope; the ``ssm_*`` / ``h1_*`` read Mamba-2 widths and scopes; ``paged_*`` a K/V kernel no
 # layer runs; ``paged_latent_*`` / ``paged_index_roofline`` price every layer to ``index_topk``
@@ -186,10 +188,7 @@ def test_its_metrics_are_listed_by_name(resolved):
             "sched_seqs_per_step", "idle_in_engine_pct", "idle_waiting_pct",
             "idle_in_host_stall_pct", "gc_pause_ms_per_s"} <= traced
     assert not NOT_ITS & traced
-    for new, old in SAME_FILE_AS.items():
-        with open(os.path.join(tiny.REPO, "benchmark", "metrics", f"{new}.json")) as f, \
-                open(os.path.join(tiny.REPO, "benchmark", "metrics", f"{old}.json")) as g:
-            assert json.load(f) == json.load(g)
+    assert not set(FOLDED) & traced
     assert {m["name"] for m in harness.metrics_for(bench, CELL, False)} == \
         {"tpot_p50_ms", "setup_s"}
     layers = {m["layer"] for m in bench["per_layer"] if m["name"] not in NEW_METRICS}

@@ -107,7 +107,7 @@ def test_its_metrics_are_listed_and_the_one_window_rooflines_are_not(resolved):
     layers = {m["layer"] for m in bench["per_layer"] if m["name"] not in NEW_METRICS}
     for name in NEW_METRICS:
         entry = next(m for m in bench["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL] and entry["layer"] in layers
+        assert CELL in entry["workloads"] and entry["layer"] in layers
         with open(os.path.join(tiny.REPO, "benchmark", "metrics", f"{name}.json")) as f:
             assert os.path.exists(os.path.join(tiny.REPO, "benchmark", "readers",
                                                f"{json.load(f)['reader']}.py"))

@@ -22,18 +22,17 @@ CELL, CONFIG = "nemotron3-nano-reason-closed", "nemotron3-nano-serve-1chip"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 REDUCED = {"num_hidden_layers": 52, "n_routed_experts": 128, "vocab_size": 131072}
 NEW_METRICS = ("ssm_busy_pct", "ssm_scan_roofline", "ssm_step_roofline",
-               "ssm_state_slots_peak_pct", "ssm_rows_per_step", "moe_held_assignment_share",
-               "moe_banks_per_held_assignment", "moe_relu2_grouped_roofline",
-               "unscoped_hybrid_busy_pct", "chunk_launch_hybrid_p50_ms",
-               "chunk_round_trip_hybrid_p50_ms", "idle_in_chunk_run_hybrid_pct")
-# accepted readers and params under names of this cell's own: the accepted metrics' lists are
-# pinned to their cells by ``tests/benchmark/test_deepseek_cell.py`` and
-# ``test_expert_and_chunk_readers.py``
-RENAMED = {"moe_held_assignment_share": "moe_local_assignment_share",
-           "moe_banks_per_held_assignment": "moe_banks_per_local_assignment",
-           "chunk_launch_hybrid_p50_ms": "chunk_launch_p50_ms",
-           "chunk_round_trip_hybrid_p50_ms": "chunk_round_trip_p50_ms",
-           "idle_in_chunk_run_hybrid_pct": "idle_in_chunk_run_pct"}
+               "ssm_state_slots_peak_pct", "ssm_rows_per_step", "moe_local_assignment_share",
+               "moe_banks_per_local_assignment", "moe_relu2_grouped_roofline",
+               "unscoped_hybrid_busy_pct", "chunk_launch_p50_ms",
+               "chunk_round_trip_p50_ms", "idle_in_chunk_run_pct")
+# the names PR 43 had to give five accepted readers a second time, and the accepted names
+# that list this cell since PR 61: one name a reading
+FOLDED = {"moe_held_assignment_share": "moe_local_assignment_share",
+          "moe_banks_per_held_assignment": "moe_banks_per_local_assignment",
+          "chunk_launch_hybrid_p50_ms": "chunk_launch_p50_ms",
+          "chunk_round_trip_hybrid_p50_ms": "chunk_round_trip_p50_ms",
+          "idle_in_chunk_run_hybrid_pct": "idle_in_chunk_run_pct"}
 # readers that do not apply to this family as they are (``test_its_metrics_are_listed``)
 NOT_ITS = {"paged_attn_roofline", "moe_share_grouped_roofline", "moe_grouped_roofline",
            "dense_ffn_busy_pct", "unscoped_busy_pct"}
@@ -154,16 +153,13 @@ def test_its_metrics_are_listed(resolved):
     # first_k_dense_replace and three matrices an expert; ``unscoped_busy_pct``'s pattern does
     # not name ``ssm`` and would count the Mamba-2 blocks twice (``unscoped_hybrid_busy_pct``)
     assert not NOT_ITS & traced
-    for new, old in RENAMED.items():
-        with open(os.path.join(tiny.REPO, "benchmark", "metrics", f"{new}.json")) as f, \
-                open(os.path.join(tiny.REPO, "benchmark", "metrics", f"{old}.json")) as g:
-            assert json.load(f) == json.load(g)
+    assert set(FOLDED.values()) <= traced and not set(FOLDED) & traced
     assert {m["name"] for m in harness.metrics_for(bench, CELL, False)} == \
         {"tpot_p50_ms", "setup_s"}
     layers = {m["layer"] for m in bench["per_layer"] if m["name"] not in NEW_METRICS}
     for name in NEW_METRICS:
         entry = next(m for m in bench["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "tpot_p50_ms"
+        assert CELL in entry["workloads"] and entry["moves"] == "tpot_p50_ms"
         assert entry["layer"] in layers
         if name.endswith("_roofline"):
             assert entry["unit"] == "%" and entry["better"] == "higher"

@@ -136,3 +136,89 @@ def test_logit_tolerance_catches_a_lower_precision():
     assert check.token_decided(ref[0], int(ref[0].argmax()), tol)
     assert not check.token_decided(ref[0], int(ref[0].argmin()), tol)
     assert check.loss_close(10.8792, 10.8788)[0] and not check.loss_close(10.0, 10.05)[0]
+
+
+def _serving_config_files():
+    import glob
+    import json
+    import os
+    here = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    served = []
+    for path in sorted(glob.glob(os.path.join(here, "benchmark", "configs", "*.json"))):
+        with open(path) as f:
+            if json.load(f).get("mode") == "serve":  # a training configuration compares losses
+                served.append(path)
+    return served
+
+
+@pytest.mark.parametrize("path", _serving_config_files(), ids=lambda p: p.rsplit("/", 1)[-1][:-5])
+def test_a_configurations_row_limits_are_its_own_or_the_rule(path):
+    """A served configuration that states no ``check`` group is held as it always
+    was (2^-7 x sqrt(layers), 4 x that at a toss-up, no median); one that states
+    limits states all three with their reason, each between the readings PERF.md
+    section 2 keeps: above the rule's tight limit (it was stated because an
+    honest row passed that), under the 2^-4.5 the fp8 control's rows read."""
+    import json
+    with open(path) as f:
+        config = json.load(f)
+    limits = check.row_limits(config)
+    rule = check.logit_rel_tol(config["num_hidden_layers"])
+    stated = config.get("check")
+    if stated is None:
+        assert limits == {"tight": rule, "loose": rule * check.TOSS_UP_TOL_FACTOR, "median": None}
+        return
+    assert set(stated) == {"tight_row_log2", "loose_row_log2", "median_row_log2", "why"}
+    assert limits == {"tight": 2.0**stated["tight_row_log2"], "loose": 2.0**stated["loose_row_log2"],
+                      "median": 2.0**stated["median_row_log2"]}
+    assert rule < limits["tight"] < 2.0**-4.5 and limits["tight"] < limits["loose"]
+    assert limits["median"] < limits["tight"]
+
+
+def test_the_median_row_catches_what_moves_every_row_and_the_worst_row_what_moves_one():
+    """DeepSeek's readings (PERF.md section 2, PR 61) as rows: honest rows at
+    2^-7 with one at 2^-5.8 are inside all three limits; every row at 2^-4.45,
+    the fp8 control, is over the median's and the tight row's; one tight row at
+    2^-4 (a mechanism gone) is over the tight row's alone; a toss-up row may
+    read 2^-4 and not 2^-3.5."""
+    limits = check.row_limits({"num_hidden_layers": 5,
+                               "check": {"tight_row_log2": -5.0, "loose_row_log2": -3.84,
+                                         "median_row_log2": -5.5}})
+    honest = [(2.0**-7, i % 2 == 0) for i in range(32)]
+    honest[5] = (2.0**-5.8, False)
+    ok, compared = check.rows_compared(honest, limits)
+    assert ok and list(compared) == ["worst_tight_row", "worst_loose_row", "median_row"]
+    assert compared["worst_tight_row"] == [2.0**-5.8, 2.0**-5.0]
+    assert compared["median_row"] == [2.0**-7, 2.0**-5.5]
+    ok, compared = check.rows_compared([(2.0**-4.45, loose) for _, loose in honest], limits)
+    over = {name for name, (v, limit) in compared.items() if v > limit}
+    assert not ok and over == {"worst_tight_row", "median_row"}
+    one = list(honest)
+    one[5] = (2.0**-4, False)
+    ok, compared = check.rows_compared(one, limits)
+    assert not ok and compared["median_row"][0] <= compared["median_row"][1]
+    one[5], one[6] = honest[5], (2.0**-4, True)
+    assert check.rows_compared(one, limits)[0]
+    one[6] = (2.0**-3.5, True)
+    assert not check.rows_compared(one, limits)[0]
+    # under the rule alone (no group stated) the same honest rows fail: 2^-5.8 is over 2^-5.84
+    ok, compared = check.rows_compared(honest, check.row_limits({"num_hidden_layers": 5}))
+    assert not ok and "median_row" not in compared
+
+
+def test_logits_close_hands_out_each_rows_error_and_kind():
+    rng = np.random.default_rng(1)
+    ref = rng.normal(0, 1.5, (4, 64)).astype(np.float32)
+    scale = float(np.abs(ref).max())
+    off = ref.copy()
+    off[1, 3] += 2.0**-6 * scale
+    off[2, 3] += 2.0**-4 * scale
+    rows = []
+    ok, detail = check.logits_close(ref, off, 2.0**-5, routing_gaps=np.array([1, 1, 2.0**-7, 1]),
+                                    loose_tol=2.0**-3.84, row_errors=rows)
+    assert ok, detail
+    assert [loose for _, loose in rows] == [False, False, True, False]
+    assert rows[0][0] == 0 and rows[1][0] == pytest.approx(2.0**-6, rel=1e-3)
+    assert rows[2][0] == pytest.approx(2.0**-4, rel=1e-3)
+    assert not check.logits_close(ref, off, 2.0**-5, routing_gaps=np.array([1, 1, 2.0**-7, 1]),
+                                  loose_tol=2.0**-4.5)[0]
+

@@ -49,7 +49,7 @@ def test_the_cell_is_the_one_the_issue_names(resolved):
     entry = next(c for c in bench["configs"] if c["name"] == CONFIG)
     assert entry["reduced"] == ["num_hidden_layers"] == list(config["reduced_from"])
     assert entry["source"] == config["source"] and "deployment_share" not in config
-    assert bench["configs"][-1] is entry and bench["workloads"][-1] is cell
+    assert [w["name"] for w in bench["workloads"] if w["config"] == CONFIG] == [CELL]
     sm = config["engine"]["state_manager"]
     assert (config["engine"]["kv_block_size"], sm["max_context"], sm["max_ragged_batch_size"],
             sm["max_ragged_sequence_count"], config["serving"]["decode_chunk"],
@@ -142,8 +142,7 @@ def test_every_engine_key_says_why(resolved):
 def test_its_metrics_are_listed_and_each_new_one_names_a_reader_that_exists(resolved):
     bench = resolved[0]
     traced = {m["name"] for m in harness.metrics_for(bench, CELL, True)}
-    assert set(NEW_METRICS) <= traced and len(bench["per_layer"]) == 102 <= 128
-    assert [m["name"] for m in bench["per_layer"][-7:]] == list(NEW_METRICS)
+    assert set(NEW_METRICS) <= traced and len(bench["per_layer"]) <= 128
     assert {"moe_busy_pct", "moe_route_busy_pct", "attn_busy_pct", "paged_prefill_busy_pct",
             "serve_generated_tokens_per_s", "device_idle_pct", "kv_blocks_peak_pct",
             "compiles_in_window", "sched_seqs_per_step", "engine_prepare_p50_ms"} <= traced
@@ -153,7 +152,7 @@ def test_its_metrics_are_listed_and_each_new_one_names_a_reader_that_exists(reso
     layers = {m["layer"] for m in bench["per_layer"] if m["name"] not in NEW_METRICS}
     for name in NEW_METRICS:
         entry = next(m for m in bench["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL] and entry["moves"] == "tpot_p50_ms"
+        assert CELL in entry["workloads"] and entry["moves"] == "tpot_p50_ms"
         assert entry["layer"] in layers
         if name.endswith("_roofline"):
             assert (entry["unit"], entry["better"], entry["source"]) == \

@@ -23,7 +23,7 @@ NEW_METRICS = ("moe_shared_busy_pct", "attn_gate_norm_busy_pct", "dense_ffn_busy
                "paged_mixed_token_roofline")
 # what the window groups give back while a sequence DECODES past the window, and the
 # throughput the decode steps deliver: read from the program's spans and the host's clock
-RELEASE_METRICS = ("kv_window_groups_released_blocks", "kv_full_group_blocks_pct")
+RELEASE_METRICS = ("kv_window_released_blocks", "kv_full_layer_blocks_pct")
 GENERATED = "serve_generated_tokens_per_s"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 
@@ -124,8 +124,7 @@ def test_its_metrics_are_listed_and_the_ones_that_misprice_it_are_not(resolved):
     traced = {m["name"] for m in harness.metrics_for(bench, CELL, True)}
     assert set(NEW_METRICS) <= traced
     assert not {"paged_attn_roofline", "paged_window_tiled_roofline",
-                "paged_window_token_roofline", "kv_window_released_blocks",
-                "moe_rows_per_assignment"} & traced
+                "paged_window_token_roofline", "moe_rows_per_assignment"} & traced
     assert {"moe_busy_pct", "moe_route_busy_pct", "attn_busy_pct", "paged_prefill_busy_pct",
             "paged_attn_busy_pct", "step_decode_p50_ms", "step_any_p50_ms", "device_idle_pct",
             "kv_blocks_peak_pct", "compiles_in_window"} <= traced
@@ -139,8 +138,6 @@ def test_its_metrics_are_listed_and_the_ones_that_misprice_it_are_not(resolved):
     layers = {m["layer"] for m in bench["per_layer"] if m["name"] not in added}
     for name in added:
         entry = next(m for m in bench["per_layer"] if m["name"] == name)
-        # (a later cell may join the list: PR 30's test pinned its lists and PR 34 could
-        # not append this cell to ``kv_full_layer_blocks_pct``)
         assert CELL in entry["workloads"] and entry["layer"] in layers
         assert entry["moves"] == "tpot_p50_ms"
         assert entry["source"] == ("device_trace" if name in NEW_METRICS else
@@ -150,15 +147,19 @@ def test_its_metrics_are_listed_and_the_ones_that_misprice_it_are_not(resolved):
                                                f"{json.load(f)['reader']}.py"))
 
 
-def test_the_release_metrics_read_what_the_accepted_ones_read_under_names_of_their_own():
-    """``kv_window_released_blocks`` and ``kv_full_layer_blocks_pct`` are pinned to one
-    cell each by their cells' tests; the same readers and params under new names list
-    this cell, whose window groups release inside ``decode_loop``."""
-    def spec(name):
-        with open(os.path.join(tiny.REPO, "benchmark", "metrics", f"{name}.json")) as f:
-            return json.load(f)
-    assert spec("kv_window_groups_released_blocks") == spec("kv_window_released_blocks")
-    assert spec("kv_full_group_blocks_pct") == spec("kv_full_layer_blocks_pct")
+def test_the_release_metrics_are_the_accepted_ones_with_this_cell_on_their_lists(resolved):
+    """``kv_window_released_blocks`` and ``kv_full_layer_blocks_pct`` read this cell, whose
+    window groups release inside ``decode_loop``, with the reader and the params they
+    read their first cells with: one name a reading, the cells on its list (PR 61; PR 34
+    had to bring each under a second name, because those cells' tests pinned the lists)."""
+    bench = resolved[0]
+    first = {"kv_window_released_blocks": "mistral-longdoc-closed",
+             "kv_full_layer_blocks_pct": "mellum2-repoctx-closed"}
+    for name in RELEASE_METRICS:
+        entry = next(m for m in bench["per_layer"] if m["name"] == name)
+        assert {first[name], CELL} <= set(entry["workloads"])
+    assert not {"kv_window_groups_released_blocks", "kv_full_group_blocks_pct"} & \
+        {m["name"] for m in bench["per_layer"]}
 
 
 # ------------------------------------------------- generated tokens a second ---

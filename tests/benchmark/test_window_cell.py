@@ -69,7 +69,7 @@ def test_its_metrics_are_listed_and_the_whole_context_roofline_is_not(resolved):
         {"tpot_p50_ms", "serve_tokens_per_s", "setup_s"}
     for name in NEW_METRICS:
         entry = next(m for m in bench["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL]
+        assert CELL in entry["workloads"]
         with open(os.path.join(tiny.REPO, "benchmark", "metrics", f"{name}.json")) as f:
             assert os.path.exists(os.path.join(tiny.REPO, "benchmark", "readers",
                                                f"{json.load(f)['reader']}.py"))
