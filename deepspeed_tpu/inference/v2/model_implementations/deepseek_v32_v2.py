@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.inference.v2.model_implementations.latent_rows import LatentRows
+from deepspeed_tpu.inference.v2.model_implementations.latent_rows import (LatentRows,
+                                                                         _rotate_pairs)
 from deepspeed_tpu.inference.v2.model_implementations.llama_v2 import _rms, _root, _rotate_half
 from deepspeed_tpu.inference.v2.model_implementations.routed_experts import RoutedExperts
 from deepspeed_tpu.inference.v2.model_implementations.transformer_base import \
@@ -35,15 +36,6 @@ from deepspeed_tpu.inference.v2.model_implementations.transformer_base import \
 from deepspeed_tpu.models.deepseek_v32 import DeepseekV32Config
 from deepspeed_tpu.models.mellum import rotary_cos_sin
 from deepspeed_tpu.ops.pallas import latent_attention
-
-
-def _rotate_pairs(x, cos, sin):
-    """x: [T, H, D]; cos, sin: [T, 1, D/2]; rotates the INTERLEAVED pairs
-    (x[2i], x[2i + 1])."""
-    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
-    a, b = pairs[..., 0], pairs[..., 1]
-    return jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1).reshape(x.shape) \
-        .astype(x.dtype)
 
 
 def _layer_norm(x, p, eps):

@@ -31,7 +31,6 @@ layer), ``moe`` with ``moe/shared`` beside ``RaggedMoE``'s own.
 """
 
 import jax
-import numpy as np
 
 from deepspeed_tpu.inference.v2.model_implementations.kda_base import GatedDeltaRule
 from deepspeed_tpu.inference.v2.model_implementations.latent_rows import LatentRows
@@ -70,25 +69,11 @@ class KimiLinearV2Model(GatedDeltaRule, LatentRows, RoutedExperts, DSTransformer
     # -------------------------------------------------------------- counters --
     def batch_counts(self, ragged_batch, steps=1):
         """The delta rule's counts (``GatedDeltaRule._kda_counts``: ``kda_rows``,
-        ``kda_segments``, ``kda_chunk_visits``, ...), and what
-        the latent kernels' rooflines are held to, over the step's rows and
-        latent layers (over the ``steps`` of a chunk a row's position advances
-        by one a step): ``latent_rows``, the causal rows the queries attend to
-        (a row at position p, p + 1: there is no selection, every causal row
-        counts), and ``latent_context_rows``, the rows of the pool the step
-        needs at all (a sequence's context once a step, however many of its
-        rows ask)."""
-        batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
-        counts = self._kda_counts(ragged_batch, steps)
-        tok, seq = np.asarray(batch["tok_meta"]), np.asarray(batch["seq_meta"])
-        ahead = np.arange(steps, dtype=np.int64)[None, :] + 1
-        last = seq[(seq[:, 3] > 0) & (seq[:, 1] > 0), 2]
-        layers = self.num_kv_layers
-        counts.update(
-            latent_rows=int((tok[2][tok[3] > 0].astype(np.int64)[:, None] + ahead).sum()) * layers,
-            latent_context_rows=int((tok[2][last].astype(np.int64)[:, None] + ahead).sum())
-            * layers)
-        return counts
+        ``kda_segments``, ``kda_chunk_visits``, ...), and what the latent
+        kernels' rooflines are held to (``LatentRows._latent_counts``:
+        ``latent_rows``, ``latent_context_rows``)."""
+        return {**self._kda_counts(ragged_batch, steps),
+                **self._latent_counts(ragged_batch, steps)}
 
     # --------------------------------------------------------------- phases --
     @jax.named_scope("attn")

@@ -1,7 +1,9 @@
 """What the ragged models whose attention keeps LATENT rows share
 (``deepseek_v32_v2.py``: rotated, under a learned selection;
-``kimi_linear_v2.py``: position-free, every causal row): the latent KV group
-they ask of the engine and the steps of absorbed attention over it.
+``kimi_linear_v2.py``: position-free, every causal row;
+``longcat_flash_v2.py``: rotated, every causal row, two latent layers a model
+layer): the latent KV group they ask of the engine and the steps of absorbed
+attention over it.
 
 - **a latent KV group** (``kv_state_widths``): a token keeps one latent row a
   latent layer (``kv_lora_rank`` + the shared key's ``qk_rope_head_dim``,
@@ -28,8 +30,18 @@ output projection); ``latent_q`` and ``latent_kv`` are the caller's.
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deepspeed_tpu.ops.pallas import latent_attention
+
+
+def _rotate_pairs(x, cos, sin):
+    """x: [T, H, D]; cos, sin: [T, 1, D/2]; rotates the INTERLEAVED pairs
+    (x[2i], x[2i + 1])."""
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1).reshape(x.shape) \
+        .astype(x.dtype)
 
 
 class LatentRows:
@@ -45,6 +57,24 @@ class LatentRows:
     @property
     def kv_state_widths(self):
         return (latent_attention.padded_width(self._config.latent_width), )
+
+    def _latent_counts(self, ragged_batch, steps=1):
+        """What the latent kernels' rooflines are held to where attention
+        reads EVERY causal row (no selection), over the step's rows and the
+        ``num_kv_layers`` latent layers (over the ``steps`` of a chunk a row's
+        position advances by one a step): ``latent_rows``, the causal rows the
+        queries attend to (a row at position p, p + 1), and
+        ``latent_context_rows``, the rows of the pool the step needs at all (a
+        sequence's context once a step, however many of its rows ask)."""
+        batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
+        tok, seq = np.asarray(batch["tok_meta"]), np.asarray(batch["seq_meta"])
+        ahead = np.arange(steps, dtype=np.int64)[None, :] + 1
+        last = seq[(seq[:, 3] > 0) & (seq[:, 1] > 0), 2]
+        layers = self.num_kv_layers
+        return {"latent_rows":
+                int((tok[2][tok[3] > 0].astype(np.int64)[:, None] + ahead).sum()) * layers,
+                "latent_context_rows":
+                int((tok[2][last].astype(np.int64)[:, None] + ahead).sum()) * layers}
 
     def attention_arm(self, T):
         """``latent_token`` / ``latent_tiled`` (the kernels' two grids) or

@@ -54,6 +54,12 @@ _FAMILIES: Dict[str, Tuple[str, str, str, str]] = {
     # latent pool and a slot pool in one cache (the first to keep both); a leading
     # dense layer, SwiGLU experts beside a shared one after it
     "kimi_linear": ("kimi_linear", "KimiLinearConfig", "kimi_linear_v2", "KimiLinearV2Model"),
+    # serving only, as one chip's share: a layer of TWO latent-attention halves (two
+    # latent layers of the pool a model layer) and two dense halves, one routed
+    # branch carried from the first half to the end of the second, and a router
+    # some of whose outputs are experts without a bank (they return their input)
+    "longcat_flash": ("longcat_flash", "LongcatFlashConfig", "longcat_flash_v2",
+                      "LongcatFlashV2Model"),
     "opt": ("decoder", "DecoderConfig", "decoder_v2", "DecoderV2Model"),
     "falcon": ("decoder", "DecoderConfig", "decoder_v2", "DecoderV2Model"),
     "phi": ("decoder", "DecoderConfig", "decoder_v2", "DecoderV2Model"),

@@ -8,7 +8,8 @@ Reads the engine config's ``expert_parallel`` and the model's ``_config.rms_norm
 everything else is what the family states to :meth:`_build_moes`.
 
 Scopes in the device trace: ``mlp`` (a dense layer), ``moe`` with ``moe/shared``
-(the always-on expert) beside ``RaggedMoE``'s own.
+(the always-on expert) beside ``RaggedMoE``'s own (``moe/zero`` among them where
+the router has experts without a bank).
 """
 
 import jax
@@ -28,7 +29,10 @@ class RoutedExperts:
         beyond the default (softmax, renormalised over the chosen, unscaled).
         ``held`` of the ``num_experts`` from ``first_held`` on where the model
         is one chip's share of its layers: the device then counts what landed
-        here (``moe_assignments_local``) beside the banks."""
+        here (``moe_assignments_local``) beside the banks; where the router
+        also has outputs that are experts without a bank (``zero_experts``
+        among ``router``), the choices that fell on those
+        (``moe_assignments_zero``)."""
         ep_cfg = getattr(self._engine_config, "expert_parallel", None)
         share = held is not None and held < num_experts
         self._moes = [
@@ -39,6 +43,8 @@ class RoutedExperts:
         self._expert_width, self._dense_layers = width, dense_layers
         if share:
             self.moe_count_names = ("moe_banks", "moe_assignments_local", "moe_visits")
+        if router.get("zero_experts"):
+            self.moe_count_names += ("moe_assignments_zero", )
 
     def _expert_parallel(self):
         """The devices a layer's experts are spread over: 1, unless the model

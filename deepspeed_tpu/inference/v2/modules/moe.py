@@ -49,6 +49,12 @@ What the router does is the model's to say and one step for every path
 sigmoid of each (``score_func``), the ``top_k`` largest picked, of the scores or
 of score + a per-expert ``select_bias`` that picks and does not weigh, the
 chosen scores renormalised (``norm_topk_prob``) and scaled (``route_scale``).
+A model may route over experts that have NO bank (``zero_experts``: the
+router's last outputs, each of which returns its input): they are chosen and
+weighed with the others, reach no sort, no buffer and no bank, and the token's
+input times the sum of their weights is added where the token lives, under the
+scope ``zero``. The grouped path computes them; such a layer routes by sorting
+whatever the bucket, and is refused on an expert-parallel mesh.
 
 Named scopes (``jax.named_scope``, metadata only), the same four on both paths:
 ``route`` (router probabilities + capacity packing, or the sort),
@@ -100,7 +106,7 @@ class RaggedMoE:
                  expert_axis: str = groups.EXPERT_AXIS, layer_id: int = 0,
                  norm_topk_prob: bool = True, score_func: str = "softmax",
                  route_scale: float = 1.0, n_group: int = 1, topk_group: int = 1,
-                 held: Optional[int] = None, first_held: int = 0):
+                 held: Optional[int] = None, first_held: int = 0, zero_experts: int = 0):
         """``norm_topk_prob``: renormalise the ``top_k`` chosen probabilities to
         sum to 1, as the model states it (Mixtral does; a top-1 router that
         weights by the raw probability passes False). ``score_func``: how a
@@ -113,13 +119,22 @@ class RaggedMoE:
         share of a layer that several chips share): its banks are ``[held,
         ...]``, it computes the assignments that land on them and nothing for
         the rest: no exchange, no stand-in for the absent chips. The weights
-        are renormalised over all the chosen, held here or not."""
+        are renormalised over all the chosen, held here or not.
+        ``zero_experts``: the router has that many outputs MORE, behind the
+        ``num_experts`` that have banks: experts that return their input
+        (:meth:`_zero_term`). They are no chip's to hold: a share computes
+        them whole. Such a layer takes the grouped path whatever the bucket
+        (:meth:`path`) and is not built for an expert-parallel mesh."""
         if score_func not in ("softmax", "sigmoid"):
             raise ValueError(f"ragged MoE scores by softmax or sigmoid, not {score_func!r}")
         if not 1 <= top_k <= num_experts:
             raise ValueError(f"ragged MoE needs 1 <= top_k <= num_experts, got top_k={top_k} "
                              f"of {num_experts}")
+        if zero_experts < 0 or (zero_experts and n_group > 1):
+            raise ValueError(f"{zero_experts} experts without a bank under {n_group} routing "
+                             f"groups: they belong to no group")
         self.num_experts = num_experts
+        self.zero_experts = int(zero_experts)
         self.top_k = top_k
         self.norm_topk_prob = bool(norm_topk_prob)
         self.score_func = score_func
@@ -144,10 +159,13 @@ class RaggedMoE:
     def _here(self, topk_e):
         """The chosen experts as indices into THIS layer's banks: themselves,
         or for a share the local index, ``experts_here`` (no bank: every arm
-        drops it) for an expert another chip holds."""
+        drops it) for an expert another chip holds and for one that has no
+        bank anywhere (``zero_experts``)."""
         import jax.numpy as jnp
         if self.held is None:
-            return topk_e
+            if not self.zero_experts:
+                return topk_e
+            return jnp.minimum(topk_e, self.num_experts)  # an expert without a bank
         local = topk_e - self.first_held
         return jnp.where((local >= 0) & (local < self.held), local, self.held)
 
@@ -161,6 +179,8 @@ class RaggedMoE:
         """``grouped`` or ``capacity``: the path a ``tokens``-token bucket takes
         through experts ``intermediate`` wide (``modules/heuristics.py``)."""
         from deepspeed_tpu.inference.v2.modules.heuristics import moe_implementation
+        if self.zero_experts:
+            return "grouped"  # the path that computes an expert without a bank
         return moe_implementation(tokens, self.num_experts, self.top_k, self.capacity(tokens),
                                   intermediate, ep, held=self.held)
 
@@ -181,14 +201,15 @@ class RaggedMoE:
         if simulated_gating_enabled():
             # Load-testing mode: every token draws from the synthetic per-layer
             # distribution; the batch seed + replica index diversify the draw.
-            probs = simulated_expert_probs(self.layer_id, self.num_experts)
+            outputs = self.num_experts + self.zero_experts
+            probs = simulated_expert_probs(self.layer_id, outputs)
             T = h.shape[0]
             key = jax.random.PRNGKey(1000 + self.layer_id)
             if gate_seed is not None:
                 key = jax.random.fold_in(key, gate_seed)
             if replica is not None:
                 key = jax.random.fold_in(key, replica)
-            u = jax.random.uniform(key, (T, self.num_experts))
+            u = jax.random.uniform(key, (T, outputs))
             # Gumbel trick over the fixed distribution
             logits = jnp.log(probs)[None, :] - jnp.log(-jnp.log(jnp.maximum(u, 1e-9)))
             return jax.nn.softmax(logits, axis=-1)
@@ -228,6 +249,24 @@ class RaggedMoE:
         if self.route_scale != 1.0:
             topk_p = topk_p * self.route_scale
         return topk_p, topk_e
+
+    def _zero_chosen(self, topk_e, token_valid=None):
+        """bool ``[T, k]``: the live tokens' choices that are experts WITHOUT a
+        bank (``zero_experts``: the router's outputs behind ``num_experts``)."""
+        zero = topk_e >= self.num_experts
+        return zero if token_valid is None else zero & token_valid[:, None]
+
+    def _zero_term(self, h, topk_p, topk_e, token_valid=None):
+        """``h`` times the summed weights of each token's chosen experts
+        without a bank, float32 ``[T, M]``: an identity expert's output is its
+        input, so they cost one multiply where the token lives, whichever
+        chip holds the others."""
+        import jax
+        import jax.numpy as jnp
+        with jax.named_scope("zero"):
+            weight = jnp.where(self._zero_chosen(topk_e, token_valid),
+                               topk_p.astype(jnp.float32), 0.0).sum(-1, keepdims=True)
+            return h.astype(jnp.float32) * weight
 
     # ------------------------------------------------------- capacity packing --
     def _pack(self, probs, token_valid, C, dtype, select_bias=None):
@@ -364,6 +403,10 @@ class RaggedMoE:
             raise NotImplementedError(
                 "a layer that holds a share of its experts runs on one replica: the exchange "
                 "of an expert-parallel mesh would need the other chips' shares")
+        if ep > 1 and self.zero_experts:
+            raise NotImplementedError(
+                "experts without a bank (zero_experts) are computed on the grouped path of one "
+                "replica: the capacity masks of an expert-parallel mesh have no term for them")
         if ep > 1 and self.num_experts % ep == 0:
             return self._ep_forward(h, gate_w, wi, wo, token_valid, activation, mesh,
                                     ep, gate_seed, select_bias)
@@ -416,8 +459,10 @@ class RaggedMoE:
             group_sizes = (e_flat[:, None] == jnp.arange(E)[None, :]).sum(0, dtype=jnp.int32)
             if banks_out is not None:
                 here = [] if self.held is None else [group_sizes.sum(dtype=jnp.int32)]
+                zero = [self._zero_chosen(topk_e, token_valid).sum(dtype=jnp.int32)] \
+                    if self.zero_experts else []
                 banks_out.append(jnp.stack([(group_sizes > 0).sum(dtype=jnp.int32), *here,
-                                            visit_count(group_sizes)]))
+                                            visit_count(group_sizes), *zero]))
             # where each assignment's row went: the inverse permutation
             _, back = jax.lax.sort((order, slots), num_keys=1)
         with jax.named_scope("dispatch"):
@@ -429,7 +474,10 @@ class RaggedMoE:
             # no expert's: whatever the kernel left there
             out = jnp.where((e_sorted < E)[:, None], out, 0.0)
             out = out[back[:T * k]].reshape(T, k, M) * topk_p[:, :, None]
-            return out.sum(axis=1).astype(h.dtype)
+            if not self.zero_experts:
+                return out.sum(axis=1).astype(h.dtype)
+            out = out.sum(axis=1)
+        return (out + self._zero_term(h, topk_p, topk_e, token_valid)).astype(h.dtype)
 
     def _grouped_ffn(self, buf, wi, wo, group_sizes, activation):
         """The experts over expert-sorted rows [rows, M]: operands in the rows'

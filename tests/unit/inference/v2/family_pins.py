@@ -8,7 +8,8 @@ positions. ``test_family_pins.py`` holds each family to ``family_pins.json``,
 which PR 59 recorded on its PARENT tree before it moved any code (PR 60
 re-recorded it: ``moe_count_names`` gained ``moe_visits`` in every family, and the
 ``put`` / ``chunk`` hashes of the four families that hold a share of their experts
-moved with the one more count their programs return; every other hash held)::
+moved with the one more count their programs return; every other hash held; PR 62
+ADDED ``longcat_flash``'s entry and left every other as it was)::
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
         python -m tests.unit.inference.v2.family_pins --record
@@ -33,7 +34,7 @@ from tests.unit.inference.v2.program_hashes import _stable
 
 TABLE = os.path.join(os.path.dirname(__file__), "family_pins.json")
 FAMILIES = ("mixtral", "mistral", "mellum", "afmoe", "sdar_moe", "deepseek_v32", "nemotron_h",
-            "falcon_h1", "solar_open2", "kimi_linear")
+            "falcon_h1", "solar_open2", "kimi_linear", "longcat_flash")
 # the put's feeds (mixed lengths; whole blocks of 4 for the block-diffusion family) and the
 # chunk's positions a sequence
 FEEDS, CHUNK = (16, 4, 8), 8
@@ -79,6 +80,10 @@ def _engine(family):
         from tests.unit.inference.v2 import test_kimi_linear as t
         cfg = m.KimiLinearConfig.tiny(dtype=jnp.float32, experts_held=4, expert_rank=1,
                                       **t.LAYERS)
+    elif family == "longcat_flash":
+        from deepspeed_tpu.models import longcat_flash as m
+        from tests.unit.inference.v2 import test_longcat_flash as t
+        cfg = m.LongcatFlashConfig.tiny(dtype=jnp.float32, experts_held=4, expert_rank=1)
     else:
         raise ValueError(family)
     return t.engine_of(cfg, m.init_params(cfg, rng=key)[1])

@@ -79,7 +79,18 @@ def test_put_program_carries_the_scope(lowered, family, arm, scope):
         f"no operation of the {family} {arm} program is under {scope}"
 
 
+def _operations(text):
+    """``text`` without its SOURCE locations (``loc("/.../file.py":line...)``): a
+    jitted helper (``jax.nn.silu``) is traced once a shape a process, and its
+    cached trace carries the file of whoever called it FIRST — after another
+    test file on the same worker that is ``deepspeed_tpu/moe/layer.py``, whose
+    path holds "/moe/" (the one red test of the driver's whole runs to PR 61).
+    What is asked here is the operations' scope paths."""
+    return re.sub(r'loc\("[^"]*\.py":[^)]*\)', "", text)
+
+
 def test_scopes_name_no_layer_and_dense_models_have_no_moe(lowered):
+    lowered = {key: _operations(text) for key, text in lowered.items()}
     assert "/moe/" not in lowered["llama", "gather"] and "/mlp/" not in lowered["mixtral", "gather"]
     assert "/attn/gather/" not in lowered["llama", "kernel"]
     # a prefill bucket on the kernel arm: the tiled kernel, no scatter, no gather

@@ -1275,3 +1275,94 @@ def test_kimi_decode_loop_program_fits_one_chip(v5e, kimi_model):
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert _kimi_pools_in_place(text)
     assert _kimi_same_cache(jax.eval_shape(loop, params, cache, batch)[1], cache)
+
+
+# ---- longcat-flash-omni-serve-1chip: two latent layers of the pool a model layer (PR 62) ----
+LONGCAT_BLOCKS, LONGCAT_BLOCK, LONGCAT_TABLE, LONGCAT_SEQS = 2048, 128, 64, 32
+
+
+@pytest.fixture(scope="module")
+def longcat_model():
+    """``longcat-flash-omni-serve-1chip``: LongCat-Flash-Omni's published widths
+    (64 heads of 128 + 64 over a 512 + 64 latent, dense halves of 12288, experts
+    of 2048), 4 of 28 layers = EIGHT latent layers of the pool, 16 of the 512
+    routed experts held beside 256 identity experts, an eighth of the
+    vocabulary, contexts to 8192 = a 64-entry table, ONE sequence bucket of 32,
+    over ``jax.eval_shape``d parameters."""
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.model_implementations.registry import model_cls_for
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import DSStateManagerConfig
+    from deepspeed_tpu.models import longcat_flash
+    cfg = longcat_flash.LongcatFlashConfig(num_layers=4, vocab_size=16384, experts_held=16,
+                                           expert_rank=11)
+    abstract = jax.eval_shape(lambda: longcat_flash.init_params(cfg, param_dtype=cfg.dtype)[1])
+    engine_config = RaggedInferenceEngineConfig(
+        state_manager=DSStateManagerConfig(max_context=8192, max_ragged_batch_size=256,
+                                           max_ragged_sequence_count=LONGCAT_SEQS),
+        kv_block_size=LONGCAT_BLOCK, use_paged_kernel=True,
+        expert_parallel={"capacity_factor": 43.0})
+    model = model_cls_for(cfg)(abstract, cfg, engine_config)
+    assert model.num_kv_layers == 8 and model.min_table_bucket == LONGCAT_TABLE
+    assert model.min_sequence_bucket == LONGCAT_SEQS and model.kv_state_widths == (640, )
+    assert model.moe_count_names[-1] == "moe_assignments_zero"
+    return model, abstract
+
+
+def _longcat_args(device, abstract, tokens):
+    one = SingleDeviceSharding(device)
+    params = jax.tree.map(lambda leaf: _on(one, leaf.shape, leaf.dtype), abstract)
+    cache = (_on(one, (8, LONGCAT_BLOCKS, LONGCAT_BLOCK, 640), jnp.bfloat16), )
+    batch = {"tok_meta": _on(one, (4, tokens), jnp.int32),
+             "seq_meta": _on(one, (LONGCAT_SEQS, 4 + LONGCAT_TABLE), jnp.int32)}
+    return params, cache, batch
+
+
+def _longcat_pool_in_place(text):
+    """No copy of the 2.5 GiB latent pool."""
+    import re
+    return not [line for line in text.splitlines()
+                if re.search(rf"= bf16\[8,{LONGCAT_BLOCKS},{LONGCAT_BLOCK},640\]\S* copy\(", line)]
+
+
+@pytest.mark.parametrize("tokens", [256, 32])
+def test_longcat_put_program_fits_one_chip(v5e, longcat_model, tokens):
+    """A ``put`` program (the 256-token chunk bucket on the tiled latent grid
+    at 64 query heads; the 32-token bucket, a decode row a sequence, on the
+    token grid): 9.7 GiB of weights beside 2.5 GiB of latent rows, one latent
+    kernel a HALF-layer, the grouped matmul over the 16 held banks ``[16, 6144,
+    2 x 2048]`` / ``[16, 2048, 6144]`` once a layer, and the identity experts'
+    term under ``moe/zero``."""
+    model, abstract = longcat_model
+    grid = "latent_tiled" if tokens > 32 else "latent_token"
+    assert model.moe_path(tokens) == "grouped" and model.attention_arm(tokens) == grid
+    params, cache, batch = _longcat_args(v5e[0], abstract, tokens)
+    compiled = jax.jit(model._forward_impl, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert len([line for line in _kernel_calls(text, grid.replace("latent_", "latent_paged_attention_"))
+                if "attn/latent_kernel" in line]) == 8
+    assert "grouped_matmul" in text and "moe/zero" in text and "mlp" in text
+    assert "latent_index_scores" not in text and "paged_attention_prefill" not in text
+    assert _device_bytes(compiled) < 0.85 * HBM_BYTES
+    assert _longcat_pool_in_place(text)
+    out = jax.eval_shape(model._forward_impl, params, cache, batch)
+    assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
+    assert out[2].shape == (4, 4)  # banks, local assignments, visits, identity choices a layer
+
+
+def test_longcat_decode_loop_program_fits_one_chip(v5e, longcat_model):
+    """``decode_loop``'s scan at 32 rows a step: the per-token latent grid
+    walking a 64-entry table in eight latent layers, the pool handed back in
+    the type it came in, the counts a step a layer beside the tokens."""
+    model, abstract = longcat_model
+    params, cache, batch = _longcat_args(v5e[0], abstract, 32)
+    loop = functools.partial(model._decode_loop_impl, n_steps=8)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert len([line for line in _kernel_calls(text, "latent_paged_attention_token")
+                if "attn/latent_kernel" in line]) == 8
+    assert "grouped_matmul" in text and "moe/zero" in text
+    assert _device_bytes(compiled) < 0.85 * HBM_BYTES
+    assert _longcat_pool_in_place(text)
+    out = jax.eval_shape(loop, params, cache, batch)
+    assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
+    assert out[2].shape == (8, 4, 4)
