@@ -93,14 +93,16 @@ class DeepseekV32V2Model(LatentRows, RoutedExperts, DSTransformerModelBase):
         """``index_keys``: keys the indexer scores over the step's rows and
         layers (a row at position p scores p + 1; none in a bucket that selects
         everything); ``index_selected``: keys attention then reads. Over the
-        ``steps`` of a chunk a row's position advances by one a step."""
+        ``steps`` of a chunk a row's position advances by one a step. Beside
+        them the tiled grid's passes (``LatentRows._latent_passes``)."""
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
         tok = np.asarray(batch["tok_meta"])
         keys = (tok[2][tok[3] > 0].astype(np.int64)[:, None] + 1 + np.arange(steps)[None, :])
         attended = int(np.minimum(keys, self._config.index_topk).sum()) * self.num_layers
         scored = int(keys.sum()) * self.num_layers if self.selects(self._bucket_of(batch)[2]) \
             else 0
-        return {"index_keys": scored, "index_selected": attended if scored else 0}
+        return {"index_keys": scored, "index_selected": attended if scored else 0,
+                **self._latent_passes(batch, steps)}
 
     # --------------------------------------------------------------- phases --
     @jax.named_scope("attn")

@@ -65,7 +65,8 @@ class LatentRows:
         position advances by one a step): ``latent_rows``, the causal rows the
         queries attend to (a row at position p, p + 1), and
         ``latent_context_rows``, the rows of the pool the step needs at all (a
-        sequence's context once a step, however many of its rows ask)."""
+        sequence's context once a step, however many of its rows ask). Beside
+        them the tiled grid's passes (:meth:`_latent_passes`)."""
         batch = ragged_batch.device_batch if hasattr(ragged_batch, "device_batch") else ragged_batch
         tok, seq = np.asarray(batch["tok_meta"]), np.asarray(batch["seq_meta"])
         ahead = np.arange(steps, dtype=np.int64)[None, :] + 1
@@ -74,7 +75,23 @@ class LatentRows:
         return {"latent_rows":
                 int((tok[2][tok[3] > 0].astype(np.int64)[:, None] + ahead).sum()) * layers,
                 "latent_context_rows":
-                int((tok[2][last].astype(np.int64)[:, None] + ahead).sum()) * layers}
+                int((tok[2][last].astype(np.int64)[:, None] + ahead).sum()) * layers,
+                **self._latent_passes(batch, steps)}
+
+    def _latent_passes(self, batch, steps=1):
+        """A bucket on the tiled latent grid: ``latent_passes``, the (sequence,
+        tile) passes attention's kernel makes over the latent layers (and a
+        chunk's ``steps``, all alike), and ``latent_rider_passes``, those of
+        them that own ONE token of their tile and walk as the per-token grid
+        does (``ops/pallas/latent_attention.py:tiled_passes``)."""
+        bucket_tokens = batch["tok_meta"].shape[1]
+        if self.attention_arm(bucket_tokens) != "latent_tiled":
+            return {}
+        seq = np.asarray(batch["seq_meta"])
+        counts = latent_attention.tiled_passes(seq[:, 1], seq[:, 2], bucket_tokens,
+                                               self._config.num_attention_heads)
+        return {name: n * self.num_kv_layers * steps
+                for name, n in zip(("latent_passes", "latent_rider_passes"), counts)}
 
     def attention_arm(self, T):
         """``latent_token`` / ``latent_tiled`` (the kernels' two grids) or

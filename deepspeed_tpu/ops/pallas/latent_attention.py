@@ -21,10 +21,16 @@ the ragged batch, and inside a tile one pass per sequence that has tokens in it
 (``seq_seen`` / ``seq_ntok`` / ``last_tok``: the scalar prefetch of
 ``paged_attention_prefill``); a pass walks the sequence's block table in
 double-buffered chunks. ``tq`` = 1 for the decode buckets (a tile is one token:
-its 128 heads are the MXU's rows), ``TQ_TILED`` for prefill and mixed buckets
-(rows = tokens x heads; a pass that owns ONE token of a tile, a decode row
-riding beside a chunk, computes that token's rows alone). The new rows are in the pools already:
-the caller scatters them (one ``[tokens, W]`` update a pool, in place).
+its heads are the MXU's rows); for prefill and mixed buckets
+(:func:`tile_tokens`) attention's tile is as many tokens as give ``TILE_ROWS``
+rows = tokens x heads whatever the head count, and the geometry of a PASS
+follows what the pass owns: several tokens of the tile, the tile's rows
+against two blocks of keys an iteration (its matmuls bind it); ONE token (a
+decode row riding beside a chunk: half of a mixed step's passes), that token's
+``heads`` rows alone against eight blocks an iteration, as the per-token grid
+walks (the copies' latency binds it), and its walk handed on to the next such
+pass of the tile. The new rows are in the pools already: the caller scatters
+them (one ``[tokens, W]`` update a pool, in place).
 
 ``latent_index_scores``: ``I(t, s) = sum_j w[t, j] relu(q_I[t, j] . k_I[s])``
 for every key s <= t of t's sequence, float32 ``[tokens, max_blocks x
@@ -50,9 +56,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.pallas import paged_attention
+
 NEG_INF = -1e30
-TQ_TILED = 8  # tokens of a query tile on the tiled grid
 TOKEN_GRID_MAX = 32  # largest bucket the per-token grid takes
+TILE_ROWS = 1024  # MXU rows (tokens x heads) of attention's tile on the tiled grid ...
+TILE_TOKENS_MIN = 8  # ... and the fewest tokens a tile holds (the index kernel's tile)
+# blocks fetched a loop iteration: a one-token pass's walk is bound by the DMAs'
+# latency (eight in flight), a many-token pass's by its matmuls (two)
+ONE_TOKEN_BLOCKS, TILE_BLOCKS = 8, 2
 LANES = 128
 
 
@@ -61,14 +73,32 @@ def padded_width(width):
     return -(-width // LANES) * LANES
 
 
-def tile_tokens(bucket_tokens):
-    return 1 if bucket_tokens <= TOKEN_GRID_MAX else TQ_TILED
+def tile_tokens(bucket_tokens, heads=None):
+    """Tokens of a query tile: 1 on the per-token grid; on the tiled grid the
+    floor, or for a call that says its ``heads`` (attention's) the power of two
+    that gives ``TILE_ROWS`` rows (32 tokens of 32 heads, 16 of 64, 8 of 128
+    and more), cut to one that divides the bucket."""
+    if bucket_tokens <= TOKEN_GRID_MAX:
+        return 1
+    if heads is None:
+        return TILE_TOKENS_MIN
+    tq = max(TILE_TOKENS_MIN, 1 << (max(TILE_ROWS // heads, 1).bit_length() - 1))
+    while bucket_tokens % tq:
+        tq //= 2
+    return tq
 
 
-def _chunk_blocks(tq, max_blocks):
-    """Blocks fetched a loop iteration: a decode token's walk is bound by the
-    DMAs' latency (eight in flight), a tile's by its matmuls (two)."""
-    return min(8 if tq == 1 else 2, max_blocks)
+def _chunk_blocks(one_token, max_blocks):
+    return min(ONE_TOKEN_BLOCKS if one_token else TILE_BLOCKS, max_blocks)
+
+
+def tiled_passes(seq_ntok, last_tok, bucket_tokens, heads):
+    """The (sequence, tile) pairs attention's tiled call works through and those
+    of them that own ONE token of their tile (the deep walk), from the host's
+    copy of the scalar prefetch: ``_attn_kernel``'s rule is the paged kernel's,
+    at this grid's tile."""
+    return paged_attention.tiled_passes(seq_ntok, last_tok, bucket_tokens,
+                                        tile=tile_tokens(bucket_tokens, heads))[:2]
 
 
 def _each(n, fn):
@@ -92,14 +122,21 @@ def _for_each_pass(S, tq, t0, seen_ref, ntok_ref, last_ref, fn):
     _each(S, sequence)
 
 
-def _walk(table_ref, pool_ref, li, s, MB, bs, chunk, nblocks, buf, sems, body):
+def _walk(table_ref, pool_ref, li, s, MB, bs, chunk, nblocks, buf, sems, body, chain=None):
     """``body(c, slot)`` for each chunk c of ``chunk`` blocks of sequence s's
     first ``nblocks`` blocks, block j of the chunk in rows ``j*bs..`` of
     ``buf[slot]``. A block past the last is the last again (its keys are past
-    every query: masked by position)."""
+    every query: masked by position).
+
+    ``chain`` = ``(state, follows, s_next, nblocks_next)`` hands the walk on from
+    one one-token pass of a tile to the next: ``state`` (SMEM ``[2]``) says
+    whether this walk's first chunk is in flight already and in which slot,
+    and where a one-token pass of sequence ``s_next`` ``follows`` in the tile,
+    its first chunk is started behind this walk's last (a pass neither starts
+    its walk cold nor ends on a chunk with no copy behind it)."""
     nchunks = pl.cdiv(nblocks, chunk)
 
-    def copies(c, slot):
+    def copies(s, nblocks, c, slot):
         out = []
         for j in range(chunk):
             b = jnp.minimum(c * chunk + j, nblocks - 1)
@@ -108,22 +145,33 @@ def _walk(table_ref, pool_ref, li, s, MB, bs, chunk, nblocks, buf, sems, body):
                                              buf.at[slot, pl.ds(j * bs, bs)], sems.at[slot, j]))
         return out
 
-    for cp in copies(0, 0):
-        cp.start()
+    def start(*chunk_of):
+        for cp in copies(*chunk_of):
+            cp.start()
+
+    if chain is None:
+        first = 0
+        start(s, nblocks, 0, 0)
+    else:
+        state, follows, s_next, nblocks_next = chain
+        in_flight = state[0] == 1
+        first = jnp.where(in_flight, state[1], 0)
+        pl.when(jnp.logical_not(in_flight))(lambda: start(s, nblocks, 0, 0))
 
     def one(c):
-        slot = jax.lax.rem(c, 2)
-
-        @pl.when(c + 1 < nchunks)
-        def _():
-            for cp in copies(c + 1, 1 - slot):
-                cp.start()
-
-        for cp in copies(c, slot):
+        slot = jax.lax.rem(first + c, 2)
+        pl.when(c + 1 < nchunks)(lambda: start(s, nblocks, c + 1, 1 - slot))
+        if chain is not None:
+            pl.when((c + 1 == nchunks) & follows)(
+                lambda: start(s_next, nblocks_next, 0, 1 - slot))
+        for cp in copies(s, nblocks, c, slot):
             cp.wait()
         body(c, slot)
 
     _each(nchunks, one)
+    if chain is not None:
+        state[0] = follows.astype(jnp.int32)
+        state[1] = jax.lax.rem(first + nchunks, 2)  # the slot behind the last chunk's
 
 
 def _row_positions(tq, t0, lo, hi, shift):
@@ -192,7 +240,7 @@ def latent_index_scores(q_index, weights, index_pool, layer_idx, block_table, se
     S, MB = block_table.shape
     tq = tile_tokens(T)
     assert D == Dc and T % tq == 0
-    chunk = _chunk_blocks(tq, MB)
+    chunk = _chunk_blocks(tq == 1, MB)
     assert MB % chunk == 0
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -242,33 +290,38 @@ def kth_largest(scores, k):
 
 
 # --------------------------------------------------------------- attention --
-def _attn_kernel(S, MB, bs, tq, H, C, chunk, selected, precision,
+def _attn_kernel(S, MB, bs, tq, H, C, chunk, deep, selected, precision,
                  layer_ref, table_ref, seen_ref, ntok_ref, last_ref, *refs):
     """Queries, softmax state and output are ``[tq, H, .]``: a token is an index
     of the leading (untiled) dimension, its heads the sublanes. A pass that owns
-    ONE token of the tile (a decode row riding beside a chunk: most of a mixed
-    step's passes) computes that token's ``H`` rows alone; a pass that owns
-    several computes the tile's ``tq x H`` rows under the tokens' masks."""
+    ONE token of the tile (a decode row riding beside a chunk: half of a mixed
+    step's passes) computes that token's ``H`` rows alone over ``deep`` blocks
+    an iteration, its walk handed on to a one-token pass that follows it in the
+    tile (``_walk``'s ``chain``; ``state`` carries it); a pass that owns several
+    computes the tile's ``tq x H`` rows under the tokens' masks over ``chunk``
+    blocks an iteration (the first of the buffer's ``deep``)."""
     if selected:
-        q_ref, scores_ref, thr_ref, pool_ref, out_ref, m_s, l_s, acc_s, k_buf, sems = refs
+        q_ref, scores_ref, thr_ref, pool_ref, out_ref, m_s, l_s, acc_s, k_buf, sems, state = refs
     else:
-        q_ref, pool_ref, out_ref, m_s, l_s, acc_s, k_buf, sems = refs
+        q_ref, pool_ref, out_ref, m_s, l_s, acc_s, k_buf, sems, state = refs
     li = layer_ref[0]
     t0 = pl.program_id(0) * tq  # read here: not inside a loop's or a branch's body
     m_s[...] = jnp.full_like(m_s, NEG_INF)
     l_s[...] = jnp.zeros_like(l_s)
     acc_s[...] = jnp.zeros_like(acc_s)
-    width = chunk * bs
+    state[0] = 0  # no walk is handed on from another tile
 
-    def attend(n, at, q_pos, nblocks, s):
+    def attend(n, at, q_pos, nblocks, s, blocks, chain=None):
         """Online softmax of ``n`` tokens' rows (``at``: their index in the
-        tile's leading dimension) over sequence s's first ``nblocks`` blocks;
-        ``q_pos`` ``[n, 1, 1]``: their positions, -1 for a token of another
-        pass (it sees no key)."""
+        tile's leading dimension) over sequence s's first ``nblocks`` blocks,
+        ``blocks`` of them an iteration; ``q_pos`` ``[n, 1, 1]``: their
+        positions, -1 for a token of another pass (it sees no key); ``chain``:
+        ``_walk``'s."""
+        width = blocks * bs
         q = q_ref[0, at].reshape(n * H, q_ref.shape[-1])  # the softmax scale folded in
 
         def body(c, slot):
-            rows = k_buf[slot]  # [width, W]
+            rows = k_buf[slot, pl.ds(0, width)]  # [width, W]
             logits = jax.lax.dot_general(q, rows, (((1, ), (1, )), ((), ())),
                                          preferred_element_type=jnp.float32,
                                          precision=precision).reshape(n, H, width)
@@ -293,26 +346,44 @@ def _attn_kernel(S, MB, bs, tq, H, C, chunk, selected, precision,
                          + pv.reshape(n, H, C)).reshape(acc_s[at].shape)
             m_s[at] = m_new.reshape(m_s[at].shape)
 
-        _walk(table_ref, pool_ref, li, s, MB, bs, chunk, nblocks, k_buf, sems, body)
+        _walk(table_ref, pool_ref, li, s, MB, bs, blocks, nblocks, k_buf, sems, body, chain)
 
     def one_pass(s, lo, hi, shift):
         nblocks = jnp.minimum((hi + shift) // bs + 1, MB)
         if tq == 1:
-            attend(1, slice(None), jnp.full((1, 1, 1), lo + shift, jnp.int32), nblocks, s)
+            attend(1, slice(None), jnp.full((1, 1, 1), lo + shift, jnp.int32), nblocks, s, deep)
             return
 
         @pl.when(lo == hi)
         def _():
-            attend(1, lo - t0, jnp.full((1, 1, 1), lo + shift, jnp.int32), nblocks, s)
+            # a decode row of the NEXT sequence in this tile: its walk is handed on
+            s_next = jnp.minimum(s + 1, S - 1)
+            at_next = last_ref[s_next]
+            follows = (s + 1 < S) & (ntok_ref[s_next] == 1) & (at_next >= t0) & (at_next < t0 + tq)
+            attend(1, lo - t0, jnp.full((1, 1, 1), lo + shift, jnp.int32), nblocks, s, deep,
+                   (state, follows, s_next, jnp.minimum(seen_ref[s_next] // bs + 1, MB)))
 
         @pl.when(lo != hi)
         def _():
             tok = t0 + jax.lax.broadcasted_iota(jnp.int32, (tq, 1, 1), 0)
             attend(tq, slice(None), jnp.where((tok >= lo) & (tok <= hi), tok + shift, -1),
-                   nblocks, s)
+                   nblocks, s, chunk)
 
     _for_each_pass(S, tq, t0, seen_ref, ntok_ref, last_ref, one_pass)
     out_ref[0] = (acc_s[...] / jnp.maximum(l_s[...], 1e-20)).astype(out_ref.dtype)
+
+
+def attention_vmem_bytes(tq, H, W, C, bs, chunk, deep, itemsize, selected_keys=0):
+    """VMEM attention's call holds: the pipelined query and output tiles (and a
+    selection's scores) twice, the softmax state (a per-row scalar pads to 128
+    lanes), the double-buffered rows of the deeper walk, and the widest
+    iteration's values (a many-token pass's logits and weights in float32, the
+    weights as operands, their product with the rows; a one-token pass's over
+    the deeper walk)."""
+    rows = tq * H
+    step = max(rows * chunk, H * deep) * bs * (4 + 4 + itemsize) + rows * C * 4
+    return (2 * rows * (W + C) * itemsize + 2 * tq * (selected_keys + LANES) * 4
+            + rows * (C + 2 * LANES) * 4 + 2 * deep * bs * W * itemsize + step)
 
 
 @functools.partial(jax.jit, static_argnames=("value_width", "interpret"))
@@ -327,10 +398,10 @@ def latent_paged_attention(q, latent_pool, layer_idx, block_table, seq_seen, seq
     T, H, W = q.shape
     _, _, bs, Wc = latent_pool.shape
     S, MB = block_table.shape
-    tq = tile_tokens(T)
+    tq = tile_tokens(T, H)
     assert W == Wc and T % tq == 0
-    chunk = _chunk_blocks(tq, MB)
-    assert MB % chunk == 0
+    chunk, deep = _chunk_blocks(tq == 1, MB), _chunk_blocks(True, MB)
+    assert MB % deep == 0
     selected = scores is not None
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -350,15 +421,19 @@ def latent_paged_attention(q, latent_pool, layer_idx, block_table, seq_seen, seq
         scratch_shapes=[pltpu.VMEM((tq, H, 1), jnp.float32),
                         pltpu.VMEM((tq, H, 1), jnp.float32),
                         pltpu.VMEM((tq, H, value_width), jnp.float32),
-                        pltpu.VMEM((2, chunk * bs, W), op_dtype),
-                        pltpu.SemaphoreType.DMA((2, chunk))])
-    kernel = functools.partial(_attn_kernel, S, MB, bs, tq, H, value_width, chunk, selected,
-                               _precision(op_dtype))
+                        pltpu.VMEM((2, deep * bs, W), op_dtype),
+                        pltpu.SemaphoreType.DMA((2, deep)),
+                        pltpu.SMEM((2, ), jnp.int32)])
+    kernel = functools.partial(_attn_kernel, S, MB, bs, tq, H, value_width, chunk, deep,
+                               selected, _precision(op_dtype))
+    held = attention_vmem_bytes(tq, H, W, value_width, bs, chunk, deep, op_dtype.itemsize,
+                                MB * bs if selected else 0)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T // tq, tq, H, value_width), q.dtype),
         interpret=interpret,
         name="latent_paged_attention_token" if tq == 1 else "latent_paged_attention_tiled",
+        **paged_attention.vmem_params(held),
     )(*_meta(layer_idx, block_table, seq_seen, seq_ntok, last_tok), *operands, latent_pool)
     return out.reshape(T, H, value_width)
 

@@ -131,15 +131,15 @@ def vmem_params(held: int) -> dict:
         vmem_limit_bytes=min(held * 5 // 4, VMEM_CEILING_BYTES))}
 
 
-def tiled_passes(seq_ntok, last_tok, bucket_tokens, block=0):
+def tiled_passes(seq_ntok, last_tok, bucket_tokens, block=0, tile=TQ):
     """The (sequence, tile) pairs a query-tiled call works through, those that
     own ONE token of their tile, and those that take the few-row arm (no more
     rows than one block of ``block``; the one-token ones without a block mask),
     by ``_tiled_kernel``'s rule, from the host's copy of the scalar prefetch
-    (numpy ``[S]``)."""
+    (numpy ``[S]``); ``tile``: the tile's tokens, for a grid with another."""
     n, last = np.asarray(seq_ntok)[:, None], np.asarray(last_tok)[:, None]
-    t0 = np.arange(0, bucket_tokens, TQ)[None, :]
-    lo, hi = np.maximum(last - n + 1, t0), np.minimum(last, t0 + TQ - 1)
+    t0 = np.arange(0, bucket_tokens, tile)[None, :]
+    lo, hi = np.maximum(last - n + 1, t0), np.minimum(last, t0 + tile - 1)
     owned = (n > 0) & (lo <= hi)
     return (int(owned.sum()), int((owned & (lo == hi)).sum()),
             int((owned & (hi - lo < max(block, 1))).sum()))

@@ -322,6 +322,14 @@ def test_the_spans_name_the_arm_and_count_the_index_and_the_local_assignments(mo
     assert m.batch_counts(short) == {"index_keys": 0, "index_selected": 0}
     assert engine.moe_counts(np.array([[3, 5, 4], [2, 4, 2]])) == \
         {"moe_banks": 5, "moe_assignments_local": 9, "moe_visits": 6}
+    # on the tiled grid the span counts the kernel's passes too: a 20-token chunk beside two
+    # decode rows in one tile of 64 tokens (4 heads), over the 3 layers; none on the token grid
+    tiled = {"tok_meta": np.zeros((4, 64), np.int32), "seq_meta": np.zeros((8, 4 + 2), np.int32)}
+    tiled["seq_meta"][:3, 1:3] = [(1, 0), (1, 1), (20, 21)]
+    served = engine_of(cfg, params, True).model
+    assert served.batch_counts(tiled) == {"index_keys": 0, "index_selected": 0,
+                                          "latent_passes": 3 * 3, "latent_rider_passes": 2 * 3}
+    assert "latent_passes" not in served.batch_counts(short) | m.batch_counts(tiled)
 
 
 def test_a_model_with_a_group_limit_that_holds_every_expert_is_served_as_afmoe():

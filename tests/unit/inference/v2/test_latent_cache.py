@@ -114,12 +114,29 @@ def _case(rng, T, seqs, S=8, MB=4, bs=16, H=4, C=32, R=8, NH=8, D=16):
                 kernel=(table, seen, ntok, last), xla=(table, tseq, tpos, tval), valid=tval)
 
 
-@pytest.mark.parametrize("T, seqs", [
-    (8, [(40, 1), (3, 1), (63, 1), (0, 1), (17, 1)]),          # the per-token grid: decode rows
-    (64, [(30, 20), (3, 1), (0, 35), (50, 1), (17, 3)]),       # the tiled grid: chunks + riders
-], ids=["token-grid", "tiled-grid"])
-def test_the_kernels_in_interpret_mode_are_the_jax_numpy_arm(T, seqs):
-    c = _case(np.random.default_rng(T), T, seqs)
+# a step on the tiled grid as the kernel's geometries meet it (16 blocks of 16: a one-token
+# pass walks 128 keys an iteration): a chunk that crosses a tile's edge at 8 and 16, a rider
+# in the same tile as that chunk's last tokens, a chunk that crosses the edge at 32, a
+# sequence of no tokens, then riders (each hands its walk on to the next in its tile; not
+# over a tile's edge, tokens 63 | 64, nor over a sequence of no tokens) whose contexts are
+# one key, shorter than one deep chunk, one whole, not a whole number and the whole table,
+# the last of them a tile of riders only (tokens 56.., 64.. at 16 and 32 tokens a tile)
+# before rows of no sequence
+_MIXED = [(30, 20), (200, 1), (0, 35), (17, 0), (0, 1), (5, 1), (127, 1), (130, 1), (255, 1),
+          (40, 1), (77, 1), (128, 1), (3, 1), (9, 0), (254, 1), (100, 1), (19, 1), (64, 1)]
+
+
+@pytest.mark.parametrize("selected", [False, True], ids=["every-key", "selected"])
+@pytest.mark.parametrize("T, seqs, tile, sizes", [
+    (8, [(40, 1), (3, 1), (63, 1), (0, 1), (17, 1)], 1, {}),     # the per-token grid: decode rows
+    (64, [(30, 20), (3, 1), (0, 35), (50, 1), (17, 3)], 64, {}),  # the tiled grid: one tile
+    (128, _MIXED, 8, dict(S=18, MB=16, H=128)),
+    (128, _MIXED, 16, dict(S=18, MB=16, H=64)),
+    (128, _MIXED, 32, dict(S=18, MB=16, H=32)),
+], ids=["token-grid", "tiled-grid", "tile-of-8", "tile-of-16", "tile-of-32"])
+def test_the_kernels_in_interpret_mode_are_the_jax_numpy_arm(T, seqs, tile, sizes, selected):
+    c = _case(np.random.default_rng(T), T, seqs, **sizes)
+    assert la.tile_tokens(T, c["q"].shape[1]) == tile
     scores_x = np.asarray(la.latent_index_scores_xla(c["q_i"], c["w_i"], c["index"], 1, *c["xla"]))
     scores_k = np.asarray(la.latent_index_scores(c["q_i"], c["w_i"], c["index"], 1, *c["kernel"],
                                                  interpret=True))
@@ -129,14 +146,26 @@ def test_the_kernels_in_interpret_mode_are_the_jax_numpy_arm(T, seqs):
     threshold = la.kth_largest(jnp.asarray(scores_k), 8)
     kept = (scores_k >= np.asarray(threshold)[:, None]) & live
     assert (kept.sum(1)[c["valid"]] == np.minimum(live.sum(1), 8)[c["valid"]]).all()
-    for selection in ((), (jnp.asarray(scores_k), threshold)):
-        want = np.asarray(la.latent_paged_attention_xla(c["q"], c["latent"], 1, *c["xla"],
-                                                        *selection, value_width=c["C"]))
-        got = np.asarray(la.latent_paged_attention(c["q"], c["latent"], 1, *c["kernel"],
-                                                   *selection, value_width=c["C"],
-                                                   interpret=True))
-        assert np.abs(got - want).max() < 1e-4
-        assert np.abs(got[~c["valid"]]).max() == 0  # a row of no sequence is zero
+    selection = (jnp.asarray(scores_k), threshold) if selected else ()
+    want = np.asarray(la.latent_paged_attention_xla(c["q"], c["latent"], 1, *c["xla"],
+                                                    *selection, value_width=c["C"]))
+    got = np.asarray(la.latent_paged_attention(c["q"], c["latent"], 1, *c["kernel"],
+                                               *selection, value_width=c["C"], interpret=True))
+    assert np.abs(got - want).max() < 1e-4
+    assert np.abs(got[~c["valid"]]).max() == 0  # a row of no sequence is zero
+
+
+@pytest.mark.parametrize("heads, tile, chunk_tiles", [(32, 32, 7), (64, 16, 14), (128, 8, 28)])
+def test_the_host_counts_the_passes_the_tiled_kernel_makes(heads, tile, chunk_tiles):
+    """``tiled_passes`` by ``_attn_kernel``'s rule: 32 decode rows beside a
+    224-token chunk are 32 rider passes and the chunk's tiles; a sequence of no
+    tokens is no pass; a chunk's last tile that owns ONE token walks as a rider."""
+    ntok = np.array([1] * 32 + [0, 224], np.int32)
+    last = np.cumsum(ntok).astype(np.int32) - 1
+    assert la.tile_tokens(256, heads) == tile
+    assert la.tiled_passes(ntok, last, 256, heads) == (32 + chunk_tiles, 32)
+    ntok = np.array([tile + 1, 0, 5], np.int32)  # tokens 0..tile, then tile + 1 .. tile + 5
+    assert la.tiled_passes(ntok, np.cumsum(ntok) - 1, 256, heads) == (3, 1)
 
 
 def test_kth_largest_is_exact_on_ties_negatives_and_short_rows():

@@ -145,6 +145,12 @@ def test_the_kernels_in_interpret_mode_are_the_jax_numpy_arm(model):
     engine = engine_of(cfg, params, kernel=True)
     assert engine.model.attention_arm(64) == "latent_tiled" \
         and engine.model.attention_arm(8) == "latent_token"
+    # a tiled step's span counts the kernel's passes: a 20-token chunk beside two decode
+    # rows in one tile of 64 tokens (4 heads), over the 4 latent layers
+    tiled = {"tok_meta": np.zeros((4, 64), np.int32), "seq_meta": np.zeros((8, 4 + 16), np.int32)}
+    tiled["seq_meta"][:3, 1:3] = [(1, 0), (1, 1), (20, 21)]
+    counts = engine.model.batch_counts(tiled)
+    assert (counts["latent_passes"], counts["latent_rider_passes"]) == (3 * 4, 2 * 4)
     prompt, feed = _ids(3, 70), _ids(4, 3)
     want = _want(cfg, params, prompt, feed)
     got, looped = _served(engine, prompt, feed, (41, 29))

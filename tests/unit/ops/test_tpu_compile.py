@@ -1366,3 +1366,24 @@ def test_longcat_decode_loop_program_fits_one_chip(v5e, longcat_model):
     out = jax.eval_shape(loop, params, cache, batch)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
     assert out[2].shape == (8, 4, 4)
+
+
+# ---- the tiled latent grid's tile, by the heads a call sees (PR 63) ----
+@pytest.mark.parametrize("fixture, heads, tile", [
+    ("kimi_model", 32, 32), ("longcat_model", 64, 16), ("deepseek_model", 128, 8)])
+def test_a_latent_tile_is_1024_mxu_rows_whatever_the_head_count(request, fixture, heads, tile):
+    """The three families' ``put`` programs above compile the tile this rule
+    gives them (and the one-token pass's deeper buffer beside it); the arm a
+    bucket takes is the bucket's alone, as it was."""
+    from deepspeed_tpu.ops.pallas import latent_attention as la
+    model = request.getfixturevalue(fixture)[0]
+    assert model._config.num_attention_heads == heads and tile * heads == la.TILE_ROWS == 1024
+    for bucket in (8, 16, 32):
+        assert la.tile_tokens(bucket, heads) == la.tile_tokens(bucket) == 1
+        assert model.attention_arm(bucket) == "latent_token"
+    for bucket in (64, 128, 256):
+        assert la.tile_tokens(bucket, heads) == tile and la.tile_tokens(bucket) == 8
+        assert model.attention_arm(bucket) == "latent_tiled"
+    # 8 tokens the floor; a bucket the rule's tile does not divide takes the next that does
+    assert la.tile_tokens(256, 2 * heads) == max(tile // 2, 8) and la.tile_tokens(256, 512) == 8
+    assert la.tile_tokens(48, heads) == min(tile, 16)
