@@ -189,10 +189,10 @@ class InferenceEngineV2:
         self.last_step_key = None
         # under a telemetry session, what the fetch of the newest ``put`` step
         # reports of its experts where its bucket routes by sorting:
-        # ``moe_path``, ``moe_assignments`` and ``moe_banks``, the last the
-        # step's own count, int32 [expert layers], a device array whose copy
-        # to the host is under way; else None, and nothing more than the
-        # step's result is ever fetched
+        # ``moe_path``, ``moe_rows``, ``moe_assignments`` and ``moe_banks``,
+        # the last the step's own counts, int32 [expert layers, counts], a
+        # device array whose copy to the host is under way; else None, and
+        # nothing more than the step's result is ever fetched
         self.last_moe_fetch = None
 
     # ------------------------------------------------------------------ groups --
@@ -465,8 +465,9 @@ class InferenceEngineV2:
         tile) visits: ``(moe_visits - moe_banks) / moe_visits`` of them are an
         expert's further row tile) summed over the expert layers (and a
         chunk's steps); a model whose layers hold a share of their experts
-        counts what landed here too (``model.moe_count_names``, the array's
-        last axis)."""
+        counts what landed here too, and the sorted rows it walked
+        (``moe_assignments_local``, ``moe_rows_walked``: ``model.moe_count_names``,
+        the array's last axis)."""
         counts = np.asarray(banks)
         return {name: int(counts[..., i].sum())
                 for i, name in enumerate(self._model.moe_count_names)}
@@ -498,6 +499,7 @@ class InferenceEngineV2:
                 banks = self._model.last_moe_banks
                 banks.copy_to_host_async()
                 self.last_moe_fetch = {"moe_path": args["moe_path"],
+                                       "moe_rows": args["moe_rows"],
                                        "moe_assignments": args["moe_assignments"],
                                        "moe_banks": banks}
             self._post_forward(batch_uids)

@@ -29,9 +29,14 @@ class RoutedExperts:
         beyond the default (softmax, renormalised over the chosen, unscaled).
         ``held`` of the ``num_experts`` from ``first_held`` on where the model
         is one chip's share of its layers: the device then counts what landed
-        here (``moe_assignments_local``) beside the banks; where the router
-        also has outputs that are experts without a bank (``zero_experts``
-        among ``router``), the choices that fell on those
+        here (``moe_assignments_local``) beside the banks, and the sorted rows
+        its dispatch, experts and combine walked (``moe_rows_walked``: whole
+        row windows as far as what landed reaches, one unless the router is
+        skewed; every row of the bucket where there is no window,
+        ``heuristics.moe_row_window``; over the host's ``moe_rows`` it is the
+        share of the bucket walked);
+        where the router also has outputs that are experts without a bank
+        (``zero_experts`` among ``router``), the choices that fell on those
         (``moe_assignments_zero``)."""
         ep_cfg = getattr(self._engine_config, "expert_parallel", None)
         share = held is not None and held < num_experts
@@ -42,7 +47,8 @@ class RoutedExperts:
             for li in layer_ids]
         self._expert_width, self._dense_layers = width, dense_layers
         if share:
-            self.moe_count_names = ("moe_banks", "moe_assignments_local", "moe_visits")
+            self.moe_count_names = ("moe_banks", "moe_assignments_local", "moe_visits",
+                                    "moe_rows_walked")
         if router.get("zero_experts"):
             self.moe_count_names += ("moe_assignments_zero", )
 

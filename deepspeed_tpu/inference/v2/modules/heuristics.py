@@ -6,8 +6,11 @@ from the registry given the model+engine config). The TPU build has two real
 attention implementations to arbitrate between; everything else is one
 XLA-fused implementation, so the heuristic surface is that choice and, for a
 sparse model on one replica, how the tokens reach their experts
-(``moe_implementation``).
+(``moe_implementation``) and, where a layer holds a share of them, how many of
+the sorted rows it walks (``moe_row_window``).
 """
+
+import math
 
 from deepspeed_tpu.utils.logging import logger
 
@@ -112,3 +115,34 @@ def moe_implementation(tokens: int, num_experts: int, top_k: int, capacity: int,
     if mask_share >= MOE_MASK_SHARE_MIN and mask_elements >= MOE_MASK_ELEMENTS_MIN:
         return "grouped"
     return "capacity"
+
+
+# A layer that holds a SHARE of the experts it routes over sorts the choices
+# that landed on it first and, uniformly routed, expects ``tokens * top_k *
+# held / outputs`` of them. Its dispatch, activation and combine walk the
+# sorted rows a WINDOW of that many times this factor at a time (whole row
+# tiles), as far as the device's own count reaches: one window unless the
+# router is skewed. LongCat's 256-token step walks 256 of 3,072 rows for ~64
+# that land (a binomial's 24 standard deviations of room), DeepSeek's 512 of
+# 2,048 for ~131. A smaller factor saves microseconds of a window's gather and
+# matmul; 4 keeps the second window for a router that is wrong, not unlucky.
+MOE_WINDOW_FACTOR = 4
+
+
+def moe_row_window(tokens: int, top_k: int, outputs: int, held=None):
+    """Rows of the sorted buffer a layer that holds ``held`` of its router's
+    ``outputs`` (the experts with a bank and without) walks at a time from
+    dispatch to combine: ``MOE_WINDOW_FACTOR`` times the expected local rows
+    in whole row tiles, never under one; or None where there is no window: a
+    layer that holds every expert (every row is local), and a share whose
+    window would pass half of the bucket's ``padded_rows(tokens * top_k)``
+    (a loop that saves less than half is not worth a program of its own:
+    Kimi's 64 of 256, Nemotron's 64 of 128, any 8-row decode bucket, whose
+    rows are one tile). A pure function of static shapes; how many windows a
+    step walks is the device's to say (``RaggedMoE._grouped_forward``)."""
+    from deepspeed_tpu.ops.pallas.grouped_matmul import ROW_TILE, padded_rows
+    if held is None or held >= outputs:
+        return None
+    expected = tokens * top_k * held / outputs
+    window = max(ROW_TILE, padded_rows(math.ceil(MOE_WINDOW_FACTOR * expected)))
+    return window if 2 * window <= padded_rows(tokens * top_k) else None

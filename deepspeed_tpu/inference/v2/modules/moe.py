@@ -38,6 +38,11 @@ gather of their rows, one grouped matmul a projection —
 dropless whatever the skew, no capacity, and no bank read that has no row; how
 many banks had a row is data on the device, and a caller that passes
 ``banks_out`` is handed the count, beside the kernel's count of visits).
+A layer that holds a SHARE of its experts has most of its assignments land on
+another chip: the sort places its own first, and where the share is small
+enough (``heuristics.moe_row_window``) dispatch, experts and combine walk the
+sorted rows a static window at a time as far as what landed reaches, and the
+counts say how many rows were walked (``_grouped_forward``).
 The grouped path is taken where the masks would cost a real share of the
 experts (many narrow experts, a full chunk of tokens), and where the bucket's
 assignments cannot touch more than half of the banks (a decode step's 8 rows
@@ -62,7 +67,12 @@ Named scopes (``jax.named_scope``, metadata only), the same four on both paths:
 GEMMs), ``combine`` (back to token-major with the routing weights) and, under
 expert parallelism, ``a2a`` (the two all-to-alls). They are relative: the
 caller's ``moe`` scope (mixtral_v2's FFN phase) makes them ``moe/route`` ... in
-the device trace.
+the device trace. The one place where that does not hold is the body of the
+loop over row windows: JAX traces a ``while``'s body under an empty name stack
+and lowers it under ``while/body``, so a scope inside it reads
+``moe/while/body/dispatch`` on the device and a reader that looks for
+``moe/dispatch/`` (``moe_route_busy_pct``) passes it by. The body's scopes
+therefore restate the caller's (``LOOP_SCOPE``): ``moe/while/body/moe/dispatch``.
 """
 
 from typing import Optional
@@ -72,6 +82,10 @@ import numpy as np
 from deepspeed_tpu.utils import groups
 
 _SIMULATED_GATING = {"enabled": False, "temperature": 1.0}
+
+# what the scopes inside the loop over row windows start with: the scope every
+# model program wraps this module in (the module docstring's last paragraph)
+LOOP_SCOPE = "moe/"
 
 
 def enable_simulated_gating(temperature: float = 1.0) -> None:
@@ -183,6 +197,13 @@ class RaggedMoE:
             return "grouped"  # the path that computes an expert without a bank
         return moe_implementation(tokens, self.num_experts, self.top_k, self.capacity(tokens),
                                   intermediate, ep, held=self.held)
+
+    def row_window(self, tokens: int):
+        """Rows of the sorted buffer a ``tokens``-token bucket's grouped
+        program walks at a time, or None: no window, every row at once
+        (``heuristics.moe_row_window``)."""
+        from deepspeed_tpu.inference.v2.modules.heuristics import moe_row_window
+        return moe_row_window(tokens, self.top_k, self.num_experts + self.zero_experts, self.held)
 
     def expert_rows(self, tokens: int, ep: int = 1, path: str = "capacity") -> int:
         """Rows the expert GEMMs compute for a ``tokens``-token bucket, live or
@@ -437,8 +458,30 @@ class RaggedMoE:
         passes the banks is the visits that are an expert's further row tile).
         A layer that holds a SHARE of its experts (``held``) sorts the
         assignments of the others' experts behind its own, as an invalid
-        token's, and appends ``[banks, assignments, visits]`` that landed
-        here."""
+        token's, and appends ``[banks, assignments, visits, rows walked]``
+        that landed here.
+
+        Such a layer WALKS THE ROWS THAT LANDED ON IT (PR 64). What landed is
+        the first ``n_local`` sorted rows and a few per cent of the bucket's
+        (LongCat: ~64 of a 256-token step's 3,072), and the gather of the rows,
+        the mask, the gather back, the weights and the sum over all of them
+        were 8.6 % of that cell's busy time for rows the kernel never stored
+        (PERF.md section 6, PR 64, step 0: combine 406 us, dispatch 114 us a
+        layer-step on a TPU v5e). Where ``heuristics.moe_row_window`` gives
+        the bucket a window of ``W`` rows, everything from dispatch to combine
+        runs on ``W`` sorted rows at a time, from the first, until past
+        ``n_local`` (a ``while`` on the device's own count: one window when
+        what landed fits it, none when nothing landed, every one under a router
+        that lands everything here): nothing is dropped whatever the skew. A
+        window's groups are each group's rows inside it (a group that straddles
+        two windows has its bank read in both; the row tiles and so the visits
+        are the un-windowed schedule's), and its rows reach their tokens by
+        one float32 matmul with the ``[T, W]`` matrix of which row is whose
+        (exact products at HIGHEST precision; a token's at most ``k`` rows
+        are summed in another order than the gather's), so there is no inverse
+        permutation. A layer without a window (one that holds every expert,
+        a share of a half, a bucket of one row tile) runs the program it ran
+        before."""
         import jax
         import jax.numpy as jnp
         from deepspeed_tpu.ops.pallas.grouped_matmul import padded_rows, visit_count
@@ -446,6 +489,7 @@ class RaggedMoE:
         T, M = h.shape
         E, k = self.experts_here, self.top_k
         rows = padded_rows(T * k)
+        window = self.row_window(T)
         with jax.named_scope("route"):
             probs = self._router_probs(h, gate_w, gate_seed=gate_seed)  # [T, E] float32
             topk_p, topk_e = self._choose(probs, select_bias)  # [T, k]
@@ -457,26 +501,74 @@ class RaggedMoE:
             # stable: an expert's rows stay in token order
             e_sorted, order = jax.lax.sort((e_flat, slots), num_keys=1, is_stable=True)
             group_sizes = (e_flat[:, None] == jnp.arange(E)[None, :]).sum(0, dtype=jnp.int32)
+            n_local = None if self.held is None else group_sizes.sum(dtype=jnp.int32)
             if banks_out is not None:
-                here = [] if self.held is None else [group_sizes.sum(dtype=jnp.int32)]
+                here, walked = [], []
+                if self.held is not None:
+                    here = [n_local]
+                    # (the last window of a bucket that is no whole number of
+                    # them is padding past ``rows``: not rows of the bucket)
+                    walked = [jnp.int32(rows) if window is None
+                              else jnp.minimum(-(-n_local // window) * window, rows)]
                 zero = [self._zero_chosen(topk_e, token_valid).sum(dtype=jnp.int32)] \
                     if self.zero_experts else []
                 banks_out.append(jnp.stack([(group_sizes > 0).sum(dtype=jnp.int32), *here,
-                                            visit_count(group_sizes), *zero]))
-            # where each assignment's row went: the inverse permutation
-            _, back = jax.lax.sort((order, slots), num_keys=1)
-        with jax.named_scope("dispatch"):
-            buf = h[jnp.minimum(order // k, T - 1)]  # [rows, M]
-        with jax.named_scope("experts"):
-            out = self._grouped_ffn(buf, wi, wo, group_sizes, activation)  # [rows, M] float32
-        with jax.named_scope("combine"):
-            # rows behind the last group (an invalid token's, the padding) are
-            # no expert's: whatever the kernel left there
-            out = jnp.where((e_sorted < E)[:, None], out, 0.0)
-            out = out[back[:T * k]].reshape(T, k, M) * topk_p[:, :, None]
-            if not self.zero_experts:
-                return out.sum(axis=1).astype(h.dtype)
-            out = out.sum(axis=1)
+                                            visit_count(group_sizes), *walked, *zero]))
+            if window is None:
+                # where each assignment's row went: the inverse permutation
+                _, back = jax.lax.sort((order, slots), num_keys=1)
+
+        def experts_over(slots_of, experts_of, sizes, under=""):
+            """Sorted rows (the assignment slot and the expert of each, in
+            groups of ``sizes``) through dispatch and the experts: float32
+            ``[len(slots_of), M]``, the rows of no expert zero. ``under``: what
+            the scopes' names start with (the loop over windows, below)."""
+            with jax.named_scope(under + "dispatch"):
+                buf = h[jnp.minimum(slots_of // k, T - 1)]
+            with jax.named_scope(under + "experts"):
+                out = self._grouped_ffn(buf, wi, wo, sizes, activation)  # float32
+            with jax.named_scope(under + "combine"):
+                # rows behind the last group (an invalid token's, the padding,
+                # another chip's) are no expert's: whatever the kernel left there
+                return jnp.where((experts_of < E)[:, None], out, 0.0)
+
+        if window is None:
+            out = experts_over(order, e_sorted, group_sizes)
+            with jax.named_scope("combine"):
+                out = (out[back[:T * k]].reshape(T, k, M) * topk_p[:, :, None]).sum(axis=1)
+        else:
+            # whole windows of the sorted rows, so that the last one is whole too
+            spare = -rows % window
+            order = jnp.pad(order, (0, spare), constant_values=rows)
+            e_sorted = jnp.pad(e_sorted, (0, spare), constant_values=E)
+            ends = jnp.cumsum(group_sizes)
+            weights = topk_p.reshape(T * k)
+
+            def one_window(carry):
+                """Rows ``start .. start + window`` of the sorted order: each
+                group's rows among them against its bank, summed onto their
+                tokens."""
+                start, out = carry
+                with jax.named_scope(LOOP_SCOPE + "route"):
+                    mine = jax.lax.dynamic_slice(order, (start, ), (window, ))
+                    experts_of = jax.lax.dynamic_slice(e_sorted, (start, ), (window, ))
+                    inside = jnp.clip(ends, start, start + window) \
+                        - jnp.clip(ends - group_sizes, start, start + window)
+                rows_out = experts_over(mine, experts_of, inside, under=LOOP_SCOPE)
+                with jax.named_scope(LOOP_SCOPE + "combine"):
+                    # a row's weight is its assignment's; which row is whose as a
+                    # matrix: exact products, a token's rows summed in float32
+                    rows_out = rows_out * weights[jnp.minimum(mine, T * k - 1)][:, None]
+                    to_token = (jnp.arange(T)[:, None] == (mine // k)[None, :])
+                    out = out + jnp.matmul(to_token.astype(jnp.float32), rows_out,
+                                           precision=jax.lax.Precision.HIGHEST)
+                return start + window, out
+
+            _, out = jax.lax.while_loop(lambda carry: carry[0] < n_local, one_window,
+                                        (jnp.int32(0), jnp.zeros((T, M), jnp.float32)))
+        if not self.zero_experts:
+            with jax.named_scope("combine"):
+                return out.astype(h.dtype)
         return (out + self._zero_term(h, topk_p, topk_e, token_valid)).astype(h.dtype)
 
     def _grouped_ffn(self, buf, wi, wo, group_sizes, activation):

@@ -2486,9 +2486,10 @@ class ServingScheduler:
         """The blocking transfer of an engine call's result to the host, apart
         from the call itself (span ``fetch``: the wait for the device is here,
         the call's own span is the dispatch). ``moe``: a grouped ``put`` step's
-        ``moe_path``, ``moe_assignments`` and its count of expert banks touched,
-        the last a device array on its way to the host since the launch
-        (``engine.last_moe_fetch``): the span carries the three, the count
+        ``moe_path``, ``moe_rows``, ``moe_assignments`` and its counts of routed
+        work (the banks touched; for a share also what landed here and the rows
+        walked), the last a device array on its way to the host since the launch
+        (``engine.last_moe_fetch``): the span carries them, the counts
         summed over the expert layers — read behind the result, when the
         device is done with the step. (A chunk's result,
         :class:`DecodeChunk`, converts itself: its tokens ``[members, K]``,

@@ -9,7 +9,9 @@ which PR 59 recorded on its PARENT tree before it moved any code (PR 60
 re-recorded it: ``moe_count_names`` gained ``moe_visits`` in every family, and the
 ``put`` / ``chunk`` hashes of the four families that hold a share of their experts
 moved with the one more count their programs return; every other hash held; PR 62
-ADDED ``longcat_flash``'s entry and left every other as it was)::
+ADDED ``longcat_flash``'s entry and left every other as it was; PR 64 re-recorded it:
+the five families that hold a share of their experts count ``moe_rows_walked`` and their
+``put`` / ``chunk`` hashes moved with it, the six that hold every expert held theirs)::
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
         python -m tests.unit.inference.v2.family_pins --record

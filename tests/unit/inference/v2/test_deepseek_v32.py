@@ -260,8 +260,9 @@ def test_the_shares_add_up_to_the_uncut_layer(path, monkeypatch):
             assert np.abs(part - want).max() < TOL * np.abs(whole).max()
             total = total + part
             if path == "grouped":
-                banks, assignments, visits = np.asarray(counts[0])
+                banks, assignments, visits, walked = np.asarray(counts[0])
                 assert 0 <= banks <= 4 and visits == banks  # one row tile: a visit a bank
+                assert walked == 128  # ... and no window on it
                 landed += int(assignments)
     assert np.abs(total - whole).max() < TOL * np.abs(whole).max()
     if path == "grouped":
@@ -310,7 +311,8 @@ def test_the_spans_name_the_arm_and_count_the_index_and_the_local_assignments(mo
     assert m.attention_arm(8) == m.attention_arm(64) == "latent_xla"
     assert engine_of(cfg, params, True).model.attention_arm(8) == "latent_token"
     assert engine_of(cfg, params, True).model.attention_arm(64) == "latent_tiled"
-    assert m.moe_count_names == ("moe_banks", "moe_assignments_local", "moe_visits")
+    assert m.moe_count_names == ("moe_banks", "moe_assignments_local", "moe_visits",
+                                 "moe_rows_walked")
     assert m.moe_path(8) == m.moe_path(64) == "grouped"
     # a decode row at position 49 in a bucket of 4 x 16 = 64 > 32 keys: 50 scored, 32 read
     batch = {"tok_meta": np.array([[1] * 8, [0] * 8, [49] + [0] * 7, [1] + [0] * 7]),
@@ -320,8 +322,8 @@ def test_the_spans_name_the_arm_and_count_the_index_and_the_local_assignments(mo
     # a table of 2 x 16 = 32 keys selects everything: nothing scored
     short = dict(batch, seq_meta=np.zeros((8, 4 + 2), np.int32))
     assert m.batch_counts(short) == {"index_keys": 0, "index_selected": 0}
-    assert engine.moe_counts(np.array([[3, 5, 4], [2, 4, 2]])) == \
-        {"moe_banks": 5, "moe_assignments_local": 9, "moe_visits": 6}
+    assert engine.moe_counts(np.array([[3, 5, 4, 128], [2, 4, 2, 256]])) == \
+        {"moe_banks": 5, "moe_assignments_local": 9, "moe_visits": 6, "moe_rows_walked": 384}
     # on the tiled grid the span counts the kernel's passes too: a 20-token chunk beside two
     # decode rows in one tile of 64 tokens (4 heads), over the 3 layers; none on the token grid
     tiled = {"tok_meta": np.zeros((4, 64), np.int32), "seq_meta": np.zeros((8, 4 + 2), np.int32)}

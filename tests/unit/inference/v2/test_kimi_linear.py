@@ -33,8 +33,9 @@ from tests.unit.inference.v2.program_hashes import decode_loop_hash
 BLOCK = 16
 TOL = 1e-4
 # sha256 of the tiny model's traced decode_loop program (``program_hashes.decode_loop_hash``)
-# re-recorded in PR 60: the chunk's count of routed work holds the grouped kernel's visits too
-DECODE_LOOP_HASH = "a94aa031ae008c330e10cf9a48acf2f19f8707be00cad6c8195e97f6d254ebe9"
+# re-recorded in PR 60: the chunk's count of routed work holds the grouped kernel's visits too;
+# in PR 64: and the sorted rows the layer walked (the 8-row bucket's one tile: a constant)
+DECODE_LOOP_HASH = "9cf5b42ef804a4f295b5b279b2113e136b0cb600e6e5581ed38e2df92b0fbfbf"
 # two periods of the tiny preset: KDA (dense), KDA, KDA, MLA, KDA, MLA
 LAYERS = dict(num_hidden_layers=6, kda_layers=(1, 2, 3, 5), full_attn_layers=(4, 6))
 
@@ -278,7 +279,7 @@ def test_the_counts_say_what_both_mixers_did(engine):
     counts = engine.model.dispatch_counts(8, 2, 4)
     assert counts["moe_path"] == "grouped" and counts["moe_assignments"] == 2 * 4 * 5 * 4
     assert engine.model.moe_count_names == ("moe_banks", "moe_assignments_local",
-                                             "moe_visits")
+                                             "moe_visits", "moe_rows_walked")
     engine.flush(0), engine.flush(1)
 
 

@@ -328,10 +328,12 @@ def test_where_every_choice_is_an_identity_expert_no_row_reaches_the_grouped_mat
         assert np.abs(got[:3] - 3.0 * np.asarray(p[:3, 4:].sum(-1, keepdims=True) * h[:3])).max() \
             < 1e-5
         names = (("moe_banks", "moe_visits") if held is None else
-                 ("moe_banks", "moe_assignments_local", "moe_visits")) + ("moe_assignments_zero", )
+                 ("moe_banks", "moe_assignments_local", "moe_visits", "moe_rows_walked")) \
+            + ("moe_assignments_zero", )
         read = dict(zip(names, (int(c) for c in counts[0])))
         assert read["moe_assignments_zero"] == 6 and read["moe_banks"] == read["moe_visits"] == 0
         assert read.get("moe_assignments_local", 0) == 0
+        assert read.get("moe_rows_walked", 128) == 128  # one row tile: no window to walk
 
 
 def test_an_expert_parallel_mesh_refuses_experts_without_a_bank_by_name():
@@ -363,7 +365,7 @@ def test_the_counts_say_what_the_layers_did(engine):
     counts = engine.model.dispatch_counts(8, 2, 4)
     assert counts["moe_path"] == "grouped" and counts["moe_assignments"] == 2 * 4 * 2 * 4
     assert engine.model.moe_count_names == ("moe_banks", "moe_assignments_local", "moe_visits",
-                                             "moe_assignments_zero")
+                                             "moe_rows_walked", "moe_assignments_zero")
     engine.flush(0), engine.flush(1)
     # the device's own count of a step: 8 identity outputs of 24, 4 choices a token a layer
     engine.put([0], [_ids(44, 40)])
@@ -371,6 +373,9 @@ def test_the_counts_say_what_the_layers_did(engine):
     engine.flush(0)
     assert 0 < read["moe_assignments_zero"] < 40 * 4 * 2
     assert 0 < read["moe_assignments_local"] <= 40 * 4 * 2 - read["moe_assignments_zero"]
+    # the bucket's every sorted row, both layers: the tiny model's share (4 of 24 outputs) has
+    # no window at this bucket
+    assert read["moe_rows_walked"] == engine.model.dispatch_counts(64, 40)["moe_rows"]
 
 
 # ------------------------------------- (f) every other family's program stays --

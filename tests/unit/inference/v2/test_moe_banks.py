@@ -330,3 +330,31 @@ def test_the_counts_way_to_the_host_starts_at_the_launch_and_only_under_a_sessio
     assert started == [(4, 2), (4, 4, 2)]
     assert handed["moe_banks"] is engine.model.last_moe_banks and handed["moe_path"] == "grouped"
     assert handed["moe_assignments"] == 5 * 4 * 4  # live tokens x top-k x expert layers
+
+
+# ------------------------------------- a share's put steps: the rows it walked ---
+def test_a_shares_put_steps_count_the_rows_they_walked_of_the_rows_they_had(session):
+    """One of sixteen experts held at top-4: a 64-token bucket's 256 sorted
+    rows have a window of 128 (``heuristics.moe_row_window``), its 8-, 16- and
+    32-token buckets are one row tile and have none. The ``fetch`` span of
+    every ``put`` step carries the bucket's rows beside the rows walked
+    (PR 64)."""
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models import deepseek_v32 as ds
+    from tests.unit.inference.v2.test_deepseek_v32 import engine_of
+    cfg = ds.DeepseekV32Config.tiny(dtype=jnp.float32, experts_held=1, expert_rank=3)
+    engine = engine_of(cfg, ds.init_params(cfg, rng=jax.random.PRNGKey(3))[1])
+    layers = len(engine.model._moes)
+    assert [engine.model._moes[0].row_window(t) for t in (8, 32, 64)] == [None, None, 128]
+    counters = _serve(engine, decode_chunk=1, lengths=(60, 50), new_tokens=4)
+    rows = session.spans.export_since(0)["spans"]
+    fetches = [s["args"] for s in rows if (s["name"], s["cat"]) == ("fetch", "sched")
+               and "moe_rows_walked" in (s.get("args") or {})]
+    assert len(fetches) == counters["moe_grouped_steps"] == counters["put_steps"] > 0
+    windowed = [a for a in fetches if a["moe_rows"] == 256 * layers]
+    assert windowed and all(a["moe_rows_walked"] % 128 == 0 and
+                            a["moe_rows_walked"] <= a["moe_rows"] for a in fetches)
+    # nothing like half of a 64-token bucket's choices lands on one expert of sixteen
+    assert all(a["moe_rows_walked"] <= 128 * layers for a in windowed)
+    assert all(a["moe_rows_walked"] == a["moe_rows"] for a in fetches if a not in windowed)

@@ -368,10 +368,12 @@ def test_the_decode_loop_program_is_the_one_it_was_before_the_scan_went_by_segme
     ``ssm.load`` / ``ssm.store_in_place`` (every other family's recorded
     programs, ``test_one_group_programs.py`` and ``test_afmoe.py``, hold).
     Re-pinned by PR 60: the chunk's count of routed work holds the grouped
-    kernel's visits beside the banks and the local assignments."""
+    kernel's visits beside the banks and the local assignments. Re-pinned by
+    PR 64: and the sorted rows the layer walked (no window at this share: the
+    bucket's every row, a constant)."""
     cfg, params = model_in_place
     assert decode_loop_hash(engine_of(cfg, params).model) == \
-        "5e3cffe624afe936e7d5cac39c2937c755c1a695eed0a30812053b554197911a"
+        "22181d5ad18cbf6508ddbd231a31bb9341986d644d87e3955cae2d27dbac7bec"
 
 
 def test_admission_stops_at_the_last_free_slot(model):

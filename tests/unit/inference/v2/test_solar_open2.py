@@ -33,8 +33,9 @@ from tests.unit.inference.v2.program_hashes import decode_loop_hash
 BLOCK = 16
 TOL = 1e-4
 # sha256 of the tiny model's traced decode_loop program (``program_hashes.decode_loop_hash``)
-# re-recorded in PR 60: the chunk's count of routed work holds the grouped kernel's visits too
-DECODE_LOOP_HASH = "ef06eb07cb605b266f3b1667d88639e008f98d8b8ae2f6ac1dc93a7bb14f773b"
+# re-recorded in PR 60: the chunk's count of routed work holds the grouped kernel's visits too;
+# in PR 64: and the sorted rows the layer walked (the 8-row bucket's one tile: a constant)
+DECODE_LOOP_HASH = "f62d48bb4c5261ed3793ea13eb0b73f7f450dbe2906152dc072dde8094f296b1"
 
 
 def sizes_of(cfg):
@@ -366,7 +367,7 @@ def test_the_counts_say_what_the_delta_rule_did(engine):
     counts = engine.model.dispatch_counts(8, 2, 4)
     assert counts["moe_path"] == "grouped" and counts["moe_assignments"] == 2 * 4 * 3 * 4
     assert engine.model.moe_count_names == ("moe_banks", "moe_assignments_local",
-                                             "moe_visits")
+                                             "moe_visits", "moe_rows_walked")
     engine.flush(0), engine.flush(1)
 
 

@@ -564,6 +564,20 @@ def _deepseek_args(device, model, abstract, bucket):
     return one, params, cache, batch
 
 
+def _walks_a_window(text, layers, window, width=6144, banks="16,(6144,4096|2048,6144)"):
+    """A share's row window in a compiled program (PR 64): a loop an expert
+    layer whose body calls the grouped matmul over ``window`` sorted rows (the
+    second projection's float32 ``[window, width]``) and no call over more (a
+    bucket of one row tile has no window and no loop of its own: ``window`` is
+    then the tile), and no copy of a bank (``banks``: their shapes) into a loop."""
+    kernels = {int(m) for m in re.findall(rf"= f32\[(\d+),{width}\]\S* custom-call\(.*grouped_matmul",
+                                          text)}
+    bank_copies = [line for line in text.splitlines()
+                   if re.search(rf"= bf16\[{banks}\]\S* copy\(", line)]
+    return (len(re.findall(r" while\(", text)) >= layers and " conditional(" not in text
+            and kernels == {window} and not bank_copies)
+
+
 def _latent_pool_copies(text):
     """``copy`` instructions whose result is a whole latent or index pool."""
     import re
@@ -591,6 +605,10 @@ def test_deepseek_put_program_fits_one_chip(v5e, deepseek_model, bucket, kernel,
     assert ("latent_index_scores" in text) == selects
     assert _device_bytes(compiled) < 0.8 * HBM_BYTES
     assert not _latent_pool_copies(text)
+    # 16 of 256 experts held: a 256-token bucket walks its 2,048 sorted rows 512 at a time as far
+    # as what landed reaches; the decode bucket's 128 rows are one tile, walked whole as before
+    assert _walks_a_window(text, layers=4, window=512 if bucket[0] == 256 else 128, width=7168,
+                           banks="16,(7168,4096|2048,7168)")
 
 
 def test_deepseek_decode_loop_program_fits_one_chip(v5e, deepseek_model):
@@ -1346,7 +1364,9 @@ def test_longcat_put_program_fits_one_chip(v5e, longcat_model, tokens):
     assert _longcat_pool_in_place(text)
     out = jax.eval_shape(model._forward_impl, params, cache, batch)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
-    assert out[2].shape == (4, 4)  # banks, local assignments, visits, identity choices a layer
+    # banks, local assignments, visits, rows walked, identity choices a layer
+    assert out[2].shape == (4, 5)
+    assert _walks_a_window(text, layers=4, window=256 if tokens == 256 else 128)
 
 
 def test_longcat_decode_loop_program_fits_one_chip(v5e, longcat_model):
@@ -1365,7 +1385,8 @@ def test_longcat_decode_loop_program_fits_one_chip(v5e, longcat_model):
     assert _longcat_pool_in_place(text)
     out = jax.eval_shape(loop, params, cache, batch)
     assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
-    assert out[2].shape == (8, 4, 4)
+    assert out[2].shape == (8, 4, 5)
+    assert _walks_a_window(text, layers=4, window=128)  # inside the scan
 
 
 # ---- the tiled latent grid's tile, by the heads a call sees (PR 63) ----
