@@ -60,12 +60,6 @@ class FalconH1V2Model(Mamba2Model):
         return self._mamba2
 
     # --------------------------------------------------------------- phases --
-    @jax.named_scope("embed")
-    def embed(self, params, ids):
-        cfg = self._config
-        rows = _root(params)["embed_tokens"]["embedding"][ids]
-        return (rows.astype(jnp.float32) * cfg.embedding_multiplier).astype(cfg.dtype)
-
     @jax.named_scope("unembed")
     def unembed(self, params, x):
         r, cfg = _root(params), self._config

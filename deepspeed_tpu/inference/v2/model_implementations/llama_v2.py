@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.v2.model_implementations.transformer_base import (
-    DSTransformerModelBase, _rms, _root)
+    DSTransformerModelBase, _rms, _root, scaled_dot)
 from deepspeed_tpu.models.llama import LlamaConfig, rotary_embedding
 
 
@@ -48,6 +48,11 @@ class PositionFreeGQA:
     (``nemotron_h_v2.py``, ``solar_open2_v2.py``): grouped-query, causal, no
     position encoding, no norm or residual of its own (the caller's)."""
 
+    # what the queries are multiplied by where the model's softmax scale is not
+    # the kernels' 1 / sqrt(head_dim) (its scale over theirs), on the q
+    # projection's float32 product; None: nothing, the projection as it is
+    query_scale = None
+
     @jax.named_scope("attn")
     def _attn_phase(self, ap, ai, h, kv, attn_fn):
         """Softmax layer ``ai`` (its ordinal: its layer of the K/V array) over
@@ -55,7 +60,11 @@ class PositionFreeGQA:
         output gated from the layer's input."""
         T = h.shape[0]
         H, KVH, D = self.num_heads, self.num_kv_heads, self.head_dim
-        q = (h @ ap["q_proj"]["kernel"].astype(h.dtype)).reshape(T, H, D)
+        if self.query_scale is None:
+            q = h @ ap["q_proj"]["kernel"].astype(h.dtype)
+        else:
+            q = scaled_dot(h, ap["q_proj"]["kernel"], self.query_scale)
+        q = q.reshape(T, H, D)
         k = (h @ ap["k_proj"]["kernel"].astype(h.dtype)).reshape(T, KVH, D)
         v = (h @ ap["v_proj"]["kernel"].astype(h.dtype)).reshape(T, KVH, D)
         out, kv = attn_fn(q, k, v, kv, ai)

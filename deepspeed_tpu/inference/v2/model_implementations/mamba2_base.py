@@ -38,18 +38,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference.v2.model_implementations.sequence_slots import SequenceSlots
-from deepspeed_tpu.inference.v2.model_implementations.transformer_base import \
-    DSTransformerModelBase
+from deepspeed_tpu.inference.v2.model_implementations.transformer_base import (
+    DSTransformerModelBase, scaled_dot)
 from deepspeed_tpu.inference.v2.modules import ssm
 from deepspeed_tpu.inference.v2.ragged.manager_configs import SequenceStateSpec
-
-
-def scaled_dot(x, kernel, scale: float):
-    """``(x kernel) scale`` with the scale on the float32 product: a
-    multiplier that is no power of two would otherwise be rounded to the
-    activations' type once itself and round the product once more."""
-    return (jnp.dot(x, kernel.astype(x.dtype), preferred_element_type=jnp.float32)
-            * scale).astype(x.dtype)
 
 
 class Mamba2Shape(NamedTuple):

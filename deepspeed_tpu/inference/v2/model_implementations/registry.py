@@ -60,6 +60,12 @@ _FAMILIES: Dict[str, Tuple[str, str, str, str]] = {
     # some of whose outputs are experts without a bank (they return their input)
     "longcat_flash": ("longcat_flash", "LongcatFlashConfig", "longcat_flash_v2",
                       "LongcatFlashV2Model"),
+    # serving only, as one chip's share: a Mamba-2 OR a position-free softmax mixer and
+    # THEN routed experts beside a shared one in every layer (two phases a layer from
+    # different mixins), four scalar multipliers (the softmax scale on the queries), a
+    # tied head: the embedding contracted on its last axis
+    "granitemoehybrid": ("granitemoehybrid", "GraniteMoeHybridConfig", "granitemoehybrid_v2",
+                         "GraniteMoeHybridV2Model"),
     "opt": ("decoder", "DecoderConfig", "decoder_v2", "DecoderV2Model"),
     "falcon": ("decoder", "DecoderConfig", "decoder_v2", "DecoderV2Model"),
     "phi": ("decoder", "DecoderConfig", "decoder_v2", "DecoderV2Model"),

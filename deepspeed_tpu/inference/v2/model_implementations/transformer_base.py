@@ -36,6 +36,14 @@ def _rms(x, w, eps):
     return (normed * w).astype(x.dtype)
 
 
+def scaled_dot(x, kernel, scale):
+    """``(x kernel) scale`` with the scale on the float32 product: a
+    multiplier that is no power of two would otherwise be rounded to the
+    activations' type once itself and round the product once more."""
+    return (jnp.dot(x, kernel.astype(x.dtype), preferred_element_type=jnp.float32)
+            * scale).astype(x.dtype)
+
+
 def _root(params):
     """Normalize the two training-tree layouts: LlamaForCausalLM nests everything
     under "model"; MixtralForCausalLM's tree is flat."""
@@ -1196,7 +1204,14 @@ class DSTransformerModelBase:
     # Subclass hooks -----------------------------------------------------------
     @jax.named_scope("embed")
     def embed(self, params, ids):
-        return _root(params)["embed_tokens"]["embedding"][ids].astype(self._config.dtype)
+        """The tokens' rows of the embedding, times the config's
+        ``embedding_multiplier`` where it has one (on the float32 product)."""
+        cfg = self._config
+        rows = _root(params)["embed_tokens"]["embedding"][ids]
+        multiplier = getattr(cfg, "embedding_multiplier", None)
+        if multiplier is None:
+            return rows.astype(cfg.dtype)
+        return (rows.astype(jnp.float32) * multiplier).astype(cfg.dtype)
 
     def layer_forward(self, params, li, x, cache, attn_fn, batch):
         raise NotImplementedError

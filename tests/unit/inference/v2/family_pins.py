@@ -11,7 +11,9 @@ re-recorded it: ``moe_count_names`` gained ``moe_visits`` in every family, and t
 moved with the one more count their programs return; every other hash held; PR 62
 ADDED ``longcat_flash``'s entry and left every other as it was; PR 64 re-recorded it:
 the five families that hold a share of their experts count ``moe_rows_walked`` and their
-``put`` / ``chunk`` hashes moved with it, the six that hold every expert held theirs)::
+``put`` / ``chunk`` hashes moved with it, the six that hold every expert held theirs; PR 65
+ADDED ``granitemoehybrid``'s entry and left every other as it was: the query scale and the
+tied head touched no shared program)::
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
         python -m tests.unit.inference.v2.family_pins --record
@@ -36,7 +38,7 @@ from tests.unit.inference.v2.program_hashes import _stable
 
 TABLE = os.path.join(os.path.dirname(__file__), "family_pins.json")
 FAMILIES = ("mixtral", "mistral", "mellum", "afmoe", "sdar_moe", "deepseek_v32", "nemotron_h",
-            "falcon_h1", "solar_open2", "kimi_linear", "longcat_flash")
+            "falcon_h1", "solar_open2", "kimi_linear", "longcat_flash", "granitemoehybrid")
 # the put's feeds (mixed lengths; whole blocks of 4 for the block-diffusion family) and the
 # chunk's positions a sequence
 FEEDS, CHUNK = (16, 4, 8), 8
@@ -86,6 +88,11 @@ def _engine(family):
         from deepspeed_tpu.models import longcat_flash as m
         from tests.unit.inference.v2 import test_longcat_flash as t
         cfg = m.LongcatFlashConfig.tiny(dtype=jnp.float32, experts_held=4, expert_rank=1)
+    elif family == "granitemoehybrid":  # a state of one lane tile: the pool is on the kernels' rule
+        from deepspeed_tpu.models import granitemoehybrid as m
+        from tests.unit.inference.v2 import test_granitemoehybrid as t
+        cfg = m.GraniteMoeHybridConfig.tiny(dtype=jnp.float32, experts_held=4, expert_rank=1,
+                                            mamba_d_state=128)
     else:
         raise ValueError(family)
     return t.engine_of(cfg, m.init_params(cfg, rng=key)[1])

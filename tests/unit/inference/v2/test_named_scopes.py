@@ -101,6 +101,73 @@ def test_scopes_name_no_layer_and_dense_models_have_no_moe(lowered):
     assert "layers_0/" not in lowered["mixtral", "gather"]  # one row a kind, whatever the layer
 
 
+def _layer_operations(model, one_token):
+    """The scope path of every operation of the model's LAYERS (the embedding,
+    the head and the batch's unpacking left out): the layers alone, lowered with
+    debug info over the model's own synthetic batch, as a ``put`` runs them or
+    (``one_token``) as a ``decode_loop`` step does."""
+    from functools import partial
+    from tests.unit.inference.v2.family_pins import _NOT_A_SCOPE, _OP_PATH
+    dev = model._synthetic_batch(model._bucket_of(model._synthetic_batch(None)))
+    fields = model._unpack_batch(dict(dev, one_token_rows=one_token))
+    fields = {k: v for k, v in fields.items() if k != "one_token_rows"}
+
+    def layers(params, x, cache, fields):
+        batch = dict(fields, one_token_rows=one_token, moe_banks=[])
+        attn = partial(model._paged_attention, batch=batch)
+        for li in range(model.num_layers):
+            x, cache = model.layer_forward(params, li, x, cache, attn, batch)
+        return x, cache
+
+    x = jnp.zeros((dev["tok_meta"].shape[1], model.config.hidden_size), model.config.dtype)
+    text = jax.jit(layers).lower(model._params, x, model.state_manager.kv_cache.cache,
+                                 fields).as_text(debug_info=True)
+    # an operation's own name starts at the jitted function (``jit(layers)/ssm/conv/add``);
+    # inside a jitted helper's body (``jnp.einsum``) a name is relative and its call carries
+    # the scope; argument names and call-stack frames are locations too, and no operation's
+    paths = set()
+    for path in _OP_PATH.findall(text):
+        if path.startswith("jit(layers)/"):
+            paths.add("/".join(p for p in path.split("/")[:-1] if not _NOT_A_SCOPE.match(p)))
+    return paths
+
+
+def test_every_operation_of_a_granite_layer_is_under_a_scope_the_metrics_read():
+    """Granite 4.0-H (PR 65): a layer is a mixer and then the experts, and
+    every operation of either form of a layer — a ``put``'s scan, a chunk's
+    recurrence — lies under ``ssm``, ``attn`` or ``moe``; each scope the
+    per-layer metrics read is there; and the new metric's pattern, READ FROM
+    its file, takes the two projections and nothing else."""
+    import json
+    import os
+    from deepspeed_tpu.models import granitemoehybrid as gm
+    from tests.unit.inference.v2 import test_granitemoehybrid as t
+    cfg = gm.GraniteMoeHybridConfig.tiny(dtype=jnp.float32, experts_held=4, expert_rank=1)
+    engine = t.engine_of(cfg, gm.init_params(cfg, rng=jax.random.PRNGKey(3))[1])
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))))
+    with open(os.path.join(repo, "benchmark", "metrics", "ssm_proj_busy_pct.json")) as f:
+        projections = re.compile(json.load(f)["params"]["pattern"])
+    layer_scope = re.compile(r"(^|/)(ssm|attn|moe)(/|$)")
+    for one_token, form in ((False, "scan"), (True, "step")):
+        paths = _layer_operations(engine.model, one_token)
+        nobodys = sorted(p for p in paths if not layer_scope.search(p))
+        assert not nobodys, nobodys
+        for scope in ("ssm/in_proj", "ssm/conv", f"ssm/{form}", "ssm/gate_norm", "ssm/out_proj",
+                      "attn", "moe/route", "moe/dispatch", "moe/experts", "moe/combine",
+                      "moe/shared"):
+            assert any(re.search(rf"(^|/){scope}(/|$)", p) for p in paths), (form, scope)
+        other = "step" if form == "scan" else "scan"
+        assert not any(re.search(rf"(^|/)ssm/{other}(/|$)", p) for p in paths)
+        taken = {p for p in paths if projections.search(p)}
+        assert taken and all(re.search(r"(^|/)ssm/(in_proj|out_proj)(/|$)", p) for p in taken)
+        assert not any(re.search(r"(^|/)(moe|attn)(/|$)", p) for p in taken)
+    engine.close()
+    # the ends, in the whole program's text
+    whole = engine.model.lower_forward().as_text(debug_info=True)
+    assert re.search(r'[/"]embed/', whole) and re.search(r'[/"]unembed/', whole)
+
+
 class _NoScope(contextlib.ContextDecorator):
 
     def __enter__(self):

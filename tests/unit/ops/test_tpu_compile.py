@@ -1408,3 +1408,137 @@ def test_a_latent_tile_is_1024_mxu_rows_whatever_the_head_count(request, fixture
     # 8 tokens the floor; a bucket the rule's tile does not divide takes the next that does
     assert la.tile_tokens(256, 2 * heads) == max(tile // 2, 8) and la.tile_tokens(256, 512) == 8
     assert la.tile_tokens(48, heads) == min(tile, 16)
+
+
+# ---- granite-4.0-h-small-serve-1chip: a Mamba-2 or softmax mixer AND experts a layer (PR 65) ----
+GRANITE_LAYERS, GRANITE_SLOTS, GRANITE_BLOCKS, GRANITE_BLOCK, GRANITE_SEQS = 10, 64, 1024, 128, 32
+GRANITE_MIXERS, GRANITE_VOCAB = 9, 50176
+GRANITE_TAILS = (8, 3200)  # the 3 x 8448 convolution tails a sequence, folded (ssm.conv_slot)
+
+
+@pytest.fixture(scope="module")
+def granite_model():
+    """``granite-4.0-h-small-serve-1chip``: Granite-4.0-H-Small's published
+    widths, the first period of ``layer_types`` (9 Mamba-2 layers, 1 attention
+    layer), 36 of the 72 routed experts held, half the vocabulary, contexts to
+    2048, 32 sequences a step, over ``jax.eval_shape``d parameters."""
+    from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.model_implementations.registry import model_cls_for
+    from deepspeed_tpu.inference.v2.ragged.manager_configs import DSStateManagerConfig
+    from deepspeed_tpu.models import granitemoehybrid as granite
+    whole = granite.GraniteMoeHybridConfig()
+    cfg = granite.GraniteMoeHybridConfig(num_hidden_layers=GRANITE_LAYERS,
+                                         layer_types=whole.layer_types[:GRANITE_LAYERS],
+                                         vocab_size=GRANITE_VOCAB, experts_held=36, expert_rank=0)
+    abstract = jax.eval_shape(lambda: granite.init_params(cfg, param_dtype=cfg.dtype)[1])
+    engine_config = RaggedInferenceEngineConfig(
+        state_manager=DSStateManagerConfig(max_context=2048, max_ragged_batch_size=256,
+                                           max_ragged_sequence_count=GRANITE_SEQS,
+                                           max_tracked_sequences=GRANITE_SLOTS),
+        kv_block_size=GRANITE_BLOCK, use_paged_kernel=True,
+        expert_parallel={"capacity_factor": 7.2})
+    model = model_cls_for(cfg)(abstract, cfg, engine_config)
+    assert model.num_kv_layers == 1 and model.min_table_bucket == 16
+    assert model.min_sequence_bucket == GRANITE_SEQS
+    assert [(s.name, s.layers, s.shape, s.dtype) for s in model.sequence_state] == [
+        ("ssm", GRANITE_MIXERS, (128, 64, 128), "float32"),
+        ("conv", GRANITE_MIXERS, GRANITE_TAILS, "bfloat16")]
+    return model, abstract
+
+
+def _granite_args(device, abstract, tokens):
+    one = SingleDeviceSharding(device)
+    params = jax.tree.map(lambda leaf: _on(one, leaf.shape, leaf.dtype), abstract)
+    cache = (_on(one, (1, 2, GRANITE_BLOCKS, 8, GRANITE_BLOCK, 128), jnp.bfloat16),
+             _on(one, (GRANITE_MIXERS, GRANITE_SLOTS, 128, 64, 128), jnp.float32),
+             _on(one, (GRANITE_MIXERS, GRANITE_SLOTS) + GRANITE_TAILS, jnp.bfloat16))
+    batch = {"tok_meta": _on(one, (4, tokens), jnp.int32),
+             "seq_meta": _on(one, (GRANITE_SEQS, 4 + 16 + 1), jnp.int32)}
+    return params, cache, batch
+
+
+def _granite_tied_head(compiled, params):
+    """The program holds NO second ``[50176, 4096]`` array: the tied head reads
+    the embedding where it lies. Nothing makes an array of its shape, either
+    way round, but what passes the parameter through (tuples, a loop's carry,
+    bitcasts and the compiler's ``bitcast_fusion``s, which copy nothing); the
+    program's temporaries are under half of the table's 0.38 GiB; and the tree
+    has no ``lm_head``."""
+    import re
+    table = re.compile(rf"bf16\[(?:{GRANITE_VOCAB},4096|4096,{GRANITE_VOCAB})\]")
+    made = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z\-]*)\(", line)
+        if not m or not table.search(m.group(1)) or m.group(2) in (
+                "parameter", "tuple", "get-tuple-element", "bitcast", "while"):
+            continue
+        if m.group(2) == "fusion" and "calls=%bitcast_fusion" in line:
+            continue
+        made.append(line.strip()[:300])
+    return not made and "lm_head" not in params \
+        and compiled.memory_analysis().temp_size_in_bytes < 0.19 * 2**30
+
+
+def _granite_one_grouped_shape(text):
+    """One grouped-matmul shape a projection a program: every call reads a bank
+    of the 36 held, ``[36, 4096, 1536]`` going in and ``[36, 768, 4096]`` coming
+    out, and the ten layers' calls are alike."""
+    import re
+    calls = _kernel_calls(text, "grouped_matmul")
+    banks = {m for line in calls for m in re.findall(r"bf16\[36,(\d+,\d+)\]", line)}
+    return len(calls) == 2 * GRANITE_LAYERS and banks == {"4096,1536", "768,4096"}
+
+
+@pytest.mark.parametrize("tokens,kernel", [(32, "paged_attention_update"),
+                                           (256, "paged_attention_prefill")],
+                         ids=["smallest-bucket", "chunk-bucket"])
+def test_granite_put_program_fits_one_chip(v5e, granite_model, tokens, kernel):
+    """8.9 GiB of weights beside 0.5 GiB of K/V in ONE layer and 2.3 GiB of
+    float32 state in 64 slots of nine mixers: the chunked scan at (heads, head,
+    state, groups) = (128, 64, 128, 1) with the state a SEQUENCE, scanned in
+    its slot; ten grouped matmuls over the 36 held banks at 768 lanes; one
+    paged layer; the head the embedding itself."""
+    model, abstract = granite_model
+    assert model.moe_path(tokens) == "grouped"
+    params, cache, batch = _granite_args(v5e[0], abstract, tokens)
+    compiled = jax.jit(model._forward_impl, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and kernel in text
+    assert "ssm/scan" in text and "ssm/step" not in text
+    assert _device_bytes(compiled) < 0.85 * HBM_BYTES
+    assert _granite_tied_head(compiled, abstract)
+    assert _granite_one_grouped_shape(text)
+    assert not _state_sized_results(text, rows=4 * GRANITE_SEQS, per_row=128 * 64 * 128,
+                                    pool=(GRANITE_MIXERS, GRANITE_SLOTS))
+    assert _scans_in_the_pool(text, mixers=GRANITE_MIXERS)
+    assert not _pool_shaped_results(text, (GRANITE_MIXERS, GRANITE_SLOTS, 128, 64))
+    assert not _step_states(text, GRANITE_SEQS, 128, 64, 128, 1)
+    assert _tails_by_the_kernels(text, mixers=GRANITE_MIXERS)
+    assert not _conv_pool_results(text, (GRANITE_MIXERS, GRANITE_SLOTS) + GRANITE_TAILS)
+    out = jax.eval_shape(model._forward_impl, params, cache, batch)
+    assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
+
+
+def test_granite_decode_loop_program_fits_one_chip(v5e, granite_model):
+    """The real-width ``decode_loop`` program of 32 rows: one
+    ``ssm_step_in_place`` a Mamba-2 layer at (128, 64, 128, 1) over the pool
+    itself (no ``[rows, H, P, N]`` state outside it), the per-token paged kernel
+    in the one attention layer, one grouped-matmul shape, the head the
+    embedding, and the pools handed back in the types they came in."""
+    model, abstract = granite_model
+    params, cache, batch = _granite_args(v5e[0], abstract, GRANITE_SEQS)
+    loop = functools.partial(model._decode_loop_impl, n_steps=8)
+    compiled = jax.jit(loop, donate_argnums=(1, )).lower(params, cache, batch).compile()
+    text = compiled.as_text()
+    assert "paged_attention_update" in text and "ssm/scan" not in text
+    kernels = _kernel_calls(text, "ssm_step_in_place")
+    assert len(kernels) == GRANITE_MIXERS and all("ssm/step" in line for line in kernels), kernels
+    assert _device_bytes(compiled) < 0.85 * HBM_BYTES
+    assert _granite_tied_head(compiled, abstract)
+    assert _granite_one_grouped_shape(text)
+    assert not _state_sized_results(text, rows=8, per_row=128 * 64 * 128,
+                                    pool=(GRANITE_MIXERS, GRANITE_SLOTS))
+    assert _tails_by_the_kernels(text, mixers=GRANITE_MIXERS)
+    assert not _conv_pool_results(text, (GRANITE_MIXERS, GRANITE_SLOTS) + GRANITE_TAILS)
+    out = jax.eval_shape(loop, params, cache, batch)
+    assert [(c.shape, c.dtype) for c in out[1]] == [(c.shape, c.dtype) for c in cache]
